@@ -235,6 +235,24 @@ def f32_upcast_store_dots(hlo_text: str) -> list[str]:
             stripped,
         )
     }
+    # ... and so is the same convert-down printed as a one-op fusion of a
+    # dot's result (only dots are looked up: each lookup scans the text)
+    dot_names = set(
+        re.findall(
+            r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*f32\[[^\]]*\][^=]*?\bdot\(",
+            stripped,
+            re.M,
+        )
+    )
+    for m in re.finditer(
+        r"%?([\w.\-]+)\s*=\s*(?:bf16|f16)\[[^\]]*\][^=]*?\bfusion\(\s*"
+        r"%?([\w.\-]+)\s*\)",
+        stripped,
+    ):
+        if m.group(2) in dot_names and _fused_convert_source_dtype(
+            hlo_text, m.group(1), dtypes
+        ):
+            downcast.add(m.group(2))
     hits: list[str] = []
     for line in stripped.splitlines():
         m = re.match(
@@ -265,10 +283,44 @@ def f32_upcast_store_dots(hlo_text: str) -> list[str]:
                 break
             if op_name.startswith("convert"):
                 src = _convert_source_dtype(hlo_text, op_name, dtypes)
-                if src in ("bf16", "f16"):
-                    hits.append(m.group(1))
-                    break
+            else:
+                src = _fused_convert_source_dtype(hlo_text, op_name, dtypes)
+            if src in ("bf16", "f16"):
+                hits.append(m.group(1))
+                break
     return hits
+
+
+def _fused_convert_source_dtype(
+    hlo_text: str, fusion_name: str, dtypes: dict[str, str]
+) -> str | None:
+    """Element type entering a fusion whose root is a convert. XLA prints a
+    lone convert as ``%wrapped_convert = f32[..] fusion(%a), kind=kLoop,
+    calls=%wrapped_convert_computation``: the dot's operand is then the
+    fusion, and the 16-bit source sits inside the called computation."""
+    stripped = strip_strings(hlo_text)
+    fusion = re.search(
+        r"%?" + re.escape(fusion_name)
+        + r"\s*=\s*[a-z0-9]+\[[^\]]*\][^=]*?\bfusion\([^)]*\)[^\n]*?"
+        r"\bcalls=%?([\w.\-]+)",
+        stripped,
+    )
+    if not fusion:
+        return None
+    body = re.search(
+        r"^%?" + re.escape(fusion.group(1)) + r"\s*\([^\n]*\{\n(.*?)^\}",
+        stripped,
+        re.S | re.M,
+    )
+    if not body:
+        return None
+    root = re.search(
+        r"ROOT\s+%?([\w.\-]+)\s*=\s*[a-z0-9]+\[[^\]]*\][^=]*?\bconvert\(",
+        body.group(1),
+    )
+    if not root:
+        return None
+    return _convert_source_dtype(hlo_text, root.group(1), dtypes)
 
 
 def _convert_source_dtype(

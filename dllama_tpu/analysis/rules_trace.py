@@ -15,8 +15,7 @@ same-module calls:
 * decorated with ``@jax.jit`` / ``@partial(jax.jit, ...)`` /
   ``@functools.partial(jax.jit, ...)``;
 * passed to ``jax.jit(...)``, ``pl.pallas_call(...)``,
-  ``shard_map(...)`` / ``shard_map_compat(...)`` (bare name or wrapped
-  in ``partial``);
+  ``shard_map(...)`` (bare name or wrapped in ``partial``);
 * called by name from an already-traced function in the same module.
 
 Flagged inside a traced body: ``time.*`` clock calls, metric/recorder
@@ -36,7 +35,7 @@ from typing import Iterable
 
 from .core import Finding, Rule, SourceModule, dotted
 
-TRACER_TAILS = {"jit", "pallas_call", "shard_map", "shard_map_compat"}
+TRACER_TAILS = {"jit", "pallas_call", "shard_map"}
 METRIC_METHODS = {"inc", "observe", "labels", "record"}
 OBS_GETTERS = {"get_registry", "get_recorder", "get_span_tracker"}
 

@@ -652,9 +652,8 @@ def run_perplexity(args) -> None:
 
 
 def main(argv=None) -> None:
-    from .parallel.mesh import enable_compilation_cache, reassert_platform
+    from .parallel.mesh import enable_compilation_cache
 
-    reassert_platform()
     enable_compilation_cache()
     args = _build_parser().parse_args(argv)
     if args.mode == "worker":

@@ -1,13 +1,12 @@
 """Compiled-step cost analysis + HBM roofline accounting.
 
-Decode on this hardware is HBM-bandwidth-bound (docs/silicon_r03.md, the
-q40i4 format PR): a decode step's floor is (bytes it must read) / (HBM
-peak). XLA already knows the first number for every compiled program —
-``compiled.cost_analysis()`` reports flops and bytes accessed — so this
-module harvests it from the engine's compile cache, pairs it with the
-measured step-time histograms, and turns "is decode as fast as the
-hardware allows?" into a single achieved-vs-roofline fraction instead of
-a guess.
+Decode on this hardware is HBM-bandwidth-bound: a decode step's floor is
+(bytes it must read) / (HBM peak). XLA already knows the first number for
+every compiled program — ``compiled.cost_analysis()`` reports flops and
+bytes accessed — so this module harvests it from the engine's compile
+cache, pairs it with the measured step-time histograms, and turns "is
+decode as fast as the hardware allows?" into a single
+achieved-vs-roofline fraction instead of a guess.
 
 The same analytic weight-read model the bench uses
 (``weight_bytes_per_token``) lives here so the CLI can print a startup
@@ -24,43 +23,39 @@ import jax
 if TYPE_CHECKING:
     from ..formats.model_file import LlmHeader
 
-# Approximate per-chip HBM peak bandwidth by TPU generation, bytes/s
-# (public chip specs; matched against jax.devices()[0].device_kind,
-# lowercase substring). Unknown kinds — and the CPU test backend — report
-# None, and every roofline figure downstream degrades to "unavailable"
-# rather than a made-up fraction.
+# Per-chip HBM peak bandwidth, bytes/s, keyed by the exact
+# ``jax.devices()[0].device_kind`` string. Source: Google Cloud TPU
+# documentation, "TPU v5e" system architecture: 16 GB HBM2e at 819 GB/s
+# per chip; the v5e reports itself as "TPU v5 lite". Only kinds whose
+# reported string has been checked are listed: a TPU that is not here is
+# an error, never a default. The CPU test backend reports None and every
+# roofline figure downstream reads "unavailable".
 HBM_PEAK_BYTES_PER_S = {
-    "v6e": 1640e9,
-    "v6": 1640e9,
-    "v5p": 2765e9,
-    "v5e": 819e9,
-    "v5litepod": 819e9,
-    "v4": 1228e9,
-    "v3": 900e9,
+    "TPU v5 lite": 819e9,
 }
 
 
 def hbm_peak_bytes_per_s() -> float | None:
-    """Per-chip HBM peak for the current backend, or None when unknown
-    (CPU, unrecognized accelerator)."""
+    """Per-chip HBM peak for the current backend; None off the TPU. An
+    unlisted TPU kind raises: a roofline share against a guessed peak is
+    worse than none."""
     if jax.default_backend() != "tpu":
         return None
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return None
-    for marker, peak in HBM_PEAK_BYTES_PER_S.items():
-        if marker in kind:
-            return peak
-    return None
+    kind = jax.devices()[0].device_kind
+    if kind not in HBM_PEAK_BYTES_PER_S:
+        raise ValueError(
+            f"no HBM peak for device_kind {kind!r}; add it (with its "
+            f"source) to obs.cost.HBM_PEAK_BYTES_PER_S "
+            f"(known: {sorted(HBM_PEAK_BYTES_PER_S)})"
+        )
+    return HBM_PEAK_BYTES_PER_S[kind]
 
 
 def extract_cost(compiled: object) -> dict | None:
-    """{flops, bytes_accessed} from an executable's ``cost_analysis()``,
-    or None when the object is not an AOT-compiled executable (lazily
-    jitted step fns), the backend returns nothing, or the surface raises.
-    jax has returned both a bare dict and a one-per-module list across
-    versions; both shapes are accepted."""
+    """{flops, bytes_accessed} from an executable's ``cost_analysis()``
+    (a dict on jax 0.9), or None when the object is not an AOT-compiled
+    executable (lazily jitted step fns), the backend returns nothing, or
+    the surface raises."""
     fn = getattr(compiled, "cost_analysis", None)
     if fn is None:
         return None
@@ -68,8 +63,6 @@ def extract_cost(compiled: object) -> dict | None:
         ca = fn()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     flops = ca.get("flops")

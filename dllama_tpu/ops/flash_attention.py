@@ -16,7 +16,7 @@ Kernel layout: grid (B * H, T blocks, S blocks), S innermost so the online
 softmax state (m, l, acc) lives in VMEM scratch across S steps. S blocks
 entirely above the causal frontier are compute-skipped via pl.when, and
 their kv index map is clamped to the causal frontier. NOTE (round-3
-silicon finding, scripts/decode_probe.py): Mosaic does NOT elide the
+silicon finding): Mosaic does NOT elide the
 HBM->VMEM copy when a block index repeats, so the clamp bounds COMPUTE
 but not DMA traffic — per-call cache reads are O(S), which is why the
 engine bounds decode reads with bucketed attn_window slicing instead and
@@ -98,7 +98,7 @@ def _flash_stats_kernel(
     frontier scale by the stride. `quant_kv`: k/v tiles arrive int8 with
     per-row f32 scales as two extra [bs, 1]-blocked refs sharing the kv
     index map — dequant happens HERE on the VMEM tile, so HBM traffic is
-    the int8 bytes (VERDICT r4 #3), amortized over the tile's bt queries."""
+    the int8 bytes, amortized over the tile's bt queries."""
     if quant_kv:
         ks_ref, vs_ref, acc_out, m_out, l_out, m_ref, l_ref, acc_ref = rest
     else:
@@ -187,7 +187,7 @@ def flash_attention_stats(
     the kernel then DMAs the int8 planes plus a [bs, 1]-blocked scale ref
     and dequants on the VMEM tile — int8 prefill reads ~half the HBM
     bytes of bf16 and never materializes a dense cache copy (the pre-r5
-    behavior; VERDICT r4 #3)."""
+    behavior)."""
     quant_kv = isinstance(k, QuantKV)
     if isinstance(v, QuantKV) != quant_kv:
         raise TypeError(
@@ -317,7 +317,7 @@ def _flash_decode_kernel(
     """T=1 decode step: one query token per lane group, online softmax
     over S blocks. Blocks entirely beyond `pos` are compute-skipped and
     their kv index clamps to pos's block — but on real Mosaic the
-    repeated-index DMA is NOT elided (scripts/decode_probe.py), so cache
+    repeated-index DMA is NOT elided (round-3 chip finding), so cache
     reads stay O(S) per call and the ENGINE does not use this kernel for
     decode anymore (windowed XLA dense attention measured faster there);
     it is kept as the op-level T=1 flash surface and for stats emission.

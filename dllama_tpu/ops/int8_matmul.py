@@ -5,7 +5,7 @@ not DMA-bound: per-element int8->float conversion + sublane-broadcast
 multiply on the VPU costs more than the HBM reads it saves (the kernel
 realizes ~46% of HBM peak vs 67% for XLA dense bf16; the r3 sweep's
 "int8-raw" probe, which measured the convert alone, ran 1.01 ms vs
-0.47 ms for the full kernel — docs/silicon_r03.md). The fix is the
+0.47 ms for the full kernel). The fix is the
 reference's own arithmetic (src/nn/nn-cpu-ops.cpp:231-449: Q80
 activations x Q40 weights in INTEGER dot products, scales applied to the
 block sums) restated for the MXU:
@@ -41,7 +41,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .quant_matmul import QuantWeight, _pick_block, dequant
+from .quant_matmul import BLOCK_M, QuantWeight, _pick_block, dequant
 
 
 class Int8Weight(NamedTuple):
@@ -124,7 +124,7 @@ def _i8mm_kernel(xq_ref, sx_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int,
                  group: int):
     """One (m, bn) output tile accumulated over k blocks: per G-slice
     native int8 MXU dots, scales applied to the [m, bn] group sums."""
-    pk = pl.program_id(1)
+    pk = pl.program_id(2)
     bk = xq_ref.shape[1]
     m = xq_ref.shape[0]
     partial_out = jnp.zeros((m, o_ref.shape[1]), jnp.float32)
@@ -166,14 +166,14 @@ def i8matmul_2d(
     """Pallas grouped-int8 matmul; returns [m, n] f32.
 
     Default blocks inherit the Q40 sweep winner (bn=256, bk=4096) as the
-    starting point; scripts/sweep_r04_i8.py re-sweeps on silicon."""
+    starting point; not yet swept on silicon."""
     m, k = xq.shape
     n = q.shape[1]
     ng = s.shape[0]
     assert k % ng == 0, (k, ng)
     group = k // ng
     assert q.shape == (k, n) and sx.shape == (m, ng), (q.shape, sx.shape)
-    bn = _pick_block(n, block_n)
+    bn = _pick_block(n, block_n, ragged=True)
     # The k block must divide k AND hold whole groups; search downward over
     # group multiples for a divisor of k (group itself always qualifies:
     # pick_group guarantees group | k).
@@ -187,21 +187,31 @@ def i8matmul_2d(
         s = s.astype(jnp.float32)
 
     n_k = k // bk
-    grid = (n // bn, n_k)  # k innermost: the accumulator tile stays live
+    gpb = bk // group  # scale rows per k block
+    # Scale planes ride with the k-block index as a leading, squeezed axis:
+    # a (gpb, bn) / (m, gpb) block cut straight out of [ng, n] / [m, ng] is
+    # only legal on the chip when gpb is a multiple of 8 / 128 or the whole
+    # axis (k=14336, G=512: gpb=7 of ng=28 is neither). As the trailing
+    # dims of [n_k, gpb, n] and [n_k, m, gpb] they always equal the array's.
+    s3 = s.reshape(n_k, gpb, n)
+    sx3 = sx.reshape(m, n_k, gpb).transpose(1, 0, 2)
+    bm = min(m, BLOCK_M)  # row tiling: see quant_matmul.BLOCK_M
+    # k innermost: the accumulator tile stays live
+    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), n_k)
     return pl.pallas_call(
         functools.partial(_i8mm_kernel, n_k=n_k, group=group),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, bk), lambda i, j: (0, j)),
-            pl.BlockSpec((m, bk // group), lambda i, j: (0, j)),
-            pl.BlockSpec((bk, bn), lambda i, j: (j, i)),
-            pl.BlockSpec((bk // group, bn), lambda i, j: (j, i)),
+            pl.BlockSpec((bm, bk), lambda r, i, j: (r, j)),
+            pl.BlockSpec((None, bm, gpb), lambda r, i, j: (j, r, 0)),
+            pl.BlockSpec((bk, bn), lambda r, i, j: (j, i)),
+            pl.BlockSpec((None, gpb, bn), lambda r, i, j: (j, 0, i)),
         ],
-        out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i)),
-        scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
+        out_specs=pl.BlockSpec((bm, bn), lambda r, i, j: (r, i)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(xq, sx, q, s)
+    )(xq, sx3, q, s3)
 
 
 def _use_pallas() -> bool:
@@ -241,7 +251,7 @@ def i8matmul_tp(
     if mesh is None or mesh.devices.size == 1:
         return i8matmul(x, w)
 
-    from ..utils.compat import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if role == "row":

@@ -2,7 +2,7 @@
 
 Lives in ops/ (not models/) so the Pallas attention kernels can consume a
 QuantKV natively without a models<->ops import cycle: the int8-KV flash
-prefill (VERDICT r4 #3) passes the int8 values and per-row scales straight
+prefill passes the int8 values and per-row scales straight
 into the kernel instead of materializing a dense bf16 view of the cache.
 """
 

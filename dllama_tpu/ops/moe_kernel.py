@@ -324,7 +324,7 @@ def _grouped_schedule(top_i, weights, n_tokens, n_experts,
     sentinel). The min(E, A) term matters at DECODE scale: lane batches
     have A = m*k << E assignments, and the old E+1 bound would append ~E
     empty grid steps that each still DMA an expert tile (Mosaic does not
-    elide repeated-index block loads — docs/silicon_r03.md).
+    elide repeated-index block loads, round-3 chip finding).
 
     `max_segments` caps the expert-segment budget BELOW the worst case —
     the two-tier decode dedup (docs/moe_decode_dedup.md) compiles a

@@ -196,7 +196,7 @@ def qmatmul_ref(x: jnp.ndarray, w) -> jnp.ndarray:
 def _qmm_kernel(x_ref, q_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     """One (m, block_n) output tile, accumulated over k blocks in VMEM
     scratch: sublane-broadcast dequant then MXU."""
-    pk = pl.program_id(1)
+    pk = pl.program_id(2)
     q = q_ref[:]  # [bk, bn] int8
     d = d_ref[:]  # [bk // 32, bn] f32
     bk, bn = q.shape
@@ -228,18 +228,35 @@ def _qmm_kernel(x_ref, q_ref, d_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[:] = acc_ref[:]
 
 
+def _f16_bits_to_f32(bits: jnp.ndarray) -> jnp.ndarray:
+    """Exact f16 -> f32 from the raw 16 bits, in integer ops. The chip's
+    vector unit has no f16 type (Mosaic refuses an f16 VMEM load:
+    "Invalid vector type for load ... xf16"), so the packed kernel takes
+    the wire's f16 scale plane bit-cast to int16 and widens it itself:
+    normals re-bias the exponent into an f32 bit pattern, subnormals are
+    mant * 2^-24. Scales are finite, so inf/nan are not decoded."""
+    b = bits.astype(jnp.int32)
+    exp = (b >> 10) & 0x1F
+    mant = b & 0x3FF
+    normal = jax.lax.bitcast_convert_type(
+        ((exp + 112) << 23) | (mant << 13), jnp.float32
+    )
+    mag = jnp.where(exp == 0, mant.astype(jnp.float32) * 2.0**-24, normal)
+    return jnp.where((b & 0x8000) != 0, -mag, mag)
+
+
 def _qmm_i4_kernel(x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     """One (m, block_n) output tile from packed-nibble weights: the
     HBM->VMEM copy moves 0.5625 B/weight, then shift/mask unpack +
     sublane-broadcast dequant in VMEM feed the MXU in bf16 exactly like
     the int8 kernel. The unpack is a handful of VPU element-ops per tile;
-    the Q40 kernel was already dequant-compute-bound at 46% of HBM peak
-    (docs/silicon_r03.md), so halving bytes moves the balance point, and
-    the staged bench sweep (BENCH_SWEEP_FORMATS) measures which side
-    wins on silicon."""
-    pk = pl.program_id(1)
+    the Q40 kernel was dequant-compute-bound at 46% of HBM peak on the
+    round-3 chip run, so halving bytes moves the balance point, and the
+    staged bench sweep (BENCH_SWEEP_FORMATS) measures which side wins on
+    silicon."""
+    pk = pl.program_id(2)
     qp = qp_ref[:]  # [bk // 2, bn] int8, two nibbles per byte
-    d = d_ref[:]  # [bk // 32, bn] f16
+    d = _f16_bits_to_f32(d_ref[:])  # [bk // 32, bn]
     half, bn = qp.shape
     bk = half * 2
     u = qp.astype(jnp.int32) & 0xFF
@@ -249,7 +266,7 @@ def _qmm_i4_kernel(x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     w = (
         (
             jnp.concatenate([lo, hi], axis=1).astype(jnp.float32)
-            * d.astype(jnp.float32)[:, None, :]
+            * d[:, None, :]
         )
         .reshape(bk, bn)
         .astype(jnp.bfloat16)
@@ -274,13 +291,39 @@ def _qmm_i4_kernel(x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[:] = acc_ref[:]
 
 
-def _pick_block(n: int, preferred: int) -> int:
-    """Largest 128-multiple <= preferred that divides n (vocab dims like
-    151936 aren't multiples of 256)."""
-    for b in range(min(preferred, n), 0, -128):
+def _pick_block(n: int, preferred: int, ragged: bool = False) -> int:
+    """Block length for an axis of length n: the largest 128-multiple
+    <= preferred that divides n (vocab dims like 151936 aren't multiples
+    of 256), or n itself when the whole axis fits in one block.
+
+    Otherwise no 128-multiple tiles the axis exactly (a tp shard of a
+    Llama-3 vocab: 128256 / 4 = 32064 = 250.5 x 128). An output (n) axis
+    may then run `ragged`: `preferred`-wide blocks on a `pl.cdiv` grid,
+    where Pallas pads the tail block's reads and drops its out-of-range
+    writes — columns are independent, so the pad never reaches a kept
+    value. A contraction axis can't (pad rows would be summed in), and a
+    whole-axis block would only turn into a minutes-long compiler OOM,
+    so that case raises here with the shape in it."""
+    if n <= preferred:
+        return n
+    for b in range(preferred, 0, -128):
         if n % b == 0:
             return b
-    return n  # fall back to a single block
+    if ragged and preferred % 128 == 0:
+        return preferred
+    raise ValueError(
+        f"no legal kernel block for an axis of length {n}: no multiple of "
+        f"128 <= {preferred} divides it"
+        + ("" if ragged else " and a contraction axis cannot be padded")
+    )
+
+
+# Row (m) block: a prefill chunk over several lanes is thousands of rows,
+# and an (m, block_k) activation tile that size alone overflows the 16 MB
+# of scoped VMEM (m=2048: 2 x 16 MB double-buffered). 512 rows compile at
+# every 8B width; beyond that the rows are tiled, re-reading the weights
+# once per row block — prefill is compute-bound, so the re-read is cheap.
+BLOCK_M = 512
 
 
 @functools.partial(
@@ -305,25 +348,27 @@ def qmatmul_2d(
     m, k = x.shape
     n = q.shape[1]
     assert q.shape == (k, n) and d.shape == (k // Q_BLOCK, n), (q.shape, d.shape)
-    bn = _pick_block(n, block_n)
+    bn = _pick_block(n, block_n, ragged=True)
     bk = _pick_block(k, block_k)
     assert bk % Q_BLOCK == 0
     if d.dtype != jnp.float32:
         d = d.astype(jnp.float32)
 
     n_k = k // bk
-    grid = (n // bn, n_k)  # k innermost: the accumulator tile stays live
+    bm = min(m, BLOCK_M)
+    # k innermost: the accumulator tile stays live
+    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), n_k)
     return pl.pallas_call(
         functools.partial(_qmm_kernel, n_k=n_k),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, bk), lambda i, j: (0, j)),
-            pl.BlockSpec((bk, bn), lambda i, j: (j, i)),
-            pl.BlockSpec((bk // Q_BLOCK, bn), lambda i, j: (j, i)),
+            pl.BlockSpec((bm, bk), lambda r, i, j: (r, j)),
+            pl.BlockSpec((bk, bn), lambda r, i, j: (j, i)),
+            pl.BlockSpec((bk // Q_BLOCK, bn), lambda r, i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i)),
-        scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
+        out_specs=pl.BlockSpec((bm, bn), lambda r, i, j: (r, i)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x.astype(jnp.bfloat16), q, d)
 
@@ -353,25 +398,27 @@ def qmatmul_i4_2d(
         qp.shape,
         d.shape,
     )
-    bn = _pick_block(n, block_n)
+    assert d.dtype == jnp.float16, d.dtype
+    bn = _pick_block(n, block_n, ragged=True)
     bk = _pick_block(k, block_k)
     assert bk % Q_BLOCK == 0
 
     n_k = k // bk
-    grid = (n // bn, n_k)
+    bm = min(m, BLOCK_M)
+    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), n_k)
     return pl.pallas_call(
         functools.partial(_qmm_i4_kernel, n_k=n_k),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, bk), lambda i, j: (0, j)),
-            pl.BlockSpec((bk // 2, bn), lambda i, j: (j, i)),
-            pl.BlockSpec((bk // Q_BLOCK, bn), lambda i, j: (j, i)),
+            pl.BlockSpec((bm, bk), lambda r, i, j: (r, j)),
+            pl.BlockSpec((bk // 2, bn), lambda r, i, j: (j, i)),
+            pl.BlockSpec((bk // Q_BLOCK, bn), lambda r, i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i)),
-        scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
+        out_specs=pl.BlockSpec((bm, bn), lambda r, i, j: (r, i)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x.astype(jnp.bfloat16), qp, d)
+    )(x.astype(jnp.bfloat16), qp, jax.lax.bitcast_convert_type(d, jnp.int16))
 
 
 def _use_pallas() -> bool:
@@ -427,7 +474,7 @@ def qmatmul_tp(
 
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map_compat
+    from jax import shard_map
 
     # both weight classes are (values, scales) NamedTuples whose leaves
     # shard identically: the packed in/2 axis and the in/32 scale axis
@@ -464,6 +511,6 @@ def qmatmul_tp(
     else:
         raise ValueError(f"unknown role: {role}")
 
-    return shard_map_compat(
+    return shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_spec, check_vma=False
     )(x, values, scales)

@@ -131,7 +131,7 @@ def forward_pp(
     to the engine's lane-parking argument). Requires the cache's S axis
     to carry >= chunk-width padding beyond `park_pos`.
     """
-    from ..utils.compat import shard_map_compat as shard_map
+    from jax import shard_map
 
     from ..models.transformer import (
         attn_positions,

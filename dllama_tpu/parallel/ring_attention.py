@@ -164,7 +164,7 @@ def ring_attention(
     Requires T % sp == 0 and S % sp == 0. Head axes stay whole here; combine
     with the tp axis by nesting specs when both are in play.
     """
-    from ..utils.compat import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sp = mesh.shape[axis_name]

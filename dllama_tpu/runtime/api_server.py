@@ -777,6 +777,11 @@ class LaneScheduler:
                     and not any(self.lanes)
                     and not self.admitting
                 ):
+                    # going idle is not a stall: without this the last
+                    # beat still says "lanes active" and an idle server
+                    # turns scheduler-stalled after the timeout
+                    if self.state.watchdog is not None:
+                        self.state.watchdog.beat()
                     self.cv.wait()
                 if self._stop:
                     return
@@ -3631,85 +3636,100 @@ def _install_drain_handler(server) -> None:
         pass
 
 
-def main(argv=None) -> None:
+def build_arg_parser():
     import argparse
-    import os
 
-    import jax
-
-    from ..cli import add_engine_args, load_engine
+    from ..cli import add_engine_args
 
     parser = argparse.ArgumentParser(prog="dllama-tpu-api")
     parser.add_argument("--host", default="0.0.0.0")
     parser.add_argument("--port", type=int, default=9990)
     add_engine_args(parser)  # includes --trace-out (the JSONL sink)
-    args = parser.parse_args(argv)
+    return parser
 
-    from ..parallel.mesh import enable_compilation_cache, reassert_platform
 
-    reassert_platform()
-    enable_compilation_cache()
+def serve_from_args(args):
+    """Engine + HTTP server from parsed CLI args: everything `main` does
+    short of `serve_forever()`, so whoever needs the server a deployment
+    would start (chip_smoke.py, tests) builds it the same way. The engine
+    is `server.state.engine`."""
+    import os
 
-    # crash-and-retry outer loop (reference: dllama-api retries whole app
-    # init every 3 s, dllama-api.cpp:616-628). Transient failures
-    # (accelerator/tunnel/runtime errors) retry; permanent configuration
-    # errors (missing files, invalid settings) exit, and the dead engine is
-    # dropped before a reload so device memory isn't pinned twice.
+    from ..cli import load_engine
+
+    engine, tok = load_engine(args)
+    ttype = (
+        CHAT_TEMPLATE_NAMES[args.chat_template]
+        if args.chat_template
+        else ChatTemplateType.UNKNOWN
+    )
+    return serve(
+        engine,
+        tok,
+        host=args.host,
+        port=args.port,
+        model_name=os.path.basename(args.model),
+        chat_template_type=ttype,
+        trace_out=args.trace_out,
+        postmortem_dir=args.postmortem_dir,
+        lane_block_size=args.lane_block_size,
+        admission_chunk=args.admission_chunk,
+        kv_page_size=args.kv_page_size,
+        kv_pool_pages=args.kv_pool_pages,
+        kv_native=args.kv_native,
+        max_streams=args.max_streams,
+        timeline_out=args.timeline_out,
+        slo_ttft_ms=args.slo_ttft_ms,
+        slo_tpot_ms=args.slo_tpot_ms,
+        series_retention=args.series_retention,
+        speculation=args.speculation,
+        spec_k=args.spec_k,
+        draft_model=args.draft_model,
+        retry_max=args.retry_max,
+        retry_backoff_ms=args.retry_backoff_ms,
+        max_queue_depth=args.max_queue_depth,
+        faults=args.faults,
+        replica_id=args.replica_id,
+        admission_predict=args.admission_predict,
+        admission_max_wait_ms=args.admission_max_wait_ms,
+        deadline_default_ms=args.deadline_default_ms,
+        deadline_priority_step_ms=args.deadline_priority_step_ms,
+    )
+
+
+# Start-up attempts, 3 s apart (the reference's dllama-api retries app init
+# every 3 s forever, dllama-api.cpp:616-628). Only what a restart can cure
+# is retried: an OS-level error such as a port still held by the previous
+# process. A kernel the compiler refuses, a chip another process holds, a
+# missing file or a bad setting raise straight out and the process exits
+# non-zero — never a server that neither listens nor dies.
+START_ATTEMPTS = 3
+
+
+def main(argv=None) -> None:
     import gc
 
-    while True:
-        engine = None
+    from ..parallel.mesh import enable_compilation_cache
+
+    args = build_arg_parser().parse_args(argv)
+    enable_compilation_cache()
+    for attempt in range(1, START_ATTEMPTS + 1):
         try:
-            engine, tok = load_engine(args)
-            ttype = (
-                CHAT_TEMPLATE_NAMES[args.chat_template]
-                if args.chat_template
-                else ChatTemplateType.UNKNOWN
-            )
-            server = serve(
-                engine,
-                tok,
-                host=args.host,
-                port=args.port,
-                model_name=os.path.basename(args.model),
-                chat_template_type=ttype,
-                trace_out=args.trace_out,
-                postmortem_dir=args.postmortem_dir,
-                lane_block_size=args.lane_block_size,
-                admission_chunk=args.admission_chunk,
-                kv_page_size=args.kv_page_size,
-                kv_pool_pages=args.kv_pool_pages,
-                kv_native=args.kv_native,
-                max_streams=args.max_streams,
-                timeline_out=args.timeline_out,
-                slo_ttft_ms=args.slo_ttft_ms,
-                slo_tpot_ms=args.slo_tpot_ms,
-                series_retention=args.series_retention,
-                speculation=args.speculation,
-                spec_k=args.spec_k,
-                draft_model=args.draft_model,
-                retry_max=args.retry_max,
-                retry_backoff_ms=args.retry_backoff_ms,
-                max_queue_depth=args.max_queue_depth,
-                faults=args.faults,
-                replica_id=args.replica_id,
-                admission_predict=args.admission_predict,
-                admission_max_wait_ms=args.admission_max_wait_ms,
-                deadline_default_ms=args.deadline_default_ms,
-                deadline_priority_step_ms=args.deadline_priority_step_ms,
-            )
-            _install_drain_handler(server)
-            server.serve_forever()
-            return
-        except KeyboardInterrupt:
-            return
-        except (SystemExit, FileNotFoundError, ValueError):
+            server = serve_from_args(args)
+            break
+        except FileNotFoundError:
             raise
-        except Exception as e:
-            print(f"⚠️  {e}; retrying in 3s...")
-            del engine
-            gc.collect()
-            time.sleep(3)
+        except OSError as e:
+            if attempt == START_ATTEMPTS:
+                raise
+            print(f"⚠️  {e}; retry {attempt}/{START_ATTEMPTS - 1} in 3s...")
+        gc.collect()  # the failed attempt's engine must not pin device memory
+        time.sleep(3)
+    _install_drain_handler(server)
+    try:
+        server.serve_forever()
+    except KeyboardInterrupt:
+        pass
 
 
 if __name__ == "__main__":

@@ -35,9 +35,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from dllama_tpu.parallel.mesh import enable_compilation_cache, reassert_platform
+from dllama_tpu.parallel.mesh import enable_compilation_cache
 
-reassert_platform()
 enable_compilation_cache()
 
 import jax
@@ -48,17 +47,12 @@ from jax.experimental.pallas import tpu as pltpu
 Q_BLOCK = 32
 
 
-def sync(x):
-    return np.asarray(jax.device_get(jnp.ravel(x)[0]))
-
-
 def timeit(f, n_iter=100):
-    o = f()
-    sync(o)
+    jax.block_until_ready(f())
     t0 = time.perf_counter()
     for _ in range(n_iter):
         o = f()
-    sync(o)
+    jax.block_until_ready(o)
     return (time.perf_counter() - t0) / n_iter * 1000
 
 

@@ -1,6 +1,6 @@
 """Monte-Carlo routing correlation study for the MoE decode dedup default.
 
-VERDICT r4 #8: `--moe-decode-dedup`'s two-tier lax.cond pays off iff the
+`--moe-decode-dedup`'s two-tier lax.cond pays off iff the
 runtime unique-expert count u of a decode batch fits the small grid
 (u <= U_small = lanes*k/2). Whether that happens depends on routing
 correlation across lanes, which no synthetic fixture exhibits and no real

@@ -1,6 +1,6 @@
 """Llama-3.3-70B fit-and-plan rehearsal on the 8-virtual-device CPU mesh.
 
-VERDICT r4 #2: nothing in the repo had ever run at 70B shapes. This script
+Nothing in the repo had ever run at 70B shapes. This script
 does, end to end, with no silicon:
 
   1. streams a REAL-SIZE synthetic Q40 `.m` to disk (80 layers, 8192 dim,

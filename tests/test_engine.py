@@ -447,8 +447,7 @@ def test_perplexity_chunk_size_invariant(tiny_model):
 
 def test_topp_mask_matches_host_sampler_support():
     """The on-device top-p mask must keep exactly the token set the host
-    (reference-parity) sampler can return — same nucleus, different RNG
-    (VERDICT r1 weak #7). Covers generic rows and the topp 0/1 edge cases
+    (reference-parity) sampler can return — same nucleus, different RNG. Covers generic rows and the topp 0/1 edge cases
     where both paths degrade to the full distribution."""
     from dllama_tpu.runtime.engine import _topp_mask
     from dllama_tpu.runtime.sampler import softmax, topp_support
@@ -604,7 +603,7 @@ def test_cache_guard_recovers_from_failed_dispatch(tiny_model):
 
 
 def test_kv_int8_bounded_quality_and_capacity(tiny_model):
-    """VERDICT r4 item 8: kv_dtype=int8 (QuantKV per-row quantization)
+    """kv_dtype=int8 (QuantKV per-row quantization)
     keeps teacher-forced NLL within a tight bound of the f32 cache and
     halves-ish the cache footprint (int8 values + 1/hd scale rows)."""
     mp, _ = tiny_model
@@ -702,7 +701,7 @@ def test_kv_int8_with_lanes_and_dp(tiny_model):
 
 
 def test_window_precompile_no_boundary_stall(tmp_path, monkeypatch):
-    """Window-crossing pre-compile (VERDICT r4 #7): decode blocks past
+    """Window-crossing pre-compile: decode blocks past
     75% of the current attention window must trigger a BACKGROUND build
     of the next window's program, so the boundary crossing finds it in
     the cache (origin == 'prefetch', no synchronous compile) — and the

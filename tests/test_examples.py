@@ -1,6 +1,4 @@
-"""The shipped example scripts must keep working (VERDICT r1 weak #8: the
-reference's CI runs its test binaries; here the examples are the
-end-to-end CLI path, so they run on a tiny model in CI too)."""
+"""The shipped example scripts must keep working."""
 
 import os
 import subprocess
@@ -66,7 +64,7 @@ def test_n_chips_cli(tiny_pair):
 def test_chat_context_exhaustion_stops_explicitly(tiny_pair):
     """When the context window fills, the chat REPL must print an explicit
     stop and exit instead of silently generating nothing forever
-    (reference behavior: src/dllama.cpp:242-253; VERDICT r2 weak #7)."""
+    (reference behavior: src/dllama.cpp:242-253)."""
     mp, tp = tiny_pair
     # seq_len 128: a few user turns exhaust it (each turn re-encodes the
     # chat template around the message and then decodes until EOS/stop)

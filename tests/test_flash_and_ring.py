@@ -337,7 +337,7 @@ def test_moe_grouped_matches_dense_routing():
     """Prefill-scale grouped active-expert MoE (assignments sorted by
     expert, static (tile, segment) schedule) vs the dense-over-all-experts
     path — same routing, bf16 kernel tolerance. Covers partial tiles and
-    tiles spanning several expert segments (VERDICT r2 missing #3)."""
+    tiles spanning several expert segments."""
     from dllama_tpu.models.transformer import _moe_ffn, _moe_ffn_grouped
     from dllama_tpu.ops.jnp_ops import silu
 
@@ -398,7 +398,7 @@ def test_moe_grouped_schedule_dedups_shared_experts():
     would otherwise pay ~E pure-waste steps). NB the static grid still
     caps the HBM-read saving (empty steps DMA regardless): the full
     analysis and the lax.cond two-tier design that would realize read
-    dedup live in docs/moe_decode_dedup.md (VERDICT r3 item 6)."""
+    dedup live in docs/moe_decode_dedup.md."""
     from dllama_tpu.ops.moe_kernel import _GROUP_ROWS, _grouped_schedule
 
     E, m, k = 128, 8, 4
@@ -471,7 +471,7 @@ def test_flash_stats_strided_matches_jnp():
 def test_ring_cyclic_flash_local_step():
     """ring_attention_local in cyclic mode with the flash local step ==
     jnp local step (interpret mode, 4 shards)."""
-    from dllama_tpu.utils.compat import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from dllama_tpu.parallel.ring_attention import ring_attention_local
 
@@ -556,7 +556,7 @@ def _quant_kv_pair(k, v):
 
 def test_flash_stats_quantkv_matches_dequant():
     """QuantKV-native flash stats (int8 planes + [bs, 1] scale refs,
-    per-tile dequant in the kernel — VERDICT r4 #3) == jnp stats over the
+    per-tile dequant in the kernel) == jnp stats over the
     dense dequantized view, across offsets, per-lane positions and a
     parked lane."""
     from dllama_tpu.ops.flash_attention import flash_attention_stats
@@ -627,7 +627,7 @@ def test_flash_stats_quantkv_strided():
 
 
 def test_flash_quantkv_no_dense_materialization():
-    """The int8 prefill read claim (VERDICT r4 #3 'reads ~half of bf16'):
+    """The int8 prefill read claim:
     (a) the traced program feeds the kernel the int8 planes directly —
     no dense cache-shaped f32/bf16 intermediate exists anywhere in the
     jaxpr; (b) the cache-sized kernel inputs are ~53% the bytes of the
@@ -662,7 +662,7 @@ def test_ring_cyclic_flash_quantkv():
     """ring_attention_local in cyclic mode over a QuantKV shard: flash
     local step (int8-native) == jnp local step (local dequant); the ring
     rotates int8 payloads either way."""
-    from dllama_tpu.utils.compat import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from dllama_tpu.ops.kv_cache import QuantKV
     from dllama_tpu.parallel.ring_attention import ring_attention_local

@@ -82,7 +82,7 @@ def test_kernel_matches_ref(group, block_k):
 def test_kernel_awkward_k_block_alignment():
     """k=11264 (22*512), group=512, block_k=4096: naive group-rounding of
     the preferred block gives 2560, which does NOT divide k — the block
-    search must fall back to a group multiple that does (ADVICE r4)."""
+    search must fall back to a group multiple that does."""
     rng = np.random.default_rng(11)
     m, k, n, group = 2, 11264, 256, 512
     w, _ = _q40(rng, k, n)

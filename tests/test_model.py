@@ -282,7 +282,7 @@ def test_streamed_load_matches_stack(tmp_path):
 
 @pytest.mark.slow
 def test_streamed_loader_memory_bound(tmp_path):
-    """The 70B fit story's loader half (VERDICT r4 #2): streaming load of
+    """The 70B fit story's loader half: streaming load of
     a model with REAL Llama-70B layer dims (8192 dim / 28672 ffn; vocab
     shrunk so embed doesn't dominate a CI run) must keep the host
     high-water mark near the device bytes — NOT device + whole host

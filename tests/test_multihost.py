@@ -31,7 +31,7 @@ initialize_multihost(
 )
 import numpy as np
 import jax.numpy as jnp
-from dllama_tpu.utils.compat import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 assert jax.process_count() == 2, jax.process_count()
@@ -47,7 +47,7 @@ garr = jax.make_array_from_single_device_arrays(
 )
 out = jax.jit(
     shard_map(lambda a: jax.lax.psum(a, "tp"), mesh=mesh,
-              in_specs=P("tp"), out_specs=P("tp"))
+              in_specs=P("tp"), out_specs=P("tp"), check_vma=False)
 )(garr)
 local = np.asarray(out.addressable_shards[0].data)
 assert np.allclose(local, 3.0), local

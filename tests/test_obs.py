@@ -325,11 +325,9 @@ def test_extract_cost_shapes():
     assert extract_cost(object()) is None  # lazily jitted fn: no surface
     assert extract_cost(_FakeCompiled(RuntimeError("no"))) is None
     assert extract_cost(_FakeCompiled(None)) is None
-    assert extract_cost(_FakeCompiled([])) is None
-    assert extract_cost(_FakeCompiled([{}])) is None
-    # jax has shipped both one-dict-per-module lists and bare dicts
+    assert extract_cost(_FakeCompiled({})) is None
     got = extract_cost(
-        _FakeCompiled([{"flops": 10.0, "bytes accessed": 20.0}])
+        _FakeCompiled({"flops": 10.0, "bytes accessed": 20.0})
     )
     assert got == {"flops": 10.0, "bytes_accessed": 20.0}
     got = extract_cost(_FakeCompiled({"flops": 3.0}))

@@ -325,7 +325,7 @@ def test_perplexity_close_reference_qwen3_moe(dllama_binary, tmp_path):
     assert abs(ours_ppl - ref_ppl) / ref_ppl < 0.05, (ours_ppl, ref_ppl)
 
 
-# ~100M-param stress (VERDICT r4 #5): realistic depth/width/GQA — drift
+# ~100M-param stress: realistic depth/width/GQA — drift
 # that 2-layer fixtures can't catch (accumulation depth, RoPE at real
 # dims, 256-token error growth).
 MID_CFG = dict(dim=768, hidden_dim=2560, n_layers=12, n_heads=12,
