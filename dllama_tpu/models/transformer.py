@@ -774,6 +774,7 @@ def rope_slices(params: Params, pos: jnp.ndarray, t: int):
     return cos, sin
 
 
+@jax.named_scope("logits_head")
 def logits_head(
     x, params: Params, h: LlmHeader, mesh, logits_mode: str,
     tp_axis: str | None = None,
@@ -967,170 +968,181 @@ def run_layers(
         lp, k_cache_l, v_cache_l = layer
 
         # -- attention block (reference: src/llm.cpp:263-403) --
-        y = rms_norm(x, lp["att_norm"], h.norm_epsilon)
-        if "wqkv" in lp:
-            # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
-            # per decode layer at ~41 us fixed cost each on the round-3
-            # chip run). The un-interleave factor is the
-            # weight's own static metadata, not the mesh's tp — a fused-
-            # load/mesh mismatch stays correct (just non-optimally laid
-            # out) instead of silently permuting columns. Under manual tp
-            # the shard's local slice is one interleave chunk (the shard-
-            # major layout puts shard i's [q_i|k_i|v_i] in chunk i), so
-            # the local split factor is fuse / tp_n.
-            fw = lp["wqkv"]
-            if fw.fuse % tp_n != 0:
-                raise ValueError(
-                    f"fused weight interleave {fw.fuse} incompatible with "
-                    f"manual tp_n={tp_n}"
+        with jax.named_scope("norm"):
+            y = rms_norm(x, lp["att_norm"], h.norm_epsilon)
+        with jax.named_scope("attn"):
+            if "wqkv" in lp:
+                # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
+                # per decode layer at ~41 us fixed cost each on the round-3
+                # chip run). The un-interleave factor is the
+                # weight's own static metadata, not the mesh's tp — a fused-
+                # load/mesh mismatch stays correct (just non-optimally laid
+                # out) instead of silently permuting columns. Under manual tp
+                # the shard's local slice is one interleave chunk (the shard-
+                # major layout puts shard i's [q_i|k_i|v_i] in chunk i), so
+                # the local split factor is fuse / tp_n.
+                fw = lp["wqkv"]
+                if fw.fuse % tp_n != 0:
+                    raise ValueError(
+                        f"fused weight interleave {fw.fuse} incompatible with "
+                        f"manual tp_n={tp_n}"
+                    )
+                qkv = mm(y, fw.weight, "row")
+                q, k, v = _split_fused(
+                    qkv, fw.fuse // tp_n, tuple(d // tp_n for d in fw.dims)
                 )
-            qkv = mm(y, fw.weight, "row")
-            q, k, v = _split_fused(
-                qkv, fw.fuse // tp_n, tuple(d // tp_n for d in fw.dims)
-            )
-            q = q.reshape(b, t, hq, h.head_dim)
-            k = k.reshape(b, t, hkv, h.head_dim)
-            v = v.reshape(b, t, hkv, h.head_dim)
-        else:
-            q = mm(y, lp["wq"], "row").reshape(b, t, hq, h.head_dim)
-            k = mm(y, lp["wk"], "row").reshape(b, t, hkv, h.head_dim)
-            v = mm(y, lp["wv"], "row").reshape(b, t, hkv, h.head_dim)
-        if is_qwen3:
-            q = qk_rms_norm(q, lp["q_norm"], h.norm_epsilon)
-            k = qk_rms_norm(k, lp["k_norm"], h.norm_epsilon)
-        q = apply_rope(q, cos, sin, interleaved)
-        k = apply_rope(k, cos, sin, interleaved)
+                q = q.reshape(b, t, hq, h.head_dim)
+                k = k.reshape(b, t, hkv, h.head_dim)
+                v = v.reshape(b, t, hkv, h.head_dim)
+            else:
+                q = mm(y, lp["wq"], "row").reshape(b, t, hq, h.head_dim)
+                k = mm(y, lp["wk"], "row").reshape(b, t, hkv, h.head_dim)
+                v = mm(y, lp["wv"], "row").reshape(b, t, hkv, h.head_dim)
+            if is_qwen3:
+                q = qk_rms_norm(q, lp["q_norm"], h.norm_epsilon)
+                k = qk_rms_norm(k, lp["k_norm"], h.norm_epsilon)
+            q = apply_rope(q, cos, sin, interleaved)
+            k = apply_rope(k, cos, sin, interleaved)
 
-        k_cache_l = _cache_append(k_cache_l, k)
-        v_cache_l = _cache_append(v_cache_l, v)
+        with jax.named_scope("kv_write"):
+            k_cache_l = _cache_append(k_cache_l, k)
+            v_cache_l = _cache_append(v_cache_l, v)
 
-        if sp_axis is not None:
-            # manual sp (cyclic layout): a global window (sp multiple) is
-            # the local prefix window/sp on every shard; dequant AFTER
-            # slicing so int8 caches keep windowed, int8-sized reads
-            if attn_window and attn_window % sp_n:
-                raise ValueError(
-                    f"attn_window {attn_window} must be a multiple of "
-                    f"sp={sp_n}"
+        with jax.named_scope("attn"):
+            if sp_axis is not None:
+                # manual sp (cyclic layout): a global window (sp multiple) is
+                # the local prefix window/sp on every shard; dequant AFTER
+                # slicing so int8 caches keep windowed, int8-sized reads
+                if attn_window and attn_window % sp_n:
+                    raise ValueError(
+                        f"attn_window {attn_window} must be a multiple of "
+                        f"sp={sp_n}"
+                    )
+                w_rows = (
+                    attn_window // sp_n
+                    if attn_window and attn_window < shard_s * sp_n
+                    else 0
                 )
-            w_rows = (
-                attn_window // sp_n
-                if attn_window and attn_window < shard_s * sp_n
-                else 0
-            )
-            z = _attention_sp_merge(
-                q,
-                dequant_kv(_slice_kv(k_cache_l, w_rows), x.dtype),
-                dequant_kv(_slice_kv(v_cache_l, w_rows), x.dtype),
-                attn_pos, sp_axis, sp_n,
-            ).reshape(b, t, hq * h.head_dim)
-        else:
-            # flat non-sp: plain prefix slice (QuantKV rides sliced-but-
-            # quantized into _attention_tp, which dequants at entry); the
-            # sp mesh path windows inside _attention_sp per shard
-            w_flat = (
-                attn_window
-                if attn_window
-                and attn_window < k_cache_l.shape[2]
-                and _sp_mesh == 1
-                else 0
-            )
-            z = _attention_tp(
-                q,
-                _slice_kv(k_cache_l, w_flat),
-                _slice_kv(v_cache_l, w_flat),
-                attn_pos, h.head_dim, mesh,
-                attn_window=attn_window if _sp_mesh > 1 else 0,
-            )
-        x = x + mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
+                z = _attention_sp_merge(
+                    q,
+                    dequant_kv(_slice_kv(k_cache_l, w_rows), x.dtype),
+                    dequant_kv(_slice_kv(v_cache_l, w_rows), x.dtype),
+                    attn_pos, sp_axis, sp_n,
+                ).reshape(b, t, hq * h.head_dim)
+            else:
+                # flat non-sp: plain prefix slice (QuantKV rides sliced-but-
+                # quantized into _attention_tp, which dequants at entry); the
+                # sp mesh path windows inside _attention_sp per shard
+                w_flat = (
+                    attn_window
+                    if attn_window
+                    and attn_window < k_cache_l.shape[2]
+                    and _sp_mesh == 1
+                    else 0
+                )
+                z = _attention_tp(
+                    q,
+                    _slice_kv(k_cache_l, w_flat),
+                    _slice_kv(v_cache_l, w_flat),
+                    attn_pos, h.head_dim, mesh,
+                    attn_window=attn_window if _sp_mesh > 1 else 0,
+                )
+            x = x + mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
 
         # -- FFN block (reference: src/llm.cpp:405-557) --
-        y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
-        if h.arch == LlmArch.QWEN3_MOE:
-            # decode (lane-sized B*T): the ragged Pallas kernel reads only
-            # each token's active experts' weights — Q40 blocks when the
-            # experts are stored quantized. Prefill / CPU: dense-over-
-            # experts (XLA's jnp.take gather measured ~3x slower than even
-            # dense, so the gather path stays opt-in via
-            # moe_gather_max_tokens).
-            from ..ops.moe_kernel import moe_pallas_supported
+        with jax.named_scope("norm"):
+            y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
+        with jax.named_scope("moe" if h.arch == LlmArch.QWEN3_MOE else "ffn"):
+            if h.arch == LlmArch.QWEN3_MOE:
+                # decode (lane-sized B*T): the ragged Pallas kernel reads only
+                # each token's active experts' weights — Q40 blocks when the
+                # experts are stored quantized. Prefill / CPU: dense-over-
+                # experts (XLA's jnp.take gather measured ~3x slower than even
+                # dense, so the gather path stays opt-in via
+                # moe_gather_max_tokens).
+                from ..ops.moe_kernel import moe_pallas_supported
 
-            _w1 = lp["w1"]
-            _quantized = isinstance(_w1, QuantWeight)
-            _itemsize = 1 if _quantized else _w1.dtype.itemsize
-            _f = _w1.q.shape[-1] if _quantized else _w1.shape[-1]
-            # the kernels run PER-SHARD under shard_map, so the VMEM/
-            # tiling gate must see the per-shard F (= F / tp), not the
-            # global one — a shape legal globally can have no Mosaic-legal
-            # F block per shard
-            pallas_ok = (
-                h.hidden_act == HiddenAct.SILU
-                and jax.default_backend() == "tpu"
-                and _f % _tp_n == 0
-                and moe_pallas_supported(
-                    h.dim, _f // _tp_n, _quantized, _itemsize
-                )
-            )
-            if pallas_ok:
-                # decode-sized token counts take the per-(token, choice)
-                # ragged kernel; prefill-scale takes the grouped kernel
-                # (FLOPs proportional to selected experts, not all E).
-                # Multi-lane decode DEDUP through the grouped kernel was
-                # investigated for r4 and rejected: a Pallas grid is
-                # static, so it must be sized for the all-distinct worst
-                # case (~m*k steps) and Mosaic does not elide the empty
-                # steps' repeated-index DMAs (round-3 chip finding) — the
-                # schedule collapses *compute* per unique expert but not
-                # HBM reads. Analysis + the viable lax.cond two-tier
-                # design: docs/moe_decode_dedup.md.
-                if b * t <= MOE_PALLAS_MAX_TOKENS:
-                    f = _moe_ffn_pallas(
-                        y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
-                        h.n_active_experts, mesh, sync_quant=sync_quant,
-                        dedup=moe_decode_dedup,
+                _w1 = lp["w1"]
+                _quantized = isinstance(_w1, QuantWeight)
+                _itemsize = 1 if _quantized else _w1.dtype.itemsize
+                _f = _w1.q.shape[-1] if _quantized else _w1.shape[-1]
+                # the kernels run PER-SHARD under shard_map, so the VMEM/
+                # tiling gate must see the per-shard F (= F / tp), not the
+                # global one — a shape legal globally can have no Mosaic-legal
+                # F block per shard
+                pallas_ok = (
+                    h.hidden_act == HiddenAct.SILU
+                    and jax.default_backend() == "tpu"
+                    and _f % _tp_n == 0
+                    and moe_pallas_supported(
+                        h.dim, _f // _tp_n, _quantized, _itemsize
                     )
+                )
+                if pallas_ok:
+                    # decode-sized token counts take the per-(token, choice)
+                    # ragged kernel; prefill-scale takes the grouped kernel
+                    # (FLOPs proportional to selected experts, not all E).
+                    # Multi-lane decode DEDUP through the grouped kernel was
+                    # investigated for r4 and rejected: a Pallas grid is
+                    # static, so it must be sized for the all-distinct worst
+                    # case (~m*k steps) and Mosaic does not elide the empty
+                    # steps' repeated-index DMAs (round-3 chip finding) — the
+                    # schedule collapses *compute* per unique expert but not
+                    # HBM reads. Analysis + the viable lax.cond two-tier
+                    # design: docs/moe_decode_dedup.md.
+                    if b * t <= MOE_PALLAS_MAX_TOKENS:
+                        f = _moe_ffn_pallas(
+                            y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
+                            h.n_active_experts, mesh, sync_quant=sync_quant,
+                            dedup=moe_decode_dedup,
+                        )
+                    else:
+                        f = _moe_ffn_grouped(
+                            y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
+                            h.n_active_experts, mesh, sync_quant=sync_quant,
+                        )
                 else:
-                    f = _moe_ffn_grouped(
-                        y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
-                        h.n_active_experts, mesh, sync_quant=sync_quant,
+                    moe = (
+                        _moe_ffn_gather
+                        if b * t <= moe_gather_max_tokens
+                        else _moe_ffn
                     )
+                    f = moe(
+                        y,
+                        lp["moe_gate"],
+                        lp["w1"],
+                        lp["w2"],
+                        lp["w3"],
+                        h.n_active_experts,
+                        act,
+                    )
+                if tp_axis is not None:
+                    # manual tp: experts arrived F-sliced (same layout the
+                    # mesh path shards); the local partial outputs all-reduce
+                    # here instead of inside the helpers' shard_map
+                    f = lax.psum(f, tp_axis)
+            elif "w13" in lp:
+                # fused w1|w3: the SwiGLU pair shares its input and activation
+                fw13 = lp["w13"]
+                dl13 = mm(y, fw13.weight, "row")
+                d1, l3 = _split_fused(
+                    dl13, fw13.fuse // tp_n, tuple(d // tp_n for d in fw13.dims)
+                )
+                d = act(d1)
+                f = mm(d * l3.astype(d.dtype), lp["w2"], "col", sync=True)
             else:
-                moe = (
-                    _moe_ffn_gather
-                    if b * t <= moe_gather_max_tokens
-                    else _moe_ffn
-                )
-                f = moe(
-                    y,
-                    lp["moe_gate"],
-                    lp["w1"],
-                    lp["w2"],
-                    lp["w3"],
-                    h.n_active_experts,
-                    act,
-                )
-            if tp_axis is not None:
-                # manual tp: experts arrived F-sliced (same layout the
-                # mesh path shards); the local partial outputs all-reduce
-                # here instead of inside the helpers' shard_map
-                f = lax.psum(f, tp_axis)
-        elif "w13" in lp:
-            # fused w1|w3: the SwiGLU pair shares its input and activation
-            fw13 = lp["w13"]
-            dl13 = mm(y, fw13.weight, "row")
-            d1, l3 = _split_fused(
-                dl13, fw13.fuse // tp_n, tuple(d // tp_n for d in fw13.dims)
-            )
-            d = act(d1)
-            f = mm(d * l3.astype(d.dtype), lp["w2"], "col", sync=True)
-        else:
-            d = act(mm(y, lp["w1"], "row"))
-            l = mm(y, lp["w3"], "row")
-            f = mm(d * l.astype(d.dtype), lp["w2"], "col", sync=True)
-        x = x + f.astype(x.dtype)
+                d = act(mm(y, lp["w1"], "row"))
+                l = mm(y, lp["w3"], "row")
+                f = mm(d * l.astype(d.dtype), lp["w2"], "col", sync=True)
+            x = x + f.astype(x.dtype)
         return x, (k_cache_l, v_cache_l)
 
-    x, (k_new, v_new) = lax.scan(
-        layer_step, x, (layers, k_cache, v_cache)
-    )
+    # scopes name the device's operations in a profile (`op_name`) and
+    # change nothing that is compiled: what runs under `layers` but under
+    # no scope of `layer_step` is the scan's own slicing of the stacked
+    # weights and caches
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(
+            layer_step, x, (layers, k_cache, v_cache)
+        )
     return x, k_new, v_new

@@ -9,28 +9,38 @@ serving path of one request reconstructs from a single export.
 
 Design constraints mirror the metrics registry:
 
-* **Low overhead.** A span is two ``perf_counter`` reads and one dict
-  append under a short lock; with the tracker disabled ``begin`` returns
-  ``None`` after one attribute read and ``end(None)`` is a no-op, so the
-  bench's obs on/off comparison toggles this layer together with the
-  registry and the recorder.
+* **Low overhead.** A span is two clock reads and one dict append under
+  a short lock; with the tracker disabled ``begin`` returns ``None``
+  after one attribute read and ``end(None)`` is a no-op, so the bench's
+  obs on/off comparison toggles this layer together with the registry
+  and the recorder.
+* **One host clock, and the profiler's.** Spans stamp ``time.monotonic``,
+  the flight recorder's clock: ``epoch_monotonic + t0`` of a span and
+  ``t`` of a recorder event are one axis. Every span whose begin and end
+  share a thread also opens a ``jax.profiler.TraceAnnotation``
+  (``dllama.<component>.<name>``), so a profiler session holds it in the
+  same ``.xplane.pb`` as the device's operations, on the profiler's
+  clock; with no session active that is one atomic read.
 * **Bounded memory.** Completed spans land in a ring; old spans fall
   off. Drops are themselves observable: the first drop (and then every
   ``capacity`` further drops) records an ``obs_overflow`` flight-recorder
   event.
-* **Two exports.** :meth:`SpanTracker.chrome_trace` renders the ring (or
+* **Three exports.** :meth:`SpanTracker.chrome_trace` renders the ring (or
   one request's spans) as Chrome-trace / Perfetto JSON — ``pid`` is the
   component (scheduler / engine / kv / http), ``tid`` is the lane — and
   :meth:`SpanTracker.request_summary` folds one request's spans into a
   millisecond accounting ("TTFT = 480ms: 210 queue + 190 prefill-chunks
   + 45 adopt + 35 first block") plus a wall-time coverage fraction.
-  ``GET /v1/debug/timeline`` and ``--timeline-out`` serve both.
+  ``GET /v1/debug/timeline`` serves both. ``--timeline-out`` streams:
+  :meth:`SpanTracker.set_sink` appends each completed span to a file as
+  one Chrome-trace event per line, whatever the ring has dropped since.
 
 Threading: ``begin``/``end`` may run on different threads (the queue
 span begins on the HTTP handler thread and ends on the scheduler
 thread); a handle is mutated only by its ender and ``end`` is idempotent
 (the first ender wins), so cross-thread handoff needs no lock beyond the
-ring append.
+ring append. Such a span is begun with ``annotate=False``: an annotation
+says what its thread was doing, and has to end where it began.
 """
 
 from __future__ import annotations
@@ -45,7 +55,14 @@ from typing import Callable, Iterator
 
 from .recorder import get_recorder
 
+try:
+    from jax.profiler import TraceAnnotation
+except ImportError:  # obs/ stays importable without JAX: spans, no annotations
+    TraceAnnotation = None
+
 DEFAULT_CAPACITY = 4096
+# the streamed timeline's first event: its args anchor every span's `ts`
+TIMELINE_EPOCH = "timeline_epoch"
 
 # stable Chrome-trace pid per component (new components get the next id)
 _COMPONENT_PIDS = {"scheduler": 1, "engine": 2, "kv": 3, "http": 4, "cli": 5}
@@ -74,10 +91,10 @@ class _SpanHandle:
     """In-flight span state between ``begin`` and ``end``."""
 
     __slots__ = ("name", "component", "request_id", "lane", "t0", "attrs",
-                 "replica", "done")
+                 "replica", "annotation", "done")
 
     def __init__(self, name, component, request_id, lane, t0, attrs,
-                 replica=None):
+                 replica=None, annotation=None):
         self.name = name
         self.component = component
         self.request_id = request_id
@@ -85,7 +102,81 @@ class _SpanHandle:
         self.t0 = t0
         self.attrs = attrs
         self.replica = replica
+        self.annotation = annotation
         self.done = False
+
+
+def _annotate(name, component, request_id, lane, attrs):
+    """An entered ``TraceAnnotation`` carrying the span's integer and
+    string attributes (what the profiler's stats can hold)."""
+    args = {k: v for k, v in attrs.items() if isinstance(v, (int, str))}
+    if request_id is not None:
+        args["request_id"] = request_id
+    if lane is not None:
+        args["lane"] = lane
+    annotation = TraceAnnotation(f"dllama.{component}.{name}", **args)
+    annotation.__enter__()
+    return annotation
+
+
+def _write_events(sink, events: list[dict]) -> None:
+    sink.write(
+        "".join(json.dumps(ev, default=repr) + ",\n" for ev in events)
+    )
+
+
+class _ChromeEvents:
+    """Span records -> Chrome-trace events: one complete ("X") event per
+    span, pid = component, tid = lane (-1 = no lane), ts/dur in
+    microseconds since the tracker epoch, and the process/thread name
+    ("M") events the first time a pid or a (pid, tid) appears."""
+
+    def __init__(self, pid_prefix: str | None = None, pid_base: int = 0):
+        self._pid_prefix = pid_prefix
+        self._pid_base = pid_base
+        self._seen_pids: set[int] = set()
+        self._seen_tids: set[tuple[int, int]] = set()
+
+    def events(self, s: dict) -> list[dict]:
+        comp = s["component"]
+        pid = _COMPONENT_PIDS.get(comp)
+        if pid is None:
+            pid = _COMPONENT_PIDS.setdefault(
+                comp, max(_COMPONENT_PIDS.values()) + 1
+            )
+        pid += self._pid_base
+        tid = s["lane"] if s["lane"] is not None else -1
+        out = []
+        if pid not in self._seen_pids:
+            self._seen_pids.add(pid)
+            out.append({
+                "ph": "M", "pid": pid, "tid": 0,
+                "name": "process_name",
+                "args": {
+                    "name": f"{self._pid_prefix}/{comp}"
+                    if self._pid_prefix else comp
+                },
+            })
+        if (pid, tid) not in self._seen_tids:
+            self._seen_tids.add((pid, tid))
+            out.append({
+                "ph": "M", "pid": pid, "tid": tid,
+                "name": "thread_name",
+                "args": {"name": f"lane {tid}" if tid >= 0 else "no lane"},
+            })
+        args = {"request_id": s["request_id"], **(s.get("attrs") or {})}
+        if s.get("replica") is not None:
+            args["replica"] = s["replica"]
+        out.append({
+            "ph": "X",
+            "pid": pid,
+            "tid": tid,
+            "ts": round(s["t0"] * 1e6, 3),
+            "dur": round(s["dur_s"] * 1e6, 3),
+            "name": s["name"],
+            "args": args,
+        })
+        return out
 
 
 class SpanTracker:
@@ -95,7 +186,7 @@ class SpanTracker:
         self,
         capacity: int = DEFAULT_CAPACITY,
         enabled: bool | None = None,
-        clock: Callable[[], float] = time.perf_counter,
+        clock: Callable[[], float] = time.monotonic,
         wall_clock: Callable[[], float] = time.time,
         recorder: object | None = None,
     ) -> None:
@@ -106,17 +197,19 @@ class SpanTracker:
         )
         self.capacity = capacity
         self._clock = clock
-        self._epoch = clock()  # all span t0s are seconds since this anchor
+        # all span t0s are seconds since this anchor, on the recorder's
+        # clock: epoch_monotonic + t0 is a recorder event's `t`
+        self.epoch_monotonic = self._epoch = clock()
         self.epoch_unix = wall_clock()
         self._ring: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._recorder = recorder
         self._total = 0
         self._dropped = 0
-        # optional throttled file sink (--timeline-out on the server)
+        # optional append-only file sink (--timeline-out on the server)
         self._sink_path: str | None = None
-        self._sink_min_interval = 5.0
-        self._sink_last = 0.0
+        self._sink = None
+        self._sink_events: _ChromeEvents | None = None
 
     @property
     def recorder(self):
@@ -134,23 +227,35 @@ class SpanTracker:
 
     def begin(self, name: str, component: str = "engine",
               request_id: str | None = None, lane: int | None = None,
+              annotate: bool = True, at: float | None = None,
               **attrs) -> _SpanHandle | None:
         """Open a span; returns an opaque handle (or None when disabled —
-        ``end(None)`` no-ops, so call sites never branch)."""
+        ``end(None)`` no-ops, so call sites never branch).
+        ``annotate=False`` is for a span that ends on another thread, or
+        outlives the spans begun after it on its own. ``at`` is the
+        caller's own reading of the tracker's clock (the engine's
+        dispatch helper reads it once for all it feeds)."""
         if not self.enabled:
             return None
+        annotation = None
+        if annotate and TraceAnnotation is not None:
+            annotation = _annotate(name, component, request_id, lane, attrs)
         return _SpanHandle(
-            name, component, request_id, lane, self._clock(), attrs or None,
-            replica=get_thread_replica(),
+            name, component, request_id, lane,
+            self._clock() if at is None else at, attrs or None,
+            replica=get_thread_replica(), annotation=annotation,
         )
 
-    def end(self, handle: _SpanHandle | None, **attrs) -> None:
+    def end(self, handle: _SpanHandle | None, at: float | None = None,
+            **attrs) -> None:
         """Close a span and commit it to the ring; idempotent (a second
         end — e.g. an error path racing the normal one — no-ops)."""
         if handle is None or handle.done:
             return
         handle.done = True
-        t1 = self._clock()
+        t1 = self._clock() if at is None else at
+        if handle.annotation is not None:
+            handle.annotation.__exit__(None, None, None)
         if attrs:
             handle.attrs = {**(handle.attrs or {}), **attrs}
         rec = {
@@ -176,6 +281,11 @@ class SpanTracker:
                 overflowed = self._dropped % self.capacity == 1
             self._ring.append(rec)
             dropped = self._dropped
+            if self._sink is not None:
+                try:
+                    _write_events(self._sink, self._sink_events.events(rec))
+                except (ValueError, OSError) as e:
+                    self._sink_failed(e)
         if overflowed:
             self.recorder.record(
                 "obs_overflow", what="span_ring", capacity=self.capacity,
@@ -238,58 +348,14 @@ class SpanTracker:
         stitcher can merge N fragments without two replicas' identical
         component names/pids colliding in the viewer (ISSUE 19)."""
         spans = self.completed(request_id, replica)
-        events: list[dict] = []
-        seen_pids: dict[str, int] = {}
-        seen_tids: set[tuple[int, int]] = set()
-        for s in spans:
-            comp = s["component"]
-            pid = _COMPONENT_PIDS.get(comp)
-            if pid is None:
-                pid = _COMPONENT_PIDS.setdefault(
-                    comp, max(_COMPONENT_PIDS.values()) + 1
-                )
-            pid += pid_base
-            tid = s["lane"] if s["lane"] is not None else -1
-            if comp not in seen_pids:
-                seen_pids[comp] = pid
-                events.append({
-                    "ph": "M", "pid": pid, "tid": 0,
-                    "name": "process_name",
-                    "args": {
-                        "name": f"{pid_prefix}/{comp}" if pid_prefix
-                        else comp
-                    },
-                })
-            if (pid, tid) not in seen_tids:
-                seen_tids.add((pid, tid))
-                events.append({
-                    "ph": "M", "pid": pid, "tid": tid,
-                    "name": "thread_name",
-                    "args": {
-                        "name": f"lane {tid}" if tid >= 0 else "no lane"
-                    },
-                })
-            args = {
-                "request_id": s["request_id"],
-                **(s.get("attrs") or {}),
-            }
-            if s.get("replica") is not None:
-                args["replica"] = s["replica"]
-            ev = {
-                "ph": "X",
-                "pid": pid,
-                "tid": tid,
-                "ts": round(s["t0"] * 1e6, 3),
-                "dur": round(s["dur_s"] * 1e6, 3),
-                "name": s["name"],
-                "args": args,
-            }
-            events.append(ev)
+        convert = _ChromeEvents(pid_prefix, pid_base)
+        events = [ev for s in spans for ev in convert.events(s)]
         out = {
             "traceEvents": events,
             "displayTimeUnit": "ms",
             "dllama": {
                 "epoch_unix": self.epoch_unix,
+                "epoch_monotonic": self.epoch_monotonic,
                 "n_spans": len(spans),
                 "dropped": self.dropped,
             },
@@ -302,41 +368,63 @@ class SpanTracker:
         return out
 
     def export_file(self, path: str, request_id: str | None = None) -> int:
-        """Write the Chrome-trace JSON to ``path`` (``--timeline-out``);
-        returns the span count. Serialization failures fall back to
-        ``repr`` per value (same policy as the tracer sink)."""
+        """Write the ring's Chrome-trace JSON to ``path`` (the CLI's
+        ``--timeline-out``, at the end of its one run); returns the span
+        count. Serialization failures fall back to ``repr`` per value
+        (same policy as the tracer sink)."""
         trace = self.chrome_trace(request_id)
         with open(path, "w") as f:
             f.write(json.dumps(trace, default=repr))
         return trace["dllama"]["n_spans"]
 
-    def set_sink(self, path: str | None,
-                 min_interval_s: float = 5.0) -> None:
-        """Throttled auto-export: ``maybe_flush`` rewrites ``path`` at
-        most every ``min_interval_s`` (the server calls it per finished
-        request); ``flush`` writes unconditionally (server shutdown)."""
-        self._sink_path = path
-        self._sink_min_interval = min_interval_s
-        self._sink_last = 0.0
-
-    def maybe_flush(self) -> None:
-        if self._sink_path is None:
-            return
-        now = self._clock()
-        if now - self._sink_last < self._sink_min_interval:
-            return
-        self._sink_last = now
-        self.flush()
+    def set_sink(self, path: str | None) -> None:
+        """Stream to ``path`` (the server's ``--timeline-out``): every
+        span completed from now on is appended once, as Chrome-trace
+        events one per line in the JSON array form — ``[`` and the
+        metadata event first, every line ending in a comma, no closing
+        bracket (Perfetto and chrome://tracing load that as it stands;
+        :func:`read_timeline` reads it back). Buffered like any file, not
+        by line: a write is a system call that hands the interpreter lock
+        to another thread, and the scheduler thread ends a hundred spans
+        a second. ``None`` closes the sink, and writes out what the
+        buffer still holds."""
+        with self._lock:
+            if self._sink is not None:
+                try:
+                    self._sink.close()
+                except OSError as e:  # the last buffer did not reach the disk
+                    self._sink_failed(e)
+            self._sink, self._sink_path = None, path
+            if path is None:
+                return
+            self._sink = open(path, "w")
+            self._sink_events = _ChromeEvents()
+            self._sink.write("[")
+            _write_events(self._sink, [{
+                "ph": "M", "pid": 0, "tid": 0, "name": TIMELINE_EPOCH,
+                "args": {
+                    "epoch_unix": self.epoch_unix,
+                    "epoch_monotonic": self.epoch_monotonic,
+                },
+            }])
 
     def flush(self) -> None:
-        if self._sink_path is None:
-            return
-        try:
-            self.export_file(self._sink_path)
-        except OSError:
-            self.recorder.record(
-                "obs_sink_error", what="timeline", path=self._sink_path
-            )
+        """Write out what the sink's buffer holds (a drained server is
+        safe to kill)."""
+        with self._lock:
+            if self._sink is not None:
+                try:
+                    self._sink.flush()
+                except (ValueError, OSError) as e:
+                    self._sink_failed(e)
+
+    def _sink_failed(self, error: Exception) -> None:
+        """(Lock held.) Drop a closed or broken sink: the ring lives on,
+        and the failure is itself observable."""
+        path, self._sink = self._sink_path, None  # dlint: disable=guarded-attrs — every caller holds self._lock
+        self.recorder.record(
+            "obs_sink_error", what="timeline", path=path, error=str(error),
+        )
 
     # -- per-request millisecond accounting --------------------------------
 
@@ -397,3 +485,21 @@ def get_span_tracker() -> SpanTracker:
     """The process-wide default span tracker (shared by the engine, the
     lane scheduler, the KV manager and ``/v1/debug/timeline``)."""
     return _DEFAULT
+
+
+def read_timeline(path: str) -> tuple[dict, list[dict]]:
+    """Load a streamed ``--timeline-out`` file back: the metadata event's
+    ``args`` (``epoch_unix``, ``epoch_monotonic``) and the span ("X")
+    events, ``ts``/``dur`` in microseconds since that epoch. The file of
+    a server that still runs may end inside a line, which is left out."""
+    meta, spans = {}, []
+    with open(path) as f:
+        for line in f:
+            if not line.endswith(",\n"):
+                break
+            ev = json.loads(line.lstrip("[")[:-2])
+            if ev["ph"] == "X":
+                spans.append(ev)
+            elif ev["name"] == TIMELINE_EPOCH:
+                meta = ev["args"]
+    return meta, spans

@@ -36,7 +36,9 @@ def _dumps_safe(rec: dict) -> str:
 
 class RequestSpan:
     """One request's lifecycle; see module docstring. All ``*_s`` fields
-    are seconds on the monotonic clock, ``submitted_unix`` is wall time."""
+    are seconds on ``time.monotonic`` (the flight recorder's and the span
+    timeline's clock, on which ``submitted_monotonic`` places the record),
+    ``submitted_unix`` is wall time."""
 
     def __init__(self, tracer: "Tracer | None", request_id: str | None = None,
                  path: str = "lanes", trace_id: str | None = None) -> None:
@@ -49,7 +51,7 @@ class RequestSpan:
         self.trace_id = trace_id
         self.path = path
         self.submitted_unix = time.time()
-        self.t_submit = time.perf_counter()
+        self.t_submit = time.monotonic()
         self.lane: int | None = None
         self.queue_wait_s: float | None = None
         self.prefill_s: float | None = None
@@ -67,7 +69,7 @@ class RequestSpan:
                       reused_prefix_tokens: int = 0) -> float:
         """Request left the queue (lane assigned / lock acquired); returns
         the queue wait in seconds."""
-        self.queue_wait_s = time.perf_counter() - self.t_submit
+        self.queue_wait_s = time.monotonic() - self.t_submit
         self.lane = lane
         self.reused_prefix_tokens = reused_prefix_tokens
         return self.queue_wait_s
@@ -91,7 +93,7 @@ class RequestSpan:
         TTFT histogram, so the None contract keeps that single-shot)."""
         if self.ttft_s is not None:
             return None
-        self.ttft_s = time.perf_counter() - self.t_submit
+        self.ttft_s = time.monotonic() - self.t_submit
         return self.ttft_s
 
     def finish(self, reason: str, n_prompt: int | None = None,
@@ -102,7 +104,7 @@ class RequestSpan:
         self._finished = True
         self.set_tokens(n_prompt, n_completion)
         self.finish_reason = reason
-        self.total_s = time.perf_counter() - self.t_submit
+        self.total_s = time.monotonic() - self.t_submit
         rec = self.to_record()
         if self.tracer is not None:
             self.tracer.record(rec)
@@ -124,6 +126,7 @@ class RequestSpan:
             "trace_id": self.trace_id,
             "path": self.path,
             "submitted_unix": round(self.submitted_unix, 6),
+            "submitted_monotonic": self.t_submit,
             "lane": self.lane,
             "queue_wait_s": self.queue_wait_s,
             "prefill_s": self.prefill_s,

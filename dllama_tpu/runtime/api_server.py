@@ -540,7 +540,8 @@ class LaneScheduler:
         # scheduler thread once admission work (tokenize + radix match)
         # is done — so timeline "queue" covers wait AND admission setup
         job.queue_span = self.state.spans.begin(
-            "queue", component="scheduler", request_id=job.span.request_id
+            "queue", component="scheduler", request_id=job.span.request_id,
+            annotate=False,  # ends on the scheduler thread
         )
         with self.cv:
             self.pending.append(job)
@@ -782,7 +783,10 @@ class LaneScheduler:
                     # turns scheduler-stalled after the timeout
                     if self.state.watchdog is not None:
                         self.state.watchdog.beat()
-                    self.cv.wait()
+                    with self.state.spans.span(
+                        "sched_wait", component="scheduler"
+                    ):
+                        self.cv.wait()
                 if self._stop:
                     return
                 admissions = []
@@ -834,23 +838,42 @@ class LaneScheduler:
                     n_active=sum(1 for ls in self.lanes if ls is not None),
                     n_admitting=len(self.admitting),
                 )
-            tick_sp = self.state.spans.begin(
+            # mono_ns: this thread's host clock at the tick's begin. In a
+            # profiler trace the annotation's own start less mono_ns is
+            # the offset between the two clocks, read once per tick, so a
+            # reader places recorder events and --trace-out records on
+            # the device's axis and can check the offset for drift
+            spans = self.state.spans
+            tick_sp = spans.begin(
                 "sched_tick", component="scheduler",
                 n_pending=n_pending, n_admitting=len(self.admitting),
+                mono_ns=time.monotonic_ns(),
             )
             for lane, job in admissions:
-                self._begin_admission(lane, job)
+                with spans.span(
+                    "begin_admission", component="scheduler",
+                    request_id=job.span.request_id, lane=lane,
+                ):
+                    self._begin_admission(lane, job)
             # oversubscription (PR 16): requests queued while every lane
             # is busy and --max-streams allows more concurrency — park
             # the most-progressed lane (publish + drop page list); it
             # frees this tick and the queued request admits next tick
-            self._maybe_park(n_pending)
+            t_evict = time.monotonic()
+            parked = self._maybe_park(n_pending)
             # deadline preemption (ISSUE 20): park an over-budget /
             # deadline-blown lower-priority stream when that flips a
             # feasible hinted request from "blows its budget waiting"
             # to "meets SLO" — reuses the PR 16 park/resume contract,
             # so the victim's stream stays byte-identical on resume
-            self._maybe_preempt(n_pending)
+            if self._maybe_preempt(n_pending) or parked:
+                # one span for both, and only when a stream was parked
+                # (whose `park` span is inside): begun after the fact,
+                # so a tick that evicts nothing pays two clock reads
+                spans.end(spans.begin(
+                    "park_preempt", component="scheduler",
+                    annotate=False, at=t_evict,
+                ))
             # stall-free admission: at most ONE bounded prefill chunk per
             # tick, then a decode block for every active lane — the worst
             # case inter-token gap is one chunk + one block, and two
@@ -911,7 +934,7 @@ class LaneScheduler:
 
     # -- oversubscription: park / resume (PR 16) ---------------------------
 
-    def _maybe_park(self, n_pending: int) -> None:
+    def _maybe_park(self, n_pending: int) -> bool:
         """Park ONE active lane when requests wait, no lane is free, and
         the stream cap (--max-streams > lanes) says the queue pressure
         is oversubscription, not overload. The victim is the lane that
@@ -919,19 +942,19 @@ class LaneScheduler:
         must have at least one full block of progress — so a deep queue
         rotates lanes round-robin instead of thrashing park/resume.
         ``n_pending`` is the tick's queue-depth snapshot (taken under
-        the cv in _loop)."""
+        the cv in _loop). Returns whether a stream was parked."""
         if (
             self.max_streams <= len(self.lanes)
             or self.kv is None
             or n_pending <= 0
             or self.admitting
         ):
-            return
+            return False
         if any(
             self.lanes[i] is None and i not in self.admitting
             for i in range(len(self.lanes))
         ):
-            return
+            return False
         victim, best = -1, self.block_size - 1
         for lane, ls in enumerate(self.lanes):
             if ls is None or ls.job.cancelled:
@@ -940,8 +963,9 @@ class LaneScheduler:
                 victim, best = lane, self._progress[lane]
         if victim >= 0:
             self._park_stream(victim)
+        return victim >= 0
 
-    def _maybe_preempt(self, n_pending: int) -> None:
+    def _maybe_preempt(self, n_pending: int) -> bool:
         """Deadline preemption (ISSUE 20, predictive mode only): when
         the EDF head is a HINTED request that blows its budget if it
         waits for natural lane turnover, but would meet it on a lane
@@ -951,7 +975,7 @@ class LaneScheduler:
         after the deadline traffic — paused, never restarted, its
         token stream byte-identical. Preemption never fires when the
         head is infeasible either way: burning a victim cannot save
-        it."""
+        it. Returns whether a stream was preempted."""
         st = self.state
         if (
             not st.admission_predict
@@ -960,12 +984,12 @@ class LaneScheduler:
             or n_pending <= 0
             or self.admitting
         ):
-            return
+            return False
         if any(
             self.lanes[i] is None and i not in self.admitting
             for i in range(len(self.lanes))
         ):
-            return
+            return False
         head, head_key = None, None
         with self.cv:
             pending = list(self.pending)
@@ -976,18 +1000,18 @@ class LaneScheduler:
             if head_key is None or key < head_key:
                 head, head_key = j, key
         if head is None or not head.params.deadline_hinted:
-            return
+            return False
         now_ms = self._clock() * 1000.0
         remaining_ms = head_key - now_ms
         if remaining_ms <= 0:
-            return
+            return False
         n_tok = head.n_prompt_tokens or st.estimate_prompt_tokens(
             head.params
         )
         occ = self.occupancy()
         wait_pred = st.predictor.predict(n_tok, occ)
         if wait_pred.ttft_ms <= remaining_ms:
-            return  # feasible by waiting — no victim needed
+            return False  # feasible by waiting — no victim needed
         # forecast against a freed lane: zero queue wait, admission
         # starts next tick
         occ_freed = self.occupancy()
@@ -995,7 +1019,7 @@ class LaneScheduler:
         occ_freed.active_lanes = max(0, occ_freed.active_lanes - 1)
         now_pred = st.predictor.predict(n_tok, occ_freed)
         if now_pred.ttft_ms > remaining_ms:
-            return  # infeasible either way
+            return False  # infeasible either way
         prio_rank = {"low": 0, "normal": 1, "high": 2}
         head_rank = prio_rank.get(head.params.priority, 1)
         victim, v_score, v_blown = -1, None, False
@@ -1016,7 +1040,7 @@ class LaneScheduler:
             if v_score is None or score < v_score:
                 victim, v_score, v_blown = lane, score, blown
         if victim < 0:
-            return
+            return False
         reason = "deadline_blown" if v_blown else "priority"
         rid = self.lanes[victim].job.span.request_id
         self._park_stream(victim)
@@ -1028,6 +1052,7 @@ class LaneScheduler:
             head_remaining_ms=round(remaining_ms, 3),
             predicted_ttft_ms=round(now_pred.ttft_ms, 3),
         )
+        return True
 
     def _park_stream(self, lane: int) -> None:
         """Evict an active stream from its lane to make room for a
@@ -1060,7 +1085,8 @@ class LaneScheduler:
         # parked = queue-visible again: a fresh queue span covers the
         # parked wait so the timeline shows where the stream's time went
         ls.job.queue_span = st.spans.begin(
-            "queue", component="scheduler", request_id=rid, parked=True
+            "queue", component="scheduler", request_id=rid, parked=True,
+            annotate=False,  # outlives this tick
         )
         self._n_parked += 1
         st.m_streams_parked.set(self._n_parked)
@@ -1326,7 +1352,11 @@ class LaneScheduler:
             if adm.cursor >= len(fills) and (
                 adm.adopted or not adopt_needed
             ):
-                self._finish_admission(lane, adm)
+                with self.state.spans.span(
+                    "finish_admission", component="scheduler",
+                    request_id=rid, lane=lane,
+                ):
+                    self._finish_admission(lane, adm)
         except Exception as e:
             self.state.recorder.record(
                 "admission_error", lane=lane, error=str(e),
@@ -1427,6 +1457,7 @@ class LaneScheduler:
                 "decode", component="scheduler",
                 request_id=job.span.request_id, lane=lane,
                 n_prompt=len(adm.tokens),
+                annotate=False,  # a stream's life, not what a thread does
             ),
         )
         del self.admitting[lane]
@@ -1475,6 +1506,15 @@ class LaneScheduler:
     def _finish(self, lane: int, reason: str) -> None:
         ls = self.lanes[lane]
         rid = ls.job.span.request_id
+        with self.state.spans.span(
+            "finish", component="scheduler", request_id=rid, lane=lane,
+            reason=reason,
+        ):
+            self._finish_stream(lane, ls, rid, reason)
+
+    def _finish_stream(
+        self, lane: int, ls: "_LaneState", rid: str, reason: str
+    ) -> None:
         self.state.spans.end(
             ls.decode_span, reason=reason,
             n_completion=ls.job.n_completion,
@@ -1505,7 +1545,6 @@ class LaneScheduler:
             ls.job.span, deadline_ms=ls.job.params.deadline_ms
         )
         self._score_prediction(ls.job, reason)
-        self.state.spans.maybe_flush()
         ls.job.events.put(("done", reason))
         self.state.recorder.record(
             "finish", lane=lane, reason=reason, pos=ls.pos,
@@ -1800,7 +1839,8 @@ class LaneScheduler:
             st.spans.end(sp)
         self._last_decode_end = self._clock()
         dt = time.perf_counter() - t0
-        n_emitted = 0
+        n_emitted = n_finished = 0
+        emit_sp = st.spans.begin("emit", component="scheduler")
         for lane, d in drafts.items():
             out = grid[lane]
             # out[0] is the greedy token after the pending one (what a
@@ -1835,7 +1875,9 @@ class LaneScheduler:
             st.m_tpot.observe(dt / len(emitted))
             for tok in emitted:
                 if not self._consume_token(lane, tok):
+                    n_finished += 1
                     break
+        st.spans.end(emit_sp, n_tokens=n_emitted, n_finished=n_finished)
         st.slo.note_tokens(n_emitted)
         if st.m_spec_drafted.value > 0:
             st.g_spec_rate.set(
@@ -1852,42 +1894,56 @@ class LaneScheduler:
 
     def _step_block(self) -> None:
         b = len(self.lanes)
-        # free lanes whose client went away before paying for more decode
-        for lane in range(b):
-            ls = self.lanes[lane]
-            if ls is not None and ls.job.cancelled:
-                self._finish(lane, "cancelled")
-        if not any(ls is not None for ls in self.lanes):
-            return
-        # speculative verify first: greedy lanes whose drafter proposes a
-        # continuation take ONE batched verify dispatch; everyone else —
-        # temperature>0 lanes, greedy lanes with nothing to propose —
-        # shares the normal decode block in the same tick, so mixed
-        # batches fall back transparently per lane, not per server
-        verified: set[int] = set()
-        if self.spec_on and self.drafters:
-            drafts = self._spec_drafts()
-            if drafts:
-                self._spec_verify(drafts)
-                verified = set(drafts)
-        active = [
-            ls is not None and lane not in verified
-            for lane, ls in enumerate(self.lanes)
-        ]
-        if not any(active):
-            return
-        tokens = [ls.token if ls else 0 for ls in self.lanes]
-        pos = [ls.pos if ls else 0 for ls in self.lanes]
-        temps = [ls.temperature if ls else 0.0 for ls in self.lanes]
-        topps = [ls.top_p if ls else 1.0 for ls in self.lanes]
-        seeds = [ls.seed if ls else None for ls in self.lanes]
-        # decode stall: the gap since the previous decode-block dispatch
-        # finished, while >=1 lane was active the whole time — whatever sat
-        # in between (admission chunks, host work) is latency a streaming
-        # client ate. Chunked admission bounds it by one chunk + one block.
-        now = self._clock()
-        if self._last_decode_end is not None:
-            self.state.m_decode_stall.observe(now - self._last_decode_end)
+        spans = self.state.spans
+        # step_prep: the cancel sweep, speculative drafts and list
+        # building — the scheduler's own host work before a dispatch
+        prep_sp = spans.begin("step_prep", component="scheduler")
+        try:
+            # free lanes whose client went away before paying for more
+            # decode
+            for lane in range(b):
+                ls = self.lanes[lane]
+                if ls is not None and ls.job.cancelled:
+                    self._finish(lane, "cancelled")
+            if not any(ls is not None for ls in self.lanes):
+                return
+            # speculative verify first: greedy lanes whose drafter
+            # proposes a continuation take ONE batched verify dispatch;
+            # everyone else — temperature>0 lanes, greedy lanes with
+            # nothing to propose — shares the normal decode block in the
+            # same tick, so mixed batches fall back transparently per
+            # lane, not per server
+            verified: set[int] = set()
+            if self.spec_on and self.drafters:
+                drafts = self._spec_drafts()
+                if drafts:
+                    spans.end(prep_sp)
+                    self._spec_verify(drafts)
+                    verified = set(drafts)
+                    prep_sp = spans.begin("step_prep", component="scheduler")
+            active = [
+                ls is not None and lane not in verified
+                for lane, ls in enumerate(self.lanes)
+            ]
+            if not any(active):
+                return
+            tokens = [ls.token if ls else 0 for ls in self.lanes]
+            pos = [ls.pos if ls else 0 for ls in self.lanes]
+            temps = [ls.temperature if ls else 0.0 for ls in self.lanes]
+            topps = [ls.top_p if ls else 1.0 for ls in self.lanes]
+            seeds = [ls.seed if ls else None for ls in self.lanes]
+            # decode stall: the gap since the previous decode-block
+            # dispatch finished, while >=1 lane was active the whole time
+            # — whatever sat in between (admission chunks, host work) is
+            # latency a streaming client ate. Chunked admission bounds it
+            # by one chunk + one block.
+            now = self._clock()
+            if self._last_decode_end is not None:
+                self.state.m_decode_stall.observe(
+                    now - self._last_decode_end
+                )
+        finally:
+            spans.end(prep_sp)
         t0 = time.perf_counter()
         wd = self.state.watchdog
         if wd is not None:
@@ -1919,12 +1975,21 @@ class LaneScheduler:
                 if self.lanes[lane] is not None and active[lane]:
                     self._finish(lane, "length")
             return
-        for row in rows:
-            for lane in range(b):
-                if self.lanes[lane] is None or not active[lane]:
-                    continue
-                if not self._consume_token(lane, row[lane]):
-                    active[lane] = False
+        # emit: one span for the block's whole row-by-lane token loop
+        # (detokenise, stop checks, events.put, perhaps _finish)
+        n_tokens = n_finished = 0
+        emit_sp = spans.begin("emit", component="scheduler")
+        try:
+            for row in rows:
+                for lane in range(b):
+                    if self.lanes[lane] is None or not active[lane]:
+                        continue
+                    n_tokens += 1
+                    if not self._consume_token(lane, row[lane]):
+                        active[lane] = False
+                        n_finished += 1
+        finally:
+            spans.end(emit_sp, n_tokens=n_tokens, n_finished=n_finished)
 
 
 class ApiState:
@@ -2775,7 +2840,6 @@ class ApiState:
         ) is not None:
             self.m_finished.labels(reason=reason).inc()
             self.slo.observe_span(span)
-            self.spans.maybe_flush()
         return _completion_response(
             self,
             buffer,
@@ -3586,8 +3650,7 @@ def serve(
         # a crashed scheduler loop / engine step dumps the event ring here
         state.recorder.postmortem_dir = postmortem_dir
     if timeline_out:
-        # throttled Chrome-trace export per finished request, plus an
-        # unconditional flush when the server is closed
+        # every completed span is appended once, as it completes
         state.spans.set_sink(timeline_out)
     server = ThreadingHTTPServer((host, port), make_handler(state))
     server.state = state  # tests and callers reach the tracer/registry here
@@ -3603,7 +3666,7 @@ def serve(
         # leaks a thread mutating the shared registry
         state.sampler.stop()
         if timeline_out:
-            state.spans.flush()
+            state.spans.set_sink(None)
 
     server.server_close = _close_and_flush
     if host in ("0.0.0.0", "127.0.0.1"):

@@ -90,6 +90,7 @@ def _topp_mask(probs, topp):
     return jnp.where(topp_valid, masked, probs)
 
 
+@jax.named_scope("sample")
 def _sample_on_device(logits, temperature, topp, key):
     """Temperature + top-p sampling on device, [B, V] f32 -> [B] int32;
     `temperature`/`topp` may be per-lane [B] vectors, and lanes with
@@ -115,6 +116,7 @@ def _sample_on_device(logits, temperature, topp, key):
     return jnp.where(temp_col[:, 0] <= 0.0, greedy, sampled)
 
 
+@jax.named_scope("sample")
 def _sample_per_lane(logits, temperature, topp, seeds, positions):
     """Per-LANE seeded sampling: lane l's key derives from (seeds[l],
     positions[l]) only, so a seeded request's draws are reproducible
@@ -572,6 +574,49 @@ class InferenceEngine:
                 raise rebuild_err from e
             raise
 
+    @contextlib.contextmanager
+    def _dispatch(self, step: str, prep=None, **fields):
+        """Time one dispatch of a compiled program, once, for everything
+        that wants it: the recorder's ``step_dispatch``/``step_complete``
+        pair (``ms`` on the latter), the ``engine`` span named ``step``
+        and the step histogram all get the same two clock readings, and
+        the yielded dict gets ``seconds``. ``prep`` is the caller's open
+        ``dispatch_prep`` span, which ends where the dispatch begins; the
+        read-back wait inside is ``_read_back``'s. A dispatch that raises
+        ends its span and completes nothing."""
+        self._spans.end(prep)
+        self.recorder.record("step_dispatch", step=step, **fields)
+        timed = {}
+        t0 = time.monotonic()
+        sp = self._spans.begin(step, component="engine", at=t0, **fields)
+        try:
+            yield timed
+        except BaseException:
+            self._spans.end(sp, error=True)
+            raise
+        t1 = time.monotonic()
+        self._spans.end(sp, at=t1)
+        timed["seconds"] = t1 - t0
+        self._m_step.labels(kind=step).observe(t1 - t0)
+        self.recorder.record(
+            "step_complete", step=step, **fields,
+            ms=round((t1 - t0) * 1000, 3),
+        )
+
+    def _dispatch_prep(self, step: str):
+        """Open the span of the host work before a lane dispatch: window
+        choice, prefetch, seed vector and the small ``jnp.asarray``
+        launches. ``_dispatch(prep=)`` ends it."""
+        return self._spans.begin("dispatch_prep", component="engine", step=step)
+
+    def _read_back(self, step: str, out) -> np.ndarray:
+        """The device-complete wait: the program call returned as soon as
+        it was enqueued, and the read-back waits for the device. Its own
+        ``<step>.device`` span, so a timeline splits dispatch overhead
+        from device time."""
+        with self._spans.span(f"{step}.device", component="engine"):
+            return np.asarray(out)
+
     def _fault(self, op: str):
         """Chaos hook (runtime/faults.py): the armed fault for this
         dispatch, if any. Callers raise a TRANSIENT fault BEFORE their
@@ -869,16 +914,9 @@ class InferenceEngine:
         rng = jax.random.fold_in(
             jax.random.fold_in(self._base_key, pos), self._rng_calls
         )
-        self.recorder.record(
-            "step_dispatch", step="decode_block", pos=pos,
-            n_steps=n_steps, window=window,
-        )
-        sp = self._spans.begin(
-            "decode_block", component="engine", n_steps=n_steps,
-            pos=pos, window=window,
-        )
-        t0 = time.perf_counter()
-        with self._cache_guard():
+        with self._dispatch(
+            "decode_block", pos=pos, n_steps=n_steps, window=window
+        ) as timed, self._cache_guard():
             out, self.cache = block(
                 self.params,
                 arr,
@@ -888,21 +926,8 @@ class InferenceEngine:
                 jnp.float32(max(self.temperature, 1e-6)),
                 jnp.float32(self.sampler.topp),
             )
-            # dispatch returned (async); the readback below waits for the
-            # device — the ".device" sub-span is that wait
-            sp_dev = self._spans.begin(
-                "decode_block.device", component="engine"
-            )
-            out = np.asarray(out)  # [n_steps, lanes]
-            self._spans.end(sp_dev)
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="decode_block").observe(dt)
-        self._m_tpot.observe(dt / n_steps)
-        self.recorder.record(
-            "step_complete", step="decode_block", pos=pos,
-            n_steps=n_steps, window=window, ms=round(dt * 1000, 3),
-        )
+            out = self._read_back("decode_block", out)  # [n_steps, lanes]
+        self._m_tpot.observe(timed["seconds"] / n_steps)
         if per_lane:
             return [[int(t) for t in row] for row in out]
         return [int(t) for t in out[:, 0]]
@@ -1253,6 +1278,7 @@ class InferenceEngine:
                 f"{n} fill tokens at pos {pos0} exceed "
                 f"seqLen {self.header.seq_len}"
             )
+        prep = self._dispatch_prep("prefill_lane_chunk")
         fault = self._fault("prefill_lane_chunk")
         if fault is not None and not fault.poison:
             raise fault
@@ -1273,39 +1299,27 @@ class InferenceEngine:
             if native
             else self._lane_prefill_fn(bucket, window=window)
         )
-        self.recorder.record(
-            "step_dispatch", step="prefill_lane_chunk", lane=lane, pos=pos0,
-            n_tokens=width, bucket=bucket, window=window,
-        )
-        sp = self._spans.begin(
-            "prefill_lane_chunk", component="engine", lane=lane,
-            pos=pos0, n_tokens=width, bucket=bucket,
-        )
-        t0 = time.perf_counter()
         arr = jax.device_put(
             jnp.asarray(rows, jnp.int32), self._token_sharding
         )
         pos_arr = jnp.asarray(posv, jnp.int32)
-        if native:
-            with self._kv_pool_guard():
-                if fault is not None:
-                    raise fault
-                self.kv_pool = step(
-                    self.params, arr, self.kv_pool,
-                    jnp.asarray(self._page_table), pos_arr,
-                )
-        else:
-            with self._cache_guard():
-                if fault is not None:
-                    raise fault
-                self.cache = step(self.params, arr, self.cache, pos_arr)
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="prefill_lane_chunk").observe(dt)
-        self.recorder.record(
-            "step_complete", step="prefill_lane_chunk", lane=lane, pos=pos0,
-            n_tokens=width, ms=round(dt * 1000, 3),
-        )
+        table = jnp.asarray(self._page_table) if native else None
+        with self._dispatch(
+            "prefill_lane_chunk", prep, lane=lane, pos=pos0,
+            n_tokens=width, bucket=bucket, window=window,
+        ):
+            if native:
+                with self._kv_pool_guard():
+                    if fault is not None:
+                        raise fault
+                    self.kv_pool = step(
+                        self.params, arr, self.kv_pool, table, pos_arr
+                    )
+            else:
+                with self._cache_guard():
+                    if fault is not None:
+                        raise fault
+                    self.cache = step(self.params, arr, self.cache, pos_arr)
         return width
 
     def prefill_lane(self, lane: int, tokens: list[int], pos0: int = 0) -> None:
@@ -1629,31 +1643,18 @@ class InferenceEngine:
         fault = self._fault("kv_adopt")
         if fault is not None and not fault.poison:
             raise fault
-        self.recorder.record(
-            "step_dispatch", step="kv_adopt", lane=lane, n_pages=n
-        )
-        sp = self._spans.begin(
-            "kv_adopt", component="engine", lane=lane, n_pages=n
-        )
-        t0 = time.perf_counter()
-        for start, bucket in self._kv_copy_chunks(n):
-            fn = self._kv_copy_fn("adopt", bucket)
-            ids = jnp.asarray(page_ids[start : start + bucket], jnp.int32)
-            with self._cache_guard():
-                if fault is not None:
-                    raise fault
-                self.cache = fn(
-                    self.cache, self.kv_pool,
-                    jnp.int32(lane), jnp.int32(start), ids,
-                )
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="kv_adopt").observe(dt)
+        with self._dispatch("kv_adopt", lane=lane, n_pages=n):
+            for start, bucket in self._kv_copy_chunks(n):
+                fn = self._kv_copy_fn("adopt", bucket)
+                ids = jnp.asarray(page_ids[start : start + bucket], jnp.int32)
+                with self._cache_guard():
+                    if fault is not None:
+                        raise fault
+                    self.cache = fn(
+                        self.cache, self.kv_pool,
+                        jnp.int32(lane), jnp.int32(start), ids,
+                    )
         self._m_kv_copy_bytes.inc(n * self._kv_page_bytes())
-        self.recorder.record(
-            "step_complete", step="kv_adopt", lane=lane, n_pages=n,
-            ms=round(dt * 1000, 3),
-        )
 
     def kv_publish(
         self, lane: int, page_ids: list[int], start_page: int
@@ -1677,33 +1678,20 @@ class InferenceEngine:
         fault = self._fault("kv_publish")
         if fault is not None and not fault.poison:
             raise fault
-        self.recorder.record(
-            "step_dispatch", step="kv_publish", lane=lane, n_pages=n,
-            start_page=start_page,
-        )
-        sp = self._spans.begin(
-            "kv_publish", component="engine", lane=lane, n_pages=n,
-            start_page=start_page,
-        )
-        t0 = time.perf_counter()
-        for off, bucket in self._kv_copy_chunks(n):
-            fn = self._kv_copy_fn("publish", bucket)
-            ids = jnp.asarray(page_ids[off : off + bucket], jnp.int32)
-            with self._kv_pool_guard():
-                if fault is not None:
-                    raise fault
-                self.kv_pool = fn(
-                    self.cache, self.kv_pool,
-                    jnp.int32(lane), jnp.int32(start_page + off), ids,
-                )
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="kv_publish").observe(dt)
+        with self._dispatch(
+            "kv_publish", lane=lane, n_pages=n, start_page=start_page
+        ):
+            for off, bucket in self._kv_copy_chunks(n):
+                fn = self._kv_copy_fn("publish", bucket)
+                ids = jnp.asarray(page_ids[off : off + bucket], jnp.int32)
+                with self._kv_pool_guard():
+                    if fault is not None:
+                        raise fault
+                    self.kv_pool = fn(
+                        self.cache, self.kv_pool,
+                        jnp.int32(lane), jnp.int32(start_page + off), ids,
+                    )
         self._m_kv_copy_bytes.inc(n * self._kv_page_bytes())
-        self.recorder.record(
-            "step_complete", step="kv_publish", lane=lane, n_pages=n,
-            ms=round(dt * 1000, 3),
-        )
 
     # -- pool-native paged programs (ISSUE 16) -------------------------------
     #
@@ -1772,29 +1760,16 @@ class InferenceEngine:
         fault = self._fault("kv_page_copy")
         if fault is not None and not fault.poison:
             raise fault
-        self.recorder.record(
-            "step_dispatch", step="kv_page_copy", n_pages=n
-        )
-        sp = self._spans.begin(
-            "kv_page_copy", component="engine", n_pages=n
-        )
-        t0 = time.perf_counter()
-        for start, bucket in self._kv_copy_chunks(n):
-            fn = self._kv_page_copy_fn(bucket)
-            src = jnp.asarray(src_ids[start : start + bucket], jnp.int32)
-            dst = jnp.asarray(dst_ids[start : start + bucket], jnp.int32)
-            with self._kv_pool_guard():
-                if fault is not None:
-                    raise fault
-                self.kv_pool = fn(self.kv_pool, src, dst)
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="kv_page_copy").observe(dt)
+        with self._dispatch("kv_page_copy", n_pages=n):
+            for start, bucket in self._kv_copy_chunks(n):
+                fn = self._kv_page_copy_fn(bucket)
+                src = jnp.asarray(src_ids[start : start + bucket], jnp.int32)
+                dst = jnp.asarray(dst_ids[start : start + bucket], jnp.int32)
+                with self._kv_pool_guard():
+                    if fault is not None:
+                        raise fault
+                    self.kv_pool = fn(self.kv_pool, src, dst)
         self._m_kv_copy_bytes.inc(n * self._kv_page_bytes())
-        self.recorder.record(
-            "step_complete", step="kv_page_copy", n_pages=n,
-            ms=round(dt * 1000, 3),
-        )
 
     def _paged_gather(self, pool, pt, window: int, tail: int):
         """Contiguous per-lane KV view of the first `window` rows plus a
@@ -2205,6 +2180,7 @@ class InferenceEngine:
         )
         if n_steps <= 0:
             return []
+        prep = self._dispatch_prep("decode_lanes")
         if temperature is None:
             temperature = [self.temperature] * self.batch_size
         if topp is None:
@@ -2258,60 +2234,32 @@ class InferenceEngine:
         fault = self._fault("decode_lanes")
         if fault is not None and not fault.poison:
             raise fault
-        self.recorder.record(
-            "step_dispatch", step="decode_lanes", pos=deepest,
-            n_steps=n_steps, window=window, n_live=len(live),
+        sampling = (
+            jnp.asarray(seed_vec, jnp.int32),
+            jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(topp, jnp.float32),
         )
-        sp = self._spans.begin(
-            "decode_lanes", component="engine", n_steps=n_steps,
-            pos=deepest, n_live=len(live), window=window,
-        )
-        t0 = time.perf_counter()
+        table = jnp.asarray(self._page_table) if native else None
         guard = self._kv_pool_guard if native else self._cache_guard
-        with guard():
+        with self._dispatch(
+            "decode_lanes", prep, pos=deepest, n_steps=n_steps,
+            window=window, n_live=len(live),
+        ) as timed, guard():
             if fault is not None:
                 raise fault
             if native:
                 out, self.kv_pool = block(
-                    self.params,
-                    arr,
-                    self.kv_pool,
-                    jnp.asarray(self._page_table),
-                    pos_arr,
-                    act_arr,
-                    jnp.asarray(seed_vec, jnp.int32),
-                    jnp.asarray(temperature, jnp.float32),
-                    jnp.asarray(topp, jnp.float32),
+                    self.params, arr, self.kv_pool, table,
+                    pos_arr, act_arr, *sampling,
                 )
             else:
                 out, self.cache = block(
-                    self.params,
-                    arr,
-                    self.cache,
-                    pos_arr,
-                    act_arr,
-                    jnp.asarray(seed_vec, jnp.int32),
-                    jnp.asarray(temperature, jnp.float32),
-                    jnp.asarray(topp, jnp.float32),
+                    self.params, arr, self.cache,
+                    pos_arr, act_arr, *sampling,
                 )
-            # the call above returned as soon as the program was enqueued;
-            # the readback is the device-complete wait — split it out so a
-            # timeline shows dispatch overhead vs device time
-            sp_dev = self._spans.begin(
-                "decode_lanes.device", component="engine"
-            )
-            out_np = np.asarray(out)
-            self._spans.end(sp_dev)
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="decode_lanes").observe(dt)
+            out_np = self._read_back("decode_lanes", out)
         # each active stream advances one token per block row
-        self._m_tpot.observe(dt / n_steps)
-        self.recorder.record(
-            "step_complete", step="decode_lanes", pos=deepest,
-            n_steps=n_steps, window=window, n_live=len(live),
-            ms=round(dt * 1000, 3),
-        )
+        self._m_tpot.observe(timed["seconds"] / n_steps)
         return [[int(t) for t in row] for row in out_np]
 
     def _lane_verify_arg_specs(self, t: int):
@@ -2439,6 +2387,7 @@ class InferenceEngine:
                     f"lane {i}: verify row at pos {pos[i]} width {t} "
                     f"exceeds seqLen {self.header.seq_len}"
                 )
+        prep = self._dispatch_prep("verify_lanes")
         deepest = max(pos[i] for i in live)
         window = self._attn_window(deepest + t)
         self._note_window(window)
@@ -2477,41 +2426,23 @@ class InferenceEngine:
         fault = self._fault("verify_lanes")
         if fault is not None and not fault.poison:
             raise fault
-        self.recorder.record(
-            "step_dispatch", step="verify_lanes", pos=deepest,
-            t=t, window=window, n_live=len(live),
-        )
-        sp = self._spans.begin(
-            "verify_lanes", component="engine", t=t,
-            pos=deepest, n_live=len(live), window=window,
-        )
-        t0 = time.perf_counter()
+        table = jnp.asarray(self._page_table) if native else None
         guard = self._kv_pool_guard if native else self._cache_guard
-        with guard():
+        with self._dispatch(
+            "verify_lanes", prep, pos=deepest, t=t, window=window,
+            n_live=len(live),
+        ), guard():
             if fault is not None:
                 raise fault
             if native:
                 out, self.kv_pool = vstep(
-                    self.params, arr, self.kv_pool,
-                    jnp.asarray(self._page_table), pos_arr, act_arr,
+                    self.params, arr, self.kv_pool, table, pos_arr, act_arr
                 )
             else:
                 out, self.cache = vstep(
                     self.params, arr, self.cache, pos_arr, act_arr
                 )
-            sp_dev = self._spans.begin(
-                "verify_lanes.device", component="engine"
-            )
-            out_np = np.asarray(out)
-            self._spans.end(sp_dev)
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="verify_lanes").observe(dt)
-        self.recorder.record(
-            "step_complete", step="verify_lanes", pos=deepest,
-            t=t, window=window, n_live=len(live),
-            ms=round(dt * 1000, 3),
-        )
+            out_np = self._read_back("verify_lanes", out)
         return [[int(x) for x in row] for row in out_np]
 
     # -- resident draft model (second-generation speculation) ----------------
@@ -2866,30 +2797,19 @@ class InferenceEngine:
             jnp.asarray([[t] for t in tokens], jnp.int32),
             self._token_sharding,
         )
-        self.recorder.record(
-            "step_dispatch", step="draft_step", n_steps=k,
-            n_live=len(live),
-        )
-        sp = self._spans.begin(
-            "draft_step", component="engine", n_steps=k, n_live=len(live),
-        )
-        t0 = time.perf_counter()
-        with self._draft_cache_guard():
+        with self._dispatch(
+            "draft_step", n_steps=k, n_live=len(live)
+        ) as timed, self._draft_cache_guard():
             out, self.draft_cache = block(
                 self._draft_params, arr, self.draft_cache,
                 jnp.asarray(pos, jnp.int32),
                 jnp.asarray(active, jnp.bool_),
             )
             out_np = np.asarray(out)
-        dt = time.perf_counter() - t0
-        self._spans.end(sp)
-        self._m_step.labels(kind="draft_step").observe(dt)
         if self._m_spec_draft_ms is not None:
-            self._m_spec_draft_ms.labels(kind="propose").observe(dt * 1000)
-        self.recorder.record(
-            "step_complete", step="draft_step", n_steps=k,
-            n_live=len(live), ms=round(dt * 1000, 3),
-        )
+            self._m_spec_draft_ms.labels(kind="propose").observe(
+                timed["seconds"] * 1000
+            )
         # transpose [k][lanes] -> [lanes][k]
         return [
             [int(out_np[i][lane]) for i in range(k)]
@@ -2959,29 +2879,17 @@ class InferenceEngine:
             arr = jax.device_put(arr, self._token_sharding)
             window = self._attn_window(p + bucket)
             step = self._step_fn(bucket, greedy=False, window=window)
-            self.recorder.record(
-                "step_dispatch", step="prefill", pos=p,
-                bucket=bucket, window=window,
-            )
-            sp = self._spans.begin(
-                "prefill", component="engine", pos=p, bucket=bucket,
-            )
-            t0 = time.perf_counter()
             # Padding tokens write garbage into cache slots [p+width,
             # p+bucket) — harmless: the causal mask hides them until real
             # tokens overwrite those positions.
-            with self._cache_guard():
+            with self._dispatch(
+                "prefill", pos=p, bucket=bucket, window=window
+            ) as timed, self._cache_guard():
                 _, self.cache = step(
                     self.params, arr, self.cache, jnp.int32(p)
                 )
                 jax.block_until_ready(self.cache)
-            chunk_ms = (time.perf_counter() - t0) * 1000
-            self._spans.end(sp)
-            total_ms += chunk_ms
-            self.recorder.record(
-                "step_complete", step="prefill", pos=p,
-                bucket=bucket, window=window, ms=round(chunk_ms, 3),
-            )
+            total_ms += timed["seconds"] * 1000
             p += width
         return StepStats(time_ms=total_ms, n_tokens=max(n - 1, 0))
 
@@ -3005,22 +2913,12 @@ class InferenceEngine:
         greedy = self.temperature == 0.0
         window = self._attn_window(pos + 1)
         step = self._step_fn(1, greedy=greedy, window=window)
-        self.recorder.record(
-            "step_dispatch", step="decode_step", pos=pos, window=window
-        )
-        sp = self._spans.begin(
-            "decode_step", component="engine", pos=pos, window=window
-        )
-        t0 = time.perf_counter()
-        with self._cache_guard():
+        with self._dispatch(
+            "decode_step", pos=pos, window=window
+        ) as timed, self._cache_guard():
             out, self.cache = step(self.params, arr, self.cache, jnp.int32(pos))
             out = jax.block_until_ready(out)
-        ms = (time.perf_counter() - t0) * 1000
-        self._spans.end(sp)
-        self.recorder.record(
-            "step_complete", step="decode_step", pos=pos, window=window,
-            ms=round(ms, 3),
-        )
+        ms = timed["seconds"] * 1000
         if greedy:
             next_token = int(np.asarray(out)[0])
         else:

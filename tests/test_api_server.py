@@ -1305,6 +1305,14 @@ def test_anomaly_fires_and_recovers_through_server(obs_server):
 
     state = obs_server.state
     mon = state.anomaly
+    # The background sampler evaluates the same monitor on the real clock
+    # and counts an edge after it has dropped the lock: between this
+    # test's own ticks that changes which thread fires and when the
+    # counter moves. It stands still for this test. The fake clock ends at
+    # the monitor's own now, so that `active_s` is a time that has passed
+    # on any host, one booted a minute ago too.
+    state.sampler.stop()
+    t0 = mon._clock() - 40.0
     val = {"v": 1.0}
     rule = AnomalyRule(
         "test_e2e", lambda: val["v"], direction="high", z_threshold=4.0,
@@ -1317,17 +1325,15 @@ def test_anomaly_fires_and_recovers_through_server(obs_server):
         mon.rules.append(rule)
         mon._state["test_e2e"] = _RuleState(rule.alpha)
     try:
-        # teach the baseline with calm ticks (the background sampler may
-        # interleave more ticks at the same value — also calm, also
-        # teaching — so every outcome below stays deterministic)
+        # teach the baseline with calm ticks
         for i in range(10):
-            mon.evaluate(now=1_000.0 + i)
+            mon.evaluate(now=t0 + i)
         assert "test_e2e" not in mon.active_signals()
         calm_health()
 
         # the signal leaves its baseline: exactly one edge
         val["v"] = 100.0
-        mon.evaluate(now=1_020.0)
+        mon.evaluate(now=t0 + 20.0)
         assert "test_e2e" in mon.active_signals()
         assert counter.value == b_count + 1
 
@@ -1346,7 +1352,7 @@ def test_anomaly_fires_and_recovers_through_server(obs_server):
         assert _sample(text, "dllama_anomaly_degraded") == 1.0
 
         # still abnormal on a later tick: edge-triggered, no re-count
-        mon.evaluate(now=1_021.0)
+        mon.evaluate(now=t0 + 21.0)
         assert counter.value == b_count + 1
         fired = state.recorder.events(kind="anomaly")[b_events:]
         assert [e for e in fired if e.get("signal") == "test_e2e"]
@@ -1354,8 +1360,8 @@ def test_anomaly_fires_and_recovers_through_server(obs_server):
         # calm again: recover_ticks consecutive calm ticks clear it (the
         # baseline was frozen at ~1.0, so 1.0 reads as calm immediately)
         val["v"] = 1.0
-        mon.evaluate(now=1_030.0)
-        mon.evaluate(now=1_031.0)
+        mon.evaluate(now=t0 + 30.0)
+        mon.evaluate(now=t0 + 31.0)
         assert "test_e2e" not in mon.active_signals()
         calm_health()
         recovered = state.recorder.events(kind="anomaly_recovered")
@@ -1367,6 +1373,7 @@ def test_anomaly_fires_and_recovers_through_server(obs_server):
                 mon.rules.remove(rule)
             mon._state.pop("test_e2e", None)
         mon.g_degraded.set(1.0 if mon.degraded else 0.0)
+        state.sampler.start()
 
 
 def test_health_degraded_reasons_compose(obs_server):

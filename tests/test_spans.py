@@ -118,6 +118,116 @@ def test_chrome_trace_shape_and_roundtrip(tmp_path):
     assert loaded["dllama"]["n_spans"] == 2
 
 
+def test_streamed_timeline_outlives_the_ring_and_loads_as_a_chrome_trace(tmp_path):
+    """--timeline-out appends every completed span once: a run with more
+    spans than the ring holds leaves all of them in the file, which is a
+    Chrome trace in the JSON array form, closing bracket optional."""
+    from dllama_tpu.obs.spans import read_timeline
+
+    clk = FakeClock(100.0)
+    st = SpanTracker(capacity=8, enabled=True, clock=clk, wall_clock=lambda: 5e9)
+    path = os.path.join(tmp_path, "timeline.json")
+    st.set_sink(path)
+    for i in range(50):
+        h = st.begin("sched_tick", component="scheduler", lane=i % 3, i=i)
+        clk.t += 0.002
+        st.end(h, done=True)
+    assert len(st.completed()) == 8 and st.dropped == 42
+    # the file of a server that still runs may end inside a line
+    assert len(read_timeline(path)[1]) < 50
+    st.set_sink(None)
+    with open(path) as f:
+        text = f.read()
+    assert text.startswith("[") and text.endswith(",\n")
+    assert all(json.loads(ln.lstrip("[").rstrip(",")) for ln in text.splitlines())
+    events = json.loads(text.rstrip(",\n") + "]")
+    assert events[0]["name"] == "timeline_epoch"
+    assert events[0]["args"] == {"epoch_unix": 5e9, "epoch_monotonic": 100.0}
+    xs = [e for e in events if e["ph"] == "X"]
+    assert [e["args"]["i"] for e in xs] == list(range(50))
+    assert xs[7]["ts"] == pytest.approx(7 * 2000.0) and xs[7]["dur"] == pytest.approx(2000.0)
+    assert all(e["args"]["done"] is True for e in xs)
+    # each pid and (pid, tid) is named once, before its first span
+    named = [(e["pid"], e["tid"]) for e in events if e["name"] == "thread_name"]
+    assert sorted(named) == sorted({(e["pid"], e["tid"]) for e in xs})
+    meta, spans = read_timeline(path)
+    assert meta["epoch_monotonic"] == 100.0 and spans == xs
+    # closing the sink ended the stream; the ring goes on
+    st.end(st.begin("later"))
+    assert len(read_timeline(path)[1]) == 50
+
+
+def test_a_broken_sink_is_recorded_and_the_ring_lives_on(tmp_path):
+    rec = FlightRecorder(capacity=16)
+    st = SpanTracker(capacity=4, enabled=True, recorder=rec)
+    st.set_sink(os.path.join(tmp_path, "timeline.json"))
+    st._sink.close()  # the disk went away under the server
+    st.end(st.begin("s"))
+    st.end(st.begin("s"))
+    assert len(st.completed()) == 2
+    (ev,) = rec.events("obs_sink_error")
+    assert ev["what"] == "timeline"
+
+
+class _FakeAnnotation:
+    built = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs, self.open = name, kwargs, None
+        _FakeAnnotation.built.append(self)
+
+    def __enter__(self):
+        self.open = True
+
+    def __exit__(self, *exc):
+        self.open = False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    from dllama_tpu.obs import spans
+
+    monkeypatch.setattr(spans, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.built = []
+    return _FakeAnnotation.built
+
+
+def test_a_span_opens_an_annotation_with_its_int_and_str_attributes(annotations):
+    st = SpanTracker(capacity=4, enabled=True)
+    h = st.begin("decode_lanes", component="engine", request_id="r1", lane=2,
+                 n_steps=8, window=512, ratio=0.5, pages=[1, 2], kind="lane")
+    (a,) = annotations
+    assert a.name == "dllama.engine.decode_lanes" and a.open is True
+    assert a.kwargs == {"request_id": "r1", "lane": 2, "n_steps": 8,
+                        "window": 512, "kind": "lane"}
+    st.end(h)
+    st.end(h)
+    assert a.open is False
+    with pytest.raises(ValueError):
+        with st.span("emit", component="scheduler"):
+            raise ValueError("the body raised")
+    assert [x.open for x in annotations] == [False, False]
+    # a span handed to another thread says nothing of what this one does
+    st.end(st.begin("queue", component="scheduler", annotate=False))
+    assert len(annotations) == 2 and len(st.completed()) == 3
+
+
+def test_a_disabled_tracker_builds_no_annotation(annotations):
+    st = SpanTracker(capacity=4, enabled=False)
+    st.end(st.begin("decode_lanes", n_steps=8))
+    with st.span("emit"):
+        pass
+    assert annotations == [] and st.completed() == []
+
+
+def test_a_caller_may_read_the_clock_for_the_tracker():
+    clk = FakeClock(50.0)
+    st = SpanTracker(capacity=4, enabled=True, clock=clk)
+    st.end(st.begin("decode_lanes", at=50.5), at=50.75)
+    (s,) = st.completed()
+    assert s["t0"] == 0.5 and s["dur_s"] == 0.25
+
+
 def test_request_summary_coverage_and_phases():
     clk = FakeClock()
     st = SpanTracker(capacity=16, enabled=True, clock=clk)

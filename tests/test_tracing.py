@@ -1,0 +1,211 @@
+"""One clock: the scheduler thread's spans inside a profiler trace.
+
+A tiny-model server streams to four clients at once under
+`jax.profiler.start_trace` on the CPU. The trace's host plane then has to
+hold the `dllama.*` annotations of the scheduler thread, nested as begun
+and with the `mono_ns` anchor, the span ring has to cover each tick with
+leaf spans, and the streamed `--timeline-out` file has to hold every span.
+The compiled lane programs have to carry the layer scopes in `op_name`.
+"""
+
+import glob
+import json
+import os
+import re
+import statistics
+import threading
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dllama_tpu.formats import FloatType
+from dllama_tpu.formats.model_file import LlmArch
+from dllama_tpu.obs.spans import read_timeline
+from dllama_tpu.runtime.api_server import serve
+from dllama_tpu.runtime.engine import InferenceEngine
+from dllama_tpu.tokenizer import Tokenizer
+
+from helpers import make_tiny_model, make_tiny_tokenizer
+
+CFG = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
+           head_dim=16, vocab_size=288, seq_len=384)
+TICK = "dllama.scheduler.sched_tick"
+
+
+def _engine(d, arch=LlmArch.LLAMA, cfg=CFG, lanes=4):
+    mp, tp_ = str(d / "m.m"), str(d / "t.t")
+    make_tiny_model(mp, arch=arch, weight_type=FloatType.Q40, cfg=cfg)
+    make_tiny_tokenizer(tp_, chat_template="<|start_header_id|>")
+    tok = Tokenizer(tp_)
+    engine = InferenceEngine(
+        mp, tokenizer=tok, tp=1, dtype=jnp.float32, temperature=0.0, seed=3,
+        batch_size=lanes,
+    )
+    return engine, tok
+
+
+def _stream(url, i, n_tokens):
+    req = urllib.request.Request(
+        url + "/v1/chat/completions",
+        data=json.dumps({
+            "messages": [{"role": "user", "content": f"hello number {i}"}],
+            "max_tokens": n_tokens, "temperature": 0, "stream": True,
+        }).encode(),
+        headers={"Content-Type": "application/json"}, method="POST",
+    )
+    with urllib.request.urlopen(req, timeout=300) as r:
+        r.read()
+
+
+def _scheduler_events(trace_dir):
+    """(name, start_ns, end_ns, stats) of the `dllama.*` events of the host
+    thread that holds the scheduler's ticks."""
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                      for e in line.events if e.name.startswith("dllama.")]
+            if any(name == TICK for name, *_ in events):
+                return events
+    return []
+
+
+@pytest.fixture(scope="module")
+def traced_run(tmp_path_factory):
+    """Four concurrent streams of 160 tokens under a profiler session."""
+    d = tmp_path_factory.mktemp("tracing")
+    engine, tok = _engine(d)
+    timeline = str(d / "timeline.json")
+    srv = serve(engine, tok, host="127.0.0.1", port=0, timeline_out=timeline)
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    url = f"http://127.0.0.1:{srv.server_address[1]}"
+    _stream(url, 99, 16)  # compile the programs outside the trace
+    spans = srv.state.spans
+    first = spans.total_recorded
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(d / "profile"), profiler_options=options)
+    clients = [threading.Thread(target=_stream, args=(url, i, 160))
+               for i in range(4)]
+    for c in clients:
+        c.start()
+    for c in clients:
+        c.join(timeout=300)
+    jax.profiler.stop_trace()
+    assert not any(c.is_alive() for c in clients)
+    n_new = spans.total_recorded - first
+    ring = spans.completed()[-n_new:]
+    srv.shutdown()
+    srv.server_close()
+    return {"events": _scheduler_events(str(d / "profile")), "ring": ring,
+            "timeline": timeline, "tracker": spans}
+
+
+def _inside(events, outer):
+    return [e for e in events if outer[1] <= e[1] and e[2] <= outer[2] and e is not outer]
+
+
+def test_scheduler_spans_are_in_the_profile_nested_as_begun(traced_run):
+    events = traced_run["events"]
+    names = {name for name, *_ in events}
+    assert {TICK, "dllama.scheduler.emit", "dllama.scheduler.step_prep",
+            "dllama.engine.dispatch_prep", "dllama.engine.decode_lanes",
+            "dllama.engine.decode_lanes.device"} <= names
+    # spans that end on another thread, or outlive their tick, stay out
+    assert not names & {"dllama.scheduler.queue", "dllama.scheduler.decode"}
+    ticks = [e for e in events if e[0] == TICK]
+    for name in ("emit", "step_prep"):
+        for e in (e for e in events if e[0] == f"dllama.scheduler.{name}"):
+            assert any(t[1] <= e[1] and e[2] <= t[2] for t in ticks), e
+    blocks = [e for e in events if e[0] == "dllama.engine.decode_lanes"]
+    assert len(blocks) >= 20
+    for block in blocks:
+        (tick,) = [t for t in ticks if t[1] <= block[1] and block[2] <= t[2]]
+        order = [e[0].rsplit(".", 1)[-1] for e in sorted(_inside(events, tick), key=lambda e: e[1])
+                 if e[0].rsplit(".", 1)[-1] in ("step_prep", "dispatch_prep", "decode_lanes", "emit")]
+        assert order[-4:] == ["step_prep", "dispatch_prep", "decode_lanes", "emit"]
+        (wait,) = [e for e in _inside(events, block)
+                   if e[0] == "dllama.engine.decode_lanes.device"]
+        assert block[3]["n_live"] >= 1 and block[3]["n_steps"] >= 1
+        assert wait[2] <= block[2]
+
+
+def test_mono_ns_anchors_the_host_clock_to_the_profile(traced_run):
+    """The profile's clock less `time.monotonic_ns()` at each tick's begin
+    is one offset: at least 20 ticks agree within 1 ms."""
+    offsets = [start - stats["mono_ns"]
+               for name, start, _, stats in traced_run["events"] if name == TICK]
+    assert len(offsets) >= 20
+    assert max(offsets) - min(offsets) < 1e6, (min(offsets), max(offsets))
+
+
+def test_leaf_spans_cover_the_scheduler_tick(traced_run):
+    """The rule: inside a `sched_tick`, at least 95% of its wall time lies
+    inside the spans begun within it (median over the run's ticks)."""
+    ring = traced_run["ring"]
+    ticks = [s for s in ring if s["name"] == "sched_tick"]
+    assert len(ticks) >= 20
+    shares = []
+    for tick in ticks:
+        lo, hi = tick["t0"], tick["t0"] + tick["dur_s"]
+        inner = sorted((max(s["t0"], lo), min(s["t0"] + s["dur_s"], hi)) for s in ring
+                       if s is not tick and s["name"] not in ("queue", "decode")
+                       and lo <= s["t0"] and s["t0"] + s["dur_s"] <= hi)
+        covered, end = 0.0, lo
+        for a, b in inner:
+            covered += max(0.0, b - max(a, end))
+            end = max(end, b)
+        shares.append(covered / tick["dur_s"])
+    assert statistics.median(shares) >= 0.95, sorted(shares)[:5]
+
+
+def test_streamed_timeline_holds_every_span_on_the_recorders_clock(traced_run):
+    tracker = traced_run["tracker"]
+    meta, spans = read_timeline(traced_run["timeline"])
+    assert meta["epoch_monotonic"] == tracker.epoch_monotonic
+    assert meta["epoch_unix"] == tracker.epoch_unix
+    by_name = {}
+    for s in spans:
+        by_name[s["name"]] = by_name.get(s["name"], 0) + 1
+    ring = {}
+    for s in traced_run["ring"]:
+        ring[s["name"]] = ring.get(s["name"], 0) + 1
+    for name in ("sched_tick", "emit", "dispatch_prep", "decode_lanes",
+                 "begin_admission", "finish", "sched_wait"):
+        assert by_name[name] >= ring[name] > 0, name
+    # a tick's mono_ns attribute is its own start on the recorder's clock
+    tick = next(s for s in spans if s["name"] == "sched_tick")
+    start = meta["epoch_monotonic"] + tick["ts"] / 1e6
+    assert abs(start - tick["args"]["mono_ns"] / 1e9) < 1e-3
+
+
+@pytest.fixture(scope="module", params=["dense", "moe"])
+def lane_programs(request, tmp_path_factory):
+    d = tmp_path_factory.mktemp(f"scopes-{request.param}")
+    if request.param == "moe":
+        engine, _ = _engine(d, arch=LlmArch.QWEN3_MOE, cfg=None, lanes=2)
+    else:
+        engine, _ = _engine(d, lanes=2)
+    window = engine._attn_window(1)
+    return request.param, {
+        "decode": engine._lane_decode_fn(4, window).as_text(),
+        "prefill": engine._lane_prefill_fn(8, window=window).as_text(),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_lane_programs_carry_the_layer_scopes(lane_programs, program):
+    kind, texts = lane_programs
+    op_names = set(re.findall(r'op_name="([^"]*)"', texts[program]))
+    ffn = "moe" if kind == "moe" else "ffn"
+    for scope in ("attn", ffn, "kv_write", "norm"):
+        assert any(re.search(rf"/layers/(while/body/(closed_call/)?)?{scope}(/|$|;)", n)
+                   for n in op_names), scope
+    if program == "decode":  # a prefill chunk's logits are dead code
+        assert any("/logits_head" in n for n in op_names)
+        assert any("/sample" in n for n in op_names)
