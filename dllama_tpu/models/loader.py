@@ -4,8 +4,10 @@ TPU-native counterpart of the reference's weight loading + distribution
 (loadLlmNetWeight, src/llm.cpp:614-669): the reference root slices every
 matmul weight per node and ships slices over TCP; here each tensor is read
 (streamed via memmap), transposed to the [in, out] matmul layout, stacked
-across layers for `lax.scan`, and `jax.device_put` with a NamedSharding does
-the slicing — XLA/ICI plays the role of the socket loader.
+across layers (one [L, ...] array per weight: the layer scan slices the
+dense ones, and the Pallas kernels read the quantized ones in place by
+layer number), and `jax.device_put` with a NamedSharding does the slicing
+— XLA/ICI plays the role of the socket loader.
 
 Llama q/k row permutation note: the converter pre-permutes q/k rows to the
 interleaved-rope layout (converter/convert-hf.py:13-16), so like the

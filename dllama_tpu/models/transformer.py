@@ -35,9 +35,11 @@ from ..formats.model_file import HiddenAct, LlmArch, LlmHeader, RopeType
 from ..ops.jnp_ops import apply_rope, gelu, qk_rms_norm, rms_norm, silu
 from ..ops.int8_matmul import Int8Weight, i8matmul_tp
 from ..ops.quant_matmul import (
+    FusedQuantWeight,
     PackedQuantWeight,
     QuantWeight,
     dequant,
+    layer_of,
     qmatmul_tp,
 )
 
@@ -68,21 +70,26 @@ KvCache = Dict[str, jnp.ndarray]
 _NEG_INF = -1e30
 
 
-def _mm(x: jnp.ndarray, w, role: str, mesh, sync_quant: bool = False) -> jnp.ndarray:
+def _mm(
+    x: jnp.ndarray, w, role: str, mesh, sync_quant: bool = False, layer=None
+) -> jnp.ndarray:
     """Matmul dispatch: dense [in, out] weights take the einsum path (GSPMD
     partitions them via the NamedSharding specs); Q40 QuantWeight leaves take
-    the Pallas kernel (shard_map'd per TP role on a mesh). `sync_quant`
-    Q80-compresses the col-split partial-sum all-reduce payload
-    (reference: --buffer-float-type q80)."""
+    the Pallas kernel (shard_map'd per TP role on a mesh), as a [L, in, out]
+    stack and the `layer` to take. `sync_quant` Q80-compresses the col-split
+    partial-sum all-reduce payload (reference: --buffer-float-type q80)."""
     if isinstance(w, Int8Weight):
         return i8matmul_tp(x, w, role, mesh, sync_quant=sync_quant).astype(x.dtype)
     if isinstance(w, _QUANT_CLASSES):
-        return qmatmul_tp(x, w, role, mesh, sync_quant=sync_quant).astype(x.dtype)
+        return qmatmul_tp(
+            x, w, role, mesh, sync_quant=sync_quant, layer=layer
+        ).astype(x.dtype)
     return jnp.einsum("bti,io->bto", x, w)
 
 
 def _mm_manual(
-    x: jnp.ndarray, w, role: str, axis: str | None, sync_quant: bool = False
+    x: jnp.ndarray, w, role: str, axis: str | None, sync_quant: bool = False,
+    layer=None,
 ) -> jnp.ndarray:
     """Matmul for MANUAL-collective contexts (inside an enclosing
     shard_map, e.g. a pipeline stage's tp group): `w` is already this
@@ -107,8 +114,17 @@ def _mm_manual(
 
         return reduce(i8matmul(x, w)).astype(x.dtype)
     if isinstance(w, _QUANT_CLASSES):
-        return reduce(qmatmul(x, w)).astype(x.dtype)
+        return reduce(qmatmul(x, w, layer)).astype(x.dtype)
     return reduce(jnp.einsum("bti,io->bto", x, w))
+
+
+def _is_quant_stack(leaf) -> bool:
+    """A layer-stacked leaf that the Pallas kernels read in place: Q40
+    values and scales, fused or not. `Int8Weight` (q40i8) and dense
+    weights stay among the layer scan's `xs`."""
+    if isinstance(leaf, FusedQuantWeight):
+        leaf = leaf.weight
+    return isinstance(leaf, _QUANT_CLASSES)
 
 
 def _split_fused(out: jnp.ndarray, tp: int, dims: tuple[int, ...]):
@@ -505,10 +521,16 @@ def _moe_ffn_gather(
 MOE_PALLAS_MAX_TOKENS = 16
 
 
+def _expert_stacks(*ws: QuantWeight) -> tuple[jnp.ndarray, ...]:
+    """Quantized experts' values and scales as [L, E, ...] stacks, in
+    argument order; one layer's [E, ...] experts are a stack of one."""
+    return tuple(a.reshape(-1, *a.shape[-3:]) for w in ws for a in w)
+
+
 def _moe_ffn_pallas(
     x: jnp.ndarray,  # [B, T, D] with B*T <= MOE_PALLAS_MAX_TOKENS
     gate_w: jnp.ndarray,
-    w1,  # [E, D, F] dense, or QuantWeight (q int8 [E, D, F] + d [E, D/32, F])
+    w1,  # [E, D, F] dense, or QuantWeight (q int8 [L, E, D, F] + d [L, E, D/32, F])
     w2,  # [E, F, D] (same)
     w3,  # [E, D, F] (same)
     n_active: int,
@@ -516,6 +538,7 @@ def _moe_ffn_pallas(
     interpret: bool = False,
     sync_quant: bool = False,
     dedup: bool = False,
+    layer=0,  # which layer of quantized experts' [L, E, ...] stacks
 ) -> jnp.ndarray:
     """Decode-step MoE via the ragged Pallas kernel (ops/moe_kernel.py):
     each token's top-k expert ids drive the HBM->VMEM DMA schedule, so only
@@ -552,17 +575,20 @@ def _moe_ffn_pallas(
         return lax.cond(u <= cap, lambda: grouped_fn(cap), ragged_fn)
 
     if quantized:
-        operands = (xf, w1.q, w1.d, w2.q, w2.d, w3.q, w3.d, top_i, weights)
+        operands = (
+            xf, *_expert_stacks(w1, w2, w3), top_i, weights,
+            jnp.asarray(layer, jnp.int32),
+        )
 
-        def run(xx, w1q, w1d, w2q, w2d, w3q, w3d, ii, wts):
+        def run(xx, w1q, w1d, w2q, w2d, w3q, w3d, ii, wts, ll):
             return _maybe_two_tier(
                 ii,
                 lambda: moe_active_experts_q40(
-                    xx, w1q, w1d, w2q, w2d, w3q, w3d, ii, wts,
+                    xx, w1q, w1d, w2q, w2d, w3q, w3d, ii, wts, ll,
                     interpret=interpret,
                 ),
                 lambda cap: moe_grouped_experts_q40(
-                    xx, w1q, w1d, w2q, w2d, w3q, w3d, ii, wts,
+                    xx, w1q, w1d, w2q, w2d, w3q, w3d, ii, wts, ll,
                     interpret=interpret, max_segments=cap,
                 ).astype(jnp.float32),
             )
@@ -594,7 +620,9 @@ def _moe_ffn_pallas(
         row_q = P(None, None, "tp")  # w1/w3 values AND scales: F on lanes
         col_q = P(None, "tp", None)  # w2 values AND scales: F on sublanes
         if quantized:
-            in_specs = (tok, row_q, row_q, col_q, col_q, row_q, row_q, tok, tok)
+            # a stack's layer axis is on no mesh axis; the layer is replicated
+            row_q, col_q = P(None, *row_q), P(None, *col_q)
+            in_specs = (tok, row_q, row_q, col_q, col_q, row_q, row_q, tok, tok, P())
         else:
             in_specs = (tok, row_q, col_q, row_q, tok, tok)
 
@@ -616,13 +644,14 @@ def _moe_ffn_pallas(
 def _moe_ffn_grouped(
     x: jnp.ndarray,  # [B, T, D] prefill-scale B*T
     gate_w: jnp.ndarray,
-    w1,  # [E, D, F] dense or QuantWeight
+    w1,  # [E, D, F] dense or QuantWeight [L, E, D, F]
     w2,
     w3,
     n_active: int,
     mesh,
     interpret: bool = False,
     sync_quant: bool = False,
+    layer=0,  # which layer of quantized experts' [L, E, ...] stacks
 ) -> jnp.ndarray:
     """Prefill MoE via the grouped active-expert kernel
     (ops/moe_kernel.moe_grouped_experts*): assignments sorted by expert,
@@ -647,10 +676,9 @@ def _moe_ffn_grouped(
 
     def run(xx, ii, ww, *wargs):
         if quantized:
-            w1q, w1d, w2q, w2d, w3q, w3d = wargs
+            # six weight planes, then the layer number
             return moe_grouped_experts_q40(
-                xx, w1q, w1d, w2q, w2d, w3q, w3d, ii, ww,
-                interpret=interpret,
+                xx, *wargs[:6], ii, ww, wargs[6], interpret=interpret
             )
         ww1, ww2, ww3 = wargs
         return moe_grouped_experts(
@@ -658,7 +686,7 @@ def _moe_ffn_grouped(
         )
 
     operands = (
-        (xf, top_i, wts, w1.q, w1.d, w2.q, w2.d, w3.q, w3.d)
+        (xf, top_i, wts, *_expert_stacks(w1, w2, w3), jnp.asarray(layer, jnp.int32))
         if quantized
         else (xf, top_i, wts, w1, w2, w3)
     )
@@ -674,7 +702,8 @@ def _moe_ffn_grouped(
         row_q = P(None, None, "tp")
         col_q = P(None, "tp", None)
         if quantized:
-            in_specs = (tok, tok, tok, row_q, row_q, col_q, col_q, row_q, row_q)
+            row_q, col_q = P(None, *row_q), P(None, *col_q)
+            in_specs = (tok, tok, tok, row_q, row_q, col_q, col_q, row_q, row_q, P())
         else:
             in_specs = (tok, tok, tok, row_q, col_q, row_q)
 
@@ -708,8 +737,9 @@ def forward(
     """Run the decoder on T tokens starting at absolute position `pos`.
 
     Returns (logits [B, T, V] f32, updated cache). Jit-safe: T is static,
-    `pos` is a traced scalar. Layers run under `lax.scan` over the stacked
-    layer parameters so compile time is O(1) in depth.
+    `pos` is a traced scalar. Layers run under one `lax.scan` over the
+    layer number (`run_layers`), so compile time is O(1) in depth; the
+    quantized weight stacks reach the kernels whole.
 
     `mesh` is only consulted by the quantized (Pallas) matmul path, which
     needs explicit shard_map partitioning; the dense path is GSPMD-managed
@@ -835,6 +865,12 @@ def run_layers(
 ):
     """`lax.scan` the decoder layers over x; returns (x, k_new, v_new).
 
+    The scan runs over the layer number, the caches and the small or dense
+    per-layer leaves. Quantized weight stacks (`_is_quant_stack`) are not
+    among its `xs`: a Pallas call is opaque to XLA, which would copy every
+    layer's slice out of the stack before the kernel reads it again. The
+    step closes over them and hands the kernels `(stack, l)`.
+
     Factored out of `forward` so the pipeline-parallel driver
     (parallel/pipeline.py) can run a STAGE'S LOCAL layer slice with
     identical math — there `layers`/caches carry L/pp layers and
@@ -879,10 +915,8 @@ def run_layers(
     # mesh tp size: per-shard shape checks (MoE kernel gate)
     _tp_n = mesh.shape.get("tp", 1) if mesh is not None else 1
 
-    def mm(yy, w, role, sync=False):
-        if tp_axis is not None:
-            return _mm_manual(yy, w, role, tp_axis, sync and sync_quant)
-        return _mm(yy, w, role, mesh, sync and sync_quant)
+    stacks = {k: v for k, v in layers.items() if _is_quant_stack(v)}
+    sliced = {k: v for k, v in layers.items() if k not in stacks}
 
     def _cache_append(cache_l, val):
         """Write the chunk at each lane's position (reference: OP_SHIFT,
@@ -965,7 +999,14 @@ def run_layers(
         return jax.vmap(lambda c, u: write(c, u, pos))(cache_l, val)
 
     def layer_step(x, layer):
-        lp, k_cache_l, v_cache_l = layer
+        lp, l, k_cache_l, v_cache_l = layer
+        lp = {**lp, **stacks}
+
+        def mm(yy, w, role, sync=False):
+            # `l` counts only where `w` is one of `stacks`
+            if tp_axis is not None:
+                return _mm_manual(yy, w, role, tp_axis, sync and sync_quant, l)
+            return _mm(yy, w, role, mesh, sync and sync_quant, l)
 
         # -- attention block (reference: src/llm.cpp:263-403) --
         with jax.named_scope("norm"):
@@ -1094,12 +1135,13 @@ def run_layers(
                         f = _moe_ffn_pallas(
                             y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
                             h.n_active_experts, mesh, sync_quant=sync_quant,
-                            dedup=moe_decode_dedup,
+                            dedup=moe_decode_dedup, layer=l,
                         )
                     else:
                         f = _moe_ffn_grouped(
                             y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
                             h.n_active_experts, mesh, sync_quant=sync_quant,
+                            layer=l,
                         )
                 else:
                     moe = (
@@ -1107,12 +1149,14 @@ def run_layers(
                         if b * t <= moe_gather_max_tokens
                         else _moe_ffn
                     )
+                    # XLA compiles these and fuses the slice into the dequant
                     f = moe(
                         y,
                         lp["moe_gate"],
-                        lp["w1"],
-                        lp["w2"],
-                        lp["w3"],
+                        *(
+                            layer_of(lp[n], l) if _quantized else lp[n]
+                            for n in ("w1", "w2", "w3")
+                        ),
                         h.n_active_experts,
                         act,
                     )
@@ -1132,17 +1176,20 @@ def run_layers(
                 f = mm(d * l3.astype(d.dtype), lp["w2"], "col", sync=True)
             else:
                 d = act(mm(y, lp["w1"], "row"))
-                l = mm(y, lp["w3"], "row")
-                f = mm(d * l.astype(d.dtype), lp["w2"], "col", sync=True)
+                l3 = mm(y, lp["w3"], "row")
+                f = mm(d * l3.astype(d.dtype), lp["w2"], "col", sync=True)
             x = x + f.astype(x.dtype)
         return x, (k_cache_l, v_cache_l)
 
     # scopes name the device's operations in a profile (`op_name`) and
     # change nothing that is compiled: what runs under `layers` but under
-    # no scope of `layer_step` is the scan's own slicing of the stacked
-    # weights and caches
+    # no scope of `layer_step` is the scan's own slicing of its `xs`, the
+    # caches above all
+    n_layers = jax.tree.leaves(k_cache)[0].shape[0]
     with jax.named_scope("layers"):
         x, (k_new, v_new) = lax.scan(
-            layer_step, x, (layers, k_cache, v_cache)
+            layer_step,
+            x,
+            (sliced, jnp.arange(n_layers, dtype=jnp.int32), k_cache, v_cache),
         )
     return x, k_new, v_new

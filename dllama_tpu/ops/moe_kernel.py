@@ -190,6 +190,21 @@ def _moe_kernel_q40(
     )
 
 
+def _layer_experts(layer, *stacks):
+    """A layer's experts inside `[L, E, ...]` stacks, for kernels that pick
+    an expert by id: the stacks viewed as `[L * E, ...]` (a merge of the
+    leading axes, no copy) and `layer * E`, the offset that turns the
+    layer's expert ids into rows of that view. So a layer scan hands the
+    kernels the whole stacks and nothing copies a layer's E experts out
+    for the few a token reads. One layer's `[E, ...]` experts are a stack
+    of one, at layer 0."""
+    n_experts = stacks[0].shape[-3]
+    return (
+        jnp.asarray(layer, jnp.int32) * n_experts,
+        tuple(w.reshape(-1, *w.shape[-2:]) for w in stacks),
+    )
+
+
 def _full_map(ti, ki, fi, idx_ref, w_ref):
     # x and out ride as ONE whole-array block: a per-token (1, D) block
     # would put a size-1 dim in the last-two block dims, which Mosaic
@@ -257,15 +272,19 @@ def moe_active_experts_q40(
     w3d: jnp.ndarray,  # [E, D // 32, F] f32
     top_i: jnp.ndarray,  # [m, k] int32
     weights: jnp.ndarray,  # [m, k] f32
+    layer=0,  # int32 scalar: which layer of [L, E, ...] stacks
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Quantized ragged MoE: selected experts' Q40 blocks are DMA'd and
     dequantized in VMEM (0.56x the bytes of bf16 per weight — the same
     HBM-traffic win as the dense-layer Pallas matmul); [m, D] f32."""
     m, d = x.shape
-    e, _, f = w1q.shape
+    e, _, f = w1q.shape[-3:]
     k = top_i.shape[-1]
     assert top_i.shape == (m, k), (top_i.shape, m, k)
+    first, (w1q, w1d, w2q, w2d, w3q, w3d) = _layer_experts(
+        layer, w1q, w1d, w2q, w2d, w3q, w3d
+    )
     bf = _pick_f_block(f, d, quantized=True)
     n_f = f // bf
 
@@ -289,7 +308,7 @@ def moe_active_experts_q40(
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
         interpret=interpret,
     )(
-        top_i, weights.astype(jnp.float32),
+        top_i + first, weights.astype(jnp.float32),
         x.astype(jnp.float32), w1q, w1d, w3q, w3d, w2q, w2d,
     )
 
@@ -570,13 +589,17 @@ def moe_grouped_experts_q40(
     w3d: jnp.ndarray,  # [E, D // 32, F] f32
     top_i: jnp.ndarray,  # [N, k] int32
     weights: jnp.ndarray,  # [N, k] f32
+    layer=0,  # int32 scalar: which layer of [L, E, ...] stacks
     interpret: bool = False,
     max_segments: int | None = None,
 ) -> jnp.ndarray:
     """Quantized grouped active-expert MoE (see moe_grouped_experts):
     selected experts' Q40 blocks stream once per overlapping row tile."""
     n, d = x.shape
-    e, _, f = w1q.shape
+    e, _, f = w1q.shape[-3:]
+    first, (w1q, w1d, w2q, w2d, w3q, w3d) = _layer_experts(
+        layer, w1q, w1d, w2q, w2d, w3q, w3d
+    )
     bf = _pick_f_block(f, d, quantized=True)
     n_f = f // bf
     r = _GROUP_ROWS
@@ -588,6 +611,7 @@ def moe_grouped_experts_q40(
     g_steps = lo.shape[0]
     x_sorted = jnp.take(x, t_s, axis=0).astype(jnp.bfloat16)
 
+    # the schedule sorted on the layer's own ids; only the rows read differ
     o_sorted = pl.pallas_call(
         functools.partial(
             _grouped_kernel_q40, n_f=n_f, n_steps=g_steps, rows=r
@@ -610,7 +634,7 @@ def moe_grouped_experts_q40(
         ),
         out_shape=jax.ShapeDtypeStruct((a_pad, d), jnp.float32),
         interpret=interpret,
-    )(lo, hi, tile, expert, x_sorted, w_col,
+    )(lo, hi, tile, expert + first, x_sorted, w_col,
       w1q, w1d, w3q, w3d, w2q, w2d)
 
     return jnp.zeros((n, d), jnp.float32).at[t_s].add(o_sorted)

@@ -36,7 +36,10 @@ Q_BLOCK = 32
 
 
 class QuantWeight(NamedTuple):
-    """Planar Q40 tensor in device layout (a pytree; scan/device_put compose).
+    """Planar Q40 tensor in device layout (a pytree of two leaves). A
+    model's layers hold one such tensor per weight with a leading layer
+    axis, [L, in, out]: the kernels take that stack whole with a layer
+    number (`qmatmul(x, w, layer)`) and their block specs pick the layer.
 
     ``q`` int8 [..., in, out] with values in [-8, 7];
     ``d`` f32 [..., in // 32, out] per-block scales (f32 holds the wire's
@@ -96,8 +99,7 @@ class FusedQuantWeight:
     ``fuse`` (the interleave shard count) and ``dims`` (the constituents'
     global out dims) ride as STATIC pytree aux data, so the un-interleave
     factor travels with the weights themselves — consuming fused params on
-    a mesh with a different tp cannot silently mis-permute columns, and
-    `lax.scan` over stacked layers preserves the metadata."""
+    a mesh with a different tp cannot silently mis-permute columns."""
 
     def __init__(self, weight: QuantWeight, fuse: int, dims: tuple[int, ...]):
         self.weight = weight
@@ -182,10 +184,21 @@ def dequant_packed(w: PackedQuantWeight, dtype=jnp.bfloat16) -> jnp.ndarray:
     return dense.reshape(*lead, inner, out).astype(dtype)
 
 
-def qmatmul_ref(x: jnp.ndarray, w) -> jnp.ndarray:
+def layer_of(w, layer):
+    """Layer `layer` (a traced scalar) of a [L, ...] stacked weight, for
+    consumers that XLA compiles and so fuses the slice into."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), w
+    )
+
+
+def qmatmul_ref(x: jnp.ndarray, w, layer=None) -> jnp.ndarray:
     """Reference path: dequant + dense matmul. x [..., in] -> [..., out] f32.
     Used for equivalence tests and as the off-TPU fallback. Accepts both
-    QuantWeight and PackedQuantWeight."""
+    QuantWeight and PackedQuantWeight, and like the kernels a [L, in, out]
+    stack with a layer number, sliced before it is dequantised."""
+    if layer is not None:
+        w = layer_of(w, layer)
     if isinstance(w, PackedQuantWeight):
         dense = dequant_packed(w, jnp.float32)
     else:
@@ -193,9 +206,10 @@ def qmatmul_ref(x: jnp.ndarray, w) -> jnp.ndarray:
     return jnp.einsum("...i,io->...o", x.astype(jnp.float32), dense)
 
 
-def _qmm_kernel(x_ref, q_ref, d_ref, o_ref, acc_ref, *, n_k: int):
+def _qmm_kernel(l_ref, x_ref, q_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     """One (m, block_n) output tile, accumulated over k blocks in VMEM
-    scratch: sublane-broadcast dequant then MXU."""
+    scratch: sublane-broadcast dequant then MXU. `l_ref` (the layer
+    number) is read by the block specs alone."""
     pk = pl.program_id(2)
     q = q_ref[:]  # [bk, bn] int8
     d = d_ref[:]  # [bk // 32, bn] f32
@@ -245,7 +259,7 @@ def _f16_bits_to_f32(bits: jnp.ndarray) -> jnp.ndarray:
     return jnp.where((b & 0x8000) != 0, -mag, mag)
 
 
-def _qmm_i4_kernel(x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
+def _qmm_i4_kernel(l_ref, x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     """One (m, block_n) output tile from packed-nibble weights: the
     HBM->VMEM copy moves 0.5625 B/weight, then shift/mask unpack +
     sublane-broadcast dequant in VMEM feed the MXU in bf16 exactly like
@@ -326,18 +340,68 @@ def _pick_block(n: int, preferred: int, ragged: bool = False) -> int:
 BLOCK_M = 512
 
 
+def _qmm_call(
+    kernel, x, values, scales, layer, pack: int, block_n: int, block_k: int,
+    interpret: bool,
+) -> jnp.ndarray:
+    """The one `pallas_call` of both Q40 kernels: `values` [L, k // pack, n]
+    and `scales` [L, k // 32, n] stay whole in HBM, the layer number rides
+    in as scalar prefetch, and the weight blocks' index maps pick the layer
+    — so a layer scan that closes over the stacks copies nothing out of
+    them. A 2-D weight (`wcls`) is a stack of one."""
+    if values.ndim == 2:
+        assert layer is None, "a layer number needs a [L, k, n] stack"
+        values, scales, layer = values[None], scales[None], 0
+    m, k = x.shape
+    n = values.shape[-1]
+    assert values.shape[1:] == (k // pack, n), (values.shape, x.shape)
+    assert scales.shape == (values.shape[0], k // Q_BLOCK, n), scales.shape
+    bn = _pick_block(n, block_n, ragged=True)
+    bk = _pick_block(k, block_k)
+    assert bk % Q_BLOCK == 0
+
+    n_k = k // bk
+    bm = min(m, BLOCK_M)
+    return pl.pallas_call(
+        functools.partial(kernel, n_k=n_k),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            # k innermost: the accumulator tile stays live
+            grid=(pl.cdiv(m, bm), pl.cdiv(n, bn), n_k),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda r, i, j, l: (r, j)),
+                pl.BlockSpec(
+                    (None, bk // pack, bn), lambda r, i, j, l: (l[0], j, i)
+                ),
+                pl.BlockSpec(
+                    (None, bk // Q_BLOCK, bn), lambda r, i, j, l: (l[0], j, i)
+                ),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda r, i, j, l: (r, i)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        x.astype(jnp.bfloat16), values, scales,
+    )
+
+
 @functools.partial(
     jax.jit, static_argnames=("block_n", "block_k", "interpret")
 )
 def qmatmul_2d(
     x: jnp.ndarray,  # [m, k]
-    q: jnp.ndarray,  # [k, n] int8
-    d: jnp.ndarray,  # [k // 32, n] f32
+    q: jnp.ndarray,  # [L, k, n] int8, or [k, n]
+    d: jnp.ndarray,  # [L, k // 32, n] f32, or [k // 32, n]
+    layer=None,  # int32 scalar: which layer of a stack
     block_n: int = 256,
     block_k: int = 4096,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Pallas quantized matmul on 2D operands; returns [m, n] f32.
+    """Pallas quantized matmul of 2D activations with one layer of a weight
+    stack; returns [m, n] f32.
 
     Default blocks are the round-3 silicon sweep winner (scripts/
     kernel_sweep.py on v5e, m=1 k=4096 n=14336): (bn=256, bk=4096) ran
@@ -345,32 +409,11 @@ def qmatmul_2d(
     for XLA's dense bf16 matvec on the same shape — narrow n tiles with
     the whole k per step keep the accumulator live and the weight DMAs
     tall; wider tiles hit the 16 MB scoped-VMEM ceiling."""
-    m, k = x.shape
-    n = q.shape[1]
-    assert q.shape == (k, n) and d.shape == (k // Q_BLOCK, n), (q.shape, d.shape)
-    bn = _pick_block(n, block_n, ragged=True)
-    bk = _pick_block(k, block_k)
-    assert bk % Q_BLOCK == 0
     if d.dtype != jnp.float32:
         d = d.astype(jnp.float32)
-
-    n_k = k // bk
-    bm = min(m, BLOCK_M)
-    # k innermost: the accumulator tile stays live
-    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), n_k)
-    return pl.pallas_call(
-        functools.partial(_qmm_kernel, n_k=n_k),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda r, i, j: (r, j)),
-            pl.BlockSpec((bk, bn), lambda r, i, j: (j, i)),
-            pl.BlockSpec((bk // Q_BLOCK, bn), lambda r, i, j: (j, i)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda r, i, j: (r, i)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x.astype(jnp.bfloat16), q, d)
+    return _qmm_call(
+        _qmm_kernel, x, q, d, layer, 1, block_n, block_k, interpret
+    )
 
 
 @functools.partial(
@@ -378,82 +421,61 @@ def qmatmul_2d(
 )
 def qmatmul_i4_2d(
     x: jnp.ndarray,  # [m, k]
-    qp: jnp.ndarray,  # [k // 2, n] int8 packed nibbles
-    d: jnp.ndarray,  # [k // 32, n] f16
+    qp: jnp.ndarray,  # [L, k // 2, n] int8 packed nibbles, or [k // 2, n]
+    d: jnp.ndarray,  # [L, k // 32, n] f16, or [k // 32, n]
+    layer=None,
     block_n: int = 256,
     block_k: int = 4096,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Pallas packed-nibble quantized matmul; returns [m, n] f32.
 
-    Same grid/accumulator structure as `qmatmul_2d` (k innermost so the
-    output tile stays live in VMEM scratch); the weight BlockSpec moves
+    Same call as `qmatmul_2d` (`_qmm_call`); the weight BlockSpec moves
     half the rows because each byte carries two values. Block defaults
     inherit the int8 sweep winner — at equal (bn, bk) the packed DMA is
     half the bytes, so the VMEM ceiling moves further out, and the
     staged silicon sweep re-tunes on hardware."""
-    m, k = x.shape
-    n = qp.shape[1]
-    assert qp.shape == (k // 2, n) and d.shape == (k // Q_BLOCK, n), (
-        qp.shape,
-        d.shape,
-    )
     assert d.dtype == jnp.float16, d.dtype
-    bn = _pick_block(n, block_n, ragged=True)
-    bk = _pick_block(k, block_k)
-    assert bk % Q_BLOCK == 0
-
-    n_k = k // bk
-    bm = min(m, BLOCK_M)
-    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), n_k)
-    return pl.pallas_call(
-        functools.partial(_qmm_i4_kernel, n_k=n_k),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda r, i, j: (r, j)),
-            pl.BlockSpec((bk // 2, bn), lambda r, i, j: (j, i)),
-            pl.BlockSpec((bk // Q_BLOCK, bn), lambda r, i, j: (j, i)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda r, i, j: (r, i)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x.astype(jnp.bfloat16), qp, jax.lax.bitcast_convert_type(d, jnp.int16))
+    return _qmm_call(
+        _qmm_i4_kernel, x, qp, jax.lax.bitcast_convert_type(d, jnp.int16),
+        layer, 2, block_n, block_k, interpret,
+    )
 
 
 def _use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def qmatmul(x: jnp.ndarray, w, block_n: int = 256) -> jnp.ndarray:
+def qmatmul(x: jnp.ndarray, w, layer=None, block_n: int = 256) -> jnp.ndarray:
     """x [..., in] @ W -> [..., out] f32, auto-flattening leading dims.
 
-    Accepts QuantWeight (int8 values) or PackedQuantWeight (nibble-packed).
+    Accepts QuantWeight (int8 values) or PackedQuantWeight (nibble-packed),
+    [in, out], or a [L, in, out] stack with the `layer` to take.
     Dispatches to the matching Pallas kernel on TPU; off-TPU (CPU test
     meshes) uses the dequant reference path — pallas interpret mode is
     orders of magnitude slower and numerically identical anyway.
     """
     *lead, k = x.shape
     if not _use_pallas():
-        return qmatmul_ref(x, w)
+        return qmatmul_ref(x, w, layer)
     m = 1
     for s in lead:
         m *= s
-    if isinstance(w, PackedQuantWeight):
-        out = qmatmul_i4_2d(x.reshape(m, k), w.qp, w.d, block_n=block_n)
-    else:
-        out = qmatmul_2d(x.reshape(m, k), w.q, w.d, block_n=block_n)
+    kernel = qmatmul_i4_2d if isinstance(w, PackedQuantWeight) else qmatmul_2d
+    out = kernel(x.reshape(m, k), *w, layer, block_n=block_n)
     return out.reshape(*lead, w.out_dim)
 
 
 def qmatmul_tp(
     x: jnp.ndarray,  # [B, T, in]
-    w,  # QuantWeight | PackedQuantWeight [in, out] (+ scales), tp-shardable
+    w,  # QuantWeight | PackedQuantWeight [in, out] (+ scales), tp-shardable,
+    #   or the [L, in, out] stack when `layer` is given
     role: str,  # "row" (out split) | "col" (in split, partial-sum psum)
     mesh=None,
     sync_quant: bool = False,  # Q80-compress the col-split partial-sum
     #   all-reduce payload (the reference's --buffer-float-type q80; see
     #   parallel/collectives.psum_q80) — for DCN multi-host, not ICI
+    layer=None,  # int32 scalar: which layer of the stack
 ) -> jnp.ndarray:
     """Tensor-parallel quantized matmul.
 
@@ -463,14 +485,15 @@ def qmatmul_tp(
     row-split needs no collective (the all-gather the reference does per
     block is deferred to the residual psum), col-split partial sums psum
     over ICI exactly where the reference ran SYNC_NODE_SLICES + OP_MERGE_ADD
-    (src/llm.cpp:403,554).
+    (src/llm.cpp:403,554). A stack's layer axis is on no mesh axis and the
+    layer number is replicated.
 
     Off TPU this degrades to the dequant einsum and lets GSPMD shard it.
     """
     if not _use_pallas():
-        return qmatmul_ref(x, w)
+        return qmatmul_ref(x, w, layer)
     if mesh is None or mesh.devices.size == 1:
-        return qmatmul(x, w)
+        return qmatmul(x, w, layer)
 
     from jax.sharding import PartitionSpec as P
 
@@ -480,37 +503,34 @@ def qmatmul_tp(
     # shard identically: the packed in/2 axis and the in/32 scale axis
     # both divide by tp under the engine's 32*tp divisibility check
     cls = type(w)
-    values, scales = w
+    stack = (None,) * (w[0].ndim - 2)
+    at = () if layer is None else (jnp.asarray(layer, jnp.int32),)
 
     if role == "row":
-        in_specs = (
-            P("dp", None, None),
-            P(None, "tp"),
-            P(None, "tp"),
-        )
+        x_spec, w_spec = P("dp", None, None), P(*stack, None, "tp")
         out_spec = P("dp", None, "tp")
 
-        def f(xx, qq, dd):
-            return qmatmul(xx, cls(qq, dd))
+        def f(xx, qq, dd, *ll):
+            return qmatmul(xx, cls(qq, dd), *ll)
 
     elif role == "col":
         from ..parallel.collectives import psum_maybe_quantized
 
-        in_specs = (
-            P("dp", None, "tp"),
-            P("tp", None),
-            P("tp", None),
-        )
+        x_spec, w_spec = P("dp", None, "tp"), P(*stack, "tp", None)
         out_spec = P("dp", None, None)
 
-        def f(xx, qq, dd):
+        def f(xx, qq, dd, *ll):
             return psum_maybe_quantized(
-                qmatmul(xx, cls(qq, dd)), "tp", sync_quant
+                qmatmul(xx, cls(qq, dd), *ll), "tp", sync_quant
             )
 
     else:
         raise ValueError(f"unknown role: {role}")
 
     return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_spec, check_vma=False
-    )(x, values, scales)
+        f,
+        mesh=mesh,
+        in_specs=(x_spec, w_spec, w_spec) + (P(),) * len(at),
+        out_specs=out_spec,
+        check_vma=False,
+    )(x, *w, *at)

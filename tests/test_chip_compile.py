@@ -109,6 +109,96 @@ def test_qmatmul_prefill_rows(one_chip, k, n):
     compiled_text(qmatmul_2d, *q40_args(2048, k, n, one_chip))
 
 
+def q40_stack(n_layers, k, n, s):
+    from dllama_tpu.ops.quant_matmul import QuantWeight
+
+    return QuantWeight(
+        sds((n_layers, k, n), jnp.int8, s),
+        sds((n_layers, k // 32, n), jnp.float32, s),
+    )
+
+
+def weight_copies(text: str) -> list[str]:
+    """HLO instructions that would materialise int8 weights: a slice or a
+    copy whose result is s8. (A `bitcast`, a merge of leading axes, moves
+    nothing.)"""
+    return [
+        line.strip()[:160]
+        for line in text.splitlines()
+        if " s8[" in line.split("=", 1)[-1][:24]
+        and any(op in line for op in ("dynamic-slice(", " copy(", " slice("))
+    ]
+
+
+def test_layer_scan_reads_weight_stacks_in_place(one_chip, monkeypatch):
+    """A scan over the layer number that closes over Mistral-7B's FFN stacks
+    (`s8[32,4096,28672]`, `s8[32,14336,4096]`): the kernels take the stacks
+    whole, so the compiled loop holds no slice or copy of an int8 weight.
+    As the scan's `xs` each layer was copied out (a `dynamic-slice` with an
+    `s8[1,4096,28672]` result) before the kernel read it again."""
+    from jax import lax
+
+    from dllama_tpu.ops import quant_matmul as qm
+
+    monkeypatch.setattr(qm, "_use_pallas", lambda: True)  # no chip here
+    n_layers = 32
+
+    def f(x, w13, w2):
+        def step(x, l):
+            up = qm.qmatmul(x, w13, l).astype(x.dtype)
+            y = qm.qmatmul(up[..., :FF] * up[..., FF:], w2, l)
+            return x + y.astype(x.dtype), None
+
+        return lax.scan(step, x, jnp.arange(n_layers, dtype=jnp.int32))[0]
+
+    text = compiled_text(
+        jax.jit(f),
+        sds((5, 1, D), jnp.bfloat16, one_chip),
+        q40_stack(n_layers, D, 2 * FF, one_chip),
+        q40_stack(n_layers, FF, D, one_chip),
+    )
+    assert text.count("tpu_custom_call") == 2
+    assert not weight_copies(text), weight_copies(text)
+
+
+@pytest.mark.parametrize("kernel", ["active", "grouped"])
+def test_moe_q40_expert_stacks_in_place(one_chip, kernel):
+    """Qwen3-30B-A3B's expert stacks of 12 layers, `s8[12,128,2048,768]`,
+    with a layer number: viewed as [L*E, ...] (a bitcast) and indexed by
+    offset ids, never a copy of a layer's 128 experts."""
+    from jax import lax
+
+    from dllama_tpu.ops import moe_kernel as mk
+
+    n_layers, d, f, e, k = 12, 2048, 768, 128, 8
+    n = 16 if kernel == "active" else 8192
+    fn = (
+        mk.moe_active_experts_q40
+        if kernel == "active"
+        else mk.moe_grouped_experts_q40
+    )
+    w13 = (sds((n_layers, e, d, f), jnp.int8, one_chip),
+           sds((n_layers, e, d // 32, f), jnp.float32, one_chip))
+    w2 = (sds((n_layers, e, f, d), jnp.int8, one_chip),
+          sds((n_layers, e, f // 32, d), jnp.float32, one_chip))
+
+    def run(x, w1q, w1d, w2q, w2d, w3q, w3d, ii, ww):
+        def step(x, l):
+            y = fn(x, w1q, w1d, w2q, w2d, w3q, w3d, ii, ww, l)
+            return x + y.astype(x.dtype), None
+
+        return lax.scan(step, x, jnp.arange(n_layers, dtype=jnp.int32))[0]
+
+    text = compiled_text(
+        jax.jit(run),
+        sds((n, d), jnp.bfloat16, one_chip),
+        *w13, *w2, *w13,
+        sds((n, k), jnp.int32, one_chip),
+        sds((n, k), jnp.float32, one_chip),
+    )
+    assert not weight_copies(text), weight_copies(text)
+
+
 @pytest.mark.parametrize("role", ["row", "col"])
 def test_qmatmul_tp4(tp4, monkeypatch, role):
     """The FFN splits at tp=4 under shard_map: the kernel is there per
@@ -131,6 +221,70 @@ def test_qmatmul_tp4(tp4, monkeypatch, role):
     )
     n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
     assert n_reduce == (1 if role == "col" else 0), text
+
+    # a layer's weights: the [L, k, n] stack and a replicated layer number
+    def g(x, q, d, l):
+        return qm.qmatmul_tp(x, qm.QuantWeight(q, d), role, tp4, layer=l)
+
+    stack = NamedSharding(tp4, P(None, *w_spec))
+    text = compiled_text(
+        jax.jit(g),
+        sds((1, 1, k), jnp.bfloat16, NamedSharding(tp4, x_spec)),
+        sds((4, k, n), jnp.int8, stack),
+        sds((4, k // 32, n), jnp.float32, stack),
+        sds((), jnp.int32, NamedSharding(tp4, P())),
+    )
+    n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
+    assert n_reduce == (1 if role == "col" else 0), text
+    assert not weight_copies(text), weight_copies(text)
+
+
+@pytest.mark.parametrize("layout", ["device", "row_major"])
+@pytest.mark.parametrize("helper", ["pallas", "grouped"])
+def test_moe_q40_expert_stacks_tp4(tp4, helper, layout):
+    """Qwen3-30B-A3B's expert stacks split four ways on F under the MoE
+    helpers' shard_map, `s8[12,128,2048,768]` with a leading `None` in the
+    spec and a replicated layer number: each shard's kernel is there and
+    the partial outputs pay one all-reduce. A shard's 192 columns are
+    stored column-major by the device (`{2,3,1,0}`; so does the chip:
+    PERF.md section 6, PR 25), and the program turns the w1 and w3 stacks
+    row-major for the kernel, whole and once a program, where the scan's
+    `xs` turned one layer a step. Placed row-major nothing is copied."""
+    from jax.experimental.layout import Format, Layout
+
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.ops.quant_matmul import QuantWeight
+
+    n_layers, d, f, e, k = 12, 2048, 768, 128, 8
+    rows = 16 if helper == "pallas" else 512
+    fn = tf._moe_ffn_pallas if helper == "pallas" else tf._moe_ffn_grouped
+    row = NamedSharding(tp4, P(None, None, None, "tp"))
+    col = NamedSharding(tp4, P(None, None, "tp", None))
+    rep = NamedSharding(tp4, P())
+    if layout == "row_major":
+        row_major = Layout(major_to_minor=(0, 1, 2, 3))
+        row, col = Format(row_major, row), Format(row_major, col)
+
+    def w(k_in, n_out, s):
+        return QuantWeight(
+            sds((n_layers, e, k_in, n_out), jnp.int8, s),
+            sds((n_layers, e, k_in // 32, n_out), jnp.float32, s),
+        )
+
+    def run(x, gate, w1, w2, w3, l):
+        return fn(x, gate, w1, w2, w3, k, tp4, layer=l)
+
+    text = compiled_text(
+        jax.jit(run),
+        sds((1, rows, d), jnp.bfloat16, rep),
+        sds((d, e), jnp.bfloat16, rep),
+        w(d, f, row), w(f, d, col), w(d, f, row),
+        sds((), jnp.int32, rep),
+    )
+    n_reduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
+    assert n_reduce == 1, n_reduce
+    turned = weight_copies(text)
+    assert len(turned) == (2 if layout == "device" else 0), turned
 
 
 def test_lm_head_tp4(tp4, monkeypatch):

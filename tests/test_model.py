@@ -329,3 +329,65 @@ def test_streamed_loader_memory_bound(tmp_path):
     # and it must beat the stack path by at least the biggest stack
     # (w13: 4 layers x 8192 x 57344 int8 ~ 1.9 GB)
     assert stacked["hwm_gb"] - streamed["hwm_gb"] > 1.0, (stacked, streamed)
+
+
+def _layer_scans(jaxpr):
+    """Every `scan` equation of a jaxpr, sub-jaxprs included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _layer_scans(sub)
+
+
+@pytest.mark.parametrize(
+    "arch,weight_format,fuse",
+    [
+        (LlmArch.LLAMA, "q40", 0),
+        (LlmArch.LLAMA, "q40", 2),
+        (LlmArch.LLAMA, "q40i4", 0),
+        (LlmArch.QWEN3_MOE, "q40", 0),
+    ],
+)
+def test_layer_scan_does_not_slice_quantized_stacks(
+    tmp_path, arch, weight_format, fuse
+):
+    """The layer scan's `xs` hold no quantized weight or scale stack: they
+    are constants of the scan, handed whole to the kernels with the layer
+    number. As `xs`, XLA would copy every layer's slice out of the stack
+    before the Pallas call reads it (2 x 218 MB a Mistral-7B layer; PERF.md,
+    PR 25). This is the guard a CPU run can give."""
+    import jax
+
+    from dllama_tpu.models.transformer import _is_quant_stack
+
+    path = str(tmp_path / "m.m")
+    make_tiny_model(path, arch=arch, weight_type=FloatType.Q40, seed=3)
+    r = ModelReader(path)
+    params = load_params(r, weight_format=weight_format, fuse=fuse)
+    h = r.header
+    stacks = {
+        k: v for k, v in params["layers"].items() if _is_quant_stack(v)
+    }
+    assert {"w2", "wo"} <= set(stacks)
+    assert ("wqkv" in stacks and "w13" in stacks) if fuse else "w1" in stacks
+    quantized = {
+        (a.dtype, a.shape[1:]) for a in jax.tree.leaves(stacks)
+    }
+    tokens = jnp.asarray([TOKENS], dtype=jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, c: forward(p, h, tokens, jnp.int32(0), c)
+    )(params, init_kv_cache(h, 1)).jaxpr
+    (scan,) = [
+        e for e in _layer_scans(jaxpr) if e.params["length"] == h.n_layers
+    ]
+    first_x = scan.params["num_consts"] + scan.params["num_carry"]
+    consts = [v.aval for v in scan.invars[: scan.params["num_consts"]]]
+    xs = [v.aval for v in scan.invars[first_x:]]
+    assert xs and all(a.shape[0] == h.n_layers for a in xs)
+    sliced = [a for a in xs if (a.dtype, a.shape[1:]) in quantized]
+    assert not sliced, f"quantized stacks among the layer scan's xs: {sliced}"
+    whole = {(a.dtype, a.shape[1:]) for a in consts if a.ndim >= 3}
+    assert quantized <= whole, quantized - whole

@@ -332,6 +332,48 @@ def test_qmatmul_tp_row_fused_shard_map(monkeypatch):
         )
 
 
+@pytest.mark.parametrize("arch", [LlmArch.LLAMA, LlmArch.QWEN3_MOE])
+def test_tp2_ids_equal_tp1_through_stacked_shard_map(tmp_path, monkeypatch, arch):
+    """The layer scan closes over the quantized [L, in, out] stacks and
+    hands `qmatmul_tp` the stack and a layer number: under `shard_map` the
+    stacks' specs gain a leading None and the layer is replicated. Off-TPU
+    the dispatcher bypasses shard_map, so force it (Pallas entry stubbed
+    with the reference matmul, which takes the same `(stack, layer)`);
+    greedy ids at tp=2 must equal tp=1's."""
+    from dllama_tpu.ops import quant_matmul as qm
+    from dllama_tpu.runtime.engine import InferenceEngine
+
+    seen = []
+
+    def stub(x, w, layer=None, block_n=256):
+        seen.append((w[0].ndim, layer is not None))
+        return qm.qmatmul_ref(x, w, layer)
+
+    monkeypatch.setattr(qm, "_use_pallas", lambda: True)
+    monkeypatch.setattr(qm, "qmatmul", stub)
+
+    path = str(tmp_path / "m.m")
+    cfg = dict(dim=128, hidden_dim=256, n_layers=3, n_heads=8, n_kv_heads=4,
+               head_dim=16, vocab_size=256, seq_len=64)
+    if arch == LlmArch.QWEN3_MOE:
+        cfg.update(n_experts=4, n_active_experts=2, moe_hidden_dim=64)
+    make_tiny_model(path, arch=arch, weight_type=FloatType.Q40, cfg=cfg)
+    prompt = [1, 2, 3, 4, 5, 6, 7]
+    e1 = InferenceEngine(path, tp=1, dtype=jnp.float32, temperature=0.0,
+                         weight_format="q40")
+    expected, _, _ = e1.generate(prompt, max_steps=16)
+    del e1
+    seen.clear()
+    e2 = InferenceEngine(path, tp=2, dtype=jnp.float32, temperature=0.0,
+                         weight_format="q40")
+    got, _, _ = e2.generate(prompt, max_steps=16)
+    assert got == expected
+    # inside the shard_map: layer weights as 3-D stacks with their layer
+    # number, the vocabulary head as the 2-D weight it is
+    assert (3, True) in seen and (2, False) in seen
+    assert all(stacked == (ndim == 3) for ndim, stacked in seen)
+
+
 def test_engine_sp_windowed_decode_parity(tmp_path):
     """sp=2 with a seq_len large enough that decode windows engage
     (window = 512*sp < seq_len): the cyclic cache layout must keep exact

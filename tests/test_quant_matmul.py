@@ -351,3 +351,94 @@ def test_fused_packed_matches_split():
         np.testing.assert_allclose(
             np.asarray(part), np.asarray(expect), rtol=0, atol=1e-5
         )
+
+
+# ------------------------------------------------- stacks and a layer number
+
+STACK_L = 3
+
+
+def _stacked(make, n, k):
+    """`STACK_L` weights of one shape as one [L, ...] stack, leaf by leaf."""
+    ws = [make(n, k, seed=40 + l) for l in range(STACK_L)]
+    return type(ws[0])(*(jnp.stack(leaves) for leaves in zip(*ws)))
+
+
+def _stacked_experts(out_dim, in_dim, n_experts, seed):
+    """QuantWeight [L, E, in, out]."""
+    per_layer = [
+        [make_qw(out_dim, in_dim, seed=seed + 10 * l + e)[0] for e in range(n_experts)]
+        for l in range(STACK_L)
+    ]
+    return QuantWeight(
+        *(
+            jnp.stack([jnp.stack([getattr(w, f) for w in ws]) for ws in per_layer])
+            for f in ("q", "d")
+        )
+    )
+
+
+@pytest.mark.parametrize("layer", [0, 1, STACK_L - 1])
+@pytest.mark.parametrize(
+    "kind", ["q40", "q40i4", "fused", "ref", "moe_active", "moe_grouped"]
+)
+def test_stack_and_layer_equals_the_layers_slice(kind, layer):
+    """The kernels take a whole [L, ...] stack and a layer number (the layer
+    scan closes over the stacks, models/transformer.run_layers): the result
+    is that of the same kernel on the layer's own slice, bit for bit, since
+    only the block specs' index maps differ. n = 320 has no 128-multiple
+    divisor, so the q40 cases run the ragged tail block too."""
+    from dllama_tpu.ops.moe_kernel import (
+        moe_active_experts_q40,
+        moe_grouped_experts_q40,
+    )
+    from dllama_tpu.ops.quant_matmul import (
+        FusedQuantWeight,
+        layer_of,
+        qmatmul_i4_2d,
+    )
+
+    rng = np.random.default_rng(50 + layer)
+    at = jnp.int32(layer)
+    if kind in ("q40", "q40i4", "fused", "ref"):
+        k, n = 256, 320
+        x = jnp.asarray(rng.standard_normal((5, k)).astype(np.float32))
+        if kind == "q40i4":
+            stack = _stacked(lambda *a, **kw: make_packed(*a, **kw)[0], n, k)
+            kernel = qmatmul_i4_2d
+        else:
+            stack = _stacked(lambda *a, **kw: make_qw(*a, **kw)[0], n, k)
+            kernel = qmatmul_2d
+        if kind == "fused":  # the fused wrapper carries the stack as it is
+            stack = FusedQuantWeight(stack, 2, (160, 160)).weight
+        if kind == "ref":  # the off-chip path takes the same (stack, layer)
+            got = qmatmul_ref(x, stack, at)
+            want = qmatmul_ref(x, layer_of(stack, layer))
+        else:
+            got = kernel(x, *stack, at, interpret=True)
+            want = kernel(x, *layer_of(stack, layer), interpret=True)
+        assert got.shape == (5, n)
+    else:
+        E, D, F, K = 4, 64, 256, 2
+        m = 3 if kind == "moe_active" else 40
+        kernel = (
+            moe_active_experts_q40
+            if kind == "moe_active"
+            else moe_grouped_experts_q40
+        )
+        w1 = _stacked_experts(F, D, E, 100)  # q [L, E, D, F]
+        w3 = _stacked_experts(F, D, E, 200)
+        w2 = _stacked_experts(D, F, E, 300)  # q [L, E, F, D]
+        x = jnp.asarray(rng.standard_normal((m, D)).astype(np.float32))
+        top_i = jnp.asarray(
+            np.stack([rng.permutation(E)[:K] for _ in range(m)]).astype(np.int32)
+        )
+        wts = jnp.asarray(rng.random((m, K)).astype(np.float32))
+        got = kernel(x, *w1, *w2, *w3, top_i, wts, at, interpret=True)
+        want = kernel(
+            x, *layer_of(w1, layer), *layer_of(w2, layer), *layer_of(w3, layer),
+            top_i, wts, interpret=True,
+        )
+        assert got.shape == (m, D)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.abs(np.asarray(got)).max() > 0
