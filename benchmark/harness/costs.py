@@ -1,5 +1,15 @@
 """Operations and bytes the algorithm needs, from a configuration's shapes.
 
+The formulas are a family's own: `benchmark/costs/<family>.py` gives
+`decode_step_bytes`, `prefill_flops` and `weights_per_token`, found by the
+configuration's `family` as its reference is, and the three functions of
+those names here hand over to it. A family that brings no such file has no
+floor: `family_costs` gives None, and a reader that divides by one returns
+None and never another family's number. What any family may count with stays
+here: the peaks, the bytes of a Q40 weight and of a cached key or value, the
+weights of a grouped-query attention layer and of a SwiGLU, and how many
+experts a batch of tokens touches.
+
 These are floors, not what the program happens to move: weights count at the
 Q40 file's 18 bytes per 32 weights (the information the model holds; the
 program keeps int8 + f32 block scales, 36 bytes per 32, so its decode step
@@ -11,6 +21,7 @@ so a share computed from them is a lower bound too.
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 
@@ -49,32 +60,36 @@ def distinct_experts(n_experts: int, top_k: int, tokens: float) -> float:
     return n_experts * (1.0 - (1.0 - top_k / n_experts) ** tokens)
 
 
+def family_costs(cfg: dict):
+    """`benchmark/costs/<family>.py`, or None where the family brings none."""
+    name = f"benchmark.costs.{cfg['family']}"
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        return None
+
+
+def _family(cfg: dict):
+    mod = family_costs(cfg)
+    if mod is None:
+        raise LookupError(f"family {cfg['family']!r} has no benchmark/costs/{cfg['family']}.py")
+    return mod
+
+
 def weights_per_token(cfg: dict) -> int:
     """Matmul weights one token's forward pass multiplies by."""
-    ffn = expert_weights(cfg) * cfg.get("num_experts_per_tok", 1)
-    router = cfg["hidden_size"] * cfg.get("num_experts", 0)
-    per_layer = attention_weights(cfg) + ffn + router
-    return cfg["num_hidden_layers"] * per_layer + cfg["hidden_size"] * cfg["vocab_size"]
+    return _family(cfg).weights_per_token(cfg)
 
 
 def decode_step_bytes(cfg: dict, live_lanes: float, context: float) -> float:
     """Bytes one decode step has to read for `live_lanes` sequences with
     `context` positions each in cache."""
-    e = cfg.get("num_experts", 0)
-    if e:
-        ffn = distinct_experts(e, cfg["num_experts_per_tok"], live_lanes) * expert_weights(cfg)
-        router = 4 * cfg["hidden_size"] * e
-    else:
-        ffn, router = expert_weights(cfg), 0
-    layer = (attention_weights(cfg) + ffn) * Q40_BYTES_PER_WEIGHT + router
-    kv_row = 2 * cfg["num_key_value_heads"] * head_dim(cfg) * KV_BYTES
-    layer += live_lanes * context * kv_row
-    head = cfg["hidden_size"] * cfg["vocab_size"] * Q40_BYTES_PER_WEIGHT
-    return cfg["num_hidden_layers"] * layer + head
+    return _family(cfg).decode_step_bytes(cfg, live_lanes, context)
 
 
 def prefill_flops(cfg: dict, rows: int) -> float:
     """Multiply-adds x 2 of the weight matmuls over `rows` token rows (the
     head runs on one row per lane and is left out)."""
-    head = cfg["hidden_size"] * cfg["vocab_size"]
-    return 2.0 * (weights_per_token(cfg) - head) * rows
+    return _family(cfg).prefill_flops(cfg, rows)

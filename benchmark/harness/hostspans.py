@@ -26,9 +26,9 @@ without a device. A trace of a program without the
 annotations (the parent of the PR that added them) gives no table, and the
 readers return None.
 
-No cell lists the five readers built on this file yet (`METRICS`; a cell's
-metrics are the `per_layer` names of its `workloads/<name>.json`), so a
-traced run's result line lacks them. After such a run,
+The five readers built on this file (`METRICS`) are per-layer metrics of
+`mistral7b-chat` and `qwen3moe-decode-sat`, so a traced run's result line
+carries them. For a run directory that is already there,
 
     python3 -m benchmark.harness.hostspans benchmark/work/run-<cell>
 

@@ -9,14 +9,16 @@ timed; its table is the reason for `gap_tol` in the configuration files.
 
 Prints one line per (seed, length, alone|concurrent) with the largest and
 rms gap in units of the reference's logit std. With `--power`, the first
-seed's served ids are also checked against two deliberately wrong
-references (rotary base 1e4; the keys of positions 512-1023 zeroed): what
-the rule reads when the model code is wrong.
+seed's served ids are also checked against each deliberately wrong reference
+that the family's module names in `FAULTS` (for `dense_gqa`: rotary base 1e4;
+the keys of positions 512-1023 zeroed): what the rule reads when the model
+code is wrong.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import random
@@ -35,18 +37,22 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
-def zero_keys(dense_gqa, lo: int, hi: int):
-    """Patch the reference's attention to drop the keys of [lo, hi)."""
-    import jax
+POWER_SAMPLES = 4  # requests checked against each wrong reference
 
-    real = dense_gqa.attention
 
-    def faulty(q, k, v):
-        return real(q, k.at[lo:hi].set(0.0), v)
-
-    dense_gqa.attention = faulty
-    jax.clear_caches()
-    return real
+def power(cfg: dict, model: str, samples: list[dict], compare) -> None:
+    """One line per request and fault of the family's `FAULTS`: a dict is
+    laid over the configuration, anything else is entered around the check."""
+    for name, fault in getattr(compare.reference_for(cfg), "FAULTS", {}).items():
+        over = isinstance(fault, dict)
+        can_show = [s for s in samples
+                    if len(s["prompt_ids"]) > getattr(fault, "min_prompt", 0)]
+        with contextlib.nullcontext() if over else fault:
+            reports = compare.check({**cfg, **fault} if over else cfg, model,
+                                    can_show[:POWER_SAMPLES])
+        for rep in reports:
+            log(f"power {name} {rep['id']}: max_gap {rep['max_gap_std']:.3f} "
+                f"top1 {rep['top1_share']:.3f}")
 
 
 def main() -> int:
@@ -70,7 +76,6 @@ def main() -> int:
     import run as bench
     from benchmark.harness import client, compare, weights, xplane
     from benchmark.harness.server import Served
-    from benchmark.references import dense_gqa
     from dllama_tpu.parallel.mesh import enable_compilation_cache
 
     cfg = bench.load_config(args.config, args.rehearse)
@@ -151,17 +156,7 @@ def main() -> int:
                 f"max_gap {rep['max_gap_std']:.4f} rms {rep['rms_gap_std']:.4f} "
                 f"top1 {rep['top1_share']:.3f} worst_at {rep['worst_at']}")
         if args.power and n_seed == 0:
-            wrong = dict(cfg, rope_theta=1e4)
-            for rep in compare.check(wrong, model, samples[:3]):
-                log(f"power rope_theta=1e4 {rep['id']}: max_gap {rep['max_gap_std']:.3f} "
-                    f"top1 {rep['top1_share']:.3f}")
-            real = zero_keys(dense_gqa, 512, 1024)
-            long = [s for s in samples if len(s["prompt_ids"]) > 1024]
-            for rep in compare.check(cfg, model, long[:4]):
-                log(f"power keys[512:1024]=0 {rep['id']}: max_gap {rep['max_gap_std']:.3f} "
-                    f"top1 {rep['top1_share']:.3f}")
-            dense_gqa.attention = real
-            jax.clear_caches()
+            power(cfg, model, samples, compare)
         shutil.rmtree(work, ignore_errors=True)
     worst = max(r["max_gap_std"] for r in rows)
     gaps = sorted(g for r in rows for g in r["gaps"])

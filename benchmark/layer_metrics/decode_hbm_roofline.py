@@ -13,6 +13,8 @@ def read(run_dir):
     if not m or not steps:
         return None
     w, cfg = rundir.window(run_dir), rundir.config(run_dir)
+    if costs.family_costs(cfg) is None:  # no floor for this family: no share of one
+        return None
     need = sum(
         e["n_steps"] * costs.decode_step_bytes(cfg, e["n_live"], w["mean_context"])
         for e in steps)

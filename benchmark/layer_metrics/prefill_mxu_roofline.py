@@ -13,6 +13,8 @@ def read(run_dir):
     if not m or not chunks:
         return None
     w, cfg = rundir.window(run_dir), rundir.config(run_dir)
+    if costs.family_costs(cfg) is None:  # no floor for this family: no share of one
+        return None
     need = sum(costs.prefill_flops(cfg, w["lanes"] * e["bucket"]) for e in chunks)
     peak = costs.peaks(w["device_kind"])["bf16_flops_per_s"] * w["chips"]
     return 100.0 * need / peak / (m[0] / m[1] * len(chunks))

@@ -20,6 +20,12 @@ Departures from the published code, all forced by what is compared:
   shapes compile; causal masking keeps every real position independent of
   the padding. Attention runs over query blocks of QB rows against all keys
   up to the block's end, which changes memory, not arithmetic.
+
+`FAULTS` names the ways this reference can be made wrong on purpose, so that
+`ladder.py --power` and the tests can show what the comparison reads when the
+model code is wrong: a dict of configuration overrides, or a context manager
+that breaks the module and mends it on exit. A fault that shows only past
+some position says so in `min_prompt`.
 """
 
 from __future__ import annotations
@@ -71,6 +77,28 @@ def attention(q, k, v):
         p = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
         outs.append(jnp.einsum("kgbt,tkd->bkgd", p, v[:e], precision=HI))
     return jnp.concatenate(outs).reshape(t, n_heads * hd)
+
+
+class ZeroedKeys:
+    """While entered, `attention` drops the keys of positions [lo, hi): a
+    chunk of the context that never reached the cache."""
+
+    def __init__(self, lo: int, hi: int):
+        self.lo, self.hi, self.min_prompt = lo, hi, hi
+
+    def __enter__(self):
+        global attention
+        self._real, lo, hi = attention, self.lo, self.hi
+        attention = lambda q, k, v: self._real(q, k.at[lo:hi].set(0.0), v)  # noqa: E731
+        jax.clear_caches()  # `layer` is jitted with the real one inside
+
+    def __exit__(self, *exc):
+        global attention
+        attention = self._real
+        jax.clear_caches()
+
+
+FAULTS = {"rope_theta=1e4": {"rope_theta": 1e4}, "keys[512:1024]=0": ZeroedKeys(512, 1024)}
 
 
 def attention_block(x, w, *, n_heads, n_kv_heads, head_dim, eps, theta, pairing):

@@ -28,6 +28,8 @@ from . import dense_gqa
 from .dense_gqa import HI, attention_block, rms_norm, swiglu
 from .q40file import Q40File
 
+FAULTS = dense_gqa.FAULTS  # this family's attention is `dense_gqa.attention`
+
 
 def routing_weights(y, gate, top_k, renormalise):
     """[T, E] weights, zero outside each token's top_k experts."""

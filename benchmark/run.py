@@ -60,17 +60,25 @@ def load_json(*parts: str) -> dict:
         return json.load(f)
 
 
+def rehearsal_of(cfg: dict) -> dict:
+    """A configuration cut to the tiny widths, where no tolerance has been
+    measured and nothing is judged. Sizes that must shrink with them and that
+    TINY does not know are the configuration's own `rehearse`: keys laid over
+    the file's, those of its `file` group one by one."""
+    over = cfg.get("rehearse", {})
+    return {
+        **cfg, **TINY, **(TINY_MOE if cfg.get("num_experts") else {}),
+        "assumed": {**cfg.get("assumed", {}), "head_dim": TINY["head_dim"]},
+        **{k: v for k, v in over.items() if k != "file"},
+        "file": {**cfg["file"], **over.get("file", {})},
+        "gap_tol": None,
+    }
+
+
 def load_config(name: str, rehearse: bool) -> dict:
-    """A configuration file; for a rehearsal, cut to the tiny widths, where
-    no tolerance has been measured and nothing is judged."""
+    """A configuration file; for a rehearsal, cut to the tiny widths."""
     cfg = load_json("configs", f"{name}.json")
-    if rehearse:
-        cfg.update(TINY)
-        cfg.setdefault("assumed", {})["head_dim"] = TINY["head_dim"]
-        if cfg.get("num_experts"):
-            cfg.update(TINY_MOE)
-        cfg["gap_tol"] = None
-    return cfg
+    return rehearsal_of(cfg) if rehearse else cfg
 
 
 def load_cell(name: str, rehearse: bool) -> tuple[dict, dict, dict]:
@@ -204,6 +212,13 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     cell, cfg, traffic = load_cell(args.workload, args.rehearse)
+    from benchmark.harness import weights  # no JAX yet
+
+    try:  # an arch or a header key the program lacks: before the chip is touched
+        weights.header_for(cfg)
+    except ValueError as e:
+        log(f"benchmark: {e}; no result")
+        return 2
 
     real_stdout = sys.stdout
     with contextlib.redirect_stdout(sys.stderr):

@@ -1,7 +1,6 @@
 """The comparison rule passes the program's honest output and fails three
 injected faults, at the `tiny` widths on the CPU."""
 
-import jax
 import numpy as np
 import pytest
 
@@ -49,17 +48,10 @@ def test_reference_with_rotary_base_1e4_fails(served):
     assert rep["passed"] is False and rep["max_gap_std"] > 2 * TOL, rep
 
 
-def test_an_earlier_chunks_keys_zeroed_fails(served, monkeypatch):
+def test_an_earlier_chunks_keys_zeroed_fails(served):
     cfg, model, prompt, ids = served
-    real = dense_gqa.attention
-    monkeypatch.setattr(
-        dense_gqa, "attention", lambda q, k, v: real(q, k.at[8:24].set(0.0), v))
-    jax.clear_caches()
-    try:
+    with dense_gqa.ZeroedKeys(8, 24):
         rep = report(cfg, model, prompt, ids)
-    finally:
-        monkeypatch.undo()
-        jax.clear_caches()
     assert rep["passed"] is False and rep["max_gap_std"] > 2 * TOL, rep
 
 

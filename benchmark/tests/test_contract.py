@@ -55,19 +55,23 @@ def test_configuration_file_matches_its_entry(entry):
     assert any(w["config"] == entry["name"] for w in SPEC["workloads"])
 
 
-def test_catalog_numbers_are_kept():
-    """Every number of the catalog's SDAR-30B-A3B-Chat row, under its key,
-    except what `reduced` lists."""
+@pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
+def test_catalog_numbers_are_kept(entry):
+    """A configuration whose `source` is a catalog row's `source_url` holds
+    every value of the row's `config` under its key, except what `reduced`
+    lists; one from elsewhere says so."""
     catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
     if not os.path.exists(catalog):
         pytest.skip("no catalog here")
     with open(catalog) as f:
-        row = next(r for r in map(json.loads, f) if r["name"] == "SDAR-30B-A3B-Chat")
-    cfg = bench.load_json("configs", "qwen3-30b-a3b-l12.json")
-    assert cfg["source"] == row["source_url"]
-    for key, value in row["config"].items():
-        if key not in cfg["reduced"]:
-            assert cfg[key] == value, key
+        rows = [r for r in map(json.loads, f) if r["source_url"] == entry["source"]]
+    cfg = bench.load_json("configs", f"{entry['name']}.json")
+    if not rows:
+        assert cfg["assumed"]["not_in_catalog"]
+    for row in rows:
+        for key, value in row["config"].items():
+            if key not in cfg["reduced"]:
+                assert cfg[key] == value, key
 
 
 def test_four_chip_cells_are_a_quarter_at_most():

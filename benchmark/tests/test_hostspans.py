@@ -191,16 +191,16 @@ def test_the_five_readers_on_a_fixture(run_dir):
     ("layer_scan_copy_pct", ("kernels", "%", "lower", "device_trace")),
 ])
 def test_a_reader_states_the_entry_it_will_have(metric, entry):
-    """What `test_contract.py` holds a reader to once `BENCHMARK.json` has
-    its entry: layers named as the file's other metrics name them."""
+    """What `test_contract.py` holds a reader to against `BENCHMARK.json`:
+    layers named as the file's other metrics name them."""
     reader = bench.layer_reader(metric)
     assert (reader.LAYER, reader.UNIT, reader.BETTER, reader.SOURCE) == entry
     assert reader.MOVES == "tpot_p95_ms"
 
 
 def test_report_prints_the_five_as_a_result_line_would(run_dir, tmp_path_factory):
-    """No cell lists the five yet, so `python3 -m benchmark.harness.hostspans
-    <run_dir>` is how a traced run's numbers are read."""
+    """`python3 -m benchmark.harness.hostspans <run_dir>` reads a run
+    directory that is already there."""
     assert tuple(NEW) == hostspans.METRICS
     got = hostspans.report(run_dir)
     assert list(got) == list(NEW)
