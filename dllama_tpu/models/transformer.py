@@ -54,8 +54,9 @@ from ..ops.flash_attention import flash_attention, pick_flash_blocks
 from ..ops.kv_cache import (
     QuantKV,
     dequant_kv,
+    layer_rows,
     quantize_kv_rows,
-    slice_kv as _slice_kv,
+    write_rows,
 )
 from ..ops.moe_kernel import (
     moe_active_experts,
@@ -169,19 +170,35 @@ def init_kv_cache(
     }
 
 
+def _use_flash(t: int, rows: int) -> bool:
+    """The Pallas flash kernels run on the chip alone, and where blocks
+    divide the query and key row counts."""
+    return (
+        jax.default_backend() == "tpu"
+        and pick_flash_blocks(t, rows) is not None
+    )
+
+
 def _attention_tp(
     q: jnp.ndarray,  # [B, T, H, hd]
-    k_cache: jnp.ndarray,  # [B, KH, S, hd]
-    v_cache: jnp.ndarray,  # [B, KH, S, hd]
+    k_cache: jnp.ndarray,  # [L, B, KH, S, hd]: the whole stack
+    v_cache: jnp.ndarray,
+    layer: jnp.ndarray,  # int32 scalar: the layer to read
     pos: jnp.ndarray,
     head_dim: int,
     mesh,
-    attn_window: int = 0,  # sp only: global window, sliced per sp shard
+    attn_window: int = 0,  # read the first `attn_window` rows only (0 = all)
 ) -> jnp.ndarray:
     """Attention dispatch on TPU: XLA dense attention for T=1 decode over
-    the (window-sliced) cache, the prefill flash kernel for T >= 8
+    the window's rows of the layer, the prefill flash kernel for T >= 8
     (blockwise online softmax, no [T, S] score materialization — the
     long-context replacement for multiheadAtt_F32), einsum elsewhere.
+
+    Either way layer `layer` is read where it lies in the stack the layer
+    scan carries: decode takes one `dynamic_slice` of the window's rows
+    (`layer_rows`; the most XLA can materialise is what attention reads
+    anyway), the flash kernel takes the stack whole with the layer number
+    and the window as its row count. Nothing copies a layer out first.
 
     Decode deliberately does NOT use the Pallas flash-decode kernel: the
     round-3 silicon probe showed (a) Mosaic does
@@ -189,7 +206,7 @@ def _attention_tp(
     so the kernel reads the WHOLE cache every step regardless of pos, and
     (b) XLA's own dense T=1 attention is faster on the same cache
     (0.25 vs 0.40 ms/iter on a 33 MB cache). O(pos) decode reads come
-    from the engine's bucketed attn_window slicing instead — the O(pos)
+    from the engine's bucketed attn_window instead — the O(pos)
     property of the reference's decode attention
     (src/nn/nn-cpu-ops.cpp:753-788) lives in the window, not the kernel.
 
@@ -203,39 +220,47 @@ def _attention_tp(
         # slice their local window first, then dequant — so int8 + sp
         # reads stay windowed AND int8-sized across the boundary
         return _attention_sp(
-            q, k_cache, v_cache, pos, head_dim, mesh,
+            q, k_cache, v_cache, layer, pos, head_dim, mesh,
             attn_window=attn_window,
         )
-    on_tpu = jax.default_backend() == "tpu"
-    s = k_cache.shape[2]
-    if on_tpu and t >= 8 and pick_flash_blocks(t, s) is not None:
-        # QuantKV rides into the kernel natively (int8 planes + [bs, 1]
-        # scale refs; dequant on the VMEM tile) — int8 prefill reads
-        # ~half the HBM bytes of bf16 and never materializes a dense
-        # cache copy. The scale-ref BlockSpec compiles on the v5e and
-        # agrees with the plain path there (chip_smoke.py --kv-dtype int8).
-        kernel = flash_attention  # handles scalar and per-lane pos
-    else:
-        k_cache = dequant_kv(k_cache, q.dtype)
-        v_cache = dequant_kv(v_cache, q.dtype)
-        return _attention(q, k_cache, v_cache, pos, head_dim)
+    s = k_cache.shape[3]
+    rows = attn_window if 0 < attn_window < s else s
+    if not (t >= 8 and _use_flash(t, rows)):
+        # a QuantKV is dequantised after the slice: window-sized
+        return _attention(
+            q,
+            dequant_kv(layer_rows(k_cache, layer, rows), q.dtype),
+            dequant_kv(layer_rows(v_cache, layer, rows), q.dtype),
+            pos, head_dim,
+        )
+    # QuantKV rides into the kernel natively (int8 planes + [bs, 1]
+    # scale refs; dequant on the VMEM tile) — int8 prefill reads
+    # ~half the HBM bytes of bf16 and never materializes a dense
+    # cache copy. The scale-ref BlockSpec compiles on the v5e and
+    # agrees with the plain path there (chip_smoke.py --kv-dtype int8).
     n_heads = q.shape[2]
+
+    def kernel(qq, kk, vv, pp, ll):  # handles scalar and per-lane pos
+        return flash_attention(qq, kk, vv, pp, layer=ll, rows=rows)
+
     if mesh is None or mesh.devices.size == 1:
-        out = kernel(q, k_cache, v_cache, pos)
+        out = kernel(q, k_cache, v_cache, pos, layer)
     else:
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec_q = P("dp", None, "tp", None)
-        spec_kv = P("dp", "tp", None, None)
+        # the stack's spec, as the weight stacks': a leading None, and the
+        # layer number replicated
+        spec_kv = P(None, "dp", "tp", None, None)
         pos_spec = P("dp") if per_lane else P()
         out = shard_map(
-            lambda qq, kk, vv, pp: kernel(qq, kk, vv, pp),
+            kernel,
             mesh=mesh,
-            in_specs=(spec_q, spec_kv, spec_kv, pos_spec),
+            in_specs=(spec_q, spec_kv, spec_kv, pos_spec, P()),
             out_specs=spec_q,
             check_vma=False,
-        )(q, k_cache, v_cache, pos)
+        )(q, k_cache, v_cache, pos, layer)
     return out.reshape(b, t, n_heads * head_dim)
 
 
@@ -282,8 +307,9 @@ def _attention_sp_merge(
 
 def _attention_sp(
     q: jnp.ndarray,  # [B, T, H, hd]
-    k_cache: jnp.ndarray,  # [B, KH, S, hd] — S sharded over "sp", CYCLIC
+    k_cache: jnp.ndarray,  # [L, B, KH, S, hd] — S sharded over "sp", CYCLIC
     v_cache: jnp.ndarray,
+    layer: jnp.ndarray,  # int32 scalar, replicated
     pos: jnp.ndarray,
     head_dim: int,
     mesh,
@@ -309,7 +335,9 @@ def _attention_sp(
     `attn_window` (a multiple of sp) slices every shard's LOCAL prefix to
     window/sp rows before attending — O(pos) decode reads on the
     long-context axis, the same engine-window mechanism the sp=1 path
-    uses.
+    uses. Each shard takes those rows of layer `layer` out of its part of
+    the stack (`layer_rows`); the ring needs them as a buffer of its own
+    to rotate.
 
     Heads stay tp-sharded inside the same shard_map — attention needs no
     tp collectives (reference: sliceMultiHeadAtt head independence)."""
@@ -319,7 +347,7 @@ def _attention_sp(
     from ..parallel.ring_attention import ring_attention_local
 
     b, t, n_heads = q.shape[0], q.shape[1], q.shape[2]
-    s = k_cache.shape[2]
+    s = k_cache.shape[3]
     sp = mesh.shape["sp"]
     shard = s // sp
     w_loc = 0
@@ -329,7 +357,7 @@ def _attention_sp(
                 f"attn_window {attn_window} must be a multiple of sp={sp}"
             )
         w_loc = attn_window // sp
-    kv_spec = P("dp", "tp", "sp", None)
+    kv_spec = P(None, "dp", "tp", "sp", None)
     per_lane = jnp.ndim(pos) == 1
     pos_spec = P("dp") if per_lane else P()
 
@@ -341,9 +369,9 @@ def _attention_sp(
         # schedule does not actually elide copies on Mosaic — so the
         # Pallas local step (flash_decode_stats) buys nothing here
 
-        def body(qq, kk, vv, pp):
-            kk = dequant_kv(_slice_kv(kk, w_loc), qq.dtype)
-            vv = dequant_kv(_slice_kv(vv, w_loc), qq.dtype)
+        def body(qq, kk, vv, pp, ll):
+            kk = dequant_kv(layer_rows(kk, ll, w_loc), qq.dtype)
+            vv = dequant_kv(layer_rows(vv, ll, w_loc), qq.dtype)
             return _attention_sp_merge(qq, kk, vv, pp, "sp", sp)
 
     else:
@@ -358,16 +386,13 @@ def _attention_sp(
         # the windowed local prefix, shrinking payloads with the window.
         tq_local = t // sp
         rows_local = w_loc or shard
-        use_flash = (
-            jax.default_backend() == "tpu"
-            and pick_flash_blocks(tq_local, rows_local) is not None
-        )
+        use_flash = _use_flash(tq_local, rows_local)
 
-        def body(qq, kk, vv, pp):
+        def body(qq, kk, vv, pp, ll):
             idx = lax.axis_index("sp")
             tq = qq.shape[1]
-            kk = _slice_kv(kk, w_loc)
-            vv = _slice_kv(vv, w_loc)
+            kk = layer_rows(kk, ll, w_loc)
+            vv = layer_rows(vv, ll, w_loc)
             return ring_attention_local(
                 qq, kk, vv,
                 q_pos0=pp + idx * tq,
@@ -380,10 +405,10 @@ def _attention_sp(
     out = shard_map(
         body,
         mesh=mesh,
-        in_specs=(q_spec, kv_spec, kv_spec, pos_spec),
+        in_specs=(q_spec, kv_spec, kv_spec, pos_spec, P()),
         out_specs=q_spec,
         check_vma=False,
-    )(q, k_cache, v_cache, pos)
+    )(q, k_cache, v_cache, pos, layer)
     return out.reshape(b, t, n_heads * head_dim)
 
 
@@ -865,11 +890,15 @@ def run_layers(
 ):
     """`lax.scan` the decoder layers over x; returns (x, k_new, v_new).
 
-    The scan runs over the layer number, the caches and the small or dense
-    per-layer leaves. Quantized weight stacks (`_is_quant_stack`) are not
-    among its `xs`: a Pallas call is opaque to XLA, which would copy every
-    layer's slice out of the stack before the kernel reads it again. The
-    step closes over them and hands the kernels `(stack, l)`.
+    The scan runs over the layer number and the small or dense per-layer
+    leaves. Quantized weight stacks (`_is_quant_stack`) are not among its
+    `xs`: a Pallas call is opaque to XLA, which would copy every layer's
+    slice out of the stack before the kernel reads it again. The step
+    closes over them and hands the kernels `(stack, l)`. The caches are
+    the scan's carry, whole: a step writes its chunk's rows into layer `l`
+    of the stack (`write_rows`, in place) and attention reads that layer
+    where it lies (`_attention_tp`), so no layer's cache is sliced out of
+    the stack or written back.
 
     Factored out of `forward` so the pipeline-parallel driver
     (parallel/pipeline.py) can run a STAGE'S LOCAL layer slice with
@@ -918,88 +947,81 @@ def run_layers(
     stacks = {k: v for k, v in layers.items() if _is_quant_stack(v)}
     sliced = {k: v for k, v in layers.items() if k not in stacks}
 
-    def _cache_append(cache_l, val):
-        """Write the chunk at each lane's position (reference: OP_SHIFT,
-        src/nn/nn-cpu-ops.cpp:1419-1441) -> dynamic_update_slice on the
-        head-major cache's S axis, vmapped over lanes when positions
-        differ. `val` arrives [B, T, KH, hd] from the projection. An
-        int8 cache (QuantKV) quantizes the rows once here and routes
-        values and scales through the SAME positional writer (the scale
-        leaf's trailing singleton keeps ranks equal)."""
+    def _cache_append(cache, l, val):
+        """Write the chunk into layer `l` of the carried stack at each
+        lane's position (reference: OP_SHIFT,
+        src/nn/nn-cpu-ops.cpp:1419-1441): a `dynamic_update_slice` of the
+        chunk's rows alone (`write_rows`; one a lane when positions
+        differ), which XLA applies to the carry in place. `val` arrives
+        [B, T, KH, hd] from the projection. An int8 cache (QuantKV)
+        quantizes the rows once here and routes values and scales through
+        the SAME positional writer (the scale leaf's trailing singleton
+        keeps ranks equal)."""
         val = val.transpose(0, 2, 1, 3)  # [B, KH, T, hd]
-        if isinstance(cache_l, QuantKV):
+        if isinstance(cache, QuantKV):
             qv, sv = quantize_kv_rows(val)
             return QuantKV(
-                _positional_write(cache_l.q, qv),
-                _positional_write(cache_l.s, sv),
+                _positional_write(cache.q, l, qv),
+                _positional_write(cache.s, l, sv),
             )
-        return _positional_write(cache_l, val.astype(cache_l.dtype))
+        return _positional_write(cache, l, val)
 
-    def _positional_write(cache_l, val):
+    def _positional_write(cache, l, val):
         if sp_axis is not None:
-            return _cache_append_sp(cache_l, val)
+            return _cache_append_sp(cache, l, val)
         if _sp_mesh > 1:
-            return _cache_append_cyclic(cache_l, val)
-        if per_lane:
-            return jax.vmap(
-                lambda c, u, p: lax.dynamic_update_slice_in_dim(c, u, p, axis=1)
-            )(cache_l, val, pos)
-        return lax.dynamic_update_slice_in_dim(cache_l, val, pos, axis=2)
+            return _cache_append_cyclic(cache, l, val)
+        return write_rows(cache, l, pos, val)
 
-    def _cache_append_cyclic(cache_l, val):
+    def _cache_append_cyclic(cache, l, val):
         """Flat-mesh sp write in the cyclic layout: global row g lives at
-        axis index (g % sp) * shard_rows + g // sp. T == 1 stays a single
-        dynamic_update_slice at the permuted index; T > 1 scatters the
-        chunk's rows to their permuted indices (GSPMD routes each row to
+        axis index (g % sp) * shard_rows + g // sp. T == 1 stays a row
+        update at the permuted index; T > 1 scatters the chunk's rows to
+        their permuted indices of layer `l` (GSPMD routes each row to
         its owning shard)."""
 
         def perm(g):
             return (g % _sp_mesh) * _shard_rows + g // _sp_mesh
 
         if t == 1:
-            if per_lane:
-                return jax.vmap(
-                    lambda c, u, p: lax.dynamic_update_slice_in_dim(
-                        c, u, perm(p), axis=1
-                    )
-                )(cache_l, val, pos)
-            return lax.dynamic_update_slice_in_dim(
-                cache_l, val, perm(pos), axis=2
-            )
+            return write_rows(cache, l, perm(pos), val)
         rows = jnp.arange(t, dtype=jnp.int32)
-        if per_lane:
-            return jax.vmap(
-                lambda c, u, p: c.at[:, perm(p + rows)].set(u)
-            )(cache_l, val, pos)
-        return cache_l.at[:, :, perm(pos + rows)].set(val)
+        # the index arrays lead the result's axes: [B, T] then [KH, hd]
+        val = val.transpose(0, 2, 1, 3).astype(cache.dtype)
+        lanes = jnp.arange(b, dtype=jnp.int32)[:, None]
+        starts = pos[:, None] if per_lane else pos
+        return cache.at[l, lanes, :, perm(starts + rows)].set(val)
 
-    def _cache_append_sp(cache_l, val):
+    def _cache_append_sp(cache, l, val):
         """Owning-shard window write for the manual (pp x sp) path with
         the CYCLIC layout: this shard's local row j holds global position
         j*sp_n + sp_idx, so a chunk [p, p+T) touches a contiguous local
         range of <= T//sp_n + 1 rows; a fixed sp_win-row window at the
         clamped local start covers the whole overlap, per-row validity +
-        a gather route each chunk row to its slot. O(T/sp rows) per
-        shard — no whole-slab select, no cross-shard collective."""
+        a gather route each chunk row to its slot. The window is read out
+        of layer `l` of the carried stack and written back to it:
+        O(T/sp rows) per shard — no whole-slab select, no cross-shard
+        collective. `pos` a scalar or [B]: the trailing axes broadcast."""
+        jstart = jnp.clip(
+            (pos - sp_idx + sp_n - 1) // sp_n, 0, shard_s - sp_win
+        )
+        cur = layer_rows(cache, l, sp_win, jstart)  # [B, KH, sp_win, hd]
+        gpos = (
+            jstart[..., None] + jnp.arange(sp_win, dtype=jnp.int32)
+        ) * sp_n + sp_idx
+        # chunk row belonging at each window row, [sp_win] or [B, sp_win]
+        r = jnp.broadcast_to(gpos - pos[..., None], (b, sp_win))
+        ok = jnp.logical_and(r >= 0, r < t)[:, None, :, None]
+        gathered = jnp.take_along_axis(
+            val, jnp.clip(r, 0, t - 1)[:, None, :, None], axis=2
+        )
+        return write_rows(
+            cache, l, jstart, jnp.where(ok, gathered.astype(cur.dtype), cur)
+        )
 
-        def write(c, u, p):  # c [KH, S_local, hd], u [KH, T, hd], p scalar
-            jstart = jnp.clip(
-                (p - sp_idx + sp_n - 1) // sp_n, 0, shard_s - sp_win
-            )
-            cur = lax.dynamic_slice_in_dim(c, jstart, sp_win, axis=1)
-            gpos = (jstart + jnp.arange(sp_win, dtype=jnp.int32)) * sp_n + sp_idx
-            r = gpos - p  # chunk row belonging at each window row
-            ok = jnp.logical_and(r >= 0, r < t)
-            gathered = jnp.take(u, jnp.clip(r, 0, t - 1), axis=1)
-            upd = jnp.where(ok[None, :, None], gathered, cur)
-            return lax.dynamic_update_slice_in_dim(c, upd, jstart, axis=1)
-
-        if per_lane:
-            return jax.vmap(write)(cache_l, val, pos)
-        return jax.vmap(lambda c, u: write(c, u, pos))(cache_l, val)
-
-    def layer_step(x, layer):
-        lp, l, k_cache_l, v_cache_l = layer
+    def layer_step(carry, layer):
+        x, k_cache, v_cache = carry
+        lp, l = layer
         lp = {**lp, **stacks}
 
         def mm(yy, w, role, sync=False):
@@ -1045,9 +1067,11 @@ def run_layers(
             q = apply_rope(q, cos, sin, interleaved)
             k = apply_rope(k, cos, sin, interleaved)
 
+        # write first, then read the updated stack: nothing else holds
+        # the carry, so the rows land in place
         with jax.named_scope("kv_write"):
-            k_cache_l = _cache_append(k_cache_l, k)
-            v_cache_l = _cache_append(v_cache_l, v)
+            k_cache = _cache_append(k_cache, l, k)
+            v_cache = _cache_append(v_cache, l, v)
 
         with jax.named_scope("attn"):
             if sp_axis is not None:
@@ -1066,27 +1090,16 @@ def run_layers(
                 )
                 z = _attention_sp_merge(
                     q,
-                    dequant_kv(_slice_kv(k_cache_l, w_rows), x.dtype),
-                    dequant_kv(_slice_kv(v_cache_l, w_rows), x.dtype),
+                    dequant_kv(layer_rows(k_cache, l, w_rows), x.dtype),
+                    dequant_kv(layer_rows(v_cache, l, w_rows), x.dtype),
                     attn_pos, sp_axis, sp_n,
                 ).reshape(b, t, hq * h.head_dim)
             else:
-                # flat non-sp: plain prefix slice (QuantKV rides sliced-but-
-                # quantized into _attention_tp, which dequants at entry); the
+                # the window's rows of layer `l`, read where they lie; the
                 # sp mesh path windows inside _attention_sp per shard
-                w_flat = (
-                    attn_window
-                    if attn_window
-                    and attn_window < k_cache_l.shape[2]
-                    and _sp_mesh == 1
-                    else 0
-                )
                 z = _attention_tp(
-                    q,
-                    _slice_kv(k_cache_l, w_flat),
-                    _slice_kv(v_cache_l, w_flat),
-                    attn_pos, h.head_dim, mesh,
-                    attn_window=attn_window if _sp_mesh > 1 else 0,
+                    q, k_cache, v_cache, l, attn_pos, h.head_dim, mesh,
+                    attn_window=attn_window,
                 )
             x = x + mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
 
@@ -1179,17 +1192,19 @@ def run_layers(
                 l3 = mm(y, lp["w3"], "row")
                 f = mm(d * l3.astype(d.dtype), lp["w2"], "col", sync=True)
             x = x + f.astype(x.dtype)
-        return x, (k_cache_l, v_cache_l)
+        return (x, k_cache, v_cache), None
 
     # scopes name the device's operations in a profile (`op_name`) and
     # change nothing that is compiled: what runs under `layers` but under
-    # no scope of `layer_step` is the scan's own slicing of its `xs`, the
-    # caches above all
+    # no scope of `layer_step` is the scan's own slicing of its `xs`,
+    # which are the norms, a dense model's weights and the layer number.
+    # The caches are the scan's carry: as `xs` and `ys` every layer's whole
+    # lane cache was copied out of the stack and back to write a row a lane
     n_layers = jax.tree.leaves(k_cache)[0].shape[0]
     with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(
+        (x, k_new, v_new), _ = lax.scan(
             layer_step,
-            x,
-            (sliced, jnp.arange(n_layers, dtype=jnp.int32), k_cache, v_cache),
+            (x, k_cache, v_cache),
+            (sliced, jnp.arange(n_layers, dtype=jnp.int32)),
         )
     return x, k_new, v_new

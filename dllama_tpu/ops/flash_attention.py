@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kv_cache import QuantKV
+from .kv_cache import QuantKV, layer_rows
 
 _NEG_INF = -1e30
 
@@ -71,10 +71,11 @@ def attention_ref(
 def _flash_stats_kernel(
     pos_ref,  # SMEM scalar prefetch: [B] int32 per-lane q start positions
     spos_ref,  # SMEM scalar prefetch: [1] int32 (s_pos0)
+    l_ref,  # SMEM scalar prefetch: [1] int32 layer, read by the index maps
     q_ref,  # [1, bt, hd]
-    k_ref,  # [1, 1, bs, hd] — one head's (seq, hd) plane
+    k_ref,  # [1, 1, bs, hd] — one head's (seq, hd) plane of that layer
     v_ref,  # [1, 1, bs, hd]
-    *rest,  # quant_kv: (ks_ref [1,1,bs,1], vs_ref [1,1,bs,1]); then
+    *rest,  # quant_kv: (ks_ref [1,1,bs,128], vs_ref [1,1,bs,128]); then
     #         outputs (acc_out [1,bt,hd], m_out [1,bt,128], l_out
     #         [1,bt,128]) and scratch (m_ref, l_ref, acc_ref)
     block_t: int,
@@ -96,9 +97,10 @@ def _flash_stats_kernel(
     s_pos0 + j*stride — the windowable sp layout, see
     models/transformer._attention_sp_merge); positions and the causal
     frontier scale by the stride. `quant_kv`: k/v tiles arrive int8 with
-    per-row f32 scales as two extra [bs, 1]-blocked refs sharing the kv
-    index map — dequant happens HERE on the VMEM tile, so HBM traffic is
-    the int8 bytes, amortized over the tile's bt queries."""
+    per-row f32 scales as two extra [bs, 128]-blocked refs (every lane
+    holds the row's scale; column 0 is read) that follow the kv index
+    map — dequant happens HERE on the VMEM tile, so HBM traffic is the
+    int8 bytes, amortized over the tile's bt queries."""
     if quant_kv:
         ks_ref, vs_ref, acc_out, m_out, l_out, m_ref, l_ref, acc_ref = rest
     else:
@@ -121,7 +123,7 @@ def _flash_stats_kernel(
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         if quant_kv:
-            k = k * ks_ref[0, 0]  # (bs, 1) per-row scales, lane-broadcast
+            k = k * ks_ref[0, 0, :, :1]  # (bs, 1) per-row scales, lane-broadcast
         scores = (
             jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -144,7 +146,7 @@ def _flash_stats_kernel(
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
         v = v_ref[0, 0].astype(jnp.float32)
         if quant_kv:
-            v = v * vs_ref[0, 0]
+            v = v * vs_ref[0, 0, :, :1]
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -161,18 +163,20 @@ def _flash_stats_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_t", "block_s", "interpret", "s_stride"),
+    static_argnames=("block_t", "block_s", "interpret", "s_stride", "rows"),
 )
 def flash_attention_stats(
     q: jnp.ndarray,  # [B, T, H, hd]
-    k: jnp.ndarray,  # [B, KH, S, hd]
-    v: jnp.ndarray,  # [B, KH, S, hd]
+    k: jnp.ndarray,  # [B, KH, S, hd], or the [L, B, KH, S, hd] cache stack
+    v: jnp.ndarray,
     q_pos0: jnp.ndarray,  # scalar or [B] int32: position of q[:, 0] per lane
     s_pos0: jnp.ndarray,  # scalar int32: absolute position of k[:, 0]
     block_t: int = 0,
     block_s: int = 0,
     interpret: bool = False,
     s_stride: int = 1,
+    layer=None,  # int32 scalar: which layer of a stack
+    rows: int = 0,  # attend to the first `rows` key rows only (0 = all S)
 ):
     """Blockwise causal GQA attention partial state: returns f32
     (acc [B, KH, G, T, hd], m [B, KH, G, T], l [B, KH, G, T]) — the same
@@ -184,18 +188,31 @@ def flash_attention_stats(
     tile shards; masks and the causal-frontier DMA clamp scale by it.
 
     `k`/`v` may be QuantKV (int8 values + f32 [.., S, 1] per-row scales):
-    the kernel then DMAs the int8 planes plus a [bs, 1]-blocked scale ref
+    the kernel then DMAs the int8 planes plus a [bs, 128]-blocked scale ref
     and dequants on the VMEM tile — int8 prefill reads ~half the HBM
     bytes of bf16 and never materializes a dense cache copy (the pre-r5
-    behavior)."""
+    behavior).
+
+    The model hands in its whole cache `[L, B, KH, S, hd]` with the `layer`
+    to read and the attention window as `rows`: the stack stays where it
+    lies in HBM, the layer number rides in as scalar prefetch and the kv
+    index maps pick the layer, and the grid covers `rows` key rows — so a
+    layer scan that carries the cache copies neither a layer nor a window
+    out of it ahead of this call (which XLA cannot fuse into). A
+    `[B, KH, S, hd]` argument (ring attention, tests) is a stack of one."""
     quant_kv = isinstance(k, QuantKV)
     if isinstance(v, QuantKV) != quant_kv:
         raise TypeError(
             f"k and v must both be QuantKV or both dense, got "
             f"k={type(k).__name__}, v={type(v).__name__}"
         )
+    if len(k.shape) == 4:
+        assert layer is None, "a layer number needs a [L, B, KH, S, hd] stack"
+        k, v = jax.tree.map(lambda a: a[None], (k, v))
+        layer = 0
     b, t, h, hd = q.shape
-    kh, s = k.shape[1], k.shape[2]
+    kh, s = k.shape[2], rows or k.shape[3]
+    assert s <= k.shape[3], (rows, k.shape)
     g = h // kh
     if not block_t or not block_s:
         picked = pick_flash_blocks(t, s)
@@ -223,11 +240,12 @@ def flash_attention_stats(
         jnp.atleast_1d(jnp.asarray(q_pos0, jnp.int32)), (b,)
     )
     spos_arr = jnp.asarray(s_pos0, jnp.int32).reshape(1)
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_map(bh, ti, si, pos_ref, spos_ref):
+    def q_map(bh, ti, si, pos_ref, spos_ref, l_ref):
         return (bh, ti, 0)
 
-    def kv_map(bh, ti, si, pos_ref, spos_ref):
+    def kv_map(bh, ti, si, pos_ref, spos_ref, l_ref):
         # clamp past the causal frontier of this query tile (fully-masked
         # tiles re-fetch the frontier block: compute is skipped but Mosaic
         # does not elide the repeated-index DMA — see module docstring);
@@ -238,27 +256,34 @@ def flash_attention_stats(
             // block_s,
             0,
         )
-        return (bh // h, (bh % h) // g, jnp.minimum(si, limit), 0)
+        return (l_ref[0], bh // h, (bh % h) // g, jnp.minimum(si, limit), 0)
 
     in_specs = [
         pl.BlockSpec((1, block_t, hd), q_map),
-        pl.BlockSpec((1, 1, block_s, hd), kv_map),
-        pl.BlockSpec((1, 1, block_s, hd), kv_map),
+        pl.BlockSpec((None, 1, 1, block_s, hd), kv_map),
+        pl.BlockSpec((None, 1, 1, block_s, hd), kv_map),
     ]
     operands = [qt, k, v]
     if quant_kv:
-        # scale refs ride the SAME index map as their value planes; the
-        # trailing dim is array-size 1 fully covered by the block (unlike
-        # the r3 blocker — a size-1 BLOCK of a larger dim in the last two
-        # dims — this tiles a genuine [.., S, 1] tensor)
-        in_specs = [
-            in_specs[0],
-            in_specs[1],
-            in_specs[2],
-            pl.BlockSpec((1, 1, block_s, 1), kv_map),
-            pl.BlockSpec((1, 1, block_s, 1), kv_map),
+        # the scales of this layer's `s` rows, sliced out of the stack and
+        # spread over the 128 lanes: the chip stores a [.., S, 1] leaf with
+        # S minor, and a kernel operand of that shape takes (8, 128) tiles,
+        # 128 times the bytes — demanded of a whole stack that the layer
+        # scan carries, that is gigabytes of temporaries (described-v5e
+        # compile, 32 x [5, 8, 4608, 1]: 6.6 GB). Spread, the operand is a
+        # shape of its own and as large as those tiles were for one layer.
+        def scale_rows(c):  # [L, B, KH, S, 1] -> [1, B, KH, s, 128]
+            r = layer_rows(c.s, layer, s)[None]
+            return jnp.broadcast_to(r, r.shape[:-1] + (128,))
+
+        def scale_map(*idx):  # the value planes' map, in a stack of one
+            return (0, *kv_map(*idx)[1:])
+
+        in_specs += [
+            pl.BlockSpec((None, 1, 1, block_s, 128), scale_map),
+            pl.BlockSpec((None, 1, 1, block_s, 128), scale_map),
         ]
-        operands = [qt, k.q, v.q, k.s, v.s]
+        operands = [qt, k.q, v.q, scale_rows(k), scale_rows(v)]
     acc, m, l = pl.pallas_call(
         functools.partial(
             _flash_stats_kernel,
@@ -271,7 +296,7 @@ def flash_attention_stats(
             quant_kv=quant_kv,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b * h, n_t, n_s),
             in_specs=in_specs,
             out_specs=[
@@ -291,7 +316,7 @@ def flash_attention_stats(
             jax.ShapeDtypeStruct((b * h, t, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(pos_arr, spos_arr, *operands)
+    )(pos_arr, spos_arr, layer_arr, *operands)
 
     # [B*H, T, ...] -> [B, KH, G, T, ...]
     acc = acc.reshape(b, kh, g, t, hd)
@@ -721,14 +746,18 @@ def paged_flash_decode(
 
 def flash_attention(
     q: jnp.ndarray,  # [B, T, H, hd]
-    k_cache: jnp.ndarray,  # [B, KH, S, hd]
-    v_cache: jnp.ndarray,  # [B, KH, S, hd]
+    k_cache: jnp.ndarray,  # [B, KH, S, hd], or [L, B, KH, S, hd] + `layer`
+    v_cache: jnp.ndarray,
     pos: jnp.ndarray,  # scalar int32, or [B] per-lane positions
     block_t: int = 0,
     block_s: int = 0,
     interpret: bool = False,
+    layer=None,
+    rows: int = 0,
 ) -> jnp.ndarray:
     """Blockwise causal GQA attention; returns [B, T, H, hd] in q.dtype.
+    `layer` and `rows`: the cache stack read in place, as
+    `flash_attention_stats` says.
 
     Implemented as normalize(flash_attention_stats(...)) so one kernel body
     serves both the dense path and ring attention's partial-state merge; the
@@ -738,6 +767,7 @@ def flash_attention(
     acc, m, l = flash_attention_stats(
         q, k_cache, v_cache, pos, 0,
         block_t=block_t, block_s=block_s, interpret=interpret,
+        layer=layer, rows=rows,
     )
     l_safe = jnp.where(l == 0.0, 1.0, l)
     out = acc / l_safe[..., None]  # [B, KH, G, T, hd]

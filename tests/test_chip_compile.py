@@ -199,6 +199,200 @@ def test_moe_q40_expert_stacks_in_place(one_chip, kernel):
     assert not weight_copies(text), weight_copies(text)
 
 
+# the benchmark's two cache shapes: [L, lanes, KH, 4096 + padding rows, hd]
+MISTRAL_CACHE = (32, 5, 8, 4608, 128)
+QWEN_CACHE = (12, 16, 4, 4608, 128)
+
+
+def cache_copies(text: str, cache: tuple[int, ...], dtype: str = "bf16"):
+    """HLO instructions that materialise one layer's whole lane cache (a
+    `dynamic-slice`, `copy`, `dynamic-update-slice` or fusion whose result
+    has a layer's shape) or copy the whole stack. Parameters, tuples and
+    bitcasts move nothing; a `dynamic-update-slice` of the STACK is the
+    in-place row write."""
+    n_layers, *layer = cache
+    one = ",".join(map(str, layer))
+    results = (f"{dtype}[{one}]", f"{dtype}[1,{one}]")
+    stack = f"{dtype}[{n_layers},{one}]"
+    moves_nothing = (
+        " parameter(", " get-tuple-element(", " bitcast(", " tuple(",
+    )
+    found = []
+    for line in text.splitlines():
+        name, _, rhs = line.strip().partition(" = ")
+        if not rhs or any(op in line for op in moves_nothing):
+            continue
+        if rhs.startswith(results) or (
+            rhs.startswith(stack) and (" copy(" in rhs or "copy" in name)
+        ):
+            found.append(line.strip()[:160])
+    return found
+
+
+def mistral_layers(n_layers, s, row=None, col=None):
+    """Mistral-7B's per-layer leaves as shapes: Q40 stacks and two norms."""
+    row, col = row or s, col or s
+    f32 = sds((n_layers, D), jnp.float32, s)
+    return dict(
+        att_norm=f32, ffn_norm=f32,
+        wq=q40_stack(n_layers, D, H * HD, row),
+        wk=q40_stack(n_layers, D, KH * HD, row),
+        wv=q40_stack(n_layers, D, KH * HD, row),
+        wo=q40_stack(n_layers, H * HD, D, col),
+        w1=q40_stack(n_layers, D, FF, row),
+        w3=q40_stack(n_layers, D, FF, row),
+        w2=q40_stack(n_layers, FF, D, col),
+    )
+
+
+def qwen_moe_layers(n_layers, s):
+    """Qwen3-30B-A3B's: 32/4 heads of 128 with q/k norm, 128 experts of 768."""
+    from dllama_tpu.ops.quant_matmul import QuantWeight
+
+    d, f, e = 2048, 768, 128
+
+    def experts(k, n):
+        return QuantWeight(
+            sds((n_layers, e, k, n), jnp.int8, s),
+            sds((n_layers, e, k // 32, n), jnp.float32, s),
+        )
+
+    return dict(
+        att_norm=sds((n_layers, d), jnp.float32, s),
+        ffn_norm=sds((n_layers, d), jnp.float32, s),
+        q_norm=sds((n_layers, HD), jnp.float32, s),
+        k_norm=sds((n_layers, HD), jnp.float32, s),
+        wq=q40_stack(n_layers, d, 32 * HD, s),
+        wk=q40_stack(n_layers, d, 4 * HD, s),
+        wv=q40_stack(n_layers, d, 4 * HD, s),
+        wo=q40_stack(n_layers, 32 * HD, d, s),
+        moe_gate=sds((n_layers, d, e), jnp.float32, s),
+        w1=experts(d, f), w3=experts(d, f), w2=experts(f, d),
+    )
+
+
+def layer_scan_text(header, layers, cache, t, window, s, cache_s=None, mesh=None):
+    """`run_layers` over `t` rows a lane at per-lane positions, the caches
+    donated as the engine's programs donate them, compiled for the chip."""
+    from dllama_tpu.models import transformer as tf
+
+    n_lanes, hd = cache[1], cache[4]
+    kv = sds(cache, jnp.bfloat16, cache_s or s)
+
+    def step(x, layers, k, v, pos, cos, sin):
+        return tf.run_layers(
+            x, layers, k, v, header, pos, pos, cos, sin,
+            mesh=mesh, attn_window=window,
+        )
+
+    return compiled_text(
+        jax.jit(step, donate_argnums=(2, 3)),
+        sds((n_lanes, t, header.dim), jnp.bfloat16, s),
+        layers, kv, kv,
+        sds((n_lanes,), jnp.int32, s),
+        sds((n_lanes, t, hd // 2), jnp.float32, s),
+        sds((n_lanes, t, hd // 2), jnp.float32, s),
+    )
+
+
+def mistral_header():
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+
+    return LlmHeader(
+        arch=LlmArch.LLAMA, dim=D, hidden_dim=FF, n_layers=32, n_heads=H,
+        n_kv_heads=KH, vocab_size=32768, seq_len=4096, head_dim=HD,
+        rope_type=RopeType.LLAMA,
+    )
+
+
+@pytest.mark.parametrize("rows,window", [(1, 1024), (512, 2048)],
+                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("model", ["mistral", "qwen3moe"])
+def test_layer_scan_writes_cache_rows_in_place(
+    one_chip, monkeypatch, model, rows, window
+):
+    """A decode step and a 512-row prefill chunk of the benchmark's two
+    configurations over their lane caches (`bf16[32,5,8,4608,128]`,
+    `bf16[12,16,4,4608,128]`): the scan carries the stacks, `kv_write` is a
+    `dynamic-update-slice` of the stack by the chunk's rows, decode
+    attention slices its window inside the dot's fusion and the flash kernel
+    takes the stack and a layer number. So nothing in the compiled loop has
+    one layer's whole cache as its result, and the stack is never copied."""
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+
+    # the program asks the backend which branches to take; no chip here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if model == "mistral":
+        header, cache = mistral_header(), MISTRAL_CACHE
+        layers = mistral_layers(32, one_chip)
+    else:
+        header, cache = LlmHeader(
+            arch=LlmArch.QWEN3_MOE, dim=2048, hidden_dim=6144,
+            moe_hidden_dim=768, n_layers=12, n_heads=32, n_kv_heads=4,
+            n_experts=128, n_active_experts=8, vocab_size=151936,
+            seq_len=4096, head_dim=HD, rope_type=RopeType.FALCON,
+            norm_epsilon=1e-6,
+        ), QWEN_CACHE
+        layers = qwen_moe_layers(12, one_chip)
+    text = layer_scan_text(header, layers, cache, rows, window, one_chip)
+    assert text.count("dynamic-update-slice(") >= 2  # K and V rows
+    assert not cache_copies(text, cache), cache_copies(text, cache)
+
+
+def test_layer_scan_writes_cache_rows_in_place_tp4(tp4, monkeypatch):
+    """The same prefill chunk at tp=4: KH is the stack's sharded axis
+    (`P(None, "dp", "tp", None, None)` into the flash kernel's `shard_map`,
+    the layer number replicated), so a shard's stack is `[32,5,2,4608,128]`
+    and no instruction has one layer of it as its result."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rep = NamedSharding(tp4, P())
+    text = layer_scan_text(
+        mistral_header(),
+        mistral_layers(
+            32, rep,
+            row=NamedSharding(tp4, P(None, None, "tp")),
+            col=NamedSharding(tp4, P(None, "tp", None)),
+        ),
+        MISTRAL_CACHE, 512, 2048, rep,
+        cache_s=NamedSharding(tp4, P(None, "dp", "tp", None, None)),
+        mesh=tp4,
+    )
+    shard = (32, 5, KH // 4, 4608, 128)
+    assert not cache_copies(text, shard), cache_copies(text, shard)
+
+
+def test_cache_copies_flags_the_caches_as_scan_xs(one_chip):
+    """The design before PR 29: the layer scan takes the caches as `xs` and
+    gives them back as `ys`. Each step then slices the layer's whole cache
+    out of the stack and writes it back whole, which is what
+    `cache_copies` is there to find."""
+    from jax import lax
+
+    n_layers, n_lanes, kh, s, hd = MISTRAL_CACHE
+
+    def old(x, k, v, pos):
+        def step(x, layer):
+            k_l, v_l = layer
+            new = x[:, None, None, :hd]  # a row a lane, [B, 1, 1, hd]
+            new = jnp.broadcast_to(new, (n_lanes, kh, 1, hd)).astype(k_l.dtype)
+            k_l = lax.dynamic_update_slice_in_dim(k_l, new, pos, axis=2)
+            v_l = lax.dynamic_update_slice_in_dim(v_l, new, pos, axis=2)
+            att = jnp.einsum("bd,bksd->bd", x[:, :hd], k_l[:, :, :1024])
+            att = att + jnp.einsum("bd,bksd->bd", x[:, :hd], v_l[:, :, :1024])
+            return x.at[:, :hd].add(att.astype(x.dtype)), (k_l, v_l)
+
+        return lax.scan(step, x, (k, v))
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(old, donate_argnums=(1, 2)).lower(
+            sds((n_lanes, D), jnp.bfloat16, one_chip),
+            sds(MISTRAL_CACHE, jnp.bfloat16, one_chip),
+            sds(MISTRAL_CACHE, jnp.bfloat16, one_chip),
+            sds((), jnp.int32, one_chip),
+        ).compile().as_text()
+    assert cache_copies(text, MISTRAL_CACHE)
+
+
 @pytest.mark.parametrize("role", ["row", "col"])
 def test_qmatmul_tp4(tp4, monkeypatch, role):
     """The FFN splits at tp=4 under shard_map: the kernel is there per

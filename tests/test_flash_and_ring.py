@@ -695,3 +695,35 @@ def test_ring_cyclic_flash_quantkv():
     np.testing.assert_allclose(
         np.asarray(run(True)), np.asarray(run(False)), rtol=1e-5, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("per_lane", [False, True], ids=["scalar", "per_lane"])
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "quantkv"])
+@pytest.mark.parametrize("layer", [0, 1, 3])
+def test_flash_reads_a_layer_of_the_stack_in_place(layer, quant, per_lane):
+    """`flash_attention(q, stack, stack, pos, layer=l, rows=w)` over the
+    whole `[L, B, KH, S, hd]` cache is the call on `stack[l][:, :, :w]`,
+    bit for bit: the layer number only moves the K/V block index, the row
+    count only the grid. (What the model used to hand the kernel was that
+    slice, copied out of the stack first.)"""
+    n_layers, b, t, h, kh, hd, s, w = 4, 2, 8, 4, 2, 16, 64, 32
+    rng = np.random.default_rng(40 + layer)
+    q = jnp.asarray(rng.standard_normal((b, t, h, hd)), jnp.float32)
+    k, v = (
+        jnp.asarray(rng.standard_normal((n_layers, b, kh, s, hd)), jnp.float32)
+        for _ in range(2)
+    )
+    if quant:
+        k, v = _quant_kv_pair(k, v)
+    pos = jnp.asarray([9, 20], jnp.int32) if per_lane else jnp.int32(13)
+    one = jax.tree.map(lambda a: a[layer][:, :, :w], (k, v))
+    kw = dict(block_t=8, block_s=8, interpret=True)
+    want = flash_attention(q, *one, pos, **kw)
+    got = flash_attention(q, k, v, pos, layer=jnp.int32(layer), rows=w, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # a window that is the whole axis, and the layer as a Python int
+    full = jax.tree.map(lambda a: a[layer], (k, v))
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention(q, k, v, pos, layer=layer, **kw)),
+        np.asarray(flash_attention(q, *full, pos, **kw)),
+    )

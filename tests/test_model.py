@@ -391,3 +391,96 @@ def test_layer_scan_does_not_slice_quantized_stacks(
     assert not sliced, f"quantized stacks among the layer scan's xs: {sliced}"
     whole = {(a.dtype, a.shape[1:]) for a in consts if a.ndim >= 3}
     assert quantized <= whole, quantized - whole
+
+
+def _filled_cache(h, batch, kv, seq_len, seed=0):
+    """A cache whose every row holds something, so a row that moved shows."""
+    import jax
+
+    rng = np.random.default_rng(seed)
+    cache = init_kv_cache(h, batch, dtype=kv, seq_len=seq_len)
+
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        return jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
+
+    return jax.tree.map(fill, cache)
+
+
+@pytest.mark.parametrize("per_lane", [False, True], ids=["scalar", "per_lane"])
+@pytest.mark.parametrize("kv", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+@pytest.mark.parametrize(
+    "arch", [LlmArch.LLAMA, LlmArch.QWEN3_MOE], ids=["dense", "sparse"]
+)
+def test_layer_scan_carries_the_cache_whole(tmp_path, arch, kv, per_lane):
+    """The caches are the layer scan's carry, every leaf whole (a
+    `QuantKV`'s values and scales alike), and are neither among its `xs`
+    nor its `ys`. As `xs` and `ys`, XLA sliced every layer's whole lane
+    cache out of the stack and wrote it back whole each step, to write one
+    row a lane (47 MB twice a Mistral-7B layer; PERF.md, PR 29)."""
+    import jax
+
+    h, params, _ = build(tmp_path, arch=arch)
+    b = 2
+    cache = init_kv_cache(h, b, dtype=kv, seq_len=h.seq_len + 8)
+    tokens = jnp.asarray([TOKENS[:2]] * b, dtype=jnp.int32)
+    pos = jnp.asarray([3, 5], jnp.int32) if per_lane else jnp.int32(3)
+    jaxpr = jax.make_jaxpr(lambda p, c: forward(p, h, tokens, pos, c))(
+        params, cache
+    ).jaxpr
+    (scan,) = [
+        e for e in _layer_scans(jaxpr) if e.params["length"] == h.n_layers
+    ]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    carry = [v.aval for v in scan.invars[n_consts : n_consts + n_carry]]
+    xs = [v.aval for v in scan.invars[n_consts + n_carry :]]
+    ys = [v.aval for v in scan.outvars[n_carry:]]
+    leaves = jax.tree.leaves(cache)
+    assert len(leaves) == (4 if kv == jnp.int8 else 2)
+    per_layer = {(a.dtype, a.shape[1:]) for a in leaves}
+    for name, avals in (("xs", xs), ("ys", ys)):
+        sliced = [
+            a for a in avals
+            if a.shape[:1] == (h.n_layers,)
+            and (a.dtype, a.shape[1:]) in per_layer
+        ]
+        assert not sliced, f"cache leaves among the layer scan's {name}: {sliced}"
+    carried = [(a.dtype, a.shape) for a in carry]
+    for a in leaves:
+        assert (a.dtype, a.shape) in carried, (a.dtype, a.shape, carried)
+        carried.remove((a.dtype, a.shape))
+
+
+@pytest.mark.parametrize("per_lane", [False, True], ids=["scalar", "per_lane"])
+@pytest.mark.parametrize("kv", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+def test_forward_moves_only_the_rows_it_writes(tmp_path, kv, per_lane):
+    """After `forward` at `pos`, every cache row outside [pos, pos + T) of
+    each lane and layer is bit-equal to what it was, and the rows inside
+    were written. A parked lane (per-lane positions) moves its padding
+    rows only."""
+    import jax
+
+    h, params, _ = build(tmp_path)
+    s, pad, t = h.seq_len, 8, 2
+    cache = _filled_cache(h, 3, kv, s + pad)
+    tokens = jnp.asarray([[7, 9], [1, 2], [4, 4]], dtype=jnp.int32)
+    if per_lane:
+        starts = [3, s, 9]  # lane 1 is parked: it writes at the padding
+        pos = jnp.asarray(starts, jnp.int32)
+    else:
+        starts = [5, 5, 5]
+        pos = jnp.int32(5)
+    _, new = forward(params, h, tokens, pos, cache, attn_park_threshold=s)
+    for old_leaf, new_leaf in zip(jax.tree.leaves(cache), jax.tree.leaves(new)):
+        old_a, new_a = np.asarray(old_leaf), np.asarray(new_leaf)
+        assert old_a.dtype == new_a.dtype and old_a.shape == new_a.shape
+        for lane, p in enumerate(starts):
+            rows = np.zeros(s + pad, bool)
+            rows[p : p + t] = True
+            np.testing.assert_array_equal(
+                new_a[:, lane][:, :, ~rows], old_a[:, lane][:, :, ~rows]
+            )
+            # uniform(0.5, 1.5) and the scales never equal a projection
+            moved = new_a[:, lane][:, :, rows] != old_a[:, lane][:, :, rows]
+            assert moved.any(axis=-1).all(), (lane, p)

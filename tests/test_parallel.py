@@ -374,6 +374,55 @@ def test_tp2_ids_equal_tp1_through_stacked_shard_map(tmp_path, monkeypatch, arch
     assert all(stacked == (ndim == 3) for ndim, stacked in seen)
 
 
+@pytest.mark.parametrize("arch", [LlmArch.LLAMA, LlmArch.QWEN3_MOE])
+def test_tp2_ids_equal_tp1_through_stacked_cache_shard_map(
+    tmp_path, monkeypatch, arch
+):
+    """Prefill attention takes the whole `[L, B, KH, S, hd]` cache, which
+    the layer scan carries, and the layer number: under `shard_map` the
+    stack's spec is `P(None, "dp", "tp", None, None)` and the layer is
+    replicated. Off-TPU the dispatcher takes the dense path, so force the
+    kernel's branch (the kernel stubbed with the reference attention over
+    the same `(stack, layer, rows)`); greedy ids at tp=2 must equal
+    tp=1's."""
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.ops.flash_attention import attention_ref
+    from dllama_tpu.ops.kv_cache import layer_rows
+    from dllama_tpu.runtime.engine import InferenceEngine
+
+    seen = []
+
+    def stub(q, k, v, pos, layer=None, rows=0):
+        seen.append((k.shape, layer is not None, rows))
+        return attention_ref(
+            q, layer_rows(k, layer, rows), layer_rows(v, layer, rows), pos
+        )
+
+    path = str(tmp_path / "m.m")
+    cfg = dict(dim=128, hidden_dim=256, n_layers=3, n_heads=8, n_kv_heads=4,
+               head_dim=16, vocab_size=256, seq_len=64)
+    if arch == LlmArch.QWEN3_MOE:
+        cfg.update(n_experts=4, n_active_experts=2, moe_hidden_dim=64)
+    make_tiny_model(path, arch=arch, weight_type=FloatType.Q40, cfg=cfg)
+    prompt = list(range(1, 20))
+    e1 = InferenceEngine(path, tp=1, dtype=jnp.float32, temperature=0.0,
+                         weight_format="q40")
+    expected, _, _ = e1.generate(prompt, max_steps=28)
+    del e1
+    monkeypatch.setattr(tf, "_use_flash", lambda t, rows: True)
+    monkeypatch.setattr(tf, "flash_attention", stub)
+    e2 = InferenceEngine(path, tp=2, dtype=jnp.float32, temperature=0.0,
+                         weight_format="q40")
+    got, _, _ = e2.generate(prompt, max_steps=28)
+    assert got == expected
+    # inside the shard_map: every layer of the cache, this shard's half of
+    # the 4 kv heads, and a layer number with it
+    assert seen and all(
+        shape[0] == 3 and shape[2] == 2 and layered
+        for shape, layered, _ in seen
+    ), seen
+
+
 def test_engine_sp_windowed_decode_parity(tmp_path):
     """sp=2 with a seq_len large enough that decode windows engage
     (window = 512*sp < seq_len): the cyclic cache layout must keep exact

@@ -391,7 +391,14 @@ def test_forward_pp_park_cuts_decode_bytes(tmp_path):
 
     b_sel = compiled_bytes(0)
     b_park = compiled_bytes(s)
-    assert b_park < 0.75 * b_sel, (b_park, b_sel)
+    # the select reads the stage's old and new cache and writes one: three
+    # passes over a stage's share (the cost analysis counts a loop's body
+    # once). A ratio of the totals would also move with everything else a
+    # step reads, as it did when the layer scan stopped copying the cache.
+    stage_cache = sum(
+        a.nbytes for a in jax.tree.leaves(init_kv_cache(h, 1, seq_len=s + 8))
+    ) // 4
+    assert b_sel - b_park >= 0.9 * 3 * stage_cache, (b_park, b_sel, stage_cache)
 
 
 def test_engine_pp_x_dp_matches_single_device(tmp_path):
