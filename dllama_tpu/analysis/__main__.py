@@ -43,8 +43,8 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument(
         "targets", nargs="*",
-        help="files/directories to lint (default: dllama_tpu/, bench.py, "
-             "launch.py, scripts/)",
+        help="files/directories to lint (default: dllama_tpu/, launch.py, "
+             "scripts/)",
     )
     ap.add_argument(
         "--rules", default="",

@@ -104,7 +104,7 @@ class Repo:
         return None
 
 
-DEFAULT_TARGETS = ("dllama_tpu", "bench.py", "launch.py", "scripts")
+DEFAULT_TARGETS = ("dllama_tpu", "launch.py", "scripts")
 _SKIP_DIRS = {"__pycache__", ".git", "node_modules"}
 
 

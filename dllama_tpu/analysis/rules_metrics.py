@@ -8,8 +8,8 @@ over this rule. Semantics are unchanged:
 
 * source side — static scan of ``counter("dllama_...")`` /
   ``gauge(`` / ``histogram(`` registration calls across ``dllama_tpu/``
-  and ``bench.py`` (registrations span lines, so the regex runs over
-  whole file contents). Dynamically named metrics (the telemetry
+  (registrations span lines, so the regex runs over whole file
+  contents). Dynamically named metrics (the telemetry
   Counter's f-string template) have no literal name at the registration
   site and stay out of scope;
 * doc side — every backticked ``dllama_*`` identifier in
@@ -40,9 +40,7 @@ def registered_names(repo: Repo) -> dict[str, tuple[str, int]]:
     """metric name -> (path, line) of its first registration site."""
     names: dict[str, tuple[str, int]] = {}
     for mod in repo.modules:
-        if not (
-            mod.rel.startswith("dllama_tpu/") or mod.rel == "bench.py"
-        ):
+        if not mod.rel.startswith("dllama_tpu/"):
             continue
         for m in _REGISTRATION.finditer(mod.text):
             line = mod.text.count("\n", 0, m.start()) + 1

@@ -151,11 +151,10 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
     p.add_argument("--gpu-index", type=int, default=None)
     p.add_argument("--gpu-segments", default=None)
     p.add_argument("--weight-format", default="auto",
-                   choices=["auto", "q40", "q40i8", "q40i4", "dense"],
+                   choices=["auto", "q40", "q40i4", "dense"],
                    help="q40 keeps weights block-quantized on device "
-                        "(Pallas kernel); q40i8 requantizes to grouped "
-                        "int8 for MXU integer dots; q40i4 stores packed "
-                        "nibbles (0.56 B/weight, in-kernel unpack)")
+                        "(Pallas kernel); q40i4 stores packed nibbles "
+                        "(0.56 B/weight, in-kernel unpack)")
     p.add_argument("--profile", default=None, metavar="DIR",
                    help="write a jax.profiler trace of the run to DIR")
     p.add_argument("--trace-out", default=None, metavar="PATH",
@@ -373,7 +372,6 @@ def load_engine(args):
     )
     print_roofline_report(
         h, engine.weight_format, tp=tp, pp=pp,
-        i8_group=engine.i8_group or 512,
         spec_k=spec_k_val if spec_mode != "off" else 0,
     )
     # live per-chip memory vs the analytic figure: a >10% gap logs a

@@ -269,15 +269,14 @@ def load_params(
     the nibble device format (`PackedQuantWeight`: two int4 values per
     byte + f16 scales, 0.5625 B/weight) host-side during the load; the
     Pallas kernel unpacks in VMEM after the HBM copy. MoE expert weights
-    stay int8 `QuantWeight` (the ragged MoE kernels consume that layout),
-    same policy as q40i8's requantize.
+    stay int8 `QuantWeight` (the ragged MoE kernels consume that layout).
 
     `fuse` (quantized path only): the tp shard count; > 0 emits fused
     "wqkv" (q|k|v) and, for dense-FFN archs, "w13" (w1|w3) weights in
     shard-major interleaved layout instead of the separate tensors —
     decode drops from 7 to 4 Pallas launches per layer and reads the
     activations once per pair (the round-3 silicon probe measured ~41 us
-    fixed cost per kernel launch; scripts/kernel_sweep.py). Must equal the
+    fixed cost per kernel launch). Must equal the
     mesh's tp axis size.
     """
     h = reader.header
@@ -421,8 +420,7 @@ def load_params(
             # dequantizes selected blocks in VMEM. Layout per expert is the
             # same [in, out] device layout as the dense matmuls, stacked
             # [L, E, ...]. Under weight_format="q40i4" the experts KEEP
-            # this int8 layout (the ragged MoE kernels consume it; same
-            # policy as q40i8's requantize, int8_matmul.requantize_params).
+            # this int8 layout (the ragged MoE kernels consume it).
             def qexperts(tag: str, which: str) -> QuantWeight:
                 if streaming:
                     w_, _ = _stream_quant_stack(

@@ -1,6 +1,6 @@
 """Synthetic model construction: headers + random params without a `.m` file.
 
-Used by bench.py, __graft_entry__.py and tests to exercise the full model
+Used by chip_smoke.py, __graft_entry__.py and tests to exercise the full model
 path at arbitrary scale without multi-GB downloads. Shapes and pytree layout
 are identical to models/loader.load_params output.
 """
@@ -299,7 +299,7 @@ def random_params(
     moe = h.arch == LlmArch.QWEN3_MOE
     E = h.n_experts
 
-    quant = weight_format in ("q40", "q40i8", "q40i4")
+    quant = weight_format in ("q40", "q40i4")
     packed = weight_format == "q40i4"
     if quant:
         def mm(name, *shape, expert=False):
@@ -356,10 +356,4 @@ def random_params(
         "rope_sin": dev("rope_sin", sin),
         "layers": layers,
     }
-    if weight_format == "q40i8":
-        # same load path as the engine: build q40, requantize on device
-        from ..ops.int8_matmul import pick_group, requantize_params
-
-        tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-        params = requantize_params(params, h, pick_group(h, tp))
     return params

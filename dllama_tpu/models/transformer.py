@@ -33,7 +33,6 @@ from jax import lax
 
 from ..formats.model_file import HiddenAct, LlmArch, LlmHeader, RopeType
 from ..ops.jnp_ops import apply_rope, gelu, qk_rms_norm, rms_norm, silu
-from ..ops.int8_matmul import Int8Weight, i8matmul_tp
 from ..ops.quant_matmul import (
     FusedQuantWeight,
     PackedQuantWeight,
@@ -79,8 +78,6 @@ def _mm(
     the Pallas kernel (shard_map'd per TP role on a mesh), as a [L, in, out]
     stack and the `layer` to take. `sync_quant` Q80-compresses the col-split
     partial-sum all-reduce payload (reference: --buffer-float-type q80)."""
-    if isinstance(w, Int8Weight):
-        return i8matmul_tp(x, w, role, mesh, sync_quant=sync_quant).astype(x.dtype)
     if isinstance(w, _QUANT_CLASSES):
         return qmatmul_tp(
             x, w, role, mesh, sync_quant=sync_quant, layer=layer
@@ -110,10 +107,6 @@ def _mm_manual(
             return psum_maybe_quantized(out, axis, sync_quant)
         return out
 
-    if isinstance(w, Int8Weight):
-        from ..ops.int8_matmul import i8matmul
-
-        return reduce(i8matmul(x, w)).astype(x.dtype)
     if isinstance(w, _QUANT_CLASSES):
         return reduce(qmatmul(x, w, layer)).astype(x.dtype)
     return reduce(jnp.einsum("bti,io->bto", x, w))
@@ -121,8 +114,8 @@ def _mm_manual(
 
 def _is_quant_stack(leaf) -> bool:
     """A layer-stacked leaf that the Pallas kernels read in place: Q40
-    values and scales, fused or not. `Int8Weight` (q40i8) and dense
-    weights stay among the layer scan's `xs`."""
+    values and scales, fused or not. Dense weights stay among the layer
+    scan's `xs`."""
     if isinstance(leaf, FusedQuantWeight):
         leaf = leaf.weight
     return isinstance(leaf, _QUANT_CLASSES)
@@ -486,59 +479,6 @@ def _moe_ffn(
     return out.astype(x.dtype)
 
 
-def _moe_ffn_gather(
-    x: jnp.ndarray,  # [B, T, D], B*T small (decode)
-    gate_w: jnp.ndarray,  # [D, E]
-    w1: jnp.ndarray,  # [E, D, F]
-    w2: jnp.ndarray,  # [E, F, D]
-    w3: jnp.ndarray,  # [E, D, F]
-    n_active: int,
-    act,
-) -> jnp.ndarray:
-    """Decode-path MoE: gather only the k active experts' weights and
-    compute them, instead of running all E experts densely. For
-    Qwen3-30B-A3B (8 of 128 experts) this cuts per-step expert FLOPs and
-    HBM reads by ~16x. Same gate math as `_moe_ffn`.
-
-    The reference computes exactly the active experts too (its MoE matmul
-    walks the indexes buffer, nn-cpu-ops.cpp:1104-1136) — this is the
-    XLA-gather restatement; the fully fused ragged kernel remains future
-    work (SURVEY.md §7).
-    """
-    b, t, d = x.shape
-    n = b * t
-    xf = x.reshape(n, d)
-    top_i, weights = _moe_route(xf, gate_w, n_active)  # [n, k]
-
-    if isinstance(w1, QuantWeight):
-        flat = top_i.reshape(-1)
-        w1_sel, w2_sel, w3_sel = (
-            dequant(
-                QuantWeight(
-                    jnp.take(w.q, flat, axis=0), jnp.take(w.d, flat, axis=0)
-                ),
-                x.dtype,
-            )
-            for w in (w1, w2, w3)
-        )
-    else:
-        w1_sel = jnp.take(w1, top_i.reshape(-1), axis=0)  # [n*k, D, F]
-        w3_sel = jnp.take(w3, top_i.reshape(-1), axis=0)
-        w2_sel = jnp.take(w2, top_i.reshape(-1), axis=0)  # [n*k, F, D]
-    k = n_active
-    w1_sel = w1_sel.reshape(n, k, *w1_sel.shape[1:])
-    w3_sel = w3_sel.reshape(n, k, *w3_sel.shape[1:])
-    w2_sel = w2_sel.reshape(n, k, *w2_sel.shape[1:])
-
-    hidden = act(jnp.einsum("nd,nkdf->nkf", xf, w1_sel))
-    hidden = hidden * jnp.einsum("nd,nkdf->nkf", xf, w3_sel).astype(hidden.dtype)
-    expert_out = jnp.einsum("nkf,nkfd->nkd", hidden, w2_sel)
-    out = jnp.einsum(
-        "nkd,nk->nd", expert_out.astype(jnp.float32), weights
-    )
-    return out.reshape(b, t, d).astype(x.dtype)
-
-
 # Largest B*T routed through the ragged Pallas kernel: decode-lane sized.
 # Beyond this, dense all-expert compute wins back (at m*k approaching E the
 # per-(token, choice) DMA schedule re-reads experts the dense path reads
@@ -752,7 +692,6 @@ def forward(
     pos: jnp.ndarray,  # scalar int32, or [B] per-lane positions
     cache: KvCache,
     mesh=None,
-    moe_gather_max_tokens: int = 0,
     attn_window: int = 0,
     attn_park_threshold: int = 0,
     logits_mode: str = "all",
@@ -800,8 +739,7 @@ def forward(
     x, k_new, v_new = run_layers(
         x, params["layers"], cache["k"], cache["v"], h, pos, attn_pos,
         cos, sin, mesh=mesh, attn_window=attn_window,
-        sync_quant=sync_quant, moe_gather_max_tokens=moe_gather_max_tokens,
-        moe_decode_dedup=moe_decode_dedup,
+        sync_quant=sync_quant, moe_decode_dedup=moe_decode_dedup,
     )
     logits = logits_head(x, params, h, mesh, logits_mode)
     return logits, {"k": k_new, "v": v_new}
@@ -846,12 +784,9 @@ def logits_head(
     y = rms_norm(x, params["final_norm"], h.norm_epsilon)
     wcls = params["wcls"]
     if tp_axis is not None:
-        from ..ops.int8_matmul import i8matmul
         from ..ops.quant_matmul import qmatmul
 
-        if isinstance(wcls, Int8Weight):
-            local = i8matmul(y, wcls)
-        elif isinstance(wcls, _QUANT_CLASSES):
+        if isinstance(wcls, _QUANT_CLASSES):
             local = qmatmul(y, wcls)
         else:
             local = jnp.einsum(
@@ -859,8 +794,6 @@ def logits_head(
                 wcls.astype(jnp.float32),
             )
         return lax.all_gather(local, tp_axis, axis=-1, tiled=True)
-    if isinstance(wcls, Int8Weight):
-        return i8matmul_tp(y, wcls, "row", mesh)
     if isinstance(wcls, _QUANT_CLASSES):
         return qmatmul_tp(y, wcls, "row", mesh)
     return jnp.einsum(
@@ -881,7 +814,6 @@ def run_layers(
     mesh=None,
     attn_window: int = 0,
     sync_quant: bool = False,
-    moe_gather_max_tokens: int = 0,
     moe_decode_dedup: bool = False,
     tp_axis: str | None = None,
     tp_n: int = 1,
@@ -1110,10 +1042,8 @@ def run_layers(
             if h.arch == LlmArch.QWEN3_MOE:
                 # decode (lane-sized B*T): the ragged Pallas kernel reads only
                 # each token's active experts' weights — Q40 blocks when the
-                # experts are stored quantized. Prefill / CPU: dense-over-
-                # experts (XLA's jnp.take gather measured ~3x slower than even
-                # dense, so the gather path stays opt-in via
-                # moe_gather_max_tokens).
+                # experts are stored quantized. CPU, and shapes the gate
+                # refuses: dense-over-experts.
                 from ..ops.moe_kernel import moe_pallas_supported
 
                 _w1 = lp["w1"]
@@ -1157,13 +1087,8 @@ def run_layers(
                             layer=l,
                         )
                 else:
-                    moe = (
-                        _moe_ffn_gather
-                        if b * t <= moe_gather_max_tokens
-                        else _moe_ffn
-                    )
-                    # XLA compiles these and fuses the slice into the dequant
-                    f = moe(
+                    # XLA compiles this and fuses the slice into the dequant
+                    f = _moe_ffn(
                         y,
                         lp["moe_gate"],
                         *(

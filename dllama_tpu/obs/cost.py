@@ -8,8 +8,8 @@ cache, pairs it with the measured step-time histograms, and turns "is
 decode as fast as the hardware allows?" into a single
 achieved-vs-roofline fraction instead of a guess.
 
-The same analytic weight-read model the bench uses
-(``weight_bytes_per_token``) lives here so the CLI can print a startup
+An analytic weight-read model (``weight_bytes_per_token``) lives here
+so the CLI can print a startup
 roofline report next to the memory/ICI reports: bytes per decoded token
 per chip, the HBM floor in ms/token, and the implied tok/s ceiling.
 """
@@ -108,18 +108,14 @@ def analytic_step_seconds(
     return float(bytes_accessed) / float(peak_bytes_per_s)
 
 
-def weight_bytes_per_token(
-    h: "LlmHeader", weight_format: str, i8_group: int = 512
-) -> int:
+def weight_bytes_per_token(h: "LlmHeader", weight_format: str) -> int:
     """HBM bytes of weights a single decode step must read: every matmul
     weight once (MoE: attention weights + the active experts' share).
     Q40 device layout = int8 values + f32 scale per 32 block = 1.125
-    B/weight; grouped int8 = 1 + 4/G; packed nibbles + f16 scales =
-    0.5625; dense bf16 = 2 B/weight. (Shared by bench.py and the startup
-    roofline report.)"""
+    B/weight; packed nibbles + f16 scales = 0.5625; dense bf16 = 2
+    B/weight."""
     bpw = {
         "q40": 1.125,
-        "q40i8": 1.0 + 4.0 / i8_group,
         "q40i4": 0.5 + 2.0 / 32.0,
     }.get(weight_format, 2.0)
     att = h.dim * h.q_dim + 2 * h.dim * h.kv_dim + h.q_dim * h.dim
@@ -194,7 +190,7 @@ def program_cost_ceilings(
 
 def roofline_report(
     h: "LlmHeader", weight_format: str, tp: int = 1, pp: int = 1,
-    i8_group: int = 512, spec_k: int = 0
+    spec_k: int = 0
 ) -> dict:
     """Analytic decode roofline for this model/format/layout: weight-read
     bytes per token per chip (weights shard over tp x pp; dp/sp replicate
@@ -206,7 +202,7 @@ def roofline_report(
     ``dllama_spec_tokens_per_weight_pass`` gauge (floor 1.0 = nothing
     accepted, ceiling ``spec_k + 1`` = every draft accepted)."""
     shards = max(tp, 1) * max(pp, 1)
-    per_chip = weight_bytes_per_token(h, weight_format, i8_group) // shards
+    per_chip = weight_bytes_per_token(h, weight_format) // shards
     peak = hbm_peak_bytes_per_s()
     rep: dict = {
         "weight_bytes_per_token_per_chip": per_chip,
@@ -227,13 +223,11 @@ def roofline_report(
 
 def print_roofline_report(
     h: "LlmHeader", weight_format: str, tp: int = 1, pp: int = 1,
-    i8_group: int = 512, spec_k: int = 0
+    spec_k: int = 0
 ) -> dict:
     """Startup roofline printout (rides next to the memory/ICI reports in
     cli.load_engine); returns the report dict it printed."""
-    rep = roofline_report(
-        h, weight_format, tp=tp, pp=pp, i8_group=i8_group, spec_k=spec_k
-    )
+    rep = roofline_report(h, weight_format, tp=tp, pp=pp, spec_k=spec_k)
     gb = rep["weight_bytes_per_token_per_chip"] / 1e9
     if rep["hbm_peak_bytes_per_s"]:
         print(

@@ -18,9 +18,8 @@ them into:
   from per-token timestamps (so it tracks in-flight streams, not just
   finished ones).
 
-Surfaced three ways: ``dllama_slo_*`` gauges (refreshed at scrape /
-snapshot time, one child per window), ``GET /v1/debug/slo``, and a
-``slo`` section in the bench's BENCH_SERVING.json.
+Surfaced two ways: ``dllama_slo_*`` gauges (refreshed at scrape /
+snapshot time, one child per window) and ``GET /v1/debug/slo``.
 
 Thread-safety: requests finish on the scheduler thread while snapshots
 run on HTTP handler threads; both sides take one short lock. Sample

@@ -42,13 +42,15 @@ class QuantKV(NamedTuple):
 
 
 def quantize_kv_rows(val: jnp.ndarray):
-    """[..., T, hd] -> (int8 values, f32 [..., T, 1] scales): the shared
-    grouped symmetric quantizer (ops/int8_matmul.quantize_acts — the Q80
-    move) with one group per cache row, so the KV path and the int8
-    matmul path cannot drift."""
-    from .int8_matmul import quantize_acts
-
-    return quantize_acts(val.astype(jnp.float32), val.shape[-1])
+    """[..., T, hd] -> (int8 values, f32 [..., T, 1] scales): symmetric
+    quantization with one scale per cache row, max|row| / 127 (the
+    reference's Q80 step, quantizeQ80Row, with the row as its block). An
+    all-zero row takes scale 1, so nothing divides by zero."""
+    x = val.astype(jnp.float32)
+    s = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 127.0
+    s = jnp.where(s == 0, 1.0, s)
+    q = jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int8)
+    return q, s
 
 
 def dequant_kv(cache_l, dtype):

@@ -265,9 +265,8 @@ def _qmm_i4_kernel(l_ref, x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     sublane-broadcast dequant in VMEM feed the MXU in bf16 exactly like
     the int8 kernel. The unpack is a handful of VPU element-ops per tile;
     the Q40 kernel was dequant-compute-bound at 46% of HBM peak on the
-    round-3 chip run, so halving bytes moves the balance point, and the
-    staged bench sweep (BENCH_SWEEP_FORMATS) measures which side wins on
-    silicon."""
+    round-3 chip run, so halving bytes moves the balance point; which
+    side wins on silicon has not been measured."""
     pk = pl.program_id(2)
     qp = qp_ref[:]  # [bk // 2, bn] int8, two nibbles per byte
     d = _f16_bits_to_f32(d_ref[:])  # [bk // 32, bn]
@@ -403,8 +402,8 @@ def qmatmul_2d(
     """Pallas quantized matmul of 2D activations with one layer of a weight
     stack; returns [m, n] f32.
 
-    Default blocks are the round-3 silicon sweep winner (scripts/
-    kernel_sweep.py on v5e, m=1 k=4096 n=14336): (bn=256, bk=4096) ran
+    Default blocks are the round-3 silicon sweep winner (v5e, m=1 k=4096
+    n=14336): (bn=256, bk=4096) ran
     0.465 ms vs 0.893 ms for the previous (512, 2048) default and 0.936 ms
     for XLA's dense bf16 matvec on the same shape — narrow n tiles with
     the whole k per step keep the accumulator live and the weight DMAs

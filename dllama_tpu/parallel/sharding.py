@@ -32,8 +32,8 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
     """PartitionSpecs matching the params pytree from models/loader.py.
 
     The same specs cover every quantized device format's leaves: a
-    QuantWeight/PackedQuantWeight/Int8Weight is a (values, scales) pytree
-    whose leaves all keep the [in-ish, out] axis order — row split puts
+    QuantWeight/PackedQuantWeight is a (values, scales) pytree whose
+    leaves all keep the [in-ish, out] axis order — row split puts
     "tp" on the last (out) axis of both leaves, col split on the
     second-to-last. For the packed q40i4 layout the value leaf's in axis
     is in//2 and the scale leaf's is in//32; both divide by tp under the

@@ -317,13 +317,13 @@ class InferenceEngine:
                 )
                 else "dense"
             )
-        if weight_format not in ("dense", "q40", "q40i8", "q40i4"):
+        if weight_format not in ("dense", "q40", "q40i4"):
             raise ValueError(
-                f"weight_format must be 'auto', 'dense', 'q40', 'q40i8' or "
-                f"'q40i4', got {weight_format!r}"
+                f"weight_format must be 'auto', 'dense', 'q40' or 'q40i4', "
+                f"got {weight_format!r}"
             )
         self.weight_format = weight_format
-        quantized = weight_format in ("q40", "q40i8", "q40i4")
+        quantized = weight_format in ("q40", "q40i4")
         # Q80-compressed partial-sum all-reduces (the reference's
         # --buffer-float-type q80, src/llm.cpp:195): worthwhile on
         # DCN-connected multi-host pods where sync bytes are the
@@ -352,26 +352,14 @@ class InferenceEngine:
             self.reader,
             dtype=dtype,
             put=shard_params_put(self.mesh, self.header),
-            # q40i8 loads the wire's Q40 blocks first, then requantizes;
             # q40i4 packs host-side inside the loader itself
-            weight_format="q40" if weight_format == "q40i8" else weight_format,
+            weight_format=weight_format,
             # quantized path: fuse q|k|v (and w1|w3 for dense-FFN archs)
             # into single shard-major-interleaved kernel launches — 7 -> 4
             # Pallas calls per decode layer (~41 us fixed cost each on
             # the round-3 chip run)
             fuse=tp if quantized else 0,
         )
-        self.i8_group = 0
-        if weight_format == "q40i8":
-            # grouped-int8 device format: native MXU integer dots instead
-            # of per-element VPU dequant (ops/int8_matmul.py) — the r4
-            # answer to the Q40 kernel's 46%-of-HBM-peak ceiling
-            from ..ops.int8_matmul import pick_group, requantize_params
-
-            self.i8_group = pick_group(self.header, tp)
-            self.params = requantize_params(
-                self.params, self.header, self.i8_group
-            )
         # Per-lane serving: lanes park their cache writes in padding rows
         # beyond seqLen while other lanes prefill/idle, so independent
         # requests can occupy the batch lanes at different positions.
@@ -676,46 +664,84 @@ class InferenceEngine:
             self._m_window_crossings.inc()
         self._obs_last_window = window
 
-    def _step_fn(self, t: int, greedy: bool, window: int = 0):
-        """Build/jit the forward step for chunk length `t`."""
-        key = (t, greedy, window)
+    def _build(self, key, make, arg_specs=None, origin: str = "dispatch"):
+        """The one way a program enters `_compiled`. A cached `key` is
+        returned as it is; a dispatch that finds a prefetch thread
+        building `key` waits for it and reuses its program. Otherwise
+        `make()` gives the jitted function. With `arg_specs` (a callable,
+        so a cache hit builds no specs) and `_aot_blocks` it is lowered
+        against them and compiled here and now, which is what lets
+        `_prefetch` build a program off the serving thread; the recorder
+        gets `compile_start` / `compile_end` (`s`: the build's seconds)
+        and xlalint reads the executable. Without `arg_specs` the program
+        is lazily jitted: XLA compiles at its first call, so there is no
+        build time to record, and one deferred `compile` marker takes
+        the pair's place."""
         with self._compile_lock:
             if key in self._compiled:
                 return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
-
-        @partial(jax.jit, donate_argnums=(2,))
-        def step(params, tokens, cache, pos):
-            ctx = (
-                jax.default_matmul_precision(precision)
-                if precision
-                else contextlib.nullcontext()
-            )
-            with ctx:
-                logits, cache = fwd(
-                    params, tokens, pos, cache,
-                    attn_window=window, logits_mode="last",
-                    n_micro=self._pp_micro(t),
-                )
-            last = logits[:, -1, :]
-            if greedy:
-                # On-device sampling (reference samples on host from the
-                # logits pipe; fusing argmax here avoids the [vocab] device
-                # -> host transfer per decoded token).
-                return jnp.argmax(last, axis=-1).astype(jnp.int32), cache
-            return last, cache
-
+            ev = self._inflight.get(key) if origin == "dispatch" else None
+        if ev is not None:
+            ev.wait()
+            with self._compile_lock:
+                if key in self._compiled:
+                    return self._compiled[key]
+        eager = arg_specs is not None and self._aot_blocks
+        if arg_specs is not None:
+            self.recorder.record("compile_start", key=str(key), origin=origin)
+        t0 = time.perf_counter()
+        fn = make()
+        if eager:
+            fn = fn.lower(*arg_specs()).compile()
+        dt = time.perf_counter() - t0
         with self._compile_lock:
-            self._compiled[key] = step
-            self._compile_origin[key] = "dispatch"
-        self._m_compiles.labels(origin="dispatch").inc()
-        # lazily jitted: XLA compiles on first call, so there is no build
-        # time to record here — one deferred marker instead of start/end
-        self.recorder.record(
-            "compile", key=str(key), origin="dispatch", deferred=True
-        )
-        return step
+            self._compiled[key] = fn
+            self._compile_origin[key] = origin
+            if eager:
+                self._compile_seconds[key] = dt
+        self._m_compiles.labels(origin=origin).inc()
+        if arg_specs is None:
+            self.recorder.record(
+                "compile", key=str(key), origin=origin, deferred=True
+            )
+        else:
+            self.recorder.record(
+                "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+            )
+            self._xlalint_after_compile(key)
+        return fn
+
+    def _step_fn(self, t: int, greedy: bool, window: int = 0):
+        """Build/jit the forward step for chunk length `t`."""
+
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def step(params, tokens, cache, pos):
+                ctx = (
+                    jax.default_matmul_precision(precision)
+                    if precision
+                    else contextlib.nullcontext()
+                )
+                with ctx:
+                    logits, cache = fwd(
+                        params, tokens, pos, cache,
+                        attn_window=window, logits_mode="last",
+                        n_micro=self._pp_micro(t),
+                    )
+                last = logits[:, -1, :]
+                if greedy:
+                    # On-device sampling (reference samples on host from the
+                    # logits pipe; fusing argmax here avoids the [vocab] device
+                    # -> host transfer per decoded token).
+                    return jnp.argmax(last, axis=-1).astype(jnp.int32), cache
+                return last, cache
+
+            return step
+
+        return self._build((t, greedy, window), make)
 
     def _block_arg_specs(self, n_steps: int):
         """ShapeDtypeStructs (with shardings) matching a decode_block
@@ -758,66 +784,48 @@ class InferenceEngine:
         executable — which is what lets `_prefetch_block` build the next
         attention window's program off-thread before a lane crosses the
         boundary (no synchronous compile at the crossing)."""
-        key = ("block", n_steps, greedy, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:  # a prefetch thread is building it: wait, reuse
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def block(params, token, cache, pos, rng, temperature, topp):
-            def body(i, carry):
-                tok, cache, out = carry
-                ctx = (
-                    jax.default_matmul_precision(precision)
-                    if precision
-                    else contextlib.nullcontext()
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def block(params, token, cache, pos, rng, temperature, topp):
+                def body(i, carry):
+                    tok, cache, out = carry
+                    ctx = (
+                        jax.default_matmul_precision(precision)
+                        if precision
+                        else contextlib.nullcontext()
+                    )
+                    with ctx:
+                        logits, cache = fwd(
+                            params, tok, pos + i, cache,
+                            attn_window=window, logits_mode="last",
+                        )
+                    last = logits[:, -1, :]
+                    if greedy:
+                        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                    else:
+                        nxt = _sample_on_device(
+                            last, temperature, topp, jax.random.fold_in(rng, i)
+                        )
+                    nxt = nxt.reshape(-1, 1)
+                    out = lax.dynamic_update_index_in_dim(out, nxt[:, 0], i, axis=0)
+                    return nxt, cache, out
+
+                out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
+                tok, cache, out = lax.fori_loop(
+                    0, n_steps, body, (token, cache, out0)
                 )
-                with ctx:
-                    logits, cache = fwd(
-                        params, tok, pos + i, cache,
-                        attn_window=window, logits_mode="last",
-                    )
-                last = logits[:, -1, :]
-                if greedy:
-                    nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                else:
-                    nxt = _sample_on_device(
-                        last, temperature, topp, jax.random.fold_in(rng, i)
-                    )
-                nxt = nxt.reshape(-1, 1)
-                out = lax.dynamic_update_index_in_dim(out, nxt[:, 0], i, axis=0)
-                return nxt, cache, out
+                return out, cache
 
-            out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
-            tok, cache, out = lax.fori_loop(
-                0, n_steps, body, (token, cache, out0)
-            )
-            return out, cache
+            return block
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            block = block.lower(*self._block_arg_specs(n_steps)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = block
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+        return self._build(
+            ("block", n_steps, greedy, window), make,
+            lambda: self._block_arg_specs(n_steps), origin,
         )
-        self._xlalint_after_compile(key)
-        return block
 
     def _prefetch(self, key, builder) -> None:
         """Compile the NEXT attention window's program in a daemon thread: called when a lane passes ~75% of the current
@@ -860,8 +868,8 @@ class InferenceEngine:
                     self._inflight.pop(key, None)
                 ev.set()
 
-        # joined via the per-key `ev` Event in _decode_block_fn (the
-        # dispatch path waits on it), not via the Thread handle
+        # joined via the per-key `ev` Event in _build (the dispatch path
+        # waits on it), not via the Thread handle
         threading.Thread(  # dlint: disable=thread-hygiene — lifetime bounded by the _inflight[key] Event; waiters join through ev.wait()
             target=work, daemon=True, name=f"dllama-prefetch-{key[1]}"
         ).start()
@@ -937,39 +945,32 @@ class InferenceEngine:
         returns the summed next-token NLL of the chunk's unmasked rows as
         ONE scalar (no [T, vocab] logits transfer — the reference ships the
         full logits pipe to host per batch, src/dllama.cpp:132-172)."""
-        key = ("score", t, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
 
-        @partial(jax.jit, donate_argnums=(4,))
-        def score(params, tokens, targets, mask, cache, pos):
-            ctx = (
-                jax.default_matmul_precision(precision)
-                if precision
-                else contextlib.nullcontext()
-            )
-            with ctx:
-                logits, cache = fwd(
-                    params, tokens, pos, cache, attn_window=window,
-                    n_micro=self._pp_micro(t),
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+
+            @partial(jax.jit, donate_argnums=(4,))
+            def score(params, tokens, targets, mask, cache, pos):
+                ctx = (
+                    jax.default_matmul_precision(precision)
+                    if precision
+                    else contextlib.nullcontext()
                 )
-            lg = logits.astype(jnp.float32)  # [B, T, V]
-            lse = jax.nn.logsumexp(lg, axis=-1)  # [B, T]
-            tgt = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
-            nll = (lse - tgt) * mask
-            return jnp.sum(nll[0]), cache
+                with ctx:
+                    logits, cache = fwd(
+                        params, tokens, pos, cache, attn_window=window,
+                        n_micro=self._pp_micro(t),
+                    )
+                lg = logits.astype(jnp.float32)  # [B, T, V]
+                lse = jax.nn.logsumexp(lg, axis=-1)  # [B, T]
+                tgt = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
+                nll = (lse - tgt) * mask
+                return jnp.sum(nll[0]), cache
 
-        with self._compile_lock:
-            self._compiled[key] = score
-            self._compile_origin[key] = "dispatch"
-        self._m_compiles.labels(origin="dispatch").inc()
-        self.recorder.record(
-            "compile", key=str(key), origin="dispatch", deferred=True
-        )
-        return score
+            return score
+
+        return self._build(("score", t, window), make)
 
     def perplexity(self, tokens: list[int]) -> tuple[float, float, int]:
         """Teacher-forced (nll, perplexity, n_scored) over `tokens`,
@@ -1071,51 +1072,33 @@ class InferenceEngine:
         AOT-compiled like the decode blocks — this is the lane scheduler's
         ADMISSION path, so a synchronous XLA compile here is exactly the
         first-admission stall rehearse_admission() exists to remove."""
-        key = ("lane_prefill", t, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:  # a rehearsal thread is building it: wait, reuse
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
-        park = self._park
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def step(params, tokens, cache, pos_vec):
-            ctx = (
-                jax.default_matmul_precision(precision)
-                if precision
-                else contextlib.nullcontext()
-            )
-            with ctx:
-                _, cache = fwd(
-                    params, tokens, pos_vec, cache,
-                    attn_window=window, attn_park_threshold=park,
-                    logits_mode="last", n_micro=self._pp_micro(t),
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+            park = self._park
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def step(params, tokens, cache, pos_vec):
+                ctx = (
+                    jax.default_matmul_precision(precision)
+                    if precision
+                    else contextlib.nullcontext()
                 )
-            return cache
+                with ctx:
+                    _, cache = fwd(
+                        params, tokens, pos_vec, cache,
+                        attn_window=window, attn_park_threshold=park,
+                        logits_mode="last", n_micro=self._pp_micro(t),
+                    )
+                return cache
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            step = step.lower(*self._lane_prefill_arg_specs(t)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = step
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+            return step
+
+        return self._build(
+            ("lane_prefill", t, window), make,
+            lambda: self._lane_prefill_arg_specs(t), origin,
         )
-        self._xlalint_after_compile(key)
-        return step
 
     def rehearse_admission(
         self,
@@ -1545,70 +1528,51 @@ class InferenceEngine:
         O(log max_pages). QuantKV caches work unchanged: jax.tree.map
         descends into the (values, scales) pair and every op below is
         shape-generic in the trailing dim."""
-        key = ("kv_" + kind, bucket)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        ps = self._kv_page_size
-
-        if kind == "adopt":
-
-            @partial(jax.jit, donate_argnums=(0,))
-            def fn(cache, pool, lane, start_page, ids):
-                def leaf(c, p):
-                    pages = p[:, ids]  # [L, bucket, KH, ps, last]
-                    l_, _, kh, _, last = pages.shape
-                    rows = pages.transpose(0, 2, 1, 3, 4).reshape(
-                        l_, 1, kh, bucket * ps, last
-                    )
-                    return lax.dynamic_update_slice(
-                        c, rows, (0, lane, 0, start_page * ps, 0)
-                    )
-
-                return jax.tree.map(leaf, cache, pool)
-
-        elif kind == "publish":
-
-            @partial(jax.jit, donate_argnums=(1,))
-            def fn(cache, pool, lane, start_page, ids):
-                def leaf(c, p):
-                    l_, _, kh, _, last = c.shape
-                    rows = lax.dynamic_slice(
-                        c, (0, lane, 0, start_page * ps, 0),
-                        (l_, 1, kh, bucket * ps, last),
-                    )
-                    pages = rows[:, 0].reshape(
-                        l_, kh, bucket, ps, last
-                    ).transpose(0, 2, 1, 3, 4)
-                    return p.at[:, ids].set(pages)
-
-                return jax.tree.map(leaf, cache, pool)
-
-        else:
+        if kind not in ("adopt", "publish"):
             raise ValueError(f"unknown kv copy kind {kind!r}")
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            fn = fn.lower(*self._kv_copy_arg_specs(bucket)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = fn
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+        def make():
+            ps = self._kv_page_size
+
+            if kind == "adopt":
+
+                @partial(jax.jit, donate_argnums=(0,))
+                def fn(cache, pool, lane, start_page, ids):
+                    def leaf(c, p):
+                        pages = p[:, ids]  # [L, bucket, KH, ps, last]
+                        l_, _, kh, _, last = pages.shape
+                        rows = pages.transpose(0, 2, 1, 3, 4).reshape(
+                            l_, 1, kh, bucket * ps, last
+                        )
+                        return lax.dynamic_update_slice(
+                            c, rows, (0, lane, 0, start_page * ps, 0)
+                        )
+
+                    return jax.tree.map(leaf, cache, pool)
+
+            else:
+
+                @partial(jax.jit, donate_argnums=(1,))
+                def fn(cache, pool, lane, start_page, ids):
+                    def leaf(c, p):
+                        l_, _, kh, _, last = c.shape
+                        rows = lax.dynamic_slice(
+                            c, (0, lane, 0, start_page * ps, 0),
+                            (l_, 1, kh, bucket * ps, last),
+                        )
+                        pages = rows[:, 0].reshape(
+                            l_, kh, bucket, ps, last
+                        ).transpose(0, 2, 1, 3, 4)
+                        return p.at[:, ids].set(pages)
+
+                    return jax.tree.map(leaf, cache, pool)
+
+            return fn
+
+        return self._build(
+            ("kv_" + kind, bucket), make,
+            lambda: self._kv_copy_arg_specs(bucket), origin,
         )
-        self._xlalint_after_compile(key)
-        return fn
 
     def _kv_copy_chunks(self, n: int):
         """Decompose an n-page copy into decreasing power-of-two buckets.
@@ -1716,40 +1680,22 @@ class InferenceEngine:
         """Pool-internal page copy (src pages -> dst pages), donating the
         pool: the COW fork of a mid-page adoption tail — the ONLY device
         copy left on the native admission path."""
-        key = ("kv_page_copy", bucket)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
 
-        @partial(jax.jit, donate_argnums=(0,))
-        def fn(pool, src, dst):
-            def leaf(p):
-                return p.at[:, dst].set(p[:, src])
+        def make():
 
-            return jax.tree.map(leaf, pool)
+            @partial(jax.jit, donate_argnums=(0,))
+            def fn(pool, src, dst):
+                def leaf(p):
+                    return p.at[:, dst].set(p[:, src])
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            fn = fn.lower(*self._kv_page_copy_arg_specs(bucket)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = fn
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+                return jax.tree.map(leaf, pool)
+
+            return fn
+
+        return self._build(
+            ("kv_page_copy", bucket), make,
+            lambda: self._kv_page_copy_arg_specs(bucket), origin,
         )
-        self._xlalint_after_compile(key)
-        return fn
 
     def kv_page_copy(self, src_ids: list[int], dst_ids: list[int]) -> None:
         """Copy pool pages ``src_ids[i]`` -> ``dst_ids[i]`` on device."""
@@ -1843,77 +1789,57 @@ class InferenceEngine:
         the gathered page view (donating the POOL, not the slab). Live
         lanes read/write the exact rows the slab program would, so the
         emitted tokens are bit-identical; the slab cache is untouched."""
-        key = ("lane_block_paged", n_steps, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
-        seq_len = self.header.seq_len
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def block(
-            params, token, pool, pt, pos_vec, active, seeds,
-            temperature, topp,
-        ):
-            view = self._paged_gather(pool, pt, window, n_steps)
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+            seq_len = self.header.seq_len
 
-            def body(i, carry):
-                tok, view, out = carry
-                ok = jnp.logical_and(active, pos_vec + i < seq_len)
-                cur = jnp.where(ok, pos_vec + i, window)
-                ctx = (
-                    jax.default_matmul_precision(precision)
-                    if precision
-                    else contextlib.nullcontext()
-                )
-                with ctx:
-                    logits, view = fwd(
-                        params, tok, cur, view,
-                        attn_window=window,
-                        attn_park_threshold=window, logits_mode="last",
+            @partial(jax.jit, donate_argnums=(2,))
+            def block(
+                params, token, pool, pt, pos_vec, active, seeds,
+                temperature, topp,
+            ):
+                view = self._paged_gather(pool, pt, window, n_steps)
+
+                def body(i, carry):
+                    tok, view, out = carry
+                    ok = jnp.logical_and(active, pos_vec + i < seq_len)
+                    cur = jnp.where(ok, pos_vec + i, window)
+                    ctx = (
+                        jax.default_matmul_precision(precision)
+                        if precision
+                        else contextlib.nullcontext()
                     )
-                last = logits[:, -1, :]
-                nxt = _sample_per_lane(last, temperature, topp, seeds, cur)
-                nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
-                out = lax.dynamic_update_index_in_dim(
-                    out, nxt[:, 0], i, axis=0
+                    with ctx:
+                        logits, view = fwd(
+                            params, tok, cur, view,
+                            attn_window=window,
+                            attn_park_threshold=window, logits_mode="last",
+                        )
+                    last = logits[:, -1, :]
+                    nxt = _sample_per_lane(last, temperature, topp, seeds, cur)
+                    nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
+                    out = lax.dynamic_update_index_in_dim(
+                        out, nxt[:, 0], i, axis=0
+                    )
+                    return nxt, view, out
+
+                out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
+                _, view, out = lax.fori_loop(
+                    0, n_steps, body, (token, view, out0)
                 )
-                return nxt, view, out
+                rows = pos_vec[:, None] + jnp.arange(n_steps)[None, :]
+                safe = jnp.logical_and(active[:, None], rows < window)
+                pool = self._paged_scatter(pool, view, pt, rows, safe)
+                return out, pool
 
-            out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
-            _, view, out = lax.fori_loop(
-                0, n_steps, body, (token, view, out0)
-            )
-            rows = pos_vec[:, None] + jnp.arange(n_steps)[None, :]
-            safe = jnp.logical_and(active[:, None], rows < window)
-            pool = self._paged_scatter(pool, view, pt, rows, safe)
-            return out, pool
+            return block
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            block = block.lower(
-                *self._lane_decode_paged_arg_specs(n_steps)
-            ).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = block
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+        return self._build(
+            ("lane_block_paged", n_steps, window), make,
+            lambda: self._lane_decode_paged_arg_specs(n_steps), origin,
         )
-        self._xlalint_after_compile(key)
-        return block
 
     def _lane_verify_paged_arg_specs(self, t: int):
         b = self.batch_size
@@ -1926,63 +1852,43 @@ class InferenceEngine:
     ):
         """Pool-native speculative verify: _lane_verify_fn on the page
         view (one fwd over t tokens, greedy argmax grid back)."""
-        key = ("lane_verify_paged", t, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
-        seq_len = self.header.seq_len
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def vstep(params, tokens, pool, pt, pos_vec, active):
-            view = self._paged_gather(pool, pt, window, t)
-            cur = jnp.where(active, pos_vec, window)
-            ctx = (
-                jax.default_matmul_precision(precision)
-                if precision
-                else contextlib.nullcontext()
-            )
-            with ctx:
-                logits, view = fwd(
-                    params, tokens, cur, view,
-                    attn_window=window, attn_park_threshold=window,
-                    logits_mode="all", n_micro=self._pp_micro(t),
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+            seq_len = self.header.seq_len
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def vstep(params, tokens, pool, pt, pos_vec, active):
+                view = self._paged_gather(pool, pt, window, t)
+                cur = jnp.where(active, pos_vec, window)
+                ctx = (
+                    jax.default_matmul_precision(precision)
+                    if precision
+                    else contextlib.nullcontext()
                 )
-            out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out = jnp.where(active[:, None], out, 0)
-            rows = cur[:, None] + jnp.arange(t)[None, :]
-            safe = jnp.logical_and(
-                jnp.logical_and(active[:, None], rows < window),
-                rows < seq_len,
-            )
-            pool = self._paged_scatter(pool, view, pt, rows, safe)
-            return out, pool
+                with ctx:
+                    logits, view = fwd(
+                        params, tokens, cur, view,
+                        attn_window=window, attn_park_threshold=window,
+                        logits_mode="all", n_micro=self._pp_micro(t),
+                    )
+                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out = jnp.where(active[:, None], out, 0)
+                rows = cur[:, None] + jnp.arange(t)[None, :]
+                safe = jnp.logical_and(
+                    jnp.logical_and(active[:, None], rows < window),
+                    rows < seq_len,
+                )
+                pool = self._paged_scatter(pool, view, pt, rows, safe)
+                return out, pool
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            vstep = vstep.lower(
-                *self._lane_verify_paged_arg_specs(t)
-            ).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = vstep
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+            return vstep
+
+        return self._build(
+            ("lane_verify_paged", t, window), make,
+            lambda: self._lane_verify_paged_arg_specs(t), origin,
         )
-        self._xlalint_after_compile(key)
-        return vstep
 
     def _lane_prefill_paged_fn(
         self, t: int, window: int, origin: str = "dispatch"
@@ -1990,54 +1896,36 @@ class InferenceEngine:
         """Pool-native lane-prefill chunk: _lane_prefill_fn on the page
         view. Parked lanes are fed pos = `window` (the view's parking
         tail), so their writes never scatter back."""
-        key = ("lane_prefill_paged", t, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def step(params, tokens, pool, pt, pos_vec):
-            view = self._paged_gather(pool, pt, window, t)
-            ctx = (
-                jax.default_matmul_precision(precision)
-                if precision
-                else contextlib.nullcontext()
-            )
-            with ctx:
-                _, view = fwd(
-                    params, tokens, pos_vec, view,
-                    attn_window=window, attn_park_threshold=window,
-                    logits_mode="last", n_micro=self._pp_micro(t),
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def step(params, tokens, pool, pt, pos_vec):
+                view = self._paged_gather(pool, pt, window, t)
+                ctx = (
+                    jax.default_matmul_precision(precision)
+                    if precision
+                    else contextlib.nullcontext()
                 )
-            rows = pos_vec[:, None] + jnp.arange(t)[None, :]
-            safe = rows < window
-            pool = self._paged_scatter(pool, view, pt, rows, safe)
-            return pool
+                with ctx:
+                    _, view = fwd(
+                        params, tokens, pos_vec, view,
+                        attn_window=window, attn_park_threshold=window,
+                        logits_mode="last", n_micro=self._pp_micro(t),
+                    )
+                rows = pos_vec[:, None] + jnp.arange(t)[None, :]
+                safe = rows < window
+                pool = self._paged_scatter(pool, view, pt, rows, safe)
+                return pool
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            step = step.lower(*self._lane_paged_specs(t)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = step
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+            return step
+
+        return self._build(
+            ("lane_prefill_paged", t, window), make,
+            lambda: self._lane_paged_specs(t), origin,
         )
-        self._xlalint_after_compile(key)
-        return step
 
     def _lane_arg_specs(self, n_steps: int):
         """Arg specs for a decode_lanes dispatch (the AOT pre-compile's
@@ -2072,77 +1960,59 @@ class InferenceEngine:
         masked, so the window only limits reads). AOT-compiled like
         _decode_block_fn so the API server's window crossings can be
         prefetched too (this IS the serving path)."""
-        key = ("lane_block", n_steps, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
-        park = self._park
 
-        seq_len = self.header.seq_len
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+            park = self._park
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def block(params, token, cache, pos_vec, active, seeds, temperature, topp):
-            def body(i, carry):
-                tok, cache, out = carry
-                # per-lane in-block stop: a lane whose window fills mid-
-                # block parks itself (writes land in padding, token 0
-                # emitted) instead of shrinking the whole batch's block to
-                # its remaining space — one near-full lane no longer
-                # degrades every concurrent stream to 1-token dispatches
-                #; callers already deactivate a lane the
-                # moment its position cap is reached.
-                ok = jnp.logical_and(active, pos_vec + i < seq_len)
-                cur = jnp.where(ok, pos_vec + i, park)
-                ctx = (
-                    jax.default_matmul_precision(precision)
-                    if precision
-                    else contextlib.nullcontext()
-                )
-                with ctx:
-                    logits, cache = fwd(
-                        params, tok, cur, cache,
-                        attn_window=window,
-                        attn_park_threshold=park, logits_mode="last",
+            seq_len = self.header.seq_len
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def block(params, token, cache, pos_vec, active, seeds, temperature, topp):
+                def body(i, carry):
+                    tok, cache, out = carry
+                    # per-lane in-block stop: a lane whose window fills mid-
+                    # block parks itself (writes land in padding, token 0
+                    # emitted) instead of shrinking the whole batch's block to
+                    # its remaining space — one near-full lane no longer
+                    # degrades every concurrent stream to 1-token dispatches
+                    #; callers already deactivate a lane the
+                    # moment its position cap is reached.
+                    ok = jnp.logical_and(active, pos_vec + i < seq_len)
+                    cur = jnp.where(ok, pos_vec + i, park)
+                    ctx = (
+                        jax.default_matmul_precision(precision)
+                        if precision
+                        else contextlib.nullcontext()
                     )
-                last = logits[:, -1, :]
-                # per-lane (seed, position)-derived keys: a seeded lane's
-                # stream is reproducible independent of the other lanes
-                # and of block splits (weak r4 #7 closed for lane mode)
-                nxt = _sample_per_lane(last, temperature, topp, seeds, cur)
-                nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
-                out = lax.dynamic_update_index_in_dim(out, nxt[:, 0], i, axis=0)
-                return nxt, cache, out
+                    with ctx:
+                        logits, cache = fwd(
+                            params, tok, cur, cache,
+                            attn_window=window,
+                            attn_park_threshold=park, logits_mode="last",
+                        )
+                    last = logits[:, -1, :]
+                    # per-lane (seed, position)-derived keys: a seeded lane's
+                    # stream is reproducible independent of the other lanes
+                    # and of block splits (weak r4 #7 closed for lane mode)
+                    nxt = _sample_per_lane(last, temperature, topp, seeds, cur)
+                    nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
+                    out = lax.dynamic_update_index_in_dim(out, nxt[:, 0], i, axis=0)
+                    return nxt, cache, out
 
-            out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
-            _, cache, out = lax.fori_loop(
-                0, n_steps, body, (token, cache, out0)
-            )
-            return out, cache
+                out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
+                _, cache, out = lax.fori_loop(
+                    0, n_steps, body, (token, cache, out0)
+                )
+                return out, cache
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            block = block.lower(*self._lane_arg_specs(n_steps)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = block
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+            return block
+
+        return self._build(
+            ("lane_block", n_steps, window), make,
+            lambda: self._lane_arg_specs(n_steps), origin,
         )
-        self._xlalint_after_compile(key)
-        return block
 
     def decode_lanes(
         self,
@@ -2296,54 +2166,36 @@ class InferenceEngine:
         decode block in the same scheduler tick. AOT-compiled and
         bucketed by draft length (spec_buckets) so no new shape compiles
         mid-serve; rehearse_admission pre-builds every bucket."""
-        key = ("lane_verify", t, window)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
-        precision = self._precision
-        fwd = self._fwd
-        park = self._park
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def vstep(params, tokens, cache, pos_vec, active):
-            cur = jnp.where(active, pos_vec, park)
-            ctx = (
-                jax.default_matmul_precision(precision)
-                if precision
-                else contextlib.nullcontext()
-            )
-            with ctx:
-                logits, cache = fwd(
-                    params, tokens, cur, cache,
-                    attn_window=window, attn_park_threshold=park,
-                    logits_mode="all", n_micro=self._pp_micro(t),
+        def make():
+            precision = self._precision
+            fwd = self._fwd
+            park = self._park
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def vstep(params, tokens, cache, pos_vec, active):
+                cur = jnp.where(active, pos_vec, park)
+                ctx = (
+                    jax.default_matmul_precision(precision)
+                    if precision
+                    else contextlib.nullcontext()
                 )
-            out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out = jnp.where(active[:, None], out, 0)
-            return out, cache
+                with ctx:
+                    logits, cache = fwd(
+                        params, tokens, cur, cache,
+                        attn_window=window, attn_park_threshold=park,
+                        logits_mode="all", n_micro=self._pp_micro(t),
+                    )
+                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out = jnp.where(active[:, None], out, 0)
+                return out, cache
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            vstep = vstep.lower(*self._lane_verify_arg_specs(t)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = vstep
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+            return vstep
+
+        return self._build(
+            ("lane_verify", t, window), make,
+            lambda: self._lane_verify_arg_specs(t), origin,
         )
-        self._xlalint_after_compile(key)
-        return vstep
 
     def verify_lanes(
         self,
@@ -2603,44 +2455,26 @@ class InferenceEngine:
         _lane_prefill_fn against the draft params/cache. Full attention
         reads (window 0): the draft is small enough that windowing buys
         nothing over its whole seqLen."""
-        key = ("draft_prefill", t)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
         self._require_draft_model()
-        dfwd = self._draft_fwd
-        park = self._draft_park()
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def step(params, tokens, cache, pos_vec):
-            _, cache = dfwd(
-                params, tokens, pos_vec, cache,
-                attn_park_threshold=park, logits_mode="last",
-            )
-            return cache
+        def make():
+            dfwd = self._draft_fwd
+            park = self._draft_park()
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            step = step.lower(*self._draft_prefill_arg_specs(t)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = step
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+            @partial(jax.jit, donate_argnums=(2,))
+            def step(params, tokens, cache, pos_vec):
+                _, cache = dfwd(
+                    params, tokens, pos_vec, cache,
+                    attn_park_threshold=park, logits_mode="last",
+                )
+                return cache
+
+            return step
+
+        return self._build(
+            ("draft_prefill", t), make,
+            lambda: self._draft_prefill_arg_specs(t), origin,
         )
-        self._xlalint_after_compile(key)
-        return step
 
     def _draft_step_arg_specs(self, n_steps: int):
         b = self.batch_size
@@ -2661,60 +2495,42 @@ class InferenceEngine:
         minus sampling — drafts only ever seed a greedy verify, so plain
         argmax is the whole sampler. One host dispatch proposes k tokens
         for every drafting lane at once."""
-        key = ("draft_step", n_steps)
-        with self._compile_lock:
-            if key in self._compiled:
-                return self._compiled[key]
-            ev = self._inflight.get(key) if origin == "dispatch" else None
-        if ev is not None:
-            ev.wait()
-            with self._compile_lock:
-                if key in self._compiled:
-                    return self._compiled[key]
         self._require_draft_model()
-        dfwd = self._draft_fwd
-        park = self._draft_park()
-        dseq = self._draft_header.seq_len
 
-        @partial(jax.jit, donate_argnums=(2,))
-        def block(params, token, cache, pos_vec, active):
-            def body(i, carry):
-                tok, cache, out = carry
-                ok = jnp.logical_and(active, pos_vec + i < dseq)
-                cur = jnp.where(ok, pos_vec + i, park)
-                logits, cache = dfwd(
-                    params, tok, cur, cache,
-                    attn_park_threshold=park, logits_mode="last",
+        def make():
+            dfwd = self._draft_fwd
+            park = self._draft_park()
+            dseq = self._draft_header.seq_len
+
+            @partial(jax.jit, donate_argnums=(2,))
+            def block(params, token, cache, pos_vec, active):
+                def body(i, carry):
+                    tok, cache, out = carry
+                    ok = jnp.logical_and(active, pos_vec + i < dseq)
+                    cur = jnp.where(ok, pos_vec + i, park)
+                    logits, cache = dfwd(
+                        params, tok, cur, cache,
+                        attn_park_threshold=park, logits_mode="last",
+                    )
+                    nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+                    nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
+                    out = lax.dynamic_update_index_in_dim(
+                        out, nxt[:, 0], i, axis=0
+                    )
+                    return nxt, cache, out
+
+                out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
+                _, cache, out = lax.fori_loop(
+                    0, n_steps, body, (token, cache, out0)
                 )
-                nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-                nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
-                out = lax.dynamic_update_index_in_dim(
-                    out, nxt[:, 0], i, axis=0
-                )
-                return nxt, cache, out
+                return out, cache
 
-            out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
-            _, cache, out = lax.fori_loop(
-                0, n_steps, body, (token, cache, out0)
-            )
-            return out, cache
+            return block
 
-        self.recorder.record("compile_start", key=str(key), origin=origin)
-        t0 = time.perf_counter()
-        if self._aot_blocks:
-            block = block.lower(*self._draft_step_arg_specs(n_steps)).compile()
-        dt = time.perf_counter() - t0
-        with self._compile_lock:
-            self._compiled[key] = block
-            self._compile_origin[key] = origin
-            if self._aot_blocks:
-                self._compile_seconds[key] = dt
-        self._m_compiles.labels(origin=origin).inc()
-        self.recorder.record(
-            "compile_end", key=str(key), origin=origin, s=round(dt, 4)
+        return self._build(
+            ("draft_step", n_steps), make,
+            lambda: self._draft_step_arg_specs(n_steps), origin,
         )
-        self._xlalint_after_compile(key)
-        return block
 
     def draft_prefill(self, lane: int, tokens: list[int], pos0: int) -> None:
         """Catch the draft cache up on `lane`: write `tokens` (context
@@ -3190,9 +3006,9 @@ class InferenceEngine:
         return self._xlalint_baseline
 
     def _xlalint_after_compile(self, key) -> None:
-        """Lint ONE just-compiled program (called at the end of every
-        builder fn, so dispatch compiles, window prefetches, and
-        rehearse_admission all pass through). Warn-by-default;
+        """Lint ONE just-compiled program (called by `_build`, so
+        dispatch compiles, window prefetches, and rehearse_admission all
+        pass through). Warn-by-default;
         DLLAMA_XLALINT=strict raises XlalintError, =0/off disables.
         Lint bugs themselves must never take down a serving compile, so
         non-strict mode swallows analysis errors after logging them."""

@@ -22,7 +22,7 @@ from dllama_tpu.analysis.rules_metrics import MetricsDocsRule  # noqa: E402
 
 
 def main() -> int:
-    repo = collect_repo(REPO, ["dllama_tpu", "bench.py"])
+    repo = collect_repo(REPO, ["dllama_tpu"])
     findings, _ = run_rules(repo, [MetricsDocsRule()])
     for f in findings:
         print(f.render())

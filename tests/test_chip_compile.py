@@ -575,20 +575,3 @@ def test_qmatmul_q40i4(one_chip, m, k, n):
         sds((k // 2, n), jnp.int8, one_chip),
         sds((k // 32, n), jnp.float16, one_chip),
     )
-
-
-@pytest.mark.parametrize("m", [1, 128])
-@pytest.mark.parametrize("k,n", SHAPES + [(D, V // 4), (FF // 4, D)])
-def test_i8matmul_q40i8(one_chip, m, k, n):
-    """--weight-format q40i8, the w2 down-projection (k=14336, 28 groups of
-    512, 7 per k block) and its tp=4 shard (k=3584) included."""
-    from dllama_tpu.ops.int8_matmul import i8matmul_2d
-
-    g = 512
-    compiled_text(
-        i8matmul_2d,
-        sds((m, k), jnp.int8, one_chip),
-        sds((m, k // g), jnp.float32, one_chip),
-        sds((k, n), jnp.int8, one_chip),
-        sds((k // g, n), jnp.float32, one_chip),
-    )

@@ -953,3 +953,276 @@ def test_recorder_captures_engine_events(tiny_model):
     assert completes and all(e["ms"] >= 0 for e in completes)
     steps = {e.get("step") for e in completes}
     assert "prefill" in steps and "decode_block" in steps
+
+
+# -- the one build path (`InferenceEngine._build`) ---------------------------
+#
+# Every compiled program enters `_compiled` through `_build`; the benchmark
+# reads what it records (`compiles_in_window`, `correct`, `_compile_origin`).
+# One engine serves all of these tests: each case builds under a key of its
+# own, so no case sees another's program.
+
+
+@pytest.fixture(scope="module")
+def build_engine(tmp_path_factory):
+    """A tiny two-lane engine that can build every kind of program: the
+    slab's, the pool-native ones (a native pool), the draft's."""
+    mp = str(tmp_path_factory.mktemp("build") / "b.m")
+    cfg = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
+               head_dim=16, vocab_size=288, seq_len=64)
+    make_tiny_model(mp, weight_type=FloatType.F32, cfg=cfg)
+    e = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
+                        batch_size=2, prefill_buckets=(8, 16))
+    e.init_kv_pool(4, 40, native=True)
+    e.init_draft_model(mp)
+    return e
+
+
+def _w(e):
+    return e._attn_window(1)
+
+
+# kind -> (key, builder call) for a size `n` and an origin
+PROGRAMS = {
+    "block": lambda e, n, o: (
+        ("block", n, True, _w(e)),
+        lambda: e._decode_block_fn(n, True, _w(e), origin=o)),
+    "lane_prefill": lambda e, n, o: (
+        ("lane_prefill", n, _w(e)),
+        lambda: e._lane_prefill_fn(n, window=_w(e), origin=o)),
+    "kv_adopt": lambda e, n, o: (
+        ("kv_adopt", n), lambda: e._kv_copy_fn("adopt", n, origin=o)),
+    "kv_publish": lambda e, n, o: (
+        ("kv_publish", n), lambda: e._kv_copy_fn("publish", n, origin=o)),
+    "kv_page_copy": lambda e, n, o: (
+        ("kv_page_copy", n), lambda: e._kv_page_copy_fn(n, origin=o)),
+    "lane_block_paged": lambda e, n, o: (
+        ("lane_block_paged", n, _w(e)),
+        lambda: e._lane_decode_paged_fn(n, _w(e), origin=o)),
+    "lane_verify_paged": lambda e, n, o: (
+        ("lane_verify_paged", n, _w(e)),
+        lambda: e._lane_verify_paged_fn(n, _w(e), origin=o)),
+    "lane_prefill_paged": lambda e, n, o: (
+        ("lane_prefill_paged", n, _w(e)),
+        lambda: e._lane_prefill_paged_fn(n, _w(e), origin=o)),
+    "lane_block": lambda e, n, o: (
+        ("lane_block", n, _w(e)),
+        lambda: e._lane_decode_fn(n, _w(e), origin=o)),
+    "lane_verify": lambda e, n, o: (
+        ("lane_verify", n, _w(e)),
+        lambda: e._lane_verify_fn(n, _w(e), origin=o)),
+    "draft_prefill": lambda e, n, o: (
+        ("draft_prefill", n), lambda: e._draft_prefill_fn(n, origin=o)),
+    "draft_step": lambda e, n, o: (
+        ("draft_step", n), lambda: e._draft_step_fn(n, origin=o)),
+    # lazily jitted: no arg specs, one deferred `compile` record
+    "step": lambda e, n, o: (
+        (n, True, _w(e)), lambda: e._step_fn(n, True, _w(e))),
+}
+SIZE_OF = {"dispatch": 2, "prefetch": 3}
+
+
+def _compile_events(e, key, since):
+    return [
+        (ev["kind"], ev["origin"]) for ev in e.recorder.events()
+        if ev["seq"] > since and ev["kind"].startswith("compile")
+        and ev.get("key") == str(key)
+    ]
+
+
+@pytest.mark.parametrize(
+    "kind,origin",
+    [(k, o) for k in PROGRAMS if k != "step" for o in SIZE_OF]
+    + [("step", "dispatch")],
+)
+def test_program_build_path(build_engine, kind, origin):
+    """The first call of a builder records exactly one `compile_start` /
+    `compile_end` pair (a lazily jitted program: one deferred `compile`)
+    under its key and origin, and fills `_compile_origin` and, when
+    compiled ahead of time, `_compile_seconds`; the second call returns
+    the same program and records nothing."""
+    e = build_engine
+    key, build = PROGRAMS[kind](e, SIZE_OF[origin], origin)
+    assert key not in e._compiled
+    counted = e._m_compiles.labels(origin=origin)
+    n0, seq0 = counted.value, e.recorder.total_recorded
+    fn = build()
+    if kind == "step":
+        want = [("compile", "dispatch")]
+        assert key not in e._compile_seconds
+    else:
+        want = [("compile_start", origin), ("compile_end", origin)]
+        assert e._aot_blocks and e._compile_seconds[key] >= 0
+        assert isinstance(fn, jax.stages.Compiled)
+        end = [ev for ev in e.recorder.events("compile_end")
+               if ev["key"] == str(key)]
+        assert end[-1]["s"] == round(e._compile_seconds[key], 4)
+    assert _compile_events(e, key, seq0) == want
+    assert e._compiled[key] is fn
+    assert e._compile_origin[key] == origin
+    assert counted.value == n0 + 1
+    seq1 = e.recorder.total_recorded
+    assert build() is fn
+    assert _compile_events(e, key, seq1) == []
+    assert counted.value == n0 + 1
+
+
+LANE_PROGRAMS = ["lane_prefill", "lane_block", "lane_verify"]
+
+
+@pytest.mark.parametrize("kind", LANE_PROGRAMS)
+def test_dispatch_waits_for_an_inflight_prefetch(build_engine, kind):
+    """A dispatch that finds a prefetch thread building its program waits
+    for it and takes that program: one build, origin `prefetch`."""
+    import threading
+
+    e = build_engine
+    key, prefetch = PROGRAMS[kind](e, 5, "prefetch")
+    _, dispatch = PROGRAMS[kind](e, 5, "dispatch")
+    gate = threading.Event()
+    seq0 = e.recorder.total_recorded
+
+    def held_prefetch():
+        assert gate.wait(60)
+        prefetch()
+
+    e._prefetch(key, held_prefetch)
+    got = []
+    t = threading.Thread(target=lambda: got.append(dispatch()), daemon=True)
+    t.start()
+    t.join(0.3)
+    assert t.is_alive() and not got, "the dispatch did not wait"
+    assert key not in e._compiled
+    gate.set()
+    t.join(120)
+    assert not t.is_alive()
+    assert got == [e._compiled[key]]
+    assert e._compile_origin[key] == "prefetch"
+    assert _compile_events(e, key, seq0) == [
+        ("compile_start", "prefetch"), ("compile_end", "prefetch")]
+    with e._compile_lock:
+        assert key not in e._inflight
+
+
+@pytest.mark.parametrize("kind", LANE_PROGRAMS)
+def test_failed_prefetch_is_marked(build_engine, kind, monkeypatch, caplog):
+    """A build that fails on a prefetch thread is logged and marked
+    `prefetch-failed` (what the benchmark's `compile_admission_path`
+    reads), releases its in-flight slot, and leaves the dispatch path
+    able to build the program itself."""
+    import logging
+    import time
+
+    e = build_engine
+    key, prefetch = PROGRAMS[kind](e, 6, "prefetch")
+    _, dispatch = PROGRAMS[kind](e, 6, "dispatch")
+    failed = e._m_compiles.labels(origin="prefetch-failed")
+    n0 = failed.value
+
+    def refuse(fn):
+        raise RuntimeError("synthetic lowering failure")
+
+    with monkeypatch.context() as m, caplog.at_level(
+        logging.ERROR, logger="dllama_tpu.runtime.engine"
+    ):
+        m.setattr(jax.stages.Lowered, "compile", refuse)
+        e._prefetch(key, prefetch)
+        deadline = time.monotonic() + 60
+        while key in e._inflight and time.monotonic() < deadline:
+            time.sleep(0.01)
+    assert e._compile_origin[key] == "prefetch-failed"
+    assert key not in e._compiled
+    assert failed.value == n0 + 1
+    assert any("prefetch failed" in r.message for r in caplog.records)
+    with e._compile_lock:
+        assert key not in e._inflight
+    assert dispatch() is e._compiled[key]
+    assert e._compile_origin[key] == "dispatch"
+
+
+# what `benchmark/harness/server.py` takes from the engine by name, with
+# the harness's own argument shapes (`compile_admission_path`,
+# `build_programs`): this PR may not edit the harness, so these are the
+# calls that must keep working, on the slab path the cells run
+
+
+@pytest.fixture(scope="module")
+def slab_engine(tmp_path_factory):
+    mp = str(tmp_path_factory.mktemp("slab") / "s.m")
+    cfg = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
+               head_dim=16, vocab_size=288, seq_len=1024)
+    make_tiny_model(mp, weight_type=FloatType.F32, cfg=cfg)
+    e = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
+                        batch_size=2, prefill_buckets=(8, 16))
+    e.init_kv_pool(4, 40)
+    return e
+
+
+BLOCK = 4
+
+
+def _harness_rehearse_admission(e):
+    e.rehearse_admission(BLOCK, wait=True)
+    assert [k for k, o in e._compile_origin.items()
+            if o == "prefetch-failed"] == []
+    with e._compile_lock:
+        assert not e._inflight
+    built = {k for k, o in e._compile_origin.items() if o == "prefetch"}
+    assert {("lane_block", BLOCK, 512), ("kv_adopt", 1), ("kv_publish", 1)} | {
+        ("lane_prefill", b, 512) for b in e.prefill_buckets} <= built
+
+
+def _harness_attn_window(e):
+    ctx = e.header.seq_len
+    assert e._attn_window(1) == e._attn_window(min(ctx, 100 + 16)) == 512
+    assert e._attn_window(512 + 1) == e._attn_window(min(ctx, 900 + BLOCK)) == ctx
+
+
+def _harness_prefill_buckets(e):
+    assert e.prefill_buckets == (8, 16) and max(e.prefill_buckets) == 16
+
+
+def _harness_lane_prefill_fn(e):
+    for bucket in e.prefill_buckets:
+        fn = e._lane_prefill_fn(bucket, window=1024, origin="prefetch")
+        assert fn is e._compiled[("lane_prefill", bucket, 1024)]
+        assert e._compile_origin[("lane_prefill", bucket, 1024)] == "prefetch"
+
+
+def _harness_lane_decode_fn(e):
+    fn = e._lane_decode_fn(BLOCK, 1024, origin="prefetch")
+    assert fn is e._compiled[("lane_block", BLOCK, 1024)]
+    assert e._compile_origin[("lane_block", BLOCK, 1024)] == "prefetch"
+    assert e._lane_decode_fn(BLOCK, 1024) is fn  # the scheduler's dispatch
+
+
+HARNESS_CALLS = {
+    "rehearse_admission": _harness_rehearse_admission,
+    "_attn_window": _harness_attn_window,
+    "prefill_buckets": _harness_prefill_buckets,
+    "_lane_prefill_fn": _harness_lane_prefill_fn,
+    "_lane_decode_fn": _harness_lane_decode_fn,
+}
+
+
+@pytest.mark.parametrize("name", list(HARNESS_CALLS))
+def test_what_the_benchmark_harness_calls(slab_engine, name):
+    HARNESS_CALLS[name](slab_engine)
+
+
+def test_weight_format_q40i8_is_refused_by_the_engine(tiny_model):
+    mp, _ = tiny_model
+    with pytest.raises(ValueError, match="weight_format must be"):
+        InferenceEngine(mp, tp=1, dtype=jnp.float32, weight_format="q40i8")
+
+
+def test_weight_format_q40i8_is_refused_by_the_parser(capsys):
+    from dllama_tpu.cli import _build_parser
+
+    with pytest.raises(SystemExit):
+        _build_parser().parse_args(
+            ["inference", "--model", "m.m", "--weight-format", "q40i8"])
+    assert "invalid choice: 'q40i8'" in capsys.readouterr().err
+    args = _build_parser().parse_args(
+        ["inference", "--model", "m.m", "--weight-format", "q40i4"])
+    assert args.weight_format == "q40i4"

@@ -171,29 +171,6 @@ def test_forward_parked_lane_isolation(tmp_path):
     assert np.abs(k2[:, 1, :, s : s + 2]).max() > 0.0  # parked writes landed
 
 
-def test_moe_gather_decode_matches_dense_routing(tmp_path):
-    """The decode-path gather MoE (active experts only) must reproduce the
-    dense-routing MoE logits exactly: decode T=1 steps vs full prefill."""
-    h, params, _ = build(tmp_path, arch=LlmArch.QWEN3_MOE)
-    tokens = jnp.asarray([TOKENS], dtype=jnp.int32)
-    cache = init_kv_cache(h, batch_size=1)
-    # prefill uses dense routing (T=8 > 4)
-    full_logits, _ = forward(params, h, tokens, jnp.int32(0), cache)
-
-    # step-by-step decode with the gather path forced on (T=1)
-    cache = init_kv_cache(h, batch_size=1)
-    step_logits = []
-    for i, t in enumerate(TOKENS):
-        lg, cache = forward(
-            params, h, jnp.asarray([[t]], dtype=jnp.int32), jnp.int32(i), cache,
-            moe_gather_max_tokens=4,
-        )
-        step_logits.append(np.asarray(lg)[0, 0])
-    np.testing.assert_allclose(
-        np.asarray(full_logits)[0], np.stack(step_logits), rtol=1e-4, atol=1e-4
-    )
-
-
 def test_fused_load_no_mesh_matches_unfused(tmp_path):
     """Params loaded with fuse=2 (tp-interleaved wqkv/w13) run through
     forward with NO mesh must still match the unfused load bit-for-policy:
@@ -484,3 +461,56 @@ def test_forward_moves_only_the_rows_it_writes(tmp_path, kv, per_lane):
             # uniform(0.5, 1.5) and the scales never equal a projection
             moved = new_a[:, lane][:, :, rows] != old_a[:, lane][:, :, rows]
             assert moved.any(axis=-1).all(), (lane, p)
+
+
+# -- the int8 cache's row quantizer (ops/kv_cache.quantize_kv_rows) ---------
+
+
+def test_quantize_kv_rows_round_trip_error():
+    """One scale per cache row, max|row| / 127: a value comes back within
+    half a step of that row's scale, and the row's largest is exact."""
+    from dllama_tpu.ops.kv_cache import QuantKV, dequant_kv, quantize_kv_rows
+
+    rng = np.random.default_rng(0)
+    val = jnp.asarray(rng.standard_normal((2, 3, 5, 16)).astype(np.float32))
+    q, s = quantize_kv_rows(val)
+    assert q.dtype == jnp.int8 and q.shape == val.shape
+    assert s.dtype == jnp.float32 and s.shape == (2, 3, 5, 1)
+    np.testing.assert_allclose(
+        np.asarray(s)[..., 0], np.abs(np.asarray(val)).max(-1) / 127.0,
+        rtol=1e-6)
+    back = np.asarray(dequant_kv(QuantKV(q, s), jnp.float32))
+    err = np.abs(back - np.asarray(val))
+    assert (err <= np.asarray(s) * 0.5 * (1 + 1e-5)).all()
+    assert np.abs(np.asarray(q)).max(-1).min() == 127
+
+
+def test_quantize_kv_rows_zero_row():
+    """An all-zero row (a cache row never written) takes scale 1: nothing
+    divides by zero and the row comes back as zeros."""
+    from dllama_tpu.ops.kv_cache import quantize_kv_rows
+
+    val = jnp.ones((4, 16), jnp.bfloat16).at[2].set(0)
+    q, s = quantize_kv_rows(val)
+    assert np.isfinite(np.asarray(s)).all()
+    assert float(s[2, 0]) == 1.0
+    np.testing.assert_array_equal(np.asarray(q[2]), 0)
+    np.testing.assert_array_equal(np.asarray(q[0]), 127)
+
+
+def test_quantize_kv_rows_rows_are_independent():
+    """A row's values and scale depend on that row alone, whatever the
+    leading axes (a `[L, B, KH, T, hd]` write or one flat `[T, hd]`), and
+    the row width need not be a multiple of anything."""
+    from dllama_tpu.ops.kv_cache import quantize_kv_rows
+
+    rng = np.random.default_rng(1)
+    val = jnp.asarray(rng.standard_normal((2, 2, 3, 4, 20)).astype(np.float32))
+    q, s = quantize_kv_rows(val)
+    qf, sf = quantize_kv_rows(val.reshape(-1, 20))
+    np.testing.assert_array_equal(np.asarray(q).reshape(-1, 20), np.asarray(qf))
+    np.testing.assert_array_equal(np.asarray(s).reshape(-1, 1), np.asarray(sf))
+    louder = val.at[0, 0, 0, 0].multiply(100.0)
+    q2, s2 = quantize_kv_rows(louder)
+    np.testing.assert_array_equal(np.asarray(q2)[1:], np.asarray(q)[1:])
+    np.testing.assert_array_equal(np.asarray(s2)[0, 0, 0, 1:], np.asarray(s)[0, 0, 0, 1:])

@@ -372,9 +372,6 @@ def test_weight_bytes_per_token_formats():
     assert weight_bytes_per_token(h, "q40") == int(base * 1.125)
     assert weight_bytes_per_token(h, "bf16") == base * 2
     assert weight_bytes_per_token(h, "q40i4") == int(base * 0.5625)
-    assert weight_bytes_per_token(h, "q40i8", i8_group=64) == int(
-        base * (1 + 4 / 64)
-    )
 
 
 def test_roofline_report_degrades_without_tpu():
