@@ -90,6 +90,34 @@ def _topp_mask(probs, topp):
     return jnp.where(topp_valid, masked, probs)
 
 
+def _sample(logits, temperature, topp, counts, draw):
+    """The one sampling rule, [B, V] f32 -> [B] int32. Lanes at
+    temperature 0 take the greedy argmax, the others a `draw` (from
+    [B, V] log-probabilities to [B] ids) under the host sampler's
+    selection rule (see _topp_mask). Softmax, vocabulary sort, running
+    sum and draw sit in a branch the device enters only when a lane that
+    `counts` (a [B] mask: live, inside its window) samples; a batch with
+    none costs one argmax. The branch that samples is the unconditional
+    formula whole, so its ids do not depend on which lanes are greedy."""
+    b = logits.shape[0]
+    temp_col = jnp.broadcast_to(
+        jnp.atleast_1d(jnp.asarray(temperature, jnp.float32)), (b,)
+    )[:, None]
+
+    def greedy_side():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def sampled_side():
+        probs = _topp_mask(
+            jax.nn.softmax(logits / jnp.maximum(temp_col, 1e-6), axis=-1), topp
+        )
+        sampled = draw(jnp.log(probs + 1e-30)).astype(jnp.int32)
+        return jnp.where(temp_col[:, 0] <= 0.0, greedy_side(), sampled)
+
+    engaged = jnp.any(jnp.logical_and(counts, temp_col[:, 0] > 0.0))
+    return lax.cond(engaged, sampled_side, greedy_side)
+
+
 @jax.named_scope("sample")
 def _sample_on_device(logits, temperature, topp, key):
     """Temperature + top-p sampling on device, [B, V] f32 -> [B] int32;
@@ -102,43 +130,31 @@ def _sample_on_device(logits, temperature, topp, key):
     per-token host round trips. Seeded runs are reproducible, just under a
     different (documented) RNG than the reference.
     """
-    b = logits.shape[0]
-    temp_col = jnp.broadcast_to(
-        jnp.atleast_1d(jnp.asarray(temperature, jnp.float32)), (b,)
-    )[:, None]
-    probs = _topp_mask(
-        jax.nn.softmax(logits / jnp.maximum(temp_col, 1e-6), axis=-1), topp
+    return _sample(
+        logits, temperature, topp, jnp.ones(logits.shape[:1], jnp.bool_),
+        lambda logp: jax.random.categorical(key, logp, axis=-1),
     )
-    sampled = jax.random.categorical(
-        key, jnp.log(probs + 1e-30), axis=-1
-    ).astype(jnp.int32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(temp_col[:, 0] <= 0.0, greedy, sampled)
 
 
 @jax.named_scope("sample")
-def _sample_per_lane(logits, temperature, topp, seeds, positions):
+def _sample_per_lane(logits, temperature, topp, seeds, positions, counts):
     """Per-LANE seeded sampling: lane l's key derives from (seeds[l],
     positions[l]) only, so a seeded request's draws are reproducible
     regardless of which other lanes are active and of how the block
     decode is split (the key depends on the absolute position, not the
-    block offset). Greedy lanes (temperature 0) ignore the key."""
-    b = logits.shape[0]
-    temp_col = jnp.broadcast_to(
-        jnp.atleast_1d(jnp.asarray(temperature, jnp.float32)), (b,)
-    )[:, None]
-    probs = _topp_mask(
-        jax.nn.softmax(logits / jnp.maximum(temp_col, 1e-6), axis=-1), topp
-    )
-    logp = jnp.log(probs + 1e-30)
-    keys = jax.vmap(
-        lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-    )(seeds, positions)
-    sampled = jax.vmap(
-        lambda k, row: jax.random.categorical(k, row)
-    )(keys, logp).astype(jnp.int32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(temp_col[:, 0] <= 0.0, greedy, sampled)
+    block offset). Greedy lanes (temperature 0) ignore the key, and a
+    lane outside `counts` (parked) engages nothing whatever its
+    temperature."""
+
+    def draw(logp):
+        keys = jax.vmap(
+            lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
+        )(seeds, positions)
+        return jax.vmap(lambda k, row: jax.random.categorical(k, row))(
+            keys, logp
+        )
+
+    return _sample(logits, temperature, topp, counts, draw)
 
 
 @dataclasses.dataclass
@@ -232,6 +248,13 @@ class InferenceEngine:
             "Per-token share of a block decode dispatch (dispatch wall "
             "time / tokens in the block).",
             buckets=DEFAULT_TOKEN_BUCKETS_S,
+        )
+        self._m_sampler = self.obs.counter(
+            "dllama_engine_decode_lanes_blocks_total",
+            "decode_lanes blocks by what the sampler ran on the device: "
+            "greedy = the argmax alone (no live lane had temperature > 0), "
+            "full = softmax, top-p sort and draw on every step.",
+            labelnames=("sampler",),
         )
         self._m_kv_copy_bytes = self.obs.counter(
             "dllama_kv_copy_bytes_total",
@@ -1818,7 +1841,9 @@ class InferenceEngine:
                             attn_park_threshold=window, logits_mode="last",
                         )
                     last = logits[:, -1, :]
-                    nxt = _sample_per_lane(last, temperature, topp, seeds, cur)
+                    nxt = _sample_per_lane(
+                        last, temperature, topp, seeds, cur, ok
+                    )
                     nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
                     out = lax.dynamic_update_index_in_dim(
                         out, nxt[:, 0], i, axis=0
@@ -1953,7 +1978,8 @@ class InferenceEngine:
         """Per-lane block decode: every lane advances from its own
         position; inactive lanes are parked (fed token 0, writing only
         padding rows). Sampling settings are per-lane vectors (temperature
-        0 = greedy argmax inside _sample_on_device), so ONE compiled
+        0 = greedy argmax inside _sample; a block none of whose live
+        lanes samples runs that argmax and nothing else), so ONE compiled
         program serves any mix of requests. One host dispatch per block,
         like decode_block. `window` bounds attention reads by the deepest
         live lane (parked writes land beyond seq_len and are causally
@@ -1996,7 +2022,9 @@ class InferenceEngine:
                     # per-lane (seed, position)-derived keys: a seeded lane's
                     # stream is reproducible independent of the other lanes
                     # and of block splits (weak r4 #7 closed for lane mode)
-                    nxt = _sample_per_lane(last, temperature, topp, seeds, cur)
+                    nxt = _sample_per_lane(
+                        last, temperature, topp, seeds, cur, ok
+                    )
                     nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
                     out = lax.dynamic_update_index_in_dim(out, nxt[:, 0], i, axis=0)
                     return nxt, cache, out
@@ -2055,6 +2083,9 @@ class InferenceEngine:
             temperature = [self.temperature] * self.batch_size
         if topp is None:
             topp = [self.sampler.topp] * self.batch_size
+        # the program's own predicate (_sample) engages on the same lanes,
+        # less one that fills its window inside the block
+        n_sampling = sum(1 for i in live if temperature[i] > 0.0)
         arr = jax.device_put(
             jnp.asarray([[t] for t in tokens], jnp.int32), self._token_sharding
         )
@@ -2113,7 +2144,7 @@ class InferenceEngine:
         guard = self._kv_pool_guard if native else self._cache_guard
         with self._dispatch(
             "decode_lanes", prep, pos=deepest, n_steps=n_steps,
-            window=window, n_live=len(live),
+            window=window, n_live=len(live), n_sampling=n_sampling,
         ) as timed, guard():
             if fault is not None:
                 raise fault
@@ -2130,6 +2161,7 @@ class InferenceEngine:
             out_np = self._read_back("decode_lanes", out)
         # each active stream advances one token per block row
         self._m_tpot.observe(timed["seconds"] / n_steps)
+        self._m_sampler.labels(sampler="full" if n_sampling else "greedy").inc()
         return [[int(t) for t in row] for row in out_np]
 
     def _lane_verify_arg_specs(self, t: int):

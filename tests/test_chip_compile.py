@@ -361,6 +361,90 @@ def test_layer_scan_writes_cache_rows_in_place_tp4(tp4, monkeypatch):
     assert not cache_copies(text, shard), cache_copies(text, shard)
 
 
+def hlo_computations(text: str) -> dict[str, list[str]]:
+    """An HLO module's computations by name, each with its instructions."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and "(" in line and " = " not in line.split("(")[0]:
+            lines = comps.setdefault(
+                line.split("(")[0].split()[-1].lstrip("%"), [])
+        elif lines is not None:
+            lines.append(line.strip())
+    return comps
+
+
+def hlo_reach(comps, roots, skip=" conditional(") -> set[str]:
+    """Computations reached from `roots` through `calls=`, `to_apply=`,
+    `body=`, `condition=` and `branch_computations=`, never through an
+    instruction that holds `skip`."""
+    import re
+
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            if skip and skip in line:
+                continue
+            for group in re.findall(
+                r"(?:calls|to_apply|body|condition|branch_computations)="
+                r"\{?([%\w.\-, ]+)\}?", line,
+            ):
+                todo += [g.strip().lstrip("%") for g in group.split(",")]
+    return seen
+
+
+def test_lane_block_keeps_the_sampler_conditional(one_chip):
+    """The engine's own `lane_block` loop at the sparse cell's size (16
+    lanes, vocabulary 151936, 8 steps), its forward pass stood in for by
+    an embedding row and the logits head: the chip's compiler keeps the
+    sampler's `cond` a `conditional` (it has not made it a select that
+    runs both sides), the vocabulary sort lies in one of its branches,
+    and the step loop's body reaches no sort any other way."""
+    import re
+    import types
+
+    from dllama_tpu.runtime.engine import InferenceEngine
+
+    lanes, vocab, dim = 16, 151936, 2048
+
+    def fwd(params, tok, cur, cache, **_):
+        x = params["emb"][tok[:, 0]]
+        cache = cache.at[:, 0].set(x[:, 0] + cur.astype(x.dtype))
+        return (x @ params["wcls"]).astype(jnp.float32)[:, None, :], cache
+
+    stand_in = types.SimpleNamespace(
+        _precision=None, _fwd=fwd, _park=4096,
+        header=types.SimpleNamespace(seq_len=4096),
+        _build=lambda key, make, specs, origin: make(),
+    )
+    block = InferenceEngine._lane_decode_fn(stand_in, 8, 1024)
+    vec = lambda dtype: sds((lanes,), dtype, one_chip)  # noqa: E731
+    with jax.default_matmul_precision("default"):
+        text = block.lower(
+            dict(emb=sds((vocab, dim), jnp.bfloat16, one_chip),
+                 wcls=sds((dim, vocab), jnp.bfloat16, one_chip)),
+            sds((lanes, 1), jnp.int32, one_chip),
+            sds((lanes, 8), jnp.bfloat16, one_chip),
+            vec(jnp.int32), vec(jnp.bool_), vec(jnp.int32),
+            vec(jnp.float32), vec(jnp.float32),
+        ).compile().as_text()
+    comps = hlo_computations(text)
+    conds = [l for lines in comps.values() for l in lines if " conditional(" in l]
+    assert len(conds) == 1 and "sample/cond" in conds[0], conds
+    branches = re.search(r"branch_computations=\{([^}]*)\}", conds[0]).group(1)
+    inside = hlo_reach(comps, [b.strip().lstrip("%") for b in branches.split(",")], skip="")
+    sorts = {name for name, lines in comps.items() if any(" sort(" in l for l in lines)}
+    assert sorts and sorts <= inside, (sorts, inside)
+    (loop,) = [l for lines in comps.values() for l in lines if " while(" in l]
+    body = re.search(r"body=%?([\w.\-]+)", loop).group(1)
+    outside = hlo_reach(comps, [body])  # the body itself among them
+    assert any(conds[0] in comps[c] for c in outside)
+    assert not sorts & outside, sorts & outside
+
+
 def test_cache_copies_flags_the_caches_as_scan_xs(one_chip):
     """The design before PR 29: the layer scan takes the caches as `xs` and
     gives them back as `ys`. Each step then slices the layer's whole cache

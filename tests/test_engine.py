@@ -823,6 +823,179 @@ def test_lane_seed_reproducible_across_lane_mix(tiny_model):
     assert [r[0] for r in out3] != lane0_a
 
 
+def _parent_sample(logits, temperature, topp, counts, draw):
+    """The sampler as it stood before `_sample` took a branch: softmax,
+    top-p mask and draw on every call, greedy lanes selected at the end.
+    `counts` is taken and ignored, as the parent had no such mask."""
+    from dllama_tpu.runtime.engine import _topp_mask
+
+    temp_col = jnp.broadcast_to(
+        jnp.atleast_1d(jnp.asarray(temperature, jnp.float32)),
+        logits.shape[:1],
+    )[:, None]
+    probs = _topp_mask(
+        jax.nn.softmax(logits / jnp.maximum(temp_col, 1e-6), axis=-1), topp
+    )
+    sampled = draw(jnp.log(probs + 1e-30)).astype(jnp.int32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.where(temp_col[:, 0] <= 0.0, greedy, sampled)
+
+
+# (temperature, top-p, lanes that count, whether the ids are the argmax's)
+SAMPLER_CASES = {
+    "all_greedy": ([0.0] * 4, [0.9] * 4, [True] * 4, True),
+    "mixed": ([0.0, 0.7, 0.0, 1.2], [0.9, 0.9, 0.5, 1.0], [True] * 4, False),
+    "one_of_four": ([0.0, 0.0, 0.8, 0.0], [0.9] * 4, [True] * 4, False),
+    "all_sampling_topp_inside": ([0.8] * 4, [0.9, 0.5, 0.3, 0.99], [True] * 4, False),
+    "all_sampling_topp_outside": ([0.8] * 4, [0.0, 1.0, 0.0, 1.0], [True] * 4, False),
+    "masked_lane_samples": ([0.0, 0.0, 0.8, 0.0], [0.9] * 4,
+                            [True, True, False, True], True),
+    "masked_lane_beside_sampling": ([0.0, 0.7, 0.8, 0.0], [0.9] * 4,
+                                    [True, True, False, True], False),
+}
+
+
+def _ids_with_and_without_the_branch(monkeypatch, sampler, *args):
+    """`sampler`'s ids as the engine has it, and with the parent's
+    unconditional formula in `_sample`'s place. A fresh lambda a call:
+    `jax.jit` keeps its traces by function, and the oracle's trace must
+    not be the first call's."""
+    from dllama_tpu.runtime import engine as eng
+
+    got = jax.jit(lambda *a: getattr(eng, sampler)(*a))(*args)
+    with monkeypatch.context() as m:
+        m.setattr(eng, "_sample", _parent_sample)
+        want = jax.jit(lambda *a: getattr(eng, sampler)(*a))(*args)
+    assert got.dtype == jnp.int32
+    return np.asarray(got), np.asarray(want)
+
+
+def _flat_logits():
+    return jax.random.normal(jax.random.PRNGKey(3), (4, 96)) * 0.5
+
+
+@pytest.mark.parametrize("case", list(SAMPLER_CASES))
+def test_sampler_branch_matches_the_unconditional_formula(case, monkeypatch):
+    """`_sample` takes its sampled side only when a lane that counts has
+    a temperature above 0, and that side is the parent's formula whole:
+    the ids are the oracle's bit for bit, with the same seeds. Where no
+    such lane exists the ids are the argmax's on every lane, a masked-out
+    lane at temperature 0.8 included (the oracle draws for it)."""
+    logits = _flat_logits()
+    argmax = np.argmax(np.asarray(logits), axis=-1)
+    temp, topp, counts, greedy = SAMPLER_CASES[case]
+    got, want = _ids_with_and_without_the_branch(
+        monkeypatch, "_sample_per_lane",
+        logits, jnp.asarray(temp, jnp.float32), jnp.asarray(topp, jnp.float32),
+        jnp.asarray([42, 7, 1234, 99], jnp.int32),
+        jnp.asarray([3, 17, 5, 250], jnp.int32), jnp.asarray(counts),
+    )
+    if greedy:
+        np.testing.assert_array_equal(got, argmax)
+        # the oracle drew for the masked lane: this batch took the other side
+        assert case == "all_greedy" or want[2] != argmax[2]
+    else:
+        np.testing.assert_array_equal(got, want)
+        assert (got != argmax).any()
+    live = np.asarray(counts)
+    np.testing.assert_array_equal(got[live], want[live])
+
+
+@pytest.mark.parametrize("temperature", [0.7, 0.0])
+def test_sample_on_device_scalar_temperature(temperature, monkeypatch):
+    """The one-stream sampler through the same helper, every lane
+    counting: the oracle's ids at 0.7, the argmax's at 0."""
+    logits = _flat_logits()
+    got, want = _ids_with_and_without_the_branch(
+        monkeypatch, "_sample_on_device",
+        logits, temperature, 0.9, jax.random.PRNGKey(11),
+    )
+    np.testing.assert_array_equal(got, want)
+    argmax = np.argmax(np.asarray(logits), axis=-1)
+    assert (got == argmax).all() == (temperature == 0.0)
+
+
+NEIGHBOURS = {
+    "greedy": dict(temperature=[0.7, 0.0, 0.0], seeds=[42, None, None]),
+    "sampling": dict(temperature=[0.7, 0.9, 1.1], seeds=[42, 7, None]),
+    "parked": dict(temperature=[0.7, 0.8, 0.8], seeds=[42, 5, 6],
+                   active=[True, False, False]),
+}
+
+
+def test_decode_lanes_serves_the_parents_ids(tiny_model, monkeypatch):
+    """Engine level: at temperature 0 `decode_lanes` gives the ids of the
+    one-stream `block` program built with `greedy=True` (an argmax and no
+    sampler, as before), and a seeded lane at 0.7 gives the same ids
+    whether its neighbours are greedy, sampling or parked: the ids an
+    engine gives whose sampler is the parent's unconditional formula."""
+    from dllama_tpu.runtime import engine as eng
+
+    mp, _ = tiny_model
+
+    def engine():
+        return InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
+                               batch_size=3)
+
+    def run(e, **kw):
+        e.reset()
+        first = e.decode_lanes([5, 9, 3], [0, 0, 0], 5, **kw)
+        rest = e.decode_lanes(first[-1], [5, 5, 5], 5, **kw)
+        return [r[0] for r in first + rest], first + rest
+
+    e = engine()
+    e.reset()
+    block = e.decode_block([5, 9, 3], 0, 10)
+    _, rows = run(e, temperature=[0.0] * 3)
+    assert rows == block
+    with monkeypatch.context() as m:
+        m.setattr(eng, "_sample", _parent_sample)
+        parent = engine()
+        want = {name: run(parent, **kw)[0] for name, kw in NEIGHBOURS.items()}
+        assert run(parent, temperature=[0.0] * 3)[1] == block
+    got = {name: run(e, **kw)[0] for name, kw in NEIGHBOURS.items()}
+    assert got == want
+    assert got["greedy"] == got["sampling"] == got["parked"]
+    assert got["greedy"] != [r[0] for r in block]
+    assert run(e, **NEIGHBOURS["sampling"])[0] == got["sampling"]
+
+
+def test_decode_lanes_counts_the_lanes_that_sample(tiny_model):
+    """`n_sampling` (live lanes with temperature > 0) rides on the
+    recorder's `step_dispatch` / `step_complete` events and the engine's
+    span beside `n_live`, and each block raises one of the sampler
+    counter's two labels. A parked lane's temperature counts for nothing."""
+    mp, _ = tiny_model
+    e = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.8,
+                        batch_size=2)
+    count = {k: e._m_sampler.labels(sampler=k) for k in ("greedy", "full")}
+
+    def block(**kw):
+        e.reset()
+        seq = e.recorder.total_recorded
+        before = {k: c.value for k, c in count.items()}
+        e.decode_lanes([5, 9], [0, 0], 4, **kw)
+        events = [
+            ev for kind in ("step_dispatch", "step_complete")
+            for ev in e.recorder.events(kind)
+            if ev["seq"] > seq and ev["step"] == "decode_lanes"
+        ]
+        assert len(events) == 2
+        assert {ev["n_live"] for ev in events} == {sum(kw.get("active", [1, 1]))}
+        (n,) = {ev["n_sampling"] for ev in events}
+        span = [s for s in e._spans.completed() if s["name"] == "decode_lanes"][-1]
+        assert span["attrs"]["n_sampling"] == n
+        return n, {k: c.value - before[k] for k, c in count.items()}
+
+    assert block(temperature=[0.0, 0.0]) == (0, {"greedy": 1, "full": 0})
+    assert block(temperature=[0.0, 0.7]) == (1, {"greedy": 0, "full": 1})
+    assert block(temperature=[0.0, 0.7], active=[True, False]) == (
+        0, {"greedy": 1, "full": 0})
+    # a direct caller that names no temperature gets the engine's own, 0.8
+    assert block() == (2, {"greedy": 0, "full": 1})
+    assert block(active=[False, True]) == (1, {"greedy": 0, "full": 1})
+
+
 def test_aot_specs_use_init_snapshot(tiny_model):
     """The AOT lowering specs are built from the init-time
     ShapeDtypeStruct snapshot, never from the live trees: a prefetch

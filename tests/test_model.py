@@ -308,15 +308,20 @@ def test_streamed_loader_memory_bound(tmp_path):
     assert stacked["hwm_gb"] - streamed["hwm_gb"] > 1.0, (stacked, streamed)
 
 
-def _layer_scans(jaxpr):
-    """Every `scan` equation of a jaxpr, sub-jaxprs included."""
+def _equations(jaxpr, path=()):
+    """(equation, names of the equations around it, outermost first) of
+    every equation of a jaxpr, sub-jaxprs included."""
     import jax
 
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
+        yield eqn, path
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _layer_scans(sub)
+            yield from _equations(sub, path + (eqn.primitive.name,))
+
+
+def _layer_scans(jaxpr):
+    """Every `scan` equation of a jaxpr, sub-jaxprs included."""
+    return (e for e, _ in _equations(jaxpr) if e.primitive.name == "scan")
 
 
 @pytest.mark.parametrize(
@@ -427,6 +432,55 @@ def test_layer_scan_carries_the_cache_whole(tmp_path, arch, kv, per_lane):
     for a in leaves:
         assert (a.dtype, a.shape) in carried, (a.dtype, a.shape, carried)
         carried.remove((a.dtype, a.shape))
+
+
+# what only the sampled side of the sampler may hold: the top-p sort and
+# running sum over [lanes, vocabulary], and the draw's key and bits
+SAMPLER_ONLY = {
+    "sort", "cumsum", "random_bits", "random_seed", "random_fold_in",
+    "random_wrap", "random_unwrap", "threefry2x32",
+}
+
+
+@pytest.mark.parametrize("program", ["lane_block", "lane_block_paged"])
+def test_sampler_work_sits_inside_the_conditional(tmp_path, monkeypatch, program):
+    """In the lane decode programs every sort, running sum and random-bits
+    equation lies inside a branch of the sampler's `cond`, itself inside
+    the block's loop over steps, and the loop's body holds none outside
+    it: a block whose live lanes are all greedy runs the argmax alone.
+    Unconditional, a `sort` of `f32[16,151936]` was 3.5 ms of a 16.7 ms
+    decode step at temperature 0 (PERF.md, PR 29 and PR 31)."""
+    import jax
+
+    from dllama_tpu.runtime.engine import InferenceEngine
+
+    # the lazily jitted function, which can still be traced
+    monkeypatch.setenv("DLLAMA_WINDOW_PRECOMPILE", "0")
+    model = str(tmp_path / "m.m")
+    make_tiny_model(model, cfg=dict(
+        dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
+        head_dim=16, vocab_size=288, seq_len=64))
+    e = InferenceEngine(model, tp=1, dtype=jnp.float32, temperature=0.0,
+                        batch_size=2)
+    if program == "lane_block":
+        fn, specs = e._lane_decode_fn(4, 64), e._lane_arg_specs(4)
+    else:
+        e.init_kv_pool(4, native=True)
+        fn = e._lane_decode_paged_fn(4, 64)
+        specs = e._lane_decode_paged_arg_specs(4)
+    found = [
+        (eqn.primitive.name, path)
+        for eqn, path in _equations(jax.make_jaxpr(fn)(*specs).jaxpr)
+        if eqn.primitive.name in SAMPLER_ONLY
+    ]
+    assert {"sort", "cumsum", "random_bits"} <= {name for name, _ in found}
+    for name, path in found:
+        loops = [i for i, p in enumerate(path) if p in ("scan", "while")]
+        assert "cond" in path and loops, (name, path)
+        assert path.index("cond") > loops[0], (name, path)
+    # and one conditional holds them all: the sampler's
+    conds = {path[: path.index("cond") + 1] for _, path in found}
+    assert len(conds) == 1, conds
 
 
 @pytest.mark.parametrize("per_lane", [False, True], ids=["scalar", "per_lane"])
