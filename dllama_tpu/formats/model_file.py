@@ -47,6 +47,10 @@ class LlmArch(enum.IntEnum):
     LLAMA = 0xABCD00
     QWEN3 = 0xABCD01
     QWEN3_MOE = 0xABCD02
+    # window and full attention layers mixed, gated attention, sandwich
+    # norms, a sigmoid router with a shared expert (`model_type: afmoe`);
+    # not in the reference, whose enum ends above
+    AFMOE = 0xABCD10
 
 
 class RopeType(enum.IntEnum):
@@ -89,6 +93,19 @@ class HeaderKey(enum.IntEnum):
     HEAD_DIM = 19
     NORM_EPSILON = 20
     MOE_HIDDEN_DIM = 21
+    # keys past the reference's (src/llm.hpp ends at 21); absent = 0, and
+    # 0 is what every architecture of the reference means by them
+    SLIDING_WINDOW = 22  # rows a window layer's query sees (0: no window layers)
+    FULL_ATTN_PERIOD = 23  # layer l attends in full where (l + 1) % period == 0
+    FULL_ATTN_NO_ROPE = 24  # 1: full layers take no rotary embedding
+    N_DENSE_LAYERS = 25  # leading layers with a dense FFN of HIDDEN_DIM
+    N_SHARED_EXPERTS = 26  # experts every token passes through
+    SCORE_FUNC = 27  # router scores: 0 softmax over all experts, 1 sigmoid
+    ROUTE_NORM = 28  # 0: the chosen scores are not divided by their sum (absent: they are)
+    ROUTE_SCALE_MILLI = 29  # the routed sum's factor, in thousandths (0: 1)
+    N_ROUTED_EXPERTS = 30  # the router's width, where N_EXPERTS are held here
+    FIRST_EXPERT = 31  # id of the first expert held here
+    EMBED_SCALE = 32  # 1: embeddings are multiplied by sqrt(DIM)
 
 
 @dataclasses.dataclass
@@ -118,6 +135,17 @@ class LlmHeader:
     head_dim: int = 0
     norm_epsilon: float = 1e-5
     moe_hidden_dim: int = 0
+    sliding_window: int = 0
+    full_attn_period: int = 0
+    full_attn_no_rope: bool = False
+    n_dense_layers: int = 0
+    n_shared_experts: int = 0
+    score_sigmoid: bool = False
+    route_norm: bool = True
+    route_scale: float = 1.0
+    n_routed_experts: int = 0
+    first_expert: int = 0
+    embed_scale: bool = False
     header_bytes: int = 0
     file_size: int = 0
     sync_type: FloatType = FloatType.Q80
@@ -133,7 +161,7 @@ class LlmHeader:
     @property
     def ff_dim(self) -> int:
         """Per-expert (MoE) or dense FFN intermediate dim (src/llm.cpp:152-157)."""
-        if self.arch == LlmArch.QWEN3_MOE:
+        if self.arch in (LlmArch.QWEN3_MOE, LlmArch.AFMOE):
             return self.moe_hidden_dim
         return self.hidden_dim
 
@@ -212,6 +240,28 @@ def read_llm_header(
                 h.norm_epsilon = _norm_epsilon(value)
             elif key == HeaderKey.MOE_HIDDEN_DIM:
                 h.moe_hidden_dim = value
+            elif key == HeaderKey.SLIDING_WINDOW:
+                h.sliding_window = value
+            elif key == HeaderKey.FULL_ATTN_PERIOD:
+                h.full_attn_period = value
+            elif key == HeaderKey.FULL_ATTN_NO_ROPE:
+                h.full_attn_no_rope = bool(value)
+            elif key == HeaderKey.N_DENSE_LAYERS:
+                h.n_dense_layers = value
+            elif key == HeaderKey.N_SHARED_EXPERTS:
+                h.n_shared_experts = value
+            elif key == HeaderKey.SCORE_FUNC:
+                h.score_sigmoid = bool(value)
+            elif key == HeaderKey.ROUTE_NORM:
+                h.route_norm = bool(value)
+            elif key == HeaderKey.ROUTE_SCALE_MILLI:
+                h.route_scale = value / 1000.0 if value else 1.0
+            elif key == HeaderKey.N_ROUTED_EXPERTS:
+                h.n_routed_experts = value
+            elif key == HeaderKey.FIRST_EXPERT:
+                h.first_expert = value
+            elif key == HeaderKey.EMBED_SCALE:
+                h.embed_scale = bool(value)
 
         if weight_type is None:
             raise ValueError("model does not specify weight type")
@@ -226,9 +276,49 @@ def read_llm_header(
     if h.head_dim == 0:
         h.head_dim = h.dim // h.n_heads
     h.sync_type = sync_type
-    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE):
+    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE):
         h.rope_type = RopeType.FALCON
+    if h.n_routed_experts == 0:
+        h.n_routed_experts = h.n_experts
+    if not 0 <= h.first_expert <= h.n_routed_experts - h.n_experts:
+        raise ValueError(
+            f"experts [{h.first_expert}, {h.first_expert + h.n_experts}) held "
+            f"of {h.n_routed_experts} routed over"
+        )
     return h
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One row of the layer table: what a layer is, as data. `row` is the
+    layer's place in its cache stack (the full layers' or the window
+    layers'), `ffn_row` its place among the layers of its FFN kind, whose
+    weights are stacked apart."""
+
+    window: bool  # attention: over the last `sliding_window` rows, or in full
+    rope: bool
+    experts: bool  # FFN: a mixture of experts, or dense
+    row: int
+    ffn_row: int
+
+
+def layer_table(h: LlmHeader) -> tuple[LayerKind, ...]:
+    """The kinds of the model's layers, read once from the header. The
+    reference's architectures are its uniform rows: every layer full, with
+    rope, and the same FFN."""
+    table, rows, ffn_rows = [], [0, 0], [0, 0]
+    for l in range(h.n_layers):
+        window = h.sliding_window > 0 and not (
+            h.full_attn_period and (l + 1) % h.full_attn_period == 0
+        )
+        experts = h.n_experts > 0 and l >= h.n_dense_layers
+        table.append(LayerKind(
+            window, window or not h.full_attn_no_rope, experts,
+            rows[window], ffn_rows[experts],
+        ))
+        rows[window] += 1
+        ffn_rows[experts] += 1
+    return tuple(table)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,27 +359,40 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
         specs.append(TensorSpec(name, ft, shape, offset, nbytes))
         offset += nbytes
 
+    afmoe = h.arch == LlmArch.AFMOE
+
+    def swiglu(prefix: str, width: int) -> None:
+        add(f"{prefix}.w1", wt, (width, h.dim))
+        add(f"{prefix}.w2", wt, (h.dim, width))
+        add(f"{prefix}.w3", wt, (width, h.dim))
+
     add("embed", FloatType.F32, (h.vocab_size, h.dim))
-    for l in range(h.n_layers):
+    for l, kind in enumerate(layer_table(h)):
         add(f"layers.{l}.q", wt, (h.q_dim, h.dim))
         add(f"layers.{l}.k", wt, (h.kv_dim, h.dim))
         add(f"layers.{l}.v", wt, (h.kv_dim, h.dim))
         add(f"layers.{l}.wo", wt, (h.dim, h.q_dim))
-        if h.n_experts > 0:
-            add(f"layers.{l}.moe_gate", FloatType.F32, (h.n_experts, h.dim))
+        if afmoe:  # the gate on the attention output, one value a channel
+            add(f"layers.{l}.att_gate", wt, (h.q_dim, h.dim))
+        if kind.experts:
+            add(f"layers.{l}.moe_gate", FloatType.F32, (h.n_routed_experts, h.dim))
+            if afmoe:
+                add(f"layers.{l}.expert_bias", FloatType.F32, (h.n_routed_experts,))
+            if h.n_shared_experts:
+                swiglu(f"layers.{l}.shared", h.n_shared_experts * h.ff_dim)
             for e in range(h.n_experts):
-                add(f"layers.{l}.experts.{e}.w1", wt, (h.ff_dim, h.dim))
-                add(f"layers.{l}.experts.{e}.w2", wt, (h.dim, h.ff_dim))
-                add(f"layers.{l}.experts.{e}.w3", wt, (h.ff_dim, h.dim))
-        else:
-            add(f"layers.{l}.w1", wt, (h.ff_dim, h.dim))
-            add(f"layers.{l}.w2", wt, (h.dim, h.ff_dim))
-            add(f"layers.{l}.w3", wt, (h.ff_dim, h.dim))
-        if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE):
+                swiglu(f"layers.{l}.experts.{e}", h.ff_dim)
+        else:  # leading dense layers are HIDDEN_DIM wide beside experts of ff_dim
+            swiglu(f"layers.{l}", h.hidden_dim if afmoe else h.ff_dim)
+        if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE):
             add(f"layers.{l}.q_norm", FloatType.F32, (h.head_dim,))
             add(f"layers.{l}.k_norm", FloatType.F32, (h.head_dim,))
         add(f"layers.{l}.att_norm", FloatType.F32, (h.dim,))
+        if afmoe:  # sandwich norms: one more after each block
+            add(f"layers.{l}.post_att_norm", FloatType.F32, (h.dim,))
         add(f"layers.{l}.ffn_norm", FloatType.F32, (h.dim,))
+        if afmoe:
+            add(f"layers.{l}.post_ffn_norm", FloatType.F32, (h.dim,))
     add("final_norm", FloatType.F32, (h.dim,))
     add("wcls", wt, (h.vocab_size, h.dim))
     return specs

@@ -38,6 +38,18 @@ HEADER_KEYS = {
     "head_dim": 19,
     "norm_epsilon": 20,
     "moe_hidden_dim": 21,
+    # past the reference's keys: see formats/model_file.HeaderKey
+    "sliding_window": 22,
+    "full_attn_period": 23,
+    "full_attn_no_rope": 24,
+    "n_dense_layers": 25,
+    "n_shared_experts": 26,
+    "score_func": 27,
+    "route_norm": 28,
+    "route_scale_milli": 29,
+    "n_routed_experts": 30,
+    "first_expert": 31,
+    "embed_scale": 32,
 }
 
 

@@ -268,6 +268,9 @@ class PagedKVManager:
         if self.native:
             return self._publish_native(lane, tokens)
         ps = self.page_size
+        # a lane whose window layers' ring has wrapped has lost its first
+        # rows there: nothing of it can be stored (engine.kv_publishable)
+        tokens = tokens[: self.engine.kv_publishable(len(tokens))]
         n_full = len(tokens) // ps
         if n_full == 0:
             return 0

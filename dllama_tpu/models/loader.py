@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..formats.model_file import LlmArch, LlmHeader, ModelReader
+from ..formats.model_file import LlmArch, LlmHeader, ModelReader, layer_table
 from ..formats.quants import FloatType, pack_q40_device
 from ..ops.jnp_ops import rope_cache
 from ..ops.quant_matmul import (
@@ -318,8 +318,16 @@ def load_params(
             a = np.ascontiguousarray(a.T)  # file is (out, in) -> we want (in, out)
         return a
 
-    def stack(fn: Callable[[int], np.ndarray]) -> np.ndarray:
-        return np.stack([fn(l) for l in range(h.n_layers)])
+    # leaves of the attention block are stacked over every layer; those of
+    # an FFN over the layers of its kind (`layer_table`), since a model may
+    # lead with dense layers and go on with experts
+    every = list(range(h.n_layers))
+    table = layer_table(h)
+    dense_layers = [l for l in every if not table[l].experts]
+    expert_layers = [l for l in every if table[l].experts]
+
+    def stack(fn: Callable[[int], np.ndarray], layers=every) -> np.ndarray:
+        return np.stack([fn(l) for l in layers])
 
     def unpack_q40(name: str) -> tuple[np.ndarray, np.ndarray]:
         """Q40 tensor -> (q int8 [in, out], d f32 [in//32, out]) device
@@ -331,16 +339,17 @@ def load_params(
             unpacked = planar_to_device_layout(*reader.planar_q40(name))
         return unpacked
 
-    def qw(tag: str, fn: Callable[[int], str]):
+    def qw(tag: str, fn: Callable[[int], str], layers=every):
         """Stacked QuantWeight (or PackedQuantWeight when packed) for a
         per-layer matmul tensor."""
         if streaming:
             w_, _ = _stream_quant_stack(
-                reader, put, tag, [fn], (h.n_layers,), packed=packed
+                reader, put, tag, [lambda i: fn(layers[i])], (len(layers),),
+                packed=packed,
             )
             return w_
         qs, ds = [], []
-        for l in range(h.n_layers):
+        for l in layers:
             q_arr, d_arr = unpack_q40(fn(l))
             if packed:
                 q_arr, d_arr = pack_q40_device(q_arr, d_arr)
@@ -356,19 +365,22 @@ def load_params(
     layers["ffn_norm"] = put(
         "ffn_norm", stack(lambda l: w(f"layers.{l}.ffn_norm", False))
     )
-    def qw_fused(tag: str, names: list[Callable[[int], str]]) -> FusedQuantWeight:
+    def qw_fused(
+        tag: str, names: list[Callable[[int], str]], layers=every
+    ) -> FusedQuantWeight:
         """Stacked FusedQuantWeight fusing several row-split matmul tensors
         along the out axis, shard-major for `fuse` tp shards; the fuse
         factor and constituent out dims ride as static pytree metadata."""
         if streaming:
             w_, dims = _stream_quant_stack(
-                reader, put, tag, names, (h.n_layers,), fuse=fuse,
-                packed=packed,
+                reader, put, tag,
+                [lambda i, fn=fn: fn(layers[i]) for fn in names],
+                (len(layers),), fuse=fuse, packed=packed,
             )
             return FusedQuantWeight(w_, fuse, dims)
         qs, ds = [], []
         dims: tuple[int, ...] = ()
-        for l in range(h.n_layers):
+        for l in layers:
             parts = [unpack_q40(fn(l)) for fn in names]
             dims = tuple(p[0].shape[-1] for p in parts)
             # interleave permutes the out axis, packing halves the in
@@ -387,31 +399,54 @@ def load_params(
             dims,
         )
 
+    has_gate = "layers.0.att_gate" in reader.by_name
+    qkv = ["q", "k", "v"] + (["att_gate"] if has_gate else [])
     if quantize and fuse:
+        # the gate on the attention output reads the same input: it rides
+        # the fused launch as a fourth constituent
         layers["wqkv"] = qw_fused(
-            "wqkv",
-            [
-                lambda l: f"layers.{l}.q",
-                lambda l: f"layers.{l}.k",
-                lambda l: f"layers.{l}.v",
-            ],
+            "wqkv", [lambda l, n=n: f"layers.{l}.{n}" for n in qkv]
         )
         layers["wo"] = qw("wo", lambda l: f"layers.{l}.wo")
     elif quantize:
-        layers["wq"] = qw("wq", lambda l: f"layers.{l}.q")
-        layers["wk"] = qw("wk", lambda l: f"layers.{l}.k")
-        layers["wv"] = qw("wv", lambda l: f"layers.{l}.v")
+        for tag, n in zip(("wq", "wk", "wv", "wg"), qkv):
+            layers[tag] = qw(tag, lambda l, n=n: f"layers.{l}.{n}")
         layers["wo"] = qw("wo", lambda l: f"layers.{l}.wo")
     else:
-        layers["wq"] = put("wq", stack(lambda l: w(f"layers.{l}.q")).astype(dtype))
-        layers["wk"] = put("wk", stack(lambda l: w(f"layers.{l}.k")).astype(dtype))
-        layers["wv"] = put("wv", stack(lambda l: w(f"layers.{l}.v")).astype(dtype))
+        for tag, n in zip(("wq", "wk", "wv", "wg"), qkv):
+            layers[tag] = put(
+                tag, stack(lambda l, n=n: w(f"layers.{l}.{n}")).astype(dtype)
+            )
         layers["wo"] = put("wo", stack(lambda l: w(f"layers.{l}.wo")).astype(dtype))
 
-    if h.arch == LlmArch.QWEN3_MOE:
+    def swiglu(prefix: str, name: Callable[[int, str], str], ls: list[int]) -> None:
+        """A SwiGLU's three matrices over the layers `ls`, as
+        `<prefix>w1`..`w3`, or fused `<prefix>w13` and `<prefix>w2`."""
+        if quantize and fuse:
+            layers[prefix + "w13"] = qw_fused(
+                prefix + "w13",
+                [lambda l: name(l, "w1"), lambda l: name(l, "w3")], ls,
+            )
+            layers[prefix + "w2"] = qw(prefix + "w2", lambda l: name(l, "w2"), ls)
+            return
+        for n in ("w1", "w2", "w3"):
+            if quantize:
+                layers[prefix + n] = qw(prefix + n, lambda l, n=n: name(l, n), ls)
+            else:
+                layers[prefix + n] = put(
+                    prefix + n,
+                    stack(lambda l, n=n: w(name(l, n)), ls).astype(dtype),
+                )
+
+    if expert_layers:
         layers["moe_gate"] = put(
-            "moe_gate", stack(lambda l: w(f"layers.{l}.moe_gate"))
+            "moe_gate", stack(lambda l: w(f"layers.{l}.moe_gate"), expert_layers)
         )
+        if f"layers.{expert_layers[0]}.expert_bias" in reader.by_name:
+            layers["expert_bias"] = put("expert_bias", stack(
+                lambda l: w(f"layers.{l}.expert_bias", False), expert_layers))
+        if h.n_shared_experts:
+            swiglu("shared_", lambda l, n: f"layers.{l}.shared.{n}", expert_layers)
 
         if quantize:
             # Experts stay block-quantized on device (the reference stores
@@ -425,12 +460,13 @@ def load_params(
                 if streaming:
                     w_, _ = _stream_quant_stack(
                         reader, put, tag,
-                        [lambda l, e, wh=which: f"layers.{l}.experts.{e}.{wh}"],
-                        (h.n_layers, h.n_experts),
+                        [lambda i, e, wh=which:
+                            f"layers.{expert_layers[i]}.experts.{e}.{wh}"],
+                        (len(expert_layers), h.n_experts),
                     )
                     return w_
                 lqs, lds = [], []
-                for l in range(h.n_layers):
+                for l in expert_layers:
                     unpacked = [
                         unpack_q40(f"layers.{l}.experts.{e}.{which}")
                         for e in range(h.n_experts)
@@ -449,31 +485,20 @@ def load_params(
                     [w(f"layers.{l}.experts.{e}.{which}") for e in range(h.n_experts)]
                 )
 
-            layers["w1"] = put("w1", stack(lambda l: experts(l, "w1")).astype(dtype))
-            layers["w2"] = put("w2", stack(lambda l: experts(l, "w2")).astype(dtype))
-            layers["w3"] = put("w3", stack(lambda l: experts(l, "w3")).astype(dtype))
-    elif quantize and fuse:
-        layers["w13"] = qw_fused(
-            "w13",
-            [lambda l: f"layers.{l}.w1", lambda l: f"layers.{l}.w3"],
+            for n in ("w1", "w2", "w3"):
+                layers[n] = put(
+                    n, stack(lambda l, n=n: experts(l, n), expert_layers).astype(dtype)
+                )
+    if dense_layers:
+        # beside experts the dense layers' FFN is a stack of its own
+        swiglu(
+            "dense_" if expert_layers else "",
+            lambda l, n: f"layers.{l}.{n}", dense_layers,
         )
-        layers["w2"] = qw("w2", lambda l: f"layers.{l}.w2")
-    elif quantize:
-        layers["w1"] = qw("w1", lambda l: f"layers.{l}.w1")
-        layers["w2"] = qw("w2", lambda l: f"layers.{l}.w2")
-        layers["w3"] = qw("w3", lambda l: f"layers.{l}.w3")
-    else:
-        layers["w1"] = put("w1", stack(lambda l: w(f"layers.{l}.w1")).astype(dtype))
-        layers["w2"] = put("w2", stack(lambda l: w(f"layers.{l}.w2")).astype(dtype))
-        layers["w3"] = put("w3", stack(lambda l: w(f"layers.{l}.w3")).astype(dtype))
 
-    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE):
-        layers["q_norm"] = put(
-            "q_norm", stack(lambda l: w(f"layers.{l}.q_norm", False))
-        )
-        layers["k_norm"] = put(
-            "k_norm", stack(lambda l: w(f"layers.{l}.k_norm", False))
-        )
+    for n in ("q_norm", "k_norm", "post_att_norm", "post_ffn_norm"):
+        if f"layers.0.{n}" in reader.by_name:
+            layers[n] = put(n, stack(lambda l, n=n: w(f"layers.{l}.{n}", False)))
 
     cos, sin = rope_cache(h)
     if quantize and streaming:

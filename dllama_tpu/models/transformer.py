@@ -25,13 +25,14 @@ rejects blocking a size-1 head dim; see ops/flash_attention.py).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..formats.model_file import HiddenAct, LlmArch, LlmHeader, RopeType
+from ..formats.model_file import HiddenAct, LlmHeader, RopeType, layer_table
 from ..ops.jnp_ops import apply_rope, gelu, qk_rms_norm, rms_norm, silu
 from ..ops.quant_matmul import (
     FusedQuantWeight,
@@ -62,6 +63,7 @@ from ..ops.moe_kernel import (
     moe_active_experts_q40,
     moe_grouped_experts,
     moe_grouped_experts_q40,
+    moe_held_experts_q40,
 )
 
 Params = Dict[str, Any]
@@ -142,14 +144,27 @@ def _split_fused(out: jnp.ndarray, tp: int, dims: tuple[int, ...]):
 
 
 def init_kv_cache(
-    h: LlmHeader, batch_size: int, dtype=jnp.float32, seq_len: int | None = None
+    h: LlmHeader, batch_size: int, dtype=jnp.float32, seq_len: int | None = None,
+    ring: int | None = None, ring_pad: int = 0,
 ) -> KvCache:
     """Allocate the KV cache (reference allocates per-layer f32 k/v buffers,
     src/llm.cpp:260-261). dtype jnp.int8 allocates the quantized layout
-    (QuantKV leaves)."""
+    (QuantKV leaves).
+
+    A model with window layers (`layer_table`) gets two stacks side by
+    side: `k`/`v` hold the full layers at `seq_len` rows, `kw`/`vw` the
+    window layers as a ring of `ring` rows (default: as many, a ring that
+    never wraps) between `ring_pad` spare rows before it and as many
+    behind, which `run_layers` needs where chunks wrap, lanes park or a
+    scan holds layers of both kinds. Each layer's `row` in the table is
+    its place in its stack."""
     s = seq_len or h.seq_len
-    shape = (h.n_layers, batch_size, h.n_kv_heads, s, h.head_dim)
+    n_window = sum(kind.window for kind in layer_table(h))
+    shape = (h.n_layers - n_window, batch_size, h.n_kv_heads, s, h.head_dim)
     if dtype == jnp.int8:
+        if n_window:
+            raise NotImplementedError("window layers' ring cache is not quantized: int8 KV")
+
         def leaf():
             return QuantKV(
                 jnp.zeros(shape, jnp.int8),
@@ -157,10 +172,16 @@ def init_kv_cache(
             )
 
         return {"k": leaf(), "v": leaf()}
-    return {
+    cache = {
         "k": jnp.zeros(shape, dtype=dtype),
         "v": jnp.zeros(shape, dtype=dtype),
     }
+    if n_window:
+        rows = (ring or s) + 2 * ring_pad
+        shape = (n_window, batch_size, h.n_kv_heads, rows, h.head_dim)
+        cache["kw"] = jnp.zeros(shape, dtype=dtype)
+        cache["vw"] = jnp.zeros(shape, dtype=dtype)
+    return cache
 
 
 def _use_flash(t: int, rows: int) -> bool:
@@ -254,6 +275,42 @@ def _attention_tp(
             out_specs=spec_q,
             check_vma=False,
         )(q, k_cache, v_cache, pos, layer)
+    return out.reshape(b, t, n_heads * head_dim)
+
+
+def _attention_window(
+    q: jnp.ndarray,  # [B, T, H, hd]
+    k_cache: jnp.ndarray,  # [Lw, B, KH, rows, hd]: the window layers' stack
+    v_cache: jnp.ndarray,
+    layer: jnp.ndarray,  # int32 scalar: the layer's row in that stack
+    pos: jnp.ndarray,
+    head_dim: int,
+    ring: int,  # position p lies at row p % ring
+    window: int,  # a query sees the last `window` positions
+    attn_window: int = 0,
+    row0: int = 0,  # the ring's first row in the stack
+) -> jnp.ndarray:
+    """A window layer's attention over its ring, after the chunk's rows are
+    written: the flash kernel for a chunk on the chip, XLA's dense
+    attention for a decode step, each with the ring's own positions
+    (`jnp_ops.ring_positions`) and the window's lower bound in its mask.
+    While the engine's `attn_window` is below the ring nothing has wrapped
+    and the first `attn_window` rows are all there is to read; past it the
+    whole ring is read, never the context."""
+    from ..ops.jnp_ops import attention_dense
+
+    b, t, n_heads = q.shape[0], q.shape[1], q.shape[2]
+    rows = min(attn_window, ring) if attn_window else ring
+    if t >= 8 and _use_flash(t, rows):
+        out = flash_attention(
+            q, k_cache, v_cache, pos, layer=layer, rows=rows, ring=ring,
+            window=window, row0=row0,
+        )
+    else:
+        out = attention_dense(
+            q, layer_rows(k_cache, layer, rows, row0),
+            layer_rows(v_cache, layer, rows, row0), pos, ring=ring, window=window,
+        )
     return out.reshape(b, t, n_heads * head_dim)
 
 
@@ -422,16 +479,70 @@ def _attention(
     return out.reshape(b, t, n_heads * head_dim)
 
 
-def _moe_route(x_flat: jnp.ndarray, gate_w: jnp.ndarray, n_active: int):
+@dataclasses.dataclass(frozen=True)
+class Routing:
+    """How a layer's router turns its scores into weighted experts, and
+    which of the experts it scores are held here: `n_held` from `first` of
+    `n_routed`. The reference's router is the default: softmax over all,
+    the chosen weights renormalised, every expert held."""
+
+    n_active: int
+    sigmoid: bool = False
+    norm: bool = True
+    scale: float = 1.0
+    first: int = 0
+    n_held: int = 0
+    n_routed: int = 0
+
+    @property
+    def shared_out(self) -> bool:
+        """Some experts the router scores lie on other chips."""
+        return self.n_held < self.n_routed
+
+    def held(self, top_i: jnp.ndarray) -> jnp.ndarray:
+        """Chosen ids as rows of the held experts' stack; `n_held` for a
+        pair that landed on an expert held elsewhere."""
+        local = top_i - self.first
+        return jnp.where(
+            jnp.logical_and(local >= 0, local < self.n_held), local, self.n_held
+        )
+
+
+def routing_of(h: LlmHeader) -> Routing:
+    return Routing(
+        h.n_active_experts, h.score_sigmoid, h.route_norm, h.route_scale,
+        h.first_expert, h.n_experts, h.n_routed_experts,
+    )
+
+
+def _moe_route(x_flat: jnp.ndarray, gate_w: jnp.ndarray, route: Routing, bias=None):
     """Shared gate routing (softmax over all experts -> top-k -> normTopk=1
     weights; reference: src/nn/nn-cpu-ops.cpp:1462-1492). `x_flat` is
-    [..., D]; returns (top_i [..., k], weights [..., k]) in f32."""
+    [..., D]; returns (top_i [..., k], weights [..., k]) in f32, the ids
+    among all the experts the router scores. Sigmoid scores take `bias`
+    [E] into the selection and never into the weights."""
     logits = jnp.einsum(
         "...d,de->...e", x_flat.astype(jnp.float32), gate_w.astype(jnp.float32)
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = lax.top_k(probs, n_active)
-    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if route.sigmoid:
+        scores = jax.nn.sigmoid(logits)
+        _, top_i = lax.top_k(
+            scores if bias is None else scores + bias.astype(jnp.float32),
+            route.n_active,
+        )
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        weights = (
+            top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+            if route.norm else top_p
+        )
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = lax.top_k(probs, route.n_active)
+        weights = (
+            top_p / jnp.sum(top_p, axis=-1, keepdims=True) if route.norm else top_p
+        )
+    if route.scale != 1.0:
+        weights = weights * route.scale
     return top_i, weights
 
 
@@ -441,11 +552,14 @@ def _moe_ffn(
     w1: jnp.ndarray,  # [E, D, F]
     w2: jnp.ndarray,  # [E, F, D]
     w3: jnp.ndarray,  # [E, D, F]
-    n_active: int,
+    route: Routing,
     act,
+    routed=None,
 ) -> jnp.ndarray:
     """MoE FFN: softmax over all experts -> top-k -> normalized weights ->
-    weighted sum of expert SwiGLU outputs.
+    weighted sum of expert SwiGLU outputs. `routed`: (rows of the held
+    experts' stack, weights) already chosen; a row of E, an expert held
+    elsewhere, adds nothing.
 
     (reference: the OP_SOFTMAX / OP_MOE_GATE / 3x OP_MATMUL / OP_SCALE /
     OP_MERGE_SUM chain, src/llm.cpp:425-499; gate math
@@ -461,8 +575,8 @@ def _moe_ffn(
     """
     if isinstance(w1, QuantWeight):
         w1, w2, w3 = (dequant(w, x.dtype) for w in (w1, w2, w3))
-    e = gate_w.shape[1]
-    top_i, weights = _moe_route(x, gate_w, n_active)  # [B, T, k]
+    e = w1.shape[0]
+    top_i, weights = routed or _moe_route(x, gate_w, route)  # [B, T, k]
 
     # routing matrix [B, T, E]: normalized weight where selected, else 0
     routing = jnp.sum(
@@ -498,12 +612,13 @@ def _moe_ffn_pallas(
     w1,  # [E, D, F] dense, or QuantWeight (q int8 [L, E, D, F] + d [L, E, D/32, F])
     w2,  # [E, F, D] (same)
     w3,  # [E, D, F] (same)
-    n_active: int,
+    route: Routing,
     mesh,
     interpret: bool = False,
     sync_quant: bool = False,
     dedup: bool = False,
     layer=0,  # which layer of quantized experts' [L, E, ...] stacks
+    bias=None,
 ) -> jnp.ndarray:
     """Decode-step MoE via the ragged Pallas kernel (ops/moe_kernel.py):
     each token's top-k expert ids drive the HBM->VMEM DMA schedule, so only
@@ -516,7 +631,7 @@ def _moe_ffn_pallas(
     b, t, d = x.shape
     n = b * t
     xf = x.reshape(n, d)
-    top_i, weights = _moe_route(xf, gate_w, n_active)  # [n, k]
+    top_i, weights = _moe_route(xf, gate_w, route, bias)  # [n, k]
     quantized = isinstance(w1, QuantWeight)
     # two-tier dedup (opt-in): when concurrent lanes share experts, a
     # small-grid grouped kernel reads each UNIQUE expert's tiles once.
@@ -612,11 +727,12 @@ def _moe_ffn_grouped(
     w1,  # [E, D, F] dense or QuantWeight [L, E, D, F]
     w2,
     w3,
-    n_active: int,
+    route: Routing,
     mesh,
     interpret: bool = False,
     sync_quant: bool = False,
     layer=0,  # which layer of quantized experts' [L, E, ...] stacks
+    bias=None,
 ) -> jnp.ndarray:
     """Prefill MoE via the grouped active-expert kernel
     (ops/moe_kernel.moe_grouped_experts*): assignments sorted by expert,
@@ -637,7 +753,7 @@ def _moe_ffn_grouped(
     quantized = isinstance(w1, QuantWeight)
     # route ONCE, outside any shard_map (same as _moe_ffn_pallas): the
     # gate einsum + top_k would otherwise rerun per tp shard
-    top_i, wts = _moe_route(xf, gate_w, n_active)
+    top_i, wts = _moe_route(xf, gate_w, route, bias)
 
     def run(xx, ii, ww, *wargs):
         if quantized:
@@ -697,6 +813,8 @@ def forward(
     logits_mode: str = "all",
     sync_quant: bool = False,
     moe_decode_dedup: bool = False,
+    kv_ring: int = 0,
+    route_stats: list | None = None,
 ) -> Tuple[jnp.ndarray, KvCache]:
     """Run the decoder on T tokens starting at absolute position `pos`.
 
@@ -734,15 +852,19 @@ def forward(
     attn_pos = attn_positions(pos, attn_park_threshold, cache["k"].shape[3])
 
     x = params["embed"][tokens]  # [B, T, D] (reference: OP_EMBEDDING)
+    if h.embed_scale:
+        x = (x.astype(jnp.float32) * float(h.dim) ** 0.5).astype(x.dtype)
 
     cos, sin = rope_slices(params, pos, t)
-    x, k_new, v_new = run_layers(
+    x, *caches = run_layers(
         x, params["layers"], cache["k"], cache["v"], h, pos, attn_pos,
         cos, sin, mesh=mesh, attn_window=attn_window,
         sync_quant=sync_quant, moe_decode_dedup=moe_decode_dedup,
+        kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
+        route_stats=route_stats,
     )
     logits = logits_head(x, params, h, mesh, logits_mode)
-    return logits, {"k": k_new, "v": v_new}
+    return logits, dict(zip(("k", "v", "kw", "vw"), caches))
 
 
 def attn_positions(pos, attn_park_threshold: int, cache_len: int):
@@ -819,8 +941,22 @@ def run_layers(
     tp_n: int = 1,
     sp_axis: str | None = None,
     sp_n: int = 1,
+    kw_cache: jnp.ndarray | None = None,  # [Lw, B, KH, rows, hd]: window layers
+    vw_cache: jnp.ndarray | None = None,
+    kv_ring: int = 0,
+    route_stats: list | None = None,
 ):
-    """`lax.scan` the decoder layers over x; returns (x, k_new, v_new).
+    """`lax.scan` the decoder layers over x; returns (x, k_new, v_new), and
+    the window layers' (kw_new, vw_new) behind them where the model has such.
+
+    What a layer is comes from the header's layer table (`layer_table`):
+    attention in full or over a window, rope or none, a dense FFN or
+    experts, and the layer's row in its cache stack. Layers of one FFN
+    kind are one scan (their weights are one stack); inside it the
+    attention kind is scanned data where it varies, and `lax.cond` takes
+    the window branch over the ring stack or the full branch over the
+    other, so each kernel compiles once a scan. A model all of whose
+    layers are alike is one scan without a branch, as ever.
 
     The scan runs over the layer number and the small or dense per-layer
     leaves. Quantized weight stacks (`_is_quant_stack`) are not among its
@@ -856,7 +992,6 @@ def run_layers(
     b, t = x.shape[0], x.shape[1]
     interleaved = h.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1)
     act = silu if h.hidden_act == HiddenAct.SILU else gelu
-    is_qwen3 = h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE)
     per_lane = jnp.ndim(pos) == 1
     if (tp_axis is not None or sp_axis is not None) and mesh is not None:
         raise ValueError("manual tp/sp (tp_axis/sp_axis) requires mesh=None")
@@ -876,10 +1011,66 @@ def run_layers(
     # mesh tp size: per-shard shape checks (MoE kernel gate)
     _tp_n = mesh.shape.get("tp", 1) if mesh is not None else 1
 
-    stacks = {k: v for k, v in layers.items() if _is_quant_stack(v)}
-    sliced = {k: v for k, v in layers.items() if k not in stacks}
+    table = layer_table(h)
+    n_layers = jax.tree.leaves(k_cache)[0].shape[0] + (
+        0 if kw_cache is None else kw_cache.shape[0]
+    )
+    alike = all(
+        (kind.window, kind.rope, kind.experts) == (
+            table[0].window, table[0].rope, table[0].experts)
+        for kind in table
+    )
+    if alike:
+        # one kind of layer (a pipeline stage holds its own share of them)
+        segments = [(0, n_layers)]
+    else:
+        if n_layers != len(table) or tp_axis is not None or sp_axis is not None:
+            raise NotImplementedError(
+                "layers of several kinds run whole on one mesh: no pipeline "
+                "stages, manual tp or manual sp"
+            )
+        # maximal runs of one FFN kind: their FFN weights are one stack
+        cuts = [0] + [
+            l for l in range(1, n_layers)
+            if table[l].experts != table[l - 1].experts
+        ] + [n_layers]
+        segments = list(zip(cuts[:-1], cuts[1:]))
+    window = h.sliding_window
+    route = routing_of(h)
+    if kw_cache is not None:
+        if mesh is not None and mesh.devices.size > 1:
+            raise NotImplementedError(
+                "window attention layers run on one device: tp, sp, dp > 1"
+            )
+        ring = kv_ring or kw_cache.shape[3]
+        ring_pad = (kw_cache.shape[3] - ring) // 2  # spare rows before and behind
+        spare_needed = jnp.ndim(attn_pos) == 1 or any(
+            len({table[l].window for l in range(a, e)}) > 1 for a, e in segments
+        )
+        if (spare_needed or ring < h.seq_len) and (
+            ring_pad < t or (spare_needed and h.seq_len + t > k_cache.shape[3])
+        ):
+            raise ValueError(
+                f"a chunk of {t} rows needs as many spare rows on either "
+                f"side of the window layers' ring of {ring} (it has "
+                f"{ring_pad}: a chunk that wraps is written twice, a parked "
+                f"lane's and the other cache kind's rows go there) and "
+                f"behind the context's {h.seq_len} rows of the full layers' "
+                f"stack (it has {k_cache.shape[3]} rows)"
+            )
+        if ring < min(window + t, k_cache.shape[3]):
+            raise ValueError(
+                f"a ring of {ring} rows cannot hold a window of {window} and "
+                f"a chunk of {t}: the chunk would overwrite rows its own "
+                f"first query sees"
+            )
+    # token rows of live lanes: what the routing counters count
+    live_rows = (
+        jnp.broadcast_to((attn_pos >= 0)[:, None], (b, t)).reshape(-1)
+        if jnp.ndim(attn_pos) == 1 else jnp.ones((b * t,), bool)
+    )
 
-    def _cache_append(cache, l, val):
+    def _cache_append(cache, l, val, there=None):
         """Write the chunk into layer `l` of the carried stack at each
         lane's position (reference: OP_SHIFT,
         src/nn/nn-cpu-ops.cpp:1419-1441): a `dynamic_update_slice` of the
@@ -888,7 +1079,9 @@ def run_layers(
         [B, T, KH, hd] from the projection. An int8 cache (QuantKV)
         quantizes the rows once here and routes values and scales through
         the SAME positional writer (the scale leaf's trailing singleton
-        keeps ranks equal)."""
+        keeps ranks equal). `there` (traced bool): where false the layer
+        is of the other kind, and the rows go past the context's
+        (`write_kv`)."""
         val = val.transpose(0, 2, 1, 3)  # [B, KH, T, hd]
         if isinstance(cache, QuantKV):
             qv, sv = quantize_kv_rows(val)
@@ -896,7 +1089,38 @@ def run_layers(
                 _positional_write(cache.q, l, qv),
                 _positional_write(cache.s, l, sv),
             )
+        if there is not None:
+            return write_rows(cache, l, jnp.where(there, pos, h.seq_len), val)
         return _positional_write(cache, l, val)
+
+    def _ring_append(cache, l, val, there=None):
+        """Write the chunk into row `l` of the window layers' stack as a
+        ring: position p at ring row p % ring, which is row `ring_pad` +
+        that of the stack: `ring_pad` spare rows lie before the ring and
+        as many behind it. A chunk may run over the ring's end, and a
+        `dynamic_update_slice` cannot, so the chunk is written twice,
+        whole, as `write_rows` writes it: where it starts, running on into
+        the spare rows behind; and one ring's length before, so that what
+        ran over lands on the ring's first rows and the rest on the spare
+        rows before them. A chunk that does not wrap, a parked lane (its
+        query position negative) and a layer of the other kind (`there`
+        false) send that second copy, or both, to the spare rows behind,
+        where no query reads. (A scatter of the rows, and a read-modify-
+        write with the chunk rolled into place, each made the chip's
+        compiler keep the stack in another layout and copy it whole twice
+        a layer: described v5e, bf16[7,8,8,5120,128].)"""
+        val = val.transpose(0, 2, 1, 3)  # [B, KH, T, hd]
+        live = attn_pos >= 0
+        if there is not None:
+            live = jnp.logical_and(live, there)
+        r0, spare = pos % ring, ring_pad + ring
+        cache = write_rows(cache, l, jnp.where(live, ring_pad + r0, spare), val)
+        if t == 1 or not ring_pad:
+            return cache  # one row cannot wrap, and a ring of the whole context never does
+        wraps = jnp.logical_and(live, r0 + t > ring)
+        return write_rows(
+            cache, l, jnp.where(wraps, ring_pad + r0 - ring, spare), val
+        )
 
     def _positional_write(cache, l, val):
         if sp_axis is not None:
@@ -951,61 +1175,37 @@ def run_layers(
             cache, l, jstart, jnp.where(ok, gathered.astype(cur.dtype), cur)
         )
 
-    def layer_step(carry, layer):
-        x, k_cache, v_cache = carry
-        lp, l = layer
-        lp = {**lp, **stacks}
+    phase = "decode" if t == 1 else "prefill"
 
-        def mm(yy, w, role, sync=False):
-            # `l` counts only where `w` is one of `stacks`
-            if tp_axis is not None:
-                return _mm_manual(yy, w, role, tp_axis, sync and sync_quant, l)
-            return _mm(yy, w, role, mesh, sync and sync_quant, l)
-
-        # -- attention block (reference: src/llm.cpp:263-403) --
-        with jax.named_scope("norm"):
-            y = rms_norm(x, lp["att_norm"], h.norm_epsilon)
-        with jax.named_scope("attn"):
-            if "wqkv" in lp:
-                # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
-                # per decode layer at ~41 us fixed cost each on the round-3
-                # chip run). The un-interleave factor is the
-                # weight's own static metadata, not the mesh's tp — a fused-
-                # load/mesh mismatch stays correct (just non-optimally laid
-                # out) instead of silently permuting columns. Under manual tp
-                # the shard's local slice is one interleave chunk (the shard-
-                # major layout puts shard i's [q_i|k_i|v_i] in chunk i), so
-                # the local split factor is fuse / tp_n.
-                fw = lp["wqkv"]
-                if fw.fuse % tp_n != 0:
-                    raise ValueError(
-                        f"fused weight interleave {fw.fuse} incompatible with "
-                        f"manual tp_n={tp_n}"
-                    )
-                qkv = mm(y, fw.weight, "row")
-                q, k, v = _split_fused(
-                    qkv, fw.fuse // tp_n, tuple(d // tp_n for d in fw.dims)
-                )
-                q = q.reshape(b, t, hq, h.head_dim)
-                k = k.reshape(b, t, hkv, h.head_dim)
-                v = v.reshape(b, t, hkv, h.head_dim)
-            else:
-                q = mm(y, lp["wq"], "row").reshape(b, t, hq, h.head_dim)
-                k = mm(y, lp["wk"], "row").reshape(b, t, hkv, h.head_dim)
-                v = mm(y, lp["wv"], "row").reshape(b, t, hkv, h.head_dim)
-            if is_qwen3:
-                q = qk_rms_norm(q, lp["q_norm"], h.norm_epsilon)
-                k = qk_rms_norm(k, lp["k_norm"], h.norm_epsilon)
-            q = apply_rope(q, cos, sin, interleaved)
-            k = apply_rope(k, cos, sin, interleaved)
-
-        # write first, then read the updated stack: nothing else holds
-        # the carry, so the rows land in place
+    def write_kv(caches, k, v, row, is_window):
+        """The chunk's keys and values into the layer's row of its stack.
+        Where a scan holds layers of both kinds (`is_window` traced) each
+        stack is written every layer, the other kind's at its rows past
+        the live ones, where parked lanes write and no query reads: a
+        write of the chunk's rows costs less than a conditional that
+        hands a stack through, which XLA copies whole (1.1 GB a layer at
+        8 lanes of 16k; the described v5e's compiler)."""
+        k_cache, v_cache, *ring_caches = caches
+        mixed = not isinstance(is_window, bool)
         with jax.named_scope("kv_write"):
-            k_cache = _cache_append(k_cache, l, k)
-            v_cache = _cache_append(v_cache, l, v)
+            if mixed or not is_window:
+                there = jnp.logical_not(is_window) if mixed else None
+                k_cache = _cache_append(k_cache, row[0], k, there)
+                v_cache = _cache_append(v_cache, row[0], v, there)
+            if mixed or is_window:
+                kw, vw = ring_caches
+                there = is_window if mixed else None
+                ring_caches = [
+                    _ring_append(kw, row[1], k, there),
+                    _ring_append(vw, row[1], v, there),
+                ]
+        return (k_cache, v_cache, *ring_caches)
 
-        with jax.named_scope("attn"):
+    def attend_full(q, caches, row):
+        """Read the updated stack: the chunk's rows were written first,
+        and nothing else holds the carry, so they landed in place."""
+        k_cache, v_cache = caches[:2]
+        with jax.named_scope("attn"), jax.named_scope(f"full_{phase}"):
             if sp_axis is not None:
                 # manual sp (cyclic layout): a global window (sp multiple) is
                 # the local prefix window/sp on every shard; dequant AFTER
@@ -1020,104 +1220,255 @@ def run_layers(
                     if attn_window and attn_window < shard_s * sp_n
                     else 0
                 )
-                z = _attention_sp_merge(
+                return _attention_sp_merge(
                     q,
-                    dequant_kv(layer_rows(k_cache, l, w_rows), x.dtype),
-                    dequant_kv(layer_rows(v_cache, l, w_rows), x.dtype),
+                    dequant_kv(layer_rows(k_cache, row[0], w_rows), x.dtype),
+                    dequant_kv(layer_rows(v_cache, row[0], w_rows), x.dtype),
                     attn_pos, sp_axis, sp_n,
                 ).reshape(b, t, hq * h.head_dim)
-            else:
-                # the window's rows of layer `l`, read where they lie; the
-                # sp mesh path windows inside _attention_sp per shard
-                z = _attention_tp(
-                    q, k_cache, v_cache, l, attn_pos, h.head_dim, mesh,
-                    attn_window=attn_window,
-                )
-            x = x + mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
+            # the window's rows of layer `row`, read where they lie; the
+            # sp mesh path windows inside _attention_sp per shard
+            return _attention_tp(
+                q, k_cache, v_cache, row[0], attn_pos, h.head_dim, mesh,
+                attn_window=attn_window,
+            )
 
-        # -- FFN block (reference: src/llm.cpp:405-557) --
-        with jax.named_scope("norm"):
-            y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
-        with jax.named_scope("moe" if h.arch == LlmArch.QWEN3_MOE else "ffn"):
-            if h.arch == LlmArch.QWEN3_MOE:
-                # decode (lane-sized B*T): the ragged Pallas kernel reads only
-                # each token's active experts' weights — Q40 blocks when the
-                # experts are stored quantized. CPU, and shapes the gate
-                # refuses: dense-over-experts.
-                from ..ops.moe_kernel import moe_pallas_supported
+    def attend_window(q, caches, row):
+        """The same over the window layers' ring stack."""
+        with jax.named_scope("attn"), jax.named_scope(f"window_{phase}"):
+            return _attention_window(
+                q, caches[2], caches[3], row[1], attn_pos, h.head_dim, ring,
+                window, attn_window=attn_window, row0=ring_pad,
+            )
 
-                _w1 = lp["w1"]
-                _quantized = isinstance(_w1, QuantWeight)
-                _itemsize = 1 if _quantized else _w1.dtype.itemsize
-                _f = _w1.q.shape[-1] if _quantized else _w1.shape[-1]
-                # the kernels run PER-SHARD under shard_map, so the VMEM/
-                # tiling gate must see the per-shard F (= F / tp), not the
-                # global one — a shape legal globally can have no Mosaic-legal
-                # F block per shard
-                pallas_ok = (
-                    h.hidden_act == HiddenAct.SILU
-                    and jax.default_backend() == "tpu"
-                    and _f % _tp_n == 0
-                    and moe_pallas_supported(
-                        h.dim, _f // _tp_n, _quantized, _itemsize
+    def moe_block(y, lp, lf):
+        """The experts' FFN of a layer whose experts' row is `lf`."""
+        # decode (lane-sized B*T): the ragged Pallas kernel reads only
+        # each token's active experts' weights — Q40 blocks when the
+        # experts are stored quantized. CPU, and shapes the gate
+        # refuses: dense-over-experts.
+        from ..ops.moe_kernel import moe_pallas_supported
+
+        _w1 = lp["w1"]
+        _quantized = isinstance(_w1, QuantWeight)
+        _itemsize = 1 if _quantized else _w1.dtype.itemsize
+        _f = _w1.q.shape[-1] if _quantized else _w1.shape[-1]
+        bias = lp.get("expert_bias")
+        # the kernels run PER-SHARD under shard_map, so the VMEM/
+        # tiling gate must see the per-shard F (= F / tp), not the
+        # global one — a shape legal globally can have no Mosaic-legal
+        # F block per shard
+        pallas_ok = (
+            h.hidden_act == HiddenAct.SILU
+            and jax.default_backend() == "tpu"
+            and _f % _tp_n == 0
+            and moe_pallas_supported(
+                h.dim, _f // _tp_n, _quantized, _itemsize
+            )
+        )
+        if route.shared_out:
+            # this chip holds a share of the experts the router scores:
+            # route over all, compute the pairs that landed here
+            top_i, wts = _moe_route(y.reshape(b * t, -1), lp["moe_gate"], route, bias)
+            # a parked lane's rows are one token's, 512 times over: routed,
+            # they would all land on the same four experts, and a held one
+            # of those would cost the kernel 28 more row tiles a layer for
+            # rows nobody reads. They count as landed elsewhere.
+            held_i = jnp.where(live_rows[:, None], route.held(top_i), route.n_held)
+            counts = None
+            if route_stats is not None:
+                on = held_i < route.n_held
+                touched = jnp.zeros((route.n_held + 1,), bool).at[held_i].set(
+                    True)[: route.n_held]
+                counts = jnp.stack([
+                    jnp.sum(live_rows) * route.n_active, jnp.sum(on),
+                    jnp.sum(touched),
+                ]).astype(jnp.int32)
+            if pallas_ok and _quantized:
+                if mesh is not None and mesh.devices.size > 1:
+                    raise NotImplementedError(
+                        "a share of the experts is computed on one device"
                     )
-                )
-                if pallas_ok:
-                    # decode-sized token counts take the per-(token, choice)
-                    # ragged kernel; prefill-scale takes the grouped kernel
-                    # (FLOPs proportional to selected experts, not all E).
-                    # Multi-lane decode DEDUP through the grouped kernel was
-                    # investigated for r4 and rejected: a Pallas grid is
-                    # static, so it must be sized for the all-distinct worst
-                    # case (~m*k steps) and Mosaic does not elide the empty
-                    # steps' repeated-index DMAs (round-3 chip finding) — the
-                    # schedule collapses *compute* per unique expert but not
-                    # HBM reads. Analysis + the viable lax.cond two-tier
-                    # design: docs/moe_decode_dedup.md.
-                    if b * t <= MOE_PALLAS_MAX_TOKENS:
-                        f = _moe_ffn_pallas(
-                            y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
-                            h.n_active_experts, mesh, sync_quant=sync_quant,
-                            dedup=moe_decode_dedup, layer=l,
-                        )
-                    else:
-                        f = _moe_ffn_grouped(
-                            y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
-                            h.n_active_experts, mesh, sync_quant=sync_quant,
-                            layer=l,
-                        )
-                else:
-                    # XLA compiles this and fuses the slice into the dequant
-                    f = _moe_ffn(
-                        y,
-                        lp["moe_gate"],
-                        *(
-                            layer_of(lp[n], l) if _quantized else lp[n]
-                            for n in ("w1", "w2", "w3")
-                        ),
-                        h.n_active_experts,
-                        act,
-                    )
+                return moe_held_experts_q40(
+                    y.reshape(b * t, -1), *_expert_stacks(lp["w1"], lp["w2"], lp["w3"]),
+                    held_i, wts, jnp.asarray(lf, jnp.int32),
+                ).reshape(b, t, -1).astype(y.dtype), counts
+            return _moe_ffn(
+                y, lp["moe_gate"],
+                *(layer_of(lp[n], lf) if _quantized else lp[n]
+                  for n in ("w1", "w2", "w3")),
+                route, act,
+                routed=(held_i.reshape(b, t, -1), wts.reshape(b, t, -1)),
+            ), counts
+        if pallas_ok:
+            # decode-sized token counts take the per-(token, choice)
+            # ragged kernel; prefill-scale takes the grouped kernel
+            # (FLOPs proportional to selected experts, not all E).
+            # Multi-lane decode DEDUP through the grouped kernel was
+            # investigated for r4 and rejected: a Pallas grid is
+            # static, so it must be sized for the all-distinct worst
+            # case (~m*k steps) and Mosaic does not elide the empty
+            # steps' repeated-index DMAs (round-3 chip finding) — the
+            # schedule collapses *compute* per unique expert but not
+            # HBM reads. Analysis + the viable lax.cond two-tier
+            # design: docs/moe_decode_dedup.md.
+            if b * t <= MOE_PALLAS_MAX_TOKENS:
+                return _moe_ffn_pallas(
+                    y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
+                    route, mesh, sync_quant=sync_quant,
+                    dedup=moe_decode_dedup, layer=lf, bias=bias,
+                ), None
+            return _moe_ffn_grouped(
+                y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
+                route, mesh, sync_quant=sync_quant, layer=lf, bias=bias,
+            ), None
+        # XLA compiles this and fuses the slice into the dequant
+        return _moe_ffn(
+            y,
+            lp["moe_gate"],
+            *(
+                layer_of(lp[n], lf) if _quantized else lp[n]
+                for n in ("w1", "w2", "w3")
+            ),
+            route,
+            act,
+            routed=_moe_route(y, lp["moe_gate"], route, bias),
+        ), None
+
+    def make_step(a: int, kinds, stacks):
+        """The scan body of layers [a, a + len(kinds)), all of one FFN
+        kind. `stacks`: the quantized weight stacks it closes over."""
+        experts = kinds[0].experts
+
+        def layer_step(carry, layer):
+            x, caches = carry
+            lp, l, extra = layer
+            lp = {**lp, **stacks}
+            # the layer's row in the full and in the window layers' cache
+            # stack (0 in the stack it is not of), and among its FFN kind's
+            row = extra.get("row", (l, l))
+            lf = l - a + kinds[0].ffn_row
+            is_window = extra.get("window", kinds[0].window)
+            has_rope = extra.get("rope")
+            counts = None
+
+            def mm(yy, w, role, sync=False, ffn=False):
+                # the layer counts only where `w` is one of `stacks`
+                li = lf if ffn else l
                 if tp_axis is not None:
-                    # manual tp: experts arrived F-sliced (same layout the
-                    # mesh path shards); the local partial outputs all-reduce
-                    # here instead of inside the helpers' shard_map
-                    f = lax.psum(f, tp_axis)
-            elif "w13" in lp:
-                # fused w1|w3: the SwiGLU pair shares its input and activation
-                fw13 = lp["w13"]
-                dl13 = mm(y, fw13.weight, "row")
-                d1, l3 = _split_fused(
-                    dl13, fw13.fuse // tp_n, tuple(d // tp_n for d in fw13.dims)
+                    return _mm_manual(yy, w, role, tp_axis, sync and sync_quant, li)
+                return _mm(yy, w, role, mesh, sync and sync_quant, li)
+
+            def swiglu(yy, prefix=""):
+                """A dense SwiGLU of this layer: its own FFN, or a shared expert."""
+                if prefix + "w13" in lp:
+                    # fused w1|w3: the SwiGLU pair shares its input and activation
+                    fw13 = lp[prefix + "w13"]
+                    dl13 = mm(yy, fw13.weight, "row", ffn=True)
+                    d1, l3 = _split_fused(
+                        dl13, fw13.fuse // tp_n, tuple(d // tp_n for d in fw13.dims)
+                    )
+                    d = act(d1)
+                else:
+                    d = act(mm(yy, lp[prefix + "w1"], "row", ffn=True))
+                    l3 = mm(yy, lp[prefix + "w3"], "row", ffn=True)
+                return mm(
+                    d * l3.astype(d.dtype), lp[prefix + "w2"], "col", sync=True,
+                    ffn=True,
                 )
-                d = act(d1)
-                f = mm(d * l3.astype(d.dtype), lp["w2"], "col", sync=True)
+
+            # -- attention block (reference: src/llm.cpp:263-403) --
+            with jax.named_scope("norm"):
+                y = rms_norm(x, lp["att_norm"], h.norm_epsilon)
+            with jax.named_scope("attn"):
+                gate = None
+                if "wqkv" in lp:
+                    # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
+                    # per decode layer at ~41 us fixed cost each on the round-3
+                    # chip run). The un-interleave factor is the
+                    # weight's own static metadata, not the mesh's tp — a fused-
+                    # load/mesh mismatch stays correct (just non-optimally laid
+                    # out) instead of silently permuting columns. Under manual tp
+                    # the shard's local slice is one interleave chunk (the shard-
+                    # major layout puts shard i's [q_i|k_i|v_i] in chunk i), so
+                    # the local split factor is fuse / tp_n. A gate on the
+                    # attention output is a fourth constituent.
+                    fw = lp["wqkv"]
+                    if fw.fuse % tp_n != 0:
+                        raise ValueError(
+                            f"fused weight interleave {fw.fuse} incompatible with "
+                            f"manual tp_n={tp_n}"
+                        )
+                    qkv = mm(y, fw.weight, "row")
+                    q, k, v, *gate = _split_fused(
+                        qkv, fw.fuse // tp_n, tuple(d // tp_n for d in fw.dims)
+                    )
+                    gate = gate[0] if gate else None
+                    q = q.reshape(b, t, hq, h.head_dim)
+                    k = k.reshape(b, t, hkv, h.head_dim)
+                    v = v.reshape(b, t, hkv, h.head_dim)
+                else:
+                    q = mm(y, lp["wq"], "row").reshape(b, t, hq, h.head_dim)
+                    k = mm(y, lp["wk"], "row").reshape(b, t, hkv, h.head_dim)
+                    v = mm(y, lp["wv"], "row").reshape(b, t, hkv, h.head_dim)
+                    if "wg" in lp:
+                        gate = mm(y, lp["wg"], "row")
+                if "q_norm" in lp:
+                    q = qk_rms_norm(q, lp["q_norm"], h.norm_epsilon)
+                    k = qk_rms_norm(k, lp["k_norm"], h.norm_epsilon)
+                if has_rope is not None:
+                    q = jnp.where(has_rope, apply_rope(q, cos, sin, interleaved), q)
+                    k = jnp.where(has_rope, apply_rope(k, cos, sin, interleaved), k)
+                elif kinds[0].rope:
+                    q = apply_rope(q, cos, sin, interleaved)
+                    k = apply_rope(k, cos, sin, interleaved)
+
+            caches = write_kv(caches, k, v, row, is_window)
+            if not isinstance(is_window, bool):
+                # the stacks go in and only the attention's output comes out
+                z = lax.cond(is_window, attend_window, attend_full, q, caches, row)
+            elif is_window:
+                z = attend_window(q, caches, row)
             else:
-                d = act(mm(y, lp["w1"], "row"))
-                l3 = mm(y, lp["w3"], "row")
-                f = mm(d * l3.astype(d.dtype), lp["w2"], "col", sync=True)
-            x = x + f.astype(x.dtype)
-        return (x, k_cache, v_cache), None
+                z = attend_full(q, caches, row)
+
+            with jax.named_scope("attn"):
+                if gate is not None:
+                    with jax.named_scope("gate"):
+                        z = (
+                            z.astype(jnp.float32)
+                            * jax.nn.sigmoid(gate.astype(jnp.float32))
+                        ).astype(z.dtype)
+                o = mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
+                if "post_att_norm" in lp:
+                    o = rms_norm(o, lp["post_att_norm"], h.norm_epsilon)
+                x = x + o
+
+            # -- FFN block (reference: src/llm.cpp:405-557) --
+            with jax.named_scope("norm"):
+                y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
+            with jax.named_scope("moe" if experts else "ffn"):
+                if experts:
+                    with jax.named_scope(phase):
+                        with jax.named_scope("routed"):
+                            f, counts = moe_block(y, lp, lf)
+                        if tp_axis is not None:
+                            # manual tp: experts arrived F-sliced (same layout the
+                            # mesh path shards); the local partial outputs all-reduce
+                            # here instead of inside the helpers' shard_map
+                            f = lax.psum(f, tp_axis)
+                        if "shared_w2" in lp:
+                            with jax.named_scope("shared"):
+                                f = f + swiglu(y, "shared_").astype(f.dtype)
+                else:
+                    f = swiglu(y)
+                f = f.astype(x.dtype)
+                if "post_ffn_norm" in lp:
+                    f = rms_norm(f, lp["post_ffn_norm"], h.norm_epsilon)
+                x = x + f
+            return (x, caches), counts
+
+        return layer_step
 
     # scopes name the device's operations in a profile (`op_name`) and
     # change nothing that is compiled: what runs under `layers` but under
@@ -1125,11 +1476,48 @@ def run_layers(
     # which are the norms, a dense model's weights and the layer number.
     # The caches are the scan's carry: as `xs` and `ys` every layer's whole
     # lane cache was copied out of the stack and back to write a row a lane
-    n_layers = jax.tree.leaves(k_cache)[0].shape[0]
-    with jax.named_scope("layers"):
-        (x, k_new, v_new), _ = lax.scan(
-            layer_step,
-            (x, k_cache, v_cache),
-            (sliced, jnp.arange(n_layers, dtype=jnp.int32)),
-        )
-    return x, k_new, v_new
+    caches = (k_cache, v_cache) if kw_cache is None else (
+        k_cache, v_cache, kw_cache, vw_cache)
+    counted = []
+    for a, e in segments:
+        kinds = [table[0] if alike else table[l] for l in range(a, e)]
+        whole = (a, e) == (0, n_layers)
+        r0 = kinds[0].ffn_row
+        if not alike and [kind.ffn_row for kind in kinds] != list(range(r0, r0 + e - a)):
+            raise ValueError(f"layers [{a}, {e}) are not one run of their FFN stack")
+        # beside experts, the leading dense layers' FFN is stacked apart
+        # under `dense_`; a scan sees its own kind's under the plain names
+        mine = {}
+        for name, leaf in layers.items():
+            of_ffn = len(segments) > 1 and (
+                name.startswith(("dense_", "shared_"))
+                or name in ("w1", "w2", "w3", "w13", "moe_gate", "expert_bias")
+            )
+            if of_ffn and name.startswith("dense_") == kinds[0].experts:
+                continue  # the other kind's
+            lo, n = (r0, jax.tree.leaves(leaf)[0].shape[0]) if of_ffn else (a, n_layers)
+            if not _is_quant_stack(leaf) and (lo, e - a) != (0, n):
+                leaf = jax.tree.map(lambda v: v[lo : lo + e - a], leaf)
+            mine[name.removeprefix("dense_") if of_ffn else name] = leaf
+        stacks = {k: v for k, v in mine.items() if _is_quant_stack(v)}
+        sliced = {k: v for k, v in mine.items() if k not in stacks}
+        extra = {}
+        if not alike:
+            extra["row"] = tuple(
+                jnp.asarray([kind.row * (kind.window == w) for kind in kinds], jnp.int32)
+                for w in (False, True)
+            )
+        for flag in ("window", "rope"):
+            if len({getattr(kind, flag) for kind in kinds}) > 1:
+                extra[flag] = jnp.asarray([getattr(kind, flag) for kind in kinds])
+        with jax.named_scope("layers"):
+            (x, caches), counts = lax.scan(
+                make_step(a, kinds, stacks),
+                (x, caches),
+                (sliced, jnp.arange(a, e, dtype=jnp.int32), extra),
+            )
+        if counts is not None:
+            counted.append(counts.sum(axis=0))
+    if counted:
+        route_stats.append(sum(counted))
+    return (x, *caches)

@@ -85,6 +85,9 @@ def _flash_stats_kernel(
     scale: float,
     s_stride: int = 1,
     quant_kv: bool = False,
+    ring: int = 0,
+    window: int = 0,
+    t_total: int = 0,
 ):
     """Like _flash_kernel but emits UNNORMALIZED online-softmax partial
     state (acc, m, l) — the drop-in local step for ring attention's
@@ -100,7 +103,13 @@ def _flash_stats_kernel(
     per-row f32 scales as two extra [bs, 128]-blocked refs (every lane
     holds the row's scale; column 0 is read) that follow the kv index
     map — dequant happens HERE on the VMEM tile, so HBM traffic is the
-    int8 bytes, amortized over the tile's bt queries."""
+    int8 bytes, amortized over the tile's bt queries. `ring` > 0: the key
+    rows are a ring (position p at row p % ring, `jnp_ops.ring_positions`)
+    that holds the lane's chunk, `t_total` rows, as its latest positions;
+    `window` > 0: a query sees the last `window` positions only. A block
+    none of whose rows a query of the tile can see is skipped: above the
+    causal frontier or below the window while the ring has not wrapped,
+    and all of a parked lane's."""
     if quant_kv:
         ks_ref, vs_ref, acc_out, m_out, l_out, m_ref, l_ref, acc_ref = rest
     else:
@@ -117,8 +126,19 @@ def _flash_stats_kernel(
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     s_start = s_pos0 + si * block_s * s_stride
+    visible = s_start <= q_pos0 + block_t - 1
+    if ring:
+        lane_last = pos_ref[pl.program_id(0) // n_heads] + t_total - 1
+        below = s_start + block_s - 1 <= q_pos0 - window if window else False
+        visible = jnp.logical_and(
+            lane_last >= 0,
+            jnp.logical_or(
+                lane_last >= ring,
+                jnp.logical_and(visible, jnp.logical_not(below)),
+            ),
+        )
 
-    @pl.when(s_start <= q_pos0 + block_t - 1)
+    @pl.when(visible)
     def _compute():
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
@@ -134,7 +154,13 @@ def _flash_stats_kernel(
         s_pos = s_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_t, block_s), 1
         ) * s_stride
-        scores = jnp.where(s_pos <= q_pos, scores, _NEG_INF)
+        seen = s_pos <= q_pos
+        if ring:  # a live lane: lane_last - s_pos + ring > 0
+            s_pos = lane_last - jax.lax.rem(lane_last - s_pos + ring, ring)
+            seen = jnp.logical_and(s_pos <= q_pos, s_pos >= 0)
+        if window:
+            seen = jnp.logical_and(seen, q_pos - s_pos < window)
+        scores = jnp.where(seen, scores, _NEG_INF)
         m_prev = m_ref[:, :1]
         m_cur = jnp.max(scores, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -163,7 +189,10 @@ def _flash_stats_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_t", "block_s", "interpret", "s_stride", "rows"),
+    static_argnames=(
+        "block_t", "block_s", "interpret", "s_stride", "rows", "ring", "window",
+        "row0",
+    ),
 )
 def flash_attention_stats(
     q: jnp.ndarray,  # [B, T, H, hd]
@@ -177,6 +206,9 @@ def flash_attention_stats(
     s_stride: int = 1,
     layer=None,  # int32 scalar: which layer of a stack
     rows: int = 0,  # attend to the first `rows` key rows only (0 = all S)
+    ring: int = 0,  # the rows are a ring of this many positions (0: in order)
+    window: int = 0,  # a query sees the last `window` positions (0: all)
+    row0: int = 0,  # the first key row read (whole blocks)
 ):
     """Blockwise causal GQA attention partial state: returns f32
     (acc [B, KH, G, T, hd], m [B, KH, G, T], l [B, KH, G, T]) — the same
@@ -199,8 +231,15 @@ def flash_attention_stats(
     index maps pick the layer, and the grid covers `rows` key rows — so a
     layer scan that carries the cache copies neither a layer nor a window
     out of it ahead of this call (which XLA cannot fuse into). A
-    `[B, KH, S, hd]` argument (ring attention, tests) is a stack of one."""
+    `[B, KH, S, hd]` argument (ring attention, tests) is a stack of one.
+
+    `ring` and `window` are a window layer's cache (`_flash_stats_kernel`):
+    the lane's chunk has been written, so the ring's latest position is
+    `q_pos0 + T - 1`, and no block index is clamped, since a ring that has
+    wrapped holds rows a query sees in every block."""
     quant_kv = isinstance(k, QuantKV)
+    if ring and (s_stride != 1 or quant_kv):
+        raise NotImplementedError("a ring cache is dense and unstrided")
     if isinstance(v, QuantKV) != quant_kv:
         raise TypeError(
             f"k and v must both be QuantKV or both dense, got "
@@ -211,8 +250,8 @@ def flash_attention_stats(
         k, v = jax.tree.map(lambda a: a[None], (k, v))
         layer = 0
     b, t, h, hd = q.shape
-    kh, s = k.shape[2], rows or k.shape[3]
-    assert s <= k.shape[3], (rows, k.shape)
+    kh, s = k.shape[2], rows or k.shape[3] - row0
+    assert row0 + s <= k.shape[3], (row0, rows, k.shape)
     g = h // kh
     if not block_t or not block_s:
         picked = pick_flash_blocks(t, s)
@@ -228,6 +267,7 @@ def flash_attention_stats(
         block_t = block_t or auto_t
         block_s = block_s or auto_s
     assert t % block_t == 0 and s % block_s == 0, (t, s, block_t, block_s)
+    assert row0 % block_s == 0, (row0, block_s)
     n_t = t // block_t
     n_s = s // block_s
     scale = 1.0 / (hd**0.5)
@@ -256,7 +296,12 @@ def flash_attention_stats(
             // block_s,
             0,
         )
-        return (l_ref[0], bh // h, (bh % h) // g, jnp.minimum(si, limit), 0)
+        if ring:
+            limit = n_s - 1
+        return (
+            l_ref[0], bh // h, (bh % h) // g,
+            row0 // block_s + jnp.minimum(si, limit), 0,
+        )
 
     in_specs = [
         pl.BlockSpec((1, block_t, hd), q_map),
@@ -294,6 +339,9 @@ def flash_attention_stats(
             scale=scale,
             s_stride=s_stride,
             quant_kv=quant_kv,
+            ring=ring,
+            window=window,
+            t_total=t,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -754,10 +802,13 @@ def flash_attention(
     interpret: bool = False,
     layer=None,
     rows: int = 0,
+    ring: int = 0,
+    window: int = 0,
+    row0: int = 0,
 ) -> jnp.ndarray:
     """Blockwise causal GQA attention; returns [B, T, H, hd] in q.dtype.
-    `layer` and `rows`: the cache stack read in place, as
-    `flash_attention_stats` says.
+    `layer` and `rows`: the cache stack read in place, `ring` and `window`:
+    a window layer's cache, as `flash_attention_stats` says.
 
     Implemented as normalize(flash_attention_stats(...)) so one kernel body
     serves both the dense path and ring attention's partial-state merge; the
@@ -767,7 +818,7 @@ def flash_attention(
     acc, m, l = flash_attention_stats(
         q, k_cache, v_cache, pos, 0,
         block_t=block_t, block_s=block_s, interpret=interpret,
-        layer=layer, rows=rows,
+        layer=layer, rows=rows, ring=ring, window=window, row0=row0,
     )
     l_safe = jnp.where(l == 0.0, 1.0, l)
     out = acc / l_safe[..., None]  # [B, KH, G, T, hd]

@@ -147,6 +147,8 @@ def attention_stats(
     q_pos0,  # scalar or [B]: absolute position of q[:, 0] (per lane)
     s_pos0,  # scalar: absolute position of k[:, :, 0]
     s_stride: int = 1,  # position step between consecutive key rows
+    ring: int = 0,  # key rows are a ring of this many positions (0: in order)
+    window: int = 0,  # a query sees the last `window` positions only (0: all)
 ):
     """Causal GQA attention partial state (unnormalized acc, running max m,
     denominator l) in f32 — the single source of the reference's
@@ -173,7 +175,15 @@ def attention_stats(
     # global position s_pos0 + j*stride (sp shard of a strided cache;
     # see parallel/sharding.cache_specs / docs on sp windows)
     s_pos = s_pos0 + jnp.arange(ts, dtype=jnp.int32) * s_stride
-    mask = s_pos[None, None, :] <= q_pos[:, :, None]  # [1 or B, tq, ts]
+    if ring:
+        s_pos = ring_positions(q_pos0_arr + (tq - 1), s_pos, ring)[:, None, :]
+    else:
+        s_pos = s_pos[None, None, :]
+    mask = s_pos <= q_pos[:, :, None]  # [1 or B, tq, ts]
+    if ring:
+        mask = jnp.logical_and(mask, s_pos >= 0)
+    if window:
+        mask = jnp.logical_and(mask, q_pos[:, :, None] - s_pos < window)
     scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
     m = jnp.max(scores, axis=-1)  # [b, kh, g, tq]
     p = jnp.exp(scores - m[..., None])
@@ -184,15 +194,30 @@ def attention_stats(
     return acc, m, l
 
 
+def ring_positions(last, rows, ring: int):
+    """The position that each of a ring's `rows` holds once position `last`
+    ([B] or [1]) is written: position p lies at row p % ring, so row i holds
+    the latest position <= last that is i modulo ring. A row that holds
+    nothing yet, and every row of a parked lane (`last` < 0), comes out
+    negative. [B or 1, len(rows)]."""
+    last = last[:, None]
+    return last - jnp.mod(last - rows[None, :], ring)
+
+
 def attention_dense(
     q: jnp.ndarray,  # [B, T, H, hd]
     k_cache: jnp.ndarray,  # [B, KH, S, hd]
     v_cache: jnp.ndarray,
     pos,  # scalar: absolute position of q[:, 0]
+    ring: int = 0,
+    window: int = 0,
 ) -> jnp.ndarray:
-    """Normalized causal GQA attention over the cache; [B, T, H, hd]."""
+    """Normalized causal GQA attention over the cache; [B, T, H, hd].
+    `ring` and `window`: a window layer's cache, as `attention_stats` says."""
     b, t, h, hd = q.shape
-    acc, m, l = attention_stats(q, k_cache, v_cache, pos, 0)
+    acc, m, l = attention_stats(
+        q, k_cache, v_cache, pos, 0, ring=ring, window=window
+    )
     l_safe = jnp.where(l == 0.0, 1.0, l)
     out = acc / l_safe[..., None]  # [b, kh, g, tq, hd]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, hd).astype(q.dtype)

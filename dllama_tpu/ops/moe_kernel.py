@@ -332,7 +332,8 @@ _GROUP_ROWS = 32  # row tile; worst-case wasted compute = E extra tiles
 
 
 def _grouped_schedule(top_i, weights, n_tokens, n_experts,
-                      max_segments: int | None = None):
+                      max_segments: int | None = None,
+                      rows: int = _GROUP_ROWS):
     """jnp (traced) schedule for the grouped kernel.
 
     Returns (t_sorted [A_pad], w_col [A_pad, 1], step_lo/hi/tile/expert
@@ -353,7 +354,7 @@ def _grouped_schedule(top_i, weights, n_tokens, n_experts,
     (never executed: the caller's predicate guarantees the fit)."""
     n, k = top_i.shape
     a = n * k
-    r = _GROUP_ROWS
+    r = rows
     a_pad = -(-a // r) * r
     n_tiles = a_pad // r
     seg_budget = (
@@ -535,7 +536,7 @@ def _grouped_kernel_q40(
     acc_ref,  # VMEM [R, D] f32
     *,
     n_f: int,
-    n_steps: int,
+    n_steps,  # the grid's steps: static, or a traced scalar (`_held_kernel_q40`)
     rows: int,
 ):
     g, fi = pl.program_id(0), pl.program_id(1)
@@ -638,3 +639,88 @@ def moe_grouped_experts_q40(
       w1q, w1d, w3q, w3d, w2q, w2d)
 
     return jnp.zeros((n, d), jnp.float32).at[t_s].add(o_sorted)
+
+
+# ---------------------------------------------------------------------------
+# A share of the experts: the router scores all of a layer's experts, and
+# this chip holds `n_held` of them. Pairs that landed on an expert held
+# elsewhere carry the id `n_held`: they sort behind every held pair, as the
+# padding does, and the grid stops at the last step that holds a real pair
+# (a dynamic grid bound), so neither a grid step nor a DMA is spent on them.
+# One kernel for decode and prefill alike: a decode batch is one row tile,
+# and its steps are the distinct held experts its tokens touched.
+# ---------------------------------------------------------------------------
+
+_HELD_ROWS = 128  # 2048 held pairs of a 512-row chunk over 32 experts: 16 tiles
+
+
+def _held_kernel_q40(lo_ref, hi_ref, tile_ref, expert_ref, n_ref, *refs, **kw):
+    _grouped_kernel_q40(
+        lo_ref, hi_ref, tile_ref, expert_ref, *refs, n_steps=n_ref[0], **kw
+    )
+
+
+def _with_count(index_map):
+    """A grouped index map under one more scalar-prefetch operand."""
+    return lambda g, fi, lo, hi, tile, expert, n: index_map(g, fi, lo, hi, tile, expert)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_held_experts_q40(
+    x: jnp.ndarray,  # [N, D]
+    w1q: jnp.ndarray,  # [E, D, F] int8, E the experts held here
+    w1d: jnp.ndarray,
+    w2q: jnp.ndarray,
+    w2d: jnp.ndarray,
+    w3q: jnp.ndarray,
+    w3d: jnp.ndarray,
+    held_i: jnp.ndarray,  # [N, k] int32: the held expert's row, or E
+    weights: jnp.ndarray,  # [N, k] f32
+    layer=0,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The held experts' part of a layer's routed sum, [N, D] f32: zero for
+    a token none of whose experts is held here."""
+    n, d = x.shape
+    e, _, f = w1q.shape[-3:]
+    first, (w1q, w1d, w2q, w2d, w3q, w3d) = _layer_experts(
+        layer, w1q, w1d, w2q, w2d, w3q, w3d
+    )
+    bf = _pick_f_block(f, d, quantized=True)
+    n_f = f // bf
+    r = _HELD_ROWS
+    t_s, w_col, lo, hi, tile, expert = _grouped_schedule(
+        held_i, weights, n, e, rows=r
+    )
+    a_pad = t_s.shape[0]
+    # sorted, the held pairs lead: the steps up to the last of them
+    n_pairs = jnp.sum(held_i < e).astype(jnp.int32)
+    n_steps = jnp.sum(lo < n_pairs).astype(jnp.int32)
+    x_sorted = jnp.take(x, t_s, axis=0).astype(jnp.bfloat16)
+    o_sorted = pl.pallas_call(
+        functools.partial(_held_kernel_q40, n_f=n_f, rows=r),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n_steps, n_f),
+            in_specs=[
+                pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
+                pl.BlockSpec((r, 1), _with_count(_grouped_row_map)),
+                pl.BlockSpec((1, d, bf), _with_count(_grouped_w13_map)),
+                pl.BlockSpec((1, d // Q_BLOCK, bf), _with_count(_grouped_w13_map)),
+                pl.BlockSpec((1, d, bf), _with_count(_grouped_w13_map)),
+                pl.BlockSpec((1, d // Q_BLOCK, bf), _with_count(_grouped_w13_map)),
+                pl.BlockSpec((1, bf, d), _with_count(_grouped_w2_map)),
+                pl.BlockSpec((1, bf // Q_BLOCK, d), _with_count(_grouped_w2_map)),
+            ],
+            out_specs=pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
+            scratch_shapes=[pltpu.VMEM((r, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((a_pad, d), jnp.float32),
+        interpret=interpret,
+    )(lo, hi, tile, expert + first, n_steps.reshape(1), x_sorted, w_col,
+      w1q, w1d, w3q, w3d, w2q, w2d)
+    # tiles the grid never reached hold whatever the buffer held
+    reached = jnp.arange(a_pad, dtype=jnp.int32)[:, None] < n_pairs
+    return jnp.zeros((n, d), jnp.float32).at[t_s].add(
+        jnp.where(reached, o_sorted, 0.0)
+    )

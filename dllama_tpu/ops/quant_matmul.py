@@ -337,6 +337,23 @@ def _pick_block(n: int, preferred: int, ragged: bool = False) -> int:
 # every 8B width; beyond that the rows are tiled, re-reading the weights
 # once per row block — prefill is compute-bound, so the re-read is cheap.
 BLOCK_M = 512
+# What a k step that accumulates may hold of the activations, in bytes of
+# its bf16 (rows, block_k) tile: 512 rows against 3584 deep, the largest
+# that has compiled (k = 14336). At 4096 deep, accumulated over three steps
+# (k = 12288), the described v5e's compiler passes the 16 MB of scoped VMEM
+# by a third of a megabyte; a single step that deep (k = 4096) keeps no
+# partial product beside the accumulator and fits.
+ACC_X_TILE_BYTES = BLOCK_M * 3584 * 2
+
+
+def _pick_k_block(k: int, preferred: int, rows: int) -> int:
+    """`_pick_block` for the contraction axis under `rows` activation rows:
+    the deepest legal block whose activation tile, where k takes several
+    steps, stays within `ACC_X_TILE_BYTES`."""
+    bk = _pick_block(k, preferred)
+    if bk < k and rows * bk * 2 > ACC_X_TILE_BYTES:
+        bk = _pick_block(k, ACC_X_TILE_BYTES // (rows * 2) // 128 * 128)
+    return bk
 
 
 def _qmm_call(
@@ -356,11 +373,10 @@ def _qmm_call(
     assert values.shape[1:] == (k // pack, n), (values.shape, x.shape)
     assert scales.shape == (values.shape[0], k // Q_BLOCK, n), scales.shape
     bn = _pick_block(n, block_n, ragged=True)
-    bk = _pick_block(k, block_k)
-    assert bk % Q_BLOCK == 0
-
-    n_k = k // bk
     bm = min(m, BLOCK_M)
+    bk = _pick_k_block(k, block_k, bm)
+    assert bk % Q_BLOCK == 0
+    n_k = k // bk
     return pl.pallas_call(
         functools.partial(kernel, n_k=n_k),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),

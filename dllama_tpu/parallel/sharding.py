@@ -39,7 +39,7 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
     is in//2 and the scale leaf's is in//32; both divide by tp under the
     engine's 32*tp divisibility check, so the col shard boundaries stay
     nibble- and block-aligned."""
-    moe = h.arch == LlmArch.QWEN3_MOE
+    moe = h.arch in (LlmArch.QWEN3_MOE, LlmArch.AFMOE)
     # stacked layer weights carry a leading layer axis; MoE adds an expert axis
     row = P(None, None, None, "tp") if moe else P(None, None, "tp")  # out split
     col = P(None, None, "tp", None) if moe else P(None, "tp", None)  # in split
@@ -61,9 +61,16 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
     }
     if moe:
         layers["moe_gate"] = P()
-    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE):
-        layers["q_norm"] = P()
-        layers["k_norm"] = P()
+        layers["expert_bias"] = P()
+        # what lies beside the experts: a shared expert every token passes
+        # through and leading dense layers, split as a dense FFN is
+        for prefix in ("shared_", "dense_"):
+            for n in ("w1", "w3", "w13"):
+                layers[prefix + n] = P(None, None, "tp")
+            layers[prefix + "w2"] = P(None, "tp", None)
+    layers["wg"] = P(None, None, "tp")  # the attention gate: heads, as wq
+    for n in ("q_norm", "k_norm", "post_att_norm", "post_ffn_norm"):
+        layers[n] = P()
     return {
         # vocab-sharded (the reference computes the embedding on the root
         # node only and broadcasts X — SYNC_WITH_ROOT, src/llm.cpp:256 —

@@ -268,6 +268,25 @@ class InferenceEngine:
         self.reader = ModelReader(model_path, max_seq_len=max_seq_len)
         self.header: LlmHeader = self.reader.header
         self.tokenizer = tokenizer
+        # a model with window layers keeps them in a second cache stack,
+        # written as a ring (models/transformer.init_kv_cache): one device
+        # serves it, and what has not been made to work over two stacks
+        # says so here and not in a wrong token
+        from ..formats.model_file import layer_table
+
+        self._two_cache_kinds = any(k.window for k in layer_table(self.header))
+        if self._two_cache_kinds:
+            for flag, n in (("--tp", tp), ("--sp", sp), ("--pp", pp), ("--dp", dp)):
+                if n > 1:
+                    raise ValueError(
+                        f"{flag} {n}: window attention layers over a ring "
+                        f"cache run on one device ({self.header.arch.name})"
+                    )
+            if kv_dtype in ("int8", jnp.int8):
+                raise ValueError(
+                    "--kv-dtype int8: the window layers' ring cache is not "
+                    f"quantized ({self.header.arch.name})"
+                )
         validate_tp(self.header, tp)
         # sequence parallelism: the KV cache's sequence axis shards over sp
         # chips (the long-context axis; models/transformer._attention_sp).
@@ -392,18 +411,71 @@ class InferenceEngine:
         # scratch rows for INVALID-tick writes (parallel/pipeline.py
         # park_pos): without padding every tick select-merges the whole
         # stage cache, which costs as much HBM as the stage weight read.
-        pad = max(self.prefill_buckets) if (batch_size > 1 or pp > 1) else 0
+        pad = (
+            max(self.prefill_buckets)
+            if (batch_size > 1 or pp > 1 or self._two_cache_kinds) else 0
+        )
         if pad and sp > 1:
             pad += (-pad) % sp
         self._lane_pad = pad
         self._park = self.header.seq_len  # first padding row
+        # the window layers' ring: a window and the largest chunk, so that
+        # a chunk, written before it is read, overwrites no row its first
+        # query still sees; never more than the context. Its stack has the
+        # same padding rows behind it for parked lanes' writes.
+        self.kv_ring = (
+            min(self.header.seq_len,
+                self.header.sliding_window + max(self.prefill_buckets))
+            if self._two_cache_kinds else 0
+        )
         self._cache_sharding = {
             k: NamedSharding(self.mesh, spec)
             for k, spec in cache_specs(
                 self.header, sp=sp > 1, pp=pp > 1
             ).items()
         }
+        if self._two_cache_kinds:
+            self._cache_sharding.update(
+                kw=self._cache_sharding["k"], vw=self._cache_sharding["v"]
+            )
+        self._m_ring_wraps = self.obs.counter(
+            "dllama_kv_ring_wraps_total",
+            "Times a lane's position passed the end of the window layers' "
+            "ring cache and its writes began again at the ring's first row.",
+        )
+        self._m_moe_pairs = self.obs.counter(
+            "dllama_moe_pairs_total",
+            "Token-expert pairs the router chose in decode blocks, on a "
+            "model that holds a share of its experts: routed = all of live "
+            "lanes, held = those that landed on an expert held here.",
+            labelnames=("landed",),
+        )
+        self._m_moe_touched = self.obs.counter(
+            "dllama_moe_held_experts_touched_total",
+            "Held experts that some live lane's token was routed to, summed "
+            "over the expert layers of every decode step: what the expert "
+            "kernel had to read.",
+        )
         self.cache = self._fresh_cache()
+        g_bytes = self.obs.gauge(
+            "dllama_kv_cache_bytes",
+            "Device bytes of the lane KV cache by kind of layer: full = "
+            "rows for the whole context, window = a ring of the window "
+            "and one chunk.",
+            labelnames=("kind",),
+        )
+        self.kv_cache_bytes = {
+            kind: sum(
+                leaf.nbytes for name in names if name in self.cache
+                for leaf in jax.tree.leaves(self.cache[name])
+            )
+            for kind, names in (("full", ("k", "v")), ("window", ("kw", "vw")))
+        }
+        for kind, n in self.kv_cache_bytes.items():
+            g_bytes.labels(kind=kind).set(n)
+        self.recorder.record(
+            "kv_cache", ring=self.kv_ring, **{f"{k}_bytes": v for k, v in self.kv_cache_bytes.items()}
+        )
         self._token_sharding = NamedSharding(self.mesh, P("dp", None))
         # AOT lowering specs are SNAPSHOTTED once here (r5 advisor item):
         # params never change after init and every fresh cache has the
@@ -513,8 +585,11 @@ class InferenceEngine:
 
         else:
 
+            kv_ring = self.kv_ring
+
             def fwd(params, tokens, pos, cache, *, attn_window=0,
-                    logits_mode="all", attn_park_threshold=0, n_micro=1):
+                    logits_mode="all", attn_park_threshold=0, n_micro=1,
+                    route_stats=None):
                 del n_micro  # sequence-wave microbatching is pp-only
                 return forward(
                     params, h, tokens, pos, cache, mesh=mesh,
@@ -522,9 +597,15 @@ class InferenceEngine:
                     attn_park_threshold=attn_park_threshold,
                     sync_quant=sync_quant,
                     moe_decode_dedup=moe_decode_dedup,
+                    kv_ring=kv_ring, route_stats=route_stats,
                 )
 
         self._fwd = fwd
+        # the lane decode block counts routed pairs where some of the
+        # experts the router scores lie on other chips
+        self._counts_routing = (
+            pp == 1 and self.header.n_experts < self.header.n_routed_experts
+        )
 
     def _pp_micro(self, t: int) -> int:
         """Sequence-wave microbatch count for a T-wide pp prefill chunk:
@@ -552,6 +633,7 @@ class InferenceEngine:
             self.batch_size,
             dtype=self.kv_dtype,
             seq_len=self.header.seq_len + self._lane_pad,
+            ring=self.kv_ring or None, ring_pad=self._lane_pad,
         )
         return {
             k: jax.device_put(v, self._cache_sharding[k]) for k, v in cache.items()
@@ -614,6 +696,23 @@ class InferenceEngine:
             ms=round((t1 - t0) * 1000, 3),
         )
 
+    def _rows_in_context(self, starts: list[int], n: int) -> dict:
+        """`step_dispatch` fields of a model with two kinds of cache: the
+        key and value rows, a layer, that the queries of the dispatch's live
+        lanes see, summed over its `n` steps or rows: in the full layers all
+        of a lane's context, in the window layers at most the window. The
+        ring's wraps that the dispatch brings are counted here too. Nothing
+        for a model of one kind."""
+        if not self._two_cache_kinds:
+            return {}
+        wraps = sum((p + n) // self.kv_ring - p // self.kv_ring for p in starts)
+        if wraps:
+            self._m_ring_wraps.inc(wraps)
+        w = self.header.sliding_window
+        # what the dispatch's queries see: position p's sees p + 1 rows
+        seen = [p + i + 1 for p in starts for i in range(n)]
+        return {"rows_full": sum(seen), "rows_window": sum(min(s, w) for s in seen)}
+
     def _dispatch_prep(self, step: str):
         """Open the span of the host work before a lane dispatch: window
         choice, prefetch, seed vector and the small ``jnp.asarray``
@@ -647,7 +746,8 @@ class InferenceEngine:
     # -- compiled steps ------------------------------------------------------
 
     def _attn_window(self, limit: int) -> int:
-        """Smallest power-of-2 window >= limit (min 512) covering the live
+        """Smallest power-of-2 window >= limit (min 512, and no less than a
+        model's sliding window) covering the live
         cache prefix; full seq_len when nothing smaller fits. One
         compiled program per window keeps decode reads proportional to
         the context actually used instead of the allocated seq_len —
@@ -667,8 +767,14 @@ class InferenceEngine:
             while w < limit:
                 w *= 2
             return min(w, s)
+        # a model with window layers starts at its window: below it every
+        # layer would read alike, and each smaller window is three more
+        # programs to build (at a context of 16384 six windows took 179 s
+        # of a cold start on the chip, three 100 s: PERF.md, PR 32), for
+        # contexts that one of 4096 rows serves at up to 3584 rows more a
+        # layer and lane in a decode step
         w = 512
-        while w < limit:
+        while w < max(limit, self.header.sliding_window):
             w *= 2
         # NB: crossing a window boundary mid-generation compiles a fresh
         # program for the next window (one synchronous stall per crossing,
@@ -1062,6 +1168,13 @@ class InferenceEngine:
 
     # -- per-lane serving (continuous-batching surface) ----------------------
 
+    def _refuse_speculation(self) -> None:
+        if self._two_cache_kinds:
+            raise ValueError(
+                "--speculation: the verify programs are untested over the "
+                f"window layers' ring cache ({self.header.arch.name})"
+            )
+
     def _require_lanes(self) -> None:
         if self._lane_pad == 0:
             raise ValueError(
@@ -1179,6 +1292,7 @@ class InferenceEngine:
                     ),
                 )
         if spec_k > 0:
+            self._refuse_speculation()
             # one verify program per draft bucket (width 1 + bucket for
             # the pending token) at the base window; deeper windows ride
             # the same 75% prefetch as the decode block
@@ -1233,9 +1347,8 @@ class InferenceEngine:
             # page-copy programs sit on the admission (adopt) and finish
             # (publish) paths; pre-build every power-of-two bucket up to a
             # full sequence's page count
-            max_pages = max(1, self.header.seq_len // self._kv_page_size)
             b = 1
-            while b <= max_pages:
+            while b <= self._kv_max_pages:
                 for kind in ("adopt", "publish"):
                     self._prefetch(
                         ("kv_" + kind, b),
@@ -1313,6 +1426,7 @@ class InferenceEngine:
         with self._dispatch(
             "prefill_lane_chunk", prep, lane=lane, pos=pos0,
             n_tokens=width, bucket=bucket, window=window,
+            **self._rows_in_context([pos0], width),
         ):
             if native:
                 with self._kv_pool_guard():
@@ -1390,6 +1504,15 @@ class InferenceEngine:
             h.n_layers, self._kv_pool_pages, h.n_kv_heads,
             self._kv_page_size, h.head_dim,
         )
+        if self._two_cache_kinds:
+            # a page holds its positions' rows of every layer, so the pool
+            # is two stacks as the cache is, under one page number
+            return {
+                name: jax.device_put(
+                    jnp.zeros((leaf.shape[0], *shape[1:]), self.kv_dtype), sharding
+                )
+                for name, leaf in self._cache_specs.items()
+            }
         if self.kv_dtype == jnp.int8:
             def leaf():
                 return QuantKV(
@@ -1428,6 +1551,11 @@ class InferenceEngine:
             raise ValueError(
                 f"page_size {page_size} exceeds lane padding {self._lane_pad}"
             )
+        if native and self._two_cache_kinds:
+            raise ValueError(
+                "--kv-native 1: the pool-native programs read one kind of "
+                f"cache; {self.header.arch.name} has window layers over a ring"
+            )
         if native and (self.pp > 1 or self.sp > 1):
             # the pp fwd closure parks at the slab's seq_len and sp shards
             # the sequence axis; both assume slab geometry — the native
@@ -1448,6 +1576,24 @@ class InferenceEngine:
         self._kv_pool_specs = jax.tree.map(_sds, self.kv_pool)
         self.kv_pool_epoch += 1
         return n_pages
+
+    def kv_publishable(self, n_tokens: int) -> int:
+        """How many of a lane's first `n_tokens` positions still have their
+        rows in every layer's cache, so that pages of them may be stored:
+        all, unless the window layers' ring has wrapped. Then the lane's
+        first positions are gone from those layers, no page from position 0
+        can be stored, and a later request with that prefix misses. (A
+        prefix that was stored is whole: every row a window layer's next
+        query needs came with it.)"""
+        if self._two_cache_kinds and n_tokens > self.kv_ring:
+            return 0
+        return n_tokens
+
+    @property
+    def _kv_max_pages(self) -> int:
+        """The most pages one adopt or publish can move."""
+        rows = self.kv_ring if self._two_cache_kinds else self.header.seq_len
+        return max(1, rows // self._kv_page_size)
 
     def reset_kv_pool(self) -> None:
         """Reallocate the pool buffer (all page contents dropped). The
@@ -1556,31 +1702,47 @@ class InferenceEngine:
 
         def make():
             ps = self._kv_page_size
+            # a lane's position 0 in each stack: the window layers' ring
+            # (which has not wrapped where pages are copied,
+            # `kv_publishable`) lies behind its spare rows
+            row0 = {
+                name: self._lane_pad if name in ("kw", "vw") else 0
+                for name in self._cache_specs
+            }
+
+            def by_stack(leaf, cache, pool):
+                return {
+                    name: jax.tree.map(
+                        partial(leaf, first=row0[name]),
+                        cache[name], pool[name],
+                    )
+                    for name in cache
+                }
 
             if kind == "adopt":
 
                 @partial(jax.jit, donate_argnums=(0,))
                 def fn(cache, pool, lane, start_page, ids):
-                    def leaf(c, p):
+                    def leaf(c, p, first):
                         pages = p[:, ids]  # [L, bucket, KH, ps, last]
                         l_, _, kh, _, last = pages.shape
                         rows = pages.transpose(0, 2, 1, 3, 4).reshape(
                             l_, 1, kh, bucket * ps, last
                         )
                         return lax.dynamic_update_slice(
-                            c, rows, (0, lane, 0, start_page * ps, 0)
+                            c, rows, (0, lane, 0, first + start_page * ps, 0)
                         )
 
-                    return jax.tree.map(leaf, cache, pool)
+                    return by_stack(leaf, cache, pool)
 
             else:
 
                 @partial(jax.jit, donate_argnums=(1,))
                 def fn(cache, pool, lane, start_page, ids):
-                    def leaf(c, p):
+                    def leaf(c, p, first):
                         l_, _, kh, _, last = c.shape
                         rows = lax.dynamic_slice(
-                            c, (0, lane, 0, start_page * ps, 0),
+                            c, (0, lane, 0, first + start_page * ps, 0),
                             (l_, 1, kh, bucket * ps, last),
                         )
                         pages = rows[:, 0].reshape(
@@ -1588,7 +1750,7 @@ class InferenceEngine:
                         ).transpose(0, 2, 1, 3, 4)
                         return p.at[:, ids].set(pages)
 
-                    return jax.tree.map(leaf, cache, pool)
+                    return by_stack(leaf, cache, pool)
 
             return fn
 
@@ -1993,11 +2155,16 @@ class InferenceEngine:
             park = self._park
 
             seq_len = self.header.seq_len
+            # a model that holds a share of its experts counts, a step, the
+            # pairs its router chose and those that landed here: three more
+            # columns of the block's one output, so no read-back is added
+            counting = self._counts_routing
 
             @partial(jax.jit, donate_argnums=(2,))
             def block(params, token, cache, pos_vec, active, seeds, temperature, topp):
                 def body(i, carry):
                     tok, cache, out = carry
+                    counts = [] if counting else None
                     # per-lane in-block stop: a lane whose window fills mid-
                     # block parks itself (writes land in padding, token 0
                     # emitted) instead of shrinking the whole batch's block to
@@ -2017,6 +2184,7 @@ class InferenceEngine:
                             params, tok, cur, cache,
                             attn_window=window,
                             attn_park_threshold=park, logits_mode="last",
+                            **({"route_stats": counts} if counting else {}),
                         )
                     last = logits[:, -1, :]
                     # per-lane (seed, position)-derived keys: a seeded lane's
@@ -2026,10 +2194,15 @@ class InferenceEngine:
                         last, temperature, topp, seeds, cur, ok
                     )
                     nxt = jnp.where(ok, nxt, 0).reshape(-1, 1)
-                    out = lax.dynamic_update_index_in_dim(out, nxt[:, 0], i, axis=0)
+                    row = nxt[:, 0]
+                    if counting:
+                        row = jnp.concatenate([row, counts[0]])
+                    out = lax.dynamic_update_index_in_dim(out, row, i, axis=0)
                     return nxt, cache, out
 
-                out0 = jnp.zeros((n_steps, token.shape[0]), jnp.int32)
+                out0 = jnp.zeros(
+                    (n_steps, token.shape[0] + (3 if counting else 0)), jnp.int32
+                )
                 _, cache, out = lax.fori_loop(
                     0, n_steps, body, (token, cache, out0)
                 )
@@ -2145,6 +2318,7 @@ class InferenceEngine:
         with self._dispatch(
             "decode_lanes", prep, pos=deepest, n_steps=n_steps,
             window=window, n_live=len(live), n_sampling=n_sampling,
+            **self._rows_in_context([pos[i] for i in live], n_steps),
         ) as timed, guard():
             if fault is not None:
                 raise fault
@@ -2159,6 +2333,18 @@ class InferenceEngine:
                     pos_arr, act_arr, *sampling,
                 )
             out_np = self._read_back("decode_lanes", out)
+        if self._counts_routing and not native:
+            routed, held, touched = (
+                int(n) for n in out_np[:, self.batch_size:].sum(axis=0)
+            )
+            out_np = out_np[:, : self.batch_size]
+            self._m_moe_pairs.labels(landed="routed").inc(routed)
+            self._m_moe_pairs.labels(landed="held").inc(held)
+            self._m_moe_touched.inc(touched)
+            self.recorder.record(
+                "moe_route", step="decode_lanes", n_steps=n_steps,
+                pairs_routed=routed, pairs_held=held, held_touched=touched,
+            )
         # each active stream advances one token per block row
         self._m_tpot.observe(timed["seconds"] / n_steps)
         self._m_sampler.labels(sampler="full" if n_sampling else "greedy").inc()
@@ -2355,6 +2541,7 @@ class InferenceEngine:
         are proposed as target token ids and verified by the target, so
         a vocab mismatch is a config error, not a quality problem."""
         self._require_lanes()
+        self._refuse_speculation()
         if self.pp > 1 or self.sp > 1:
             raise ValueError(
                 "draft model requires pp == 1 and sp == 1 (the draft "

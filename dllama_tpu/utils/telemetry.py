@@ -83,6 +83,7 @@ _REPLICATED_KEYS = {
     # embed left this set in r5: vocab-sharded over tp (param_spec_tree)
     "final_norm", "rope_cos", "rope_sin",
     "att_norm", "ffn_norm", "q_norm", "k_norm", "moe_gate",
+    "post_att_norm", "post_ffn_norm", "expert_bias",
 }
 
 

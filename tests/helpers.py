@@ -172,3 +172,57 @@ def make_tiny_tokenizer(
     )
     write_tokenizer(path, data)
     return data
+
+
+AFMOE_WINDOW = 32
+
+
+def tiny_afmoe_config(layer_types=None, **over) -> dict:
+    """A benchmark configuration file's worth of the `afmoe` architecture
+    (window and full layers, a dense layer and then experts, a share of the
+    experts) at test widths, for `benchmark/harness/weights.write_model`."""
+    types = layer_types or ["sliding_attention"] * 3 + ["full_attention", "sliding_attention"]
+    sliding = [t == "sliding_attention" for t in types]
+    period = 0 if all(sliding) else 4
+    cfg = {
+        "name": "afmoe-tiny", "family": "afmoe",
+        "hidden_size": 64, "intermediate_size": 160, "moe_intermediate_size": 128,
+        "num_hidden_layers": len(types), "num_attention_heads": 8,
+        "num_key_value_heads": 4, "head_dim": 16, "vocab_size": 512,
+        "max_position_embeddings": 4096, "rope_theta": 10000, "rms_norm_eps": 1e-5,
+        "num_experts": 4, "num_routed_experts": 8, "first_expert": 0,
+        "num_experts_per_tok": 2, "num_shared_experts": 1, "num_dense_layers": 1,
+        "route_norm": True, "route_scale": 2.448, "score_func": "sigmoid",
+        "mup_enabled": True, "sliding_window": AFMOE_WINDOW if any(sliding) else 0,
+        "layer_types": types,
+    }
+    cfg.update(over)
+    cfg["file"] = {
+        "arch": "AFMOE", "rope_pairing": "half", "qk_norm": True, "norm_epsilon_enum": 5,
+        "header": {
+            "sliding_window": cfg["sliding_window"], "full_attn_period": period,
+            "full_attn_no_rope": 1, "n_dense_layers": cfg["num_dense_layers"],
+            "n_shared_experts": cfg["num_shared_experts"], "score_func": 1,
+            "route_norm": 1, "route_scale_milli": 2448,
+            "n_routed_experts": cfg["num_routed_experts"],
+            "first_expert": cfg["first_expert"], "embed_scale": 1,
+        },
+        "tensors": {"embed": {"dist": "normal", "std": 0.125}},
+    }
+    if cfg["num_dense_layers"] < len(types):
+        cfg["file"]["tensors"]["expert_bias"] = {"dist": "normal", "std": 0.05}
+    return cfg
+
+
+def make_tiny_afmoe(path: str, seed: int = 3, **over) -> dict:
+    """Write a seeded tiny `afmoe` model at `path` through the benchmark's own
+    writer (the program's format code under it); returns its configuration."""
+    import sys
+
+    if REPO_ROOT not in sys.path:
+        sys.path.insert(0, REPO_ROOT)
+    from benchmark.harness import weights
+
+    cfg = tiny_afmoe_config(**over)
+    weights.write_model(path, cfg, seed)
+    return cfg

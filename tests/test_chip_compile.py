@@ -339,6 +339,98 @@ def test_layer_scan_writes_cache_rows_in_place(
     assert not cache_copies(text, cache), cache_copies(text, cache)
 
 
+# Trinity-Large's cut: 2 full layers at 16384 + 512 rows, 7 window layers as a
+# ring of 4096 + 512 between 512 spare rows on either side, 8 lanes
+TRINITY_FULL = (2, 8, 8, 16896, 128)
+TRINITY_RING = (7, 8, 8, 5632, 128)
+
+
+def trinity_layers(s):
+    """Trinity-Large-Preview's per-layer leaves as the loader stacks them:
+    attention over all 9 layers (q, k, v and the gate fused), the leading
+    dense layer's FFN apart, and 8 expert layers of a shared expert and the
+    32 experts held of 256."""
+    from dllama_tpu.ops.quant_matmul import FusedQuantWeight, QuantWeight
+
+    d, f, fd, e, n, ns = 3072, 3072, 12288, 32, 9, 8
+
+    def f32(*shape):
+        return sds(shape, jnp.float32, s)
+
+    def fused(layers, k, dims):
+        return FusedQuantWeight(q40_stack(layers, k, sum(dims), s), 1, tuple(dims))
+
+    def experts(k, width):
+        return QuantWeight(sds((ns, e, k, width), jnp.int8, s),
+                           sds((ns, e, k // 32, width), jnp.float32, s))
+
+    return dict(
+        att_norm=f32(n, d), ffn_norm=f32(n, d), post_att_norm=f32(n, d),
+        post_ffn_norm=f32(n, d), q_norm=f32(n, HD), k_norm=f32(n, HD),
+        wqkv=fused(n, d, (48 * HD, 8 * HD, 8 * HD, 48 * HD)),
+        wo=q40_stack(n, 48 * HD, d, s),
+        dense_w13=fused(1, d, (fd, fd)), dense_w2=q40_stack(1, fd, d, s),
+        moe_gate=f32(ns, d, 256), expert_bias=f32(ns, 256),
+        shared_w13=fused(ns, d, (f, f)), shared_w2=q40_stack(ns, f, d, s),
+        w1=experts(d, f), w3=experts(d, f), w2=experts(f, d),
+    )
+
+
+@pytest.mark.parametrize("rows,window", [(1, 8192), (512, 8192)],
+                         ids=["decode", "prefill"])
+def test_layer_scan_writes_both_cache_stacks_in_place(one_chip, monkeypatch, rows, window):
+    """Trinity's cut at its cell's size: window and full layers in one scan
+    over two cache stacks. Each stack is written by `dynamic-update-slice`
+    of the chunk's rows (the kind that a layer is not of, at its spare
+    rows), attention reads under a conditional that hands no stack through,
+    a chunk that wraps the ring is two such writes: so no instruction has a
+    layer of either stack as its result, and neither stack is copied or
+    kept in another layout. (A conditional around the writes copied the
+    other kind's stack whole; a scatter of the ring's rows, and a rolled
+    read-modify-write, made the compiler transpose the ring stack twice a
+    layer.) And the held experts' kernel, whose grid is as long as the
+    pairs that landed here, compiles."""
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+    from dllama_tpu.models import transformer as tf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = one_chip
+    h = LlmHeader(
+        arch=LlmArch.AFMOE, dim=3072, hidden_dim=12288, moe_hidden_dim=3072,
+        n_layers=9, n_heads=48, n_kv_heads=8, n_experts=32, n_active_experts=4,
+        vocab_size=25024, seq_len=16384, head_dim=HD, rope_type=RopeType.FALCON,
+        sliding_window=4096, full_attn_period=4, full_attn_no_rope=True,
+        n_dense_layers=1, n_shared_experts=1, score_sigmoid=True,
+        route_scale=2.448, n_routed_experts=256, embed_scale=True,
+    )
+    lanes = TRINITY_FULL[1]
+
+    def step(x, layers, k, v, kw, vw, pos, cos, sin):
+        counts = []
+        out = tf.run_layers(
+            x, layers, k, v, h, pos, jnp.where(pos >= 16384, -16896, pos), cos, sin,
+            attn_window=window, kw_cache=kw, vw_cache=vw, kv_ring=4608,
+            route_stats=counts,
+        )
+        return out, counts
+
+    text = compiled_text(
+        jax.jit(step, donate_argnums=(2, 3, 4, 5)),
+        sds((lanes, rows, 3072), jnp.bfloat16, s), trinity_layers(s),
+        sds(TRINITY_FULL, jnp.bfloat16, s), sds(TRINITY_FULL, jnp.bfloat16, s),
+        sds(TRINITY_RING, jnp.bfloat16, s), sds(TRINITY_RING, jnp.bfloat16, s),
+        sds((lanes,), jnp.int32, s),
+        sds((lanes, rows, HD // 2), jnp.float32, s),
+        sds((lanes, rows, HD // 2), jnp.float32, s),
+    )
+    assert text.count("dynamic-update-slice(") >= 4  # K and V rows of two stacks
+    for stack in (TRINITY_FULL, TRINITY_RING):
+        assert not cache_copies(text, stack), cache_copies(text, stack)
+        shape = ",".join(map(str, stack))
+        assert f"bf16[{shape}]{{3,4" not in text  # nor kept in a transposed layout
+    assert "moe_held_experts_q40" in text and " conditional(" in text
+
+
 def test_layer_scan_writes_cache_rows_in_place_tp4(tp4, monkeypatch):
     """The same prefill chunk at tp=4: KH is the stack's sharded axis
     (`P(None, "dp", "tp", None, None)` into the flash kernel's `shard_map`,
@@ -416,7 +508,7 @@ def test_lane_block_keeps_the_sampler_conditional(one_chip):
         return (x @ params["wcls"]).astype(jnp.float32)[:, None, :], cache
 
     stand_in = types.SimpleNamespace(
-        _precision=None, _fwd=fwd, _park=4096,
+        _precision=None, _fwd=fwd, _park=4096, _counts_routing=False,
         header=types.SimpleNamespace(seq_len=4096),
         _build=lambda key, make, specs, origin: make(),
     )
@@ -550,7 +642,7 @@ def test_moe_q40_expert_stacks_tp4(tp4, helper, layout):
         )
 
     def run(x, gate, w1, w2, w3, l):
-        return fn(x, gate, w1, w2, w3, k, tp4, layer=l)
+        return fn(x, gate, w1, w2, w3, tf.Routing(k), tp4, layer=l)
 
     text = compiled_text(
         jax.jit(run),

@@ -1319,14 +1319,22 @@ def test_failed_prefetch_is_marked(build_engine, kind, monkeypatch, caplog):
 # calls that must keep working, on the slab path the cells run
 
 
-@pytest.fixture(scope="module")
-def slab_engine(tmp_path_factory):
+@pytest.fixture(scope="module", params=["llama", "afmoe"])
+def slab_engine(tmp_path_factory, request):
+    """An engine of one kind of layer, and one of two (window and full
+    layers over two cache stacks, a share of the experts): the harness
+    calls both by the same names."""
     mp = str(tmp_path_factory.mktemp("slab") / "s.m")
-    cfg = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
-               head_dim=16, vocab_size=288, seq_len=1024)
-    make_tiny_model(mp, weight_type=FloatType.F32, cfg=cfg)
+    if request.param == "afmoe":
+        from helpers import make_tiny_afmoe
+
+        make_tiny_afmoe(mp)
+    else:
+        cfg = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
+                   head_dim=16, vocab_size=288, seq_len=1024)
+        make_tiny_model(mp, weight_type=FloatType.F32, cfg=cfg)
     e = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
-                        batch_size=2, prefill_buckets=(8, 16))
+                        batch_size=2, prefill_buckets=(8, 16), max_seq_len=1024)
     e.init_kv_pool(4, 40)
     return e
 

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dllama_tpu.models.transformer import Routing
 from dllama_tpu.ops.flash_attention import attention_ref, flash_attention
 from dllama_tpu.parallel.mesh import make_mesh
 from dllama_tpu.parallel.ring_attention import ring_attention
@@ -186,8 +187,8 @@ def test_moe_pallas_tp_branch_matches_dense():
     x = jnp.asarray(rng.standard_normal((1, 1, D)).astype(np.float32))
 
     mesh = make_mesh(tp=2)
-    out = _moe_ffn_pallas(x, gate, w1, w2, w3, K, mesh, interpret=True)
-    dense = _moe_ffn(x, gate, w1, w2, w3, K, silu)
+    out = _moe_ffn_pallas(x, gate, w1, w2, w3, Routing(K), mesh, interpret=True)
+    dense = _moe_ffn(x, gate, w1, w2, w3, Routing(K), silu)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(dense), rtol=1e-4, atol=1e-4
     )
@@ -223,10 +224,10 @@ def test_moe_pallas_tp_quantized_and_multitoken():
     x = jnp.asarray(rng.standard_normal((4, 1, D)).astype(np.float32))  # 4 dp lanes
 
     mesh = make_mesh(tp=2, dp=2)
-    out = _moe_ffn_pallas(x, gate, w1, w2, w3, K, mesh, interpret=True)
+    out = _moe_ffn_pallas(x, gate, w1, w2, w3, Routing(K), mesh, interpret=True)
     dense = _moe_ffn(
         x, gate, dequant(w1, jnp.float32), dequant(w2, jnp.float32),
-        dequant(w3, jnp.float32), K, silu,
+        dequant(w3, jnp.float32), Routing(K), silu,
     )
     # bf16 tolerance: the kernel computes in bf16, the reference in f32
     np.testing.assert_allclose(
@@ -315,9 +316,9 @@ def test_moe_pallas_tp_q80_sync_close():
     x = jnp.asarray(rng.standard_normal((1, 1, D)).astype(np.float32))
 
     mesh = make_mesh(tp=2)
-    exact = _moe_ffn_pallas(x, gate, w1, w2, w3, K, mesh, interpret=True)
+    exact = _moe_ffn_pallas(x, gate, w1, w2, w3, Routing(K), mesh, interpret=True)
     q80 = _moe_ffn_pallas(
-        x, gate, w1, w2, w3, K, mesh, interpret=True, sync_quant=True
+        x, gate, w1, w2, w3, Routing(K), mesh, interpret=True, sync_quant=True
     )
     scale = float(np.abs(np.asarray(exact)).max())
     err = float(np.abs(np.asarray(q80) - np.asarray(exact)).max())
@@ -346,8 +347,8 @@ def test_moe_grouped_matches_dense_routing():
     w1, w2, w3, gate = _rand_moe(rng, E, D, F)
     x = jnp.asarray(rng.standard_normal((2, 20, D)).astype(np.float32))
 
-    out = _moe_ffn_grouped(x, gate, w1, w2, w3, 3, mesh=None, interpret=True)
-    dense = _moe_ffn(x, gate, w1, w2, w3, 3, silu)
+    out = _moe_ffn_grouped(x, gate, w1, w2, w3, Routing(3), mesh=None, interpret=True)
+    dense = _moe_ffn(x, gate, w1, w2, w3, Routing(3), silu)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(dense), rtol=2e-2, atol=2e-2
     )
@@ -381,10 +382,10 @@ def test_moe_grouped_tp_and_q40():
     x = jnp.asarray(rng.standard_normal((2, 24, D)).astype(np.float32))
 
     mesh = make_mesh(tp=2, dp=2)
-    out = _moe_ffn_grouped(x, gate, w1, w2, w3, K, mesh, interpret=True)
+    out = _moe_ffn_grouped(x, gate, w1, w2, w3, Routing(K), mesh, interpret=True)
     dense = _moe_ffn(
         x, gate, dequant(w1, jnp.float32), dequant(w2, jnp.float32),
-        dequant(w3, jnp.float32), K, silu,
+        dequant(w3, jnp.float32), Routing(K), silu,
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(dense), rtol=3e-2, atol=3e-2
@@ -431,8 +432,8 @@ def test_moe_grouped_multilane_decode_parity():
     m = 6  # decode-lane scale
     x = jnp.asarray(rng.standard_normal((m, 1, D)).astype(np.float32))
 
-    ragged = _moe_ffn_pallas(x, gate, w1, w2, w3, 3, mesh=None, interpret=True)
-    grouped = _moe_ffn_grouped(x, gate, w1, w2, w3, 3, mesh=None, interpret=True)
+    ragged = _moe_ffn_pallas(x, gate, w1, w2, w3, Routing(3), mesh=None, interpret=True)
+    grouped = _moe_ffn_grouped(x, gate, w1, w2, w3, Routing(3), mesh=None, interpret=True)
     np.testing.assert_allclose(
         np.asarray(grouped), np.asarray(ragged), rtol=2e-2, atol=2e-2
     )
@@ -528,7 +529,7 @@ def test_moe_two_tier_dedup_matches_ragged():
     x_div = jnp.asarray(rng.standard_normal((m, 1, D)).astype(np.float32))
 
     def uniques(x):
-        ii, _ = _moe_route(x.reshape(m, D), gate, K)
+        ii, _ = _moe_route(x.reshape(m, D), gate, Routing(K))
         return len(np.unique(np.asarray(ii)))
 
     assert uniques(x_shared) <= cap, (uniques(x_shared), cap)
@@ -536,10 +537,10 @@ def test_moe_two_tier_dedup_matches_ragged():
 
     for x in (x_shared, x_div):
         base = _moe_ffn_pallas(
-            x, gate, w1, w2, w3, K, mesh=None, interpret=True
+            x, gate, w1, w2, w3, Routing(K), mesh=None, interpret=True
         )
         two = _moe_ffn_pallas(
-            x, gate, w1, w2, w3, K, mesh=None, interpret=True, dedup=True
+            x, gate, w1, w2, w3, Routing(K), mesh=None, interpret=True, dedup=True
         )
         np.testing.assert_allclose(
             np.asarray(two), np.asarray(base), rtol=2e-2, atol=2e-2

@@ -231,6 +231,9 @@ class _StubEngine:
     def reset_kv_pool(self):
         pass
 
+    def kv_publishable(self, n_tokens):
+        return n_tokens
+
 
 @pytest.mark.fast
 def test_publish_pressure_pins_matched_prefix():

@@ -375,6 +375,52 @@ def test_layer_scan_does_not_slice_quantized_stacks(
     assert quantized <= whole, quantized - whole
 
 
+def test_layer_scans_of_two_layer_kinds_do_not_slice_quantized_stacks(tmp_path):
+    """The same over a model whose layers differ (window and full layers
+    over two cache stacks, a leading dense layer and then experts): one
+    scan a run of layers of one FFN kind, the quantized stacks constants of
+    each, the two cache stacks carried whole by both and among neither's
+    `xs` or `ys`."""
+    import jax
+
+    from helpers import make_tiny_afmoe
+
+    from dllama_tpu.models.transformer import _is_quant_stack
+
+    path = str(tmp_path / "m.m")
+    make_tiny_afmoe(path)
+    r = ModelReader(path, max_seq_len=64)
+    params = load_params(r, weight_format="q40", fuse=1)
+    h = r.header
+    stacks = {k: v for k, v in params["layers"].items() if _is_quant_stack(v)}
+    assert {"wqkv", "wo", "dense_w13", "dense_w2", "shared_w13", "shared_w2",
+            "w1", "w2", "w3"} <= set(stacks)
+    quantized = {(a.dtype, a.shape[1:]) for a in jax.tree.leaves(stacks)}
+    cache = init_kv_cache(h, 1, seq_len=64 + 8, ring=40, ring_pad=8)
+    tokens = jnp.asarray([TOKENS[:8]], dtype=jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, c: forward(p, h, tokens, jnp.int32(0), c, kv_ring=40)
+    )(params, cache).jaxpr
+    scans = [e for e in _layer_scans(jaxpr) if e.params["num_carry"] >= 3]
+    assert sorted(e.params["length"] for e in scans) == [1, 4]  # dense, experts
+    per_layer = {(a.dtype, a.shape[1:]) for a in jax.tree.leaves(cache)}
+    for scan in scans:
+        n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+        consts = [v.aval for v in scan.invars[:n_consts]]
+        carry = [v.aval for v in scan.invars[n_consts:n_consts + n_carry]]
+        moved = [v.aval for v in scan.invars[n_consts + n_carry:]] + [
+            v.aval for v in scan.outvars[n_carry:]]
+        assert not [a for a in moved if (a.dtype, a.shape[1:]) in quantized]
+        assert not [a for a in moved if (a.dtype, a.shape[1:]) in per_layer]
+        # the dense layer is a window layer: its scan carries that stack alone
+        names = ("kw", "vw") if scan.params["length"] == 1 else tuple(cache)
+        for name in names:
+            assert (cache[name].dtype, cache[name].shape) in [
+                (a.dtype, a.shape) for a in carry]
+        whole = {(a.dtype, a.shape[1:]) for a in consts if a.ndim >= 3}
+        assert whole & quantized
+
+
 def _filled_cache(h, batch, kv, seq_len, seed=0):
     """A cache whose every row holds something, so a row that moved shows."""
     import jax

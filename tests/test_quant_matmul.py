@@ -94,10 +94,10 @@ def test_moe_active_experts_kernel(m):
     weights = top_p / top_p.sum(axis=-1, keepdims=True)
     out = moe_active_experts(x, w1, w2, w3, top_i, weights, interpret=True)
 
-    from dllama_tpu.models.transformer import _moe_ffn
+    from dllama_tpu.models.transformer import Routing, _moe_ffn
     from dllama_tpu.ops.jnp_ops import silu
 
-    dense = _moe_ffn(x[:, None], gate, w1, w2, w3, K, silu)  # [m, 1, D]
+    dense = _moe_ffn(x[:, None], gate, w1, w2, w3, Routing(K), silu)  # [m, 1, D]
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(dense)[:, 0], rtol=1e-5, atol=1e-5
     )
