@@ -193,7 +193,9 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                         "grouped kernel when concurrent lanes share most "
                         "experts (docs/moe_decode_dedup.md); auto = on at "
                         ">= 8 decode lanes (routing-correlation study, "
-                        "scripts/moe_routing_sim.py)")
+                        "scripts/moe_routing_sim.py). Acts only on a mesh "
+                        "of more than one device: one device reads each "
+                        "distinct expert once whatever this says")
     p.add_argument("--replica-id", default=None, dest="replica_id",
                    metavar="NAME",
                    help="name this server instance as a fleet replica: "

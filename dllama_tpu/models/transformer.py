@@ -619,6 +619,7 @@ def _moe_ffn_pallas(
     dedup: bool = False,
     layer=0,  # which layer of quantized experts' [L, E, ...] stacks
     bias=None,
+    routed=None,  # (top_i, weights) [n, k] where the caller has routed
 ) -> jnp.ndarray:
     """Decode-step MoE via the ragged Pallas kernel (ops/moe_kernel.py):
     each token's top-k expert ids drive the HBM->VMEM DMA schedule, so only
@@ -631,7 +632,7 @@ def _moe_ffn_pallas(
     b, t, d = x.shape
     n = b * t
     xf = x.reshape(n, d)
-    top_i, weights = _moe_route(xf, gate_w, route, bias)  # [n, k]
+    top_i, weights = routed or _moe_route(xf, gate_w, route, bias)  # [n, k]
     quantized = isinstance(w1, QuantWeight)
     # two-tier dedup (opt-in): when concurrent lanes share experts, a
     # small-grid grouped kernel reads each UNIQUE expert's tiles once.
@@ -733,6 +734,7 @@ def _moe_ffn_grouped(
     sync_quant: bool = False,
     layer=0,  # which layer of quantized experts' [L, E, ...] stacks
     bias=None,
+    routed=None,  # (top_i, weights) [n, k] where the caller has routed
 ) -> jnp.ndarray:
     """Prefill MoE via the grouped active-expert kernel
     (ops/moe_kernel.moe_grouped_experts*): assignments sorted by expert,
@@ -753,7 +755,7 @@ def _moe_ffn_grouped(
     quantized = isinstance(w1, QuantWeight)
     # route ONCE, outside any shard_map (same as _moe_ffn_pallas): the
     # gate einsum + top_k would otherwise rerun per tp shard
-    top_i, wts = _moe_route(xf, gate_w, route, bias)
+    top_i, wts = routed or _moe_route(xf, gate_w, route, bias)
 
     def run(xx, ii, ww, *wargs):
         if quantized:
@@ -1010,6 +1012,8 @@ def run_layers(
     hq, hkv = h.n_heads // tp_n, h.n_kv_heads // tp_n
     # mesh tp size: per-shard shape checks (MoE kernel gate)
     _tp_n = mesh.shape.get("tp", 1) if mesh is not None else 1
+    # the layer's weights lie whole on the device that runs this
+    one_device = (mesh is None or mesh.devices.size == 1) and tp_axis is None
 
     table = layer_table(h)
     n_layers = jax.tree.leaves(k_cache)[0].shape[0] + (
@@ -1242,11 +1246,8 @@ def run_layers(
             )
 
     def moe_block(y, lp, lf):
-        """The experts' FFN of a layer whose experts' row is `lf`."""
-        # decode (lane-sized B*T): the ragged Pallas kernel reads only
-        # each token's active experts' weights — Q40 blocks when the
-        # experts are stored quantized. CPU, and shapes the gate
-        # refuses: dense-over-experts.
+        """The experts' FFN of a layer whose experts' row is `lf`, and what
+        the routing counters count of it where they are asked for."""
         from ..ops.moe_kernel import moe_pallas_supported
 
         _w1 = lp["w1"]
@@ -1266,74 +1267,64 @@ def run_layers(
                 h.dim, _f // _tp_n, _quantized, _itemsize
             )
         )
-        if route.shared_out:
-            # this chip holds a share of the experts the router scores:
-            # route over all, compute the pairs that landed here
-            top_i, wts = _moe_route(y.reshape(b * t, -1), lp["moe_gate"], route, bias)
-            # a parked lane's rows are one token's, 512 times over: routed,
-            # they would all land on the same four experts, and a held one
-            # of those would cost the kernel 28 more row tiles a layer for
-            # rows nobody reads. They count as landed elsewhere.
-            held_i = jnp.where(live_rows[:, None], route.held(top_i), route.n_held)
-            counts = None
-            if route_stats is not None:
-                on = held_i < route.n_held
-                touched = jnp.zeros((route.n_held + 1,), bool).at[held_i].set(
-                    True)[: route.n_held]
-                counts = jnp.stack([
-                    jnp.sum(live_rows) * route.n_active, jnp.sum(on),
-                    jnp.sum(touched),
-                ]).astype(jnp.int32)
-            if pallas_ok and _quantized:
-                if mesh is not None and mesh.devices.size > 1:
-                    raise NotImplementedError(
-                        "a share of the experts is computed on one device"
-                    )
-                return moe_held_experts_q40(
-                    y.reshape(b * t, -1), *_expert_stacks(lp["w1"], lp["w2"], lp["w3"]),
-                    held_i, wts, jnp.asarray(lf, jnp.int32),
-                ).reshape(b, t, -1).astype(y.dtype), counts
-            return _moe_ffn(
-                y, lp["moe_gate"],
-                *(layer_of(lp[n], lf) if _quantized else lp[n]
-                  for n in ("w1", "w2", "w3")),
-                route, act,
-                routed=(held_i.reshape(b, t, -1), wts.reshape(b, t, -1)),
-            ), counts
+        # route once over all the experts the router scores, and compute
+        # the pairs that landed on an expert held here (all of them, where
+        # no other chip shares the layer)
+        routed = _moe_route(y.reshape(b * t, -1), lp["moe_gate"], route, bias)
+        top_i, wts = routed
+        # a parked lane's rows are one token's, 512 times over: routed,
+        # they would all land on the same few experts, and a held one of
+        # those would cost the kernel 28 more row tiles a layer for rows
+        # nobody reads. They count as landed elsewhere.
+        held_i = jnp.where(live_rows[:, None], route.held(top_i), route.n_held)
+        counts = None
+        if route_stats is not None:
+            on = held_i < route.n_held
+            touched = jnp.zeros((route.n_held + 1,), bool).at[held_i].set(
+                True)[: route.n_held]
+            counts = jnp.stack([
+                jnp.sum(live_rows) * route.n_active, jnp.sum(on),
+                jnp.sum(touched),
+            ]).astype(jnp.int32)
+        # one device holds the layer: each distinct quantized expert the
+        # live rows touched is read once, in a chunk and a decode block
+        # alike (the kernel's grid stops behind the last of them)
+        if pallas_ok and _quantized and one_device:
+            return moe_held_experts_q40(
+                y.reshape(b * t, -1), *_expert_stacks(lp["w1"], lp["w2"], lp["w3"]),
+                held_i, wts, jnp.asarray(lf, jnp.int32),
+            ).reshape(b, t, -1).astype(y.dtype), counts
         if pallas_ok:
-            # decode-sized token counts take the per-(token, choice)
-            # ragged kernel; prefill-scale takes the grouped kernel
-            # (FLOPs proportional to selected experts, not all E).
-            # Multi-lane decode DEDUP through the grouped kernel was
-            # investigated for r4 and rejected: a Pallas grid is
-            # static, so it must be sized for the all-distinct worst
-            # case (~m*k steps) and Mosaic does not elide the empty
-            # steps' repeated-index DMAs (round-3 chip finding) — the
-            # schedule collapses *compute* per unique expert but not
-            # HBM reads. Analysis + the viable lax.cond two-tier
-            # design: docs/moe_decode_dedup.md.
+            # more than one device, or unquantized experts: the older two
+            # kernels until the held kernel is partitioned
+            # (docs/moe_decode_dedup.md): one expert read a (token,
+            # choice) pair at decode sizes, every row's pairs sorted by
+            # expert over a static grid beyond them
+            if route.shared_out:
+                raise NotImplementedError(
+                    "a share of the experts is computed on one device, "
+                    "from quantized experts"
+                )
             if b * t <= MOE_PALLAS_MAX_TOKENS:
                 return _moe_ffn_pallas(
                     y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
                     route, mesh, sync_quant=sync_quant,
-                    dedup=moe_decode_dedup, layer=lf, bias=bias,
-                ), None
+                    dedup=moe_decode_dedup, layer=lf, bias=bias, routed=routed,
+                ), counts
             return _moe_ffn_grouped(
                 y, lp["moe_gate"], lp["w1"], lp["w2"], lp["w3"],
                 route, mesh, sync_quant=sync_quant, layer=lf, bias=bias,
-            ), None
-        # XLA compiles this and fuses the slice into the dequant
+                routed=routed,
+            ), counts
+        # the CPU, and shapes the gate refuses: dense over the held experts
+        # (XLA compiles this and fuses the slice into the dequant)
         return _moe_ffn(
-            y,
-            lp["moe_gate"],
-            *(
-                layer_of(lp[n], lf) if _quantized else lp[n]
-                for n in ("w1", "w2", "w3")
-            ),
-            route,
-            act,
-            routed=_moe_route(y, lp["moe_gate"], route, bias),
-        ), None
+            y, lp["moe_gate"],
+            *(layer_of(lp[n], lf) if _quantized else lp[n]
+              for n in ("w1", "w2", "w3")),
+            route, act,
+            routed=(held_i.reshape(b, t, -1), wts.reshape(b, t, -1)),
+        ), counts
 
     def make_step(a: int, kinds, stacks):
         """The scan body of layers [a, a + len(kinds)), all of one FFN

@@ -445,9 +445,10 @@ class InferenceEngine:
         )
         self._m_moe_pairs = self.obs.counter(
             "dllama_moe_pairs_total",
-            "Token-expert pairs the router chose in decode blocks, on a "
-            "model that holds a share of its experts: routed = all of live "
-            "lanes, held = those that landed on an expert held here.",
+            "Token-expert pairs the router chose in decode blocks, where "
+            "the experts compute the pairs that landed here (a held share, "
+            "or a whole layer on one device): routed = all of live lanes, "
+            "held = those that landed on an expert held here.",
             labelnames=("landed",),
         )
         self._m_moe_touched = self.obs.counter(
@@ -601,10 +602,13 @@ class InferenceEngine:
                 )
 
         self._fwd = fwd
-        # the lane decode block counts routed pairs where some of the
-        # experts the router scores lie on other chips
-        self._counts_routing = (
-            pp == 1 and self.header.n_experts < self.header.n_routed_experts
+        # the lane decode block counts routed pairs where its experts take
+        # the path that computes the pairs that landed here: some of the
+        # experts the router scores lie on other chips, or one device holds
+        # the whole layer (`models/transformer.py:moe_block`)
+        self._counts_routing = pp == 1 and h.n_experts > 0 and (
+            h.n_experts < h.n_routed_experts
+            or mesh is None or mesh.devices.size == 1
         )
 
     def _pp_micro(self, t: int) -> int:
@@ -2155,8 +2159,8 @@ class InferenceEngine:
             park = self._park
 
             seq_len = self.header.seq_len
-            # a model that holds a share of its experts counts, a step, the
-            # pairs its router chose and those that landed here: three more
+            # a model whose experts compute the pairs that landed here
+            # counts, a step, the pairs its router chose and those: three more
             # columns of the block's one output, so no read-back is added
             counting = self._counts_routing
 

@@ -197,9 +197,12 @@ def test_a_sliced_head_gives_the_references_rows(tmp_path):
     assert np.abs(got - whole[:, :64]).max() < 1e-4
 
 
-def held_kernel_case(n, k, p_held, seed):
+def held_kernel_case(n, k, p_held, seed, e=4, live=None):
+    """Pairs of `n` rows over `e` held experts, each held with probability
+    `p_held` and the sentinel `e` otherwise; rows outside `live` (a slice)
+    carry the sentinel alone, as a parked lane's do."""
     rng = np.random.default_rng(seed)
-    n_layers, e, d, f = 2, 4, 64, 256
+    n_layers, d, f = 2, 64, 256
 
     def stack(i, o):
         q = rng.integers(-8, 8, (n_layers, e, i, o)).astype(np.int8)
@@ -210,30 +213,53 @@ def held_kernel_case(n, k, p_held, seed):
     w1, w3, w2 = stack(d, f), stack(d, f), stack(f, d)
     x = rng.standard_normal((n, d)).astype(np.float32)
     held = rng.random((n, k)) < p_held
+    if live is not None:
+        parked = np.ones(n, bool)
+        parked[live] = False
+        held[parked] = False
     ids = np.where(held, rng.integers(0, e, (n, k)), e).astype(np.int32)
     wts = np.where(held, rng.random((n, k)), 0).astype(np.float32)
     return x, ids, wts, w1, w2, w3
 
 
-@pytest.mark.parametrize("n,k,p_held", [(5, 2, 0.3), (300, 4, 0.125), (3, 2, 0.0), (40, 4, 1.0)],
-                         ids=["decode", "prefill", "none-held", "all-held"])
-def test_held_experts_kernel_computes_the_pairs_that_landed_here(n, k, p_held):
+@pytest.mark.parametrize("n,k,p_held,e,live", [
+    (5, 2, 0.3, 4, None), (300, 4, 0.125, 4, None), (3, 2, 0.0, 4, None),
+    (40, 4, 1.0, 4, None),
+    # what one device that holds a whole layer makes hot: a decode block's
+    # lanes over every expert, ids repeating across rows; an admission chunk
+    # of four lanes of which one is live
+    (16, 8, 1.0, 128, None), (256, 4, 1.0, 16, slice(64, 128)),
+], ids=["decode", "prefill", "none-held", "all-held", "all-held-decode-lanes",
+        "chunk-one-lane-live"])
+def test_held_experts_kernel_computes_the_pairs_that_landed_here(n, k, p_held, e, live):
     """`moe_held_experts_q40` in interpret mode (its grid is as long as the
-    steps that hold a real pair) against the sum written out."""
+    steps that hold a real pair) against the sum written out, and against
+    the dense `_moe_ffn` over the same pairs."""
+    from dllama_tpu.ops.jnp_ops import silu
     from dllama_tpu.ops.moe_kernel import moe_held_experts_q40
 
-    x, ids, wts, w1, w2, w3 = held_kernel_case(n, k, p_held, seed=n)
+    x, ids, wts, w1, w2, w3 = held_kernel_case(n, k, p_held, seed=n, e=e, live=live)
+    if e > 4:
+        assert len(np.unique(ids[ids < e])) < (ids < e).sum()  # experts shared by rows
+    if live is not None:
+        parked = np.ones(n, bool)
+        parked[live] = False
+        assert (ids[parked] == e).all() and (ids[~parked] < e).all()
     layer = 1
     got = np.asarray(moe_held_experts_q40(
         jnp.asarray(x), *(jnp.asarray(a) for w in (w1, w2, w3) for a in w[:2]),
         jnp.asarray(ids), jnp.asarray(wts), jnp.int32(layer), interpret=True))
     xb = np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
     want = np.zeros_like(x)
-    for t, j in zip(*np.nonzero(ids < 4)):
+    for t, j in zip(*np.nonzero(ids < e)):
         h1, h3 = xb[t] @ w1[2][layer, ids[t, j]], xb[t] @ w3[2][layer, ids[t, j]]
         want[t] += wts[t, j] * ((h1 / (1 + np.exp(-h1)) * h3) @ w2[2][layer, ids[t, j]])
     assert np.isfinite(got).all()
     assert np.abs(got - want).max() <= 0.02 * max(np.abs(want).max(), 1e-6)
+    dense = np.asarray(tf._moe_ffn(
+        jnp.asarray(xb)[None], None, *(jnp.asarray(w[2][layer]) for w in (w1, w2, w3)),
+        tf.Routing(k), silu, routed=(jnp.asarray(ids)[None], jnp.asarray(wts)[None])))[0]
+    assert np.abs(got - dense).max() <= 0.02 * max(np.abs(dense).max(), 1e-6)
 
 
 def test_ring_flash_kernel_equals_the_dense_path():

@@ -305,8 +305,59 @@ def mistral_header():
     )
 
 
-@pytest.mark.parametrize("rows,window", [(1, 1024), (512, 2048)],
-                         ids=["decode", "prefill"])
+def qwen_moe_header():
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+
+    return LlmHeader(
+        arch=LlmArch.QWEN3_MOE, dim=2048, hidden_dim=6144,
+        moe_hidden_dim=768, n_layers=12, n_heads=32, n_kv_heads=4,
+        n_experts=128, n_active_experts=8, vocab_size=151936,
+        seq_len=4096, head_dim=HD, rope_type=RopeType.FALCON,
+        norm_epsilon=1e-6,
+    )
+
+
+_SCAN_TEXTS: dict = {}  # a lane program is compiled once for the tests that read it
+
+
+def scan_text_of(model, rows, window, s, monkeypatch) -> str:
+    # the program asks the backend which branches to take; no chip here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if (model, rows, window) not in _SCAN_TEXTS:
+        header, cache, layers = (
+            (mistral_header(), MISTRAL_CACHE, mistral_layers(32, s))
+            if model == "mistral"
+            else (qwen_moe_header(), QWEN_CACHE, qwen_moe_layers(12, s))
+        )
+        _SCAN_TEXTS[model, rows, window] = layer_scan_text(
+            header, layers, cache, rows, window, s)
+    return _SCAN_TEXTS[model, rows, window]
+
+
+LANE_PROGRAMS = pytest.mark.parametrize(
+    "rows,window", [(1, 1024), (512, 2048)], ids=["decode", "prefill"])
+
+
+@LANE_PROGRAMS
+def test_one_chip_reads_each_distinct_expert_once(one_chip, monkeypatch, rows, window):
+    """Qwen3-30B-A3B's decode step and 512-row chunk over 16 lanes on one
+    chip: every expert layer is `moe_held_experts_q40`, whose grid stops
+    behind the distinct experts the live rows touched; the kernel that reads
+    an expert a (token, choice) pair and the one over a static grid of every
+    row's pairs are for a mesh of more than one device
+    (`test_moe_q40_expert_stacks_tp4`)."""
+    text = scan_text_of("qwen3moe", rows, window, one_chip, monkeypatch)
+    # the custom calls by the jitted function that made them (the text also
+    # holds a table of every Python frame its traces passed through)
+    kernels = {
+        line.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+        for line in text.splitlines() if "tpu_custom_call" in line and " = " in line
+    }
+    assert "moe_held_experts_q40" in kernels, kernels
+    assert not {"moe_active_experts_q40", "moe_grouped_experts_q40"} & kernels, kernels
+
+
+@LANE_PROGRAMS
 @pytest.mark.parametrize("model", ["mistral", "qwen3moe"])
 def test_layer_scan_writes_cache_rows_in_place(
     one_chip, monkeypatch, model, rows, window
@@ -318,23 +369,8 @@ def test_layer_scan_writes_cache_rows_in_place(
     attention slices its window inside the dot's fusion and the flash kernel
     takes the stack and a layer number. So nothing in the compiled loop has
     one layer's whole cache as its result, and the stack is never copied."""
-    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
-
-    # the program asks the backend which branches to take; no chip here
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if model == "mistral":
-        header, cache = mistral_header(), MISTRAL_CACHE
-        layers = mistral_layers(32, one_chip)
-    else:
-        header, cache = LlmHeader(
-            arch=LlmArch.QWEN3_MOE, dim=2048, hidden_dim=6144,
-            moe_hidden_dim=768, n_layers=12, n_heads=32, n_kv_heads=4,
-            n_experts=128, n_active_experts=8, vocab_size=151936,
-            seq_len=4096, head_dim=HD, rope_type=RopeType.FALCON,
-            norm_epsilon=1e-6,
-        ), QWEN_CACHE
-        layers = qwen_moe_layers(12, one_chip)
-    text = layer_scan_text(header, layers, cache, rows, window, one_chip)
+    cache = MISTRAL_CACHE if model == "mistral" else QWEN_CACHE
+    text = scan_text_of(model, rows, window, one_chip, monkeypatch)
     assert text.count("dynamic-update-slice(") >= 2  # K and V rows
     assert not cache_copies(text, cache), cache_copies(text, cache)
 

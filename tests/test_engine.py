@@ -380,6 +380,38 @@ def test_engine_moe_lanes_unequal_prompts(tmp_path):
     assert outs == singles, (outs, singles)
 
 
+def test_moe_lane_block_counts_routed_pairs_on_one_device(tmp_path):
+    """Qwen3-MoE on one device takes the path that computes the pairs that
+    landed here, with every expert held: a decode block records `moe_route`
+    (all routed pairs land, the touched experts are the distinct ones of a
+    layer and step) and moves both counters, in the block's one output."""
+    path = str(tmp_path / "moe.m")
+    make_tiny_model(path, arch=LlmArch.QWEN3_MOE, weight_type=FloatType.Q40)
+    e = InferenceEngine(path, tp=1, dtype=jnp.float32, temperature=0.0, batch_size=4)
+    e.prefill_lane(0, [1, 2, 3, 4, 5, 6])
+    e.prefill_lane(2, [9, 8, 7])
+
+    def counters():
+        return [e._m_moe_pairs.labels(landed="routed").value,
+                e._m_moe_pairs.labels(landed="held").value, e._m_moe_touched.value]
+
+    before = counters()
+    n_steps, n_live, n_expert_layers = 4, 2, e.header.n_layers
+    n0 = len(e.recorder.events("moe_route"))
+    out = e.decode_lanes([6, 0, 7, 0], [5, 0, 2, 0], n_steps,
+                         active=[True, False, True, False])
+    assert np.asarray(out).shape == (n_steps, 4)
+    (event,) = e.recorder.events("moe_route")[n0:]
+    assert event["n_steps"] == n_steps
+    assert event["pairs_routed"] == (
+        n_steps * n_live * e.header.n_active_experts * n_expert_layers)
+    assert event["pairs_held"] == event["pairs_routed"]
+    assert 0 < event["held_touched"] <= min(
+        event["pairs_held"], e.header.n_experts * n_expert_layers * n_steps)
+    assert [a - b for a, b in zip(counters(), before)] == [
+        event["pairs_routed"], event["pairs_held"], event["held_touched"]]
+
+
 def test_prefill_lane_preserves_other_lanes(tiny_model):
     """Prefilling a new request into a free lane must not disturb a lane
     mid-conversation: decode lane 0, prefill lane 1, keep decoding lane 0

@@ -39,6 +39,41 @@ def test_forward_matches_numpy_oracle(tmp_path, arch):
     )
 
 
+def test_moe_lanes_with_one_parked_match_numpy_oracle(tmp_path):
+    """Qwen3-MoE, four lanes at unequal positions decode a step together,
+    lane 2 parked: its rows are routed nowhere (`run_layers`' `live_rows`),
+    and every live lane's logits are still the oracle's for its sequence,
+    which is what the forward pass gave when parked rows were routed too."""
+    import jax
+
+    h, params, tensors = build(tmp_path, arch=LlmArch.QWEN3_MOE)
+    lanes, park, chunk = 4, h.seq_len, 8
+    rng = np.random.default_rng(7)
+    lengths = [5, 17, 3, 30]
+    seqs = [[int(t) for t in rng.integers(0, h.vocab_size, n + 1)] for n in lengths]
+    cache = init_kv_cache(h, lanes, seq_len=h.seq_len + chunk)
+    step = jax.jit(lambda toks, pos, cache: forward(
+        params, h, toks, pos, cache, attn_park_threshold=park))
+    # fill lane by lane, as the engine's lane prefill does: the others parked
+    for lane, ids in enumerate(seqs):
+        for p in range(0, lengths[lane], chunk):
+            width = min(chunk, lengths[lane] - p)
+            toks = np.zeros((lanes, width), np.int32)
+            toks[lane] = ids[p:p + width]
+            pos = np.full(lanes, park, np.int32)
+            pos[lane] = p
+            _, cache = step(jnp.asarray(toks), jnp.asarray(pos), cache)
+    pos = np.asarray(lengths, np.int32)
+    pos[2] = park
+    toks = np.asarray([[ids[-1]] for ids in seqs], np.int32)
+    logits, _ = step(jnp.asarray(toks), jnp.asarray(pos), cache)
+    for lane, ids in enumerate(seqs):
+        if lane == 2:
+            continue
+        want = numpy_forward(tensors, h, ids)[-1]
+        assert np.abs(np.asarray(logits[lane, 0]) - want).max() < 2e-4 * want.std(), lane
+
+
 def test_forward_llama31_rope_scaling(tmp_path):
     h, params, tensors = build(tmp_path, rope_scaling=True)
     assert h.rope_scaling_factor == 8.0
