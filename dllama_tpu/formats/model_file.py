@@ -51,6 +51,10 @@ class LlmArch(enum.IntEnum):
     # norms, a sigmoid router with a shared expert (`model_type: afmoe`);
     # not in the reference, whose enum ends above
     AFMOE = 0xABCD10
+    # latent attention (MLA) over one cache stack of `[c | k_rope]` rows,
+    # sandwich norms, a sigmoid router with a shared expert after leading
+    # dense layers (`model_type: pangu_ultra_moe`)
+    PANGU_MOE = 0xABCD11
 
 
 class RopeType(enum.IntEnum):
@@ -106,6 +110,12 @@ class HeaderKey(enum.IntEnum):
     N_ROUTED_EXPERTS = 30  # the router's width, where N_EXPERTS are held here
     FIRST_EXPERT = 31  # id of the first expert held here
     EMBED_SCALE = 32  # 1: embeddings are multiplied by sqrt(DIM)
+    # latent attention (0: none, what every model above means)
+    Q_LORA_RANK = 33  # width of the query's latent
+    KV_LORA_RANK = 34  # width of the cached latent `c`; > 0 makes every layer latent
+    QK_NOPE_HEAD_DIM = 35  # a head's query and key columns that take no rope
+    QK_ROPE_HEAD_DIM = 36  # a head's query columns, and the one shared key's, that do
+    V_HEAD_DIM = 37  # a head's value width
 
 
 @dataclasses.dataclass
@@ -146,6 +156,11 @@ class LlmHeader:
     n_routed_experts: int = 0
     first_expert: int = 0
     embed_scale: bool = False
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     header_bytes: int = 0
     file_size: int = 0
     sync_type: FloatType = FloatType.Q80
@@ -159,9 +174,24 @@ class LlmHeader:
         return self.head_dim * self.n_kv_heads
 
     @property
+    def latent(self) -> bool:
+        """Attention over a cache of latent rows (MLA)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Width of a latent cache row: `[c | k_rope]`."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """Columns of a head that the rotary embedding turns."""
+        return self.qk_rope_head_dim if self.latent else self.head_dim
+
+    @property
     def ff_dim(self) -> int:
         """Per-expert (MoE) or dense FFN intermediate dim (src/llm.cpp:152-157)."""
-        if self.arch in (LlmArch.QWEN3_MOE, LlmArch.AFMOE):
+        if self.arch in (LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE):
             return self.moe_hidden_dim
         return self.hidden_dim
 
@@ -262,6 +292,16 @@ def read_llm_header(
                 h.first_expert = value
             elif key == HeaderKey.EMBED_SCALE:
                 h.embed_scale = bool(value)
+            elif key == HeaderKey.Q_LORA_RANK:
+                h.q_lora_rank = value
+            elif key == HeaderKey.KV_LORA_RANK:
+                h.kv_lora_rank = value
+            elif key == HeaderKey.QK_NOPE_HEAD_DIM:
+                h.qk_nope_head_dim = value
+            elif key == HeaderKey.QK_ROPE_HEAD_DIM:
+                h.qk_rope_head_dim = value
+            elif key == HeaderKey.V_HEAD_DIM:
+                h.v_head_dim = value
 
         if weight_type is None:
             raise ValueError("model does not specify weight type")
@@ -273,10 +313,19 @@ def read_llm_header(
     h.orig_seq_len = h.seq_len
     if max_seq_len > 0 and h.seq_len > max_seq_len:
         h.seq_len = max_seq_len
+    if h.latent:
+        if not (h.q_lora_rank and h.qk_nope_head_dim and h.qk_rope_head_dim
+                and h.v_head_dim):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
+            )
+        # a query head is its nope and its rope columns
+        h.head_dim = h.qk_nope_head_dim + h.qk_rope_head_dim
     if h.head_dim == 0:
         h.head_dim = h.dim // h.n_heads
     h.sync_type = sync_type
-    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE):
+    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE):
         h.rope_type = RopeType.FALCON
     if h.n_routed_experts == 0:
         h.n_routed_experts = h.n_experts
@@ -291,32 +340,40 @@ def read_llm_header(
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """One row of the layer table: what a layer is, as data. `row` is the
-    layer's place in its cache stack (the full layers' or the window
-    layers'), `ffn_row` its place among the layers of its FFN kind, whose
-    weights are stacked apart."""
+    layer's place in its cache stack (the full layers', the window layers'
+    or the latent layers'), `ffn_row` its place among the layers of its FFN
+    kind, whose weights are stacked apart."""
 
     window: bool  # attention: over the last `sliding_window` rows, or in full
-    rope: bool
+    rope: bool  # the layer's whole heads take the rotary embedding
     experts: bool  # FFN: a mixture of experts, or dense
     row: int
     ffn_row: int
+    latent: bool = False  # the cache row is `[c | k_rope]`, one head for all
+
+    @property
+    def cache(self) -> str:
+        """The kind of cache the layer's rows live in."""
+        return "latent" if self.latent else "window" if self.window else "full"
 
 
 def layer_table(h: LlmHeader) -> tuple[LayerKind, ...]:
     """The kinds of the model's layers, read once from the header. The
     reference's architectures are its uniform rows: every layer full, with
     rope, and the same FFN."""
-    table, rows, ffn_rows = [], [0, 0], [0, 0]
+    table, rows, ffn_rows = [], {"full": 0, "window": 0, "latent": 0}, [0, 0]
     for l in range(h.n_layers):
-        window = h.sliding_window > 0 and not (
+        window = not h.latent and h.sliding_window > 0 and not (
             h.full_attn_period and (l + 1) % h.full_attn_period == 0
         )
         experts = h.n_experts > 0 and l >= h.n_dense_layers
+        cache = "latent" if h.latent else "window" if window else "full"
         table.append(LayerKind(
-            window, window or not h.full_attn_no_rope, experts,
-            rows[window], ffn_rows[experts],
+            # a latent layer turns the rope columns of its heads itself
+            window, not h.latent and (window or not h.full_attn_no_rope), experts,
+            rows[cache], ffn_rows[experts], h.latent,
         ))
-        rows[window] += 1
+        rows[cache] += 1
         ffn_rows[experts] += 1
     return tuple(table)
 
@@ -360,6 +417,8 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
         offset += nbytes
 
     afmoe = h.arch == LlmArch.AFMOE
+    # sandwich norms, and leading dense layers HIDDEN_DIM wide beside experts
+    sandwich = afmoe or h.arch == LlmArch.PANGU_MOE
 
     def swiglu(prefix: str, width: int) -> None:
         add(f"{prefix}.w1", wt, (width, h.dim))
@@ -368,10 +427,23 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
 
     add("embed", FloatType.F32, (h.vocab_size, h.dim))
     for l, kind in enumerate(layer_table(h)):
-        add(f"layers.{l}.q", wt, (h.q_dim, h.dim))
-        add(f"layers.{l}.k", wt, (h.kv_dim, h.dim))
-        add(f"layers.{l}.v", wt, (h.kv_dim, h.dim))
-        add(f"layers.{l}.wo", wt, (h.dim, h.q_dim))
+        if kind.latent:
+            # queries through a latent of q_lora_rank, keys and values
+            # through the cached one: `wkv_a` gives `[c | k_rope]`, `wkv_b`
+            # a head's `[k_nope | v]` from `c`
+            per_head = h.qk_nope_head_dim + h.v_head_dim
+            add(f"layers.{l}.wq_a", wt, (h.q_lora_rank, h.dim))
+            add(f"layers.{l}.q_a_norm", FloatType.F32, (h.q_lora_rank,))
+            add(f"layers.{l}.wq_b", wt, (h.q_dim, h.q_lora_rank))
+            add(f"layers.{l}.wkv_a", wt, (h.latent_row, h.dim))
+            add(f"layers.{l}.kv_a_norm", FloatType.F32, (h.kv_lora_rank,))
+            add(f"layers.{l}.wkv_b", wt, (h.n_heads * per_head, h.kv_lora_rank))
+            add(f"layers.{l}.wo", wt, (h.dim, h.n_heads * h.v_head_dim))
+        else:
+            add(f"layers.{l}.q", wt, (h.q_dim, h.dim))
+            add(f"layers.{l}.k", wt, (h.kv_dim, h.dim))
+            add(f"layers.{l}.v", wt, (h.kv_dim, h.dim))
+            add(f"layers.{l}.wo", wt, (h.dim, h.q_dim))
         if afmoe:  # the gate on the attention output, one value a channel
             add(f"layers.{l}.att_gate", wt, (h.q_dim, h.dim))
         if kind.experts:
@@ -383,15 +455,15 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
             for e in range(h.n_experts):
                 swiglu(f"layers.{l}.experts.{e}", h.ff_dim)
         else:  # leading dense layers are HIDDEN_DIM wide beside experts of ff_dim
-            swiglu(f"layers.{l}", h.hidden_dim if afmoe else h.ff_dim)
+            swiglu(f"layers.{l}", h.hidden_dim if sandwich else h.ff_dim)
         if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE):
             add(f"layers.{l}.q_norm", FloatType.F32, (h.head_dim,))
             add(f"layers.{l}.k_norm", FloatType.F32, (h.head_dim,))
         add(f"layers.{l}.att_norm", FloatType.F32, (h.dim,))
-        if afmoe:  # sandwich norms: one more after each block
+        if sandwich:  # one more norm after each block
             add(f"layers.{l}.post_att_norm", FloatType.F32, (h.dim,))
         add(f"layers.{l}.ffn_norm", FloatType.F32, (h.dim,))
-        if afmoe:
+        if sandwich:
             add(f"layers.{l}.post_ffn_norm", FloatType.F32, (h.dim,))
     add("final_norm", FloatType.F32, (h.dim,))
     add("wcls", wt, (h.vocab_size, h.dim))

@@ -50,6 +50,11 @@ HEADER_KEYS = {
     "n_routed_experts": 30,
     "first_expert": 31,
     "embed_scale": 32,
+    "q_lora_rank": 33,
+    "kv_lora_rank": 34,
+    "qk_nope_head_dim": 35,
+    "qk_rope_head_dim": 36,
+    "v_head_dim": 37,
 }
 
 

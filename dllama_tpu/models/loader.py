@@ -401,7 +401,35 @@ def load_params(
 
     has_gate = "layers.0.att_gate" in reader.by_name
     qkv = ["q", "k", "v"] + (["att_gate"] if has_gate else [])
-    if quantize and fuse:
+    if h.latent:
+        # latent attention: the projections as they are in the file, but
+        # `wkv_b`. The absorbed path contracts it over its OUTPUT side (a
+        # query's nope columns against a head's `U_h`), which a Q40 block
+        # along the input side cannot serve, so it is dequantised once
+        # into two per-head stacks in the activation dtype: `wkv_b_k`
+        # [L, H, nope, kv_lora] and `wkv_b_v` [L, H, kv_lora, v].
+        for n in ("wq_a", "wq_b", "wkv_a", "wo"):
+            if quantize:
+                layers[n] = qw(n, lambda l, n=n: f"layers.{l}.{n}")
+            else:
+                layers[n] = put(
+                    n, stack(lambda l, n=n: w(f"layers.{l}.{n}")).astype(dtype)
+                )
+        for n in ("q_a_norm", "kv_a_norm"):
+            layers[n] = put(n, stack(lambda l, n=n: w(f"layers.{l}.{n}", False)))
+        nope = h.qk_nope_head_dim
+
+        def per_head(l: int) -> np.ndarray:  # [H, nope + v, kv_lora]
+            return w(f"layers.{l}.wkv_b", False).reshape(
+                h.n_heads, nope + h.v_head_dim, h.kv_lora_rank
+            )
+
+        layers["wkv_b_k"] = put(
+            "wkv_b_k", stack(lambda l: per_head(l)[:, :nope]).astype(dtype)
+        )
+        layers["wkv_b_v"] = put("wkv_b_v", stack(
+            lambda l: per_head(l)[:, nope:].transpose(0, 2, 1)).astype(dtype))
+    elif quantize and fuse:
         # the gate on the attention output reads the same input: it rides
         # the fused launch as a fourth constituent
         layers["wqkv"] = qw_fused(

@@ -48,7 +48,11 @@ from ..ops.quant_matmul import (
 # QuantWeight under every quantized format, so the expert kernels below
 # test for that class alone
 _QUANT_CLASSES = (QuantWeight, PackedQuantWeight)
-from ..ops.flash_attention import flash_attention, pick_flash_blocks
+from ..ops.flash_attention import (
+    flash_attention,
+    latent_flash_attention,
+    pick_flash_blocks,
+)
 # QuantKV lives in ops/kv_cache so the flash kernels consume it natively
 # (no models<->ops cycle); re-exported here for engine/cli/pipeline use.
 from ..ops.kv_cache import (
@@ -157,8 +161,15 @@ def init_kv_cache(
     never wraps) between `ring_pad` spare rows before it and as many
     behind, which `run_layers` needs where chunks wrap, lanes park or a
     scan holds layers of both kinds. Each layer's `row` in the table is
-    its place in its stack."""
+    its place in its stack. A model with latent attention gets one stack
+    `c` of `[L, B, 1, S, kv_lora_rank + qk_rope_head_dim]` and no other."""
     s = seq_len or h.seq_len
+    if h.latent:
+        # one stack of `[c | k_rope]` rows, one head for every query head:
+        # no per-head keys or values are ever stored
+        if dtype == jnp.int8:
+            raise NotImplementedError("latent cache rows are not quantized: int8 KV")
+        return {"c": jnp.zeros((h.n_layers, batch_size, 1, s, h.latent_row), dtype)}
     n_window = sum(kind.window for kind in layer_table(h))
     shape = (h.n_layers - n_window, batch_size, h.n_kv_heads, s, h.head_dim)
     if dtype == jnp.int8:
@@ -312,6 +323,76 @@ def _attention_window(
             layer_rows(v_cache, layer, rows, row0), pos, ring=ring, window=window,
         )
     return out.reshape(b, t, n_heads * head_dim)
+
+
+def latent_attention_dense(
+    q: jnp.ndarray,  # [B, T, H, W]: absorbed queries `[q_nope U_h^T | q_rope]`
+    rows: jnp.ndarray,  # [B, 1, S, W]: cached `[c | k_rope]`, positions in order
+    pos,  # scalar or [B]: position of q[:, 0]; negative = a parked lane
+    kv_rank: int,  # the rows' first `kv_rank` columns are the values
+    scale: float,
+) -> jnp.ndarray:
+    """Absorbed latent attention in plain XLA, [B, T, H, kv_rank]: every
+    query head against the one cached head, the weighted sum over the rows'
+    first `kv_rank` columns (a head's `V_h` is applied by the caller, once a
+    query). f32 throughout, as `attention_stats` is: XLA fuses the cache's
+    widening into the two products, which at the chip's default precision
+    are one bf16 pass each, so a bf16 cache is read as bf16: per row
+    2 x H x (W + kv_rank) FLOP over W x 2 bytes, the chip's ridge. A lane
+    whose position is negative sees nothing and gives zeros."""
+    b, t = q.shape[0], q.shape[1]
+    ck = rows[:, 0].astype(jnp.float32)  # [B, S, W]
+    scores = jnp.einsum("bthw,bsw->bhts", q.astype(jnp.float32), ck) * scale
+    q_pos = jnp.atleast_1d(jnp.asarray(pos, jnp.int32))[:, None] + jnp.arange(
+        t, dtype=jnp.int32)[None, :]  # [1 or B, T]
+    seen = jnp.arange(ck.shape[1], dtype=jnp.int32)[None, None, :] <= q_pos[:, :, None]
+    scores = jnp.where(seen[:, None], scores, _NEG_INF)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    p = jnp.where(m <= _NEG_INF / 2, 0.0, jnp.exp(scores - m))
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    p = p / jnp.where(l == 0.0, 1.0, l)
+    return jnp.einsum("bhts,bsc->bthc", p, ck[..., :kv_rank]).astype(q.dtype)
+
+
+def _attention_latent(
+    q: jnp.ndarray,  # [B, T, H, W] absorbed queries
+    c_cache: jnp.ndarray,  # [L, B, 1, S, W]: the latent layers' stack
+    layer: jnp.ndarray,
+    pos: jnp.ndarray,
+    kv_rank: int,
+    scale: float,
+    attn_window: int = 0,
+) -> jnp.ndarray:
+    """Absorbed attention over the window's rows of layer `layer` of the
+    latent stack, after the chunk's rows are written: the Pallas kernel
+    for a chunk on the chip (`latent_flash_attention`: the H heads of a
+    position are H query rows against the one cached head, a parked
+    lane's blocks skipped), XLA's dense products for a decode step and on
+    the CPU (`_attention_tp` says why decode takes no kernel). Both read
+    the stack where it lies; [B, T, H, kv_rank]."""
+    t, n_heads = q.shape[1], q.shape[2]
+    s = c_cache.shape[3]
+    rows = attn_window if 0 < attn_window < s else s
+    if t >= 8 and _use_flash(t * n_heads, rows):
+        return latent_flash_attention(
+            q, c_cache, pos, layer=layer, rows=rows, kv_rank=kv_rank, scale=scale
+        )
+    # a lane at a time, as `write_rows` writes: with the lanes as a batch
+    # axis of one product the chip's compiler keeps the stack in a layout of
+    # its own (the lanes between the rows and the columns) and copies it
+    # whole twice a step (described v5e, bf16[5,4,1,16896,576]); and each
+    # lane's rows by one `dynamic_slice` of the stack itself: a static slice
+    # of the lane first made it four stacks of one lane, a copy of the whole
+    b, w = q.shape[0], q.shape[3]
+    lane_pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    return jnp.concatenate([
+        latent_attention_dense(
+            q[lane : lane + 1],
+            lax.dynamic_slice(c_cache, (layer, lane, 0, 0, 0), (1, 1, 1, rows, w))[0],
+            lane_pos[lane], kv_rank, scale,
+        )
+        for lane in range(b)
+    ])
 
 
 def _attention_sp_merge(
@@ -851,7 +932,8 @@ def forward(
     # `pos` may be a [B] vector: each batch lane decodes at its own
     # position (independent request lanes — the continuous-batching
     # surface the reference's single-stream loop lacks)
-    attn_pos = attn_positions(pos, attn_park_threshold, cache["k"].shape[3])
+    names = [n for n in ("k", "v", "kw", "vw", "c") if n in cache]
+    attn_pos = attn_positions(pos, attn_park_threshold, cache[names[0]].shape[3])
 
     x = params["embed"][tokens]  # [B, T, D] (reference: OP_EMBEDDING)
     if h.embed_scale:
@@ -859,14 +941,14 @@ def forward(
 
     cos, sin = rope_slices(params, pos, t)
     x, *caches = run_layers(
-        x, params["layers"], cache["k"], cache["v"], h, pos, attn_pos,
+        x, params["layers"], cache.get("k"), cache.get("v"), h, pos, attn_pos,
         cos, sin, mesh=mesh, attn_window=attn_window,
         sync_quant=sync_quant, moe_decode_dedup=moe_decode_dedup,
         kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
-        route_stats=route_stats,
+        route_stats=route_stats, c_cache=cache.get("c"),
     )
     logits = logits_head(x, params, h, mesh, logits_mode)
-    return logits, dict(zip(("k", "v", "kw", "vw"), caches))
+    return logits, dict(zip(names, caches))
 
 
 def attn_positions(pos, attn_park_threshold: int, cache_len: int):
@@ -947,9 +1029,12 @@ def run_layers(
     vw_cache: jnp.ndarray | None = None,
     kv_ring: int = 0,
     route_stats: list | None = None,
+    c_cache: jnp.ndarray | None = None,  # [L, B, 1, S, W]: latent layers, alone
 ):
     """`lax.scan` the decoder layers over x; returns (x, k_new, v_new), and
-    the window layers' (kw_new, vw_new) behind them where the model has such.
+    the window layers' (kw_new, vw_new) behind them where the model has such;
+    for a model with latent attention (x, c_new): the carry holds the stacks
+    the layer table asks for and no other.
 
     What a layer is comes from the header's layer table (`layer_table`):
     attention in full or over a window, rope or none, a dense FFN or
@@ -997,6 +1082,18 @@ def run_layers(
     per_lane = jnp.ndim(pos) == 1
     if (tp_axis is not None or sp_axis is not None) and mesh is not None:
         raise ValueError("manual tp/sp (tp_axis/sp_axis) requires mesh=None")
+    latent = c_cache is not None
+    if latent:
+        if k_cache is not None or kw_cache is not None:
+            raise ValueError("a latent cache stands alone: no k, v, kw or vw")
+        if not ((mesh is None or mesh.devices.size == 1)
+                and tp_axis is None and sp_axis is None):
+            raise NotImplementedError(
+                "latent attention layers run on one device: tp, sp, dp, pp > 1"
+            )
+        k_cache = c_cache  # the stack whose shape the lines below read
+    if latent != layer_table(h)[0].latent:
+        raise ValueError("the layer table and the cache stacks disagree on latent rows")
     shard_s = k_cache.shape[3]  # local (per-sp-shard) sequence length
     # manual sp: the per-shard write window is t//sp_n (+1 for unaligned
     # chunk starts) local rows, capped at the whole local shard — a
@@ -1020,8 +1117,8 @@ def run_layers(
         0 if kw_cache is None else kw_cache.shape[0]
     )
     alike = all(
-        (kind.window, kind.rope, kind.experts) == (
-            table[0].window, table[0].rope, table[0].experts)
+        (kind.cache, kind.rope, kind.experts) == (
+            table[0].cache, table[0].rope, table[0].experts)
         for kind in table
     )
     if alike:
@@ -1189,6 +1286,11 @@ def run_layers(
         write of the chunk's rows costs less than a conditional that
         hands a stack through, which XLA copies whole (1.1 GB a layer at
         8 lanes of 16k; the described v5e's compiler)."""
+        if latent:
+            # one row of `[c | k_rope]` (`k`; there are no values) into the
+            # one stack, a parked lane's past the context as any row's
+            with jax.named_scope("kv_write"):
+                return (_cache_append(caches[0], row[0], k),)
         k_cache, v_cache, *ring_caches = caches
         mixed = not isinstance(is_window, bool)
         with jax.named_scope("kv_write"):
@@ -1244,6 +1346,33 @@ def run_layers(
                 q, caches[2], caches[3], row[1], attn_pos, h.head_dim, ring,
                 window, attn_window=attn_window, row0=ring_pad,
             )
+
+    def attend_latent(q, caches, row):
+        """Absorbed attention over the latent stack: [B, T, H, kv_lora]."""
+        with jax.named_scope("attn"), jax.named_scope(f"latent_{phase}"):
+            return _attention_latent(
+                q, caches[0], row[0], attn_pos, h.kv_lora_rank,
+                float(h.head_dim) ** -0.5, attn_window=attn_window,
+            )
+
+    def latent_queries_and_row(y, lp, mm):
+        """The five projections' first three and both norms of a latent
+        layer: (absorbed queries [B, T, H, W], the cache row [B, T, 1, W]).
+        A query is `[q_nope U_h^T | rope(q_rope)]`, so that against the
+        cached `[c | rope(k_rope)]` it scores what `q_nope . k_nope +
+        q_rope . k_rope` scores, with no key rebuilt."""
+        nope, kvl = h.qk_nope_head_dim, h.kv_lora_rank
+        cq = rms_norm(mm(y, lp["wq_a"], "row"), lp["q_a_norm"], h.norm_epsilon)
+        q = mm(cq, lp["wq_b"], "row").reshape(b, t, hq, h.head_dim)
+        ckv = mm(y, lp["wkv_a"], "row")
+        c = rms_norm(ckv[..., :kvl], lp["kv_a_norm"], h.norm_epsilon)
+        kr = apply_rope(ckv[..., None, kvl:], cos, sin, interleaved)
+        q_rope = apply_rope(q[..., nope:], cos, sin, interleaved)
+        q_abs = jnp.einsum("bthn,hnc->bthc", q[..., :nope], lp["wkv_b_k"])
+        return (
+            jnp.concatenate([q_abs, q_rope], axis=-1),
+            jnp.concatenate([c[..., None, :], kr], axis=-1),
+        )
 
     def moe_block(y, lp, lf):
         """The experts' FFN of a layer whose experts' row is `lf`, and what
@@ -1373,7 +1502,12 @@ def run_layers(
                 y = rms_norm(x, lp["att_norm"], h.norm_epsilon)
             with jax.named_scope("attn"):
                 gate = None
-                if "wqkv" in lp:
+                if kinds[0].latent:
+                    # the cache row stands where the keys do; there are no values
+                    with jax.named_scope("latent_proj"):
+                        q, k = latent_queries_and_row(y, lp, mm)
+                    v = None
+                elif "wqkv" in lp:
                     # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
                     # per decode layer at ~41 us fixed cost each on the round-3
                     # chip run). The un-interleave factor is the
@@ -1415,7 +1549,13 @@ def run_layers(
                     k = apply_rope(k, cos, sin, interleaved)
 
             caches = write_kv(caches, k, v, row, is_window)
-            if not isinstance(is_window, bool):
+            if kinds[0].latent:
+                z = attend_latent(q, caches, row)
+                with jax.named_scope("attn"), jax.named_scope("latent_proj"):
+                    # a head's values from the weighted latents, once a query
+                    z = jnp.einsum("bthc,hcv->bthv", z, lp["wkv_b_v"]).reshape(
+                        b, t, hq * h.v_head_dim)
+            elif not isinstance(is_window, bool):
                 # the stacks go in and only the attention's output comes out
                 z = lax.cond(is_window, attend_window, attend_full, q, caches, row)
             elif is_window:
@@ -1467,7 +1607,7 @@ def run_layers(
     # which are the norms, a dense model's weights and the layer number.
     # The caches are the scan's carry: as `xs` and `ys` every layer's whole
     # lane cache was copied out of the stack and back to write a row a lane
-    caches = (k_cache, v_cache) if kw_cache is None else (
+    caches = (c_cache,) if latent else (k_cache, v_cache) if kw_cache is None else (
         k_cache, v_cache, kw_cache, vw_cache)
     counted = []
     for a, e in segments:

@@ -43,12 +43,12 @@ from .kv_cache import QuantKV, layer_rows
 _NEG_INF = -1e30
 
 
-def pick_flash_blocks(t: int, s: int) -> tuple[int, int] | None:
+def pick_flash_blocks(t: int, s: int, max_t: int = 256) -> tuple[int, int] | None:
     """(block_t, block_s) that divide the shapes, or None when the flash
     kernel can't run them (callers then fall back to dense attention).
-    block_t: largest multiple of 8 <= 256 dividing t; block_s: largest
+    block_t: largest multiple of 8 <= `max_t` dividing t; block_s: largest
     multiple of 128 <= 512 dividing s."""
-    bt = next((b for b in range(min(256, t), 0, -8) if t % b == 0), None)
+    bt = next((b for b in range(min(max_t, t), 0, -8) if t % b == 0), None)
     bs = next((b for b in range(min(512, s - s % 128), 0, -128) if s % b == 0), None)
     if not bt or not bs:
         return None
@@ -790,6 +790,166 @@ def paged_flash_decode(
         interpret=interpret,
     )(pos_arr, pt, *operands)
     return out.reshape(b, kh, g, hd).reshape(b, 1, h, hd).astype(q.dtype)
+
+
+def _latent_flash_kernel(
+    pos_ref,  # SMEM scalar prefetch: [B] int32 per-lane q start positions
+    l_ref,  # SMEM scalar prefetch: [1] int32 layer, read by the index maps
+    q_ref,  # [1, bq, W]: bq query rows, row r is head r % H of position r // H
+    c_ref,  # [1, 1, W, bs]: cached `[c | k_rope]` rows of that layer and lane, turned
+    out_ref,  # [1, bq, kv_rank]
+    m_ref, l_acc, acc_ref,  # scratch: running max, denominator, weighted sum
+    *,
+    block_q: int,
+    block_s: int,
+    n_s: int,
+    n_heads: int,
+    kv_rank: int,
+    scale: float,
+):
+    """Absorbed latent attention, one block of query rows against one block
+    of cached rows, online softmax across the row blocks (innermost grid
+    axis). The H heads of a position are H query rows of one matrix product
+    against the ONE cached head, and the values are the same block's first
+    `kv_rank` columns: a cached row is moved once a query block, for keys
+    and values both. A block above the causal frontier of the query block's
+    last position is skipped, and so is every block of a parked lane
+    (position <= -T)."""
+    qi = pl.program_id(1)
+    si = pl.program_id(2)
+    pos0 = pos_ref[pl.program_id(0)]
+    row0 = qi * block_q  # first query row of the block, of T * H
+
+    @pl.when(si == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_acc[:] = jnp.zeros_like(l_acc)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    s_start = si * block_s
+    last_pos = pos0 + (row0 + block_q - 1) // n_heads
+
+    @pl.when(s_start <= last_pos)
+    def _compute():
+        q = q_ref[0]
+        c = c_ref[0, 0]  # [W, bs]: a cached row is a column
+        scores = jax.lax.dot_general(
+            q, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_s), 0)
+        if n_heads & (n_heads - 1) == 0:  # a shift where the vector unit can
+            q_pos = pos0 + jax.lax.shift_right_logical(
+                rows, jnp.int32(n_heads.bit_length() - 1))
+        else:
+            q_pos = pos0 + rows // n_heads
+        s_pos = s_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_s), 1)
+        scores = jnp.where(s_pos <= q_pos, scores, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0, jnp.exp(m_prev - m_new))
+        p = jnp.where(m_new <= _NEG_INF / 2, 0.0, jnp.exp(scores - m_new))
+        l_new = alpha * l_acc[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(c.dtype), c[:kv_rank], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_acc[:] = jnp.broadcast_to(l_new, l_acc.shape)
+
+    @pl.when(si == n_s - 1)
+    def _emit():
+        l = l_acc[:, :1]
+        out_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("rows", "kv_rank", "scale", "block_q", "block_s", "interpret"),
+)
+def latent_flash_attention(
+    q: jnp.ndarray,  # [B, T, H, W]: absorbed queries `[q_nope U_h^T | q_rope]`
+    c_stack: jnp.ndarray,  # [L, B, 1, S, W] latent cache stack, or [B, 1, S, W]
+    pos: jnp.ndarray,  # scalar or [B] int32: position of q[:, 0] per lane
+    layer=None,  # int32 scalar: which layer of a stack
+    rows: int = 0,  # attend to the first `rows` cached rows only (0 = all S)
+    kv_rank: int = 0,  # the rows' first `kv_rank` columns are the values
+    scale: float = 1.0,
+    block_q: int = 0,
+    block_s: int = 0,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Blockwise causal latent attention in the absorbed form, [B, T, H,
+    kv_rank] in q's type: `softmax_j(q . row_j * scale) row_j[:kv_rank]`
+    over the cached rows a query's position sees. The stack stays where it
+    lies (the layer rides as scalar prefetch, the window as `rows`); the
+    products take the operands' own type with f32 accumulation, the softmax
+    is f32. A strongly negative lane position masks the lane whole.
+
+    The kernel takes the stack with its last two axes swapped, `[.., W, S]`.
+    On the chip that moves nothing: W = 576 is no multiple of the 128 lanes
+    and S is, so the chip's own layout of a `[.., S, 576]` array already has
+    the positions minor (described v5e: `{3,4,2,1,0}`), and the swapped view
+    in its default layout is the same bytes. Handed over unswapped, the
+    stack was copied whole into the other layout before the layer scan and
+    back after it, 0.39 GB each way a chunk."""
+    if c_stack.ndim == 4:
+        assert layer is None, "a layer number needs a [L, B, 1, S, W] stack"
+        c_stack, layer = c_stack[None], 0
+    b, t, h, w = q.shape
+    s = rows or c_stack.shape[3]
+    assert c_stack.shape[2] == 1 and c_stack.shape[4] == w and s <= c_stack.shape[3]
+    n_rows = t * h
+    if not block_q or not block_s:
+        # up to 512 query rows a block (T x H of them): a cached block is
+        # moved once a query block
+        picked = pick_flash_blocks(n_rows, s, max_t=512)
+        if picked is None:
+            if not interpret:
+                raise ValueError(
+                    f"no valid latent blocks for {n_rows} query rows, s={s}; "
+                    "use dense attention"
+                )
+            picked = (n_rows, s)
+        block_q, block_s = block_q or picked[0], block_s or picked[1]
+    assert n_rows % block_q == 0 and s % block_s == 0, (n_rows, s, block_q, block_s)
+    n_q, n_s = n_rows // block_q, s // block_s
+    qr = q.reshape(b, n_rows, w).astype(c_stack.dtype)
+    pos_arr = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (b,))
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def q_map(bi, qi, si, pos_ref, l_ref):
+        return (bi, qi, 0)
+
+    def c_map(bi, qi, si, pos_ref, l_ref):
+        # clamp past the causal frontier of the query block's last position
+        limit = jnp.maximum(
+            (pos_ref[bi] + ((qi + 1) * block_q - 1) // h) // block_s, 0)
+        return (l_ref[0], bi, 0, 0, jnp.minimum(si, limit))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_flash_kernel, block_q=block_q, block_s=block_s, n_s=n_s,
+            n_heads=h, kv_rank=kv_rank, scale=scale,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_q, n_s),
+            in_specs=[
+                pl.BlockSpec((1, block_q, w), q_map),
+                pl.BlockSpec((None, 1, 1, w, block_s), c_map),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, kv_rank), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, kv_rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, n_rows, kv_rank), q.dtype),
+        interpret=interpret,
+    )(pos_arr, layer_arr, qr, jnp.swapaxes(c_stack, 3, 4))
+    return out.reshape(b, t, h, kv_rank)
 
 
 def flash_attention(

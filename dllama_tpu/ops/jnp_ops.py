@@ -79,8 +79,8 @@ def rope_frequencies(h: LlmHeader) -> "np.ndarray":
     for the falcon layout (src/nn/nn-core.cpp:361-374) — identical values,
     different pairing; the pairing lives in `apply_rope`.
     """
-    half = h.head_dim // 2
-    exponents = 2.0 * np.arange(half, dtype=np.float32) / np.float32(h.head_dim)
+    half = h.rope_dim // 2
+    exponents = 2.0 * np.arange(half, dtype=np.float32) / np.float32(h.rope_dim)
     freqs = (1.0 / (h.rope_theta**exponents)).astype(np.float32)
     if h.rope_type == RopeType.LLAMA3_1 and h.rope_scaling_factor != 1.0:
         freqs = _scale_frequency_llama3(freqs, h).astype(np.float32)

@@ -654,6 +654,17 @@ def moe_grouped_experts_q40(
 _HELD_ROWS = 128  # 2048 held pairs of a 512-row chunk over 32 experts: 16 tiles
 
 
+def _held_compiler_params(d: int, bf: int):
+    """A larger scoped-VMEM limit where the smallest legal F block still
+    passes the default 16 MiB: at D = 7680 the three 7680 x 256 tiles, their
+    dequantised copies and the 128-row x, out and accumulator blocks read
+    16.41 MB on the described v5e (of 128 MiB physical). Expert shapes that
+    fit (3072 x 256 and below) keep the default, and their programs."""
+    if d * bf <= 1 << 20:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=48 << 20)
+
+
 def _held_kernel_q40(lo_ref, hi_ref, tile_ref, expert_ref, n_ref, *refs, **kw):
     _grouped_kernel_q40(
         lo_ref, hi_ref, tile_ref, expert_ref, *refs, n_steps=n_ref[0], **kw
@@ -717,6 +728,7 @@ def moe_held_experts_q40(
         ),
         out_shape=jax.ShapeDtypeStruct((a_pad, d), jnp.float32),
         interpret=interpret,
+        compiler_params=_held_compiler_params(d, bf),
     )(lo, hi, tile, expert + first, n_steps.reshape(1), x_sorted, w_col,
       w1q, w1d, w3q, w3d, w2q, w2d)
     # tiles the grid never reached hold whatever the buffer held

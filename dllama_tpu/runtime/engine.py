@@ -35,6 +35,8 @@ from .sampler import Sampler
 # --nBatches plays the same role: its graphs are compiled-in for nBatches
 # rows and prefill walks the prompt in nBatches-sized chunks).
 DEFAULT_PREFILL_BUCKETS = (1, 8, 32, 128, 512)
+# the smallest attention window of a cache of latent rows (`_attn_window`)
+LATENT_MIN_WINDOW = 4096
 
 
 def _sds(x):
@@ -275,16 +277,26 @@ class InferenceEngine:
         from ..formats.model_file import layer_table
 
         self._two_cache_kinds = any(k.window for k in layer_table(self.header))
-        if self._two_cache_kinds:
+        # latent attention keeps one stack of `[c | k_rope]` rows, one head
+        # for all, which heads-over-chips cannot divide
+        self._latent = self.header.latent
+        if self._two_cache_kinds or self._latent:
+            what, cache = (
+                ("latent attention layers over a cache of latent rows",
+                 "a cache of latent rows")
+                if self._latent else
+                ("window attention layers over a ring cache",
+                 "the window layers' ring cache")
+            )
             for flag, n in (("--tp", tp), ("--sp", sp), ("--pp", pp), ("--dp", dp)):
                 if n > 1:
                     raise ValueError(
-                        f"{flag} {n}: window attention layers over a ring "
-                        f"cache run on one device ({self.header.arch.name})"
+                        f"{flag} {n}: {what} run on one device "
+                        f"({self.header.arch.name})"
                     )
             if kv_dtype in ("int8", jnp.int8):
                 raise ValueError(
-                    "--kv-dtype int8: the window layers' ring cache is not "
+                    f"--kv-dtype int8: {cache} is not "
                     f"quantized ({self.header.arch.name})"
                 )
         validate_tp(self.header, tp)
@@ -438,6 +450,8 @@ class InferenceEngine:
             self._cache_sharding.update(
                 kw=self._cache_sharding["k"], vw=self._cache_sharding["v"]
             )
+        if self._latent:
+            self._cache_sharding = {"c": self._cache_sharding["k"]}
         self._m_ring_wraps = self.obs.counter(
             "dllama_kv_ring_wraps_total",
             "Times a lane's position passed the end of the window layers' "
@@ -462,7 +476,8 @@ class InferenceEngine:
             "dllama_kv_cache_bytes",
             "Device bytes of the lane KV cache by kind of layer: full = "
             "rows for the whole context, window = a ring of the window "
-            "and one chunk.",
+            "and one chunk, latent = one stack of [c | k_rope] rows for the "
+            "whole context.",
             labelnames=("kind",),
         )
         self.kv_cache_bytes = {
@@ -470,7 +485,8 @@ class InferenceEngine:
                 leaf.nbytes for name in names if name in self.cache
                 for leaf in jax.tree.leaves(self.cache[name])
             )
-            for kind, names in (("full", ("k", "v")), ("window", ("kw", "vw")))
+            for kind, names in (
+                ("full", ("k", "v")), ("window", ("kw", "vw")), ("latent", ("c",)))
         }
         for kind, n in self.kv_cache_bytes.items():
             g_bytes.labels(kind=kind).set(n)
@@ -705,8 +721,12 @@ class InferenceEngine:
         key and value rows, a layer, that the queries of the dispatch's live
         lanes see, summed over its `n` steps or rows: in the full layers all
         of a lane's context, in the window layers at most the window. The
-        ring's wraps that the dispatch brings are counted here too. Nothing
-        for a model of one kind."""
+        ring's wraps that the dispatch brings are counted here too. A latent
+        cache: `rows_latent`, the latent rows a layer those queries see.
+        Nothing for a model of keys and values of one kind."""
+        if self._latent:
+            # every live query sees its whole context, one latent row a layer
+            return {"rows_latent": sum(p + i + 1 for p in starts for i in range(n))}
         if not self._two_cache_kinds:
             return {}
         wraps = sum((p + n) // self.kv_ring - p // self.kv_ring for p in starts)
@@ -777,8 +797,13 @@ class InferenceEngine:
         # of a cold start on the chip, three 100 s: PERF.md, PR 32), for
         # contexts that one of 4096 rows serves at up to 3584 rows more a
         # layer and lane in a decode step
+        # a latent cache starts at 4096 rows: a 4096-row read of latents
+        # (4.7 MB a layer and lane at 576 wide) costs what 1152 rows of a
+        # GQA cache of 8 heads of 128 cost, and each smaller window is
+        # three more programs in a cold run
         w = 512
-        while w < max(limit, self.header.sliding_window):
+        floor = LATENT_MIN_WINDOW if self._latent else self.header.sliding_window
+        while w < max(limit, floor):
             w *= 2
         # NB: crossing a window boundary mid-generation compiles a fresh
         # program for the next window (one synchronous stall per crossing,
@@ -1173,6 +1198,11 @@ class InferenceEngine:
     # -- per-lane serving (continuous-batching surface) ----------------------
 
     def _refuse_speculation(self) -> None:
+        if self._latent:
+            raise ValueError(
+                "--speculation: the verify programs are untested over a "
+                f"cache of latent rows ({self.header.arch.name})"
+            )
         if self._two_cache_kinds:
             raise ValueError(
                 "--speculation: the verify programs are untested over the "
@@ -1508,12 +1538,17 @@ class InferenceEngine:
             h.n_layers, self._kv_pool_pages, h.n_kv_heads,
             self._kv_page_size, h.head_dim,
         )
-        if self._two_cache_kinds:
+        if self._two_cache_kinds or self._latent:
             # a page holds its positions' rows of every layer, so the pool
-            # is two stacks as the cache is, under one page number
+            # is the cache's stacks (two kinds of layer: two pairs; latent
+            # rows: one stack, one head, `latent_row` wide) under one page
+            # number
             return {
                 name: jax.device_put(
-                    jnp.zeros((leaf.shape[0], *shape[1:]), self.kv_dtype), sharding
+                    jnp.zeros(
+                        (leaf.shape[0], shape[1], leaf.shape[2], shape[3], leaf.shape[4]),
+                        self.kv_dtype,
+                    ), sharding
                 )
                 for name, leaf in self._cache_specs.items()
             }
@@ -1554,6 +1589,11 @@ class InferenceEngine:
             # padding (dynamic_slice would clamp silently and misalign)
             raise ValueError(
                 f"page_size {page_size} exceeds lane padding {self._lane_pad}"
+            )
+        if native and self._latent:
+            raise ValueError(
+                "--kv-native 1: the pool-native programs read keys and "
+                f"values; {self.header.arch.name} caches latent rows"
             )
         if native and self._two_cache_kinds:
             raise ValueError(

@@ -214,15 +214,63 @@ def tiny_afmoe_config(layer_types=None, **over) -> dict:
     return cfg
 
 
-def make_tiny_afmoe(path: str, seed: int = 3, **over) -> dict:
-    """Write a seeded tiny `afmoe` model at `path` through the benchmark's own
-    writer (the program's format code under it); returns its configuration."""
+def _write_tiny(path: str, cfg: dict, seed: int) -> dict:
+    """Write the seeded model of a configuration at `path` through the
+    benchmark's own writer (the program's format code under it)."""
     import sys
 
     if REPO_ROOT not in sys.path:
         sys.path.insert(0, REPO_ROOT)
     from benchmark.harness import weights
 
-    cfg = tiny_afmoe_config(**over)
     weights.write_model(path, cfg, seed)
     return cfg
+
+
+def make_tiny_afmoe(path: str, seed: int = 3, **over) -> dict:
+    """Write a seeded tiny `afmoe` model at `path`; returns its configuration."""
+    return _write_tiny(path, tiny_afmoe_config(**over), seed)
+
+
+def tiny_pangu_config(**over) -> dict:
+    """A benchmark configuration file's worth of the `pangu_ultra_moe`
+    architecture (latent attention, a dense layer and then experts with a
+    shared one, a share of the experts) at test widths that keep every
+    ratio: the query's latent, the cached latent, the nope, rope and value
+    widths all differ from each other."""
+    cfg = {
+        "name": "pangu-tiny", "family": "pangu_ultra_moe",
+        "hidden_size": 64, "intermediate_size": 160, "moe_intermediate_size": 128,
+        "num_hidden_layers": 5, "first_k_dense_replace": 1,
+        "num_attention_heads": 8, "num_key_value_heads": 8,
+        "q_lora_rank": 96, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 8, "v_head_dim": 24, "vocab_size": 512,
+        "max_position_embeddings": 4096, "rope_theta": 25600000, "rms_norm_eps": 1e-5,
+        "n_routed_experts": 4, "num_routed_experts": 8, "first_expert": 0,
+        "num_experts_per_tok": 2, "n_shared_experts": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 2.5, "assumed": {"head_dim": 24},
+    }
+    cfg.update(over)
+    cfg["num_experts"] = cfg["n_routed_experts"]  # the harness's name for the experts held
+    sparse = cfg["first_k_dense_replace"] < cfg["num_hidden_layers"]
+    if not sparse:
+        cfg["num_experts"] = 0
+    cfg["file"] = {
+        "arch": "PANGU_MOE", "rope_pairing": "half", "norm_epsilon_enum": 5,
+        "header": {
+            "n_dense_layers": cfg["first_k_dense_replace"],
+            "n_shared_experts": cfg["n_shared_experts"] if sparse else 0,
+            "score_func": 1, "route_norm": 1, "route_scale_milli": 2500,
+            "n_routed_experts": cfg["num_routed_experts"] if sparse else 0,
+            "first_expert": cfg["first_expert"],
+            **{k: cfg[k] for k in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                                   "qk_rope_head_dim", "v_head_dim")},
+        },
+        "tensors": {"q_a_norm": {"dist": "uniform", "lo": 2.0, "hi": 3.0}},
+    }
+    return cfg
+
+
+def make_tiny_pangu(path: str, seed: int = 3, **over) -> dict:
+    """The same for `pangu_ultra_moe`, as `make_tiny_afmoe`."""
+    return _write_tiny(path, tiny_pangu_config(**over), seed)

@@ -133,27 +133,37 @@ def test_eight_lanes_at_unequal_positions_one_parked(tmp_path):
     assert not np.array_equal(after["kw"][:, 6, :, ring], before["kw"][:, 6, :, ring])
 
 
-def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(tmp_path):
+@pytest.mark.parametrize("family", ["afmoe", "pangu_ultra_moe"])
+def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(tmp_path, family):
     """The share tied to the model: one expert layer of a model that holds
     all 8 experts the router scores, in the reference; and the same layer
     as 8 chips would compute it, each holding one expert, in the program's
     own routing and expert code. The routed parts of the 8 shares plus the
     shared expert, counted once, are the uncut layer's output. Some token
-    has no expert on some chip, and gets nothing from it."""
-    cfg = tiny(num_experts=8)
+    has no expert on some chip, and gets nothing from it. Both families
+    that serve a held share: `afmoe` (a selection bias, scale 2.448) and
+    `pangu_ultra_moe` (none, scale 2.5)."""
+    if family == "afmoe":
+        ref, cfg, scale = afmoe, tiny(num_experts=8), 2.448
+    else:
+        from benchmark.references import pangu_ultra_moe as ref
+        from helpers import tiny_pangu_config
+
+        cfg, scale = tiny_pangu_config(n_routed_experts=8), 2.5
     path, h, params = build(tmp_path, cfg)
     layer = 2  # an expert layer; its row among the expert layers' stacks is 1
     lp = {k: v[1] for k, v in params["layers"].items()
           if k in ("moe_gate", "expert_bias", "w1", "w2", "w3", "shared_w1", "shared_w2", "shared_w3")}
+    assert ("expert_bias" in lp) == (family == "afmoe")
     y = jnp.asarray(np.random.default_rng(2).standard_normal((1, 40, 64)), jnp.float32)
-    f = afmoe.Q40File(path)
-    w = afmoe.layer_weights(f, layer, cfg)
-    want = np.asarray(afmoe.routed_experts(y[0], w, cfg) + afmoe.dense_ffn(
+    f = ref.Q40File(path)
+    w = ref.layer_weights(f, layer, cfg)
+    want = np.asarray(ref.routed_experts(y[0], w, cfg) + ref.dense_ffn(
         y[0], w["shared_w1"], w["shared_w2"], w["shared_w3"]))
     parts, empty = [], 0
     for first in range(8):
-        route = tf.Routing(2, True, True, 2.448, first, 1, 8)
-        top_i, wts = tf._moe_route(y, lp["moe_gate"], route, lp["expert_bias"])
+        route = tf.Routing(2, True, True, scale, first, 1, 8)
+        top_i, wts = tf._moe_route(y, lp["moe_gate"], route, lp.get("expert_bias"))
         held = route.held(top_i)
         part = tf._moe_ffn(
             y, lp["moe_gate"], *(lp[n][first:first + 1] for n in ("w1", "w2", "w3")),

@@ -467,6 +467,91 @@ def test_layer_scan_writes_both_cache_stacks_in_place(one_chip, monkeypatch, row
     assert "moe_held_experts_q40" in text and " conditional(" in text
 
 
+# openPangu-Ultra-MoE's cut: 5 latent layers at 16384 + 512 rows of 576, 4 lanes
+# (the context ISSUE 34's first set of sizes asked for; its cell runs 8192)
+PANGU_LATENT = (5, 4, 1, 16896, 576)
+
+
+def pangu_layers(s):
+    """openPangu-Ultra-MoE's per-layer leaves as the loader stacks them:
+    the latent projections over all 5 layers (`wkv_b` as two per-head bf16
+    stacks), the leading dense layer's FFN apart, and 4 expert layers of a
+    shared expert and the 32 experts held of 256."""
+    from dllama_tpu.ops.quant_matmul import FusedQuantWeight, QuantWeight
+
+    d, f, fd, e, n, ns, heads = 7680, 2048, 18432, 32, 5, 4, 128
+
+    def f32(*shape):
+        return sds(shape, jnp.float32, s)
+
+    def fused(layers, k, dims):
+        return FusedQuantWeight(q40_stack(layers, k, sum(dims), s), 1, tuple(dims))
+
+    def experts(k, width):
+        return QuantWeight(sds((ns, e, k, width), jnp.int8, s),
+                           sds((ns, e, k // 32, width), jnp.float32, s))
+
+    return dict(
+        att_norm=f32(n, d), ffn_norm=f32(n, d), post_att_norm=f32(n, d),
+        post_ffn_norm=f32(n, d), q_a_norm=f32(n, 1536), kv_a_norm=f32(n, 512),
+        wq_a=q40_stack(n, d, 1536, s), wq_b=q40_stack(n, 1536, heads * 192, s),
+        wkv_a=q40_stack(n, d, 576, s), wo=q40_stack(n, heads * 128, d, s),
+        wkv_b_k=sds((n, heads, 128, 512), jnp.bfloat16, s),
+        wkv_b_v=sds((n, heads, 512, 128), jnp.bfloat16, s),
+        dense_w13=fused(1, d, (fd, fd)), dense_w2=q40_stack(1, fd, d, s),
+        moe_gate=f32(ns, d, 256),
+        shared_w13=fused(ns, d, (f, f)), shared_w2=q40_stack(ns, f, d, s),
+        w1=experts(d, f), w3=experts(d, f), w2=experts(f, d),
+    )
+
+
+@pytest.mark.parametrize("rows,window", [(1, 16384), (512, 4096), (512, 16384)],
+                         ids=["decode", "prefill-4096", "prefill-16384"])
+def test_layer_scan_writes_latent_rows_in_place(one_chip, monkeypatch, rows, window):
+    """openPangu-Ultra-MoE's cut at twice its cell's context: latent attention
+    over one cache stack of 576-wide rows. A layer's row is one
+    `dynamic-update-slice` a lane into the carried stack, a chunk's
+    attention is the latent kernel over the stack where it lies and a decode
+    step's a slice of the window's rows: no instruction has a layer of the
+    stack as its result and the stack is never copied; no per-head key or
+    value of the context's length exists."""
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+    from dllama_tpu.models import transformer as tf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = one_chip
+    h = LlmHeader(
+        arch=LlmArch.PANGU_MOE, dim=7680, hidden_dim=18432, moe_hidden_dim=2048,
+        n_layers=5, n_heads=128, n_kv_heads=128, n_experts=32, n_active_experts=8,
+        vocab_size=19200, seq_len=16384, head_dim=192, rope_type=RopeType.FALCON,
+        n_dense_layers=1, n_shared_experts=1, score_sigmoid=True, route_scale=2.5,
+        n_routed_experts=256, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    )
+    lanes = PANGU_LATENT[1]
+
+    def step(x, layers, c, pos, cos, sin):
+        counts = []
+        out = tf.run_layers(
+            x, layers, None, None, h, pos, jnp.where(pos >= 16384, -16896, pos),
+            cos, sin, attn_window=window, c_cache=c, route_stats=counts,
+        )
+        return out, counts
+
+    text = compiled_text(
+        jax.jit(step, donate_argnums=(2,)),
+        sds((lanes, rows, 7680), jnp.bfloat16, s), pangu_layers(s),
+        sds(PANGU_LATENT, jnp.bfloat16, s), sds((lanes,), jnp.int32, s),
+        sds((lanes, rows, 32), jnp.float32, s), sds((lanes, rows, 32), jnp.float32, s),
+    )
+    assert text.count("dynamic-update-slice(") >= 1
+    assert not cache_copies(text, PANGU_LATENT), cache_copies(text, PANGU_LATENT)
+    assert "moe_held_experts_q40" in text
+    assert ("latent_flash_attention" in text) == (rows > 1)
+    # nothing of a context's length per head: 128 heads x rows x 192 or 128
+    assert f"bf16[{lanes},128,{window}," not in text and f",{window},128,1" not in text
+
+
 def test_layer_scan_writes_cache_rows_in_place_tp4(tp4, monkeypatch):
     """The same prefill chunk at tp=4: KH is the stack's sharded axis
     (`P(None, "dp", "tp", None, None)` into the flash kernel's `shard_map`,
