@@ -898,6 +898,7 @@ def forward(
     moe_decode_dedup: bool = False,
     kv_ring: int = 0,
     route_stats: list | None = None,
+    one_live_lane: bool = False,
 ) -> Tuple[jnp.ndarray, KvCache]:
     """Run the decoder on T tokens starting at absolute position `pos`.
 
@@ -927,6 +928,10 @@ def forward(
     prefill chunks only sample from their last row, and for small models
     the vocab matmul is a large fraction of chunk FLOPs (~25% on a
     1B/128k-vocab shape), which lands directly on TTFT.
+
+    `one_live_lane` (static): the caller's program admits ONE lane and parks
+    every other (the engine's chunk programs): `run_layers`' expert block
+    then computes that lane's rows alone.
     """
     b, t = tokens.shape
     # `pos` may be a [B] vector: each batch lane decodes at its own
@@ -946,9 +951,15 @@ def forward(
         sync_quant=sync_quant, moe_decode_dedup=moe_decode_dedup,
         kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
         route_stats=route_stats, c_cache=cache.get("c"),
+        one_live_lane=one_live_lane,
     )
     logits = logits_head(x, params, h, mesh, logits_mode)
     return logits, dict(zip(names, caches))
+
+
+def lanes_on_one_device(mesh) -> bool:
+    """No mesh axis splits the lanes, so one lane's rows are a local slice."""
+    return mesh is None or mesh.shape.get("dp", 1) == 1
 
 
 def attn_positions(pos, attn_park_threshold: int, cache_len: int):
@@ -1030,6 +1041,7 @@ def run_layers(
     kv_ring: int = 0,
     route_stats: list | None = None,
     c_cache: jnp.ndarray | None = None,  # [L, B, 1, S, W]: latent layers, alone
+    one_live_lane: bool = False,
 ):
     """`lax.scan` the decoder layers over x; returns (x, k_new, v_new), and
     the window layers' (kw_new, vw_new) behind them where the model has such;
@@ -1075,6 +1087,16 @@ def run_layers(
     the merged-stats math and cache writes land on owning shards via a
     fixed-width window update + validity gather (a chunk's rows spread
     over every shard). Requires mesh=None.
+
+    `one_live_lane`: what the program is, not what its rows look like: a
+    chunk program admits one lane and parks the others, so at most one
+    entry of `attn_pos` is a position. A layer's expert block (the norm
+    before it, the router, the routed and the shared experts, the norm
+    behind) then runs over that lane's `[T, D]` rows, one contiguous slice
+    of `x` at a traced lane number, and writes them back; a parked lane's
+    rows, which no query reads, pass the block as they came. Where the
+    lanes are split over devices (`dp`) every lane's rows are computed as
+    before: the slice would gather across them.
     """
     b, t = x.shape[0], x.shape[1]
     interleaved = h.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1)
@@ -1165,11 +1187,16 @@ def run_layers(
                 f"a chunk of {t}: the chunk would overwrite rows its own "
                 f"first query sees"
             )
-    # token rows of live lanes: what the routing counters count
-    live_rows = (
-        jnp.broadcast_to((attn_pos >= 0)[:, None], (b, t)).reshape(-1)
-        if jnp.ndim(attn_pos) == 1 else jnp.ones((b * t,), bool)
-    )
+    # token rows of live lanes: what the expert block computes pairs for and
+    # the routing counters count; `lone`: the admitted lane's rows alone
+    lone = one_live_lane and jnp.ndim(attn_pos) == 1 and lanes_on_one_device(mesh)
+    if lone:
+        lane = jnp.argmax(attn_pos >= 0).astype(jnp.int32)
+        live_rows = jnp.broadcast_to(attn_pos[lane] >= 0, (t,))
+    elif jnp.ndim(attn_pos) == 1:
+        live_rows = jnp.broadcast_to((attn_pos >= 0)[:, None], (b, t)).reshape(-1)
+    else:
+        live_rows = jnp.ones((b * t,), bool)
 
     def _cache_append(cache, l, val, there=None):
         """Write the chunk into layer `l` of the carried stack at each
@@ -1375,9 +1402,12 @@ def run_layers(
         )
 
     def moe_block(y, lp, lf):
-        """The experts' FFN of a layer whose experts' row is `lf`, and what
-        the routing counters count of it where they are asked for."""
+        """The experts' FFN of a layer whose experts' row is `lf` over the
+        rows of `y` (every lane's, or the one admitted lane's), and what the
+        routing counters count of it where they are asked for."""
         from ..ops.moe_kernel import moe_pallas_supported
+
+        b, t = y.shape[0], y.shape[1]
 
         _w1 = lp["w1"]
         _quantized = isinstance(_w1, QuantWeight)
@@ -1576,6 +1606,10 @@ def run_layers(
                 x = x + o
 
             # -- FFN block (reference: src/llm.cpp:405-557) --
+            # experts of a chunk program: over the admitted lane's rows alone
+            x_all = x
+            if experts and lone:
+                x = lax.dynamic_slice_in_dim(x_all, lane, 1, axis=0)  # [1, T, D]
             with jax.named_scope("norm"):
                 y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
             with jax.named_scope("moe" if experts else "ffn"):
@@ -1597,6 +1631,8 @@ def run_layers(
                 if "post_ffn_norm" in lp:
                     f = rms_norm(f, lp["post_ffn_norm"], h.norm_epsilon)
                 x = x + f
+                if experts and lone:
+                    x = lax.dynamic_update_slice_in_dim(x_all, x, lane, axis=0)
             return (x, caches), counts
 
         return layer_step

@@ -85,6 +85,7 @@ def forward_pp(
     sync_quant: bool = False,
     park_pos: int = 0,
     moe_decode_dedup: bool = False,
+    one_live_lane: bool = False,
 ):
     """Pipeline-parallel forward: same contract as models.forward.
 
@@ -135,6 +136,7 @@ def forward_pp(
 
     from ..models.transformer import (
         attn_positions,
+        lanes_on_one_device,
         logits_head,
         rope_slices,
         run_layers,
@@ -264,6 +266,8 @@ def forward_pp(
                 moe_decode_dedup=moe_decode_dedup,
                 tp_axis="tp" if tp > 1 else None, tp_n=tp,
                 sp_axis=sp_ax, sp_n=sp,
+                # a micro-batch holds the admitted lane's rows as the chunk does
+                one_live_lane=one_live_lane and lanes_on_one_device(mesh),
             )
             # commit this stage's cache range only for a valid chunk;
             # invalid ticks computed on pass-through/fill data (park mode:

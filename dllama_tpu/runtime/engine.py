@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..formats.model_file import LlmHeader, ModelReader
 from ..formats.quants import FloatType
 from ..models import forward, init_kv_cache, load_params
+from ..models.transformer import lanes_on_one_device
 from ..parallel import cache_specs, make_mesh, shard_params_put, validate_tp
 from ..tokenizer import Tokenizer
 from .faults import get_fault_plane
@@ -471,6 +472,13 @@ class InferenceEngine:
             "over the expert layers of every decode step: what the expert "
             "kernel had to read.",
         )
+        self._m_moe_chunk_rows = self.obs.counter(
+            "dllama_moe_chunk_rows_total",
+            "Token rows of prefill chunk programs by what the expert block "
+            "did with them: computed = rows it routed and ran (the admitted "
+            "lane's bucket), parked_skipped = parked lanes' rows it left out.",
+            labelnames=("rows",),
+        )
         self.cache = self._fresh_cache()
         g_bytes = self.obs.gauge(
             "dllama_kv_cache_bytes",
@@ -591,13 +599,15 @@ class InferenceEngine:
             park = self._park if self._lane_pad else 0
 
             def fwd(params, tokens, pos, cache, *, attn_window=0,
-                    logits_mode="all", attn_park_threshold=0, n_micro=1):
+                    logits_mode="all", attn_park_threshold=0, n_micro=1,
+                    one_live_lane=False):
                 return forward_pp(
                     params, h, tokens, pos, cache, mesh,
                     attn_window=attn_window, logits_mode=logits_mode,
                     attn_park_threshold=attn_park_threshold,
                     n_micro=n_micro, sync_quant=sync_quant,
                     park_pos=park, moe_decode_dedup=moe_decode_dedup,
+                    one_live_lane=one_live_lane,
                 )
 
         else:
@@ -606,7 +616,7 @@ class InferenceEngine:
 
             def fwd(params, tokens, pos, cache, *, attn_window=0,
                     logits_mode="all", attn_park_threshold=0, n_micro=1,
-                    route_stats=None):
+                    route_stats=None, one_live_lane=False):
                 del n_micro  # sequence-wave microbatching is pp-only
                 return forward(
                     params, h, tokens, pos, cache, mesh=mesh,
@@ -615,6 +625,7 @@ class InferenceEngine:
                     sync_quant=sync_quant,
                     moe_decode_dedup=moe_decode_dedup,
                     kv_ring=kv_ring, route_stats=route_stats,
+                    one_live_lane=one_live_lane,
                 )
 
         self._fwd = fwd
@@ -736,6 +747,21 @@ class InferenceEngine:
         # what the dispatch's queries see: position p's sees p + 1 rows
         seen = [p + i + 1 for p in starts for i in range(n)]
         return {"rows_full": sum(seen), "rows_window": sum(min(s, w) for s in seen)}
+
+    def _chunk_expert_rows(self, bucket: int) -> dict:
+        """`step_dispatch` field of a sparse model's chunk: the token rows
+        its expert block computes, the admitted lane's `bucket` where the
+        program takes that lane's rows alone (`run_layers`' `one_live_lane`)
+        and every lane's where the lanes are split over devices. Counted
+        too, with the parked lanes' rows left out. Nothing for a dense
+        model."""
+        if not self.header.n_experts:
+            return {}
+        every = self.batch_size * bucket
+        rows = bucket if lanes_on_one_device(self.mesh) else every
+        self._m_moe_chunk_rows.labels(rows="computed").inc(rows)
+        self._m_moe_chunk_rows.labels(rows="parked_skipped").inc(every - rows)
+        return {"expert_rows": rows}
 
     def _dispatch_prep(self, step: str):
         """Open the span of the host work before a lane dispatch: window
@@ -1260,6 +1286,7 @@ class InferenceEngine:
                         params, tokens, pos_vec, cache,
                         attn_window=window, attn_park_threshold=park,
                         logits_mode="last", n_micro=self._pp_micro(t),
+                        one_live_lane=True,
                     )
                 return cache
 
@@ -1461,6 +1488,7 @@ class InferenceEngine:
             "prefill_lane_chunk", prep, lane=lane, pos=pos0,
             n_tokens=width, bucket=bucket, window=window,
             **self._rows_in_context([pos0], width),
+            **self._chunk_expert_rows(bucket),
         ):
             if native:
                 with self._kv_pool_guard():
@@ -2145,6 +2173,7 @@ class InferenceEngine:
                         params, tokens, pos_vec, view,
                         attn_window=window, attn_park_threshold=window,
                         logits_mode="last", n_micro=self._pp_micro(t),
+                        one_live_lane=True,
                     )
                 rows = pos_vec[:, None] + jnp.arange(t)[None, :]
                 safe = rows < window
@@ -2617,11 +2646,12 @@ class InferenceEngine:
         mesh = self.mesh
 
         def dfwd(params, tokens, pos, cache, *, attn_park_threshold=0,
-                 logits_mode="all"):
+                 logits_mode="all", one_live_lane=False):
             return forward(
                 params, dh, tokens, pos, cache, mesh=mesh,
                 attn_window=0, logits_mode=logits_mode,
                 attn_park_threshold=attn_park_threshold,
+                one_live_lane=one_live_lane,
             )
 
         self._draft_fwd = dfwd
@@ -2729,6 +2759,7 @@ class InferenceEngine:
                 _, cache = dfwd(
                     params, tokens, pos_vec, cache,
                     attn_park_threshold=park, logits_mode="last",
+                    one_live_lane=True,
                 )
                 return cache
 

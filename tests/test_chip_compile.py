@@ -273,7 +273,8 @@ def qwen_moe_layers(n_layers, s):
 
 def layer_scan_text(header, layers, cache, t, window, s, cache_s=None, mesh=None):
     """`run_layers` over `t` rows a lane at per-lane positions, the caches
-    donated as the engine's programs donate them, compiled for the chip."""
+    donated as the engine's programs donate them, compiled for the chip. A
+    chunk (`t` > 1) is the engine's `lane_prefill`: one admitted lane."""
     from dllama_tpu.models import transformer as tf
 
     n_lanes, hd = cache[1], cache[4]
@@ -282,7 +283,7 @@ def layer_scan_text(header, layers, cache, t, window, s, cache_s=None, mesh=None
     def step(x, layers, k, v, pos, cos, sin):
         return tf.run_layers(
             x, layers, k, v, header, pos, pos, cos, sin,
-            mesh=mesh, attn_window=window,
+            mesh=mesh, attn_window=window, one_live_lane=t > 1,
         )
 
     return compiled_text(
@@ -355,6 +356,30 @@ def test_one_chip_reads_each_distinct_expert_once(one_chip, monkeypatch, rows, w
     }
     assert "moe_held_experts_q40" in kernels, kernels
     assert not {"moe_active_experts_q40", "moe_grouped_experts_q40"} & kernels, kernels
+
+
+def test_chunk_program_sorts_one_lanes_pairs(one_chip, monkeypatch):
+    """Qwen3-30B-A3B's `lane_prefill` program, 16 lanes x 512 rows with one
+    lane admitted: the expert block takes that lane's rows, so the held
+    kernel's sorted operand (`x_sorted`) and its output are `bucket x k` =
+    4096 rows of 2048, and nothing in the program is shaped by the 65536
+    pairs of every lane's rows: no sort, gather, `where` or scatter-add over
+    them. The decode block and the tp=4 programs are as they were
+    (`test_one_chip_reads_each_distinct_expert_once[decode]`, the `tp4`
+    tests)."""
+    import re
+
+    lanes, bucket, k, d = QWEN_CACHE[1], 512, 8, 2048
+    text = scan_text_of("qwen3moe", bucket, 2048, one_chip, monkeypatch)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "moe_held_experts_q40" in line.split(" = ")[0]]
+    assert calls
+    for line in calls:
+        assert f"bf16[{bucket * k},{d}]" in line, line[:300]  # x_sorted
+        assert line.split(" = ")[1].startswith(f"f32[{bucket * k},{d}]"), line[:300]
+    padded = lanes * bucket * k
+    shaped = re.findall(rf"\w+\[{padded}(?:,\d+)*\]", text)
+    assert not shaped, sorted(set(shaped))
 
 
 @LANE_PROGRAMS
@@ -446,7 +471,7 @@ def test_layer_scan_writes_both_cache_stacks_in_place(one_chip, monkeypatch, row
         out = tf.run_layers(
             x, layers, k, v, h, pos, jnp.where(pos >= 16384, -16896, pos), cos, sin,
             attn_window=window, kw_cache=kw, vw_cache=vw, kv_ring=4608,
-            route_stats=counts,
+            route_stats=counts, one_live_lane=rows > 1,
         )
         return out, counts
 
@@ -535,6 +560,7 @@ def test_layer_scan_writes_latent_rows_in_place(one_chip, monkeypatch, rows, win
         out = tf.run_layers(
             x, layers, None, None, h, pos, jnp.where(pos >= 16384, -16896, pos),
             cos, sin, attn_window=window, c_cache=c, route_stats=counts,
+            one_live_lane=rows > 1,
         )
         return out, counts
 

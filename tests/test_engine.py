@@ -412,6 +412,59 @@ def test_moe_lane_block_counts_routed_pairs_on_one_device(tmp_path):
         event["pairs_routed"], event["pairs_held"], event["held_touched"]]
 
 
+@pytest.mark.parametrize("case", ["one_device", "dp2", "dense"])
+def test_chunk_dispatch_says_which_rows_its_experts_computed(tmp_path, tiny_model, case):
+    """A chunk program admits one lane of four and its expert block computes
+    that lane's rows alone: `step_dispatch` and the `prefill_lane_chunk`
+    span carry `expert_rows` = the bucket, and
+    `dllama_moe_chunk_rows_total` counts them and the parked lanes' rows
+    left out. Lanes split over devices (`dp`) compute every lane's rows and
+    say so; a dense model says nothing. What the routing counters count of a
+    served prompt is what they counted before: the live lanes' pairs of the
+    decode block, every one landed."""
+    if case == "dense":
+        path = tiny_model[0]
+    else:
+        path = str(tmp_path / "moe.m")
+        make_tiny_model(path, arch=LlmArch.QWEN3_MOE, weight_type=FloatType.Q40)
+    lanes = 4
+    e = InferenceEngine(path, tp=1, dp=2 if case == "dp2" else 1, dtype=jnp.float32,
+                        temperature=0.0, batch_size=lanes)
+    def rows_counted():
+        return [e._m_moe_chunk_rows.labels(rows=r).value for r in ("computed", "parked_skipped")]
+
+    # the recorder, the span ring and the registry outlive an engine
+    n_spans, n_events = e._spans.total_recorded, len(e.recorder.events("step_dispatch"))
+    n_routes, rows0 = len(e.recorder.events("moe_route")), rows_counted()
+    routed0 = e._m_moe_pairs.labels(landed="routed").value
+    prompt = list(range(1, 12))
+    e.prefill_lane(2, prompt)
+    spans = [sp for sp in e._spans.completed()[-(e._spans.total_recorded - n_spans):]
+             if sp["name"] == "prefill_lane_chunk"]
+    chunks = [ev for ev in e.recorder.events("step_dispatch")[n_events:]
+              if ev["step"] == "prefill_lane_chunk"]
+    assert chunks and sum(ev["n_tokens"] for ev in chunks) == len(prompt) - 1
+    computed, skipped = (a - b for a, b in zip(rows_counted(), rows0))
+    every = sum(lanes * ev["bucket"] for ev in chunks)
+    if case == "dense":
+        assert all("expert_rows" not in ev for ev in chunks)
+        assert (computed, skipped) == (0, 0)
+        return
+    want = [ev["bucket"] * (lanes if case == "dp2" else 1) for ev in chunks]
+    assert [ev["expert_rows"] for ev in chunks] == want
+    assert [sp["attrs"]["expert_rows"] for sp in spans] == want
+    assert (computed, skipped) == (sum(want), every - sum(want))
+    n_steps = 4
+    e.decode_lanes([0, 0, prompt[-1], 0], [0, 0, len(prompt) - 1, 0], n_steps,
+                   active=[False, False, True, False])
+    if case == "dp2":
+        return  # more than one device: the older kernels, which count nothing
+    (event,) = e.recorder.events("moe_route")[n_routes:]
+    routed = n_steps * e.header.n_active_experts * e.header.n_layers
+    assert (event["pairs_routed"], event["pairs_held"]) == (routed, routed)
+    assert e._m_moe_pairs.labels(landed="routed").value - routed0 == routed
+
+
 def test_prefill_lane_preserves_other_lanes(tiny_model):
     """Prefilling a new request into a free lane must not disturb a lane
     mid-conversation: decode lane 0, prefill lane 1, keep decoding lane 0

@@ -74,6 +74,116 @@ def test_moe_lanes_with_one_parked_match_numpy_oracle(tmp_path):
         assert np.abs(np.asarray(logits[lane, 0]) - want).max() < 2e-4 * want.std(), lane
 
 
+def _sparse_family(tmp_path, family: str):
+    """(header, params, forward's cache keywords, cache keywords, a
+    sequence's logits by the family's oracle) of a tiny sparse model: the
+    top-k softmax router with every expert held, `afmoe`'s held share with a
+    shared expert over two cache stacks, the latent family's sparse layers."""
+    seq, chunk = 256, 16
+    if family == "qwen3_moe":
+        h, params, tensors = build(tmp_path, arch=LlmArch.QWEN3_MOE)
+        return h, params, {}, {"seq_len": h.seq_len + chunk}, (
+            lambda ids: numpy_forward(tensors, h, ids))
+    import sys
+
+    import helpers
+
+    if helpers.REPO_ROOT not in sys.path:
+        sys.path.insert(0, helpers.REPO_ROOT)
+    if family == "afmoe":
+        from benchmark.references import afmoe as ref
+
+        cfg = helpers.tiny_afmoe_config()
+        ring = helpers.AFMOE_WINDOW + chunk
+        fwd_kw = {"kv_ring": ring}
+        cache_kw = {"seq_len": seq + chunk, "ring": ring, "ring_pad": chunk}
+    else:
+        from benchmark.references import pangu_ultra_moe as ref
+
+        cfg = helpers.tiny_pangu_config()
+        fwd_kw, cache_kw = {}, {"seq_len": seq + chunk}
+    path = str(tmp_path / "m.m")
+    helpers._write_tiny(path, cfg, 3)
+    reader = ModelReader(path, max_seq_len=seq)
+    params = load_params(reader, dtype=jnp.float32)
+    return reader.header, params, fwd_kw, cache_kw, (
+        lambda ids: np.asarray(ref.last_logits(path, cfg, [ids], [len(ids)])[0]))
+
+
+@pytest.mark.parametrize("live", [0, 2, 3], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("family", ["qwen3_moe", "afmoe", "pangu_ultra_moe"])
+def test_chunk_of_one_live_lane_computes_its_experts_alone(tmp_path, family, live):
+    """A chunk program's rows hold one admitted lane of four, the others
+    parked (`one_live_lane`): the expert block runs over that lane's rows
+    alone. Two chunks at the lane, first, in the middle or last: every row's
+    logits and the cache rows written are what the program over every lane's
+    rows gives (the uncompacted formula), the other lanes' rows stay as they
+    were, and the logits are the oracle's for the sequence."""
+    import jax
+
+    h, params, fwd_kw, cache_kw, oracle = _sparse_family(tmp_path, family)
+    lanes, park, chunk = 4, h.seq_len, 16
+    ids = [int(t) for t in np.random.default_rng(11 + live).integers(
+        0, min(500, h.vocab_size), 2 * chunk)]
+
+    def run(one_live_lane: bool):
+        cache = init_kv_cache(h, lanes, jnp.float32, **cache_kw)
+        rng = np.random.default_rng(5)
+        cache = {k: jnp.asarray(rng.standard_normal(v.shape), v.dtype)
+                 for k, v in cache.items()}  # rows a stray write would change
+        before = {k: np.asarray(v) for k, v in cache.items()}
+        step = jax.jit(lambda toks, pos, cache: forward(
+            params, h, toks, pos, cache, attn_park_threshold=park,
+            one_live_lane=one_live_lane, **fwd_kw))
+        out = []
+        for p in (0, chunk):
+            toks = np.zeros((lanes, chunk), np.int32)
+            toks[live] = ids[p:p + chunk]
+            pos = np.full(lanes, park, np.int32)
+            pos[live] = p
+            logits, cache = step(jnp.asarray(toks), jnp.asarray(pos), cache)
+            out.append(np.asarray(logits[live]))
+        return np.concatenate(out), before, {k: np.asarray(v) for k, v in cache.items()}
+
+    got, before, cache = run(True)
+    plain, _, plain_cache = run(False)
+    want = oracle(ids)
+    assert np.abs(got - plain).max() < 1e-5 * want.std()
+    assert np.abs(got - want).max() < 2e-4 * want.std()
+    for name, rows in cache.items():
+        # the live lane's rows as the uncompacted program wrote them; nobody
+        # else's rows inside the context moved
+        assert np.abs(rows[:, live] - plain_cache[name][:, live]).max() < 1e-5, name
+        assert not np.array_equal(rows[:, live], before[name][:, live]), name
+        # context rows only: a parked lane writes past them (a ring: its spare rows)
+        kept = slice(chunk, rows.shape[3] - chunk) if name in ("kw", "vw") else slice(0, park)
+        for lane in set(range(lanes)) - {live}:
+            assert np.array_equal(rows[:, lane, :, kept], before[name][:, lane, :, kept]), (
+                name, lane)
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode", "verify", "scalar"])
+def test_only_a_chunk_program_routes_one_lane(tmp_path, program):
+    """What the router's `top_k` is shaped by: a chunk program (one admitted
+    lane by construction) routes `t` rows; a decode block and a verify
+    program, whose lanes are all live, and a single stream at a scalar
+    position route every row they hold, told or not."""
+    import jax
+
+    h, params, _ = build(tmp_path, arch=LlmArch.QWEN3_MOE)
+    lanes, t = (1, 8) if program == "scalar" else (4, 1 if program == "decode" else 8)
+    cache = init_kv_cache(h, lanes, seq_len=h.seq_len + t)
+    pos = jnp.int32(0) if program == "scalar" else jnp.zeros((lanes,), jnp.int32)
+    # a decode block or a verify program never says so; a scalar position has no lanes
+    told = program in ("chunk", "scalar")
+    jaxpr = jax.make_jaxpr(lambda toks, pos, cache: forward(
+        params, h, toks, pos, cache, attn_park_threshold=h.seq_len,
+        one_live_lane=told))(jnp.zeros((lanes, t), jnp.int32), pos, cache)
+    routed = {e.invars[0].aval.shape[0] for e, _ in _equations(jaxpr.jaxpr)
+              if e.primitive.name == "top_k"}
+    assert routed == {t if program == "chunk" else lanes * t}
+
+
 def test_forward_llama31_rope_scaling(tmp_path):
     h, params, tensors = build(tmp_path, rope_scaling=True)
     assert h.rope_scaling_factor == 8.0
