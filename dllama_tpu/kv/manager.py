@@ -58,8 +58,8 @@ class PagedKVManager:
         self.native = bool(native)
         n = engine.init_kv_pool(self.page_size, n_pages, native=self.native)
         self.recorder = get_recorder()
-        # component="kv" spans over the host-side accounting (the engine's
-        # device copies inside adopt/publish record their own spans)
+        # the component="kv" span over the radix match (adopt and publish
+        # are the scheduler's spans, their device copies the engine's)
         self.spans = get_span_tracker()
         self.pool = PagePool(n, self.page_size, on_event=self._pool_event)
         self.tree = RadixTree(self.page_size)
@@ -183,9 +183,7 @@ class PagedKVManager:
     def _adopt_native(self, lane: int, pages: list[int]) -> None:
         ps = self.page_size
         n_blocks = self.engine._kv_n_blocks
-        with self.spans.span(
-            "kv_adopt_native", component="kv", lane=lane, n_pages=len(pages)
-        ), self.lock:
+        with self.lock:
             fault = get_fault_plane().draw("kv_alloc", op="adopt")
             if fault is not None:
                 raise fault
@@ -258,13 +256,6 @@ class PagedKVManager:
         makes a fanned-out system prompt physically one set of pages.
         Returns the number of pages newly stored (0 = full dedup or no
         whole page to store)."""
-        with self.spans.span(
-            "kv_publish_host", component="kv", lane=lane,
-            n_tokens=len(tokens),
-        ):
-            return self._publish(lane, tokens)
-
-    def _publish(self, lane: int, tokens: list[int]) -> int:
         if self.native:
             return self._publish_native(lane, tokens)
         ps = self.page_size

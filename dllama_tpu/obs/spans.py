@@ -41,10 +41,21 @@ thread); a handle is mutated only by its ender and ``end`` is idempotent
 (the first ender wins), so cross-thread handoff needs no lock beyond the
 ring append. Such a span is begun with ``annotate=False``: an annotation
 says what its thread was doing, and has to end where it began.
+
+Nesting: every record carries an ``id``, the ``thread`` that began it
+(``threading.get_ident()``) and ``parent``, the id of the span that was
+open under it on that thread (``None`` at the top). The open spans of a
+thread are a thread-local stack: ``begin`` pushes, ``end`` pops, and with
+them what was begun above and never ended (an exception between a
+``begin`` and its ``end``). A span begun with ``annotate=False`` says
+nothing of what its thread is doing, so it never enters the stack and its
+record has no ``parent`` key at all. A span's self time is then its
+duration less its children's, from the timeline alone.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -87,14 +98,25 @@ def get_thread_replica() -> str | None:
     return getattr(_thread_ctx, "replica", None)
 
 
+def profiler_collecting() -> int:
+    """1 while a profiler session collects annotations (and, with the
+    profiler's defaults, every Python call of every thread): what a tick
+    stamps on itself so that a reader can leave it out. One atomic read."""
+    return int(TraceAnnotation is not None and TraceAnnotation.is_enabled())
+
+
+_span_ids = itertools.count(1)
+
+
 class _SpanHandle:
     """In-flight span state between ``begin`` and ``end``."""
 
     __slots__ = ("name", "component", "request_id", "lane", "t0", "attrs",
-                 "replica", "annotation", "done")
+                 "replica", "annotation", "done", "id", "thread", "parent",
+                 "stack")
 
     def __init__(self, name, component, request_id, lane, t0, attrs,
-                 replica=None, annotation=None):
+                 replica=None, annotation=None, stack=None):
         self.name = name
         self.component = component
         self.request_id = request_id
@@ -104,6 +126,16 @@ class _SpanHandle:
         self.replica = replica
         self.annotation = annotation
         self.done = False
+        self.id = next(_span_ids)
+        self.thread = threading.get_ident()
+        # the beginning thread's open spans, this one on top; None for a
+        # span that says nothing of what its thread is doing
+        self.stack = stack
+        self.parent = None
+        if stack is not None:
+            if stack:
+                self.parent = stack[-1].id
+            stack.append(self)
 
 
 def _annotate(name, component, request_id, lane, attrs):
@@ -129,7 +161,8 @@ class _ChromeEvents:
     """Span records -> Chrome-trace events: one complete ("X") event per
     span, pid = component, tid = lane (-1 = no lane), ts/dur in
     microseconds since the tracker epoch, and the process/thread name
-    ("M") events the first time a pid or a (pid, tid) appears."""
+    ("M") events the first time a pid or a (pid, tid) appears. `args`
+    holds the span's attributes and its `id`, `thread` and `parent`."""
 
     def __init__(self, pid_prefix: str | None = None, pid_base: int = 0):
         self._pid_prefix = pid_prefix
@@ -164,7 +197,10 @@ class _ChromeEvents:
                 "name": "thread_name",
                 "args": {"name": f"lane {tid}" if tid >= 0 else "no lane"},
             })
-        args = {"request_id": s["request_id"], **(s.get("attrs") or {})}
+        args = {"request_id": s["request_id"], **(s.get("attrs") or {}),
+                "id": s["id"], "thread": s["thread"]}
+        if "parent" in s:
+            args["parent"] = s["parent"]
         if s.get("replica") is not None:
             args["replica"] = s["replica"]
         out.append({
@@ -232,18 +268,25 @@ class SpanTracker:
         """Open a span; returns an opaque handle (or None when disabled —
         ``end(None)`` no-ops, so call sites never branch).
         ``annotate=False`` is for a span that ends on another thread, or
-        outlives the spans begun after it on its own. ``at`` is the
+        outlives the spans begun after it on its own: it opens no
+        annotation and is nobody's parent. ``at`` is the
         caller's own reading of the tracker's clock (the engine's
         dispatch helper reads it once for all it feeds)."""
         if not self.enabled:
             return None
-        annotation = None
-        if annotate and TraceAnnotation is not None:
-            annotation = _annotate(name, component, request_id, lane, attrs)
+        annotation = stack = None
+        if annotate:
+            stack = getattr(_thread_ctx, "stack", None)
+            if stack is None:
+                stack = _thread_ctx.stack = []
+            if TraceAnnotation is not None:
+                annotation = _annotate(
+                    name, component, request_id, lane, attrs
+                )
         return _SpanHandle(
             name, component, request_id, lane,
             self._clock() if at is None else at, attrs or None,
-            replica=get_thread_replica(), annotation=annotation,
+            replica=get_thread_replica(), annotation=annotation, stack=stack,
         )
 
     def end(self, handle: _SpanHandle | None, at: float | None = None,
@@ -256,6 +299,12 @@ class SpanTracker:
         t1 = self._clock() if at is None else at
         if handle.annotation is not None:
             handle.annotation.__exit__(None, None, None)
+        stack = handle.stack
+        if stack:
+            if stack[-1] is handle:
+                stack.pop()
+            elif handle in stack:  # with what was begun above it and never ended
+                del stack[stack.index(handle):]
         if attrs:
             handle.attrs = {**(handle.attrs or {}), **attrs}
         rec = {
@@ -265,7 +314,11 @@ class SpanTracker:
             "lane": handle.lane,
             "t0": handle.t0 - self._epoch,
             "dur_s": max(t1 - handle.t0, 0.0),
+            "id": handle.id,
+            "thread": handle.thread,
         }
+        if stack is not None:
+            rec["parent"] = handle.parent
         if handle.replica is not None:
             rec["replica"] = handle.replica
         if handle.attrs:

@@ -64,7 +64,11 @@ from ..obs.device import compare_with_analytic, sample_device_memory
 from ..obs.metrics import DEFAULT_TOKEN_BUCKETS_S, get_registry
 from ..obs.recorder import get_recorder
 from ..obs.slo import SloTracker, resolve_slo_knobs
-from ..obs.spans import get_span_tracker, set_thread_replica
+from ..obs.spans import (
+    get_span_tracker,
+    profiler_collecting,
+    set_thread_replica,
+)
 from ..obs.timeseries import (
     MetricsSampler,
     SeriesStore,
@@ -842,12 +846,15 @@ class LaneScheduler:
             # profiler trace the annotation's own start less mono_ns is
             # the offset between the two clocks, read once per tick, so a
             # reader places recorder events and --trace-out records on
-            # the device's axis and can check the offset for drift
+            # the device's axis and can check the offset for drift.
+            # profiled: a profiler session was collecting at the begin,
+            # so its Python tracer slows this tick and a reader of host
+            # time leaves it out
             spans = self.state.spans
             tick_sp = spans.begin(
                 "sched_tick", component="scheduler",
                 n_pending=n_pending, n_admitting=len(self.admitting),
-                mono_ns=time.monotonic_ns(),
+                mono_ns=time.monotonic_ns(), profiled=profiler_collecting(),
             )
             for lane, job in admissions:
                 with spans.span(

@@ -479,6 +479,16 @@ class InferenceEngine:
             "lane's bucket), parked_skipped = parked lanes' rows it left out.",
             labelnames=("rows",),
         )
+        self._m_drained = self.obs.counter(
+            "dllama_engine_device_drained_seconds_total",
+            "Seconds the device stood drained and waited for the host: from "
+            "the end of a read-back to the begin of the next dispatch that "
+            "is no pool copy, by the step dispatched.",
+            labelnames=("before",),
+        )
+        # the clock reading since which the device has had nothing to do;
+        # None while something enqueued has not been read back
+        self._drained_at = None
         self.cache = self._fresh_cache()
         g_bytes = self.obs.gauge(
             "dllama_kv_cache_bytes",
@@ -698,6 +708,10 @@ class InferenceEngine:
                 raise rebuild_err from e
             raise
 
+    # programs nobody reads back, and short (a millisecond of pages
+    # copied): to the drained interval one is host work like any other
+    _POOL_COPIES = frozenset(("kv_adopt", "kv_publish", "kv_page_copy"))
+
     @contextlib.contextmanager
     def _dispatch(self, step: str, prep=None, **fields):
         """Time one dispatch of a compiled program, once, for everything
@@ -707,11 +721,30 @@ class InferenceEngine:
         the yielded dict gets ``seconds``. ``prep`` is the caller's open
         ``dispatch_prep`` span, which ends where the dispatch begins; the
         read-back wait inside is ``_read_back``'s. A dispatch that raises
-        ends its span and completes nothing."""
+        ends its span and completes nothing.
+
+        The drained interval ends here too. Where ``_read_back`` left its
+        mark and nothing was enqueued since, the device has stood idle
+        from the mark to ``t0`` and the host is why: that is the span
+        ``device_drained`` (``before`` = this step), the counter
+        ``dllama_engine_device_drained_seconds_total{before}`` and
+        ``drained_ms`` on ``step_dispatch``. The first enqueue clears the
+        mark, so a dispatch behind an un-read one (a block behind a
+        chunk) records nothing; a pool copy neither ends the interval
+        nor clears the mark."""
         self._spans.end(prep)
-        self.recorder.record("step_dispatch", step=step, **fields)
-        timed = {}
         t0 = time.monotonic()
+        drained = {}
+        if self._drained_at is not None and step not in self._POOL_COPIES:
+            since, self._drained_at = self._drained_at, None
+            self._spans.end(self._spans.begin(
+                "device_drained", component="engine", annotate=False,
+                at=since, before=step,
+            ), at=t0)
+            self._m_drained.labels(before=step).inc(t0 - since)
+            drained = {"drained_ms": round((t0 - since) * 1000, 3)}
+        self.recorder.record("step_dispatch", step=step, **fields, **drained)
+        timed = {}
         sp = self._spans.begin(step, component="engine", at=t0, **fields)
         try:
             yield timed
@@ -773,9 +806,20 @@ class InferenceEngine:
         """The device-complete wait: the program call returned as soon as
         it was enqueued, and the read-back waits for the device. Its own
         ``<step>.device`` span, so a timeline splits dispatch overhead
-        from device time."""
-        with self._spans.span(f"{step}.device", component="engine"):
-            return np.asarray(out)
+        from device time. ``out`` is the newest program's output and the
+        device runs programs in the order they were enqueued, so at the
+        wait's end it is drained: the same clock reading ends the span
+        and marks that for the next ``_dispatch``."""
+        sp = self._spans.begin(f"{step}.device", component="engine")
+        try:
+            host = np.asarray(out)
+        except BaseException:
+            self._spans.end(sp, error=True)
+            raise
+        t1 = time.monotonic()
+        self._spans.end(sp, at=t1)
+        self._drained_at = t1
+        return host
 
     def _fault(self, op: str):
         """Chaos hook (runtime/faults.py): the armed fault for this
@@ -2915,7 +2959,7 @@ class InferenceEngine:
                 jnp.asarray(pos, jnp.int32),
                 jnp.asarray(active, jnp.bool_),
             )
-            out_np = np.asarray(out)
+            out_np = self._read_back("draft_step", out)
         if self._m_spec_draft_ms is not None:
             self._m_spec_draft_ms.labels(kind="propose").observe(
                 timed["seconds"] * 1000

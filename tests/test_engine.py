@@ -1492,3 +1492,80 @@ def test_weight_format_q40i8_is_refused_by_the_parser(capsys):
     args = _build_parser().parse_args(
         ["inference", "--model", "m.m", "--weight-format", "q40i4"])
     assert args.weight_format == "q40i4"
+
+
+# -- the drained interval (`_read_back` marks it, `_dispatch` closes it) ------
+#
+# Each case is a run of dispatches on the tiny preset under a clock that
+# advances one second a reading: the steps before which the device stands
+# drained.
+
+DRAINED_CASES = {
+    # a block behind an un-read chunk records nothing; between two blocks
+    # there is exactly one interval
+    "chunk_block_block": (["chunk", "block", "block"], ["decode_lanes"]),
+    # a pool copy onto a drained device is host work inside the interval
+    "block_publish_chunk_block": (
+        ["block", "publish", "chunk", "block"], ["prefill_lane_chunk"]),
+    "block_adopt_block": (["block", "adopt", "block"], ["decode_lanes"]),
+    # and behind an un-read chunk it leaves no mark
+    "block_chunk_publish_block": (
+        ["block", "chunk", "publish", "block"], ["prefill_lane_chunk"]),
+    "block_block_block": (["block"] * 3, ["decode_lanes"] * 2),
+}
+
+
+class _TickingTime:
+    """`time`, with a `monotonic` that advances a second a reading."""
+
+    def __init__(self):
+        self.t = 1000.0
+
+    def monotonic(self):
+        self.t += 1.0
+        return self.t
+
+    def __getattr__(self, name):
+        import time
+
+        return getattr(time, name)
+
+
+@pytest.mark.parametrize("case", list(DRAINED_CASES))
+def test_device_drained_between_a_read_back_and_the_next_dispatch(
+        slab_engine, monkeypatch, case):
+    import dllama_tpu.runtime.engine as engine_mod
+
+    e = slab_engine
+    calls, want = DRAINED_CASES[case]
+    do = {
+        "chunk": lambda: e.prefill_lane_chunk(1, list(range(1, 9)), 0),
+        "block": lambda: e.decode_lanes([5, 0], [0, 0], 4, active=[True, False]),
+        "publish": lambda: e.kv_publish(0, [1], start_page=0),
+        "adopt": lambda: e.kv_adopt(1, [1]),
+    }
+    for call in dict.fromkeys(calls):
+        do[call]()  # built outside the clock's reach
+    e.reset()
+    e._drained_at = None
+    monkeypatch.setattr(engine_mod, "time", _TickingTime())
+    n_spans, seq = e._spans.total_recorded, e.recorder.total_recorded
+    steps = set(want)
+    counted0 = {b: e._m_drained.labels(before=b).value for b in steps}
+    for call in calls:
+        do[call]()
+    spans = [s for s in e._spans.completed()[-(e._spans.total_recorded - n_spans):]
+             if s["name"] == "device_drained"]
+    assert [s["attrs"] for s in spans] == [{"before": b} for b in want]
+    assert all(s["component"] == "engine" and "parent" not in s for s in spans)
+    # the counter, the spans and `drained_ms` are one pair of clock readings
+    for b in steps:
+        assert e._m_drained.labels(before=b).value - counted0[b] == sum(
+            s["dur_s"] for s in spans if s["attrs"]["before"] == b)
+    events = [ev for ev in e.recorder.events("step_dispatch") if ev["seq"] > seq]
+    assert len(events) == len(calls)
+    assert [(ev["step"], ev["drained_ms"]) for ev in events if "drained_ms" in ev] == [
+        (s["attrs"]["before"], s["dur_s"] * 1000) for s in spans]
+    assert all(s["dur_s"] >= 1.0 and s["dur_s"] == int(s["dur_s"]) for s in spans)
+    completes = [ev for ev in e.recorder.events("step_complete") if ev["seq"] > seq]
+    assert all("drained_ms" not in ev for ev in completes)

@@ -228,6 +228,153 @@ def test_a_caller_may_read_the_clock_for_the_tracker():
     assert s["t0"] == 0.5 and s["dur_s"] == 0.25
 
 
+# -- which thread a span ran on, and which span it ran under -----------------
+
+
+def _nested(st):
+    with st.span("sched_tick", component="scheduler"):
+        with st.span("emit", component="scheduler"):
+            with st.span("finish", component="scheduler"):
+                pass
+    return {"sched_tick": None, "emit": "sched_tick", "finish": "emit"}
+
+
+def _siblings(st):
+    with st.span("sched_tick", component="scheduler"):
+        st.end(st.begin("step_prep", component="scheduler"))
+        st.end(st.begin("dispatch_prep"))
+    st.end(st.begin("sched_wait", component="scheduler"))
+    return {"sched_tick": None, "step_prep": "sched_tick",
+            "dispatch_prep": "sched_tick", "sched_wait": None}
+
+
+def _unstacked(st):
+    """A span begun with `annotate=False` is nobody's parent and has none,
+    whatever is open around it or begun while it is."""
+    with st.span("sched_tick", component="scheduler"):
+        decode = st.begin("decode", component="scheduler", annotate=False)
+        with st.span("emit", component="scheduler"):
+            st.end(st.begin("device_drained", annotate=False))
+        st.end(decode)
+    return {"sched_tick": None, "decode": None, "emit": "sched_tick",
+            "device_drained": None}
+
+
+def _raised(st):
+    """`span` ends in a `finally`; a bare `begin` whose `end` an exception
+    skipped leaves the stack with the span under it."""
+    with st.span("sched_tick", component="scheduler"):
+        with pytest.raises(RuntimeError):
+            with st.span("emit", component="scheduler"):
+                st.begin("finish", component="scheduler")  # never ended
+                raise RuntimeError("the body raised")
+        st.end(st.begin("step_prep", component="scheduler"))
+    st.end(st.begin("sched_wait", component="scheduler"))
+    return {"sched_tick": None, "emit": "sched_tick",
+            "step_prep": "sched_tick", "sched_wait": None}
+
+
+def _ended_late(st):
+    """An `end` that comes after the span's parent ended pops nothing."""
+    tick = st.begin("sched_tick", component="scheduler")
+    emit = st.begin("emit", component="scheduler")
+    st.end(tick)
+    st.end(emit)
+    st.end(st.begin("sched_wait", component="scheduler"))
+    return {"sched_tick": None, "emit": "sched_tick", "sched_wait": None}
+
+
+NESTING_CASES = {f.__name__.lstrip("_"): f for f in (
+    _nested, _siblings, _unstacked, _raised, _ended_late)}
+
+
+@pytest.mark.parametrize("case", list(NESTING_CASES))
+def test_a_span_says_its_thread_and_its_parent(case):
+    import threading
+
+    from dllama_tpu.obs import spans
+
+    st = SpanTracker(capacity=16, enabled=True)
+    want = NESTING_CASES[case](st)
+    done = {s["name"]: s for s in st.completed()}
+    assert set(done) == set(want)
+    ids = {s["id"]: s["name"] for s in done.values()}
+    assert len(ids) == len(done)
+    assert {n: ids.get(s.get("parent")) for n, s in done.items()} == want
+    # what never entered the stack says so by carrying no parent at all
+    assert {n for n, s in done.items() if "parent" not in s} == {
+        n for n in done if n in ("decode", "device_drained")}
+    assert {s["thread"] for s in done.values()} == {threading.get_ident()}
+    assert spans._thread_ctx.stack == []
+
+
+def test_a_span_of_another_thread_has_that_threads_parent():
+    import threading
+
+    st = SpanTracker(capacity=16, enabled=True)
+
+    def handler():
+        with st.span("sse_flush", component="http"):
+            pass
+        st.end(queue)  # begun there, ended here: its thread is the beginner's
+
+    with st.span("sched_tick", component="scheduler"):
+        queue = st.begin("queue", component="scheduler", annotate=False)
+        t = threading.Thread(target=handler)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    done = {s["name"]: s for s in st.completed()}
+    assert done["sse_flush"]["parent"] is None  # the tick is not its parent
+    assert done["sse_flush"]["thread"] == t.ident != threading.get_ident()
+    assert done["queue"]["thread"] == done["sched_tick"]["thread"] == threading.get_ident()
+
+
+def test_a_disabled_tracker_keeps_no_stack():
+    from dllama_tpu.obs import spans
+
+    def other_thread():
+        st = SpanTracker(capacity=4, enabled=False)
+        with st.span("sched_tick"):
+            assert st.begin("emit") is None
+        seen.append(hasattr(spans._thread_ctx, "stack"))
+
+    import threading
+
+    seen = []
+    t = threading.Thread(target=other_thread)
+    t.start()
+    t.join(timeout=10)
+    assert seen == [False]
+
+
+def test_the_sink_and_the_export_carry_thread_and_parent(tmp_path):
+    import threading
+
+    from dllama_tpu.obs.spans import read_timeline
+
+    st = SpanTracker(capacity=16, enabled=True)
+    path = os.path.join(tmp_path, "timeline.json")
+    st.set_sink(path)
+    with st.span("sched_tick", component="scheduler", mono_ns=5, profiled=0):
+        st.end(st.begin("device_drained", annotate=False, before="decode_lanes"))
+        with st.span("emit", component="scheduler"):
+            pass
+    st.set_sink(None)
+    _, streamed = read_timeline(path)
+    exported = [ev for ev in st.chrome_trace()["traceEvents"] if ev["ph"] == "X"]
+    assert streamed == exported
+    by_name = {ev["name"]: ev["args"] for ev in streamed}
+    assert {a["thread"] for a in by_name.values()} == {threading.get_ident()}
+    assert by_name["emit"]["parent"] == by_name["sched_tick"]["id"]
+    assert by_name["sched_tick"]["parent"] is None
+    assert "parent" not in by_name["device_drained"]
+    assert by_name["sched_tick"]["profiled"] == 0
+    # pid and tid stay the component and the lane
+    assert {ev["name"]: (ev["pid"], ev["tid"]) for ev in streamed} == {
+        "sched_tick": (1, -1), "emit": (1, -1), "device_drained": (2, -1)}
+
+
 def test_request_summary_coverage_and_phases():
     clk = FakeClock()
     st = SpanTracker(capacity=16, enabled=True, clock=clk)

@@ -103,7 +103,7 @@ def traced_run(tmp_path_factory):
     srv.shutdown()
     srv.server_close()
     return {"events": _scheduler_events(str(d / "profile")), "ring": ring,
-            "timeline": timeline, "tracker": spans}
+            "timeline": timeline, "tracker": spans, "engine": engine}
 
 
 def _inside(events, outer):
@@ -154,7 +154,8 @@ def test_leaf_spans_cover_the_scheduler_tick(traced_run):
     for tick in ticks:
         lo, hi = tick["t0"], tick["t0"] + tick["dur_s"]
         inner = sorted((max(s["t0"], lo), min(s["t0"] + s["dur_s"], hi)) for s in ring
-                       if s is not tick and s["name"] not in ("queue", "decode")
+                       if s is not tick
+                       and s["name"] not in ("queue", "decode", "device_drained")
                        and lo <= s["t0"] and s["t0"] + s["dur_s"] <= hi)
         covered, end = 0.0, lo
         for a, b in inner:
@@ -182,6 +183,78 @@ def test_streamed_timeline_holds_every_span_on_the_recorders_clock(traced_run):
     tick = next(s for s in spans if s["name"] == "sched_tick")
     start = meta["epoch_monotonic"] + tick["ts"] / 1e6
     assert abs(start - tick["args"]["mono_ns"] / 1e9) < 1e-3
+
+
+def test_streamed_timeline_nests_by_parent_off_the_profiler(traced_run):
+    """Every span says its thread and the span it ran under, so the
+    timeline nests without the profile: a child lies inside its parent on
+    its parent's thread, and a tick's self time is what its children leave.
+    A tick also says whether the profiler was collecting when it began."""
+    _, spans = read_timeline(traced_run["timeline"])
+    by_id = {s["args"]["id"]: s for s in spans}
+    assert len(by_id) == len(spans)
+    ticks = [s for s in spans if s["name"] == "sched_tick"]
+    (thread,) = {t["args"]["thread"] for t in ticks}
+    parents = {}
+    for s in spans:
+        parent = by_id.get(s["args"].get("parent"))
+        if parent is None:
+            assert s["args"].get("parent") is None
+            continue
+        assert parent["args"]["thread"] == s["args"]["thread"]
+        assert parent["ts"] - 0.01 <= s["ts"]
+        assert s["ts"] + s["dur"] <= parent["ts"] + parent["dur"] + 0.01
+        parents.setdefault(s["name"], set()).add(parent["name"])
+    assert parents["emit"] == parents["step_prep"] == {"sched_tick"}
+    assert parents["finish"] == {"emit"} and parents["decode_lanes.device"] == {"decode_lanes"}
+    assert "sched_tick" not in parents and "sched_wait" not in parents
+    # spans that end on another thread have a thread and no parent
+    assert all(("parent" in s["args"]) == (s["name"] not in ("queue", "decode", "device_drained"))
+               for s in spans)
+    self_us = [t["dur"] - sum(s["dur"] for s in spans if s["args"].get("parent") == t["args"]["id"])
+               for t in ticks]
+    assert all(us >= -0.01 for us in self_us)
+    # the warm-up request's ticks ran before the session, the streams' inside
+    profiled = [t["args"]["profiled"] for t in ticks]
+    assert set(profiled) == {0, 1} and profiled == sorted(profiled)
+    assert sum(profiled) >= 20 and thread != threading.get_ident()
+
+
+def test_drained_spans_lie_between_a_read_back_and_the_next_dispatch(traced_run):
+    """Served streams: every `device_drained` begins where a `.device` wait
+    ended and ends where the dispatch named by `before` begins, on the
+    scheduler's thread, and no dispatch but a pool copy lies inside it; the
+    counter holds their sum."""
+    _, spans = read_timeline(traced_run["timeline"])
+    (thread,) = {s["args"]["thread"] for s in spans if s["name"] == "sched_tick"}
+    drained = [s for s in spans if s["name"] == "device_drained"]
+    assert len(drained) >= 20
+    copies = {"kv_adopt", "kv_publish", "kv_page_copy"}
+    steps = {s["name"][: -len(".device")] for s in spans if s["name"].endswith(".device")}
+    assert "prefill_lane_chunk" not in steps | copies
+    dispatches = [s for s in spans if s["pid"] == 2
+                  and s["name"] in steps | copies | {"prefill_lane_chunk"}]
+    assert {"kv_publish", "kv_adopt"} <= {s["name"] for s in dispatches}
+    inside = set()
+    for d in drained:
+        assert d["args"]["thread"] == thread and d["pid"] == 2
+        lo, hi = d["ts"], d["ts"] + d["dur"]
+        assert any(abs(w["ts"] + w["dur"] - lo) < 0.01 for w in spans
+                   if w["name"].endswith(".device")), d
+        assert any(abs(s["ts"] - hi) < 0.01 and s["name"] == d["args"]["before"]
+                   for s in dispatches), d
+        within = {s["name"] for s in dispatches if lo - 0.01 <= s["ts"] < hi - 0.01}
+        assert within <= copies, (d, within)
+        inside |= within
+    assert "kv_publish" in inside  # a finished stream's pages, inside `emit`
+    # every block but the first of a burst follows a drained interval
+    blocks = [s for s in spans if s["name"] == "decode_lanes"]
+    assert len(drained) >= len(blocks) - 8
+    # the counter is the process's own: other engines may have added to it
+    for before in {d["args"]["before"] for d in drained}:
+        seconds = sum(d["dur"] for d in drained if d["args"]["before"] == before) / 1e6
+        counted = traced_run["engine"]._m_drained.labels(before=before).value
+        assert counted >= seconds - 1e-6 > 0
 
 
 @pytest.fixture(scope="module", params=["dense", "moe"])
