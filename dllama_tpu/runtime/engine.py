@@ -713,7 +713,7 @@ class InferenceEngine:
     _POOL_COPIES = frozenset(("kv_adopt", "kv_publish", "kv_page_copy"))
 
     @contextlib.contextmanager
-    def _dispatch(self, step: str, prep=None, **fields):
+    def _dispatch(self, step: str, prep=None, head=None, host_args=0, **fields):
         """Time one dispatch of a compiled program, once, for everything
         that wants it: the recorder's ``step_dispatch``/``step_complete``
         pair (``ms`` on the latter), the ``engine`` span named ``step``
@@ -722,6 +722,11 @@ class InferenceEngine:
         ``dispatch_prep`` span, which ends where the dispatch begins; the
         read-back wait inside is ``_read_back``'s. A dispatch that raises
         ends its span and completes nothing.
+
+        ``step_dispatch`` says what the host did before the call:
+        ``prep_ms`` from ``prep``'s begin (a method with no such span:
+        ``head``, its first clock reading) to ``t0``, and ``host_args``,
+        the host arrays ``_host_args`` handed over for the call.
 
         The drained interval ends here too. Where ``_read_back`` left its
         mark and nothing was enqueued since, the device has stood idle
@@ -734,7 +739,10 @@ class InferenceEngine:
         nor clears the mark."""
         self._spans.end(prep)
         t0 = time.monotonic()
-        drained = {}
+        host = {"host_args": host_args}
+        begun = prep.t0 if prep is not None else head
+        if begun is not None:
+            host["prep_ms"] = round((t0 - begun) * 1000, 3)
         if self._drained_at is not None and step not in self._POOL_COPIES:
             since, self._drained_at = self._drained_at, None
             self._spans.end(self._spans.begin(
@@ -742,8 +750,8 @@ class InferenceEngine:
                 at=since, before=step,
             ), at=t0)
             self._m_drained.labels(before=step).inc(t0 - since)
-            drained = {"drained_ms": round((t0 - since) * 1000, 3)}
-        self.recorder.record("step_dispatch", step=step, **fields, **drained)
+            host["drained_ms"] = round((t0 - since) * 1000, 3)
+        self.recorder.record("step_dispatch", step=step, **fields, **host)
         timed = {}
         sp = self._spans.begin(step, component="engine", at=t0, **fields)
         try:
@@ -798,9 +806,39 @@ class InferenceEngine:
 
     def _dispatch_prep(self, step: str):
         """Open the span of the host work before a lane dispatch: window
-        choice, prefetch, seed vector and the small ``jnp.asarray``
-        launches. ``_dispatch(prep=)`` ends it."""
+        choice, prefetch, seed vector and the host arrays of the call.
+        ``_dispatch(prep=)`` ends it."""
         return self._spans.begin("dispatch_prep", component="engine", step=step)
+
+    def _host_args(self, *arrays, tokens=None) -> tuple:
+        """The one way a dispatch's host values reach its program: numpy
+        arrays of the dtypes and shapes the program's arg specs state,
+        handed over as the call's own arguments, which moves them. No
+        ``jnp.asarray`` or ``jnp.int32``: each is a little program of its
+        own, launched while the device stands drained. ``tokens`` comes
+        first, as in every program's signature; its spec alone carries a
+        sharding, and on a mesh of more than one device it is placed by
+        it (a transfer, no program), as a lazily jitted program expects."""
+        if tokens is None:
+            return arrays
+        if self.mesh.size > 1:
+            tokens = jax.device_put(tokens, self._token_sharding)
+        return (tokens, *arrays)
+
+    def _page_table_arg(self) -> tuple:
+        """The paged programs' page table argument, which follows the
+        pool in their signatures (a copy: the host goes on writing its
+        mirror); nothing for the slab's programs."""
+        return (self._page_table.copy(),) if self.kv_native else ()
+
+    def _one_lane_chunk(self, lane: int, tokens, bucket: int, pos0: int, park: int):
+        """A chunk program's token rows and positions: ``tokens`` in
+        ``lane``'s row at ``pos0``, every other lane zeros at ``park``."""
+        rows = np.zeros((self.batch_size, bucket), np.int32)
+        rows[lane, : len(tokens)] = tokens
+        posv = np.full(self.batch_size, park, np.int32)
+        posv[lane] = pos0
+        return rows, posv
 
     def _read_back(self, step: str, out) -> np.ndarray:
         """The device-complete wait: the program call returned as soon as
@@ -1118,19 +1156,15 @@ class InferenceEngine:
 
         `token` may be a per-lane list (one independent sequence per batch
         lane, the dp axis); the return is then [n_steps][lanes]."""
+        head = time.monotonic()
         per_lane = isinstance(token, (list, tuple))
         n_steps = self._block_width(pos, n_steps)
         if n_steps <= 0:
             return []
-        if per_lane:
-            if len(token) != self.batch_size:
-                raise ValueError(
-                    f"{len(token)} lane tokens for batch_size {self.batch_size}"
-                )
-            arr = jnp.asarray([[t] for t in token], dtype=jnp.int32)
-        else:
-            arr = jnp.asarray([[token]] * self.batch_size, dtype=jnp.int32)
-        arr = jax.device_put(arr, self._token_sharding)
+        if per_lane and len(token) != self.batch_size:
+            raise ValueError(
+                f"{len(token)} lane tokens for batch_size {self.batch_size}"
+            )
         greedy = self.temperature == 0.0
         window = self._attn_window(pos + n_steps)
         self._note_window(window)
@@ -1150,17 +1184,21 @@ class InferenceEngine:
         rng = jax.random.fold_in(
             jax.random.fold_in(self._base_key, pos), self._rng_calls
         )
+        b = self.batch_size
+        arr, pos_arg, temperature, topp = self._host_args(
+            np.int32(pos),
+            np.float32(max(self.temperature, 1e-6)),
+            np.float32(self.sampler.topp),
+            tokens=np.asarray(
+                token if per_lane else [token] * b, np.int32
+            ).reshape(b, 1),
+        )
         with self._dispatch(
-            "decode_block", pos=pos, n_steps=n_steps, window=window
+            "decode_block", head=head, host_args=4,
+            pos=pos, n_steps=n_steps, window=window,
         ) as timed, self._cache_guard():
             out, self.cache = block(
-                self.params,
-                arr,
-                self.cache,
-                jnp.int32(pos),
-                rng,
-                jnp.float32(max(self.temperature, 1e-6)),
-                jnp.float32(self.sampler.topp),
+                self.params, arr, self.cache, pos_arg, rng, temperature, topp
             )
             out = self._read_back("decode_block", out)  # [n_steps, lanes]
         self._m_tpot.observe(timed["seconds"] / n_steps)
@@ -1240,24 +1278,21 @@ class InferenceEngine:
                 1.0 if (p + j + 1 < t and j < width) else 0.0
                 for j in range(bucket)
             ]
-            arr = jax.device_put(
-                jnp.asarray([chunk] * self.batch_size, jnp.int32),
-                self._token_sharding,
-            )
-            tgt = jax.device_put(
-                jnp.asarray([targets] * self.batch_size, jnp.int32),
-                self._token_sharding,
-            )
-            msk = jax.device_put(
-                jnp.asarray([mask] * self.batch_size, jnp.float32),
-                self._token_sharding,
+            arr, tgt, msk = (
+                jax.device_put(
+                    np.tile(np.asarray(row, dtype), (self.batch_size, 1)),
+                    self._token_sharding,
+                )
+                for row, dtype in (
+                    (chunk, np.int32), (targets, np.int32), (mask, np.float32)
+                )
             )
             score = self._score_fn(
                 bucket, window=self._attn_window(p + bucket)
             )
             with self._cache_guard():
                 part, self.cache = score(
-                    self.params, arr, tgt, msk, self.cache, jnp.int32(p)
+                    self.params, arr, tgt, msk, self.cache, np.int32(p)
                 )
                 nll_sum += float(np.asarray(part))
             p += width
@@ -1509,27 +1544,24 @@ class InferenceEngine:
         want = min(n, budget) if budget and budget > 0 else n
         bucket = self._bucket_for(want, pos0)
         width = min(bucket, want)
-        chunk = tokens[:width] + [0] * (bucket - width)
-        rows = [[0] * bucket for _ in range(self.batch_size)]
-        rows[lane] = chunk
         window = self._attn_window(pos0 + bucket)
         native = self.kv_native
-        # the paged view parks at `window` (its tail rows); the slab
-        # parks at seq_len (its padding rows)
-        posv = [window if native else self._park] * self.batch_size
-        posv[lane] = pos0
         step = (
             self._lane_prefill_paged_fn(bucket, window=window)
             if native
             else self._lane_prefill_fn(bucket, window=window)
         )
-        arr = jax.device_put(
-            jnp.asarray(rows, jnp.int32), self._token_sharding
+        # the paged view parks at `window` (its tail rows); the slab
+        # parks at seq_len (its padding rows)
+        rows, posv = self._one_lane_chunk(
+            lane, tokens[:width], bucket, pos0, window if native else self._park
         )
-        pos_arr = jnp.asarray(posv, jnp.int32)
-        table = jnp.asarray(self._page_table) if native else None
+        arr, *rest = self._host_args(
+            *self._page_table_arg(), posv, tokens=rows
+        )
         with self._dispatch(
-            "prefill_lane_chunk", prep, lane=lane, pos=pos0,
+            "prefill_lane_chunk", prep, host_args=1 + len(rest),
+            lane=lane, pos=pos0,
             n_tokens=width, bucket=bucket, window=window,
             **self._rows_in_context([pos0], width),
             **self._chunk_expert_rows(bucket),
@@ -1538,14 +1570,12 @@ class InferenceEngine:
                 with self._kv_pool_guard():
                     if fault is not None:
                         raise fault
-                    self.kv_pool = step(
-                        self.params, arr, self.kv_pool, table, pos_arr
-                    )
+                    self.kv_pool = step(self.params, arr, self.kv_pool, *rest)
             else:
                 with self._cache_guard():
                     if fault is not None:
                         raise fault
-                    self.cache = step(self.params, arr, self.cache, pos_arr)
+                    self.cache = step(self.params, arr, self.cache, *rest)
         return width
 
     def prefill_lane(self, lane: int, tokens: list[int], pos0: int = 0) -> None:
@@ -1897,6 +1927,7 @@ class InferenceEngine:
         matched token count hold the donor's stale tail; they are
         overwritten by suffix prefill before any query position can
         attend to them (the parked-row garbage argument)."""
+        head = time.monotonic()
         self._require_kv_pool()
         if not 0 <= lane < self.batch_size:
             raise ValueError(f"lane {lane} out of range")
@@ -1908,17 +1939,19 @@ class InferenceEngine:
         fault = self._fault("kv_adopt")
         if fault is not None and not fault.poison:
             raise fault
-        with self._dispatch("kv_adopt", lane=lane, n_pages=n):
-            for start, bucket in self._kv_copy_chunks(n):
+        chunks, ids = self._kv_copy_chunks(n), np.asarray(page_ids, np.int32)
+        with self._dispatch(
+            "kv_adopt", head=head, host_args=3 * len(chunks), lane=lane, n_pages=n
+        ):
+            for start, bucket in chunks:
                 fn = self._kv_copy_fn("adopt", bucket)
-                ids = jnp.asarray(page_ids[start : start + bucket], jnp.int32)
+                args = self._host_args(
+                    np.int32(lane), np.int32(start), ids[start : start + bucket]
+                )
                 with self._cache_guard():
                     if fault is not None:
                         raise fault
-                    self.cache = fn(
-                        self.cache, self.kv_pool,
-                        jnp.int32(lane), jnp.int32(start), ids,
-                    )
+                    self.cache = fn(self.cache, self.kv_pool, *args)
         self._m_kv_copy_bytes.inc(n * self._kv_page_bytes())
 
     def kv_publish(
@@ -1929,6 +1962,7 @@ class InferenceEngine:
         full-page KV becomes adoptable by every later admission. The
         caller dedups against the radix tree first, so only slots the
         tree does not already hold are written."""
+        head = time.monotonic()
         self._require_kv_pool()
         if not 0 <= lane < self.batch_size:
             raise ValueError(f"lane {lane} out of range")
@@ -1943,19 +1977,21 @@ class InferenceEngine:
         fault = self._fault("kv_publish")
         if fault is not None and not fault.poison:
             raise fault
+        chunks, ids = self._kv_copy_chunks(n), np.asarray(page_ids, np.int32)
         with self._dispatch(
-            "kv_publish", lane=lane, n_pages=n, start_page=start_page
+            "kv_publish", head=head, host_args=3 * len(chunks),
+            lane=lane, n_pages=n, start_page=start_page,
         ):
-            for off, bucket in self._kv_copy_chunks(n):
+            for off, bucket in chunks:
                 fn = self._kv_copy_fn("publish", bucket)
-                ids = jnp.asarray(page_ids[off : off + bucket], jnp.int32)
+                args = self._host_args(
+                    np.int32(lane), np.int32(start_page + off),
+                    ids[off : off + bucket],
+                )
                 with self._kv_pool_guard():
                     if fault is not None:
                         raise fault
-                    self.kv_pool = fn(
-                        self.cache, self.kv_pool,
-                        jnp.int32(lane), jnp.int32(start_page + off), ids,
-                    )
+                    self.kv_pool = fn(self.cache, self.kv_pool, *args)
         self._m_kv_copy_bytes.inc(n * self._kv_page_bytes())
 
     # -- pool-native paged programs (ISSUE 16) -------------------------------
@@ -2000,6 +2036,7 @@ class InferenceEngine:
 
     def kv_page_copy(self, src_ids: list[int], dst_ids: list[int]) -> None:
         """Copy pool pages ``src_ids[i]`` -> ``dst_ids[i]`` on device."""
+        head = time.monotonic()
         self._require_kv_pool()
         n = len(src_ids)
         if n < 1 or len(dst_ids) != n:
@@ -2007,15 +2044,20 @@ class InferenceEngine:
         fault = self._fault("kv_page_copy")
         if fault is not None and not fault.poison:
             raise fault
-        with self._dispatch("kv_page_copy", n_pages=n):
-            for start, bucket in self._kv_copy_chunks(n):
+        chunks = self._kv_copy_chunks(n)
+        src, dst = np.asarray(src_ids, np.int32), np.asarray(dst_ids, np.int32)
+        with self._dispatch(
+            "kv_page_copy", head=head, host_args=2 * len(chunks), n_pages=n
+        ):
+            for start, bucket in chunks:
                 fn = self._kv_page_copy_fn(bucket)
-                src = jnp.asarray(src_ids[start : start + bucket], jnp.int32)
-                dst = jnp.asarray(dst_ids[start : start + bucket], jnp.int32)
+                args = self._host_args(
+                    src[start : start + bucket], dst[start : start + bucket]
+                )
                 with self._kv_pool_guard():
                     if fault is not None:
                         raise fault
-                    self.kv_pool = fn(self.kv_pool, src, dst)
+                    self.kv_pool = fn(self.kv_pool, *args)
         self._m_kv_copy_bytes.inc(n * self._kv_page_bytes())
 
     def _paged_gather(self, pool, pt, window: int, tail: int):
@@ -2376,11 +2418,6 @@ class InferenceEngine:
         # the program's own predicate (_sample) engages on the same lanes,
         # less one that fills its window inside the block
         n_sampling = sum(1 for i in live if temperature[i] > 0.0)
-        arr = jax.device_put(
-            jnp.asarray([[t] for t in tokens], jnp.int32), self._token_sharding
-        )
-        pos_arr = jnp.asarray(pos, jnp.int32)
-        act_arr = jnp.asarray(active, jnp.bool_)
         deepest = max(pos[i] for i in live)
         window = self._attn_window(deepest + n_steps)
         self._note_window(window)
@@ -2425,30 +2462,28 @@ class InferenceEngine:
         fault = self._fault("decode_lanes")
         if fault is not None and not fault.poison:
             raise fault
-        sampling = (
-            jnp.asarray(seed_vec, jnp.int32),
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(topp, jnp.float32),
+        arr, *rest = self._host_args(
+            *self._page_table_arg(),
+            np.asarray(pos, np.int32),
+            np.asarray(active, np.bool_),
+            np.asarray(seed_vec, np.int32),
+            np.asarray(temperature, np.float32),
+            np.asarray(topp, np.float32),
+            tokens=np.asarray(tokens, np.int32).reshape(self.batch_size, 1),
         )
-        table = jnp.asarray(self._page_table) if native else None
         guard = self._kv_pool_guard if native else self._cache_guard
         with self._dispatch(
-            "decode_lanes", prep, pos=deepest, n_steps=n_steps,
+            "decode_lanes", prep, host_args=1 + len(rest),
+            pos=deepest, n_steps=n_steps,
             window=window, n_live=len(live), n_sampling=n_sampling,
             **self._rows_in_context([pos[i] for i in live], n_steps),
         ) as timed, guard():
             if fault is not None:
                 raise fault
             if native:
-                out, self.kv_pool = block(
-                    self.params, arr, self.kv_pool, table,
-                    pos_arr, act_arr, *sampling,
-                )
+                out, self.kv_pool = block(self.params, arr, self.kv_pool, *rest)
             else:
-                out, self.cache = block(
-                    self.params, arr, self.cache,
-                    pos_arr, act_arr, *sampling,
-                )
+                out, self.cache = block(self.params, arr, self.cache, *rest)
             out_np = self._read_back("decode_lanes", out)
         if self._counts_routing and not native:
             routed, held, touched = (
@@ -2605,30 +2640,26 @@ class InferenceEngine:
                             t, nw, origin="prefetch"
                         ),
                 )
-        arr = jax.device_put(
-            jnp.asarray(rows, jnp.int32), self._token_sharding
-        )
-        pos_arr = jnp.asarray(pos, jnp.int32)
-        act_arr = jnp.asarray(active, jnp.bool_)
         fault = self._fault("verify_lanes")
         if fault is not None and not fault.poison:
             raise fault
-        table = jnp.asarray(self._page_table) if native else None
+        arr, *rest = self._host_args(
+            *self._page_table_arg(),
+            np.asarray(pos, np.int32),
+            np.asarray(active, np.bool_),
+            tokens=np.asarray(rows, np.int32),
+        )
         guard = self._kv_pool_guard if native else self._cache_guard
         with self._dispatch(
-            "verify_lanes", prep, pos=deepest, t=t, window=window,
-            n_live=len(live),
+            "verify_lanes", prep, host_args=1 + len(rest),
+            pos=deepest, t=t, window=window, n_live=len(live),
         ), guard():
             if fault is not None:
                 raise fault
             if native:
-                out, self.kv_pool = vstep(
-                    self.params, arr, self.kv_pool, table, pos_arr, act_arr
-                )
+                out, self.kv_pool = vstep(self.params, arr, self.kv_pool, *rest)
             else:
-                out, self.cache = vstep(
-                    self.params, arr, self.cache, pos_arr, act_arr
-                )
+                out, self.cache = vstep(self.params, arr, self.cache, *rest)
             out_np = self._read_back("verify_lanes", out)
         return [[int(x) for x in row] for row in out_np]
 
@@ -2897,19 +2928,12 @@ class InferenceEngine:
         while fills:
             bucket = self._draft_bucket_for(len(fills), p)
             width = min(bucket, len(fills))
-            chunk = fills[:width] + [0] * (bucket - width)
-            rows = [[0] * bucket for _ in range(self.batch_size)]
-            rows[lane] = chunk
-            posv = [park] * self.batch_size
-            posv[lane] = p
             step = self._draft_prefill_fn(bucket)
-            arr = jax.device_put(
-                jnp.asarray(rows, jnp.int32), self._token_sharding
-            )
+            rows, posv = self._one_lane_chunk(lane, fills[:width], bucket, p, park)
+            arr, posv = self._host_args(posv, tokens=rows)
             with self._draft_cache_guard():
                 self.draft_cache = step(
-                    self._draft_params, arr, self.draft_cache,
-                    jnp.asarray(posv, jnp.int32),
+                    self._draft_params, arr, self.draft_cache, posv
                 )
             fills = fills[width:]
             p += width
@@ -2936,6 +2960,7 @@ class InferenceEngine:
         returned token goes through the target's verify pass, so this
         can be wrong, stale, or truncated without any correctness
         cost."""
+        head = time.monotonic()
         self._require_draft_model()
         if len(tokens) != self.batch_size or len(pos) != self.batch_size:
             raise ValueError("tokens/pos must have one entry per lane")
@@ -2947,17 +2972,17 @@ class InferenceEngine:
         if k <= 0:
             return []
         block = self._draft_step_fn(k)
-        arr = jax.device_put(
-            jnp.asarray([[t] for t in tokens], jnp.int32),
-            self._token_sharding,
+        arr, *rest = self._host_args(
+            np.asarray(pos, np.int32),
+            np.asarray(active, np.bool_),
+            tokens=np.asarray(tokens, np.int32).reshape(self.batch_size, 1),
         )
         with self._dispatch(
-            "draft_step", n_steps=k, n_live=len(live)
+            "draft_step", head=head, host_args=1 + len(rest),
+            n_steps=k, n_live=len(live),
         ) as timed, self._draft_cache_guard():
             out, self.draft_cache = block(
-                self._draft_params, arr, self.draft_cache,
-                jnp.asarray(pos, jnp.int32),
-                jnp.asarray(active, jnp.bool_),
+                self._draft_params, arr, self.draft_cache, *rest
             )
             out_np = self._read_back("draft_step", out)
         if self._m_spec_draft_ms is not None:
@@ -3025,23 +3050,21 @@ class InferenceEngine:
         while fills[0]:
             bucket = self._bucket_for(len(fills[0]), p)
             width = min(bucket, len(fills[0]))
-            padded = [
-                fill[:width] + [0] * (bucket - width) for fill in fills
-            ]
+            head = time.monotonic()
+            padded = np.zeros((len(fills), bucket), np.int32)
+            padded[:, :width] = [fill[:width] for fill in fills]
             fills = [fill[width:] for fill in fills]
-            arr = jnp.asarray(padded, dtype=jnp.int32)
-            arr = jax.device_put(arr, self._token_sharding)
+            arr, pos_arg = self._host_args(np.int32(p), tokens=padded)
             window = self._attn_window(p + bucket)
             step = self._step_fn(bucket, greedy=False, window=window)
             # Padding tokens write garbage into cache slots [p+width,
             # p+bucket) — harmless: the causal mask hides them until real
             # tokens overwrite those positions.
             with self._dispatch(
-                "prefill", pos=p, bucket=bucket, window=window
+                "prefill", head=head, host_args=2,
+                pos=p, bucket=bucket, window=window,
             ) as timed, self._cache_guard():
-                _, self.cache = step(
-                    self.params, arr, self.cache, jnp.int32(p)
-                )
+                _, self.cache = step(self.params, arr, self.cache, pos_arg)
                 jax.block_until_ready(self.cache)
             total_ms += timed["seconds"] * 1000
             p += width
@@ -3062,15 +3085,17 @@ class InferenceEngine:
                 f"decode position {pos} out of range (seqLen "
                 f"{self.header.seq_len}); the KV cache would clamp silently"
             )
-        arr = jnp.asarray([[token]] * self.batch_size, dtype=jnp.int32)
-        arr = jax.device_put(arr, self._token_sharding)
+        head = time.monotonic()
+        arr, pos_arg = self._host_args(
+            np.int32(pos), tokens=np.full((self.batch_size, 1), token, np.int32)
+        )
         greedy = self.temperature == 0.0
         window = self._attn_window(pos + 1)
         step = self._step_fn(1, greedy=greedy, window=window)
         with self._dispatch(
-            "decode_step", pos=pos, window=window
+            "decode_step", head=head, host_args=2, pos=pos, window=window
         ) as timed, self._cache_guard():
-            out, self.cache = step(self.params, arr, self.cache, jnp.int32(pos))
+            out, self.cache = step(self.params, arr, self.cache, pos_arg)
             out = jax.block_until_ready(out)
         ms = timed["seconds"] * 1000
         if greedy:

@@ -1,6 +1,7 @@
 """Engine + CLI tests: generation invariants and the dllama-compatible
 command surface."""
 
+import contextlib
 import os
 import subprocess
 import sys
@@ -1569,3 +1570,192 @@ def test_device_drained_between_a_read_back_and_the_next_dispatch(
     assert all(s["dur_s"] >= 1.0 and s["dur_s"] == int(s["dur_s"]) for s in spans)
     completes = [ev for ev in e.recorder.events("step_complete") if ev["seq"] > seq]
     assert all("drained_ms" not in ev for ev in completes)
+
+
+# -- a dispatch hands its host arguments over in the call ----------------------
+#
+# Between a method's `dispatch_prep` (a pool copy: its head) and its program
+# call nothing is launched on the device: no `jnp.asarray`, no `jnp.int32(..)`
+# (each a little program of its own) and, on one device, no `device_put`.
+
+
+@contextlib.contextmanager
+def _watched(e, monkeypatch):
+    """For the block's duration: the programs `e` calls, by key with their
+    arguments; the eager constructors of `jax.numpy` that were called; the
+    shardings `device_put` was asked for."""
+    seen = {"programs": [], "eager": [], "puts": []}
+
+    def noting(what, note, real):
+        def call(*a, **k):
+            seen[what].append(note(*a, **k))
+            return real(*a, **k)
+
+        return call
+
+    with monkeypatch.context() as m:
+        for key, fn in list(e._compiled.items()):
+            m.setitem(e._compiled, key, noting(
+                "programs", lambda *a, _k=key: (_k, a), fn))
+        for name in ("asarray", "array", "zeros", "ones", "full", "arange"):
+            m.setattr(jnp, name, noting(
+                "eager", lambda *a, _n=name, **k: _n, getattr(jnp, name)))
+        scalar = type(jnp.int32)  # jnp.int32(3) is an eager launch too
+        m.setattr(scalar, "__call__", noting(
+            "eager", lambda self, *a, **k: str(self), scalar.__call__))
+        m.setattr(jax, "device_put", noting(
+            "puts", lambda x, device=None, **k: device, jax.device_put))
+        yield seen
+
+
+N_PAGES = 7  # three power-of-two buckets: 4, 2, 1
+HANDED_OVER = {
+    # method -> (call, program keys, host arrays a program)
+    "decode_lanes": (
+        lambda e: e.decode_lanes([5, 0], [0, 0], BLOCK, active=[True, False]),
+        [("lane_block", BLOCK, 512)], 6),
+    "prefill_lane_chunk": (
+        lambda e: e.prefill_lane_chunk(1, list(range(1, 8)), 0),
+        [("lane_prefill", 8, 512)], 2),
+    "verify_lanes": (
+        lambda e: e.verify_lanes([[5, 6, 7], [0, 0, 0]], [0, 0], [True, False]),
+        [("lane_verify", 3, 512)], 3),
+    "kv_adopt": (
+        lambda e: e.kv_adopt(1, list(range(1, 1 + N_PAGES))),
+        [("kv_adopt", b) for b in (4, 2, 1)], 3),
+    "kv_publish": (
+        lambda e: e.kv_publish(0, list(range(1, 1 + N_PAGES)), start_page=2),
+        [("kv_publish", b) for b in (4, 2, 1)], 3),
+    "kv_page_copy": (
+        lambda e: e.kv_page_copy(list(range(1, 1 + N_PAGES)),
+                                 list(range(11, 11 + N_PAGES))),
+        [("kv_page_copy", b) for b in (4, 2, 1)], 2),
+}
+
+
+@pytest.mark.parametrize("method", list(HANDED_OVER))
+def test_a_step_method_launches_its_programs_and_nothing_else(
+        slab_engine, monkeypatch, method):
+    """After warm-up, one call of a step method runs its step program(s)
+    and no other device program: every host value reaches the program as
+    a numpy array of the spec's dtype, as the call's own argument."""
+    e = slab_engine
+    call, keys, n_host = HANDED_OVER[method]
+    call(e)  # builds what it needs
+    e.reset()
+    seq = e.recorder.total_recorded
+    with _watched(e, monkeypatch) as seen:
+        call(e)
+    assert seen["eager"] == []
+    assert [k for k, _ in seen["programs"]] == keys
+    assert seen["puts"] == []  # one device: the call moves the tokens too
+    for _, args in seen["programs"]:
+        host = [a for a in args if not isinstance(a, dict)]
+        assert len(host) == n_host
+        assert all(isinstance(a, (np.ndarray, np.generic)) for a in host)
+        assert {str(a.dtype) for a in host} <= {"int32", "bool", "float32"}
+    dispatch, = [ev for ev in e.recorder.events("step_dispatch") if ev["seq"] > seq]
+    assert dispatch["step"] == method
+    assert dispatch["host_args"] == n_host * len(keys)
+
+
+def test_a_chunks_token_rows_are_built_without_a_python_list(
+        slab_engine, monkeypatch):
+    """A chunk's `lanes x bucket` token array is zeros with one row
+    assigned, never a nested Python list, and equals the parent's."""
+    e = slab_engine
+    lane, tokens, bucket = 1, list(range(3, 10)), 8
+    rows = [[0] * bucket for _ in range(e.batch_size)]  # the parent's lines
+    rows[lane] = tokens + [0] * (bucket - len(tokens))
+    posv = [e._park] * e.batch_size
+    posv[lane] = 5
+    handed = []
+    real = e._host_args
+    monkeypatch.setattr(e, "_host_args", lambda *a, tokens=None: (
+        handed.append((tokens, a)), real(*a, tokens=tokens))[1])
+    assert e.prefill_lane_chunk(lane, tokens, 5) == len(tokens)
+    (got_rows, (got_pos,)), = handed
+    assert isinstance(got_rows, np.ndarray) and isinstance(got_pos, np.ndarray)
+    assert got_rows.dtype == got_pos.dtype == np.int32
+    np.testing.assert_array_equal(got_rows, np.asarray(rows, np.int32))
+    np.testing.assert_array_equal(got_pos, np.asarray(posv, np.int32))
+    e.reset()
+
+
+STEP_KINDS = {
+    # step -> (call, host arrays)
+    "decode_lanes": (HANDED_OVER["decode_lanes"][0], 6),
+    "prefill_lane_chunk": (HANDED_OVER["prefill_lane_chunk"][0], 2),
+    "verify_lanes": (HANDED_OVER["verify_lanes"][0], 3),
+    "kv_adopt": (lambda e: e.kv_adopt(1, [1, 2, 3]), 6),
+    "kv_publish": (lambda e: e.kv_publish(0, [1], start_page=0), 3),
+    "kv_page_copy": (lambda e: e.kv_page_copy([1, 2], [3, 4]), 2),
+    "decode_block": (lambda e: e.decode_block([5, 6], 0, 2), 4),
+    "decode_step": (lambda e: e.decode_step(5, 0), 2),
+    "prefill": (lambda e: e.prefill([1, 2, 3, 4]), 2),
+}
+# the pool-native programs take the page table too; the draft's are the slab's
+NATIVE_STEP_KINDS = {
+    "decode_lanes": (HANDED_OVER["decode_lanes"][0], 7),
+    "prefill_lane_chunk": (HANDED_OVER["prefill_lane_chunk"][0], 3),
+    "verify_lanes": (HANDED_OVER["verify_lanes"][0], 4),
+    "draft_step": (
+        lambda e: e.draft_propose([5, 0], [0, 0], [True, False], 2), 3),
+}
+
+
+def _dispatch_says_prep_and_host_arrays(e, step, call, n_host):
+    seq = e.recorder.total_recorded
+    call(e)
+    e.reset()
+    dispatch = [ev for ev in e.recorder.events("step_dispatch") if ev["seq"] > seq]
+    assert [ev["step"] for ev in dispatch] == [step]
+    assert dispatch[0]["host_args"] == n_host
+    assert dispatch[0]["prep_ms"] >= 0.0
+    completes = [ev for ev in e.recorder.events("step_complete") if ev["seq"] > seq]
+    assert [ev["step"] for ev in completes] == [step]
+    assert "prep_ms" not in completes[0] and "host_args" not in completes[0]
+
+
+@pytest.mark.parametrize("step", list(STEP_KINDS))
+def test_step_dispatch_says_its_prep_and_its_host_arrays(slab_engine, step):
+    """Every step kind's `step_dispatch` carries `prep_ms` (from the
+    method's `dispatch_prep`, or its head, to the dispatch's begin) and
+    `host_args`; `step_complete` carries neither."""
+    _dispatch_says_prep_and_host_arrays(slab_engine, step, *STEP_KINDS[step])
+
+
+@pytest.mark.parametrize("step", list(NATIVE_STEP_KINDS))
+def test_paged_step_dispatch_says_its_prep_and_its_host_arrays(build_engine, step):
+    e = build_engine
+    for lane in range(e.batch_size):  # rows 0-15 of every lane on its own pages
+        e.adopt_pages(lane, [1 + 4 * lane + i for i in range(4)])
+    _dispatch_says_prep_and_host_arrays(e, step, *NATIVE_STEP_KINDS[step])
+
+
+def test_prep_ms_is_the_prep_spans_begin_to_the_dispatchs(slab_engine, monkeypatch):
+    """`prep_ms` is made of readings the spans take: `dispatch_prep`'s
+    begin to the step span's begin; a pool copy's, its head to its
+    span's begin, and it neither carries `drained_ms` nor clears the
+    mark a read-back left."""
+    import dllama_tpu.runtime.engine as engine_mod
+
+    e = slab_engine
+    HANDED_OVER["decode_lanes"][0](e)
+    e.kv_publish(0, [1], start_page=0)
+    e.reset()
+    monkeypatch.setattr(engine_mod, "time", _TickingTime())
+    n_spans, seq = e._spans.total_recorded, e.recorder.total_recorded
+    HANDED_OVER["decode_lanes"][0](e)
+    e.kv_publish(0, [1], start_page=0)
+    assert e._drained_at is not None
+    spans = {s["name"]: s for s in
+             e._spans.completed()[-(e._spans.total_recorded - n_spans):]}
+    block, copy = [ev for ev in e.recorder.events("step_dispatch") if ev["seq"] > seq]
+    # `e._spans` keeps its own clock: only the engine's readings tick
+    assert block["prep_ms"] == round(
+        (spans["decode_lanes"]["t0"] - spans["dispatch_prep"]["t0"]) * 1000, 3)
+    assert copy["step"] == "kv_publish" and copy["prep_ms"] == 1000.0
+    assert "drained_ms" not in copy
+    e.reset()
+    e._drained_at = None

@@ -677,3 +677,48 @@ def test_measure_sync_ms_collectives():
         _pytest.skip("profiler trace unavailable on this backend")
     assert ms_with > 0.0
     assert (ms_without or 0.0) <= ms_with
+
+
+@pytest.mark.parametrize("aot", [True, False], ids=["aot", "lazy_jit"])
+def test_lane_tokens_reach_a_two_device_program_with_their_sharding(
+        tmp_path, monkeypatch, aot):
+    """On a mesh of two devices a lane dispatch's token array reaches its
+    program placed by `_token_sharding`, as the program's arg spec states
+    it, whether the program was compiled ahead of time against that spec
+    or is lazily jitted against the array; the rest are host arrays, and
+    the stream is the one-device stream."""
+    from dllama_tpu.runtime.engine import InferenceEngine
+
+    path = str(tmp_path / "m.m")
+    cfg = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
+               head_dim=16, vocab_size=256, seq_len=64)
+    make_tiny_model(path, weight_type=FloatType.F32, cfg=cfg)
+    if not aot:
+        monkeypatch.setenv("DLLAMA_WINDOW_PRECOMPILE", "0")
+    prompts = [[1, 2, 3, 4], [9, 8, 7, 6, 5, 4]]
+
+    def stream(e):
+        for lane, p in enumerate(prompts):
+            assert e.prefill_lane_chunk(lane, p[:-1], 0) == len(p) - 1
+        return e.decode_lanes(
+            [p[-1] for p in prompts], [len(p) - 1 for p in prompts], 6)
+
+    kw = dict(tp=1, dtype=jnp.float32, temperature=0.0, batch_size=2,
+              prefill_buckets=(8,))
+    want = stream(InferenceEngine(path, **kw))
+    e = InferenceEngine(path, dp=2, **kw)
+    assert e._aot_blocks is aot
+    assert len(e._token_sharding.device_set) == 2
+    stream(e)  # builds the programs
+    e.reset()
+    seen = []
+    for key, fn in list(e._compiled.items()):
+        monkeypatch.setitem(e._compiled, key, lambda *a, _k=key, _f=fn: (
+            seen.append((_k[0], a[1], a[3:])), _f(*a))[1])
+    assert stream(e) == want
+    assert [kind for kind, _, _ in seen] == ["lane_prefill"] * 2 + ["lane_block"]
+    for _, tokens, rest in seen:
+        assert isinstance(tokens, jax.Array) and tokens.committed
+        assert tokens.sharding == e._token_sharding
+        assert {d.id for d in tokens.devices()} == {d.id for d in e.mesh.devices.flat}
+        assert all(isinstance(a, np.ndarray) for a in rest)
