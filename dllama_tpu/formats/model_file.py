@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import math
 import struct
 from typing import Iterator
 
@@ -55,6 +56,12 @@ class LlmArch(enum.IntEnum):
     # sandwich norms, a sigmoid router with a shared expert after leading
     # dense layers (`model_type: pangu_ultra_moe`)
     PANGU_MOE = 0xABCD11
+    # latent attention whose queries attend to the `index_topk` rows a
+    # learned index picks (a second cache stack of index keys), a sigmoid
+    # router with a selection bias and a group limit, one norm before each
+    # block, a rotary table scaled by frequency band
+    # (`model_type: deepseek_v32`)
+    DEEPSEEK_V32 = 0xABCD12
 
 
 class RopeType(enum.IntEnum):
@@ -63,6 +70,7 @@ class RopeType(enum.IntEnum):
     LLAMA = 0  # interleaved pairs (x[2i], x[2i+1])
     FALCON = 1  # half-rotation (x[j], x[j + headDim/2])
     LLAMA3_1 = 2  # interleaved + llama-3.1 frequency scaling
+    YARN = 3  # interleaved + frequencies scaled by band (`rope_scaling.type: yarn`)
 
 
 class HiddenAct(enum.IntEnum):
@@ -116,6 +124,18 @@ class HeaderKey(enum.IntEnum):
     QK_NOPE_HEAD_DIM = 35  # a head's query and key columns that take no rope
     QK_ROPE_HEAD_DIM = 36  # a head's query columns, and the one shared key's, that do
     V_HEAD_DIM = 37  # a head's value width
+    # a learned index over the latent cache (0: none, every row is attended to)
+    INDEX_N_HEADS = 38  # heads of the index
+    INDEX_HEAD_DIM = 39  # width of an index head, and of the cached index key
+    INDEX_TOPK = 40  # rows a query attends to: the best by index score; > 0 adds the `i` stack
+    # a group limit on the router's selection (0: none, one group of all)
+    N_GROUP = 41  # the routed experts lie in this many groups of equal size
+    TOPK_GROUP = 42  # groups a token's experts may come from
+    # what RopeType.YARN needs beyond keys 14 (factor) and 17 (original length)
+    ROPE_BETA_FAST = 43  # rotations over the original length above which a band keeps its frequency
+    ROPE_BETA_SLOW = 44  # rotations below which a band's frequency is divided by the factor
+    ROPE_MSCALE_MILLI = 45  # `mscale`, in thousandths
+    ROPE_MSCALE_ALL_DIM_MILLI = 46  # `mscale_all_dim`, in thousandths
 
 
 @dataclasses.dataclass
@@ -161,6 +181,15 @@ class LlmHeader:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.0
+    rope_mscale_all_dim: float = 0.0
     header_bytes: int = 0
     file_size: int = 0
     sync_type: FloatType = FloatType.Q80
@@ -184,6 +213,21 @@ class LlmHeader:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def indexed(self) -> bool:
+        """A learned index picks the rows a latent layer's query attends to."""
+        return self.index_topk > 0
+
+    @property
+    def softmax_scale(self) -> float:
+        """What attention scores are multiplied by: 1 / sqrt(head_dim), and
+        under a rotary table scaled by band the square of the magnitude
+        factor that goes with it."""
+        scale = float(self.head_dim) ** -0.5
+        if self.rope_type == RopeType.YARN and self.rope_mscale_all_dim:
+            scale *= yarn_mscale(self.rope_scaling_factor, self.rope_mscale_all_dim) ** 2
+        return scale
+
+    @property
     def rope_dim(self) -> int:
         """Columns of a head that the rotary embedding turns."""
         return self.qk_rope_head_dim if self.latent else self.head_dim
@@ -191,13 +235,26 @@ class LlmHeader:
     @property
     def ff_dim(self) -> int:
         """Per-expert (MoE) or dense FFN intermediate dim (src/llm.cpp:152-157)."""
-        if self.arch in (LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE):
+        if self.arch in _WIDE_DENSE or self.arch == LlmArch.QWEN3_MOE:
             return self.moe_hidden_dim
         return self.hidden_dim
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+
+# leading dense layers HIDDEN_DIM wide beside experts of MOE_HIDDEN_DIM
+_WIDE_DENSE = (LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32)
+# one more norm after each block
+_SANDWICH = (LlmArch.AFMOE, LlmArch.PANGU_MOE)
+# a selection bias beside the router
+_EXPERT_BIAS = (LlmArch.AFMOE, LlmArch.DEEPSEEK_V32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """The magnitude factor of a rotary table scaled by `factor`."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
 
 
 def _norm_epsilon(value: int) -> float:
@@ -302,6 +359,24 @@ def read_llm_header(
                 h.qk_rope_head_dim = value
             elif key == HeaderKey.V_HEAD_DIM:
                 h.v_head_dim = value
+            elif key == HeaderKey.INDEX_N_HEADS:
+                h.index_n_heads = value
+            elif key == HeaderKey.INDEX_HEAD_DIM:
+                h.index_head_dim = value
+            elif key == HeaderKey.INDEX_TOPK:
+                h.index_topk = value
+            elif key == HeaderKey.N_GROUP:
+                h.n_group = value or 1
+            elif key == HeaderKey.TOPK_GROUP:
+                h.topk_group = value or 1
+            elif key == HeaderKey.ROPE_BETA_FAST:
+                h.rope_beta_fast = float(value)
+            elif key == HeaderKey.ROPE_BETA_SLOW:
+                h.rope_beta_slow = float(value)
+            elif key == HeaderKey.ROPE_MSCALE_MILLI:
+                h.rope_mscale = value / 1000.0
+            elif key == HeaderKey.ROPE_MSCALE_ALL_DIM_MILLI:
+                h.rope_mscale_all_dim = value / 1000.0
 
         if weight_type is None:
             raise ValueError("model does not specify weight type")
@@ -327,8 +402,18 @@ def read_llm_header(
     h.sync_type = sync_type
     if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE):
         h.rope_type = RopeType.FALCON
+    if h.indexed and not (h.latent and h.index_n_heads and h.index_head_dim >= h.rope_dim):
+        raise ValueError(
+            "an index (index_topk > 0) needs latent attention, index_n_heads "
+            "and an index_head_dim of at least the rope columns"
+        )
     if h.n_routed_experts == 0:
         h.n_routed_experts = h.n_experts
+    if not (1 <= h.topk_group <= h.n_group
+            and (h.n_routed_experts or h.n_group) % h.n_group == 0):
+        raise ValueError(
+            f"{h.topk_group} of {h.n_group} groups over {h.n_routed_experts} routed experts"
+        )
     if not 0 <= h.first_expert <= h.n_routed_experts - h.n_experts:
         raise ValueError(
             f"experts [{h.first_expert}, {h.first_expert + h.n_experts}) held "
@@ -417,8 +502,7 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
         offset += nbytes
 
     afmoe = h.arch == LlmArch.AFMOE
-    # sandwich norms, and leading dense layers HIDDEN_DIM wide beside experts
-    sandwich = afmoe or h.arch == LlmArch.PANGU_MOE
+    sandwich = h.arch in _SANDWICH
 
     def swiglu(prefix: str, width: int) -> None:
         add(f"{prefix}.w1", wt, (width, h.dim))
@@ -439,6 +523,16 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
             add(f"layers.{l}.kv_a_norm", FloatType.F32, (h.kv_lora_rank,))
             add(f"layers.{l}.wkv_b", wt, (h.n_heads * per_head, h.kv_lora_rank))
             add(f"layers.{l}.wo", wt, (h.dim, h.n_heads * h.v_head_dim))
+            if h.indexed:
+                # the index: its queries from the query's latent, one key a
+                # position from the layer's input (LayerNorm: weight and
+                # bias), and a weight a head from the same input
+                add(f"layers.{l}.idx_wq_b", wt,
+                    (h.index_n_heads * h.index_head_dim, h.q_lora_rank))
+                add(f"layers.{l}.idx_wk", wt, (h.index_head_dim, h.dim))
+                add(f"layers.{l}.idx_k_norm", FloatType.F32, (h.index_head_dim,))
+                add(f"layers.{l}.idx_k_bias", FloatType.F32, (h.index_head_dim,))
+                add(f"layers.{l}.idx_w", FloatType.F32, (h.index_n_heads, h.dim))
         else:
             add(f"layers.{l}.q", wt, (h.q_dim, h.dim))
             add(f"layers.{l}.k", wt, (h.kv_dim, h.dim))
@@ -448,14 +542,14 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
             add(f"layers.{l}.att_gate", wt, (h.q_dim, h.dim))
         if kind.experts:
             add(f"layers.{l}.moe_gate", FloatType.F32, (h.n_routed_experts, h.dim))
-            if afmoe:
+            if h.arch in _EXPERT_BIAS:
                 add(f"layers.{l}.expert_bias", FloatType.F32, (h.n_routed_experts,))
             if h.n_shared_experts:
                 swiglu(f"layers.{l}.shared", h.n_shared_experts * h.ff_dim)
             for e in range(h.n_experts):
                 swiglu(f"layers.{l}.experts.{e}", h.ff_dim)
         else:  # leading dense layers are HIDDEN_DIM wide beside experts of ff_dim
-            swiglu(f"layers.{l}", h.hidden_dim if sandwich else h.ff_dim)
+            swiglu(f"layers.{l}", h.hidden_dim if h.arch in _WIDE_DENSE else h.ff_dim)
         if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE):
             add(f"layers.{l}.q_norm", FloatType.F32, (h.head_dim,))
             add(f"layers.{l}.k_norm", FloatType.F32, (h.head_dim,))

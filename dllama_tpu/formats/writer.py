@@ -55,6 +55,15 @@ HEADER_KEYS = {
     "qk_nope_head_dim": 35,
     "qk_rope_head_dim": 36,
     "v_head_dim": 37,
+    "index_n_heads": 38,
+    "index_head_dim": 39,
+    "index_topk": 40,
+    "n_group": 41,
+    "topk_group": 42,
+    "rope_beta_fast": 43,
+    "rope_beta_slow": 44,
+    "rope_mscale_milli": 45,
+    "rope_mscale_all_dim_milli": 46,
 }
 
 

@@ -408,15 +408,20 @@ def load_params(
         # along the input side cannot serve, so it is dequantised once
         # into two per-head stacks in the activation dtype: `wkv_b_k`
         # [L, H, nope, kv_lora] and `wkv_b_v` [L, H, kv_lora, v].
-        for n in ("wq_a", "wq_b", "wkv_a", "wo"):
+        # an index over the cache: its two projections as the others, its
+        # head weights f32 as the router's, the key's LayerNorm as a norm
+        index = ("idx_wq_b", "idx_wk") if h.indexed else ()
+        for n in ("wq_a", "wq_b", "wkv_a", "wo", *index):
             if quantize:
                 layers[n] = qw(n, lambda l, n=n: f"layers.{l}.{n}")
             else:
                 layers[n] = put(
                     n, stack(lambda l, n=n: w(f"layers.{l}.{n}")).astype(dtype)
                 )
-        for n in ("q_a_norm", "kv_a_norm"):
+        for n in ("q_a_norm", "kv_a_norm") + (("idx_k_norm", "idx_k_bias") if index else ()):
             layers[n] = put(n, stack(lambda l, n=n: w(f"layers.{l}.{n}", False)))
+        if index:
+            layers["idx_w"] = put("idx_w", stack(lambda l: w(f"layers.{l}.idx_w")))
         nope = h.qk_nope_head_dim
 
         def per_head(l: int) -> np.ndarray:  # [H, nope + v, kv_lora]

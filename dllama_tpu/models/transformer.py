@@ -62,6 +62,7 @@ from ..ops.kv_cache import (
     quantize_kv_rows,
     write_rows,
 )
+from ..ops.sparse_index import index_scores, select_rows
 from ..ops.moe_kernel import (
     moe_active_experts,
     moe_active_experts_q40,
@@ -162,14 +163,19 @@ def init_kv_cache(
     behind, which `run_layers` needs where chunks wrap, lanes park or a
     scan holds layers of both kinds. Each layer's `row` in the table is
     its place in its stack. A model with latent attention gets one stack
-    `c` of `[L, B, 1, S, kv_lora_rank + qk_rope_head_dim]` and no other."""
+    `c` of `[L, B, 1, S, kv_lora_rank + qk_rope_head_dim]` and, where a
+    learned index picks the rows a query attends to (`index_topk > 0`), a
+    second, `i`, of the positions' index keys, `index_head_dim` wide."""
     s = seq_len or h.seq_len
     if h.latent:
         # one stack of `[c | k_rope]` rows, one head for every query head:
         # no per-head keys or values are ever stored
         if dtype == jnp.int8:
             raise NotImplementedError("latent cache rows are not quantized: int8 KV")
-        return {"c": jnp.zeros((h.n_layers, batch_size, 1, s, h.latent_row), dtype)}
+        cache = {"c": jnp.zeros((h.n_layers, batch_size, 1, s, h.latent_row), dtype)}
+        if h.indexed:
+            cache["i"] = jnp.zeros((h.n_layers, batch_size, 1, s, h.index_head_dim), dtype)
+        return cache
     n_window = sum(kind.window for kind in layer_table(h))
     shape = (h.n_layers - n_window, batch_size, h.n_kv_heads, s, h.head_dim)
     if dtype == jnp.int8:
@@ -331,6 +337,7 @@ def latent_attention_dense(
     pos,  # scalar or [B]: position of q[:, 0]; negative = a parked lane
     kv_rank: int,  # the rows' first `kv_rank` columns are the values
     scale: float,
+    keep: jnp.ndarray | None = None,  # bool [B, T, S]: the rows an index picked
 ) -> jnp.ndarray:
     """Absorbed latent attention in plain XLA, [B, T, H, kv_rank]: every
     query head against the one cached head, the weighted sum over the rows'
@@ -339,13 +346,16 @@ def latent_attention_dense(
     widening into the two products, which at the chip's default precision
     are one bf16 pass each, so a bf16 cache is read as bf16: per row
     2 x H x (W + kv_rank) FLOP over W x 2 bytes, the chip's ridge. A lane
-    whose position is negative sees nothing and gives zeros."""
+    whose position is negative sees nothing and gives zeros. `keep`: a
+    query attends to those of the rows it sees alone."""
     b, t = q.shape[0], q.shape[1]
     ck = rows[:, 0].astype(jnp.float32)  # [B, S, W]
     scores = jnp.einsum("bthw,bsw->bhts", q.astype(jnp.float32), ck) * scale
     q_pos = jnp.atleast_1d(jnp.asarray(pos, jnp.int32))[:, None] + jnp.arange(
         t, dtype=jnp.int32)[None, :]  # [1 or B, T]
     seen = jnp.arange(ck.shape[1], dtype=jnp.int32)[None, None, :] <= q_pos[:, :, None]
+    if keep is not None:
+        seen = jnp.logical_and(seen, keep)
     scores = jnp.where(seen[:, None], scores, _NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.where(m <= _NEG_INF / 2, 0.0, jnp.exp(scores - m))
@@ -362,6 +372,7 @@ def _attention_latent(
     kv_rank: int,
     scale: float,
     attn_window: int = 0,
+    keep: jnp.ndarray | None = None,  # bool [B or 1, T, rows]: `index_keep`'s
 ) -> jnp.ndarray:
     """Absorbed attention over the window's rows of layer `layer` of the
     latent stack, after the chunk's rows are written: the Pallas kernel
@@ -369,13 +380,16 @@ def _attention_latent(
     position are H query rows against the one cached head, a parked
     lane's blocks skipped), XLA's dense products for a decode step and on
     the CPU (`_attention_tp` says why decode takes no kernel). Both read
-    the stack where it lies; [B, T, H, kv_rank]."""
+    the stack where it lies; [B, T, H, kv_rank]. `keep`: the rows an index
+    picked for each query (one lane's where the program admits one: the
+    others are parked and see nothing); both paths mask the rest."""
     t, n_heads = q.shape[1], q.shape[2]
     s = c_cache.shape[3]
     rows = attn_window if 0 < attn_window < s else s
     if t >= 8 and _use_flash(t * n_heads, rows):
         return latent_flash_attention(
-            q, c_cache, pos, layer=layer, rows=rows, kv_rank=kv_rank, scale=scale
+            q, c_cache, pos, layer=layer, rows=rows, kv_rank=kv_rank, scale=scale,
+            keep=keep,
         )
     # a lane at a time, as `write_rows` writes: with the lanes as a batch
     # axis of one product the chip's compiler keeps the stack in a layout of
@@ -390,9 +404,50 @@ def _attention_latent(
             q[lane : lane + 1],
             lax.dynamic_slice(c_cache, (layer, lane, 0, 0, 0), (1, 1, 1, rows, w))[0],
             lane_pos[lane], kv_rank, scale,
+            keep=None if keep is None else keep[min(lane, keep.shape[0] - 1)][None],
         )
         for lane in range(b)
     ])
+
+
+def index_keep(
+    qi: jnp.ndarray,  # [B, T, J, dI] index queries
+    w: jnp.ndarray,  # [B, T, J] f32 index head weights
+    i_cache: jnp.ndarray,  # [L, B, 1, S, dI]: the index keys' stack
+    layer: jnp.ndarray,
+    pos: jnp.ndarray,  # scalar or [B]: position of qi[:, 0]; negative = parked
+    topk: int,
+    rows: int,  # score the first `rows` cached rows
+    lane=None,  # traced lane number: the one lane the program admits
+) -> jnp.ndarray:
+    """The rows each query attends to, bool [B, T, rows] ([1, T, rows] for
+    the one admitted `lane`): the `topk` of largest index score among those
+    it sees (`ops/sparse_index`), after the chunk's keys are written. The
+    keys of a lane are one `dynamic_slice` of the stack where it lies, a
+    lane at a time as `_attention_latent` reads the rows; the selection runs
+    over every lane's queries at once."""
+    b, t = qi.shape[0], qi.shape[1]
+    di = i_cache.shape[4]
+    lane_pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    lanes = range(b) if lane is None else [lane]
+    with jax.named_scope("index_score"):
+        scores = jnp.stack([
+            index_scores(
+                lax.dynamic_index_in_dim(qi, ln, 0, keepdims=False),
+                lax.dynamic_index_in_dim(w, ln, 0, keepdims=False),
+                lax.dynamic_slice(i_cache, (layer, ln, 0, 0, 0), (1, 1, 1, rows, di))[0, 0, 0],
+            )
+            for ln in lanes
+        ])  # [B or 1, T, rows]
+    with jax.named_scope("index_select"):
+        if lane is not None:
+            lane_pos = lax.dynamic_index_in_dim(lane_pos, lane, 0, keepdims=True)
+        # a parked lane's positions stay negative across the chunk
+        q_pos = jnp.where(
+            lane_pos[:, None] < 0, -1,
+            lane_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :])
+        keep = select_rows(scores.reshape(-1, rows), q_pos.reshape(-1), topk)
+    return keep.reshape(-1, t, rows)
 
 
 def _attention_sp_merge(
@@ -565,7 +620,10 @@ class Routing:
     """How a layer's router turns its scores into weighted experts, and
     which of the experts it scores are held here: `n_held` from `first` of
     `n_routed`. The reference's router is the default: softmax over all,
-    the chosen weights renormalised, every expert held."""
+    the chosen weights renormalised, every expert held. `n_group` and
+    `topk_group`: the routed experts lie in `n_group` groups of equal size,
+    and a token's experts come from the `topk_group` groups whose two best
+    selection scores add up to most (1 of 1: no limit)."""
 
     n_active: int
     sigmoid: bool = False
@@ -574,6 +632,8 @@ class Routing:
     first: int = 0
     n_held: int = 0
     n_routed: int = 0
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def shared_out(self) -> bool:
@@ -592,7 +652,7 @@ class Routing:
 def routing_of(h: LlmHeader) -> Routing:
     return Routing(
         h.n_active_experts, h.score_sigmoid, h.route_norm, h.route_scale,
-        h.first_expert, h.n_experts, h.n_routed_experts,
+        h.first_expert, h.n_experts, h.n_routed_experts, h.n_group, h.topk_group,
     )
 
 
@@ -601,27 +661,31 @@ def _moe_route(x_flat: jnp.ndarray, gate_w: jnp.ndarray, route: Routing, bias=No
     weights; reference: src/nn/nn-cpu-ops.cpp:1462-1492). `x_flat` is
     [..., D]; returns (top_i [..., k], weights [..., k]) in f32, the ids
     among all the experts the router scores. Sigmoid scores take `bias`
-    [E] into the selection and never into the weights."""
+    [E] into the selection and never into the weights; a group limit
+    (`Routing.topk_group` of `n_group`) zeroes the selection scores of the
+    groups left out before the top-k."""
     logits = jnp.einsum(
         "...d,de->...e", x_flat.astype(jnp.float32), gate_w.astype(jnp.float32)
     )
-    if route.sigmoid:
-        scores = jax.nn.sigmoid(logits)
-        _, top_i = lax.top_k(
-            scores if bias is None else scores + bias.astype(jnp.float32),
-            route.n_active,
-        )
+    scores = jax.nn.sigmoid(logits) if route.sigmoid else jax.nn.softmax(logits, axis=-1)
+    chosen_by = scores
+    if route.sigmoid and bias is not None:
+        chosen_by = scores + bias.astype(jnp.float32)
+    if route.n_group > 1:
+        grouped = chosen_by.reshape(*chosen_by.shape[:-1], route.n_group, -1)
+        group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = lax.top_k(group_score, route.topk_group)
+        stays = jnp.any(
+            kept[..., None] == jnp.arange(route.n_group, dtype=kept.dtype), axis=-2)
+        chosen_by = jnp.where(stays[..., None], grouped, 0.0).reshape(chosen_by.shape)
+    top_p, top_i = lax.top_k(chosen_by, route.n_active)
+    if route.sigmoid:  # the weights are the scores themselves, not what chose them
         top_p = jnp.take_along_axis(scores, top_i, axis=-1)
-        weights = (
-            top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
-            if route.norm else top_p
-        )
-    else:
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = lax.top_k(probs, route.n_active)
-        weights = (
-            top_p / jnp.sum(top_p, axis=-1, keepdims=True) if route.norm else top_p
-        )
+    weights = top_p
+    if route.norm:
+        # a sigmoid's chosen scores may all be near 0
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        weights = top_p / (total + 1e-20 if route.sigmoid else total)
     if route.scale != 1.0:
         weights = weights * route.scale
     return top_i, weights
@@ -937,7 +1001,7 @@ def forward(
     # `pos` may be a [B] vector: each batch lane decodes at its own
     # position (independent request lanes — the continuous-batching
     # surface the reference's single-stream loop lacks)
-    names = [n for n in ("k", "v", "kw", "vw", "c") if n in cache]
+    names = [n for n in ("k", "v", "kw", "vw", "c", "i") if n in cache]
     attn_pos = attn_positions(pos, attn_park_threshold, cache[names[0]].shape[3])
 
     x = params["embed"][tokens]  # [B, T, D] (reference: OP_EMBEDDING)
@@ -950,7 +1014,7 @@ def forward(
         cos, sin, mesh=mesh, attn_window=attn_window,
         sync_quant=sync_quant, moe_decode_dedup=moe_decode_dedup,
         kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
-        route_stats=route_stats, c_cache=cache.get("c"),
+        route_stats=route_stats, c_cache=cache.get("c"), i_cache=cache.get("i"),
         one_live_lane=one_live_lane,
     )
     logits = logits_head(x, params, h, mesh, logits_mode)
@@ -1042,10 +1106,12 @@ def run_layers(
     route_stats: list | None = None,
     c_cache: jnp.ndarray | None = None,  # [L, B, 1, S, W]: latent layers, alone
     one_live_lane: bool = False,
+    i_cache: jnp.ndarray | None = None,  # [L, B, 1, S, dI]: their index keys
 ):
     """`lax.scan` the decoder layers over x; returns (x, k_new, v_new), and
     the window layers' (kw_new, vw_new) behind them where the model has such;
-    for a model with latent attention (x, c_new): the carry holds the stacks
+    for a model with latent attention (x, c_new), and (x, c_new, i_new) where
+    an index picks the rows its queries attend to: the carry holds the stacks
     the layer table asks for and no other.
 
     What a layer is comes from the header's layer table (`layer_table`):
@@ -1099,7 +1165,7 @@ def run_layers(
     before: the slice would gather across them.
     """
     b, t = x.shape[0], x.shape[1]
-    interleaved = h.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1)
+    interleaved = h.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1, RopeType.YARN)
     act = silu if h.hidden_act == HiddenAct.SILU else gelu
     per_lane = jnp.ndim(pos) == 1
     if (tp_axis is not None or sp_axis is not None) and mesh is not None:
@@ -1114,7 +1180,7 @@ def run_layers(
                 "latent attention layers run on one device: tp, sp, dp, pp > 1"
             )
         k_cache = c_cache  # the stack whose shape the lines below read
-    if latent != layer_table(h)[0].latent:
+    if latent != layer_table(h)[0].latent or (i_cache is not None) != h.indexed:
         raise ValueError("the layer table and the cache stacks disagree on latent rows")
     shard_s = k_cache.shape[3]  # local (per-sp-shard) sequence length
     # manual sp: the per-shard write window is t//sp_n (+1 for unaligned
@@ -1315,9 +1381,12 @@ def run_layers(
         8 lanes of 16k; the described v5e's compiler)."""
         if latent:
             # one row of `[c | k_rope]` (`k`; there are no values) into the
-            # one stack, a parked lane's past the context as any row's
+            # one stack, a parked lane's past the context as any row's; the
+            # position's index key (`v`) into the stack beside it
             with jax.named_scope("kv_write"):
-                return (_cache_append(caches[0], row[0], k),)
+                return tuple(
+                    _cache_append(cache, row[0], val) for cache, val in zip(caches, (k, v))
+                )
         k_cache, v_cache, *ring_caches = caches
         mixed = not isinstance(is_window, bool)
         with jax.named_scope("kv_write"):
@@ -1374,17 +1443,31 @@ def run_layers(
                 window, attn_window=attn_window, row0=ring_pad,
             )
 
-    def attend_latent(q, caches, row):
-        """Absorbed attention over the latent stack: [B, T, H, kv_lora]."""
+    def attend_latent(q, caches, row, index=None):
+        """Absorbed attention over the latent stack: [B, T, H, kv_lora].
+        `index`: a layer's index queries and head weights; its queries then
+        attend to the `index_topk` rows of largest index score alone. While
+        the window's rows are no more than that, every row is taken and the
+        index is left unread."""
+        keep = None
+        s_rows = caches[0].shape[3]
+        rows = attn_window if 0 < attn_window < s_rows else s_rows
+        if index is not None and rows > h.index_topk:
+            with jax.named_scope("attn"):
+                keep = index_keep(
+                    *index, caches[1], row[0], attn_pos, h.index_topk, rows,
+                    lane=lane if lone else None,
+                )
         with jax.named_scope("attn"), jax.named_scope(f"latent_{phase}"):
             return _attention_latent(
                 q, caches[0], row[0], attn_pos, h.kv_lora_rank,
-                float(h.head_dim) ** -0.5, attn_window=attn_window,
+                h.softmax_scale, attn_window=attn_window, keep=keep,
             )
 
     def latent_queries_and_row(y, lp, mm):
         """The five projections' first three and both norms of a latent
-        layer: (absorbed queries [B, T, H, W], the cache row [B, T, 1, W]).
+        layer: (absorbed queries [B, T, H, W], the cache row [B, T, 1, W],
+        the normalised query latent, which an index's queries start from).
         A query is `[q_nope U_h^T | rope(q_rope)]`, so that against the
         cached `[c | rope(k_rope)]` it scores what `q_nope . k_nope +
         q_rope . k_rope` scores, with no key rebuilt."""
@@ -1399,7 +1482,31 @@ def run_layers(
         return (
             jnp.concatenate([q_abs, q_rope], axis=-1),
             jnp.concatenate([c[..., None, :], kr], axis=-1),
+            cq,
         )
+
+    def index_queries_and_key(y, cq, lp, mm):
+        """A layer's index: ((queries [B, T, J, dI], head weights [B, T, J]
+        f32), the position's index key [B, T, 1, dI]). Queries come from the
+        normalised query latent, the key from the layer's input through a
+        LayerNorm (weight and bias), each with rope on its first rope
+        columns in the rotate-half pairing, whatever the heads' own; the
+        weights from the input in f32, times heads^-1/2 dI^-1/2."""
+        n_idx, di, rd = h.index_n_heads, h.index_head_dim, h.rope_dim
+
+        def turned(z):  # [B, T, heads, dI]
+            return jnp.concatenate(
+                [apply_rope(z[..., :rd], cos, sin, False), z[..., rd:]], axis=-1)
+
+        qi = turned(mm(cq, lp["idx_wq_b"], "row").reshape(b, t, n_idx, di))
+        kf = mm(y, lp["idx_wk"], "row").astype(jnp.float32)
+        kf = kf - jnp.mean(kf, axis=-1, keepdims=True)
+        kf = kf * lax.rsqrt(jnp.mean(kf * kf, axis=-1, keepdims=True) + h.norm_epsilon)
+        ki = (kf * lp["idx_k_norm"] + lp["idx_k_bias"]).astype(y.dtype)
+        w = jnp.einsum(
+            "btd,dj->btj", y.astype(jnp.float32), lp["idx_w"].astype(jnp.float32)
+        ) * (float(n_idx) ** -0.5 * float(di) ** -0.5)
+        return (qi, w), turned(ki[..., None, :])
 
     def moe_block(y, lp, lf):
         """The experts' FFN of a layer whose experts' row is `lf` over the
@@ -1441,9 +1548,11 @@ def run_layers(
             on = held_i < route.n_held
             touched = jnp.zeros((route.n_held + 1,), bool).at[held_i].set(
                 True)[: route.n_held]
+            # pairs chosen, pairs landed here, held experts touched, and token
+            # rows with a pair that landed here (summed over the layers)
             counts = jnp.stack([
                 jnp.sum(live_rows) * route.n_active, jnp.sum(on),
-                jnp.sum(touched),
+                jnp.sum(touched), jnp.sum(jnp.any(on, axis=-1)),
             ]).astype(jnp.int32)
         # one device holds the layer: each distinct quantized expert the
         # live rows touched is read once, in a chunk and a decode block
@@ -1535,8 +1644,11 @@ def run_layers(
                 if kinds[0].latent:
                     # the cache row stands where the keys do; there are no values
                     with jax.named_scope("latent_proj"):
-                        q, k = latent_queries_and_row(y, lp, mm)
-                    v = None
+                        q, k, cq = latent_queries_and_row(y, lp, mm)
+                    v = index = None
+                    if "idx_wk" in lp:  # the index key is cached where values would be
+                        with jax.named_scope("index_proj"):
+                            index, v = index_queries_and_key(y, cq, lp, mm)
                 elif "wqkv" in lp:
                     # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
                     # per decode layer at ~41 us fixed cost each on the round-3
@@ -1580,7 +1692,7 @@ def run_layers(
 
             caches = write_kv(caches, k, v, row, is_window)
             if kinds[0].latent:
-                z = attend_latent(q, caches, row)
+                z = attend_latent(q, caches, row, index)
                 with jax.named_scope("attn"), jax.named_scope("latent_proj"):
                     # a head's values from the weighted latents, once a query
                     z = jnp.einsum("bthc,hcv->bthv", z, lp["wkv_b_v"]).reshape(
@@ -1643,7 +1755,9 @@ def run_layers(
     # which are the norms, a dense model's weights and the layer number.
     # The caches are the scan's carry: as `xs` and `ys` every layer's whole
     # lane cache was copied out of the stack and back to write a row a lane
-    caches = (c_cache,) if latent else (k_cache, v_cache) if kw_cache is None else (
+    caches = (
+        (c_cache,) if i_cache is None else (c_cache, i_cache)
+    ) if latent else (k_cache, v_cache) if kw_cache is None else (
         k_cache, v_cache, kw_cache, vw_cache)
     counted = []
     for a, e in segments:

@@ -800,6 +800,7 @@ def _latent_flash_kernel(
     out_ref,  # [1, bq, kv_rank]
     m_ref, l_acc, acc_ref,  # scratch: running max, denominator, weighted sum
     *,
+    keep_ref=None,  # [1, 1, P, bs]: `_latent_flash_kernel_kept`'s fifth operand
     block_q: int,
     block_s: int,
     n_s: int,
@@ -814,7 +815,9 @@ def _latent_flash_kernel(
     `kv_rank` columns: a cached row is moved once a query block, for keys
     and values both. A block above the causal frontier of the query block's
     last position is skipped, and so is every block of a parked lane
-    (position <= -T)."""
+    (position <= -T). `keep_ref` [1, 1, P, bs] (where an index picks the rows
+    a query attends to): above 0 where the block's position p attends to the
+    row; its heads' query rows, bq / P to a position, take the same mask."""
     qi = pl.program_id(1)
     si = pl.program_id(2)
     pos0 = pos_ref[pl.program_id(0)]
@@ -843,7 +846,14 @@ def _latent_flash_kernel(
         else:
             q_pos = pos0 + rows // n_heads
         s_pos = s_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_s), 1)
-        scores = jnp.where(s_pos <= q_pos, scores, _NEG_INF)
+        seen = s_pos <= q_pos
+        if keep_ref is not None:
+            kept = keep_ref[0, 0]  # [P padded to whole tiles, bs]
+            seen = jnp.logical_and(seen, jnp.concatenate([
+                jnp.broadcast_to(kept[i : i + 1], (n_heads, block_s))
+                for i in range(block_q // n_heads)
+            ]) > 0)
+        scores = jnp.where(seen, scores, _NEG_INF)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0, jnp.exp(m_prev - m_new))
@@ -863,6 +873,12 @@ def _latent_flash_kernel(
         out_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
 
 
+def _latent_flash_kernel_kept(pos_ref, l_ref, q_ref, c_ref, keep_ref, *refs, **static):
+    """`_latent_flash_kernel` under an index's mask: the mask rides as the
+    operand after the cached rows."""
+    _latent_flash_kernel(pos_ref, l_ref, q_ref, c_ref, *refs, keep_ref=keep_ref, **static)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("rows", "kv_rank", "scale", "block_q", "block_s", "interpret"),
@@ -878,10 +894,13 @@ def latent_flash_attention(
     block_q: int = 0,
     block_s: int = 0,
     interpret: bool = False,
+    keep: jnp.ndarray | None = None,  # bool [B or 1, T, rows]: rows a query attends to
 ) -> jnp.ndarray:
     """Blockwise causal latent attention in the absorbed form, [B, T, H,
     kv_rank] in q's type: `softmax_j(q . row_j * scale) row_j[:kv_rank]`
-    over the cached rows a query's position sees. The stack stays where it
+    over the cached rows a query's position sees, or with `keep` over those
+    of them that it names (one lane's mask serves every lane: a program that
+    admits one lane parks the others, whose blocks are skipped). The stack stays where it
     lies (the layer rides as scalar prefetch, the window as `rows`); the
     products take the operands' own type with f32 accumulation, the softmax
     is f32. A strongly negative lane position masks the lane whole.
@@ -921,24 +940,50 @@ def latent_flash_attention(
     def q_map(bi, qi, si, pos_ref, l_ref):
         return (bi, qi, 0)
 
-    def c_map(bi, qi, si, pos_ref, l_ref):
+    def s_block(bi, qi, si, pos_ref):
         # clamp past the causal frontier of the query block's last position
         limit = jnp.maximum(
             (pos_ref[bi] + ((qi + 1) * block_q - 1) // h) // block_s, 0)
-        return (l_ref[0], bi, 0, 0, jnp.minimum(si, limit))
+        return jnp.minimum(si, limit)
+
+    def c_map(bi, qi, si, pos_ref, l_ref):
+        return (l_ref[0], bi, 0, 0, s_block(bi, qi, si, pos_ref))
+
+    in_specs = [
+        pl.BlockSpec((1, block_q, w), q_map),
+        pl.BlockSpec((None, 1, 1, w, block_s), c_map),
+    ]
+    operands = [qr, jnp.swapaxes(c_stack, 3, 4)]
+    if keep is not None:
+        # a query block is whole positions, P of them; their masks lie as
+        # one tile-aligned block [P padded to 8s, bs] a query block
+        if block_q % h:
+            raise ValueError(
+                f"a mask a position needs query blocks of whole positions: "
+                f"{block_q} rows a block, {h} heads")
+        per, one = block_q // h, keep.shape[0] == 1
+        assert keep.shape[1:] == (t, s) and keep.shape[0] in (1, b), keep.shape
+        pad = -per % 8
+        kept = jnp.pad(
+            keep.reshape(keep.shape[0], n_q, per, s).astype(jnp.float32),
+            ((0, 0), (0, 0), (0, pad), (0, 0)))
+
+        def keep_map(bi, qi, si, pos_ref, l_ref):
+            return (0 if one else bi, qi, 0, s_block(bi, qi, si, pos_ref))
+
+        in_specs.append(pl.BlockSpec((1, 1, per + pad, block_s), keep_map))
+        operands.append(kept)
 
     out = pl.pallas_call(
         functools.partial(
-            _latent_flash_kernel, block_q=block_q, block_s=block_s, n_s=n_s,
+            _latent_flash_kernel if keep is None else _latent_flash_kernel_kept,
+            block_q=block_q, block_s=block_s, n_s=n_s,
             n_heads=h, kv_rank=kv_rank, scale=scale,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_q, n_s),
-            in_specs=[
-                pl.BlockSpec((1, block_q, w), q_map),
-                pl.BlockSpec((None, 1, 1, w, block_s), c_map),
-            ],
+            in_specs=in_specs,
             out_specs=pl.BlockSpec((1, block_q, kv_rank), q_map),
             scratch_shapes=[
                 pltpu.VMEM((block_q, 128), jnp.float32),
@@ -948,7 +993,7 @@ def latent_flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_rows, kv_rank), q.dtype),
         interpret=interpret,
-    )(pos_arr, layer_arr, qr, jnp.swapaxes(c_stack, 3, 4))
+    )(pos_arr, layer_arr, *operands)
     return out.reshape(b, t, h, kv_rank)
 
 

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from ..formats.model_file import LlmHeader, RopeType
+from ..formats.model_file import LlmHeader, RopeType, yarn_mscale
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -71,6 +71,24 @@ def _scale_frequency_llama3(freq: "np.ndarray", h: LlmHeader) -> "np.ndarray":
     )
 
 
+def _scale_frequency_yarn(freq: "np.ndarray", h: LlmHeader) -> "np.ndarray":
+    """Frequencies scaled by band (`rope_scaling.type: yarn`): pair d keeps
+    its frequency below band `low`, has it divided by the factor above
+    band `high`, and a linear blend between; the limits are the pairs that
+    turn `beta_fast` and `beta_slow` times over the original length."""
+    dim, orig = h.rope_dim, h.rope_scaling_orig_max_seq_len
+
+    def band(rotations: float) -> float:
+        return dim * np.log(orig / (rotations * 2.0 * np.pi)) / (2.0 * np.log(h.rope_theta))
+
+    low = max(int(np.floor(band(h.rope_beta_fast))), 0)
+    high = min(int(np.ceil(band(h.rope_beta_slow))), dim // 2 - 1)
+    ramp = np.clip(
+        (np.arange(dim // 2, dtype=np.float32) - low) / max(high - low, 0.001), 0.0, 1.0
+    )
+    return (1.0 - ramp) * freq + ramp * freq / h.rope_scaling_factor
+
+
 def rope_frequencies(h: LlmHeader) -> "np.ndarray":
     """Per-pair inverse frequencies, shape [headDim // 2], f32, on host.
 
@@ -84,6 +102,8 @@ def rope_frequencies(h: LlmHeader) -> "np.ndarray":
     freqs = (1.0 / (h.rope_theta**exponents)).astype(np.float32)
     if h.rope_type == RopeType.LLAMA3_1 and h.rope_scaling_factor != 1.0:
         freqs = _scale_frequency_llama3(freqs, h).astype(np.float32)
+    if h.rope_type == RopeType.YARN and h.rope_scaling_factor != 1.0:
+        freqs = _scale_frequency_yarn(freqs, h).astype(np.float32)
     return freqs
 
 
@@ -98,7 +118,14 @@ def rope_cache(h: LlmHeader, seq_len: int | None = None):
         seq_len = h.seq_len
     freqs = rope_frequencies(h)
     angles = np.arange(seq_len, dtype=np.float32)[:, None] * freqs[None, :]
-    return np.cos(angles), np.sin(angles)
+    cos, sin = np.cos(angles), np.sin(angles)
+    if h.rope_type == RopeType.YARN:
+        # the table's own magnitude factor (1 where the two mscales agree)
+        m = yarn_mscale(h.rope_scaling_factor, h.rope_mscale) / yarn_mscale(
+            h.rope_scaling_factor, h.rope_mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * np.float32(m), sin * np.float32(m)
+    return cos, sin
 
 
 def apply_rope(

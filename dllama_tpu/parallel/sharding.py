@@ -39,7 +39,8 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
     is in//2 and the scale leaf's is in//32; both divide by tp under the
     engine's 32*tp divisibility check, so the col shard boundaries stay
     nibble- and block-aligned."""
-    moe = h.arch in (LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE)
+    moe = h.arch in (
+        LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32)
     # stacked layer weights carry a leading layer axis; MoE adds an expert axis
     row = P(None, None, None, "tp") if moe else P(None, None, "tp")  # out split
     col = P(None, None, "tp", None) if moe else P(None, "tp", None)  # in split

@@ -452,11 +452,20 @@ class InferenceEngine:
                 kw=self._cache_sharding["k"], vw=self._cache_sharding["v"]
             )
         if self._latent:
-            self._cache_sharding = {"c": self._cache_sharding["k"]}
+            # the latent rows and, where an index picks among them, its keys
+            names = ("c", "i") if self.header.indexed else ("c",)
+            self._cache_sharding = {n: self._cache_sharding["k"] for n in names}
         self._m_ring_wraps = self.obs.counter(
             "dllama_kv_ring_wraps_total",
             "Times a lane's position passed the end of the window layers' "
             "ring cache and its writes began again at the ring's first row.",
+        )
+        self._m_index_rows = self.obs.counter(
+            "dllama_attn_index_rows_total",
+            "Cached rows a layer that live queries' index scored, and those "
+            "of them the queries then attended to (at most index_topk a "
+            "query), in decode blocks and prefill chunks.",
+            labelnames=("kind",),
         )
         self._m_moe_pairs = self.obs.counter(
             "dllama_moe_pairs_total",
@@ -495,7 +504,7 @@ class InferenceEngine:
             "Device bytes of the lane KV cache by kind of layer: full = "
             "rows for the whole context, window = a ring of the window "
             "and one chunk, latent = one stack of [c | k_rope] rows for the "
-            "whole context.",
+            "whole context, index = the index keys beside them.",
             labelnames=("kind",),
         )
         self.kv_cache_bytes = {
@@ -504,7 +513,9 @@ class InferenceEngine:
                 for leaf in jax.tree.leaves(self.cache[name])
             )
             for kind, names in (
-                ("full", ("k", "v")), ("window", ("kw", "vw")), ("latent", ("c",)))
+                ("full", ("k", "v")), ("window", ("kw", "vw")), ("latent", ("c",)),
+                ("index", ("i",)))
+            if kind != "index" or "i" in self.cache  # a kind of its own where there is one
         }
         for kind, n in self.kv_cache_bytes.items():
             g_bytes.labels(kind=kind).set(n)
@@ -774,11 +785,20 @@ class InferenceEngine:
         lanes see, summed over its `n` steps or rows: in the full layers all
         of a lane's context, in the window layers at most the window. The
         ring's wraps that the dispatch brings are counted here too. A latent
-        cache: `rows_latent`, the latent rows a layer those queries see.
+        cache: `rows_latent`, the latent rows a layer those queries see (and
+        an index scores), and under an index `rows_selected`, those of them
+        they attend to: at most `index_topk` a query; counted too.
         Nothing for a model of keys and values of one kind."""
         if self._latent:
             # every live query sees its whole context, one latent row a layer
-            return {"rows_latent": sum(p + i + 1 for p in starts for i in range(n))}
+            seen = [p + i + 1 for p in starts for i in range(n)]
+            if not self.header.indexed:
+                return {"rows_latent": sum(seen)}
+            rows = {"rows_latent": sum(seen),
+                    "rows_selected": sum(min(r, self.header.index_topk) for r in seen)}
+            self._m_index_rows.labels(kind="scored").inc(rows["rows_latent"])
+            self._m_index_rows.labels(kind="selected").inc(rows["rows_selected"])
+            return rows
         if not self._two_cache_kinds:
             return {}
         wraps = sum((p + n) // self.kv_ring - p // self.kv_ring for p in starts)
@@ -2315,8 +2335,9 @@ class InferenceEngine:
 
             seq_len = self.header.seq_len
             # a model whose experts compute the pairs that landed here
-            # counts, a step, the pairs its router chose and those: three more
-            # columns of the block's one output, so no read-back is added
+            # counts, a step, the pairs its router chose, those, the held experts
+            # touched and the tokens that landed: four more columns of the
+            # block's one output, so no read-back is added
             counting = self._counts_routing
 
             @partial(jax.jit, donate_argnums=(2,))
@@ -2360,7 +2381,7 @@ class InferenceEngine:
                     return nxt, cache, out
 
                 out0 = jnp.zeros(
-                    (n_steps, token.shape[0] + (3 if counting else 0)), jnp.int32
+                    (n_steps, token.shape[0] + (4 if counting else 0)), jnp.int32
                 )
                 _, cache, out = lax.fori_loop(
                     0, n_steps, body, (token, cache, out0)
@@ -2486,7 +2507,7 @@ class InferenceEngine:
                 out, self.cache = block(self.params, arr, self.cache, *rest)
             out_np = self._read_back("decode_lanes", out)
         if self._counts_routing and not native:
-            routed, held, touched = (
+            routed, held, touched, landed = (
                 int(n) for n in out_np[:, self.batch_size:].sum(axis=0)
             )
             out_np = out_np[:, : self.batch_size]
@@ -2496,6 +2517,7 @@ class InferenceEngine:
             self.recorder.record(
                 "moe_route", step="decode_lanes", n_steps=n_steps,
                 pairs_routed=routed, pairs_held=held, held_touched=touched,
+                tokens_landed=landed,
             )
         # each active stream advances one token per block row
         self._m_tpot.observe(timed["seconds"] / n_steps)

@@ -274,3 +274,62 @@ def tiny_pangu_config(**over) -> dict:
 def make_tiny_pangu(path: str, seed: int = 3, **over) -> dict:
     """The same for `pangu_ultra_moe`, as `make_tiny_afmoe`."""
     return _write_tiny(path, tiny_pangu_config(**over), seed)
+
+
+DSV32_TOPK = 16
+
+
+def tiny_dsv32_config(**over) -> dict:
+    """A benchmark configuration file's worth of the `deepseek_v32`
+    architecture (latent attention over the rows an index picks, a router
+    limited to some of its groups, a rotary table scaled by band) at test
+    widths at which each still bites: an index of 4 heads of 16 that keeps
+    16 rows, 8 routed experts in 4 groups of which 2 stay, the first two
+    groups held, a rotary scaling over an original length of 64."""
+    cfg = {
+        "name": "dsv32-tiny", "family": "deepseek_v32",
+        "hidden_size": 64, "intermediate_size": 160, "moe_intermediate_size": 128,
+        "num_hidden_layers": 5, "first_k_dense_replace": 1,
+        "num_attention_heads": 8, "num_key_value_heads": 8,
+        "q_lora_rank": 96, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 8, "v_head_dim": 24, "vocab_size": 512,
+        "index_n_heads": 4, "index_head_dim": 16, "index_topk": DSV32_TOPK,
+        "max_position_embeddings": 4096, "rope_theta": 10000, "rms_norm_eps": 1e-6,
+        "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+                         "mscale_all_dim": 1, "original_max_position_embeddings": 64,
+                         "type": "yarn"},
+        "n_routed_experts": 4, "num_routed_experts": 8, "first_expert": 0,
+        "n_group": 4, "topk_group": 2,
+        "num_experts_per_tok": 2, "n_shared_experts": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 2.5, "assumed": {"head_dim": 24},
+    }
+    cfg.update(over)
+    cfg["num_experts"] = cfg["n_routed_experts"]  # the harness's name for the experts held
+    scaling = cfg["rope_scaling"]
+    cfg["file"] = {
+        "arch": "DEEPSEEK_V32", "rope_pairing": "interleaved", "norm_epsilon_enum": 6,
+        "header": {
+            "n_dense_layers": cfg["first_k_dense_replace"],
+            "n_shared_experts": cfg["n_shared_experts"],
+            "score_func": 1, "route_norm": 1, "route_scale_milli": 2500,
+            "n_routed_experts": cfg["num_routed_experts"],
+            "first_expert": cfg["first_expert"],
+            **{k: cfg[k] for k in (
+                "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                "v_head_dim", "index_n_heads", "index_head_dim", "index_topk",
+                "n_group", "topk_group")},
+            "rope_type": 3, "rope_scaling_factor": scaling["factor"],
+            "rope_scaling_orig_max_seq_len": scaling["original_max_position_embeddings"],
+            "rope_beta_fast": scaling["beta_fast"], "rope_beta_slow": scaling["beta_slow"],
+            "rope_mscale_milli": round(1000 * scaling["mscale"]),
+            "rope_mscale_all_dim_milli": round(1000 * scaling["mscale_all_dim"]),
+        },
+        "tensors": {"q_a_norm": {"dist": "uniform", "lo": 2.0, "hi": 3.0},
+                    "expert_bias": {"dist": "normal", "std": 0.05}},
+    }
+    return cfg
+
+
+def make_tiny_dsv32(path: str, seed: int = 3, **over) -> dict:
+    """The same for `deepseek_v32`, as `make_tiny_afmoe`."""
+    return _write_tiny(path, tiny_dsv32_config(**over), seed)

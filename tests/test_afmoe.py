@@ -133,18 +133,26 @@ def test_eight_lanes_at_unequal_positions_one_parked(tmp_path):
     assert not np.array_equal(after["kw"][:, 6, :, ring], before["kw"][:, 6, :, ring])
 
 
-@pytest.mark.parametrize("family", ["afmoe", "pangu_ultra_moe"])
+@pytest.mark.parametrize("family", ["afmoe", "pangu_ultra_moe", "deepseek_v32"])
 def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(tmp_path, family):
     """The share tied to the model: one expert layer of a model that holds
     all 8 experts the router scores, in the reference; and the same layer
     as 8 chips would compute it, each holding one expert, in the program's
     own routing and expert code. The routed parts of the 8 shares plus the
     shared expert, counted once, are the uncut layer's output. Some token
-    has no expert on some chip, and gets nothing from it. Both families
-    that serve a held share: `afmoe` (a selection bias, scale 2.448) and
-    `pangu_ultra_moe` (none, scale 2.5)."""
+    has no expert on some chip, and gets nothing from it. The families
+    that serve a held share: `afmoe` (a selection bias, scale 2.448),
+    `pangu_ultra_moe` (none, scale 2.5) and `deepseek_v32` (a bias, scale
+    2.5, and a group limit: 2 of 4 groups of 2, so a token's two experts lie
+    on at most two of the four pairs of chips that hold a group)."""
+    groups = (1, 1)
     if family == "afmoe":
         ref, cfg, scale = afmoe, tiny(num_experts=8), 2.448
+    elif family == "deepseek_v32":
+        from benchmark.references import deepseek_v32 as ref
+        from helpers import tiny_dsv32_config
+
+        cfg, scale, groups = tiny_dsv32_config(n_routed_experts=8), 2.5, (4, 2)
     else:
         from benchmark.references import pangu_ultra_moe as ref
         from helpers import tiny_pangu_config
@@ -154,7 +162,7 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(tmp_path, family)
     layer = 2  # an expert layer; its row among the expert layers' stacks is 1
     lp = {k: v[1] for k, v in params["layers"].items()
           if k in ("moe_gate", "expert_bias", "w1", "w2", "w3", "shared_w1", "shared_w2", "shared_w3")}
-    assert ("expert_bias" in lp) == (family == "afmoe")
+    assert ("expert_bias" in lp) == (family != "pangu_ultra_moe")
     y = jnp.asarray(np.random.default_rng(2).standard_normal((1, 40, 64)), jnp.float32)
     f = ref.Q40File(path)
     w = ref.layer_weights(f, layer, cfg)
@@ -162,7 +170,7 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(tmp_path, family)
         y[0], w["shared_w1"], w["shared_w2"], w["shared_w3"]))
     parts, empty = [], 0
     for first in range(8):
-        route = tf.Routing(2, True, True, scale, first, 1, 8)
+        route = tf.Routing(2, True, True, scale, first, 1, 8, *groups)
         top_i, wts = tf._moe_route(y, lp["moe_gate"], route, lp.get("expert_bias"))
         held = route.held(top_i)
         part = tf._moe_ffn(

@@ -578,6 +578,109 @@ def test_layer_scan_writes_latent_rows_in_place(one_chip, monkeypatch, rows, win
     assert f"bf16[{lanes},128,{window}," not in text and f",{window},128,1" not in text
 
 
+# DeepSeek-V3.2's cut: 5 latent layers at 8192 + 512 rows, 4 lanes, and the
+# index keys' stack beside the latent rows
+DSV32_LATENT = (5, 4, 1, 8704, 576)
+DSV32_INDEX = (5, 4, 1, 8704, 128)
+
+
+def dsv32_layers(s):
+    """DeepSeek-V3.2's per-layer leaves as the loader stacks them: the
+    latent projections and the index's over all 5 layers, the leading dense
+    layer's FFN apart, and 4 expert layers of a router with its selection
+    bias, a shared expert and the 32 experts held of 256. No post-norms."""
+    from dllama_tpu.ops.quant_matmul import FusedQuantWeight, QuantWeight
+
+    d, f, fd, e, n, ns, heads = 7168, 2048, 18432, 32, 5, 4, 128
+
+    def f32(*shape):
+        return sds(shape, jnp.float32, s)
+
+    def fused(layers, k, dims):
+        return FusedQuantWeight(q40_stack(layers, k, sum(dims), s), 1, tuple(dims))
+
+    def experts(k, width):
+        return QuantWeight(sds((ns, e, k, width), jnp.int8, s),
+                           sds((ns, e, k // 32, width), jnp.float32, s))
+
+    return dict(
+        att_norm=f32(n, d), ffn_norm=f32(n, d), q_a_norm=f32(n, 1536),
+        kv_a_norm=f32(n, 512),
+        wq_a=q40_stack(n, d, 1536, s), wq_b=q40_stack(n, 1536, heads * 192, s),
+        wkv_a=q40_stack(n, d, 576, s), wo=q40_stack(n, heads * 128, d, s),
+        wkv_b_k=sds((n, heads, 128, 512), jnp.bfloat16, s),
+        wkv_b_v=sds((n, heads, 512, 128), jnp.bfloat16, s),
+        idx_wq_b=q40_stack(n, 1536, 64 * 128, s), idx_wk=q40_stack(n, d, 128, s),
+        idx_k_norm=f32(n, 128), idx_k_bias=f32(n, 128), idx_w=f32(n, d, 64),
+        dense_w13=fused(1, d, (fd, fd)), dense_w2=q40_stack(1, fd, d, s),
+        moe_gate=f32(ns, d, 256), expert_bias=f32(ns, 256),
+        shared_w13=fused(ns, d, (f, f)), shared_w2=q40_stack(ns, f, d, s),
+        w1=experts(d, f), w3=experts(d, f), w2=experts(f, d),
+    )
+
+
+@pytest.mark.parametrize("rows,window", [(1, 8192), (512, 4096), (512, 8192)],
+                         ids=["decode", "prefill-4096", "prefill-8192"])
+def test_layer_scan_writes_latent_rows_and_index_keys_in_place(
+        one_chip, monkeypatch, rows, window):
+    """DeepSeek-V3.2's cut at its cell's context: latent attention over the
+    rows an index picks, two cache stacks under one position. A layer's
+    latent row and its index key are each one `dynamic-update-slice` a lane
+    into the carried stacks; the index reads a lane's keys by one slice of
+    the window's rows, the selection is a mask and gathers nothing, a
+    chunk's attention is the latent kernel over the stack where it lies
+    with the mask beside it: neither stack is ever copied whole, and no
+    instruction has a layer of one as its result."""
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+    from dllama_tpu.models import transformer as tf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = one_chip
+    h = LlmHeader(
+        arch=LlmArch.DEEPSEEK_V32, dim=7168, hidden_dim=18432, moe_hidden_dim=2048,
+        n_layers=5, n_heads=128, n_kv_heads=128, n_experts=32, n_active_experts=8,
+        vocab_size=16160, seq_len=8192, head_dim=192, rope_type=RopeType.YARN,
+        rope_scaling_factor=40.0, rope_scaling_orig_max_seq_len=4096,
+        rope_mscale=1.0, rope_mscale_all_dim=1.0, norm_epsilon=1e-6,
+        n_dense_layers=1, n_shared_experts=1, score_sigmoid=True, route_scale=2.5,
+        n_routed_experts=256, n_group=8, topk_group=4, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        index_n_heads=64, index_head_dim=128, index_topk=2048,
+    )
+    lanes = DSV32_LATENT[1]
+
+    def step(x, layers, c, i, pos, cos, sin):
+        counts = []
+        out = tf.run_layers(
+            x, layers, None, None, h, pos, jnp.where(pos >= 8192, -8704, pos),
+            cos, sin, attn_window=window, c_cache=c, i_cache=i, route_stats=counts,
+            one_live_lane=rows > 1,
+        )
+        return out, counts
+
+    text = compiled_text(
+        jax.jit(step, donate_argnums=(2, 3)),
+        sds((lanes, rows, 7168), jnp.bfloat16, s), dsv32_layers(s),
+        sds(DSV32_LATENT, jnp.bfloat16, s), sds(DSV32_INDEX, jnp.bfloat16, s),
+        sds((lanes,), jnp.int32, s),
+        sds((lanes, rows, 32), jnp.float32, s), sds((lanes, rows, 32), jnp.float32, s),
+    )
+    assert text.count("dynamic-update-slice(") >= 2
+    for stack in (DSV32_LATENT, DSV32_INDEX):
+        assert not cache_copies(text, stack), cache_copies(text, stack)
+    assert "moe_held_experts_q40" in text
+    assert ("latent_flash_attention" in text) == (rows > 1)
+    # the selection counts and compares; the router's top-k is the one sort
+    assert "index_select" in text and "index_score" in text
+    assert not [ln for ln in text.splitlines() if " sort(" in ln and "/index_" in ln]
+    assert f"bf16[{lanes},128,{window}," not in text and f",{window},128,1" not in text
+    if rows == 1:
+        # the held eighth of the vocabulary, 16160 rows: no multiple of 128 divides it
+        from dllama_tpu.ops.quant_matmul import qmatmul_2d
+
+        compiled_text(qmatmul_2d, *q40_args(lanes, 7168, 16160, s))
+
+
 def test_layer_scan_writes_cache_rows_in_place_tp4(tp4, monkeypatch):
     """The same prefill chunk at tp=4: KH is the stack's sharded axis
     (`P(None, "dp", "tp", None, None)` into the flash kernel's `shard_map`,
