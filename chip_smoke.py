@@ -260,16 +260,25 @@ def free_engine(engine) -> None:
 
 def record_decode(engine) -> list:
     """Keep what the scheduler's decode dispatches return (token ids per
-    lane): the HTTP body carries text only."""
+    lane): the HTTP body carries text only. The scheduler dispatches a
+    block and collects it a tick later; a call's rows are filled in then."""
     calls: list = []
-    real = engine.decode_lanes
+    dispatch, collect = engine.dispatch_lanes, engine.collect_lanes
 
-    def decode_lanes(tokens, pos, n_steps, active=None, *a, **kw):
-        rows = real(tokens, pos, n_steps, active, *a, **kw)
-        calls.append((list(tokens), list(pos), list(active or []), rows))
+    def dispatch_lanes(tokens, pos, n_steps, active=None, *a, **kw):
+        block = dispatch(tokens, pos, n_steps, active, *a, **kw)
+        if block is not None:
+            calls.append([list(tokens), list(pos), list(active or []), block])
+        return block
+
+    def collect_lanes(block):
+        rows = collect(block)
+        for call in calls:
+            if call[3] is block:
+                call[3] = rows
         return rows
 
-    engine.decode_lanes = decode_lanes
+    engine.dispatch_lanes, engine.collect_lanes = dispatch_lanes, collect_lanes
     return calls
 
 

@@ -309,6 +309,16 @@ class _AdmittingLane:
     from_park: bool = False
 
 
+@dataclass
+class _BlockInFlight:
+    """A decode block the scheduler dispatched and has not collected."""
+
+    block: object  # the engine's handle (`LaneBlock`)
+    # the _LaneState each live lane ran for, None elsewhere: a lane whose
+    # stream ended or changed since has its rows dropped at the collect
+    states: list
+
+
 def _env_int(name: str, default: int) -> int:
     import os
 
@@ -487,6 +497,12 @@ class LaneScheduler:
         # scheduler tests replace it; production uses the monotonic timer)
         self._clock = time.perf_counter
         self._last_decode_end: float | None = None
+        # the decode loop runs one block ahead: the block dispatched and
+        # not yet collected, and why the last one was collected before
+        # its successor was dispatched (the next block's `reason`)
+        self._flight: _BlockInFlight | None = None
+        self._drained_why: str | None = None
+        self._n_pending = 0  # requests left waiting after the tick's admissions
         # transient-dispatch retry policy (resolve_resilience_knobs):
         # attempts after the first failure, exponential backoff base.
         # _sleep is injectable so chaos tests don't pay real backoff.
@@ -588,18 +604,23 @@ class LaneScheduler:
 
     # -- failure classification + recovery (PR 12) -------------------------
 
-    def _retry_dispatch(self, what: str, fn):
+    def _retry_dispatch(self, what: str, fn, failed: Exception | None = None):
         """Bounded exponential-backoff retry for engine dispatches whose
         failure left the donated buffers intact: the cache epoch did not
         move, so the guard never fired, lane KV is exactly as it was
         before the call, and re-issuing the dispatch is safe and
         idempotent. A failure that DID move the epoch re-raises
         immediately — retrying against the rebuilt (zeroed) cache would
-        decode garbage; the caller's recovery path owns that class."""
+        decode garbage; the caller's recovery path owns that class.
+        ``failed`` is a first attempt the caller made itself (a block
+        dispatched ahead) that raised on an intact cache."""
         attempt = 0
         while True:
             epoch = self.engine.cache_epoch
             try:
+                if failed is not None:
+                    e, failed = failed, None
+                    raise e
                 return fn()
             except Exception as e:
                 if (
@@ -665,6 +686,7 @@ class LaneScheduler:
         RETRYABLE error and keep the scheduler thread alive — the
         pre-PR 12 behavior, now with clients told to come back."""
         err = {"message": str(e), "retryable": True}
+        self._discard_flight()
         for lane in range(len(self.lanes)):
             if self.lanes[lane] is not None:
                 self._fail_active(lane, err)
@@ -696,6 +718,9 @@ class LaneScheduler:
         admission dispatch poisoned the cache — gets a structured
         retryable error."""
         err = {"message": str(e), "retryable": True}
+        # a block still in flight decoded from the cache that went, for
+        # lane states that resume from their histories: dropped un-read
+        self._discard_flight()
         native = self.kv is not None and getattr(self.kv, "native", False)
         if native:
             # pool-native lanes decode straight out of the pool; the
@@ -781,6 +806,7 @@ class LaneScheduler:
                     and not self.pending
                     and not any(self.lanes)
                     and not self.admitting
+                    and self._flight is None
                 ):
                     # going idle is not a stall: without this the last
                     # beat still says "lanes active" and an idle server
@@ -834,6 +860,7 @@ class LaneScheduler:
                     admissions.append((lane, job))
                 n_pending = len(self.pending)
                 self.state.m_queue_depth.set(n_pending)
+            self._n_pending = n_pending  # this thread's own, read in the tick
             # liveness heartbeat: the watchdog's scheduler-stalled rule
             # audits the gap between these
             wd = self.state.watchdog
@@ -865,7 +892,11 @@ class LaneScheduler:
             # oversubscription (PR 16): requests queued while every lane
             # is busy and --max-streams allows more concurrency — park
             # the most-progressed lane (publish + drop page list); it
-            # frees this tick and the queued request admits next tick
+            # frees this tick and the queued request admits next tick.
+            # A park publishes a live lane's history, so a block in
+            # flight is collected first: every token it holds is read
+            if self._flight is not None and self._may_evict(n_pending):
+                self._guarded_step(lambda: self._drain("park"))
             t_evict = time.monotonic()
             parked = self._maybe_park(n_pending)
             # deadline preemption (ISSUE 20): park an over-budget /
@@ -887,59 +918,73 @@ class LaneScheduler:
             # pending jobs can never prefill back-to-back while another
             # lane is mid-stream
             self._admission_tick()
-            if any(self.lanes):
-                epoch0 = self.engine.cache_epoch
-                try:
-                    self._step_block()
-                except Exception as e:
-                    # the scheduler thread must survive any engine error
-                    # (the reference's crash-retry loop plays this role
-                    # for its single stream, dllama-api.cpp:616-628).
-                    # _retry_dispatch already absorbed transient failures;
-                    # what reaches here is classified by the cache epoch:
-                    # moved => the dispatch guard rebuilt the donated
-                    # cache (every lane's slab KV is gone) and the lanes
-                    # RESUME from the shared page pool; unchanged =>
-                    # retries exhausted on an intact cache — fail the
-                    # in-flight requests with a structured retryable
-                    # error and keep serving.
-                    import logging
-
-                    poisoned = self.engine.cache_epoch != epoch0
-                    logging.getLogger(__name__).exception(
-                        "lane scheduler step failed (%s); %s",
-                        "cache poisoned" if poisoned else "cache intact",
-                        "recovering lanes" if poisoned
-                        else "dropping in-flight lanes",
-                    )
-                    self.state.m_sched_errors.inc()
-                    self.state.recorder.record(
-                        "scheduler_error",
-                        error=str(e),
-                        error_type=type(e).__name__,
-                        poisoned=poisoned,
-                        n_lanes=sum(
-                            1 for ls in self.lanes if ls is not None
-                        ),
-                    )
-                    # black-box dump: the ring holds the dispatches that
-                    # led here (written only when a postmortem dir is set)
-                    self.state.recorder.postmortem("scheduler-loop", e)
-                    if poisoned:
-                        # batched dispatch: no single lane is culpable, so
-                        # every lane resumes (none of them caused it)
-                        self._recover(e, culprit=None)
-                    else:
-                        self._drop_all(e)
-                    with self.cv:
-                        self.cv.notify_all()
+            if any(self.lanes) or self._flight is not None:
+                self._guarded_step(self._step_block)
             self.state.spans.end(tick_sp)
             if not any(self.lanes):
                 # decode went idle: the next dispatch starts a new stall
                 # window, don't charge it for the quiet period
                 self._last_decode_end = None
 
+    def _guarded_step(self, step) -> None:
+        """Run one decode-side step of the tick (`_step_block`, or the
+        collect before a park). The scheduler thread must survive any
+        engine error (the reference's crash-retry loop plays this role
+        for its single stream, dllama-api.cpp:616-628). _retry_dispatch
+        already absorbed transient failures; what reaches here is
+        classified by the cache epoch: moved => the dispatch guard
+        rebuilt the donated cache (every lane's slab KV is gone) and the
+        lanes RESUME from the shared page pool; unchanged => retries
+        exhausted on an intact cache — fail the in-flight requests with
+        a structured retryable error and keep serving."""
+        epoch0 = self.engine.cache_epoch
+        try:
+            step()
+        except Exception as e:
+            import logging
+
+            poisoned = self.engine.cache_epoch != epoch0
+            logging.getLogger(__name__).exception(
+                "lane scheduler step failed (%s); %s",
+                "cache poisoned" if poisoned else "cache intact",
+                "recovering lanes" if poisoned
+                else "dropping in-flight lanes",
+            )
+            self.state.m_sched_errors.inc()
+            self.state.recorder.record(
+                "scheduler_error",
+                error=str(e),
+                error_type=type(e).__name__,
+                poisoned=poisoned,
+                n_lanes=sum(
+                    1 for ls in self.lanes if ls is not None
+                ),
+            )
+            # black-box dump: the ring holds the dispatches that
+            # led here (written only when a postmortem dir is set)
+            self.state.recorder.postmortem("scheduler-loop", e)
+            if poisoned:
+                # batched dispatch: no single lane is culpable, so
+                # every lane resumes (none of them caused it)
+                self._recover(e, culprit=None)
+            else:
+                self._drop_all(e)
+            with self.cv:
+                self.cv.notify_all()
+
     # -- oversubscription: park / resume (PR 16) ---------------------------
+
+    def _may_evict(self, n_pending: int) -> bool:
+        """What a park and a preemption both need: one of the two is
+        switched on, requests wait, the shared pool can hold a parked
+        stream, no admission is under way and no lane is free."""
+        if not (
+            self.max_streams > len(self.lanes) or self.state.admission_predict
+        ):
+            return False
+        if n_pending <= 0 or self.kv is None or self.admitting:
+            return False
+        return all(ls is not None for ls in self.lanes)
 
     def _maybe_park(self, n_pending: int) -> bool:
         """Park ONE active lane when requests wait, no lane is free, and
@@ -950,17 +995,7 @@ class LaneScheduler:
         rotates lanes round-robin instead of thrashing park/resume.
         ``n_pending`` is the tick's queue-depth snapshot (taken under
         the cv in _loop). Returns whether a stream was parked."""
-        if (
-            self.max_streams <= len(self.lanes)
-            or self.kv is None
-            or n_pending <= 0
-            or self.admitting
-        ):
-            return False
-        if any(
-            self.lanes[i] is None and i not in self.admitting
-            for i in range(len(self.lanes))
-        ):
+        if self.max_streams <= len(self.lanes) or not self._may_evict(n_pending):
             return False
         victim, best = -1, self.block_size - 1
         for lane, ls in enumerate(self.lanes):
@@ -987,14 +1022,7 @@ class LaneScheduler:
         if (
             not st.admission_predict
             or st.predictor is None
-            or self.kv is None
-            or n_pending <= 0
-            or self.admitting
-        ):
-            return False
-        if any(
-            self.lanes[i] is None and i not in self.admitting
-            for i in range(len(self.lanes))
+            or not self._may_evict(n_pending)
         ):
             return False
         head, head_key = None, None
@@ -1900,6 +1928,17 @@ class LaneScheduler:
             )
 
     def _step_block(self) -> None:
+        """The decode side of one tick. Where nothing holds it back
+        (`_why_not_ahead`) the loop runs one block ahead: with block n
+        in flight, block n + 1 is built from what the host knows without
+        n's tokens (positions advance by n's steps, a lane whose length
+        ends inside n drops out, a continuing lane's input token stays
+        on the device) and dispatched BEFORE n is collected, so the
+        device has it queued while the host emits n's tokens, finishes
+        and publishes streams and runs the next admission tick. Where
+        something does (nobody waits for a lane, above all), the tick is
+        dispatch, then collect, as it always was, and a block still in
+        flight is collected first."""
         b = len(self.lanes)
         spans = self.state.spans
         # step_prep: the cancel sweep, speculative drafts and list
@@ -1907,12 +1946,16 @@ class LaneScheduler:
         prep_sp = spans.begin("step_prep", component="scheduler")
         try:
             # free lanes whose client went away before paying for more
-            # decode
+            # decode (a block in flight drops such a lane's rows)
             for lane in range(b):
                 ls = self.lanes[lane]
                 if ls is not None and ls.job.cancelled:
                     self._finish(lane, "cancelled")
-            if not any(ls is not None for ls in self.lanes):
+            held = self._why_not_ahead()
+            if self._flight is not None and held is not None:
+                # the next tick dispatches after its admission work
+                spans.end(prep_sp)
+                self._drain(held)
                 return
             # speculative verify first: greedy lanes whose drafter
             # proposes a continuation take ONE batched verify dispatch;
@@ -1928,72 +1971,192 @@ class LaneScheduler:
                     self._spec_verify(drafts)
                     verified = set(drafts)
                     prep_sp = spans.begin("step_prep", component="scheduler")
-            active = [
-                ls is not None and lane not in verified
-                for lane, ls in enumerate(self.lanes)
-            ]
-            if not any(active):
-                return
-            tokens = [ls.token if ls else 0 for ls in self.lanes]
-            pos = [ls.pos if ls else 0 for ls in self.lanes]
-            temps = [ls.temperature if ls else 0.0 for ls in self.lanes]
-            topps = [ls.top_p if ls else 1.0 for ls in self.lanes]
-            seeds = [ls.seed if ls else None for ls in self.lanes]
-            # decode stall: the gap since the previous decode-block
-            # dispatch finished, while >=1 lane was active the whole time
-            # — whatever sat in between (admission chunks, host work) is
-            # latency a streaming client ate. Chunked admission bounds it
-            # by one chunk + one block.
-            now = self._clock()
-            if self._last_decode_end is not None:
-                self.state.m_decode_stall.observe(
-                    now - self._last_decode_end
-                )
+            args = self._block_args(verified)
+            if args is None and self._flight is not None:
+                spans.end(prep_sp)
+                self._drain("no_live_lane")
         finally:
             spans.end(prep_sp)
-        t0 = time.perf_counter()
-        wd = self.state.watchdog
+        if args is None:
+            return
+        failed = None
+        if self._flight is not None:
+            epoch = self.engine.cache_epoch
+            try:
+                ahead = self._dispatch_block(args)
+            except Exception as e:
+                # what is in flight was decoded before the fault: its
+                # tokens are good, and are emitted before the recovery
+                # (epoch moved) or the retry (cache intact) that follows
+                self._drain("fault")
+                if self.engine.cache_epoch != epoch:
+                    raise
+                failed, args = e, self._block_args(verified)
+                if args is None:
+                    return
+                held = self._why_not_ahead()
+            else:
+                flight, self._flight = self._flight, ahead
+                self._collect_block(flight, since=ahead.block.t1)
+                return
+        self._flight = self._retry_dispatch(
+            "decode_lanes", lambda: self._dispatch_block(args), failed
+        )
+        if self._flight is None:
+            # every decode-side lane is out of sequence space (verified
+            # lanes already advanced this tick and are not touched)
+            for lane, ls in enumerate(args[0]):
+                if ls is not None and self.lanes[lane] is ls:
+                    self._finish(lane, "length")
+        elif held is not None:
+            self._drain(held, since=self._flight.block.t1)
+
+    def _why_not_ahead(self) -> str | None:
+        """What holds the decode loop to dispatch, then collect, in one
+        tick; None where a block may stay in flight into the next tick
+        and the next block be dispatched ahead of its collect. `paged`:
+        the pool's block takes no device-side input token. `verify`: a
+        lane with a drafter needs its newest tokens for its draft.
+        `no_queue`: nobody waits for a lane, so a request that arrives
+        takes one at once, and its first chunk would stand behind the
+        block queued ahead: its first token a block later than under
+        dispatch, then collect, whenever it finds the device busy. That
+        is the one token running ahead delivers later, so the loop runs
+        ahead only where a queue pays for it: there a first token waits
+        for a lane, not for a block, and the lanes come free sooner.
+        `park`: a park or a preemption publishes a live lane's
+        history."""
+        if self.engine.kv_native:
+            return "paged"
+        if self.spec_on and self.drafters:
+            return "verify"
+        if self._n_pending == 0:
+            return "no_queue"
+        if self._may_evict(self._n_pending):
+            return "park"
+        return None
+
+    def _block_args(self, verified: set[int]):
+        """The next decode block, None where no lane would run live:
+        the `_LaneState` a lane, None where it is not live, and
+        `dispatch_lanes`' arguments. A lane that runs in the block in flight
+        continues from it: its token stays on the device (None here) and
+        its position is past that block's steps; where its length ends
+        inside that block it has no step to come. Any other lane starts
+        from its host token."""
+        flight = self._flight
+        n = len(self.lanes)
+        states, tokens, pos = [None] * n, [0] * n, [0] * n
+        for lane, ls in enumerate(self.lanes):
+            if ls is None or lane in verified:
+                continue
+            token, at = ls.token, ls.pos
+            if flight is not None and flight.states[lane] is ls:
+                token, at = None, ls.pos + flight.block.n_steps
+                if at >= ls.max_pos:
+                    continue
+            states[lane], tokens[lane], pos[lane] = ls, token, at
+        if not any(states):
+            return None
+        return states, (
+            tokens, pos, self.block_size,
+            [ls is not None for ls in states],
+            [ls.temperature if ls else 0.0 for ls in states],
+            [ls.top_p if ls else 1.0 for ls in states],
+            [ls.seed if ls else None for ls in states],
+        )
+
+    def _dispatch_block(self, args) -> "_BlockInFlight | None":
+        """Dispatch one decode block (the engine returns at the enqueue)
+        and count it by its order: `ahead` of the collect of the block in
+        flight, or `drained_first` with the reason that block was
+        collected before this dispatch (`first_block`: there was none)."""
+        states, call = args
+        st = self.state
+        # decode stall: the gap since the previous decode-block
+        # collect finished, while >=1 lane was active the whole time
+        # — whatever sat in between (admission chunks, host work) is
+        # latency a streaming client ate. Chunked admission bounds it
+        # by one chunk + one block.
+        if self._last_decode_end is not None:
+            st.m_decode_stall.observe(self._clock() - self._last_decode_end)
+        wd = st.watchdog
         if wd is not None:
             wd.dispatch_begin("decode_lanes")
         try:
-            rows = self._retry_dispatch(
-                "decode_lanes",
-                lambda: self.engine.decode_lanes(
-                    tokens, pos, self.block_size, active, temps, topps,
-                    seeds=seeds
-                ),
-            )
+            block = self.engine.dispatch_lanes(*call)
         finally:
             if wd is not None:
                 wd.dispatch_end()
-        self._last_decode_end = self._clock()
-        if rows:
-            # every active stream advanced len(rows) tokens in this block
-            self.state.m_tpot.observe(
-                (time.perf_counter() - t0) / len(rows)
-            )
-            self.state.slo.note_tokens(
-                len(rows) * sum(1 for a in active if a)
-            )
-        if not rows:
-            # every decode-side lane is out of sequence space (verified
-            # lanes already advanced this tick and are not touched)
-            for lane in range(b):
-                if self.lanes[lane] is not None and active[lane]:
-                    self._finish(lane, "length")
-            return
-        # emit: one span for the block's whole row-by-lane token loop
-        # (detokenise, stop checks, events.put, perhaps _finish)
-        n_tokens = n_finished = 0
-        emit_sp = spans.begin("emit", component="scheduler")
+        if block is None:
+            return None
+        if self._flight is not None:
+            st.m_decode_blocks.labels(order="ahead", reason="").inc()
+        else:
+            st.m_decode_blocks.labels(
+                order="drained_first",
+                reason=self._drained_why or "first_block",
+            ).inc()
+            self._drained_why = None
+        return _BlockInFlight(block=block, states=states)
+
+    def _drain(self, reason: str, since: float | None = None) -> None:
+        """Collect the block in flight now, before the next is
+        dispatched, and keep `reason` for that block's count."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._drained_why = reason
+            self._collect_block(flight, since)
+
+    def _discard_flight(self) -> None:
+        """Abandon the block in flight un-read: its streams were dropped,
+        or resume from their histories on a fresh cache."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self.engine.discard_lanes(flight.block)
+
+    def _collect_block(
+        self, flight: _BlockInFlight, since: float | None = None
+    ) -> None:
+        """Wait for a dispatched block and emit its rows: one span for
+        the block's whole row-by-lane token loop (detokenise, stop
+        checks, events.put, perhaps _finish). A lane whose stream ended
+        on an earlier block's tokens (EOS, a stop string, a client that
+        went away) ran this block for nobody: its rows are dropped, and
+        what was published is its history up to that end. `since`: where
+        the span before this collect ended on the tick's thread (the
+        call of the block dispatched just now), so that the tick's
+        spans leave no stretch between them to nobody."""
+        st, spans = self.state, self.state.spans
+        wd = st.watchdog
+        # collect: the wait for the block (the engine's `.device` span
+        # inside it) and the engine's accounting of what it read back
+        collect_sp = spans.begin("collect", component="scheduler", at=since)
+        if wd is not None:
+            wd.dispatch_begin("decode_lanes")
         try:
+            rows = self.engine.collect_lanes(flight.block)
+        finally:
+            if wd is not None:
+                wd.dispatch_end()
+            collected = time.monotonic()
+            spans.end(collect_sp, at=collected)
+        n_tokens = n_finished = 0
+        emit_sp = spans.begin("emit", component="scheduler", at=collected)
+        try:
+            self._last_decode_end = self._clock()
+            live = [
+                lane for lane, ls in enumerate(flight.states)
+                if ls is not None and self.lanes[lane] is ls
+            ]
+            # every active stream advanced len(rows) tokens in this block
+            st.m_tpot.observe(flight.block.seconds / len(rows))
+            st.slo.note_tokens(len(rows) * len(live))
             for row in rows:
-                for lane in range(b):
-                    if self.lanes[lane] is None or not active[lane]:
-                        continue
+                for lane in list(live):
                     n_tokens += 1
                     if not self._consume_token(lane, row[lane]):
-                        active[lane] = False
+                        live.remove(lane)
                         n_finished += 1
         finally:
             spans.end(emit_sp, n_tokens=n_tokens, n_finished=n_finished)
@@ -2209,6 +2372,15 @@ class ApiState:
             "dllama_admission_chunks_total",
             "Bounded prefill chunks dispatched by the chunked admission "
             "state machine (one per scheduler tick per admitting lane).",
+        )
+        self.m_decode_blocks = self.obs.counter(
+            "dllama_sched_decode_blocks_total",
+            "Decode blocks by the order of their dispatch: ahead = enqueued "
+            "before the block in flight was read back (the device keeps a "
+            "program queued), drained_first = after it, with the reason: "
+            "first_block (none was in flight), no_queue, verify, park, "
+            "fault, paged, no_live_lane.",
+            labelnames=("order", "reason"),
         )
         self.m_decode_stall = self.obs.histogram(
             "dllama_decode_stall_seconds",

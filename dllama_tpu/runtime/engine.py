@@ -168,6 +168,22 @@ class StepStats:
     n_tokens: int
 
 
+@dataclasses.dataclass
+class LaneBlock:
+    """A decode block that `dispatch_lanes` enqueued and nobody has read
+    back: what `collect_lanes` needs to wait for it and account for it."""
+
+    out: object  # the program's un-read output, [n_steps, lanes (+ counts)]
+    n_steps: int  # after the clamp at the context's end
+    live: frozenset  # the lanes the program ran live
+    native: bool
+    enqueued: int  # the engine's count of dispatches at its own
+    t0: float  # its dispatch's begin, on the spans' clock
+    t1: float  # its call's end: the program is enqueued
+    fields: dict  # what `step_complete` repeats of `step_dispatch`
+    seconds: float | None = None  # `step_complete`'s `ms`, once collected
+
+
 class InferenceEngine:
     """See module docstring. `batch_size` > 1 turns the batch axis into
     independent decoding lanes (`generate_batch`) — the data-parallel
@@ -498,6 +514,16 @@ class InferenceEngine:
         # the clock reading since which the device has had nothing to do;
         # None while something enqueued has not been read back
         self._drained_at = None
+        # programs enqueued so far that are no pool copy (counted where the
+        # call returned: one that raised enqueued nothing): a block's handle
+        # keeps the count at its own, so its collect can tell whether
+        # anything was enqueued behind it
+        self._enqueued = 0
+        # the newest lane block while it is dispatched and not collected,
+        # and the end of the newest collect (`collect_lanes`' `ms` starts
+        # no earlier)
+        self._uncollected: LaneBlock | None = None
+        self._collected_at = 0.0
         self.cache = self._fresh_cache()
         g_bytes = self.obs.gauge(
             "dllama_kv_cache_bytes",
@@ -523,6 +549,11 @@ class InferenceEngine:
             "kv_cache", ring=self.kv_ring, **{f"{k}_bytes": v for k, v in self.kv_cache_bytes.items()}
         )
         self._token_sharding = NamedSharding(self.mesh, P("dp", None))
+        # each lane's last token of the newest decode block, on the device:
+        # the next block's `last` (`_lane_decode_fn`)
+        self._lane_last = jax.device_put(
+            np.zeros((self.batch_size, 1), np.int32), self._token_sharding
+        )
         # AOT lowering specs are SNAPSHOTTED once here (r5 advisor item):
         # params never change after init and every fresh cache has the
         # same shapes/dtypes/shardings, so the prefetch thread lowers
@@ -680,6 +711,8 @@ class InferenceEngine:
         self.cache_epoch = getattr(self, "cache_epoch", -1) + 1
         self._m_epochs.inc()
         self.recorder.record("cache_epoch", epoch=self.cache_epoch)
+        # a block in flight wrote the cache that went: nothing continues it
+        self._uncollected = None
         cache = init_kv_cache(
             self.header,
             self.batch_size,
@@ -723,16 +756,12 @@ class InferenceEngine:
     # copied): to the drained interval one is host work like any other
     _POOL_COPIES = frozenset(("kv_adopt", "kv_publish", "kv_page_copy"))
 
-    @contextlib.contextmanager
-    def _dispatch(self, step: str, prep=None, head=None, host_args=0, **fields):
-        """Time one dispatch of a compiled program, once, for everything
-        that wants it: the recorder's ``step_dispatch``/``step_complete``
-        pair (``ms`` on the latter), the ``engine`` span named ``step``
-        and the step histogram all get the same two clock readings, and
-        the yielded dict gets ``seconds``. ``prep`` is the caller's open
-        ``dispatch_prep`` span, which ends where the dispatch begins; the
-        read-back wait inside is ``_read_back``'s. A dispatch that raises
-        ends its span and completes nothing.
+    def _begin_dispatch(
+        self, step: str, prep=None, head=None, host_args=0, **fields
+    ) -> float:
+        """The begin of one dispatch of a compiled program: ends ``prep``,
+        the caller's open ``dispatch_prep`` span, reads the clock once
+        (returned: the dispatch's ``t0``) and records ``step_dispatch``.
 
         ``step_dispatch`` says what the host did before the call:
         ``prep_ms`` from ``prep``'s begin (a method with no such span:
@@ -746,10 +775,11 @@ class InferenceEngine:
         ``dllama_engine_device_drained_seconds_total{before}`` and
         ``drained_ms`` on ``step_dispatch``. The first enqueue clears the
         mark, so a dispatch behind an un-read one (a block behind a
-        chunk) records nothing; a pool copy neither ends the interval
-        nor clears the mark."""
-        self._spans.end(prep)
+        chunk or behind a block) records nothing; a pool copy neither
+        ends the interval nor clears the mark, nor counts as an enqueue
+        to the read-back that asks whether its program is the newest."""
         t0 = time.monotonic()
+        self._spans.end(prep, at=t0)
         host = {"host_args": host_args}
         begun = prep.t0 if prep is not None else head
         if begun is not None:
@@ -763,6 +793,31 @@ class InferenceEngine:
             self._m_drained.labels(before=step).inc(t0 - since)
             host["drained_ms"] = round((t0 - since) * 1000, 3)
         self.recorder.record("step_dispatch", step=step, **fields, **host)
+        return t0
+
+    def _complete_dispatch(self, step: str, t0: float, t1: float, **fields) -> float:
+        """The end of one dispatch: the step histogram and the recorder's
+        ``step_complete`` (``ms``) from the same two clock readings;
+        returns their distance in seconds."""
+        self._m_step.labels(kind=step).observe(t1 - t0)
+        self.recorder.record(
+            "step_complete", step=step, **fields,
+            ms=round((t1 - t0) * 1000, 3),
+        )
+        return t1 - t0
+
+    @contextlib.contextmanager
+    def _dispatch(self, step: str, prep=None, head=None, host_args=0, **fields):
+        """Time one dispatch of a compiled program that is read back, or
+        left un-read, inside the ``with``, once, for everything that
+        wants it: the recorder's ``step_dispatch``/``step_complete``
+        pair (``ms`` on the latter), the ``engine`` span named ``step``
+        and the step histogram all get the same two clock readings
+        (``_begin_dispatch``, ``_complete_dispatch``), and the yielded
+        dict gets ``seconds``. The read-back wait inside is
+        ``_read_back``'s. A dispatch that raises ends its span and
+        completes nothing."""
+        t0 = self._begin_dispatch(step, prep, head, host_args, **fields)
         timed = {}
         sp = self._spans.begin(step, component="engine", at=t0, **fields)
         try:
@@ -772,12 +827,9 @@ class InferenceEngine:
             raise
         t1 = time.monotonic()
         self._spans.end(sp, at=t1)
-        timed["seconds"] = t1 - t0
-        self._m_step.labels(kind=step).observe(t1 - t0)
-        self.recorder.record(
-            "step_complete", step=step, **fields,
-            ms=round((t1 - t0) * 1000, 3),
-        )
+        if step not in self._POOL_COPIES:
+            self._enqueued += 1
+        timed["seconds"] = self._complete_dispatch(step, t0, t1, **fields)
 
     def _rows_in_context(self, starts: list[int], n: int) -> dict:
         """`step_dispatch` fields of a model with two kinds of cache: the
@@ -860,14 +912,17 @@ class InferenceEngine:
         posv[lane] = pos0
         return rows, posv
 
-    def _read_back(self, step: str, out) -> np.ndarray:
+    def _read_back(self, step: str, out, newest: bool = True) -> np.ndarray:
         """The device-complete wait: the program call returned as soon as
         it was enqueued, and the read-back waits for the device. Its own
         ``<step>.device`` span, so a timeline splits dispatch overhead
-        from device time. ``out`` is the newest program's output and the
-        device runs programs in the order they were enqueued, so at the
-        wait's end it is drained: the same clock reading ends the span
-        and marks that for the next ``_dispatch``."""
+        from device time. The device runs programs in the order they
+        were enqueued, so where ``out`` is the ``newest`` program's
+        output it is drained at the wait's end: the same clock reading
+        ends the span and marks that for the next ``_begin_dispatch``.
+        With a program enqueued behind the awaited one (a block
+        dispatched ahead of this collect) the device goes on, and no
+        mark is set."""
         sp = self._spans.begin(f"{step}.device", component="engine")
         try:
             host = np.asarray(out)
@@ -876,7 +931,8 @@ class InferenceEngine:
             raise
         t1 = time.monotonic()
         self._spans.end(sp, at=t1)
-        self._drained_at = t1
+        if newest:
+            self._drained_at = t1
         return host
 
     def _fault(self, op: str):
@@ -1838,6 +1894,7 @@ class InferenceEngine:
                 raise rebuild_err from e
             self.kv_pool_epoch += 1
             if self.kv_native:
+                self._uncollected = None
                 self.cache_epoch += 1
                 self._m_epochs.inc()
                 self.recorder.record(
@@ -2311,6 +2368,7 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((b,), jnp.int32),  # per-lane seeds
             jax.ShapeDtypeStruct((b,), jnp.float32),
             jax.ShapeDtypeStruct((b,), jnp.float32),
+            tok,  # the newest block's last tokens, on the device
         )
 
     def _lane_decode_fn(
@@ -2326,7 +2384,12 @@ class InferenceEngine:
         live lane (parked writes land beyond seq_len and are causally
         masked, so the window only limits reads). AOT-compiled like
         _decode_block_fn so the API server's window crossings can be
-        prefetched too (this IS the serving path)."""
+        prefetched too (this IS the serving path).
+
+        `last` is the third output of the block dispatched before this
+        one, each lane's last sampled token, still on the device: a lane
+        whose host `token` is negative starts from it, so the host can
+        dispatch this block before it has read the last one back."""
 
         def make():
             precision = self._precision
@@ -2339,9 +2402,13 @@ class InferenceEngine:
             # touched and the tokens that landed: four more columns of the
             # block's one output, so no read-back is added
             counting = self._counts_routing
+            token_sharding = self._token_sharding
 
             @partial(jax.jit, donate_argnums=(2,))
-            def block(params, token, cache, pos_vec, active, seeds, temperature, topp):
+            def block(params, token, cache, pos_vec, active, seeds, temperature,
+                      topp, last):
+                token = jnp.where(token < 0, last, token)
+
                 def body(i, carry):
                     tok, cache, out = carry
                     counts = [] if counting else None
@@ -2383,10 +2450,11 @@ class InferenceEngine:
                 out0 = jnp.zeros(
                     (n_steps, token.shape[0] + (4 if counting else 0)), jnp.int32
                 )
-                _, cache, out = lax.fori_loop(
+                last, cache, out = lax.fori_loop(
                     0, n_steps, body, (token, cache, out0)
                 )
-                return out, cache
+                # placed as the next block's arg spec states it
+                return out, cache, lax.with_sharding_constraint(last, token_sharding)
 
             return block
 
@@ -2395,29 +2463,27 @@ class InferenceEngine:
             lambda: self._lane_arg_specs(n_steps), origin,
         )
 
-    def decode_lanes(
+    def dispatch_lanes(
         self,
-        tokens: list[int],
+        tokens: list[int | None],
         pos: list[int],
         n_steps: int,
         active: list[bool] | None = None,
         temperature: list[float] | None = None,
         topp: list[float] | None = None,
         seeds: list[int | None] | None = None,
-    ) -> list[list[int]]:
-        """Decode `n_steps` tokens on every ACTIVE lane in one device
-        dispatch, each lane at its own position (and its own sampling
-        settings — temperature 0 decodes that lane greedily; a per-lane
-        `seeds[l]` makes that lane's sampled stream reproducible
-        regardless of the other lanes — r4's 'seed ignored in lane mode'
-        gap). Returns
-        [n_steps][lanes] (parked lanes report token 0). A lane that fills
-        its window MID-BLOCK parks itself on device and reports 0 for the
-        remaining rows — callers must stop consuming a lane's rows once
-        its position cap is reached (both the API scheduler and
-        generate_batch already do); the block length is clamped only by
-        the DEEPEST live lane, so one near-full lane doesn't reduce the
-        whole batch to tiny dispatches."""
+    ) -> LaneBlock | None:
+        """The dispatch half of `decode_lanes`: everything up to and
+        including the program call, which returns at the enqueue. Gives
+        the block's handle for `collect_lanes`, or None where no lane is
+        live or no step fits.
+
+        A lane whose `tokens[l]` is None continues from the last token
+        the block dispatched before this one sampled for it, which is
+        still on the device (the program's `last`): the caller passes
+        `pos[l]` = that block's `pos[l]` + its `n_steps` and needs no
+        read-back to dispatch. Such a lane has to have run live in that
+        block, and the slab's program alone takes it (not `kv_native`)."""
         self._require_lanes()
         if len(tokens) != self.batch_size or len(pos) != self.batch_size:
             raise ValueError("tokens/pos must have one entry per lane")
@@ -2425,12 +2491,22 @@ class InferenceEngine:
             active = [True] * self.batch_size
         live = [i for i, a in enumerate(active) if a]
         if not live:
-            return []
+            return None
         n_steps = min(
             n_steps, max(self.header.seq_len - pos[i] for i in live)
         )
         if n_steps <= 0:
-            return []
+            return None
+        native = self.kv_native
+        carried = [i for i in live if tokens[i] is None]
+        before = self._uncollected
+        if carried and (
+            native or before is None or not before.live.issuperset(carried)
+        ):
+            raise ValueError(
+                f"lanes {carried} continue from the device's last tokens, "
+                "which the block before this one did not sample for them"
+            )
         prep = self._dispatch_prep("decode_lanes")
         if temperature is None:
             temperature = [self.temperature] * self.batch_size
@@ -2442,7 +2518,6 @@ class InferenceEngine:
         deepest = max(pos[i] for i in live)
         window = self._attn_window(deepest + n_steps)
         self._note_window(window)
-        native = self.kv_native
         block = (
             self._lane_decode_paged_fn(n_steps, window)
             if native
@@ -2490,23 +2565,75 @@ class InferenceEngine:
             np.asarray(seed_vec, np.int32),
             np.asarray(temperature, np.float32),
             np.asarray(topp, np.float32),
-            tokens=np.asarray(tokens, np.int32).reshape(self.batch_size, 1),
+            tokens=np.asarray(
+                [-1 if t is None else t for t in tokens], np.int32
+            ).reshape(self.batch_size, 1),
         )
-        guard = self._kv_pool_guard if native else self._cache_guard
-        with self._dispatch(
-            "decode_lanes", prep, host_args=1 + len(rest),
+        fields = dict(
             pos=deepest, n_steps=n_steps,
             window=window, n_live=len(live), n_sampling=n_sampling,
+        )
+        # ahead: a block is enqueued and not collected, so the device has
+        # this one queued when that one ends
+        t0 = self._begin_dispatch(
+            "decode_lanes", prep, host_args=1 + len(rest), **fields,
+            ahead=int(self._uncollected is not None),
             **self._rows_in_context([pos[i] for i in live], n_steps),
-        ) as timed, guard():
-            if fault is not None:
-                raise fault
-            if native:
-                out, self.kv_pool = block(self.params, arr, self.kv_pool, *rest)
-            else:
-                out, self.cache = block(self.params, arr, self.cache, *rest)
-            out_np = self._read_back("decode_lanes", out)
-        if self._counts_routing and not native:
+        )
+        # the call: the enqueue and the transfer of its host arrays
+        sp = self._spans.begin("decode_lanes", component="engine", at=t0, **fields)
+        guard = self._kv_pool_guard if native else self._cache_guard
+        try:
+            with guard():
+                if fault is not None:
+                    raise fault
+                if native:
+                    out, self.kv_pool = block(self.params, arr, self.kv_pool, *rest)
+                else:
+                    out, self.cache, self._lane_last = block(
+                        self.params, arr, self.cache, *rest, self._lane_last
+                    )
+        except BaseException:
+            self._spans.end(sp, error=True)
+            raise
+        self._enqueued += 1
+        t1 = time.monotonic()
+        self._uncollected = LaneBlock(
+            out=out, n_steps=n_steps, live=frozenset(live), native=native,
+            enqueued=self._enqueued, t0=t0, t1=t1, fields=fields,
+        )
+        self._spans.end(sp, at=t1)
+        return self._uncollected
+
+    def discard_lanes(self, block: LaneBlock) -> None:
+        """Abandon a dispatched block un-read (the scheduler dropped or
+        resumes its streams): no lane continues from it, and the next
+        block is not ahead of a collect that never comes."""
+        if self._uncollected is block:
+            self._uncollected = None
+
+    def collect_lanes(self, block: LaneBlock) -> list[list[int]]:
+        """The collect half of `decode_lanes`: wait for the block, read
+        its rows back and count what it counted. Blocks are collected in
+        the order they were dispatched. `step_complete`'s `ms` runs from
+        the later of the block's dispatch and the end of the collect
+        before it (with a block dispatched ahead, about where the device
+        began it) to this collect's end."""
+        if self._uncollected is block:
+            self._uncollected = None
+        guard = self._kv_pool_guard if block.native else self._cache_guard
+        with guard():
+            out_np = self._read_back(
+                "decode_lanes", block.out,
+                newest=block.enqueued == self._enqueued,
+            )
+        t1 = time.monotonic()
+        block.seconds = self._complete_dispatch(
+            "decode_lanes", max(block.t0, self._collected_at), t1,
+            **block.fields,
+        )
+        self._collected_at = t1
+        if self._counts_routing and not block.native:
             routed, held, touched, landed = (
                 int(n) for n in out_np[:, self.batch_size:].sum(axis=0)
             )
@@ -2515,14 +2642,47 @@ class InferenceEngine:
             self._m_moe_pairs.labels(landed="held").inc(held)
             self._m_moe_touched.inc(touched)
             self.recorder.record(
-                "moe_route", step="decode_lanes", n_steps=n_steps,
+                "moe_route", step="decode_lanes", n_steps=block.n_steps,
                 pairs_routed=routed, pairs_held=held, held_touched=touched,
                 tokens_landed=landed,
             )
         # each active stream advances one token per block row
-        self._m_tpot.observe(timed["seconds"] / n_steps)
-        self._m_sampler.labels(sampler="full" if n_sampling else "greedy").inc()
+        self._m_tpot.observe(block.seconds / block.n_steps)
+        self._m_sampler.labels(
+            sampler="full" if block.fields["n_sampling"] else "greedy"
+        ).inc()
         return [[int(t) for t in row] for row in out_np]
+
+    def decode_lanes(
+        self,
+        tokens: list[int],
+        pos: list[int],
+        n_steps: int,
+        active: list[bool] | None = None,
+        temperature: list[float] | None = None,
+        topp: list[float] | None = None,
+        seeds: list[int | None] | None = None,
+    ) -> list[list[int]]:
+        """Decode `n_steps` tokens on every ACTIVE lane in one device
+        dispatch, each lane at its own position (and its own sampling
+        settings — temperature 0 decodes that lane greedily; a per-lane
+        `seeds[l]` makes that lane's sampled stream reproducible
+        regardless of the other lanes — r4's 'seed ignored in lane mode'
+        gap). Returns
+        [n_steps][lanes] (parked lanes report token 0). A lane that fills
+        its window MID-BLOCK parks itself on device and reports 0 for the
+        remaining rows — callers must stop consuming a lane's rows once
+        its position cap is reached (both the API scheduler and
+        generate_batch already do); the block length is clamped only by
+        the DEEPEST live lane, so one near-full lane doesn't reduce the
+        whole batch to tiny dispatches.
+
+        `dispatch_lanes` and `collect_lanes` back to back: the order of a
+        caller that does not run a block ahead."""
+        block = self.dispatch_lanes(
+            tokens, pos, n_steps, active, temperature, topp, seeds
+        )
+        return [] if block is None else self.collect_lanes(block)
 
     def _lane_verify_arg_specs(self, t: int):
         """Arg specs for a speculative verify dispatch (the AOT

@@ -268,7 +268,7 @@ def test_fake_clock_stall_bounded_by_chunk_plus_block(
 
     fake = {"t": 0.0}
     monkeypatch.setattr(sched, "_clock", lambda: fake["t"])
-    real_chunk, real_decode = eng.prefill_lane_chunk, eng.decode_lanes
+    real_chunk, real_decode = eng.prefill_lane_chunk, eng.collect_lanes
 
     def chunk_wrapped(*a, **k):
         out = real_chunk(*a, **k)
@@ -281,7 +281,7 @@ def test_fake_clock_stall_bounded_by_chunk_plus_block(
         return out
 
     monkeypatch.setattr(eng, "prefill_lane_chunk", chunk_wrapped)
-    monkeypatch.setattr(eng, "decode_lanes", decode_wrapped)
+    monkeypatch.setattr(eng, "collect_lanes", decode_wrapped)
     samples: list[float] = []
     real_observe = state.m_decode_stall.observe
     monkeypatch.setattr(
@@ -398,3 +398,240 @@ def test_scheduler_knob_threading(sched_state):
     sched = sched_state.scheduler
     assert sched.block_size == 4
     assert sched.admission_chunk == 6
+
+
+# -- the decode loop runs one block ahead --------------------------------------
+#
+# With a block in flight the scheduler dispatches the next one before it
+# collects: each request still streams the tokens the drained order (collect,
+# then dispatch) streams, and a stream whose end only its tokens show runs
+# through one more block, for nobody.
+
+
+@pytest.fixture(scope="module")
+def ahead_state(tmp_path_factory):
+    """`sched_state` with a tokenizer as wide as the model's vocabulary, so
+    a sampled lane's ids all decode."""
+    d = tmp_path_factory.mktemp("ahead")
+    mp, tp_ = str(d / "m.m"), str(d / "t.t")
+    make_tiny_model(mp, cfg=CFG)
+    make_tiny_tokenizer(tp_, chat_template="<|start_header_id|>", pad_to=CFG["vocab_size"])
+    tok = Tokenizer(tp_)
+    engine = InferenceEngine(
+        mp, tokenizer=tok, tp=1, dtype=jnp.float32, temperature=0.0, seed=3,
+        batch_size=3,
+    )
+    return ApiState(engine, tok, lane_block_size=4, admission_chunk=6)
+
+
+def _ids(job, timeout=300, cancel_after=None):
+    """(token ids, finish reason) of a job submitted with `include_tokens`;
+    `cancel_after` deltas the client goes away."""
+    ids, n = [], 0
+    deadline = time.time() + timeout
+    while True:
+        kind, payload = job.events.get(timeout=max(0.1, deadline - time.time()))
+        if kind == "delta":
+            ids += payload["tokens"] if isinstance(payload, dict) else []
+            n += 1
+            if n == cancel_after:
+                job.cancelled = True
+        elif kind == "done":
+            return ids, payload
+        else:
+            raise AssertionError(f"job errored: {payload}")
+
+
+def _idle(sched, timeout=60):
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        # (a block being collected is in nobody's hands but the watchdog's)
+        if not (sched.admitting or any(sched.lanes) or sched.pending
+                or sched._flight is not None
+                or sched.state.watchdog._dispatch_kind is not None):
+            return
+        time.sleep(0.02)
+    raise AssertionError("the scheduler did not go idle")
+
+
+def _run_order(state, order, params, cancel_after=None, beside=()):
+    """Submit `params` together and drain them under one order: `ahead` is
+    the scheduler's own; `drained` collects the block in flight before
+    every decode tick. `beside`: more jobs, whose clients stay. Returns what each job streamed, what was published,
+    the run's recorder events, the jobs' token counts and what every tick
+    found at its begin."""
+    sched, rec = state.scheduler, state.recorder
+    base = rec.total_recorded
+    published = []
+    real_publish, real_step = sched.kv.publish, sched._step_block
+    real_tick, wd = sched._admission_tick, state.watchdog
+    sched.kv.publish = lambda lane, tokens: (
+        published.append(len(tokens)), real_publish(lane, tokens))[1]
+    if order == "drained":
+        sched._step_block = lambda: (sched._drain("verify"), real_step())
+    # every tick, before its dispatches: is a block in flight, and does the
+    # watchdog hold a dispatch open across ticks (it must not: its bracket
+    # is the collect's, and a dispatch's)
+    ticks = []
+    sched._admission_tick = lambda: (
+        ticks.append((sched._flight is not None, wd._dispatch_kind)), real_tick())[1]
+    try:
+        jobs = _submit_together(state, *params, *beside)
+        out = [_ids(job, cancel_after=cancel_after) for job in jobs[:len(params)]]
+        out += [_ids(job) for job in jobs[len(params):]]
+        _idle(sched)
+    finally:
+        sched.kv.publish = real_publish
+        sched.__dict__.pop("_step_block", None)
+        sched.__dict__.pop("_admission_tick", None)
+    assert wd._dispatch_kind is None
+    events = [e for e in rec.events() if e["seq"] > base]
+    return out, sorted(published), events, [job.n_completion for job in jobs], ticks
+
+
+def _blocks(events):
+    return [e for e in events if e["kind"] == "step_dispatch" and e["step"] == "decode_lanes"]
+
+
+def _params(text, **kw):
+    return InferenceParams(messages=[ChatMessage("user", text)],
+                           include_tokens=True, **kw)
+
+
+def test_running_ahead_streams_what_the_drained_order_streams(ahead_state):
+    """Six requests through three lanes, greedy and seeded sampled, lengths
+    that end inside a block and at its edge: token for token the drained
+    order's. While a request waits for a lane the blocks are dispatched
+    ahead; once nobody waits (a request that came now would stand behind a
+    block queued ahead) the tick is dispatch, then collect, again."""
+    state = ahead_state
+    params = [
+        _params("alpha one", max_tokens=38, temperature=0.0),
+        _params("beta two two", max_tokens=32, temperature=0.9, seed=5),
+        _params("gamma three " * 3, max_tokens=9, temperature=0.0),
+        _params("delta four", max_tokens=29, temperature=0.7, seed=6),
+        _params("epsilon five", max_tokens=12, temperature=0.0),
+        _params("zeta six", max_tokens=10, temperature=0.8, seed=7),
+    ]
+    counted = {reason: state.m_decode_blocks.labels(
+        order="drained_first" if reason else "ahead", reason=reason)
+        for reason in ("", "verify", "no_queue")}
+    before = {k: c.value for k, c in counted.items()}
+    want, pub_want, ev_want, n_want, _ = _run_order(state, "drained", params)
+    assert counted[""].value == before[""]
+    assert counted["verify"].value > before["verify"]
+    assert not any(e["ahead"] for e in _blocks(ev_want))
+    mid = counted[""].value
+    got, pub_got, ev_got, n_got, ticks = _run_order(state, "ahead", params)
+    assert got == want and n_got == n_want
+    # a block is in flight from one tick to the next; no watchdog bracket is
+    assert sum(flying for flying, _ in ticks) >= 4
+    assert {kind for _, kind in ticks} == {None}
+    # (the ids a delta carries lag by what the stop detector holds back)
+    assert n_got == [38, 32, 9, 29, 12, 10] and all(ids for ids, _ in got)
+    assert all(reason == "length" for _, reason in got)
+    assert pub_got == pub_want  # every stream's pages, for its history
+    blocks = _blocks(ev_got)
+    n_ahead = sum(e["ahead"] for e in blocks)
+    assert counted[""].value - mid == n_ahead >= 4
+    # and the blocks after the last waiting request got its lane say why not
+    assert counted["no_queue"].value > before["no_queue"]
+    assert not blocks[-1]["ahead"]
+    # the lengths are known ahead, so no lane ran a block for nobody
+    assert sum(e["n_live"] for e in blocks) == sum(e["n_live"] for e in _blocks(ev_want))
+
+
+@pytest.mark.parametrize("end", ["stop_string", "cancelled"])
+def test_a_stream_that_ends_on_its_tokens_runs_one_block_for_nobody(ahead_state, end):
+    """A stop string, or a client that goes away, ends a stream inside block
+    n with block n + 1 already in flight (two longer streams keep the other
+    lanes and two more requests wait for one): nothing of n + 1 is emitted,
+    what is published is the history up to the stop, and the lane is free."""
+    state = ahead_state
+    sched = state.scheduler
+    prompt = f"ends on its tokens {end}"
+    ((full, _),), _, _, n_full, _ = _run_order(
+        state, "ahead", [_params(prompt, max_tokens=24, temperature=0.0)])
+    assert n_full == [24]
+    kw = dict(max_tokens=24, temperature=0.0)
+    if end == "stop_string":
+        # a piece the stream reaches inside its third block
+        piece = state.tokenizer.stream_decoder()
+        pieces = [piece.decode(t) or "" for t in full]
+        at = next(i for i in range(9, 12) if pieces[i].strip())
+        kw["stop"] = [pieces[at]]
+    params = [_params(prompt, **kw),
+              _params("a longer stream beside it", max_tokens=60, temperature=0.0),
+              _params("and another one", max_tokens=56, temperature=0.0),
+              _params("one that waits for a lane", max_tokens=12, temperature=0.0),
+              _params("and a second", max_tokens=10, temperature=0.0)]
+    cancel_after = 2 if end == "cancelled" else None
+
+    def run(order):
+        # only the first job's client goes away
+        out, pub, events, n, _ = _run_order(state, order, params[:1], cancel_after, beside=params[1:])
+        return out, pub, events, n
+
+    want, pub_want, ev_want, n_want = run("drained")
+    got, pub_got, ev_got, n_got = run("ahead")
+    (ids, reason), beside = got[0], got[1:]
+    assert beside == want[1:] and n_got[1:] == n_want[1:] == [60, 56, 12, 10]
+    finish, = [e for e in ev_got if e["kind"] == "finish" and e["reason"] != "length"]
+    assert finish["n_completion"] == n_got[0]
+    blocks = _blocks(ev_got)
+    if end == "stop_string":
+        assert got == want and n_got == n_want and reason == "stop"
+        assert len(ids) < 16 and ids == full[:len(ids)]
+        assert pub_got == pub_want and len(pub_got) == 5
+        # the lane ran on through the block in flight, and only that one
+        assert sum(e["n_live"] for e in blocks) == 1 + sum(e["n_live"] for e in _blocks(ev_want))
+    else:
+        # when the scheduler sees the flag is the client's timing; what it
+        # streamed is a prefix of the stream, and nothing of it is published
+        assert reason == "cancelled" and ids == full[:len(ids)] and len(ids) < 24
+        assert pub_got == pub_want and len(pub_got) == 4
+    # the block in flight when the stream ended ran its lane live
+    last = [e for e in blocks if e["seq"] < finish["seq"]][-1]
+    assert last["ahead"] == 1 and last["n_live"] == 3
+    assert sched._flight is None and not any(sched.lanes)
+    sched.kv.check()
+
+
+def test_streams_dropped_with_a_block_in_flight_leave_none_to_continue(ahead_state):
+    """A read-back that fails on an intact cache drops every stream while
+    the block dispatched ahead of it is still in flight: that block is
+    abandoned un-read, in the engine too, so the waiting requests' first
+    block is nobody's successor (`ahead: 0`, `first_block`)."""
+    state = ahead_state
+    sched, eng, rec = state.scheduler, state.engine, state.recorder
+    first = state.m_decode_blocks.labels(order="drained_first", reason="first_block")
+    params = [_params(f"dropped beside a block in flight {i}", max_tokens=40, temperature=0.0)
+              for i in range(5)]
+    real, failed = eng.collect_lanes, {}
+
+    def collect_lanes(block):
+        flight = sched._flight
+        if not failed and flight is not None and flight.block is not block:
+            failed.update(seq=rec.total_recorded, first=first.value, ahead=flight.block)
+            raise RuntimeError("injected read-back failure")
+        return real(block)
+
+    eng.collect_lanes = collect_lanes
+    try:
+        jobs = _submit_together(state, *params)
+        ends = []
+        for job in jobs:
+            kind = None
+            while kind not in ("done", "error"):
+                kind, payload = job.events.get(timeout=300)
+            ends.append((kind, payload))
+        _idle(sched)
+    finally:
+        del eng.collect_lanes
+    assert failed and eng._uncollected is None and sched._flight is None
+    assert [kind for kind, _ in ends] == ["error"] * 3 + ["done"] * 2
+    assert all(err["retryable"] and "injected" in err["message"] for _, err in ends[:3])
+    assert [job.n_completion for job in jobs[3:]] == [40, 40]
+    after = [e for e in _blocks(rec.events()) if e["seq"] > failed["seq"]]
+    assert after[0]["ahead"] == 0 and first.value == failed["first"] + 1
+    sched.kv.check()

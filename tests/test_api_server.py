@@ -842,12 +842,12 @@ def test_scheduler_error_counter(obs_server):
     engine = state.engine
     b_err = state.m_sched_errors.value
     b_retry = state.m_dispatch_retries.value
-    real = engine.decode_lanes
+    real = engine.dispatch_lanes
 
     def boom(*a, **k):
         raise RuntimeError("injected lane dispatch failure")
 
-    engine.decode_lanes = boom
+    engine.dispatch_lanes = boom
     try:
         with pytest.raises(urllib.error.HTTPError) as exc:
             _post(_url(obs_server), {
@@ -860,7 +860,7 @@ def test_scheduler_error_counter(obs_server):
         assert "injected" in err["message"]
         assert err["retryable"] is True
     finally:
-        engine.decode_lanes = real
+        engine.dispatch_lanes = real
     assert state.m_sched_errors.value == b_err + 1
     # the deterministic failure was retried with backoff before the drop
     assert state.m_dispatch_retries.value == b_retry + state.retry_max
@@ -974,12 +974,12 @@ def test_scheduler_error_writes_postmortem(obs_server, tmp_path):
     pm_dir = tmp_path / "pm"
     old_dir = state.recorder.postmortem_dir
     state.recorder.postmortem_dir = str(pm_dir)
-    real = engine.decode_lanes
+    real = engine.dispatch_lanes
 
     def boom(*a, **k):
         raise RuntimeError("injected postmortem failure")
 
-    engine.decode_lanes = boom
+    engine.dispatch_lanes = boom
     try:
         with pytest.raises(urllib.error.HTTPError) as exc:
             _post(_url(obs_server), {
@@ -988,7 +988,7 @@ def test_scheduler_error_writes_postmortem(obs_server, tmp_path):
             }).read()
         assert exc.value.code == 503
     finally:
-        engine.decode_lanes = real
+        engine.dispatch_lanes = real
         state.recorder.postmortem_dir = old_dir
 
     files = sorted(pm_dir.glob("postmortem-*.json"))

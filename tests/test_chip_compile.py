@@ -759,6 +759,7 @@ def test_lane_block_keeps_the_sampler_conditional(one_chip):
 
     stand_in = types.SimpleNamespace(
         _precision=None, _fwd=fwd, _park=4096, _counts_routing=False,
+        _token_sharding=one_chip,
         header=types.SimpleNamespace(seq_len=4096),
         _build=lambda key, make, specs, origin: make(),
     )
@@ -772,6 +773,7 @@ def test_lane_block_keeps_the_sampler_conditional(one_chip):
             sds((lanes, 8), jnp.bfloat16, one_chip),
             vec(jnp.int32), vec(jnp.bool_), vec(jnp.int32),
             vec(jnp.float32), vec(jnp.float32),
+            sds((lanes, 1), jnp.int32, one_chip),  # the last block's last tokens
         ).compile().as_text()
     comps = hlo_computations(text)
     conds = [l for lines in comps.values() for l in lines if " conditional(" in l]

@@ -14,6 +14,7 @@ import pytest
 from dllama_tpu.formats import FloatType
 from dllama_tpu.formats.model_file import LlmArch
 from dllama_tpu.runtime.engine import InferenceEngine
+from dllama_tpu.runtime.faults import InjectedFault
 from dllama_tpu.tokenizer import Tokenizer
 
 from helpers import REPO_ROOT, make_tiny_model, make_tiny_tokenizer
@@ -1513,6 +1514,21 @@ DRAINED_CASES = {
     "block_chunk_publish_block": (
         ["block", "chunk", "publish", "block"], ["prefill_lane_chunk"]),
     "block_block_block": (["block"] * 3, ["decode_lanes"] * 2),
+    # a block dispatched ahead is queued behind the awaited one: that wait
+    # leaves no mark; the wait for the newest program does
+    "block_dispatch_ahead_collect_collect_block": (
+        ["block", "dispatch", "ahead", "collect", "collect", "block"],
+        ["decode_lanes", "decode_lanes"]),
+    # nor does a wait behind which a chunk was enqueued
+    "dispatch_ahead_collect_chunk_collect_block": (
+        ["dispatch", "ahead", "collect", "chunk", "collect", "block"], []),
+    # a dispatch ahead that raises enqueued nothing: the block in flight is
+    # still the newest program, and its wait leaves the mark
+    "dispatch_poison_collect_block": (
+        ["dispatch", "poison", "collect", "block"], ["decode_lanes"]),
+    # a block abandoned un-read still runs (no mark, no interval), but the
+    # next block is not ahead of a collect that never comes
+    "dispatch_discard_block": (["dispatch", "discard", "block"], []),
 }
 
 
@@ -1539,14 +1555,33 @@ def test_device_drained_between_a_read_back_and_the_next_dispatch(
 
     e = slab_engine
     calls, want = DRAINED_CASES[case]
+    flying = []
     do = {
         "chunk": lambda: e.prefill_lane_chunk(1, list(range(1, 9)), 0),
         "block": lambda: e.decode_lanes([5, 0], [0, 0], 4, active=[True, False]),
         "publish": lambda: e.kv_publish(0, [1], start_page=0),
         "adopt": lambda: e.kv_adopt(1, [1]),
+        "dispatch": lambda: flying.append(
+            e.dispatch_lanes([5, 0], [0, 0], 4, active=[True, False])),
+        # lane 0 goes on from the token the block in flight samples last
+        "ahead": lambda: flying.append(
+            e.dispatch_lanes([None, 0], [4, 0], 4, active=[True, False])),
+        "collect": lambda: e.collect_lanes(flying.pop(0)),
+        "poison": lambda: poisoned(),
+        "discard": lambda: e.discard_lanes(flying.pop(0)),
     }
+
+    def poisoned():
+        fault = InjectedFault("dispatch", "decode_lanes", "poison", 1)
+        with monkeypatch.context() as m:
+            m.setattr(e, "_fault", lambda op: fault)
+            with pytest.raises(InjectedFault):
+                do["ahead"]()
+
     for call in dict.fromkeys(calls):
         do[call]()  # built outside the clock's reach
+    while flying:
+        do["collect"]()
     e.reset()
     e._drained_at = None
     monkeypatch.setattr(engine_mod, "time", _TickingTime())
@@ -1564,12 +1599,101 @@ def test_device_drained_between_a_read_back_and_the_next_dispatch(
         assert e._m_drained.labels(before=b).value - counted0[b] == sum(
             s["dur_s"] for s in spans if s["attrs"]["before"] == b)
     events = [ev for ev in e.recorder.events("step_dispatch") if ev["seq"] > seq]
-    assert len(events) == len(calls)
+    assert len(events) == sum(call not in ("collect", "discard") for call in calls)
+    # a block says whether one was in flight at its dispatch
+    assert [ev["ahead"] for ev in events if ev["step"] == "decode_lanes"] == [
+        int(call in ("ahead", "poison")) for call in calls
+        if call in ("block", "dispatch", "ahead", "poison")]
     assert [(ev["step"], ev["drained_ms"]) for ev in events if "drained_ms" in ev] == [
         (s["attrs"]["before"], s["dur_s"] * 1000) for s in spans]
     assert all(s["dur_s"] >= 1.0 and s["dur_s"] == int(s["dur_s"]) for s in spans)
     completes = [ev for ev in e.recorder.events("step_complete") if ev["seq"] > seq]
     assert all("drained_ms" not in ev for ev in completes)
+
+
+# -- dispatch and collect, one block ahead ------------------------------------
+#
+# `dispatch_lanes` + `collect_lanes` with every block dispatched before the
+# one in flight is collected give what `decode_lanes`, block after block from
+# host tokens, gives: the rows token for token and the cache bit for bit.
+
+
+def _two_streams(e, step, chunk):
+    """Lane 0 greedy throughout; lane 1 a seeded sampled stream whose length
+    ends two tokens into the second block, then a chunk for a new prompt and
+    another seeded stream that joins with its host token. `step(tokens, pos,
+    active, seeds)` is called five times; tokens are (lane 0, lane 1), None
+    for a lane that goes on where the last block left it."""
+    temps = [0.0, 0.8]
+    chunk(0, [5, 6, 7, 8, 9, 10, 11, 12], 0)
+    chunk(1, [30, 31, 32, 33, 34, 35, 36, 37], 0)
+    step([13, 38], [8, 8], [True, True], [None, 11], temps)
+    step([None, None], [8 + BLOCK, 8 + BLOCK], [True, True], [None, 11], temps)
+    # lane 1 ended by length inside that block: not live in the next one,
+    # and its lane takes another prompt behind it
+    step([None, 0], [8 + 2 * BLOCK, 0], [True, False], [None, None], temps)
+    chunk(1, [40, 41, 42, 43, 44, 45, 46, 47], 0)
+    step([None, 48], [8 + 3 * BLOCK, 8], [True, True], [None, 12], temps)
+    step([None, None], [8 + 4 * BLOCK, 8 + BLOCK], [True, True], [None, 12], temps)
+
+
+def test_dispatch_and_collect_one_block_ahead_give_decode_lanes_rows(slab_engine):
+    e = slab_engine
+    chunk = lambda lane, tokens, pos: e.prefill_lane_chunk(lane, tokens, pos)
+    e.reset()
+    want = []
+
+    def drained(tokens, pos, active, seeds, temps):
+        # the host's own tokens: the last row it read back
+        tokens = [want[-1][-1][i] if t is None else t for i, t in enumerate(tokens)]
+        want.append(e.decode_lanes(tokens, pos, BLOCK, active, temps, seeds=seeds))
+
+    _two_streams(e, drained, chunk)
+    cache_want = [np.asarray(x) for x in jax.tree.leaves(e.cache)]
+
+    e.reset()
+    got, flying = [], []
+    seq = e.recorder.total_recorded
+
+    def ahead(tokens, pos, active, seeds, temps):
+        flying.append(e.dispatch_lanes(tokens, pos, BLOCK, active, temps, seeds=seeds))
+        if len(flying) == 2:
+            got.append(e.collect_lanes(flying.pop(0)))
+
+    _two_streams(e, ahead, chunk)
+    got.append(e.collect_lanes(flying.pop(0)))
+    assert len(got) == len(want) == 5
+    for n, (rows, ref) in enumerate(zip(got, want)):
+        assert len(rows) == BLOCK
+        live = (0,) if n == 2 else (0, 1)  # a parked lane reports zeros either way
+        assert [[r[i] for i in live] for r in rows] == [[r[i] for i in live] for r in ref], n
+    # the sampled lane moved (no argmax), and both caches are one
+    assert [r[1] for r in got[0]] != [r[0] for r in got[0]]
+    for a, b in zip(jax.tree.leaves(e.cache), cache_want):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    dispatches = [ev for ev in e.recorder.events("step_dispatch")
+                  if ev["seq"] > seq and ev["step"] == "decode_lanes"]
+    assert [ev["ahead"] for ev in dispatches] == [0, 1, 1, 1, 1]
+    assert [ev["n_live"] for ev in dispatches] == [2, 2, 1, 2, 2]
+    assert all("drained_ms" not in ev for ev in dispatches[1:])
+    completes = [ev for ev in e.recorder.events("step_complete")
+                 if ev["seq"] > seq and ev["step"] == "decode_lanes"]
+    assert len(completes) == 5 and all(ev["ms"] >= 0 for ev in completes)
+
+
+def test_a_lane_goes_on_from_the_device_only_where_the_last_block_ran_it(slab_engine):
+    """The device's last tokens are the newest uncollected block's: a lane
+    it did not run live, or a block that was collected, has none to give."""
+    e = slab_engine
+    e.reset()
+    block = e.dispatch_lanes([5, 0], [0, 0], BLOCK, active=[True, False])
+    with pytest.raises(ValueError, match="continue from the device"):
+        e.dispatch_lanes([None, None], [BLOCK, 0], BLOCK, active=[True, True])
+    rows = e.collect_lanes(block)
+    with pytest.raises(ValueError, match="continue from the device"):
+        e.dispatch_lanes([None, 0], [BLOCK, 0], BLOCK, active=[True, False])
+    assert e.decode_lanes([rows[-1][0], 0], [BLOCK, 0], BLOCK, active=[True, False])
+    e.reset()
 
 
 # -- a dispatch hands its host arguments over in the call ----------------------
@@ -1650,7 +1774,11 @@ def test_a_step_method_launches_its_programs_and_nothing_else(
     assert [k for k, _ in seen["programs"]] == keys
     assert seen["puts"] == []  # one device: the call moves the tokens too
     for _, args in seen["programs"]:
-        host = [a for a in args if not isinstance(a, dict)]
+        # a block's last argument stays on the device: the last tokens of
+        # the block before it, which no host array carries
+        on_device = [a for a in args if isinstance(a, jax.Array)]
+        assert len(on_device) == (method == "decode_lanes")
+        host = [a for a in args if not isinstance(a, (dict, jax.Array))]
         assert len(host) == n_host
         assert all(isinstance(a, (np.ndarray, np.generic)) for a in host)
         assert {str(a.dtype) for a in host} <= {"int32", "bool", "float32"}

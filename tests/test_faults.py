@@ -319,15 +319,37 @@ def test_kv_alloc_fault_is_absorbed(chaos_server):
         assert res == ("ok", content)
 
 
-def test_poison_recovery_resumes_stream_byte_identical(chaos_server):
+@pytest.mark.parametrize("kind", ["poison", "transient"])
+def test_poison_recovery_resumes_stream_byte_identical(chaos_server, kind):
     """Arm a decode poison MID-STREAM: the lane re-prefills its history
     and the client's stream continues byte-identically — the blast-radius
-    acceptance check, without soak timing in the way."""
+    acceptance check, without soak timing in the way. A transient fault
+    there is retried on the cache as it stands. Either way the dispatch
+    that drew the fault was one ahead of a block in flight."""
     state = chaos_server.state
-    mt = 2 * state.scheduler.block_size + 4
+    # the loop runs one block ahead while requests wait for a lane: when
+    # the first delta has arrived and two more requests have queued, blocks
+    # have gone by, so leave enough of them to poison
+    mt = 16 * state.scheduler.block_size + 4
     status, want = _ask(chaos_server, "resume me byte for byte", max_tokens=mt)
     assert status == "ok"
     b_recovered = state.m_lanes_recovered.value
+    b_retries = state.m_dispatch_retries.value
+    after_fault = state.m_decode_blocks.labels(order="drained_first", reason="fault")
+    b_fault = after_fault.value
+    # three longer streams take the other lanes and two more requests
+    # queue for one: with a queue the loop runs ahead, and a batched fault
+    # hits every stream
+    beside = [f"a stream beside the faulted one, number {i}" for i in range(5)]
+    want_beside = [_ask(chaos_server, p, max_tokens=2 * mt) for p in beside]
+    got_beside = [None] * 5
+    fillers = [threading.Thread(target=lambda i=i: got_beside.__setitem__(
+        i, _ask(chaos_server, beside[i], max_tokens=2 * mt))) for i in range(5)]
+    for t in fillers[:3]:
+        t.start()
+    deadline = time.time() + 60
+    while time.time() < deadline and sum(ls is not None for ls in state.scheduler.lanes) < 3:
+        time.sleep(0.01)
 
     req = urllib.request.Request(
         _url(chaos_server) + "/v1/chat/completions",
@@ -351,18 +373,37 @@ def test_poison_recovery_resumes_stream_byte_identical(chaos_server):
             if delta:
                 deltas.append(delta)
             if deltas and not armed:
-                # decode is in flight: poison its next dispatch
+                for t in fillers[3:]:
+                    t.start()
+                deadline = time.time() + 60
+                while time.time() < deadline and not state.scheduler.pending:
+                    time.sleep(0.002)
+                # the queue has formed: the next block is the last one
+                # dispatched after its predecessor's collect, those behind
+                # it go ahead, and the third draws the fault
                 set_fault_plane(
-                    "dispatch:op=decode_lanes:nth=1:kind=poison"
+                    f"dispatch:op=decode_lanes:nth=3:kind={kind}"
                 )
                 armed = True
     plane = set_fault_plane("")
     assert armed
     assert "".join(deltas) == want, "recovered stream diverged"
-    assert state.m_lanes_recovered.value > b_recovered
+    for t in fillers:
+        t.join(timeout=300)
+    assert got_beside == want_beside and all(r[0] == "ok" for r in got_beside)
+    # the faulted dispatch was one ahead of a block in flight: that block
+    # was collected (its tokens are in the stream) before the recovery or
+    # the retry, and the first block after it says why it did not run ahead
+    assert after_fault.value == b_fault + 1
     kinds = {e["kind"]
              for e in _get_json(chaos_server, "/v1/debug/recorder")["events"]}
-    assert {"fault_injected", "lane_recovery", "lane_recovered"} <= kinds
+    if kind == "poison":
+        assert state.m_lanes_recovered.value > b_recovered
+        assert {"fault_injected", "lane_recovery", "lane_recovered"} <= kinds
+    else:
+        assert state.m_lanes_recovered.value == b_recovered
+        assert state.m_dispatch_retries.value == b_retries + 1
+        assert {"fault_injected", "dispatch_retry"} <= kinds
     state.scheduler.kv.check()
 
 

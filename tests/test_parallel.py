@@ -685,8 +685,10 @@ def test_lane_tokens_reach_a_two_device_program_with_their_sharding(
     """On a mesh of two devices a lane dispatch's token array reaches its
     program placed by `_token_sharding`, as the program's arg spec states
     it, whether the program was compiled ahead of time against that spec
-    or is lazily jitted against the array; the rest are host arrays, and
-    the stream is the one-device stream."""
+    or is lazily jitted against the array; the rest are host arrays (and,
+    last of a block's, the block before's last tokens, which stay on the
+    devices under the same sharding), and the stream is the one-device
+    stream."""
     from dllama_tpu.runtime.engine import InferenceEngine
 
     path = str(tmp_path / "m.m")
@@ -717,8 +719,13 @@ def test_lane_tokens_reach_a_two_device_program_with_their_sharding(
             seen.append((_k[0], a[1], a[3:])), _f(*a))[1])
     assert stream(e) == want
     assert [kind for kind, _, _ in seen] == ["lane_prefill"] * 2 + ["lane_block"]
-    for _, tokens, rest in seen:
+    for kind, tokens, rest in seen:
         assert isinstance(tokens, jax.Array) and tokens.committed
         assert tokens.sharding == e._token_sharding
         assert {d.id for d in tokens.devices()} == {d.id for d in e.mesh.devices.flat}
+        if kind == "lane_block":
+            *rest, last = rest
+            # (as the block before placed it: the program constrains it)
+            assert isinstance(last, jax.Array) and last.committed
+            assert last.sharding.is_equivalent_to(e._token_sharding, last.ndim)
         assert all(isinstance(a, np.ndarray) for a in rest)

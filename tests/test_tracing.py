@@ -1,6 +1,6 @@
 """One clock: the scheduler thread's spans inside a profiler trace.
 
-A tiny-model server streams to four clients at once under
+A tiny-model server with four lanes streams to six clients at once under
 `jax.profiler.start_trace` on the CPU. The trace's host plane then has to
 hold the `dllama.*` annotations of the scheduler thread, nested as begun
 and with the `mono_ns` anchor, the span ring has to cover each tick with
@@ -14,6 +14,7 @@ import os
 import re
 import statistics
 import threading
+import time
 import urllib.request
 
 import jax
@@ -77,7 +78,9 @@ def _scheduler_events(trace_dir):
 
 @pytest.fixture(scope="module")
 def traced_run(tmp_path_factory):
-    """Four concurrent streams of 160 tokens under a profiler session."""
+    """Six concurrent streams of 120 tokens through four lanes (two wait
+    for a lane, so the scheduler runs a block ahead) under a profiler
+    session."""
     d = tmp_path_factory.mktemp("tracing")
     engine, tok = _engine(d)
     timeline = str(d / "timeline.json")
@@ -85,13 +88,21 @@ def traced_run(tmp_path_factory):
     threading.Thread(target=srv.serve_forever, daemon=True).start()
     url = f"http://127.0.0.1:{srv.server_address[1]}"
     _stream(url, 99, 16)  # compile the programs outside the trace
+    # the scheduler runs one block ahead: let it collect what is in flight
+    # and go to its wait before the session and the count of spans begin
+    sched = srv.state.scheduler
+    deadline = time.time() + 60
+    while time.time() < deadline and (
+            sched._flight is not None or any(sched.lanes) or sched.admitting):
+        time.sleep(0.01)
+    time.sleep(0.3)
     spans = srv.state.spans
     first = spans.total_recorded
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(d / "profile"), profiler_options=options)
-    clients = [threading.Thread(target=_stream, args=(url, i, 160))
-               for i in range(4)]
+    clients = [threading.Thread(target=_stream, args=(url, i, 120))
+               for i in range(6)]
     for c in clients:
         c.start()
     for c in clients:
@@ -119,20 +130,43 @@ def test_scheduler_spans_are_in_the_profile_nested_as_begun(traced_run):
     # spans that end on another thread, or outlive their tick, stay out
     assert not names & {"dllama.scheduler.queue", "dllama.scheduler.decode"}
     ticks = [e for e in events if e[0] == TICK]
+    # the session ends when the last client has its `done`, which the last
+    # tick sends from inside its `emit`: where that tick is cut off, what it
+    # had completed is in the profile without it
+    cut = max(t[2] for t in ticks)
+    events = [e for e in events if e[1] < cut]
     for name in ("emit", "step_prep"):
         for e in (e for e in events if e[0] == f"dllama.scheduler.{name}"):
             assert any(t[1] <= e[1] and e[2] <= t[2] for t in ticks), e
+    # a block's span is its call (the enqueue and the transfer of its host
+    # arrays); its `.device` wait is the collect's, a tick later, and the
+    # loop runs one block ahead: in most ticks the next block's call comes
+    # BEFORE the wait for the one in flight, and `emit` after that wait
     blocks = [e for e in events if e[0] == "dllama.engine.decode_lanes"]
-    assert len(blocks) >= 20
+    waits = [e for e in events if e[0] == "dllama.engine.decode_lanes.device"]
+    assert len(blocks) >= 20 and abs(len(blocks) - len(waits)) <= 1
+    ahead = 0
     for block in blocks:
         (tick,) = [t for t in ticks if t[1] <= block[1] and block[2] <= t[2]]
-        order = [e[0].rsplit(".", 1)[-1] for e in sorted(_inside(events, tick), key=lambda e: e[1])
-                 if e[0].rsplit(".", 1)[-1] in ("step_prep", "dispatch_prep", "decode_lanes", "emit")]
-        assert order[-4:] == ["step_prep", "dispatch_prep", "decode_lanes", "emit"]
-        (wait,) = [e for e in _inside(events, block)
-                   if e[0] == "dllama.engine.decode_lanes.device"]
+        inside = sorted(_inside(events, tick), key=lambda e: e[1])
+        order = [e[0].rsplit(".", 1)[-1] for e in inside if e[1] <= block[1]
+                 and e[0].rsplit(".", 1)[-1] in ("step_prep", "dispatch_prep", "decode_lanes")]
+        assert order[-3:] == ["step_prep", "dispatch_prep", "decode_lanes"]
         assert block[3]["n_live"] >= 1 and block[3]["n_steps"] >= 1
-        assert wait[2] <= block[2]
+        assert not [w for w in waits if block[1] <= w[1] < block[2]]
+        later = [e[0].rsplit(".", 2)[-1] for e in inside if e[1] >= block[2]
+                 and e[0].endswith(("decode_lanes.device", "scheduler.emit"))]
+        ahead += later[:2] == ["device", "emit"]
+    # (until a request waits for a lane, and after the last has got one,
+    # the tick is dispatch, then collect)
+    assert ahead >= 12
+    for wait in waits:
+        (tick,) = [t for t in ticks if t[1] <= wait[1] and wait[2] <= t[2]]
+        (collect,) = [e for e in _inside(events, tick)
+                      if e[0] == "dllama.scheduler.collect" and e[1] <= wait[1] <= e[2]]
+        assert wait[2] <= collect[2]
+        assert any(e[0] == "dllama.scheduler.emit" and e[1] >= collect[2]
+                   for e in _inside(events, tick))
 
 
 def test_mono_ns_anchors_the_host_clock_to_the_profile(traced_run):
@@ -206,7 +240,8 @@ def test_streamed_timeline_nests_by_parent_off_the_profiler(traced_run):
         assert s["ts"] + s["dur"] <= parent["ts"] + parent["dur"] + 0.01
         parents.setdefault(s["name"], set()).add(parent["name"])
     assert parents["emit"] == parents["step_prep"] == {"sched_tick"}
-    assert parents["finish"] == {"emit"} and parents["decode_lanes.device"] == {"decode_lanes"}
+    assert parents["finish"] == {"emit"} and parents["decode_lanes.device"] == {"collect"}
+    assert parents["decode_lanes"] == parents["collect"] == {"sched_tick"}
     assert "sched_tick" not in parents and "sched_wait" not in parents
     # spans that end on another thread have a thread and no parent
     assert all(("parent" in s["args"]) == (s["name"] not in ("queue", "decode", "device_drained"))
@@ -224,32 +259,47 @@ def test_drained_spans_lie_between_a_read_back_and_the_next_dispatch(traced_run)
     """Served streams: every `device_drained` begins where a `.device` wait
     ended and ends where the dispatch named by `before` begins, on the
     scheduler's thread, and no dispatch but a pool copy lies inside it; the
-    counter holds their sum."""
+    counter holds their sum. The loop runs one block ahead, so the wait for
+    a block behind which the next was dispatched ends in no interval, and a
+    block dispatched ahead says so and carries no `drained_ms`."""
     _, spans = read_timeline(traced_run["timeline"])
     (thread,) = {s["args"]["thread"] for s in spans if s["name"] == "sched_tick"}
     drained = [s for s in spans if s["name"] == "device_drained"]
-    assert len(drained) >= 20
     copies = {"kv_adopt", "kv_publish", "kv_page_copy"}
     steps = {s["name"][: -len(".device")] for s in spans if s["name"].endswith(".device")}
     assert "prefill_lane_chunk" not in steps | copies
     dispatches = [s for s in spans if s["pid"] == 2
                   and s["name"] in steps | copies | {"prefill_lane_chunk"}]
     assert {"kv_publish", "kv_adopt"} <= {s["name"] for s in dispatches}
-    inside = set()
+    waits = [w for w in spans if w["name"].endswith(".device")]
     for d in drained:
         assert d["args"]["thread"] == thread and d["pid"] == 2
         lo, hi = d["ts"], d["ts"] + d["dur"]
-        assert any(abs(w["ts"] + w["dur"] - lo) < 0.01 for w in spans
-                   if w["name"].endswith(".device")), d
+        assert any(abs(w["ts"] + w["dur"] - lo) < 0.01 for w in waits), d
         assert any(abs(s["ts"] - hi) < 0.01 and s["name"] == d["args"]["before"]
                    for s in dispatches), d
         within = {s["name"] for s in dispatches if lo - 0.01 <= s["ts"] < hi - 0.01}
         assert within <= copies, (d, within)
-        inside |= within
-    assert "kv_publish" in inside  # a finished stream's pages, inside `emit`
-    # every block but the first of a burst follows a drained interval
-    blocks = [s for s in spans if s["name"] == "decode_lanes"]
-    assert len(drained) >= len(blocks) - 8
+    # a wait behind which a block or a chunk was enqueued leaves no mark:
+    # no interval begins at its end
+    # (blocks are collected in the order of their calls: the i-th wait is
+    # for the i-th call)
+    enqueues = sorted(s["ts"] for s in dispatches if s["name"] not in copies)
+    blocks = sorted((s for s in spans if s["name"] == "decode_lanes"), key=lambda s: s["ts"])
+    block_waits = sorted((w for w in waits if w["name"] == "decode_lanes.device"),
+                         key=lambda w: w["ts"])
+    n_queued_behind = 0
+    for block, w in zip(blocks, block_waits):
+        assert block["ts"] < w["ts"]
+        if any(block["ts"] < t < w["ts"] for t in enqueues):
+            n_queued_behind += 1
+            assert not any(abs(w["ts"] + w["dur"] - d["ts"]) < 0.01 for d in drained), w
+    assert n_queued_behind >= 12
+    assert 1 <= len(drained) <= len(blocks) - n_queued_behind + 2
+    events = [e for e in traced_run["engine"].recorder.events("step_dispatch")
+              if e["step"] == "decode_lanes"]
+    assert sum(e["ahead"] for e in events) >= 12
+    assert not [e for e in events if e["ahead"] and "drained_ms" in e]
     # the counter is the process's own: other engines may have added to it
     for before in {d["args"]["before"] for d in drained}:
         seconds = sum(d["dur"] for d in drained if d["args"]["before"] == before) / 1e6
