@@ -62,6 +62,13 @@ class LlmArch(enum.IntEnum):
     # block, a rotary table scaled by frequency band
     # (`model_type: deepseek_v32`)
     DEEPSEEK_V32 = 0xABCD12
+    # gated short convolutions where most layers' attention would be: such
+    # a layer keeps the last `conv_l_cache - 1` gated rows of a lane as its
+    # state and no cache row; the layers named by the attention mask are
+    # grouped-query attention with a norm on each head's q and k; leading
+    # dense layers, then a sigmoid router with a selection bias
+    # (`model_type: lfm2_moe`)
+    LFM2_MOE = 0xABCD13
 
 
 class RopeType(enum.IntEnum):
@@ -136,6 +143,10 @@ class HeaderKey(enum.IntEnum):
     ROPE_BETA_SLOW = 44  # rotations below which a band's frequency is divided by the factor
     ROPE_MSCALE_MILLI = 45  # `mscale`, in thousandths
     ROPE_MSCALE_ALL_DIM_MILLI = 46  # `mscale_all_dim`, in thousandths
+    # gated short convolutions (0: none, every layer attends)
+    CONV_L_CACHE = 47  # taps of the depthwise convolution; > 0: a layer is one unless named below
+    ATTN_LAYERS_LO = 48  # bit l: layer l is attention (`layer_types`), l < 30
+    ATTN_LAYERS_HI = 49  # bit l - 30: layer l is attention, 30 <= l < 60
 
 
 @dataclasses.dataclass
@@ -190,6 +201,8 @@ class LlmHeader:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 0.0
     rope_mscale_all_dim: float = 0.0
+    conv_l_cache: int = 0
+    attn_layers: int = 0  # bit l: layer l is attention, where conv_l_cache > 0
     header_bytes: int = 0
     file_size: int = 0
     sync_type: FloatType = FloatType.Q80
@@ -216,6 +229,33 @@ class LlmHeader:
     def indexed(self) -> bool:
         """A learned index picks the rows a latent layer's query attends to."""
         return self.index_topk > 0
+
+    @property
+    def stateful(self) -> bool:
+        """Some layers keep a state a lane (the last gated rows of a short
+        convolution) and no cache row a position."""
+        return self.conv_l_cache > 0
+
+    @property
+    def conv_state_rows(self) -> int:
+        """Rows of a convolution layer's state: the taps before the newest."""
+        return self.conv_l_cache - 1
+
+    @property
+    def kv_pack(self) -> int:
+        """Key-value heads that share one cache row. The chip lays an array
+        whose last axis is narrower than its 128 lanes out with the positions
+        minor, and the flash kernel, which wants the head's columns there, had
+        the whole stack copied in and out of every chunk program (described
+        v5e, bf16[10,16,8,4608,64]). So a model with lane state, the first
+        served with heads of 64, caches neighbouring heads side by side in rows
+        of up to 128 columns (`models/transformer.py` pads the queries to
+        match); every other model's cache is as it was."""
+        pack = 1
+        while (self.stateful and 2 * pack * self.head_dim <= 128
+               and self.n_kv_heads % (2 * pack) == 0):
+            pack *= 2
+        return pack
 
     @property
     def softmax_scale(self) -> float:
@@ -245,11 +285,15 @@ class LlmHeader:
 
 
 # leading dense layers HIDDEN_DIM wide beside experts of MOE_HIDDEN_DIM
-_WIDE_DENSE = (LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32)
+_WIDE_DENSE = (LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32, LlmArch.LFM2_MOE)
 # one more norm after each block
 _SANDWICH = (LlmArch.AFMOE, LlmArch.PANGU_MOE)
 # a selection bias beside the router
-_EXPERT_BIAS = (LlmArch.AFMOE, LlmArch.DEEPSEEK_V32)
+_EXPERT_BIAS = (LlmArch.AFMOE, LlmArch.DEEPSEEK_V32, LlmArch.LFM2_MOE)
+# a norm on each head's q and k
+_QK_NORM = (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.LFM2_MOE)
+# layers a word of the attention mask names (the format stores int32)
+_ATTN_MASK_BITS = 30
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -377,6 +421,12 @@ def read_llm_header(
                 h.rope_mscale = value / 1000.0
             elif key == HeaderKey.ROPE_MSCALE_ALL_DIM_MILLI:
                 h.rope_mscale_all_dim = value / 1000.0
+            elif key == HeaderKey.CONV_L_CACHE:
+                h.conv_l_cache = value
+            elif key == HeaderKey.ATTN_LAYERS_LO:
+                h.attn_layers |= value
+            elif key == HeaderKey.ATTN_LAYERS_HI:
+                h.attn_layers |= value << _ATTN_MASK_BITS
 
         if weight_type is None:
             raise ValueError("model does not specify weight type")
@@ -400,8 +450,26 @@ def read_llm_header(
     if h.head_dim == 0:
         h.head_dim = h.dim // h.n_heads
     h.sync_type = sync_type
-    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE):
+    if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE,
+                  LlmArch.LFM2_MOE):
         h.rope_type = RopeType.FALCON
+    if h.stateful:
+        if h.conv_l_cache < 2 or h.latent or h.sliding_window:
+            raise ValueError(
+                f"a short convolution of {h.conv_l_cache} taps beside latent or "
+                "window attention: conv_l_cache >= 2, and full attention alone"
+            )
+        if h.attn_layers in (0, (1 << h.n_layers) - 1):
+            raise ValueError(
+                f"attention layers {h.attn_layers:#x} of {h.n_layers} layers: a model "
+                "with convolution layers has layers of both kinds (conv_l_cache 0: "
+                "every layer attends)"
+            )
+        if h.n_layers > 2 * _ATTN_MASK_BITS or h.attn_layers >> h.n_layers:
+            raise ValueError(
+                f"attention layers {h.attn_layers:#x} of {h.n_layers} layers: the "
+                f"mask names layers below {min(h.n_layers, 2 * _ATTN_MASK_BITS)}"
+            )
     if h.indexed and not (h.latent and h.index_n_heads and h.index_head_dim >= h.rope_dim):
         raise ValueError(
             "an index (index_topk > 0) needs latent attention, index_n_heads "
@@ -426,8 +494,9 @@ def read_llm_header(
 class LayerKind:
     """One row of the layer table: what a layer is, as data. `row` is the
     layer's place in its cache stack (the full layers', the window layers'
-    or the latent layers'), `ffn_row` its place among the layers of its FFN
-    kind, whose weights are stacked apart."""
+    or the latent layers'; a convolution layer's in the state stack, where
+    it keeps a state a lane and no cache row), `ffn_row` its place among the
+    layers of its FFN kind, whose weights are stacked apart."""
 
     window: bool  # attention: over the last `sliding_window` rows, or in full
     rope: bool  # the layer's whole heads take the rotary embedding
@@ -435,10 +504,14 @@ class LayerKind:
     row: int
     ffn_row: int
     latent: bool = False  # the cache row is `[c | k_rope]`, one head for all
+    conv: bool = False  # a gated short convolution stands where attention would
 
     @property
     def cache(self) -> str:
-        """The kind of cache the layer's rows live in."""
+        """The kind of cache the layer's rows live in; `state`: none, the
+        layer keeps a state a lane."""
+        if self.conv:
+            return "state"
         return "latent" if self.latent else "window" if self.window else "full"
 
 
@@ -446,17 +519,20 @@ def layer_table(h: LlmHeader) -> tuple[LayerKind, ...]:
     """The kinds of the model's layers, read once from the header. The
     reference's architectures are its uniform rows: every layer full, with
     rope, and the same FFN."""
-    table, rows, ffn_rows = [], {"full": 0, "window": 0, "latent": 0}, [0, 0]
+    table, ffn_rows = [], [0, 0]
+    rows = {"full": 0, "window": 0, "latent": 0, "state": 0}
     for l in range(h.n_layers):
         window = not h.latent and h.sliding_window > 0 and not (
             h.full_attn_period and (l + 1) % h.full_attn_period == 0
         )
         experts = h.n_experts > 0 and l >= h.n_dense_layers
-        cache = "latent" if h.latent else "window" if window else "full"
+        conv = h.stateful and not h.attn_layers >> l & 1
+        cache = "state" if conv else "latent" if h.latent else "window" if window else "full"
         table.append(LayerKind(
             # a latent layer turns the rope columns of its heads itself
-            window, not h.latent and (window or not h.full_attn_no_rope), experts,
-            rows[cache], ffn_rows[experts], h.latent,
+            window,
+            not conv and not h.latent and (window or not h.full_attn_no_rope),
+            experts, rows[cache], ffn_rows[experts], h.latent, conv,
         ))
         rows[cache] += 1
         ffn_rows[experts] += 1
@@ -533,6 +609,12 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
                 add(f"layers.{l}.idx_k_norm", FloatType.F32, (h.index_head_dim,))
                 add(f"layers.{l}.idx_k_bias", FloatType.F32, (h.index_head_dim,))
                 add(f"layers.{l}.idx_w", FloatType.F32, (h.index_n_heads, h.dim))
+        elif kind.conv:
+            # `[B | C | x]` from the layer's input, the depthwise taps (the
+            # last meets the newest row), and the projection back
+            add(f"layers.{l}.conv_in", wt, (3 * h.dim, h.dim))
+            add(f"layers.{l}.conv_w", FloatType.F32, (h.dim, h.conv_l_cache))
+            add(f"layers.{l}.conv_out", wt, (h.dim, h.dim))
         else:
             add(f"layers.{l}.q", wt, (h.q_dim, h.dim))
             add(f"layers.{l}.k", wt, (h.kv_dim, h.dim))
@@ -550,7 +632,7 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
                 swiglu(f"layers.{l}.experts.{e}", h.ff_dim)
         else:  # leading dense layers are HIDDEN_DIM wide beside experts of ff_dim
             swiglu(f"layers.{l}", h.hidden_dim if h.arch in _WIDE_DENSE else h.ff_dim)
-        if h.arch in (LlmArch.QWEN3, LlmArch.QWEN3_MOE, LlmArch.AFMOE):
+        if h.arch in _QK_NORM and not kind.conv:
             add(f"layers.{l}.q_norm", FloatType.F32, (h.head_dim,))
             add(f"layers.{l}.k_norm", FloatType.F32, (h.head_dim,))
         add(f"layers.{l}.att_norm", FloatType.F32, (h.dim,))

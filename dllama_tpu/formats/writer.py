@@ -64,6 +64,9 @@ HEADER_KEYS = {
     "rope_beta_slow": 44,
     "rope_mscale_milli": 45,
     "rope_mscale_all_dim_milli": 46,
+    "conv_l_cache": 47,
+    "attn_layers_lo": 48,
+    "attn_layers_hi": 49,
 }
 
 

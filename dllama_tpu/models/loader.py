@@ -325,6 +325,10 @@ def load_params(
     table = layer_table(h)
     dense_layers = [l for l in every if not table[l].experts]
     expert_layers = [l for l in every if table[l].experts]
+    # and where some layers are gated short convolutions, each operator's
+    # leaves over the layers of its own kind
+    attn_layers = [l for l in every if not table[l].conv]
+    conv_layers = [l for l in every if table[l].conv]
 
     def stack(fn: Callable[[int], np.ndarray], layers=every) -> np.ndarray:
         return np.stack([fn(l) for l in layers])
@@ -399,6 +403,16 @@ def load_params(
             dims,
         )
 
+    if conv_layers:
+        for n in ("conv_in", "conv_out"):
+            if quantize:
+                layers[n] = qw(n, lambda l, n=n: f"layers.{l}.{n}", conv_layers)
+            else:
+                layers[n] = put(n, stack(
+                    lambda l, n=n: w(f"layers.{l}.{n}"), conv_layers).astype(dtype))
+        # the taps as [K, D] rows, f32 as the norms are
+        layers["conv_w"] = put(
+            "conv_w", stack(lambda l: w(f"layers.{l}.conv_w"), conv_layers))
     has_gate = "layers.0.att_gate" in reader.by_name
     qkv = ["q", "k", "v"] + (["att_gate"] if has_gate else [])
     if h.latent:
@@ -438,19 +452,20 @@ def load_params(
         # the gate on the attention output reads the same input: it rides
         # the fused launch as a fourth constituent
         layers["wqkv"] = qw_fused(
-            "wqkv", [lambda l, n=n: f"layers.{l}.{n}" for n in qkv]
+            "wqkv", [lambda l, n=n: f"layers.{l}.{n}" for n in qkv], attn_layers
         )
-        layers["wo"] = qw("wo", lambda l: f"layers.{l}.wo")
+        layers["wo"] = qw("wo", lambda l: f"layers.{l}.wo", attn_layers)
     elif quantize:
         for tag, n in zip(("wq", "wk", "wv", "wg"), qkv):
-            layers[tag] = qw(tag, lambda l, n=n: f"layers.{l}.{n}")
-        layers["wo"] = qw("wo", lambda l: f"layers.{l}.wo")
+            layers[tag] = qw(tag, lambda l, n=n: f"layers.{l}.{n}", attn_layers)
+        layers["wo"] = qw("wo", lambda l: f"layers.{l}.wo", attn_layers)
     else:
         for tag, n in zip(("wq", "wk", "wv", "wg"), qkv):
             layers[tag] = put(
-                tag, stack(lambda l, n=n: w(f"layers.{l}.{n}")).astype(dtype)
+                tag, stack(lambda l, n=n: w(f"layers.{l}.{n}"), attn_layers).astype(dtype)
             )
-        layers["wo"] = put("wo", stack(lambda l: w(f"layers.{l}.wo")).astype(dtype))
+        layers["wo"] = put(
+            "wo", stack(lambda l: w(f"layers.{l}.wo"), attn_layers).astype(dtype))
 
     def swiglu(prefix: str, name: Callable[[int, str], str], ls: list[int]) -> None:
         """A SwiGLU's three matrices over the layers `ls`, as
@@ -529,7 +544,11 @@ def load_params(
             lambda l, n: f"layers.{l}.{n}", dense_layers,
         )
 
-    for n in ("q_norm", "k_norm", "post_att_norm", "post_ffn_norm"):
+    for n in ("q_norm", "k_norm"):  # of the attention layers
+        if f"layers.{attn_layers[0]}.{n}" in reader.by_name:
+            layers[n] = put(
+                n, stack(lambda l, n=n: w(f"layers.{l}.{n}", False), attn_layers))
+    for n in ("post_att_norm", "post_ffn_norm"):
         if f"layers.0.{n}" in reader.by_name:
             layers[n] = put(n, stack(lambda l, n=n: w(f"layers.{l}.{n}", False)))
 

@@ -62,6 +62,7 @@ from ..ops.kv_cache import (
     quantize_kv_rows,
     write_rows,
 )
+from ..ops.short_conv import short_conv_chunk, short_conv_step
 from ..ops.sparse_index import index_scores, select_rows
 from ..ops.moe_kernel import (
     moe_active_experts,
@@ -119,6 +120,22 @@ def _mm_manual(
     return reduce(jnp.einsum("bti,io->bto", x, w))
 
 
+# what a convolution layer's operator reads, and with it what attention's
+# does: stacked over the layers of their own kind where a model has both
+_CONV_LEAVES = ("conv_in", "conv_w", "conv_out")
+_OPERATOR_LEAVES = _CONV_LEAVES + (
+    "wq", "wk", "wv", "wqkv", "wo", "wg", "q_norm", "k_norm")
+
+
+def _pattern_period(flags: list) -> int:
+    """The shortest period of a layer pattern: the least p with
+    `flags[i] == flags[i % p]` throughout (the pattern's length at most)."""
+    return next(
+        p for p in range(1, len(flags) + 1)
+        if all(f == flags[i % p] for i, f in enumerate(flags))
+    )
+
+
 def _is_quant_stack(leaf) -> bool:
     """A layer-stacked leaf that the Pallas kernels read in place: Q40
     values and scales, fused or not. Dense weights stay among the layer
@@ -165,7 +182,10 @@ def init_kv_cache(
     its place in its stack. A model with latent attention gets one stack
     `c` of `[L, B, 1, S, kv_lora_rank + qk_rope_head_dim]` and, where a
     learned index picks the rows a query attends to (`index_topk > 0`), a
-    second, `i`, of the positions' index keys, `index_head_dim` wide."""
+    second, `i`, of the positions' index keys, `index_head_dim` wide. A model
+    whose convolution layers keep a state a lane (`h.stateful`) gets `k`/`v`
+    over its attention layers alone and a state stack `s` of
+    `[convolution layers, B, conv_l_cache - 1, dim]` beside them."""
     s = seq_len or h.seq_len
     if h.latent:
         # one stack of `[c | k_rope]` rows, one head for every query head:
@@ -177,8 +197,12 @@ def init_kv_cache(
             cache["i"] = jnp.zeros((h.n_layers, batch_size, 1, s, h.index_head_dim), dtype)
         return cache
     n_window = sum(kind.window for kind in layer_table(h))
-    shape = (h.n_layers - n_window, batch_size, h.n_kv_heads, s, h.head_dim)
+    n_conv = sum(kind.conv for kind in layer_table(h))
+    shape = (h.n_layers - n_window - n_conv, batch_size, h.n_kv_heads // h.kv_pack, s,
+             h.head_dim * h.kv_pack)
     if dtype == jnp.int8:
+        if n_conv:
+            raise NotImplementedError("a convolution layer's state is not quantized: int8 KV")
         if n_window:
             raise NotImplementedError("window layers' ring cache is not quantized: int8 KV")
 
@@ -198,6 +222,8 @@ def init_kv_cache(
         shape = (n_window, batch_size, h.n_kv_heads, rows, h.head_dim)
         cache["kw"] = jnp.zeros(shape, dtype=dtype)
         cache["vw"] = jnp.zeros(shape, dtype=dtype)
+    if n_conv:
+        cache["s"] = jnp.zeros((n_conv, batch_size, h.conv_state_rows, h.dim), dtype)
     return cache
 
 
@@ -963,6 +989,9 @@ def forward(
     kv_ring: int = 0,
     route_stats: list | None = None,
     one_live_lane: bool = False,
+    state_rows: jnp.ndarray | None = None,
+    state_fresh: jnp.ndarray | None = None,
+    write_floor: jnp.ndarray | None = None,
 ) -> Tuple[jnp.ndarray, KvCache]:
     """Run the decoder on T tokens starting at absolute position `pos`.
 
@@ -996,12 +1025,16 @@ def forward(
     `one_live_lane` (static): the caller's program admits ONE lane and parks
     every other (the engine's chunk programs): `run_layers`' expert block
     then computes that lane's rows alone.
+
+    `state_rows`, `state_fresh`, `write_floor`: a model with lane state
+    alone (`run_layers` says what each means); left out, every live lane's
+    state moves by the T rows, from zero at position 0.
     """
     b, t = tokens.shape
     # `pos` may be a [B] vector: each batch lane decodes at its own
     # position (independent request lanes — the continuous-batching
     # surface the reference's single-stream loop lacks)
-    names = [n for n in ("k", "v", "kw", "vw", "c", "i") if n in cache]
+    names = [n for n in ("k", "v", "kw", "vw", "c", "i", "s") if n in cache]
     attn_pos = attn_positions(pos, attn_park_threshold, cache[names[0]].shape[3])
 
     x = params["embed"][tokens]  # [B, T, D] (reference: OP_EMBEDDING)
@@ -1016,6 +1049,8 @@ def forward(
         kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
         route_stats=route_stats, c_cache=cache.get("c"), i_cache=cache.get("i"),
         one_live_lane=one_live_lane,
+        **({"s_cache": cache["s"], "state_rows": state_rows, "state_fresh": state_fresh,
+            "write_floor": write_floor} if "s" in cache else {}),
     )
     logits = logits_head(x, params, h, mesh, logits_mode)
     return logits, dict(zip(names, caches))
@@ -1107,6 +1142,10 @@ def run_layers(
     c_cache: jnp.ndarray | None = None,  # [L, B, 1, S, W]: latent layers, alone
     one_live_lane: bool = False,
     i_cache: jnp.ndarray | None = None,  # [L, B, 1, S, dI]: their index keys
+    s_cache: jnp.ndarray | None = None,  # [Lc, B, K - 1, D]: convolution layers' states
+    state_rows: jnp.ndarray | None = None,  # [B] int32
+    state_fresh: jnp.ndarray | None = None,  # [B] bool
+    write_floor: jnp.ndarray | None = None,  # int32 scalar
 ):
     """`lax.scan` the decoder layers over x; returns (x, k_new, v_new), and
     the window layers' (kw_new, vw_new) behind them where the model has such;
@@ -1163,6 +1202,23 @@ def run_layers(
     rows, which no query reads, pass the block as they came. Where the
     lanes are split over devices (`dp`) every lane's rows are computed as
     before: the slice would gather across them.
+
+    `s_cache`: a model some of whose layers are gated short convolutions
+    (`LayerKind.conv`) carries their states behind `k` and `v`, which then
+    hold the attention layers alone; returns (x, k_new, v_new, s_new). A
+    state belongs to a lane and not to a position, so parking and padding
+    must not move it: lane b's state advances by its first `state_rows[b]`
+    rows of the T (left out: all T of a lane whose `attn_pos` is a position,
+    none of a parked one), from zero where `state_fresh[b]` or the lane
+    writes at position 0, which nothing precedes. `write_floor` (a chunk
+    program's, which admits one lane): that lane's cache rows at positions
+    below it keep what they hold (it adopted them and replays the rows
+    before to rebuild its states: `runtime/engine.py`).
+    The layer pattern is static: each run of one FFN kind is scanned by
+    whole periods of its pattern, a period's layers unrolled in the scan's
+    body, so no branch is taken on the device and an attention layer alone
+    writes cache rows. In a chunk program (`one_live_lane`) a convolution
+    layer, its FFN with it, runs over the admitted lane's rows alone.
     """
     b, t = x.shape[0], x.shape[1]
     interleaved = h.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1, RopeType.YARN)
@@ -1182,6 +1238,15 @@ def run_layers(
         k_cache = c_cache  # the stack whose shape the lines below read
     if latent != layer_table(h)[0].latent or (i_cache is not None) != h.indexed:
         raise ValueError("the layer table and the cache stacks disagree on latent rows")
+    stateful = s_cache is not None
+    if stateful != h.stateful:
+        raise ValueError("the layer table and the cache stacks disagree on lane state")
+    if stateful and not (
+        (mesh is None or mesh.devices.size == 1) and tp_axis is None and sp_axis is None
+    ):
+        raise NotImplementedError(
+            "layers with lane state run on one device: tp, sp, dp, pp > 1"
+        )
     shard_s = k_cache.shape[3]  # local (per-sp-shard) sequence length
     # manual sp: the per-shard write window is t//sp_n (+1 for unaligned
     # chunk starts) local rows, capped at the whole local shard — a
@@ -1203,7 +1268,7 @@ def run_layers(
     table = layer_table(h)
     n_layers = jax.tree.leaves(k_cache)[0].shape[0] + (
         0 if kw_cache is None else kw_cache.shape[0]
-    )
+    ) + (s_cache.shape[0] if stateful else 0)
     alike = all(
         (kind.cache, kind.rope, kind.experts) == (
             table[0].cache, table[0].rope, table[0].experts)
@@ -1256,6 +1321,8 @@ def run_layers(
     # token rows of live lanes: what the expert block computes pairs for and
     # the routing counters count; `lone`: the admitted lane's rows alone
     lone = one_live_lane and jnp.ndim(attn_pos) == 1 and lanes_on_one_device(mesh)
+    if write_floor is not None and not (lone or b == 1):
+        raise ValueError("write_floor: of a program that admits one lane (one_live_lane)")
     if lone:
         lane = jnp.argmax(attn_pos >= 0).astype(jnp.int32)
         live_rows = jnp.broadcast_to(attn_pos[lane] >= 0, (t,))
@@ -1263,6 +1330,14 @@ def run_layers(
         live_rows = jnp.broadcast_to((attn_pos >= 0)[:, None], (b, t)).reshape(-1)
     else:
         live_rows = jnp.ones((b * t,), bool)
+    if stateful:
+        # each lane's rows that move its states, and the lanes that start from zero
+        if state_rows is None:
+            state_rows = jnp.where(
+                jnp.broadcast_to(attn_pos, (b,)) >= 0, t, 0).astype(jnp.int32)
+        state_zero = jnp.broadcast_to(pos, (b,)) == 0
+        if state_fresh is not None:
+            state_zero = jnp.logical_or(state_zero, state_fresh)
 
     def _cache_append(cache, l, val, there=None):
         """Write the chunk into layer `l` of the carried stack at each
@@ -1285,6 +1360,19 @@ def run_layers(
             )
         if there is not None:
             return write_rows(cache, l, jnp.where(there, pos, h.seq_len), val)
+        if write_floor is not None:
+            # the admitted lane's rows below the floor keep what they hold:
+            # read where they lie and written back with the chunk. One lane's
+            # rows in one slice: for a slice a lane the chip's compiler wrote
+            # every lane's rows of the whole stack anew (2.1 ms a layer)
+            at = lane if lone else 0
+            p = jnp.broadcast_to(pos, (b,))[at]
+            old = lax.dynamic_slice(
+                cache, (l, at, 0, p, 0), (1, 1, cache.shape[2], t, cache.shape[4]))[0]
+            mine = lax.dynamic_slice_in_dim(val, at, 1, axis=0).astype(cache.dtype)
+            keep = (p + jnp.arange(t, dtype=jnp.int32) < write_floor)[None, None, :, None]
+            val = lax.dynamic_update_slice_in_dim(
+                val.astype(cache.dtype), jnp.where(keep, old, mine), at, axis=0)
         return _positional_write(cache, l, val)
 
     def _ring_append(cache, l, val, there=None):
@@ -1431,7 +1519,7 @@ def run_layers(
             # the window's rows of layer `row`, read where they lie; the
             # sp mesh path windows inside _attention_sp per shard
             return _attention_tp(
-                q, k_cache, v_cache, row[0], attn_pos, h.head_dim, mesh,
+                q, k_cache, v_cache, row[0], attn_pos, q.shape[-1], mesh,
                 attn_window=attn_window,
             )
 
@@ -1594,10 +1682,61 @@ def run_layers(
             routed=(held_i.reshape(b, t, -1), wts.reshape(b, t, -1)),
         ), counts
 
-    def make_step(a: int, kinds, stacks):
+    def head_slot():
+        """[H, pack] one-hot: the place of a query head's key-value head in
+        its cache row (`LlmHeader.kv_pack` heads side by side)."""
+        slot = (jnp.arange(hq) // (hq // hkv)) % h.kv_pack
+        return jax.nn.one_hot(slot, h.kv_pack, dtype=x.dtype)
+
+    def pack_heads(q, k, v):
+        """Keys and values of neighbouring heads side by side, `kv_pack` to a
+        row; a query padded with zeros to the row's width, its own columns
+        where its head's lie, so that it scores its own head's keys alone;
+        and times sqrt(pack), since attention scales by the row's width."""
+        rows = (b, t, hkv // h.kv_pack, h.kv_pack * h.head_dim)
+        scale = jnp.asarray(float(h.kv_pack) ** 0.5, jnp.float32)
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        q = (q[..., None, :] * head_slot()[:, :, None]).reshape(b, t, hq, rows[-1])
+        return q, k.reshape(rows), v.reshape(rows)
+
+    def unpack_heads(z):
+        """A head's own columns of its packed row's weighted values."""
+        z = z.reshape(b, t, hq, h.kv_pack, h.head_dim)
+        return jnp.sum(z * head_slot()[:, :, None], axis=3).reshape(b, t, hq * h.head_dim)
+
+    def conv_operator(y, lp, s_cache, srow, mm):
+        """A convolution layer's operator over `y`'s rows (every lane's, or
+        the admitted lane's [1, T, D]): (its output, the state stack with
+        the layer's row `srow` moved on)."""
+        ks, d = s_cache.shape[2], s_cache.shape[3]
+        rows, zero = state_rows, state_zero
+        if y.shape[0] == 1 and b > 1:  # the admitted lane alone
+            state = lax.dynamic_slice(s_cache, (srow, lane, 0, 0), (1, 1, ks, d))[0]
+            rows = lax.dynamic_slice_in_dim(rows, lane, 1)
+            zero = lax.dynamic_slice_in_dim(zero, lane, 1)
+            at = (srow, lane, 0, 0)
+        else:
+            state = lax.dynamic_index_in_dim(s_cache, srow, 0, keepdims=False)
+            at = (srow, 0, 0, 0)
+        state = jnp.where(zero[:, None, None], jnp.zeros((), state.dtype), state)
+        bcx = mm(y, lp["conv_in"], "row")
+        with jax.named_scope("mix"):
+            if t == 1:
+                o, state = short_conv_step(bcx, lp["conv_w"], state, rows > 0)
+            else:
+                o, state = short_conv_chunk(bcx, lp["conv_w"], state, rows)
+        o = mm(o, lp["conv_out"], "col", sync=True)
+        return o, lax.dynamic_update_slice(s_cache, state[None], at)
+
+    def make_step(a: int, kinds, stacks, ffn_row0=None, whole=None):
         """The scan body of layers [a, a + len(kinds)), all of one FFN
-        kind. `stacks`: the quantized weight stacks it closes over."""
+        kind. `stacks`: the quantized weight stacks it closes over; `whole`:
+        the dense stacks of an operator whose layers are a subset (a model
+        with lane state), from which the step takes its layer's."""
         experts = kinds[0].experts
+        ffn_row0 = kinds[0].ffn_row if ffn_row0 is None else ffn_row0
+        # a convolution layer of a chunk program: the admitted lane's rows
+        lane_alone = lone and kinds[0].conv
 
         def layer_step(carry, layer):
             x, caches = carry
@@ -1606,14 +1745,24 @@ def run_layers(
             # the layer's row in the full and in the window layers' cache
             # stack (0 in the stack it is not of), and among its FFN kind's
             row = extra.get("row", (l, l))
-            lf = l - a + kinds[0].ffn_row
+            lf = l - a + ffn_row0
             is_window = extra.get("window", kinds[0].window)
             has_rope = extra.get("rope")
             counts = None
+            # the layer's place among its operator's kind, whose weights are
+            # stacked apart where the kinds keep different ones
+            op_row = extra.get("op_row", l)
+            if whole:
+                lp.update({
+                    name: jax.tree.map(
+                        lambda v: lax.dynamic_index_in_dim(v, op_row, 0, keepdims=False),
+                        leaf)
+                    for name, leaf in whole.items()
+                })
 
             def mm(yy, w, role, sync=False, ffn=False):
                 # the layer counts only where `w` is one of `stacks`
-                li = lf if ffn else l
+                li = lf if ffn else op_row
                 if tp_axis is not None:
                     return _mm_manual(yy, w, role, tp_axis, sync and sync_quant, li)
                 return _mm(yy, w, role, mesh, sync and sync_quant, li)
@@ -1637,90 +1786,111 @@ def run_layers(
                 )
 
             # -- attention block (reference: src/llm.cpp:263-403) --
+            x_all = x
+            if lane_alone:
+                x = lax.dynamic_slice_in_dim(x_all, lane, 1, axis=0)  # [1, T, D]
             with jax.named_scope("norm"):
                 y = rms_norm(x, lp["att_norm"], h.norm_epsilon)
-            with jax.named_scope("attn"):
-                gate = None
-                if kinds[0].latent:
-                    # the cache row stands where the keys do; there are no values
-                    with jax.named_scope("latent_proj"):
-                        q, k, cq = latent_queries_and_row(y, lp, mm)
-                    v = index = None
-                    if "idx_wk" in lp:  # the index key is cached where values would be
-                        with jax.named_scope("index_proj"):
-                            index, v = index_queries_and_key(y, cq, lp, mm)
-                elif "wqkv" in lp:
-                    # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
-                    # per decode layer at ~41 us fixed cost each on the round-3
-                    # chip run). The un-interleave factor is the
-                    # weight's own static metadata, not the mesh's tp — a fused-
-                    # load/mesh mismatch stays correct (just non-optimally laid
-                    # out) instead of silently permuting columns. Under manual tp
-                    # the shard's local slice is one interleave chunk (the shard-
-                    # major layout puts shard i's [q_i|k_i|v_i] in chunk i), so
-                    # the local split factor is fuse / tp_n. A gate on the
-                    # attention output is a fourth constituent.
-                    fw = lp["wqkv"]
-                    if fw.fuse % tp_n != 0:
-                        raise ValueError(
-                            f"fused weight interleave {fw.fuse} incompatible with "
-                            f"manual tp_n={tp_n}"
-                        )
-                    qkv = mm(y, fw.weight, "row")
-                    q, k, v, *gate = _split_fused(
-                        qkv, fw.fuse // tp_n, tuple(d // tp_n for d in fw.dims)
-                    )
-                    gate = gate[0] if gate else None
-                    q = q.reshape(b, t, hq, h.head_dim)
-                    k = k.reshape(b, t, hkv, h.head_dim)
-                    v = v.reshape(b, t, hkv, h.head_dim)
-                else:
-                    q = mm(y, lp["wq"], "row").reshape(b, t, hq, h.head_dim)
-                    k = mm(y, lp["wk"], "row").reshape(b, t, hkv, h.head_dim)
-                    v = mm(y, lp["wv"], "row").reshape(b, t, hkv, h.head_dim)
-                    if "wg" in lp:
-                        gate = mm(y, lp["wg"], "row")
-                if "q_norm" in lp:
-                    q = qk_rms_norm(q, lp["q_norm"], h.norm_epsilon)
-                    k = qk_rms_norm(k, lp["k_norm"], h.norm_epsilon)
-                if has_rope is not None:
-                    q = jnp.where(has_rope, apply_rope(q, cos, sin, interleaved), q)
-                    k = jnp.where(has_rope, apply_rope(k, cos, sin, interleaved), k)
-                elif kinds[0].rope:
-                    q = apply_rope(q, cos, sin, interleaved)
-                    k = apply_rope(k, cos, sin, interleaved)
-
-            caches = write_kv(caches, k, v, row, is_window)
-            if kinds[0].latent:
-                z = attend_latent(q, caches, row, index)
-                with jax.named_scope("attn"), jax.named_scope("latent_proj"):
-                    # a head's values from the weighted latents, once a query
-                    z = jnp.einsum("bthc,hcv->bthv", z, lp["wkv_b_v"]).reshape(
-                        b, t, hq * h.v_head_dim)
-            elif not isinstance(is_window, bool):
-                # the stacks go in and only the attention's output comes out
-                z = lax.cond(is_window, attend_window, attend_full, q, caches, row)
-            elif is_window:
-                z = attend_window(q, caches, row)
+            if kinds[0].conv:
+                # a gated short convolution stands where attention would (and
+                # in its scope: a profile's reduction that knows the layers'
+                # scopes by name would read any other as the scan's own
+                # copying): no cache row is written, the lane's state moves on
+                with (jax.named_scope("attn"), jax.named_scope("conv"),
+                      jax.named_scope(phase)):
+                    o, s_new = conv_operator(y, lp, caches[2], op_row, mm)
+                    x = x + o.astype(x.dtype)
+                caches = (*caches[:2], s_new)
             else:
-                z = attend_full(q, caches, row)
+                with jax.named_scope("attn"):
+                    gate = None
+                    if kinds[0].latent:
+                        # the cache row stands where the keys do; there are no values
+                        with jax.named_scope("latent_proj"):
+                            q, k, cq = latent_queries_and_row(y, lp, mm)
+                        v = index = None
+                        if "idx_wk" in lp:  # the index key is cached where values would be
+                            with jax.named_scope("index_proj"):
+                                index, v = index_queries_and_key(y, cq, lp, mm)
+                    elif "wqkv" in lp:
+                        # fused q|k|v: one kernel launch reads y once (7 -> 4 launches
+                        # per decode layer at ~41 us fixed cost each on the round-3
+                        # chip run). The un-interleave factor is the
+                        # weight's own static metadata, not the mesh's tp — a fused-
+                        # load/mesh mismatch stays correct (just non-optimally laid
+                        # out) instead of silently permuting columns. Under manual tp
+                        # the shard's local slice is one interleave chunk (the shard-
+                        # major layout puts shard i's [q_i|k_i|v_i] in chunk i), so
+                        # the local split factor is fuse / tp_n. A gate on the
+                        # attention output is a fourth constituent.
+                        fw = lp["wqkv"]
+                        if fw.fuse % tp_n != 0:
+                            raise ValueError(
+                                f"fused weight interleave {fw.fuse} incompatible with "
+                                f"manual tp_n={tp_n}"
+                            )
+                        qkv = mm(y, fw.weight, "row")
+                        q, k, v, *gate = _split_fused(
+                            qkv, fw.fuse // tp_n, tuple(d // tp_n for d in fw.dims)
+                        )
+                        gate = gate[0] if gate else None
+                        q = q.reshape(b, t, hq, h.head_dim)
+                        k = k.reshape(b, t, hkv, h.head_dim)
+                        v = v.reshape(b, t, hkv, h.head_dim)
+                    else:
+                        q = mm(y, lp["wq"], "row").reshape(b, t, hq, h.head_dim)
+                        k = mm(y, lp["wk"], "row").reshape(b, t, hkv, h.head_dim)
+                        v = mm(y, lp["wv"], "row").reshape(b, t, hkv, h.head_dim)
+                        if "wg" in lp:
+                            gate = mm(y, lp["wg"], "row")
+                    if "q_norm" in lp:
+                        q = qk_rms_norm(q, lp["q_norm"], h.norm_epsilon)
+                        k = qk_rms_norm(k, lp["k_norm"], h.norm_epsilon)
+                    if has_rope is not None:
+                        q = jnp.where(has_rope, apply_rope(q, cos, sin, interleaved), q)
+                        k = jnp.where(has_rope, apply_rope(k, cos, sin, interleaved), k)
+                    elif kinds[0].rope:
+                        q = apply_rope(q, cos, sin, interleaved)
+                        k = apply_rope(k, cos, sin, interleaved)
 
-            with jax.named_scope("attn"):
-                if gate is not None:
-                    with jax.named_scope("gate"):
-                        z = (
-                            z.astype(jnp.float32)
-                            * jax.nn.sigmoid(gate.astype(jnp.float32))
-                        ).astype(z.dtype)
-                o = mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
-                if "post_att_norm" in lp:
-                    o = rms_norm(o, lp["post_att_norm"], h.norm_epsilon)
-                x = x + o
+                if h.kv_pack > 1:
+                    with jax.named_scope("attn"):
+                        q, k, v = pack_heads(q, k, v)
+                caches = write_kv(caches, k, v, row, is_window)
+                if kinds[0].latent:
+                    z = attend_latent(q, caches, row, index)
+                    with jax.named_scope("attn"), jax.named_scope("latent_proj"):
+                        # a head's values from the weighted latents, once a query
+                        z = jnp.einsum("bthc,hcv->bthv", z, lp["wkv_b_v"]).reshape(
+                            b, t, hq * h.v_head_dim)
+                elif not isinstance(is_window, bool):
+                    # the stacks go in and only the attention's output comes out
+                    z = lax.cond(is_window, attend_window, attend_full, q, caches, row)
+                elif is_window:
+                    z = attend_window(q, caches, row)
+                else:
+                    z = attend_full(q, caches, row)
+
+                if h.kv_pack > 1:
+                    with jax.named_scope("attn"):
+                        z = unpack_heads(z)
+                with jax.named_scope("attn"):
+                    if gate is not None:
+                        with jax.named_scope("gate"):
+                            z = (
+                                z.astype(jnp.float32)
+                                * jax.nn.sigmoid(gate.astype(jnp.float32))
+                            ).astype(z.dtype)
+                    o = mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
+                    if "post_att_norm" in lp:
+                        o = rms_norm(o, lp["post_att_norm"], h.norm_epsilon)
+                    x = x + o
 
             # -- FFN block (reference: src/llm.cpp:405-557) --
             # experts of a chunk program: over the admitted lane's rows alone
-            x_all = x
-            if experts and lone:
+            if not lane_alone:
+                x_all = x
+            if experts and lone and not lane_alone:
                 x = lax.dynamic_slice_in_dim(x_all, lane, 1, axis=0)  # [1, T, D]
             with jax.named_scope("norm"):
                 y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
@@ -1743,7 +1913,7 @@ def run_layers(
                 if "post_ffn_norm" in lp:
                     f = rms_norm(f, lp["post_ffn_norm"], h.norm_epsilon)
                 x = x + f
-                if experts and lone:
+                if (experts and lone) or lane_alone:
                     x = lax.dynamic_update_slice_in_dim(x_all, x, lane, axis=0)
             return (x, caches), counts
 
@@ -1757,7 +1927,8 @@ def run_layers(
     # lane cache was copied out of the stack and back to write a row a lane
     caches = (
         (c_cache,) if i_cache is None else (c_cache, i_cache)
-    ) if latent else (k_cache, v_cache) if kw_cache is None else (
+    ) if latent else (k_cache, v_cache, s_cache) if stateful else (
+        k_cache, v_cache) if kw_cache is None else (
         k_cache, v_cache, kw_cache, vw_cache)
     counted = []
     for a, e in segments:
@@ -1777,11 +1948,56 @@ def run_layers(
             if of_ffn and name.startswith("dense_") == kinds[0].experts:
                 continue  # the other kind's
             lo, n = (r0, jax.tree.leaves(leaf)[0].shape[0]) if of_ffn else (a, n_layers)
-            if not _is_quant_stack(leaf) and (lo, e - a) != (0, n):
+            if stateful and name in _OPERATOR_LEAVES:
+                pass  # stacked over the layers of its kind: taken by `op_row`
+            elif not _is_quant_stack(leaf) and (lo, e - a) != (0, n):
                 leaf = jax.tree.map(lambda v: v[lo : lo + e - a], leaf)
             mine[name.removeprefix("dense_") if of_ffn else name] = leaf
         stacks = {k: v for k, v in mine.items() if _is_quant_stack(v)}
         sliced = {k: v for k, v in mine.items() if k not in stacks}
+        if stateful:
+            # the pattern is static: scan by whole periods of it, a period's
+            # layers unrolled in the body, each with its own kind's step
+            op_whole = {k: sliced.pop(k) for k in list(sliced) if k in _OPERATOR_LEAVES}
+            op_rows = jnp.asarray([kind.row for kind in kinds], jnp.int32)
+            xs = (sliced, jnp.arange(a, e, dtype=jnp.int32),
+                  {"op_row": op_rows, "row": (op_rows, op_rows)})
+            period = _pattern_period([kind.conv for kind in kinds])
+            done = 0
+            for n_groups, width in (((e - a) // period, period), (1, (e - a) % period)):
+                if not n_groups * width:
+                    continue
+                steps = [
+                    make_step(
+                        a, [kinds[done + j]],
+                        {k: v for k, v in stacks.items()
+                         if k not in _OPERATOR_LEAVES or (k in _CONV_LEAVES) == kinds[done + j].conv},
+                        ffn_row0=r0,
+                        whole={k: v for k, v in op_whole.items()
+                               if (k in _CONV_LEAVES) == kinds[done + j].conv},
+                    )
+                    for j in range(width)
+                ]
+
+                def group_step(carry, group, steps=steps):
+                    counts = None
+                    for j, step in enumerate(steps):
+                        carry, c = step(carry, jax.tree.map(lambda v: v[j], group))
+                        if c is not None:
+                            counts = c if counts is None else counts + c
+                    return carry, counts
+
+                lo, hi = done, done + n_groups * width
+                with jax.named_scope("layers"):
+                    (x, caches), counts = lax.scan(
+                        group_step, (x, caches),
+                        jax.tree.map(
+                            lambda v: v[lo:hi].reshape(n_groups, width, *v.shape[1:]), xs),
+                    )
+                if counts is not None:
+                    counted.append(counts.sum(axis=0))
+                done = hi
+            continue
         extra = {}
         if not alike:
             extra["row"] = tuple(

@@ -304,10 +304,11 @@ def _qmm_i4_kernel(l_ref, x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[:] = acc_ref[:]
 
 
-def _pick_block(n: int, preferred: int, ragged: bool = False) -> int:
+def _pick_block(n: int, preferred: int, ragged: bool = False, step: int = 128) -> int:
     """Block length for an axis of length n: the largest 128-multiple
     <= preferred that divides n (vocab dims like 151936 aren't multiples
-    of 256), or n itself when the whole axis fits in one block.
+    of 256), or n itself when the whole axis fits in one block. `step`:
+    what the block is to be a multiple of, where 128 is not enough.
 
     Otherwise no 128-multiple tiles the axis exactly (a tp shard of a
     Llama-3 vocab: 128256 / 4 = 32064 = 250.5 x 128). An output (n) axis
@@ -319,14 +320,14 @@ def _pick_block(n: int, preferred: int, ragged: bool = False) -> int:
     so that case raises here with the shape in it."""
     if n <= preferred:
         return n
-    for b in range(preferred, 0, -128):
+    for b in range(preferred // step * step, 0, -step):
         if n % b == 0:
             return b
     if ragged and preferred % 128 == 0:
         return preferred
     raise ValueError(
         f"no legal kernel block for an axis of length {n}: no multiple of "
-        f"128 <= {preferred} divides it"
+        f"{step} <= {preferred} divides it"
         + ("" if ragged else " and a contraction axis cannot be padded")
     )
 
@@ -349,10 +350,13 @@ ACC_X_TILE_BYTES = BLOCK_M * 3584 * 2
 def _pick_k_block(k: int, preferred: int, rows: int) -> int:
     """`_pick_block` for the contraction axis under `rows` activation rows:
     the deepest legal block whose activation tile, where k takes several
-    steps, stays within `ACC_X_TILE_BYTES`."""
-    bk = _pick_block(k, preferred)
+    steps, stays within `ACC_X_TILE_BYTES`. A block that is not the whole
+    axis is a multiple of 256: its 32nd part, the scales' block, has to be a
+    multiple of 8 rows (k = 11776 = 23 x 512 has the 128-multiple 2944, whose
+    92 scale rows the chip's compiler refuses; 512 it takes)."""
+    bk = _pick_block(k, preferred, step=8 * Q_BLOCK)
     if bk < k and rows * bk * 2 > ACC_X_TILE_BYTES:
-        bk = _pick_block(k, ACC_X_TILE_BYTES // (rows * 2) // 128 * 128)
+        bk = _pick_block(k, ACC_X_TILE_BYTES // (rows * 2) // 128 * 128, step=8 * Q_BLOCK)
     return bk
 
 

@@ -40,7 +40,8 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
     engine's 32*tp divisibility check, so the col shard boundaries stay
     nibble- and block-aligned."""
     moe = h.arch in (
-        LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32)
+        LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32,
+        LlmArch.LFM2_MOE)
     # stacked layer weights carry a leading layer axis; MoE adds an expert axis
     row = P(None, None, None, "tp") if moe else P(None, None, "tp")  # out split
     col = P(None, None, "tp", None) if moe else P(None, "tp", None)  # in split
@@ -70,6 +71,9 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
                 layers[prefix + n] = P(None, None, "tp")
             layers[prefix + "w2"] = P(None, "tp", None)
     layers["wg"] = P(None, None, "tp")  # the attention gate: heads, as wq
+    # a gated short convolution's projections and taps: one device holds them
+    for n in ("conv_in", "conv_out", "conv_w"):
+        layers[n] = P()
     for n in ("q_norm", "k_norm", "post_att_norm", "post_ffn_norm"):
         layers[n] = P()
     return {

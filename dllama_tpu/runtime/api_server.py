@@ -287,7 +287,8 @@ class _AdmittingLane:
     tokens: list[int]  # full conversation prompt, pending token included
     pos0: int
     cursor: int  # fill tokens already in the lane's cache (adopted rows
-    # count: the chunked prefill starts at the radix-match point)
+    # count: the chunked prefill starts at the radix-match point, or for a
+    # model with lane state `engine.state_replay_rows` before it)
     prompt_end: int
     max_pos: int
     public_prompt: str
@@ -738,7 +739,7 @@ class LaneScheduler:
                 continue
             # the partial prefill died with the cache; the adopt copy
             # must re-run too (it targeted the old buffer)
-            adm.cursor = adm.start_pos
+            adm.cursor = self._prefill_start(adm.start_pos)
             adm.adopted = False
             if native:
                 # the adopted prefix's pages died with the pool: this
@@ -768,12 +769,12 @@ class LaneScheduler:
             self._draft_pos.pop(lane, None)
             start_pos, pages = 0, []
             if self.kv is not None:
-                start_pos, pages = self.kv.match(lane, ls.history)
+                start_pos, pages = self._match_prefix(lane, ls.history)
             self.admitting[lane] = _AdmittingLane(
                 job=ls.job,
                 tokens=list(ls.history),
                 pos0=0,
-                cursor=start_pos,
+                cursor=self._prefill_start(start_pos),
                 prompt_end=len(ls.history) - 1,
                 max_pos=ls.max_pos,
                 public_prompt="",
@@ -1136,6 +1137,24 @@ class LaneScheduler:
             n_pending=n_pending, n_parked=self._n_parked,
         )
 
+    def _prefill_start(self, start_pos: int) -> int:
+        """Where the chunked prefill begins behind an adopted prefix of
+        `start_pos` positions: at its end, or, for a model whose lanes keep
+        state (`engine.state_replay_rows`), that many positions before it:
+        those rows run again, their cache writes masked, to rebuild the
+        lane's states (`engine.prefill_lane_chunk`'s `write_floor`)."""
+        return start_pos - self.engine.state_replay_rows if start_pos else start_pos
+
+    def _match_prefix(self, lane: int, tokens: list[int]) -> tuple[int, list]:
+        """The pool's longest stored prefix of `tokens` and its pages,
+        retained for `lane`. A model whose lanes keep state declines a
+        prefix no longer than what it would run again to rebuild them."""
+        start_pos, pages = self.kv.match(lane, tokens)
+        if start_pos and self._prefill_start(start_pos) <= 0:
+            self.kv.release_lane(lane)
+            return 0, []
+        return start_pos, pages
+
     def _begin_admission(self, lane: int, job: LaneJob) -> None:
         """Resolve the prompt and park it as an _AdmittingLane — the front
         half of the old monolithic _admit, with NO engine work: the adopt
@@ -1188,7 +1207,7 @@ class LaneScheduler:
                 # match retains the pages for this lane immediately —
                 # the adopt copy runs a tick later and unpinned pages
                 # could be evicted/reallocated in between
-                start_pos, adopt_pages = self.kv.match(lane, tokens)
+                start_pos, adopt_pages = self._match_prefix(lane, tokens)
             if start_pos > 0:
                 state.m_prefix_hits.inc()
                 state.m_reused_tokens.inc(start_pos)
@@ -1237,7 +1256,7 @@ class LaneScheduler:
                 job=job,
                 tokens=tokens,
                 pos0=0,
-                cursor=start_pos,
+                cursor=self._prefill_start(start_pos),
                 prompt_end=prompt_end,
                 max_pos=max_pos,
                 public_prompt=public_prompt,
@@ -1269,7 +1288,7 @@ class LaneScheduler:
         job._park_resume = None
         try:
             # park requires the shared pool, so self.kv is non-None here
-            start_pos, adopt_pages = self.kv.match(lane, ls.history)
+            start_pos, adopt_pages = self._match_prefix(lane, ls.history)
             if start_pos > 0:
                 state.m_prefix_hits.inc()
                 state.m_reused_tokens.inc(start_pos)
@@ -1282,7 +1301,7 @@ class LaneScheduler:
                 job=job,
                 tokens=list(ls.history),
                 pos0=0,
-                cursor=start_pos,
+                cursor=self._prefill_start(start_pos),
                 prompt_end=len(ls.history) - 1,
                 max_pos=ls.max_pos,
                 public_prompt="",
@@ -1369,6 +1388,9 @@ class LaneScheduler:
                             fills[adm.cursor:],
                             adm.pos0 + adm.cursor,
                             budget=self.admission_chunk,
+                            # lane state: the adopted rows stay as they are
+                            **({"write_floor": adm.pos0 + adm.start_pos}
+                               if adm.cursor < adm.start_pos else {}),
                         ),
                     )
                 finally:

@@ -297,11 +297,18 @@ class InferenceEngine:
         # latent attention keeps one stack of `[c | k_rope]` rows, one head
         # for all, which heads-over-chips cannot divide
         self._latent = self.header.latent
-        if self._two_cache_kinds or self._latent:
+        # layers that keep a state a lane and no cache row a position (gated
+        # short convolutions): the state stack rides in `self.cache` beside
+        # the attention layers' keys and values, on one device
+        self._stateful = self.header.stateful
+        if self._two_cache_kinds or self._latent or self._stateful:
             what, cache = (
                 ("latent attention layers over a cache of latent rows",
                  "a cache of latent rows")
                 if self._latent else
+                ("convolution layers that keep a state a lane",
+                 "a convolution layer's state")
+                if self._stateful else
                 ("window attention layers over a ring cache",
                  "the window layers' ring cache")
             )
@@ -316,6 +323,12 @@ class InferenceEngine:
                     f"--kv-dtype int8: {cache} is not "
                     f"quantized ({self.header.arch.name})"
                 )
+        if self._stateful and batch_size < 2:
+            raise ValueError(
+                f"--batch-size {batch_size}: a model with lane state is served by "
+                f"the lane engine, which needs two lanes or more "
+                f"({self.header.arch.name})"
+            )
         validate_tp(self.header, tp)
         # sequence parallelism: the KV cache's sequence axis shards over sp
         # chips (the long-context axis; models/transformer._attention_sp).
@@ -471,6 +484,31 @@ class InferenceEngine:
             # the latent rows and, where an index picks among them, its keys
             names = ("c", "i") if self.header.indexed else ("c",)
             self._cache_sharding = {n: self._cache_sharding["k"] for n in names}
+        # positions before an adopted prefix's end that a lane runs again to
+        # rebuild its states (0: the model keeps none): a convolution layer's
+        # state reaches back `conv_state_rows` positions of its own input, so
+        # that of the k-th from the bottom k times as many of the tokens, and
+        # an attention layer between them reads older positions from the cache
+        # alone. Rounded up to a multiple of 8 rows.
+        n_conv = sum(k.conv for k in layer_table(self.header))
+        self.state_replay_rows = -(-n_conv * self.header.conv_state_rows // 8) * 8
+        if self._stateful:
+            self._cache_sharding["s"] = NamedSharding(self.mesh, P())
+            # where each lane's states stand: the position behind the last row
+            # that moved them, None where nothing was installed
+            self._state_pos: list[int | None] = [None] * batch_size
+        self._m_state_installs = self.obs.counter(
+            "dllama_conv_state_installs_total",
+            "Lane states installed at an admission's first chunk: zero = from "
+            "position 0, replay = rebuilt behind an adopted prefix by running "
+            "the positions before its end again.",
+            labelnames=("how",),
+        )
+        self._m_replay_tokens = self.obs.counter(
+            "dllama_conv_replay_tokens_total",
+            "Token rows of adopted prefixes that chunk programs ran again to "
+            "rebuild a lane's convolution states (cache writes masked).",
+        )
         self._m_ring_wraps = self.obs.counter(
             "dllama_kv_ring_wraps_total",
             "Times a lane's position passed the end of the window layers' "
@@ -530,7 +568,8 @@ class InferenceEngine:
             "Device bytes of the lane KV cache by kind of layer: full = "
             "rows for the whole context, window = a ring of the window "
             "and one chunk, latent = one stack of [c | k_rope] rows for the "
-            "whole context, index = the index keys beside them.",
+            "whole context, index = the index keys beside them, conv = the "
+            "convolution layers' states (rows a lane, not a position).",
             labelnames=("kind",),
         )
         self.kv_cache_bytes = {
@@ -540,8 +579,9 @@ class InferenceEngine:
             )
             for kind, names in (
                 ("full", ("k", "v")), ("window", ("kw", "vw")), ("latent", ("c",)),
-                ("index", ("i",)))
-            if kind != "index" or "i" in self.cache  # a kind of its own where there is one
+                ("index", ("i",)), ("conv", ("s",)))
+            # a kind of its own where there is one
+            if kind not in ("index", "conv") or names[0] in self.cache
         }
         for kind, n in self.kv_cache_bytes.items():
             g_bytes.labels(kind=kind).set(n)
@@ -668,7 +708,7 @@ class InferenceEngine:
 
             def fwd(params, tokens, pos, cache, *, attn_window=0,
                     logits_mode="all", attn_park_threshold=0, n_micro=1,
-                    route_stats=None, one_live_lane=False):
+                    route_stats=None, one_live_lane=False, **lane_state):
                 del n_micro  # sequence-wave microbatching is pp-only
                 return forward(
                     params, h, tokens, pos, cache, mesh=mesh,
@@ -677,7 +717,7 @@ class InferenceEngine:
                     sync_quant=sync_quant,
                     moe_decode_dedup=moe_decode_dedup,
                     kv_ring=kv_ring, route_stats=route_stats,
-                    one_live_lane=one_live_lane,
+                    one_live_lane=one_live_lane, **lane_state,
                 )
 
         self._fwd = fwd
@@ -713,6 +753,8 @@ class InferenceEngine:
         self.recorder.record("cache_epoch", epoch=self.cache_epoch)
         # a block in flight wrote the cache that went: nothing continues it
         self._uncollected = None
+        if self._stateful:
+            self._state_pos = [None] * self.batch_size
         cache = init_kv_cache(
             self.header,
             self.batch_size,
@@ -851,6 +893,9 @@ class InferenceEngine:
             self._m_index_rows.labels(kind="scored").inc(rows["rows_latent"])
             self._m_index_rows.labels(kind="selected").inc(rows["rows_selected"])
             return rows
+        if self._stateful:
+            # the attention layers, a subset of the model's, see the context
+            return {"rows_full": sum(p + i + 1 for p in starts for i in range(n))}
         if not self._two_cache_kinds:
             return {}
         wraps = sum((p + n) // self.kv_ring - p // self.kv_ring for p in starts)
@@ -902,6 +947,49 @@ class InferenceEngine:
         pool in their signatures (a copy: the host goes on writing its
         mirror); nothing for the slab's programs."""
         return (self._page_table.copy(),) if self.kv_native else ()
+
+    def _lane_state_arg(self, lane: int, pos0: int, width: int, floor: int) -> tuple:
+        """A chunk program's `aux` for a model with lane state, and the
+        host's account of where the lane's states then stand; nothing for a
+        model without. The chunk starts the states from zero unless it
+        continues where they stand (position 0 is zero by itself)."""
+        if not self._stateful:
+            return ()
+        if floor > pos0 and pos0 + self.state_replay_rows < floor:
+            raise ValueError(
+                f"a write floor of {floor} over a chunk at {pos0}: a replay "
+                f"starts {self.state_replay_rows} positions before the floor"
+            )
+        fresh = self._state_pos[lane] != pos0
+        if fresh:
+            if pos0 and floor <= pos0:
+                raise ValueError(
+                    f"lane {lane}: a chunk at position {pos0} neither continues "
+                    f"the lane's states (at {self._state_pos[lane]}) nor replays "
+                    "behind an adopted prefix (write_floor)"
+                )
+            self._m_state_installs.labels(how="replay" if pos0 else "zero").inc()
+        self._state_pos[lane] = pos0 + width
+        return (np.asarray([width, floor, fresh], np.int32),)
+
+    def _chunk_state_fields(self, pos0: int, width: int, floor: int) -> dict:
+        """`step_dispatch` fields of a chunk of a model with lane state:
+        `state_lanes`, the lanes whose states the program moves (the
+        admitted one), and `replay_tokens`, the chunk's rows below the write
+        floor, run again for the states alone; counted too."""
+        if not self._stateful:
+            return {}
+        replay = max(0, min(floor, pos0 + width) - pos0)
+        if replay:
+            self._m_replay_tokens.inc(replay)
+        return {"state_lanes": 1, "replay_tokens": replay}
+
+    def _require_stateless(self, what: str) -> None:
+        if self._stateful:
+            raise ValueError(
+                f"{what}: a model with lane state runs through the lane programs "
+                f"alone (prefill_lane_chunk, decode_lanes) ({self.header.arch.name})"
+            )
 
     def _one_lane_chunk(self, lane: int, tokens, bucket: int, pos0: int, park: int):
         """A chunk program's token rows and positions: ``tokens`` in
@@ -1055,6 +1143,7 @@ class InferenceEngine:
 
     def _step_fn(self, t: int, greedy: bool, window: int = 0):
         """Build/jit the forward step for chunk length `t`."""
+        self._require_stateless("prefill / decode_step")
 
         def make():
             precision = self._precision
@@ -1126,6 +1215,7 @@ class InferenceEngine:
         executable — which is what lets `_prefetch_block` build the next
         attention window's program off-thread before a lane crosses the
         boundary (no synchronous compile at the crossing)."""
+        self._require_stateless("decode_block")
 
         def make():
             precision = self._precision
@@ -1287,6 +1377,7 @@ class InferenceEngine:
         returns the summed next-token NLL of the chunk's unmasked rows as
         ONE scalar (no [T, vocab] logits transfer — the reference ships the
         full logits pipe to host per batch, src/dllama.cpp:132-172)."""
+        self._require_stateless("perplexity")
 
         def make():
             precision = self._precision
@@ -1379,6 +1470,11 @@ class InferenceEngine:
     # -- per-lane serving (continuous-batching surface) ----------------------
 
     def _refuse_speculation(self) -> None:
+        if self._stateful:
+            raise ValueError(
+                "--speculation: a rejected draft would have moved a lane's "
+                f"convolution states, which cannot step back ({self.header.arch.name})"
+            )
         if self._latent:
             raise ValueError(
                 "--speculation: the verify programs are untested over a "
@@ -1413,6 +1509,8 @@ class InferenceEngine:
             tok,
             self._cache_specs,
             jax.ShapeDtypeStruct((b,), jnp.int32),
+            # lane state: (the chunk's real rows, the write floor, fresh)
+            *((jax.ShapeDtypeStruct((3,), jnp.int32),) if self._stateful else ()),
         )
 
     def _lane_prefill_fn(
@@ -1422,26 +1520,43 @@ class InferenceEngine:
         own position; parked lanes write into the padding rows.
         AOT-compiled like the decode blocks — this is the lane scheduler's
         ADMISSION path, so a synchronous XLA compile here is exactly the
-        first-admission stall rehearse_admission() exists to remove."""
+        first-admission stall rehearse_admission() exists to remove.
+
+        A model with lane state takes one more argument, `aux` = (the
+        chunk's real rows, the write floor, fresh): a chunk is padded to its
+        bucket and every lane writes one, so the admitted lane's states move
+        by its real rows alone and every other lane's stay; cache rows at
+        positions below the floor keep what they hold; and `fresh` starts
+        the lane's states from zero (`prefill_lane_chunk`)."""
 
         def make():
             precision = self._precision
             fwd = self._fwd
             park = self._park
+            stateful = self._stateful
 
             @partial(jax.jit, donate_argnums=(2,))
-            def step(params, tokens, cache, pos_vec):
+            def step(params, tokens, cache, pos_vec, *aux):
                 ctx = (
                     jax.default_matmul_precision(precision)
                     if precision
                     else contextlib.nullcontext()
                 )
+                lane_state = {}
+                if stateful:
+                    (aux,) = aux
+                    live = pos_vec < park
+                    lane_state = dict(
+                        state_rows=jnp.where(live, aux[0], 0),
+                        write_floor=aux[1],
+                        state_fresh=jnp.logical_and(live, aux[2] > 0),
+                    )
                 with ctx:
                     _, cache = fwd(
                         params, tokens, pos_vec, cache,
                         attn_window=window, attn_park_threshold=park,
                         logits_mode="last", n_micro=self._pp_micro(t),
-                        one_live_lane=True,
+                        one_live_lane=True, **lane_state,
                     )
                 return cache
 
@@ -1591,6 +1706,7 @@ class InferenceEngine:
         tokens: list[int],
         pos0: int,
         budget: int | None = None,
+        write_floor: int = 0,
     ) -> int:
         """Write ONE bucket-shaped chunk of `tokens` (fill rows — the
         caller already dropped the prompt's final token) into `lane`'s
@@ -1601,7 +1717,16 @@ class InferenceEngine:
         whole prefill. `budget` caps the chunk width (--admission-chunk).
         Chunks reuse the same _lane_prefill_fn bucket programs as the
         monolithic path — no new compiled shapes — and write the same KV
-        rows, so chunked admission is token-exact vs monolithic."""
+        rows, so chunked admission is token-exact vs monolithic.
+
+        A model with lane state (`header.stateful`): the lane's states move
+        by the chunk's `width` real rows. A chunk at `pos0` that does not
+        continue where the lane's states stand starts them from zero: at
+        position 0 a cold admission, elsewhere a REPLAY behind a prefix the
+        lane adopted (`kv_adopt`): the caller passes the prefix's length as
+        `write_floor` and starts `state_replay_rows` positions before it;
+        the cache rows below the floor stay the adopted ones, and at the
+        floor every layer's state is what a cold run would hold there."""
         self._require_lanes()
         if not 0 <= lane < self.batch_size:
             raise ValueError(f"lane {lane} out of range")
@@ -1633,7 +1758,8 @@ class InferenceEngine:
             lane, tokens[:width], bucket, pos0, window if native else self._park
         )
         arr, *rest = self._host_args(
-            *self._page_table_arg(), posv, tokens=rows
+            *self._page_table_arg(), posv,
+            *self._lane_state_arg(lane, pos0, width, write_floor), tokens=rows
         )
         with self._dispatch(
             "prefill_lane_chunk", prep, host_args=1 + len(rest),
@@ -1641,6 +1767,7 @@ class InferenceEngine:
             n_tokens=width, bucket=bucket, window=window,
             **self._rows_in_context([pos0], width),
             **self._chunk_expert_rows(bucket),
+            **self._chunk_state_fields(pos0, width, write_floor),
         ):
             if native:
                 with self._kv_pool_guard():
@@ -1654,7 +1781,9 @@ class InferenceEngine:
                     self.cache = step(self.params, arr, self.cache, *rest)
         return width
 
-    def prefill_lane(self, lane: int, tokens: list[int], pos0: int = 0) -> None:
+    def prefill_lane(
+        self, lane: int, tokens: list[int], pos0: int = 0, write_floor: int = 0
+    ) -> None:
         """Prefill one lane's prompt (all but the last token) while every
         other lane's cache rows stay untouched — their writes land in the
         padding rows beyond seqLen, and causal masking hides those rows
@@ -1683,7 +1812,10 @@ class InferenceEngine:
         )
         t0 = time.perf_counter()
         while fills:
-            width = self.prefill_lane_chunk(lane, fills, p)
+            # lane state: a replay's chunks, which start below the floor, keep it
+            width = self.prefill_lane_chunk(
+                lane, fills, p, **({"write_floor": write_floor} if p < write_floor else {})
+            )
             fills = fills[width:]
             p += width
         if p > pos0:
@@ -1716,11 +1848,12 @@ class InferenceEngine:
             h.n_layers, self._kv_pool_pages, h.n_kv_heads,
             self._kv_page_size, h.head_dim,
         )
-        if self._two_cache_kinds or self._latent:
+        if self._two_cache_kinds or self._latent or self._stateful:
             # a page holds its positions' rows of every layer, so the pool
             # is the cache's stacks (two kinds of layer: two pairs; latent
-            # rows: one stack, one head, `latent_row` wide) under one page
-            # number
+            # rows: one stack, one head, `latent_row` wide; lane state: the
+            # attention layers' keys and values, and nothing of the states,
+            # which belong to no position) under one page number
             return {
                 name: jax.device_put(
                     jnp.zeros(
@@ -1728,7 +1861,7 @@ class InferenceEngine:
                         self.kv_dtype,
                     ), sharding
                 )
-                for name, leaf in self._cache_specs.items()
+                for name, leaf in self._cache_specs.items() if name != "s"
             }
         if self.kv_dtype == jnp.int8:
             def leaf():
@@ -1767,6 +1900,11 @@ class InferenceEngine:
             # padding (dynamic_slice would clamp silently and misalign)
             raise ValueError(
                 f"page_size {page_size} exceeds lane padding {self._lane_pad}"
+            )
+        if native and self._stateful:
+            raise ValueError(
+                "--kv-native 1: the pool-native programs keep no lane state; "
+                f"{self.header.arch.name} has convolution layers"
             )
         if native and self._latent:
             raise ValueError(
@@ -1934,13 +2072,18 @@ class InferenceEngine:
             }
 
             def by_stack(leaf, cache, pool):
-                return {
+                # the lane states are no stack of the pool: an adopted cache
+                # hands them through, a published pool never sees them
+                out = {
                     name: jax.tree.map(
                         partial(leaf, first=row0[name]),
                         cache[name], pool[name],
                     )
-                    for name in cache
+                    for name in pool
                 }
+                if kind == "adopt":
+                    out.update({n: c for n, c in cache.items() if n not in pool})
+                return out
 
             if kind == "adopt":
 
@@ -2017,6 +2160,9 @@ class InferenceEngine:
         if fault is not None and not fault.poison:
             raise fault
         chunks, ids = self._kv_copy_chunks(n), np.asarray(page_ids, np.int32)
+        if self._stateful:
+            # the rows are another lane's work: this lane's states stand nowhere
+            self._state_pos[lane] = None
         with self._dispatch(
             "kv_adopt", head=head, host_args=3 * len(chunks), lane=lane, n_pages=n
         ):
@@ -2573,6 +2719,18 @@ class InferenceEngine:
             pos=deepest, n_steps=n_steps,
             window=window, n_live=len(live), n_sampling=n_sampling,
         )
+        if self._stateful:
+            # a live lane's states move a row a step; they have to stand
+            # where the lane decodes (position 0 is zero by itself)
+            astray = [i for i in live if pos[i] and self._state_pos[i] != pos[i]]
+            if astray:
+                raise ValueError(
+                    f"lanes {astray} decode at {[pos[i] for i in astray]} and their "
+                    f"states stand at {[self._state_pos[i] for i in astray]}"
+                )
+            for i in live:
+                self._state_pos[i] = pos[i] + n_steps
+            fields["state_lanes"] = len(live)
         # ahead: a block is enqueued and not collected, so the device has
         # this one queued when that one ends
         t0 = self._begin_dispatch(

@@ -84,6 +84,8 @@ _REPLICATED_KEYS = {
     "final_norm", "rope_cos", "rope_sin",
     "att_norm", "ffn_norm", "q_norm", "k_norm", "moe_gate",
     "post_att_norm", "post_ffn_norm", "expert_bias",
+    # a gated short convolution's projections and taps: one device holds them
+    "conv_in", "conv_out", "conv_w",
 }
 
 

@@ -333,3 +333,52 @@ def tiny_dsv32_config(**over) -> dict:
 def make_tiny_dsv32(path: str, seed: int = 3, **over) -> dict:
     """The same for `deepseek_v32`, as `make_tiny_afmoe`."""
     return _write_tiny(path, tiny_dsv32_config(**over), seed)
+
+
+LFM2_TYPES = ["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 2 + [
+    "full_attention", "conv"]
+
+
+def attn_layer_words(layer_types) -> dict:
+    """The header's two words that name the attention layers of a pattern of
+    `conv` and `full_attention` layers (keys 48 and 49: 30 layers a word)."""
+    mask = sum(1 << l for l, t in enumerate(layer_types) if t == "full_attention")
+    return {"attn_layers_lo": mask & ((1 << 30) - 1), "attn_layers_hi": mask >> 30}
+
+
+def tiny_lfm2_config(layer_types=None, **over) -> dict:
+    """A benchmark configuration file's worth of the `lfm2_moe` architecture
+    (gated short convolutions that keep a state a lane, an attention layer
+    every fourth, two leading dense layers and then a share of the experts
+    behind a biased sigmoid router) at test widths. The default pattern is
+    the published one's shape: its sparse run is two whole periods of four
+    and a tail of two, as the published 38 are nine and a tail of two."""
+    types = list(layer_types or LFM2_TYPES)
+    cfg = {
+        "name": "lfm2-tiny", "family": "lfm2_moe",
+        "hidden_size": 64, "intermediate_size": 160, "moe_intermediate_size": 128,
+        "num_hidden_layers": len(types), "num_attention_heads": 8,
+        "num_key_value_heads": 4, "head_dim": 8, "vocab_size": 512,
+        "max_position_embeddings": 4096, "rope_theta": 1000000, "rms_norm_eps": 1e-5,
+        "conv_L_cache": 3, "layer_types": types,
+        "num_experts": 4, "num_routed_experts": 8, "first_expert": 0,
+        "num_experts_per_tok": 2, "num_dense_layers": 2,
+        "norm_topk_prob": True, "routed_scaling_factor": 1,
+    }
+    cfg.update(over)
+    cfg["file"] = {
+        "arch": "LFM2_MOE", "rope_pairing": "half", "qk_norm": True, "norm_epsilon_enum": 5,
+        "header": {
+            "n_dense_layers": cfg["num_dense_layers"], "score_func": 1, "route_norm": 1,
+            "n_routed_experts": cfg["num_routed_experts"],
+            "first_expert": cfg["first_expert"],
+            "conv_l_cache": cfg["conv_L_cache"], **attn_layer_words(types),
+        },
+        "tensors": {"expert_bias": {"dist": "normal", "std": 0.05}},
+    }
+    return cfg
+
+
+def make_tiny_lfm2(path: str, seed: int = 3, **over) -> dict:
+    """The same for `lfm2_moe`, as `make_tiny_afmoe`."""
+    return _write_tiny(path, tiny_lfm2_config(**over), seed)

@@ -681,6 +681,128 @@ def test_layer_scan_writes_latent_rows_and_index_keys_in_place(
         compiled_text(qmatmul_2d, *q40_args(lanes, 7168, 16160, s))
 
 
+# LFM2-24B-A2B's cut: 10 attention layers at 4096 + 512 rows, 30 convolution
+# layers' states of 2 rows of 2048, 16 lanes
+LFM2_CACHE = (10, 16, 4, 4608, 128)  # two heads of 64 to a row
+LFM2_STATE = (30, 16, 2, 2048)
+LFM2_TYPES = ["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 9 + [
+    "full_attention", "conv"]
+
+
+def lfm2_header(published: bool):
+    """LFM2-24B-A2B as one of four chips, or the tests' tiny widths with the
+    same pattern over 12 layers."""
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+
+    types = LFM2_TYPES if published else LFM2_TYPES[:10] + LFM2_TYPES[-2:]
+    sizes = dict(
+        dim=2048, hidden_dim=11776, moe_hidden_dim=1536, n_heads=32, n_kv_heads=8,
+        n_experts=16, n_routed_experts=64, n_active_experts=4, vocab_size=16384,
+    ) if published else dict(
+        dim=256, hidden_dim=512, moe_hidden_dim=256, n_heads=4, n_kv_heads=2,
+        n_experts=4, n_routed_experts=8, n_active_experts=2, vocab_size=512,
+    )
+    return LlmHeader(
+        arch=LlmArch.LFM2_MOE, n_layers=len(types), seq_len=4096, head_dim=64,
+        rope_type=RopeType.FALCON, n_dense_layers=2, score_sigmoid=True,
+        conv_l_cache=3,
+        attn_layers=sum(1 << l for l, t in enumerate(types) if t == "full_attention"),
+        **sizes,
+    )
+
+
+def lfm2_layers(h, s):
+    """The leaves as the loader stacks them: each operator's over the layers
+    of its kind, the dense layers' FFN apart, the held experts' stacks."""
+    from dllama_tpu.formats.model_file import layer_table
+    from dllama_tpu.ops.quant_matmul import FusedQuantWeight, QuantWeight
+
+    table = layer_table(h)
+    n, nc = h.n_layers, sum(k.conv for k in table)
+    na, ns = n - nc, n - h.n_dense_layers
+    d, f, fd, e = h.dim, h.moe_hidden_dim, h.hidden_dim, h.n_experts
+
+    def f32(*shape):
+        return sds(shape, jnp.float32, s)
+
+    def fused(layers, k, dims):
+        return FusedQuantWeight(q40_stack(layers, k, sum(dims), s), 1, tuple(dims))
+
+    def experts(k, width):
+        return QuantWeight(sds((ns, e, k, width), jnp.int8, s),
+                           sds((ns, e, k // 32, width), jnp.float32, s))
+
+    return dict(
+        att_norm=f32(n, d), ffn_norm=f32(n, d), q_norm=f32(na, 64), k_norm=f32(na, 64),
+        wqkv=fused(na, d, (h.q_dim, h.kv_dim, h.kv_dim)), wo=q40_stack(na, h.q_dim, d, s),
+        conv_in=q40_stack(nc, d, 3 * d, s), conv_out=q40_stack(nc, d, d, s),
+        conv_w=f32(nc, 3, d),
+        dense_w13=fused(2, d, (fd, fd)), dense_w2=q40_stack(2, fd, d, s),
+        moe_gate=f32(ns, d, h.n_routed_experts), expert_bias=f32(ns, h.n_routed_experts),
+        w1=experts(d, f), w3=experts(d, f), w2=experts(f, d),
+    )
+
+
+@pytest.mark.parametrize("rows,window", [(1, 1024), (512, 2048)], ids=["decode", "prefill"])
+@pytest.mark.parametrize("published", [True, False], ids=["published", "tiny"])
+def test_layer_scan_carries_lane_state_beside_a_cache_of_ten_layers(
+        one_chip, monkeypatch, published, rows, window):
+    """LFM2-24B-A2B's decode step and 512-row chunk at its cell's size, and
+    at the tests' tiny widths: the scan carries the cache stack of the 10
+    attention layers and the state stack of the 30 convolution layers, each
+    written in place by `dynamic-update-slice` (a chunk's rows; a lane's two
+    rows), no layer of either is copied out, no branch is taken on the
+    device over the layer pattern, and the chunk program's convolution
+    layers compute the admitted lane's 512 rows, not the 8192 of all."""
+    from dllama_tpu.formats.model_file import layer_table
+    from dllama_tpu.models import transformer as tf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = one_chip
+    h = lfm2_header(published)
+    table = layer_table(h)
+    nc = sum(k.conv for k in table)
+    lanes = 16
+    cache = (h.n_layers - nc, lanes, h.n_kv_heads // h.kv_pack, 4608, 64 * h.kv_pack)
+    state = (nc, lanes, 2, h.dim)
+    assert cache == LFM2_CACHE or not published
+
+    def step(x, layers, k, v, st, pos, cos, sin, aux):
+        counts = []
+        live = pos < 4096
+        out = tf.run_layers(
+            x, layers, k, v, h, pos, jnp.where(live, pos, -4608), cos, sin,
+            attn_window=window, route_stats=counts, one_live_lane=rows > 1,
+            s_cache=st, state_rows=jnp.where(live, aux[0], 0),
+            write_floor=aux[1] if rows > 1 else None,  # a chunk program's alone
+            state_fresh=jnp.logical_and(live, aux[2] > 0),
+        )
+        return out, counts
+
+    text = compiled_text(
+        jax.jit(step, donate_argnums=(2, 3, 4)),
+        sds((lanes, rows, h.dim), jnp.bfloat16, s), lfm2_layers(h, s),
+        sds(cache, jnp.bfloat16, s), sds(cache, jnp.bfloat16, s),
+        sds(state, jnp.bfloat16, s), sds((lanes,), jnp.int32, s),
+        sds((lanes, rows, 32), jnp.float32, s), sds((lanes, rows, 32), jnp.float32, s),
+        sds((3,), jnp.int32, s),
+    )
+    assert "moe_held_experts_q40" in text and " conditional(" not in text
+    if not published:
+        return  # the tiny widths lower: a shape the chip rejects fails here
+    assert text.count("dynamic-update-slice(") >= 3  # K rows, V rows, a state
+    # the cache stack is written in place and never copied; the state stack
+    # (3.9 MB) the compiler may lay out its own way for a block: no matter
+    assert not cache_copies(text, cache), cache_copies(text, cache)
+    # nor one lane's rows of every layer: the masked write below a floor read
+    # a slice a lane once, and the chip rewrote all sixteen a layer (2.1 ms)
+    assert f"bf16[{cache[0]},1,{cache[2]},{cache[3]},{cache[4]}]" not in text
+    if rows > 1:
+        # `in_proj` of a convolution layer over one lane's bucket
+        assert f"bf16[{rows},{3 * h.dim}]" in text or f"f32[{rows},{3 * h.dim}]" in text
+        assert f"[{lanes * rows},{3 * h.dim}]" not in text
+
+
 def test_layer_scan_writes_cache_rows_in_place_tp4(tp4, monkeypatch):
     """The same prefill chunk at tp=4: KH is the stack's sharded axis
     (`P(None, "dp", "tp", None, None)` into the flash kernel's `shard_map`,
