@@ -152,9 +152,13 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
     p.add_argument("--gpu-segments", default=None)
     p.add_argument("--weight-format", default="auto",
                    choices=["auto", "q40", "q40i4", "dense"],
-                   help="q40 keeps weights block-quantized on device "
-                        "(Pallas kernel); q40i4 stores packed nibbles "
-                        "(0.56 B/weight, in-kernel unpack)")
+                   help="q40 keeps weights block-quantized on device as "
+                        "int8 values (Pallas kernel, 1.125 B/weight); q40i4 "
+                        "keeps the dense matmuls' nibbles packed (0.625 "
+                        "B/weight, unpacked in the kernel; routed experts "
+                        "stay int8); auto = q40i4 on a TPU with a Q40 file "
+                        "(q40 where a matmul's in dim is no multiple of "
+                        "256 a shard), dense elsewhere")
     p.add_argument("--profile", default=None, metavar="DIR",
                    help="write a jax.profiler trace of the run to DIR")
     p.add_argument("--trace-out", default=None, metavar="PATH",
@@ -353,7 +357,13 @@ def load_engine(args):
             f"⚠️  tokenizer vocab ({tok.vocab_size}) != model vocab "
             f"({h.vocab_size}); decoding may fail for out-of-range tokens"
         )
-    print(f"💡 WeightFormat: {engine.weight_format}")
+    wb = engine.weight_bytes
+    print(
+        f"💡 WeightFormat: {engine.weight_format} (resident: packed "
+        f"{wb['packed'] / 1e9:.2f} GB, int8 {wb['int8'] / 1e9:.2f} GB, float "
+        f"{wb['float'] / 1e9:.2f} GB; packed share of a decode step's "
+        f"quantized bytes {wb['decode_packed_share']:.2f})"
+    )
     from .utils.telemetry import memory_report
 
     mem = memory_report(

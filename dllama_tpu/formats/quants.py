@@ -15,10 +15,10 @@ Quantization rounding matches converter/writer.py exactly (asymmetric
 tensors we write are byte-identical with the reference converter's output.
 
 These host-side codecs are numpy-vectorized. On device the framework never
-touches this packed layout: weights are unpacked once at load time into a
-planar (int8 values, fp scales) pair — `q40_to_planar` — which is the layout
-the Pallas matmul kernel and the jnp dequant path both consume (int8 lanes
-tile cleanly onto the TPU MXU/VPU; interleaved nibble+scale blocks do not).
+touches the wire's interleaved nibble+scale blocks: weights are re-laid once
+at load time into two planes, values and scales — `q40_to_planar` (int8
+values) or `pack_q40_device` (the nibbles kept packed, eight to an int32
+word) — which tile cleanly onto the TPU's (8, 128) vector registers.
 """
 
 from __future__ import annotations
@@ -158,27 +158,33 @@ def q40_to_planar(raw: np.ndarray, n_elements: int) -> tuple[np.ndarray, np.ndar
 
 
 def pack_q40_device(
-    q: np.ndarray, d: np.ndarray
+    raw: np.ndarray, out_dim: int, in_dim: int
 ) -> tuple[np.ndarray, np.ndarray]:
-    """Re-pack device-layout planar Q40 into the packed-nibble device
-    format (weight_format="q40i4"; ops.quant_matmul.PackedQuantWeight).
+    """Packed Q40 bytes of a [out, in] tensor -> the packed-nibble device
+    format (weight_format="q40i4"; ops.quant_matmul.PackedQuantWeight):
+    (``qp`` int32 [in // 8, out], ``d`` f32 [in // 32, out]).
 
-    ``q`` int8 [..., in, out] values in [-8, 7], ``d`` float [..., in//32,
-    out] scales -> (``qp`` int8 [..., in//2, out], ``d`` f16). The nibble
-    pairing matches the wire format's own intra-block layout (byte j: low
-    nibble element j, high nibble element j + 16), so the in-kernel unpack
-    is the same shift/mask as `q40_to_planar`. Scales go back to f16 — the
-    wire scale dtype, so the cast is exact and the device cost is
-    0.5 + 2/32 = 0.5625 B/weight including scales."""
-    *lead, inner, out = q.shape
-    if inner % Q40_BLOCK_SIZE:
-        raise ValueError(f"in dim {inner} not a multiple of {Q40_BLOCK_SIZE}")
-    half = Q40_BLOCK_SIZE // 2
-    blk = q.reshape(*lead, inner // Q40_BLOCK_SIZE, Q40_BLOCK_SIZE, out)
-    lo = (blk[..., :half, :].astype(np.int16) + 8)
-    hi = (blk[..., half:, :].astype(np.int16) + 8)
-    qp = np.ascontiguousarray((lo | (hi << 4)).astype(np.uint8)).view(np.int8)
-    return qp.reshape(*lead, inner // 2, out), d.astype(np.float16)
+    Eight weights a word, each the two's complement of ``nib - 8`` (the
+    wire's nibble with its top bit flipped); word row ``g * seg + t`` holds
+    in nibble ``j`` weight row ``g * 8 * seg + j * seg + t``, ``seg`` = 32
+    where in is a multiple of 256, else in // 8. No int8 plane between:
+    the numpy twin of native `q40_pack_transposed`."""
+    if in_dim % Q40_BLOCK_SIZE:
+        raise ValueError(f"in dim {in_dim} not a multiple of {Q40_BLOCK_SIZE}")
+    nb = in_dim // Q40_BLOCK_SIZE
+    blocks = np.frombuffer(
+        raw, dtype=np.uint8, count=out_dim * nb * Q40_BLOCK_BYTES
+    ).reshape(out_dim, nb, Q40_BLOCK_BYTES)
+    d = blocks[:, :, :2].copy().view(np.float16).reshape(out_dim, nb)
+    nib = blocks[:, :, 2:] ^ 0x88  # both nibbles to two's complement
+    vals = np.concatenate([nib & 0xF, nib >> 4], axis=-1)  # [out, nb, 32]
+    seg = Q40_BLOCK_SIZE if in_dim % (8 * Q40_BLOCK_SIZE) == 0 else in_dim // 8
+    vals = vals.reshape(out_dim, in_dim // (8 * seg), 8, seg).astype(np.uint32)
+    words = np.zeros((out_dim, in_dim // (8 * seg), seg), np.uint32)
+    for j in range(8):
+        words |= vals[:, :, j, :] << np.uint32(4 * j)
+    qp = np.ascontiguousarray(words.reshape(out_dim, in_dim // 8).T).view(np.int32)
+    return qp, np.ascontiguousarray(d.T).astype(np.float32)
 
 
 def q80_to_planar(raw: np.ndarray, n_elements: int) -> tuple[np.ndarray, np.ndarray]:

@@ -27,6 +27,8 @@ from ..formats.model_file import LlmArch, LlmHeader, ModelReader, layer_table
 from ..formats.quants import FloatType, pack_q40_device
 from ..ops.jnp_ops import rope_cache
 from ..ops.quant_matmul import (
+    NIBBLES,
+    PACKED_GROUP,
     FusedQuantWeight,
     PackedQuantWeight,
     QuantWeight,
@@ -43,6 +45,15 @@ PutFn = Callable[[str, np.ndarray], jnp.ndarray]
 
 def _default_put(name: str, arr: np.ndarray) -> jnp.ndarray:
     return jnp.asarray(arr)
+
+
+def _pack_q40(raw: np.ndarray, out_dim: int, in_dim: int):
+    """The wire's bytes of a [out, in] tensor -> the packed device planes
+    (words int32 [in // 8, out], scales f32 [in // 32, out]): native C++
+    when built (one multithreaded pass), numpy otherwise."""
+    return native.q40_pack_transposed(raw, out_dim, in_dim) or pack_q40_device(
+        raw, out_dim, in_dim
+    )
 
 
 def _interleave_concat(arrs: list[np.ndarray], tp: int) -> np.ndarray:
@@ -103,12 +114,12 @@ def _stream_quant_stack(
     (the _interleave_concat layout restated as index math, so a fused
     shard never touches the other shards' bytes).
 
-    `packed` re-packs each shard into the nibble device format
-    (weight_format="q40i4": int8 byte = two int4 values, f16 scales)
-    HOST-SIDE before device_put — the device never sees the 1 B/value
-    layout, and the fused qkv/w13 interleave metadata is unchanged
-    because packing acts on the in axis while the interleave permutes
-    the out axis.
+    `packed` lays each shard out in the nibble device format
+    (weight_format="q40i4": an int32 word = eight int4 values) straight
+    from the wire's bytes — neither host nor device ever sees the
+    1 B/value layout, and the fused qkv/w13 interleave metadata is
+    unchanged because packing acts on the in axis while the interleave
+    permutes the out axis.
 
     Returns (QuantWeight | PackedQuantWeight, out_dims) with out_dims the
     constituents' global out dims (FusedQuantWeight metadata)."""
@@ -148,10 +159,11 @@ def _stream_quant_stack(
             g += take
 
     def ranged_both(lead_idx, g0, g1, b0, b1):
-        """Device-layout (values [i, o] int8, scales [i//32, o] f32) for
-        one lead index; ONE unpack pass feeds both leaves (native C++
-        when built). Full-width rows slice the memmap zero-copy; block
-        sub-ranges copy exactly the shard's bytes first."""
+        """Device-layout (values [i, o] int8, or packed words [i//8, o]
+        int32; scales [i//32, o] f32) for one lead index; ONE pass feeds
+        both leaves (native C++ when built). Full-width rows slice the
+        memmap zero-copy; block sub-ranges copy exactly the shard's bytes
+        first."""
         qs, ds = [], []
         for j, c0, c1 in fused_parts(g0, g1):
             name = name_fns[j](*lead_idx)
@@ -162,13 +174,13 @@ def _stream_quant_stack(
             else:
                 full = reader.raw(name).reshape(-1, nb, Q40_BLOCK_BYTES)
                 raw = np.ascontiguousarray(full[c0:c1, b0:b1]).reshape(-1)
-            unpacked = native.q40_unpack_transposed(raw, c1 - c0, sub_inner)
+            if packed:
+                unpacked = _pack_q40(raw, c1 - c0, sub_inner)
+            else:
+                unpacked = native.q40_unpack_transposed(raw, c1 - c0, sub_inner)
             if unpacked is None:
                 q, d = reader.planar_q40_range(name, c0, c1, b0, b1)
-                unpacked = (
-                    np.ascontiguousarray(q.T),
-                    np.ascontiguousarray(d.T).astype(np.float32),
-                )
+                unpacked = planar_to_device_layout(q, d)
             qs.append(unpacked[0])
             ds.append(unpacked[1])
         if len(qs) == 1:
@@ -205,25 +217,21 @@ def _stream_quant_stack(
             len(range(*sl.indices(n))) for sl, n in zip(lead_sls, lead_shape)
         ]
         # preallocate at the final shard shape and write each lead index's
-        # unpack (and optional nibble re-pack) in place: a pairs list +
-        # np.stack would hold TWO copies of the shard at once — several GB
-        # of transient for a 70B w13 tp shard
+        # planes in place: a pairs list + np.stack would hold TWO copies of
+        # the shard at once — several GB of transient for a 70B w13 tp shard
         sub_inner = (b1 - b0) * 32
-        q_np = np.empty(
-            (len(leads), sub_inner // 2 if packed else sub_inner, o1 - o0),
-            np.int8,
+        if packed and sub_inner != inner and sub_inner % PACKED_GROUP:
+            raise ValueError(
+                f"{tag}: a packed shard's in slice [{i0},{i1}) is not whole "
+                f"groups of {PACKED_GROUP} rows"
+            )
+        q_rows, q_dtype = (
+            (sub_inner // NIBBLES, np.int32) if packed else (sub_inner, np.int8)
         )
-        d_np = np.empty(
-            (len(leads), b1 - b0, o1 - o0),
-            np.float16 if packed else np.float32,
-        )
+        q_np = np.empty((len(leads), q_rows, o1 - o0), q_dtype)
+        d_np = np.empty((len(leads), b1 - b0, o1 - o0), np.float32)
         for i, li in enumerate(leads):
-            q_i, d_i = ranged_both(li, o0, o1, b0, b1)
-            if packed:
-                q_i, d_i = pack_q40_device(q_i, d_i)
-            q_np[i] = q_i
-            d_np[i] = d_i
-            del q_i, d_i
+            q_np[i], d_np[i] = ranged_both(li, o0, o1, b0, b1)
         q_np = q_np.reshape(*lead_lens, *q_np.shape[1:])
         d_np = d_np.reshape(*lead_lens, *d_np.shape[1:])
         for dev in devs:
@@ -233,7 +241,9 @@ def _stream_quant_stack(
             [q_parts[d] for d in devs] + [d_parts[d] for d in devs]
         )
         del q_np, d_np
-    out_q_shape = (*lead_shape, inner // 2, total_out) if packed else q_shape
+    out_q_shape = (
+        (*lead_shape, inner // NIBBLES, total_out) if packed else q_shape
+    )
     q_arr = jax.make_array_from_single_device_arrays(
         out_q_shape, sh, [q_parts[d] for d in q_map]
     )
@@ -265,9 +275,9 @@ def load_params(
     file's device footprint stays ~1.125 B/weight instead of blowing up to
     bf16 density.
 
-    `weight_format="q40i4"` additionally re-packs the matmul weights into
-    the nibble device format (`PackedQuantWeight`: two int4 values per
-    byte + f16 scales, 0.5625 B/weight) host-side during the load; the
+    `weight_format="q40i4"` instead lays the matmul weights out in the
+    nibble device format (`PackedQuantWeight`: eight int4 values per int32
+    word + f32 scales, 0.625 B/weight) straight from the wire's bytes; the
     Pallas kernel unpacks in VMEM after the HBM copy. MoE expert weights
     stay int8 `QuantWeight` (the ragged MoE kernels consume that layout).
 
@@ -333,11 +343,14 @@ def load_params(
     def stack(fn: Callable[[int], np.ndarray], layers=every) -> np.ndarray:
         return np.stack([fn(l) for l in layers])
 
-    def unpack_q40(name: str) -> tuple[np.ndarray, np.ndarray]:
+    def unpack_q40(name: str, pack: bool = False) -> tuple[np.ndarray, np.ndarray]:
         """Q40 tensor -> (q int8 [in, out], d f32 [in//32, out]) device
-        layout; native C++ unpack when built (one multithreaded pass),
-        numpy fallback otherwise."""
+        layout, or with `pack` its words int32 [in//8, out] in q's
+        place; native C++ when built (one multithreaded pass), numpy
+        fallback otherwise."""
         out_dim, in_dim = reader.by_name[name].shape
+        if pack:
+            return _pack_q40(reader.raw(name), out_dim, in_dim)
         unpacked = native.q40_unpack_transposed(reader.raw(name), out_dim, in_dim)
         if unpacked is None:
             unpacked = planar_to_device_layout(*reader.planar_q40(name))
@@ -354,9 +367,7 @@ def load_params(
             return w_
         qs, ds = [], []
         for l in layers:
-            q_arr, d_arr = unpack_q40(fn(l))
-            if packed:
-                q_arr, d_arr = pack_q40_device(q_arr, d_arr)
+            q_arr, d_arr = unpack_q40(fn(l), packed)
             qs.append(q_arr)
             ds.append(d_arr)
         cls = PackedQuantWeight if packed else QuantWeight
@@ -385,17 +396,13 @@ def load_params(
         qs, ds = [], []
         dims: tuple[int, ...] = ()
         for l in layers:
-            parts = [unpack_q40(fn(l)) for fn in names]
+            parts = [unpack_q40(fn(l), packed) for fn in names]
             dims = tuple(p[0].shape[-1] for p in parts)
-            # interleave permutes the out axis, packing halves the in
+            # interleave permutes the out axis, packing folds the in
             # axis — they commute, so the fuse/dims metadata is the same
             # for both device formats
-            q_l = _interleave_concat([p[0] for p in parts], fuse)
-            d_l = _interleave_concat([p[1] for p in parts], fuse)
-            if packed:
-                q_l, d_l = pack_q40_device(q_l, d_l)
-            qs.append(q_l)
-            ds.append(d_l)
+            qs.append(_interleave_concat([p[0] for p in parts], fuse))
+            ds.append(_interleave_concat([p[1] for p in parts], fuse))
         cls = PackedQuantWeight if packed else QuantWeight
         return FusedQuantWeight(
             cls(put(tag, np.stack(qs)), put(tag, np.stack(ds))),
@@ -558,9 +565,7 @@ def load_params(
             reader, put, "wcls", [lambda: "wcls"], (), packed=packed
         )
     elif quantize:
-        q_arr, d_arr = unpack_q40("wcls")
-        if packed:
-            q_arr, d_arr = pack_q40_device(q_arr, d_arr)
+        q_arr, d_arr = unpack_q40("wcls", packed)
         cls = PackedQuantWeight if packed else QuantWeight
         wcls = cls(put("wcls", q_arr), put("wcls", d_arr))
     else:

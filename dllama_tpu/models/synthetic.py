@@ -258,32 +258,32 @@ def random_params(
     def mk_quant(name, *shape, packed=False):
         """Random QuantWeight [..., in, out] on device: int8 values in
         [-8, 7] + f32 per-block scales (the loader's q40 layout). With
-        `packed` the q40i4 device layout instead: nibble-packed int8
-        [..., in//2, out] + f16 scales — any byte is a valid nibble pair,
-        so the packed tensor is generated directly at its final shape."""
+        `packed` the q40i4 device layout instead: int32 words of eight
+        nibbles [..., in//8, out] — any word is eight valid nibbles, so
+        the packed tensor is generated directly at its final shape."""
         import zlib
 
-        from ..ops.quant_matmul import PackedQuantWeight, QuantWeight
+        from ..ops.quant_matmul import NIBBLES, PackedQuantWeight, QuantWeight
 
         sh = sharding_for(name)
         *lead, inner, out = shape
         key = jax.random.fold_in(root_key, zlib.crc32(name.encode()))
         kq, kd = jax.random.split(key)
-        q_shape = (*lead, inner // 2, out) if packed else shape
+        q_shape = (*lead, inner // NIBBLES, out) if packed else shape
         q = jax.jit(
             lambda k: (
-                jax.random.randint(k, q_shape, -128, 128, dtype=jnp.int8)
+                jax.lax.bitcast_convert_type(
+                    jax.random.bits(k, q_shape, jnp.uint32), jnp.int32)
                 if packed
                 else jax.random.randint(k, q_shape, -8, 8, dtype=jnp.int8)
             ),
             out_shardings=sh,
         )(kq)
         d_shape = (*lead, inner // 32, out)
-        d_dtype = jnp.float16 if packed else jnp.float32
         d = jax.jit(
             lambda k: jax.random.uniform(
                 k, d_shape, jnp.float32, minval=0.5 * scale / 8, maxval=scale / 8
-            ).astype(d_dtype),
+            ),
             out_shardings=sh,
         )(kd)
         cls = PackedQuantWeight if packed else QuantWeight

@@ -108,24 +108,67 @@ def analytic_step_seconds(
     return float(bytes_accessed) / float(peak_bytes_per_s)
 
 
+# bytes a weight, scales included, by the form a leaf is held in
+# (ops/quant_matmul: eight nibbles an int32 word, or int8 values; an f32
+# scale per 32 block either way)
+PACKED_BYTES_PER_WEIGHT = 0.5 + 4.0 / 32.0
+INT8_BYTES_PER_WEIGHT = 1.0 + 4.0 / 32.0
+
+
 def weight_bytes_per_token(h: "LlmHeader", weight_format: str) -> int:
     """HBM bytes of weights a single decode step must read: every matmul
-    weight once (MoE: attention weights + the active experts' share).
-    Q40 device layout = int8 values + f32 scale per 32 block = 1.125
-    B/weight; packed nibbles + f16 scales = 0.5625; dense bf16 = 2
-    B/weight."""
-    bpw = {
-        "q40": 1.125,
-        "q40i4": 0.5 + 2.0 / 32.0,
-    }.get(weight_format, 2.0)
+    weight once (MoE: attention weights + the active experts' share), each
+    charged by the form the loader holds it in: under `q40i4` the dense
+    matmuls are packed nibbles (0.625 B/weight) while the routed experts
+    stay int8 as under `q40` (1.125); dense bf16 = 2 B/weight."""
+    quantized = weight_format in ("q40", "q40i4")
+    expert_bpw = INT8_BYTES_PER_WEIGHT if quantized else 2.0
+    dense_bpw = PACKED_BYTES_PER_WEIGHT if weight_format == "q40i4" else expert_bpw
     att = h.dim * h.q_dim + 2 * h.dim * h.kv_dim + h.q_dim * h.dim
     ffn = 3 * h.dim * h.ff_dim
+    ffn_bpw = dense_bpw
     if h.n_experts:
         ffn *= h.n_active_experts  # ragged kernel reads active experts only
-    total = (h.n_layers * (att + ffn) + h.dim * h.vocab_size) * bpw
+        ffn_bpw = expert_bpw
+    total = (
+        h.n_layers * (att * dense_bpw + ffn * ffn_bpw)
+        + h.dim * h.vocab_size * dense_bpw
+    )
     if h.n_experts:
         total += h.n_layers * h.dim * h.n_experts * 4  # f32 gate
     return int(total)
+
+
+def weight_bytes_by_form(params, h: "LlmHeader") -> dict[str, float]:
+    """What the engine holds, from the leaves it loaded: resident bytes by
+    form (`packed`: nibble words + their scales; `int8`: int8 values + their
+    scales; `float`: every other leaf: embedding, norms, routers, dense
+    matmuls; the three add up to the leaves' bytes), and
+    `decode_packed_share`: of the quantized bytes a one-token decode step
+    reads (every dense stack whole, a routed expert stack [L, E, ...] at
+    the active share of the experts held), the part that is packed. 1.0
+    says every byte of a step is half what int8 holds; a sparse model
+    whose experts are most of a step reads well under a half."""
+    import jax
+
+    from ..ops.quant_matmul import FusedQuantWeight, PackedQuantWeight, QuantWeight
+
+    out = {"packed": 0, "int8": 0, "float": 0}
+    step = {"packed": 0.0, "int8": 0.0}
+    is_leaf = lambda x: isinstance(x, (QuantWeight, PackedQuantWeight, FusedQuantWeight))
+    for leaf in jax.tree.leaves(params, is_leaf=is_leaf):
+        if isinstance(leaf, FusedQuantWeight):
+            leaf = leaf.weight
+        if not isinstance(leaf, (QuantWeight, PackedQuantWeight)):
+            out["float"] += leaf.nbytes
+            continue
+        form = "packed" if isinstance(leaf, PackedQuantWeight) else "int8"
+        n = sum(a.nbytes for a in leaf)
+        out[form] += n
+        routed = leaf[0].ndim == 4 and h.n_experts  # [L, E, in, out]
+        step[form] += n * h.n_active_experts / h.n_experts if routed else n
+    read = step["packed"] + step["int8"]
+    return {**out, "decode_packed_share": step["packed"] / read if read else 0.0}
 
 
 def program_cost_ceilings(

@@ -59,24 +59,40 @@ class QuantWeight(NamedTuple):
         return self.q.shape[-1]
 
 
+# nibbles of a packed word; the weight rows of a packed tensor's group: a
+# quant block a nibble position, which the kernel asks of every in axis and
+# a tp shard's in slice has to be whole multiples of
+NIBBLES = 8
+PACKED_GROUP = NIBBLES * Q_BLOCK
+
+
+def packed_segment(in_dim: int) -> int:
+    """Weight rows a nibble position covers inside one group of a packed
+    tensor: a quant block (32) where the in axis is whole groups of 8 x 32
+    = 256 rows, which every served width is and the kernel asks for; else
+    the axis is one group of eight equal segments (tiny test models)."""
+    return Q_BLOCK if in_dim % PACKED_GROUP == 0 else in_dim // NIBBLES
+
+
 class PackedQuantWeight(NamedTuple):
-    """Packed-nibble Q40 tensor in device layout (weight_format="q40i4").
+    """Packed-nibble Q40 tensor in device layout (weight_format="q40i4",
+    and what "auto" serves on a TPU): 0.5 B a weight in HBM, unpacked by
+    the kernel AFTER the HBM->VMEM copy.
 
-    Two int4 values per int8 byte in HBM, following the wire format's own
-    intra-block pairing (formats/quants.py): within each 32-element quant
-    block, byte row j holds element j in its low nibble and element j + 16
-    in its high nibble. The kernel unpacks AFTER the HBM->VMEM copy
-    (shift/mask, then the same sublane-broadcast scale multiply as the
-    int8 path), so HBM traffic drops to what is actually stored:
+    ``qp`` int32 [..., in // 8, out]: eight weights a word, each a
+    two's-complement nibble (the wire's ``nib - 8``, so a shift pair
+    sign-extends it: no mask, no subtract). Word row ``g * seg + t`` holds
+    in nibble ``j`` (bits 4j..4j+3) weight row ``g * 8 * seg + j * seg + t``
+    with ``seg = packed_segment(in)`` = 32: a nibble position of 32 word
+    rows is one whole quant block, already in the (8, 128) tiles of an
+    int32 array, so the unpacked pieces need no sublane shuffle and meet
+    their block's scale row as they are.
+    ``d``  f32 [..., in // 32, out] per-block scales, as `QuantWeight`'s
+    (the wire's f16 values, exactly).
 
-    ``qp`` int8 [..., in // 2, out] packed nibble pairs;
-    ``d``  f16 [..., in // 32, out] per-block scales — f16 IS the wire
-    scale dtype, so packed dequant is bit-identical to the int8 path's
-    (which widens the same f16 values to f32).
-
-    0.5 + 2/32 = 0.5625 B/weight including scales, vs 1.125 for the
-    unpacked QuantWeight layout — decode matmuls are HBM-bandwidth-bound,
-    so this halves the weight-read floor per token.
+    0.5 + 4/32 = 0.625 B/weight including scales, vs 1.125 for the
+    unpacked QuantWeight layout. The dequantised bf16 tile is the int8
+    kernel's bit for bit: ``(nib - 8) * d`` in f32, narrowed.
     """
 
     qp: jnp.ndarray
@@ -84,7 +100,7 @@ class PackedQuantWeight(NamedTuple):
 
     @property
     def in_dim(self) -> int:
-        return self.qp.shape[-2] * 2
+        return self.qp.shape[-2] * NIBBLES
 
     @property
     def out_dim(self) -> int:
@@ -141,47 +157,45 @@ def dequant(w: QuantWeight, dtype=jnp.bfloat16) -> jnp.ndarray:
 
 
 def pack_nibbles(w: QuantWeight) -> PackedQuantWeight:
-    """Device-layout int8 QuantWeight -> packed-nibble PackedQuantWeight
-    (jnp; formats.quants.pack_q40_device is the numpy twin for the load
+    """Device-layout int8 QuantWeight -> PackedQuantWeight (jnp;
+    formats.quants.pack_q40_device packs the wire's bytes for the load
     path). Values must already be in [-8, 7]."""
     *lead, inner, out = w.q.shape
-    blk = w.q.astype(jnp.int32).reshape(
-        *lead, inner // Q_BLOCK, Q_BLOCK, out
-    )
-    lo = blk[..., : Q_BLOCK // 2, :] + 8
-    hi = blk[..., Q_BLOCK // 2 :, :] + 8
-    b = lo | (hi << 4)  # [0, 255]
-    qp = jnp.where(b >= 128, b - 256, b).astype(jnp.int8)
+    seg = packed_segment(inner)
+    nib = jax.lax.bitcast_convert_type(w.q.astype(jnp.int32) & 0xF, jnp.uint32)
+    nib = nib.reshape(*lead, inner // (NIBBLES * seg), NIBBLES, seg, out)
+    shifts = (4 * jnp.arange(NIBBLES, dtype=jnp.uint32)).reshape(NIBBLES, 1, 1)
+    words = jnp.sum(nib << shifts, axis=-3, dtype=jnp.uint32)  # disjoint bits
     return PackedQuantWeight(
-        qp.reshape(*lead, inner // 2, out), w.d.astype(jnp.float16)
+        jax.lax.bitcast_convert_type(words, jnp.int32).reshape(
+            *lead, inner // NIBBLES, out
+        ),
+        w.d.astype(jnp.float32),
     )
+
+
+def _nibble(words: jnp.ndarray, j: int) -> jnp.ndarray:
+    """Nibble j of every word as int32 in [-8, 7]: to the top of the word,
+    then an arithmetic shift down sign-extends it."""
+    return (words << (28 - 4 * j) if j < NIBBLES - 1 else words) >> 28
 
 
 def unpack_nibbles(qp: jnp.ndarray) -> jnp.ndarray:
-    """Packed nibble bytes [..., in // 2, out] -> int values
-    [..., in, out] int32 in [-8, 7], restoring the wire's intra-block
-    (j, j + 16) pairing. Shapes stay 2D-tiled the whole way (reshape /
-    concat touch the second-to-last axis only), so the same code runs
-    inside the Pallas kernel's VMEM tiles."""
-    *lead, half, out = qp.shape
-    u = qp.astype(jnp.int32) & 0xFF
-    blk = u.reshape(*lead, half // (Q_BLOCK // 2), Q_BLOCK // 2, out)
-    lo = (blk & 0xF) - 8
-    hi = (blk >> 4) - 8
-    q = jnp.concatenate([lo, hi], axis=-2)  # [..., nb, 32, out]
-    return q.reshape(*lead, half * 2, out)
+    """Packed words [..., in // 8, out] -> int values [..., in, out] int32
+    in [-8, 7], a group's eight nibble positions side by side (the jnp twin
+    of what `_qmm_i4_kernel` does to a VMEM tile)."""
+    *lead, rows, out = qp.shape
+    seg = packed_segment(rows * NIBBLES)
+    words = qp.reshape(*lead, rows // seg, seg, out)
+    pieces = [_nibble(words, j) for j in range(NIBBLES)]
+    return jnp.concatenate(pieces, axis=-2).reshape(*lead, rows * NIBBLES, out)
 
 
 def dequant_packed(w: PackedQuantWeight, dtype=jnp.bfloat16) -> jnp.ndarray:
     """[..., in, out] dense tensor from the packed-nibble layout; computes
     exactly what `dequant` computes on the unpacked equivalent (same int
-    values, same f16-exact scales)."""
-    *lead, half, out = w.qp.shape
-    inner = half * 2
-    q = unpack_nibbles(w.qp).astype(jnp.float32)
-    q = q.reshape(*lead, inner // Q_BLOCK, Q_BLOCK, out)
-    dense = q * w.d.astype(jnp.float32)[..., :, None, :]
-    return dense.reshape(*lead, inner, out).astype(dtype)
+    values, same scales)."""
+    return dequant(QuantWeight(unpack_nibbles(w.qp), w.d), dtype)
 
 
 def layer_of(w, layer):
@@ -206,11 +220,34 @@ def qmatmul_ref(x: jnp.ndarray, w, layer=None) -> jnp.ndarray:
     return jnp.einsum("...i,io->...o", x.astype(jnp.float32), dense)
 
 
+def _mxu_accumulate(x_ref, w, o_ref, acc_ref, n_k: int):
+    """The tail of both Q40 kernels: the rows against the dequantised bf16
+    tile `w` on the MXU, accumulated over the k steps in VMEM scratch."""
+    pk = pl.program_id(2)
+    partial_out = jax.lax.dot_general(
+        x_ref[:],
+        w,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+    @pl.when(pk == 0)
+    def _init():
+        acc_ref[:] = partial_out
+
+    @pl.when(pk > 0)
+    def _accum():
+        acc_ref[:] += partial_out
+
+    @pl.when(pk == n_k - 1)
+    def _emit():
+        o_ref[:] = acc_ref[:]
+
+
 def _qmm_kernel(l_ref, x_ref, q_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     """One (m, block_n) output tile, accumulated over k blocks in VMEM
     scratch: sublane-broadcast dequant then MXU. `l_ref` (the layer
     number) is read by the block specs alone."""
-    pk = pl.program_id(2)
     q = q_ref[:]  # [bk, bn] int8
     d = d_ref[:]  # [bk // 32, bn] f32
     bk, bn = q.shape
@@ -222,86 +259,29 @@ def _qmm_kernel(l_ref, x_ref, q_ref, d_ref, o_ref, acc_ref, *, n_k: int):
         .reshape(bk, bn)
         .astype(jnp.bfloat16)
     )
-    partial_out = jax.lax.dot_general(
-        x_ref[:],
-        w,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(pk == 0)
-    def _init():
-        acc_ref[:] = partial_out
-
-    @pl.when(pk > 0)
-    def _accum():
-        acc_ref[:] += partial_out
-
-    @pl.when(pk == n_k - 1)
-    def _emit():
-        o_ref[:] = acc_ref[:]
-
-
-def _f16_bits_to_f32(bits: jnp.ndarray) -> jnp.ndarray:
-    """Exact f16 -> f32 from the raw 16 bits, in integer ops. The chip's
-    vector unit has no f16 type (Mosaic refuses an f16 VMEM load:
-    "Invalid vector type for load ... xf16"), so the packed kernel takes
-    the wire's f16 scale plane bit-cast to int16 and widens it itself:
-    normals re-bias the exponent into an f32 bit pattern, subnormals are
-    mant * 2^-24. Scales are finite, so inf/nan are not decoded."""
-    b = bits.astype(jnp.int32)
-    exp = (b >> 10) & 0x1F
-    mant = b & 0x3FF
-    normal = jax.lax.bitcast_convert_type(
-        ((exp + 112) << 23) | (mant << 13), jnp.float32
-    )
-    mag = jnp.where(exp == 0, mant.astype(jnp.float32) * 2.0**-24, normal)
-    return jnp.where((b & 0x8000) != 0, -mag, mag)
+    _mxu_accumulate(x_ref, w, o_ref, acc_ref, n_k)
 
 
 def _qmm_i4_kernel(l_ref, x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
-    """One (m, block_n) output tile from packed-nibble weights: the
-    HBM->VMEM copy moves 0.5625 B/weight, then shift/mask unpack +
-    sublane-broadcast dequant in VMEM feed the MXU in bf16 exactly like
-    the int8 kernel. The unpack is a handful of VPU element-ops per tile;
-    the Q40 kernel was dequant-compute-bound at 46% of HBM peak on the
-    round-3 chip run, so halving bytes moves the balance point; which
-    side wins on silicon has not been measured."""
-    pk = pl.program_id(2)
-    qp = qp_ref[:]  # [bk // 2, bn] int8, two nibbles per byte
-    d = _f16_bits_to_f32(d_ref[:])  # [bk // 32, bn]
-    half, bn = qp.shape
-    bk = half * 2
-    u = qp.astype(jnp.int32) & 0xFF
-    blk = u.reshape(bk // Q_BLOCK, Q_BLOCK // 2, bn)
-    lo = (blk & 0xF) - 8
-    hi = (blk >> 4) - 8
-    w = (
-        (
-            jnp.concatenate([lo, hi], axis=1).astype(jnp.float32)
-            * d[:, None, :]
+    """One (m, block_n) output tile from packed words: the HBM->VMEM copy
+    moves 0.625 B a weight, then each nibble position of a group's 32 word
+    rows is shifted out as one quant block of 32 weight rows, multiplied
+    by its scale row broadcast along sublanes and narrowed, all in whole
+    (8, 128) tiles; the eight blocks side by side are the group's 256
+    rows of the bf16 tile `_qmm_kernel` builds, bit for bit."""
+    qp = qp_ref[:]  # [bk // 8, bn] int32, eight nibbles a word
+    rows, bn = qp.shape
+    groups = rows // Q_BLOCK
+    words = qp.reshape(groups, Q_BLOCK, bn)
+    d = d_ref[:].reshape(groups, NIBBLES, bn)  # a group's eight scale rows
+    pieces = [
+        (_nibble(words, j).astype(jnp.float32) * d[:, j : j + 1, :]).astype(
+            jnp.bfloat16
         )
-        .reshape(bk, bn)
-        .astype(jnp.bfloat16)
-    )
-    partial_out = jax.lax.dot_general(
-        x_ref[:],
-        w,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(pk == 0)
-    def _init():
-        acc_ref[:] = partial_out
-
-    @pl.when(pk > 0)
-    def _accum():
-        acc_ref[:] += partial_out
-
-    @pl.when(pk == n_k - 1)
-    def _emit():
-        o_ref[:] = acc_ref[:]
+        for j in range(NIBBLES)
+    ]
+    w = jnp.concatenate(pieces, axis=1).reshape(rows * NIBBLES, bn)
+    _mxu_accumulate(x_ref, w, o_ref, acc_ref, n_k)
 
 
 def _pick_block(n: int, preferred: int, ragged: bool = False, step: int = 128) -> int:
@@ -345,6 +325,8 @@ BLOCK_M = 512
 # by a third of a megabyte; a single step that deep (k = 4096) keeps no
 # partial product beside the accumulator and fits.
 ACC_X_TILE_BYTES = BLOCK_M * 3584 * 2
+# the most rows at which the packed kernel takes 512-wide tiles
+DECODE_ROWS = 64
 
 
 def _pick_k_block(k: int, preferred: int, rows: int) -> int:
@@ -440,24 +422,31 @@ def qmatmul_2d(
 )
 def qmatmul_i4_2d(
     x: jnp.ndarray,  # [m, k]
-    qp: jnp.ndarray,  # [L, k // 2, n] int8 packed nibbles, or [k // 2, n]
-    d: jnp.ndarray,  # [L, k // 32, n] f16, or [k // 32, n]
+    qp: jnp.ndarray,  # [L, k // 8, n] int32 packed words, or [k // 8, n]
+    d: jnp.ndarray,  # [L, k // 32, n] f32, or [k // 32, n]
     layer=None,
-    block_n: int = 256,
+    block_n: int | None = None,
     block_k: int = 4096,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Pallas packed-nibble quantized matmul; returns [m, n] f32.
 
-    Same call as `qmatmul_2d` (`_qmm_call`); the weight BlockSpec moves
-    half the rows because each byte carries two values. Block defaults
-    inherit the int8 sweep winner — at equal (bn, bk) the packed DMA is
-    half the bytes, so the VMEM ceiling moves further out, and the
-    staged silicon sweep re-tunes on hardware."""
-    assert d.dtype == jnp.float16, d.dtype
+    Same call as `qmatmul_2d` (`_qmm_call`); the weight BlockSpec moves an
+    eighth of the rows because each word carries eight values. k has to be
+    whole groups of 256 rows (`packed_segment`).
+
+    `block_n` by the rows, where the caller names none: at decode rows a
+    call is bound by how fast the MXUs take weight tiles (4 x 128 weights a
+    cycle: 1.37 us a [4096, 256] tile on a v5e, whatever the bytes), and
+    512-wide tiles reach that (w13 at 5 rows: 155 us against 173 at 256
+    wide and the int8 kernel's 205; PR 43's chip runs); under a chunk's
+    512-row blocks a tile that wide passes the 16 MB of scoped VMEM."""
+    assert qp.dtype == jnp.int32 and d.dtype == jnp.float32, (qp.dtype, d.dtype)
+    assert x.shape[-1] % PACKED_GROUP == 0, x.shape
+    if block_n is None:
+        block_n = 512 if x.shape[0] <= DECODE_ROWS else 256
     return _qmm_call(
-        _qmm_i4_kernel, x, qp, jax.lax.bitcast_convert_type(d, jnp.int16),
-        layer, 2, block_n, block_k, interpret,
+        _qmm_i4_kernel, x, qp, d, layer, NIBBLES, block_n, block_k, interpret
     )
 
 
@@ -465,7 +454,7 @@ def _use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def qmatmul(x: jnp.ndarray, w, layer=None, block_n: int = 256) -> jnp.ndarray:
+def qmatmul(x: jnp.ndarray, w, layer=None) -> jnp.ndarray:
     """x [..., in] @ W -> [..., out] f32, auto-flattening leading dims.
 
     Accepts QuantWeight (int8 values) or PackedQuantWeight (nibble-packed),
@@ -475,13 +464,15 @@ def qmatmul(x: jnp.ndarray, w, layer=None, block_n: int = 256) -> jnp.ndarray:
     orders of magnitude slower and numerically identical anyway.
     """
     *lead, k = x.shape
-    if not _use_pallas():
+    packed = isinstance(w, PackedQuantWeight)
+    # a packed axis that is not whole groups of 256 is no served width
+    if not _use_pallas() or (packed and k % PACKED_GROUP):
         return qmatmul_ref(x, w, layer)
     m = 1
     for s in lead:
         m *= s
-    kernel = qmatmul_i4_2d if isinstance(w, PackedQuantWeight) else qmatmul_2d
-    out = kernel(x.reshape(m, k), *w, layer, block_n=block_n)
+    kernel = qmatmul_i4_2d if packed else qmatmul_2d
+    out = kernel(x.reshape(m, k), *w, layer)
     return out.reshape(*lead, w.out_dim)
 
 
@@ -519,8 +510,9 @@ def qmatmul_tp(
     from jax import shard_map
 
     # both weight classes are (values, scales) NamedTuples whose leaves
-    # shard identically: the packed in/2 axis and the in/32 scale axis
-    # both divide by tp under the engine's 32*tp divisibility check
+    # shard identically: the packed in/8 axis and the in/32 scale axis
+    # both divide by tp under the engine's divisibility check (32*tp; a
+    # packed in axis whole groups of 256 a shard)
     cls = type(w)
     stack = (None,) * (w[0].ndim - 2)
     at = () if layer is None else (jnp.asarray(layer, jnp.int32),)

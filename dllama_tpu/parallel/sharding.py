@@ -36,9 +36,10 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
     leaves all keep the [in-ish, out] axis order — row split puts
     "tp" on the last (out) axis of both leaves, col split on the
     second-to-last. For the packed q40i4 layout the value leaf's in axis
-    is in//2 and the scale leaf's is in//32; both divide by tp under the
-    engine's 32*tp divisibility check, so the col shard boundaries stay
-    nibble- and block-aligned."""
+    is in//8 (words of eight nibbles, in groups of 256 weight rows) and
+    the scale leaf's is in//32; the engine serves it at tp > 1 only where
+    every in dim divides by 256*tp, so the col shard boundaries stay
+    group- and block-aligned."""
     moe = h.arch in (
         LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32,
         LlmArch.LFM2_MOE)

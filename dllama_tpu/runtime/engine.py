@@ -27,6 +27,7 @@ from ..formats.model_file import LlmHeader, ModelReader
 from ..formats.quants import FloatType
 from ..models import forward, init_kv_cache, load_params
 from ..models.transformer import lanes_on_one_device
+from ..ops.quant_matmul import PACKED_GROUP
 from ..parallel import cache_specs, make_mesh, shard_params_put, validate_tp
 from ..tokenizer import Tokenizer
 from .faults import get_fault_plane
@@ -390,21 +391,26 @@ class InferenceEngine:
         ) or ((1,) if sp == 1 else (sp,))
 
         # "auto": keep Q40 weights quantized on device when the Pallas path
-        # is available (TPU); dense bf16/f32 elsewhere (the CPU fallback
-        # dequantizes per call, fine for tests, slow for serving).
+        # is available (TPU), packed two nibbles a byte wherever the kernel
+        # takes every dense matmul's in axis (`_packs`; else int8 values);
+        # dense bf16/f32 elsewhere (the CPU fallback dequantizes per call,
+        # fine for tests, slow for serving).
         if weight_format == "auto":
-            weight_format = (
-                "q40"
-                if (
-                    self.header.weight_type == FloatType.Q40
-                    and jax.default_backend() == "tpu"
-                )
-                else "dense"
-            )
+            weight_format = "dense"
+            if (
+                self.header.weight_type == FloatType.Q40
+                and jax.default_backend() == "tpu"
+            ):
+                weight_format = "q40i4" if self._packs(tp) else "q40"
         if weight_format not in ("dense", "q40", "q40i4"):
             raise ValueError(
                 f"weight_format must be 'auto', 'dense', 'q40' or 'q40i4', "
                 f"got {weight_format!r}"
+            )
+        if weight_format == "q40i4" and tp > 1 and not self._packs(tp):
+            raise ValueError(
+                f"q40i4 weight format with tp={tp} needs every dense matmul's "
+                f"in dim divisible by {PACKED_GROUP * tp}"
             )
         self.weight_format = weight_format
         quantized = weight_format in ("q40", "q40i4")
@@ -444,6 +450,29 @@ class InferenceEngine:
             # the round-3 chip run)
             fuse=tp if quantized else 0,
         )
+        # what was loaded, by the form each leaf is held in, and how much of
+        # a decode step's quantized bytes is packed (1.0 dense; a sparse
+        # model's routed experts stay int8): obs/cost.weight_bytes_by_form
+        from ..obs.cost import weight_bytes_by_form
+
+        self.weight_bytes = weight_bytes_by_form(self.params, self.header)
+        g_weights = self.obs.gauge(
+            "dllama_weight_bytes",
+            "Resident bytes of the loaded parameters over the whole mesh, by "
+            "the form a leaf is held in: packed = nibble words and their "
+            "block scales, int8 = int8 values and their block scales, float "
+            "= every other leaf.",
+            labelnames=("form",),
+        )
+        for form in ("packed", "int8", "float"):
+            g_weights.labels(form=form).set(self.weight_bytes[form])
+        self.obs.gauge(
+            "dllama_decode_step_packed_share",
+            "Share of the quantized weight bytes a one-token decode step "
+            "reads (dense stacks whole, routed expert stacks at the active "
+            "share of the experts held) that is packed nibbles.",
+        ).set(self.weight_bytes["decode_packed_share"])
+        self.recorder.record("weights", format=weight_format, **self.weight_bytes)
         # Per-lane serving: lanes park their cache writes in padding rows
         # beyond seqLen while other lanes prefill/idle, so independent
         # requests can occupy the batch lanes at different positions.
@@ -743,6 +772,17 @@ class InferenceEngine:
         return 1
 
     # -- cache ---------------------------------------------------------------
+
+    def _packs(self, tp: int) -> bool:
+        """Whether the packed kernel takes every dense matmul of the file:
+        each one's in axis whole groups of 256 rows a tp shard (a routed
+        expert stays int8 and `wkv_b` is dequantised, whatever theirs)."""
+        return all(
+            spec.shape[1] % (PACKED_GROUP * tp) == 0
+            for spec in self.reader.specs
+            if spec.float_type == FloatType.Q40 and len(spec.shape) == 2
+            and ".experts." not in spec.name and not spec.name.endswith(".wkv_b")
+        )
 
     def _fresh_cache(self):
         # epoch lets callers detect that cached KV state was dropped

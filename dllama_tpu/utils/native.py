@@ -25,7 +25,7 @@ def _threads() -> int:
     return max(1, min(os.cpu_count() or 1, 16))
 
 
-_ABI_VERSION = 3
+_ABI_VERSION = 4
 
 
 def _needs_build() -> bool:
@@ -51,6 +51,7 @@ def _open_library():
     i64p = ctypes.POINTER(ctypes.c_int64)
     i64 = ctypes.c_int64
     lib.q40_unpack_transposed.argtypes = [u8, i64, i64, i8, f32, ctypes.c_int]
+    lib.q40_pack_transposed.argtypes = [u8, i64, i64, i32, f32, ctypes.c_int]
     lib.q40_dequant_transposed.argtypes = [u8, i64, i64, f32, ctypes.c_int]
     lib.q40_dequant.argtypes = [u8, i64, i64, f32, ctypes.c_int]
     lib.f32_transpose.argtypes = [f32, i64, i64, f32, ctypes.c_int]
@@ -130,6 +131,30 @@ def q40_unpack_transposed(
         _threads(),
     )
     return q, d
+
+
+def q40_pack_transposed(
+    raw: np.ndarray, rows: int, cols: int
+) -> tuple[np.ndarray, np.ndarray] | None:
+    """Packed Q40 bytes -> (words int32 [cols // 8, rows], d f32
+    [cols // 32, rows]): quant_matmul's packed device layout, straight from
+    the wire's nibbles (formats.quants.pack_q40_device is the numpy twin).
+    None if no native lib."""
+    lib = load_library()
+    if lib is None:
+        return None
+    raw = np.ascontiguousarray(np.frombuffer(raw, dtype=np.uint8))
+    words = np.empty((cols // 8, rows), dtype=np.int32)
+    d = np.empty((cols // 32, rows), dtype=np.float32)
+    lib.q40_pack_transposed(
+        _u8ptr(raw),
+        rows,
+        cols,
+        words.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        d.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+        _threads(),
+    )
+    return words, d
 
 
 def q40_dequant_transposed(raw: np.ndarray, rows: int, cols: int) -> np.ndarray | None:

@@ -122,6 +122,58 @@ void q40_unpack_transposed(const uint8_t *raw, int64_t rows, int64_t cols,
     });
 }
 
+// Packed twin of q40_unpack_transposed: the wire's nibbles straight into the
+// packed device form (ops/quant_matmul.PackedQuantWeight), no int8 plane
+// between:
+//   w_out  int32 [cols/8, rows]   eight two's-complement nibbles a word
+//   d_out  float [cols/32, rows]
+// Word row g*seg + t holds in nibble j (bits 4j..4j+3) weight row
+// g*8*seg + j*seg + t, where seg = 32 (cols % 256 == 0: a nibble's rows
+// are one quant block, eight blocks a group) or cols/8 (one group).
+void q40_pack_transposed(const uint8_t *raw, int64_t rows, int64_t cols,
+                         int32_t *w_out, float *d_out, int n_threads) {
+    const int64_t blocks_per_row = cols / kBlock;
+    const int64_t seg = cols % 256 == 0 ? 32 : cols / 8;
+    const int64_t n_groups = cols / (8 * seg);
+    constexpr int64_t TILE = 64;
+    const int64_t n_tiles = (rows + TILE - 1) / TILE;
+    parallel_for(n_tiles, n_threads, [=](int64_t t0, int64_t t1) {
+        std::vector<uint32_t> tile((size_t)(seg * TILE));
+        for (int64_t tr = t0; tr < t1; tr++) {
+            const int64_t r0 = tr * TILE;
+            const int64_t r1 = r0 + TILE < rows ? r0 + TILE : rows;
+            const int64_t width = r1 - r0;
+            for (int64_t g = 0; g < n_groups; g++) {
+                for (int64_t r = r0; r < r1; r++) {
+                    const uint8_t *row = raw + r * blocks_per_row * kBlockBytes;
+                    const int64_t rr = r - r0;
+                    const int64_t per_group = 8 * seg / kBlock;
+                    for (int64_t b = g * per_group; b < (g + 1) * per_group; b++) {
+                        uint16_t h;
+                        std::memcpy(&h, row + b * kBlockBytes, 2);
+                        d_out[b * rows + r] = f16_to_f32(h);
+                    }
+                    for (int64_t t = 0; t < seg; t++) {
+                        uint32_t word = 0;
+                        for (int j = 0; j < 8; j++) {
+                            const int64_t k = (g * 8 + j) * seg + t;
+                            const int e = (int)(k % kBlock);
+                            const uint8_t byte =
+                                row[(k / kBlock) * kBlockBytes + 2 + (e & 15)];
+                            const uint32_t nib = (e < 16 ? byte : byte >> 4) & 0xFu;
+                            word |= (nib ^ 8u) << (4 * j);  // nib - 8, two's complement
+                        }
+                        tile[(size_t)(t * TILE + rr)] = word;
+                    }
+                }
+                for (int64_t t = 0; t < seg; t++)
+                    std::memcpy(w_out + (g * seg + t) * rows + r0,
+                                tile.data() + t * TILE, (size_t)width * 4);
+            }
+        }
+    });
+}
+
 // Dequantize packed Q40 rows to dense f32 in the TRANSPOSED [cols, rows]
 // layout the dense loader wants (file is [rows, cols] row-major).
 void q40_dequant_transposed(const uint8_t *raw, int64_t rows, int64_t cols,
@@ -375,6 +427,6 @@ int64_t bpe_encode(void *handle, const uint8_t *text, int64_t text_len,
     return (int64_t)toks.size();
 }
 
-int dllama_native_version() { return 3; }
+int dllama_native_version() { return 4; }
 
 }  // extern "C"

@@ -109,33 +109,45 @@ def test_qmatmul_prefill_rows(one_chip, k, n):
     compiled_text(qmatmul_2d, *q40_args(2048, k, n, one_chip))
 
 
-def q40_stack(n_layers, k, n, s):
-    from dllama_tpu.ops.quant_matmul import QuantWeight
+def q40_stack(n_layers, k, n, s, packed=False):
+    from dllama_tpu.ops.quant_matmul import PackedQuantWeight, QuantWeight
 
-    return QuantWeight(
-        sds((n_layers, k, n), jnp.int8, s),
-        sds((n_layers, k // 32, n), jnp.float32, s),
-    )
+    scales = sds((n_layers, k // 32, n), jnp.float32, s)
+    if packed:
+        return PackedQuantWeight(sds((n_layers, k // 8, n), jnp.int32, s), scales)
+    return QuantWeight(sds((n_layers, k, n), jnp.int8, s), scales)
 
 
 def weight_copies(text: str) -> list[str]:
-    """HLO instructions that would materialise int8 weights: a slice or a
-    copy whose result is s8. (A `bitcast`, a merge of leading axes, moves
-    nothing.)"""
-    return [
-        line.strip()[:160]
-        for line in text.splitlines()
-        if " s8[" in line.split("=", 1)[-1][:24]
-        and any(op in line for op in ("dynamic-slice(", " copy(", " slice("))
-    ]
+    """HLO instructions that would materialise quantized weights: a slice or
+    a copy whose result is s8, or s32 of a weight's size (packed words: a
+    layer of the smallest stack here is 512 Ki; a program's other int32
+    arrays are positions, tokens and a router's 512 rows x 128 experts, 64
+    Ki at most). (A `bitcast`, a merge of leading axes, moves nothing.)"""
+    import math
+    import re
+
+    found = []
+    for line in text.splitlines():
+        if not any(op in line for op in ("dynamic-slice(", " copy(", " slice(")):
+            continue
+        result = re.match(r"\s*(s8|s32)\[([\d,]*)\]", line.split("=", 1)[-1])
+        if result and (
+            result[1] == "s8"
+            or math.prod(int(d) for d in result[2].split(",") if d) >= 1 << 18
+        ):
+            found.append(line.strip()[:160])
+    return found
 
 
-def test_layer_scan_reads_weight_stacks_in_place(one_chip, monkeypatch):
+@pytest.mark.parametrize("packed", [False, True], ids=["int8", "packed"])
+def test_layer_scan_reads_weight_stacks_in_place(one_chip, monkeypatch, packed):
     """A scan over the layer number that closes over Mistral-7B's FFN stacks
-    (`s8[32,4096,28672]`, `s8[32,14336,4096]`): the kernels take the stacks
-    whole, so the compiled loop holds no slice or copy of an int8 weight.
-    As the scan's `xs` each layer was copied out (a `dynamic-slice` with an
-    `s8[1,4096,28672]` result) before the kernel read it again."""
+    (`s8[32,4096,28672]`, `s8[32,14336,4096]`, or packed `s32[32,512,28672]`,
+    `s32[32,1792,4096]`): the kernels take the stacks whole, so the compiled
+    loop holds no slice or copy of a quantized weight. As the scan's `xs`
+    each layer was copied out (a `dynamic-slice` with an `s8[1,4096,28672]`
+    result) before the kernel read it again."""
     from jax import lax
 
     from dllama_tpu.ops import quant_matmul as qm
@@ -154,8 +166,8 @@ def test_layer_scan_reads_weight_stacks_in_place(one_chip, monkeypatch):
     text = compiled_text(
         jax.jit(f),
         sds((5, 1, D), jnp.bfloat16, one_chip),
-        q40_stack(n_layers, D, 2 * FF, one_chip),
-        q40_stack(n_layers, FF, D, one_chip),
+        q40_stack(n_layers, D, 2 * FF, one_chip, packed),
+        q40_stack(n_layers, FF, D, one_chip, packed),
     )
     assert text.count("tpu_custom_call") == 2
     assert not weight_copies(text), weight_copies(text)
@@ -229,19 +241,19 @@ def cache_copies(text: str, cache: tuple[int, ...], dtype: str = "bf16"):
     return found
 
 
-def mistral_layers(n_layers, s, row=None, col=None):
+def mistral_layers(n_layers, s, row=None, col=None, packed=False):
     """Mistral-7B's per-layer leaves as shapes: Q40 stacks and two norms."""
     row, col = row or s, col or s
     f32 = sds((n_layers, D), jnp.float32, s)
     return dict(
         att_norm=f32, ffn_norm=f32,
-        wq=q40_stack(n_layers, D, H * HD, row),
-        wk=q40_stack(n_layers, D, KH * HD, row),
-        wv=q40_stack(n_layers, D, KH * HD, row),
-        wo=q40_stack(n_layers, H * HD, D, col),
-        w1=q40_stack(n_layers, D, FF, row),
-        w3=q40_stack(n_layers, D, FF, row),
-        w2=q40_stack(n_layers, FF, D, col),
+        wq=q40_stack(n_layers, D, H * HD, row, packed),
+        wk=q40_stack(n_layers, D, KH * HD, row, packed),
+        wv=q40_stack(n_layers, D, KH * HD, row, packed),
+        wo=q40_stack(n_layers, H * HD, D, col, packed),
+        w1=q40_stack(n_layers, D, FF, row, packed),
+        w3=q40_stack(n_layers, D, FF, row, packed),
+        w2=q40_stack(n_layers, FF, D, col, packed),
     )
 
 
@@ -326,8 +338,9 @@ def scan_text_of(model, rows, window, s, monkeypatch) -> str:
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if (model, rows, window) not in _SCAN_TEXTS:
         header, cache, layers = (
-            (mistral_header(), MISTRAL_CACHE, mistral_layers(32, s))
-            if model == "mistral"
+            (mistral_header(), MISTRAL_CACHE,
+             mistral_layers(32, s, packed=model == "mistral-packed"))
+            if model.startswith("mistral")
             else (qwen_moe_header(), QWEN_CACHE, qwen_moe_layers(12, s))
         )
         _SCAN_TEXTS[model, rows, window] = layer_scan_text(
@@ -380,6 +393,26 @@ def test_chunk_program_sorts_one_lanes_pairs(one_chip, monkeypatch):
     padded = lanes * bucket * k
     shaped = re.findall(rf"\w+\[{padded}(?:,\d+)*\]", text)
     assert not shaped, sorted(set(shaped))
+
+
+@LANE_PROGRAMS
+def test_packed_lane_programs_read_weight_stacks_in_place(
+    one_chip, monkeypatch, rows, window
+):
+    """`mistral-7b-v0.3` as `--weight-format auto` serves it: the decode
+    step of five lanes and the 512-row chunk program over packed stacks
+    (`s32[32,512,4096]` ...): seven kernel calls a layer, the cache rows
+    written in place as over int8 stacks, and no slice or copy of a packed
+    weight anywhere in the compiled loop."""
+    text = scan_text_of("mistral-packed", rows, window, one_chip, monkeypatch)
+    assert "s32[32,512,4096]" in text and " s8[" not in text
+    assert not weight_copies(text), weight_copies(text)
+    assert not cache_copies(text, MISTRAL_CACHE), cache_copies(text, MISTRAL_CACHE)
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "qmatmul_i4_2d" in line.split(" = ")[0]
+    ]
+    assert len(kernels) == 7, len(kernels)
 
 
 @LANE_PROGRAMS
@@ -1112,16 +1145,62 @@ def test_moe_grouped_experts_q40(one_chip):
     )
 
 
+def packed_args(m, k, n, s):
+    return (
+        sds((m, k), jnp.bfloat16, s),
+        sds((k // 8, n), jnp.int32, s),
+        sds((k // 32, n), jnp.float32, s),
+    )
+
+
 @pytest.mark.parametrize("m", [1, 128])
 @pytest.mark.parametrize("k,n", SHAPES + [(D, V // 4)])
 def test_qmatmul_q40i4(one_chip, m, k, n):
-    """--weight-format q40i4: the f16 scale plane enters the kernel as its
-    raw int16 bits (the chip has no f16 vector load)."""
+    """--weight-format q40i4 at Llama-3.1-8B's widths: int32 words of eight
+    nibbles, 512-wide tiles at one row and 256-wide at 128."""
     from dllama_tpu.ops.quant_matmul import qmatmul_i4_2d
 
-    compiled_text(
-        qmatmul_i4_2d,
-        sds((m, k), jnp.bfloat16, one_chip),
-        sds((k // 2, n), jnp.int8, one_chip),
-        sds((k // 32, n), jnp.float16, one_chip),
-    )
+    compiled_text(qmatmul_i4_2d, *packed_args(m, k, n, one_chip))
+
+
+# every (k, n) the seven cells' programs hand `qmatmul` (fused q|k|v(|gate)
+# and w1|w3 as one tp shard fuses them), with the rows of the cell's decode
+# block and of its chunk program (lanes x 512; a convolution layer and its
+# FFN: the admitted lane's 512)
+CELL_MATMULS = {
+    "mistral-7b-v0.3": ([5, 2560], [
+        (4096, 6144), (4096, 4096), (4096, 28672), (14336, 4096), (4096, 32768)]),
+    "qwen3-30b-a3b-l12": ([16, 8192], [(2048, 5120), (4096, 2048), (2048, 151936)]),
+    "trinity-large-l9-e32": ([8, 4096], [
+        (3072, 14336), (6144, 3072), (3072, 24576), (12288, 3072), (3072, 6144),
+        (3072, 3072), (3072, 25024)]),
+    "openpangu-ultra-l5-e32": ([4, 2048], [
+        (7680, 1536), (1536, 24576), (7680, 576), (16384, 7680), (7680, 36864),
+        (18432, 7680), (7680, 4096), (2048, 7680), (7680, 19200)]),
+    "deepseek-v3.2-l5-e32": ([4, 2048], [
+        (7168, 1536), (1536, 24576), (7168, 576), (16384, 7168), (7168, 36864),
+        (18432, 7168), (7168, 4096), (2048, 7168), (1536, 8192), (7168, 128),
+        (7168, 16160)]),
+    "lfm2-24b-a2b-e16": ([16, 512, 8192], [
+        (2048, 3072), (2048, 2048), (2048, 6144), (2048, 23552), (11776, 2048),
+        (2048, 16384)]),
+}
+
+
+@pytest.mark.parametrize(
+    "m,k,n",
+    [
+        pytest.param(m, k, n, id=f"{config}-{m}x{k}x{n}")
+        for config, (rows, shapes) in CELL_MATMULS.items()
+        for m in rows for k, n in shapes
+    ],
+)
+def test_qmatmul_packed_at_the_cells_shapes(one_chip, m, k, n):
+    """What `--weight-format auto` serves in every cell: the packed kernel
+    at each configuration's widths (k = 11776 in k blocks of 512, the
+    latent and index projections, per-shard vocabularies no 128-multiple
+    divides) and both its row counts, 512-wide tiles under the decode rows
+    and 256-wide under a chunk's row blocks."""
+    from dllama_tpu.ops.quant_matmul import qmatmul_i4_2d
+
+    compiled_text(qmatmul_i4_2d, *packed_args(m, k, n, one_chip))

@@ -258,11 +258,11 @@ def test_prefetch_builder_failure_is_recorded(tiny_model, caplog):
 
 
 def test_packed_weight_format_matches_q40(tiny_model):
-    """weight_format='q40i4' (packed nibbles + f16 scales) reproduces the
-    q40 greedy tokens exactly: f16 scales are wire-exact and the nibble
-    unpack is lossless, so off-TPU the two dequant paths are bit-identical.
-    Also pins the loaded leaf layout (the point of the format: 0.5625 B/w
-    on device instead of 1.125)."""
+    """weight_format='q40i4' (packed nibbles, the same f32 scales)
+    reproduces the q40 greedy tokens exactly: the nibble unpack is
+    lossless, so off-TPU the two dequant paths are bit-identical. Also pins
+    the loaded leaf layout (the point of the format: 0.625 B/w on device
+    instead of 1.125)."""
     from dllama_tpu.models.loader import FusedQuantWeight
     from dllama_tpu.ops.quant_matmul import PackedQuantWeight
 
@@ -279,26 +279,31 @@ def test_packed_weight_format_matches_q40(tiny_model):
     assert isinstance(wqkv, FusedQuantWeight)
     pw = wqkv.weight
     assert isinstance(pw, PackedQuantWeight)
-    assert pw.qp.dtype == jnp.int8 and pw.d.dtype == jnp.float16
-    n_weights = pw.in_dim * pw.out_dim * pw.qp.shape[0]  # [L, in//2, out]
-    assert (pw.qp.nbytes + pw.d.nbytes) / n_weights <= 0.60
+    assert pw.qp.dtype == jnp.int32 and pw.d.dtype == jnp.float32
+    n_weights = pw.in_dim * pw.out_dim * pw.qp.shape[0]  # [L, in//8, out]
+    assert (pw.qp.nbytes + pw.d.nbytes) / n_weights == 0.625
 
 
 def test_packed_weight_format_tp(tmp_path):
-    """Packed weights sharded over a tp=4 mesh reproduce single-chip: the
-    in//2 (nibble) and in//32 (scale) axes both divide by tp under the
-    engine's 32*tp divisibility check, so col shards stay byte-aligned."""
+    """Packed weights sharded over a tp=2 mesh reproduce single-chip: every
+    in dim is whole groups of 256 rows a shard (the engine's check), so the
+    in//8 (word) and in//32 (scale) axes split at group boundaries and each
+    col shard is a packed tensor of its own. A width that is not is refused
+    by name."""
     mp = str(tmp_path / "mq4.m")
-    cfg = dict(dim=128, hidden_dim=256, n_layers=2, n_heads=8, n_kv_heads=4,
+    cfg = dict(dim=512, hidden_dim=512, n_layers=2, n_heads=32, n_kv_heads=4,
                head_dim=16, vocab_size=256, seq_len=64)
     make_tiny_model(mp, weight_type=FloatType.Q40, cfg=cfg)
     e1 = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
                          weight_format="q40i4")
     out1, _, _ = e1.generate([5, 6, 7], max_steps=10)
-    e4 = InferenceEngine(mp, tp=4, dtype=jnp.float32, temperature=0.0,
+    e2 = InferenceEngine(mp, tp=2, dtype=jnp.float32, temperature=0.0,
                          weight_format="q40i4")
-    out4, _, _ = e4.generate([5, 6, 7], max_steps=10)
-    assert out1 == out4
+    out2, _, _ = e2.generate([5, 6, 7], max_steps=10)
+    assert out1 == out2
+    with pytest.raises(ValueError, match="divisible by 1024"):
+        InferenceEngine(mp, tp=4, dtype=jnp.float32, temperature=0.0,
+                        weight_format="q40i4")
 
 
 def test_packed_weight_format_moe_keeps_int8_experts(tmp_path):
@@ -329,7 +334,7 @@ def test_packed_streamed_load_matches_host_stack(tmp_path, monkeypatch):
     from jax.tree_util import tree_leaves_with_path
 
     mp = str(tmp_path / "mq4s.m")
-    cfg = dict(dim=128, hidden_dim=256, n_layers=2, n_heads=8, n_kv_heads=4,
+    cfg = dict(dim=512, hidden_dim=512, n_layers=2, n_heads=32, n_kv_heads=4,
                head_dim=16, vocab_size=256, seq_len=64)
     make_tiny_model(mp, weight_type=FloatType.Q40, cfg=cfg)
     e_stream = InferenceEngine(mp, tp=2, dtype=jnp.float32, temperature=0.0,
@@ -1887,3 +1892,88 @@ def test_prep_ms_is_the_prep_spans_begin_to_the_dispatchs(slab_engine, monkeypat
     assert "drained_ms" not in copy
     e.reset()
     e._drained_at = None
+
+
+# ------------------------------------------- what "auto" serves, and the gauges
+
+_PACKABLE = dict(dim=256, hidden_dim=256, n_layers=1, n_heads=16, n_kv_heads=4,
+                 head_dim=16, vocab_size=256, seq_len=64)
+
+
+@pytest.mark.parametrize(
+    "backend,cfg,want",
+    [
+        ("tpu", _PACKABLE, "q40i4"),  # every in dim whole groups of 256 rows
+        ("tpu", dict(_PACKABLE, hidden_dim=160), "q40"),  # w2's is not
+        ("cpu", _PACKABLE, "dense"),
+    ],
+)
+def test_auto_weight_format_by_backend(tmp_path, monkeypatch, backend, cfg, want):
+    """`--weight-format auto`: on a TPU a Q40 file is served packed where
+    the packed kernel takes every dense matmul's in dim, as int8 values
+    where it does not; off a TPU dense. The backend is what
+    `jax.default_backend()` reports (monkeypatched: nothing runs here)."""
+    import dllama_tpu.runtime.engine as engine_mod
+    from dllama_tpu.ops.quant_matmul import PackedQuantWeight, QuantWeight
+
+    mp = str(tmp_path / "auto.m")
+    make_tiny_model(mp, weight_type=FloatType.Q40, cfg=cfg)
+    monkeypatch.setattr(engine_mod.jax, "default_backend", lambda: backend)
+    e = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
+                        weight_format="auto")
+    assert e.weight_format == want
+    held = {"q40i4": PackedQuantWeight, "q40": QuantWeight}.get(want)
+    wo = e.params["layers"]["wo"]
+    assert type(wo) is held if held else not isinstance(wo, tuple)
+
+
+@pytest.mark.parametrize(
+    "arch,weight_format",
+    [
+        (LlmArch.LLAMA, "q40i4"), (LlmArch.LLAMA, "q40"), (LlmArch.LLAMA, "dense"),
+        (LlmArch.QWEN3_MOE, "q40i4"), (LlmArch.QWEN3_MOE, "q40"),
+    ],
+)
+def test_weight_bytes_by_form_add_up(tmp_path, arch, weight_format):
+    """The resident-bytes gauges add up to the leaves' bytes, form by form,
+    and the packed share of a decode step's quantized bytes is 1.0 for a
+    dense model served packed, 0.0 served as int8 values, and between them
+    where routed experts (int8 under both formats, read at the active share
+    of those held) stand beside packed dense matmuls."""
+    import jax
+
+    from dllama_tpu.obs.metrics import get_registry
+    from dllama_tpu.ops.quant_matmul import PackedQuantWeight, QuantWeight
+
+    mp = str(tmp_path / "wb.m")
+    make_tiny_model(mp, arch=arch, weight_type=FloatType.Q40)
+    e = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
+                        weight_format=weight_format)
+    wb = e.weight_bytes
+    leaves = jax.tree.leaves(e.params)
+    assert wb["packed"] + wb["int8"] + wb["float"] == sum(a.nbytes for a in leaves)
+    quant = lambda cls: sum(
+        a.nbytes
+        for w in jax.tree.leaves(e.params, is_leaf=lambda x: isinstance(x, tuple))
+        if type(w) is cls for a in w
+    )
+    assert wb["packed"] == quant(PackedQuantWeight)
+    assert wb["int8"] == quant(QuantWeight)
+    text = get_registry().render()
+    for form in ("packed", "int8", "float"):
+        assert f'dllama_weight_bytes{{form="{form}"}} {wb[form]}\n' in text
+    share = wb["decode_packed_share"]
+    if arch == LlmArch.LLAMA:
+        assert share == {"q40i4": 1.0, "q40": 0.0, "dense": 0.0}[weight_format]
+        assert (wb["packed"] > 0) == (weight_format == "q40i4")
+        assert (wb["int8"] > 0) == (weight_format == "q40")
+    elif weight_format == "q40":
+        assert share == 0.0 and wb["packed"] == 0
+    else:
+        h = e.header
+        experts = sum(
+            a.nbytes for n in ("w1", "w2", "w3") for a in e.params["layers"][n])
+        assert experts == wb["int8"]  # the routed stacks, and nothing else
+        step_int8 = experts * h.n_active_experts / h.n_experts
+        assert share == pytest.approx(wb["packed"] / (wb["packed"] + step_int8))
+        assert 0.0 < share < 1.0

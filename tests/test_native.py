@@ -33,6 +33,35 @@ def test_unpack_transposed_parity(lib):
     )
 
 
+@pytest.mark.parametrize(
+    "rows,cols", [(96, 160), (130, 512), (7, 288), (64, 256), (200, 1024)]
+)
+def test_pack_transposed_parity(lib, rows, cols):
+    """The native packer writes the numpy packer's words and scales
+    (formats.quants.pack_q40_device), and `unpack_nibbles` reads the wire's
+    values back out of them: whole groups of 256 rows (512, 256, 1024) or
+    one group of eight segments (160, 288), more rows than a tile of 64."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.formats.quants import pack_q40_device
+    from dllama_tpu.ops.quant_matmul import unpack_nibbles
+
+    rng = np.random.default_rng(rows + cols)
+    raw = quantize_q40(rng.standard_normal((rows, cols)).astype(np.float32))
+    words, d = native.q40_pack_transposed(raw, rows, cols)
+    words_np, d_np = pack_q40_device(raw, rows, cols)
+    assert words.dtype == np.int32 and words.shape == (cols // 8, rows)
+    np.testing.assert_array_equal(words, words_np)
+    np.testing.assert_array_equal(d, d_np)
+    q_np, dq = q40_to_planar(raw, rows * cols)
+    np.testing.assert_array_equal(
+        np.asarray(unpack_nibbles(jnp.asarray(words))), q_np.reshape(rows, cols).T
+    )
+    np.testing.assert_array_equal(
+        d, dq.reshape(rows, cols // 32).T.astype(np.float32)
+    )
+
+
 def test_dequant_parity(lib):
     rows, cols = 64, 128
     rng = np.random.default_rng(1)
@@ -47,8 +76,10 @@ def test_dequant_parity(lib):
     )
 
 
-def test_loader_uses_native_path(tmp_path, lib):
-    """End-to-end: params loaded with the native path match the numpy path."""
+@pytest.mark.parametrize("weight_format", ["q40", "q40i4"])
+def test_loader_uses_native_path(tmp_path, lib, weight_format):
+    """End-to-end: params loaded with the native path match the numpy path,
+    int8 planes (`wq.q`) and packed words (`wq.qp`) alike."""
     import sys
 
     sys.path.insert(0, "tests")
@@ -60,23 +91,23 @@ def test_loader_uses_native_path(tmp_path, lib):
     mp = str(tmp_path / "m.m")
     make_tiny_model(mp, weight_type=FloatType.Q40)
     reader = ModelReader(mp)
-    p_native = load_params(reader, weight_format="q40")
+    p_native = load_params(reader, weight_format=weight_format)
     # force numpy fallback
     saved = native._lib
     native._lib = None
     native._lib_tried = True
     try:
-        p_numpy = load_params(reader, weight_format="q40")
+        p_numpy = load_params(reader, weight_format=weight_format)
     finally:
         native._lib = saved
     np.testing.assert_array_equal(
-        np.asarray(p_native["layers"]["wq"].q), np.asarray(p_numpy["layers"]["wq"].q)
+        np.asarray(p_native["layers"]["wq"][0]), np.asarray(p_numpy["layers"]["wq"][0])
     )
     np.testing.assert_allclose(
         np.asarray(p_native["layers"]["wq"].d), np.asarray(p_numpy["layers"]["wq"].d)
     )
     np.testing.assert_array_equal(
-        np.asarray(p_native["wcls"].q), np.asarray(p_numpy["wcls"].q)
+        np.asarray(p_native["wcls"][0]), np.asarray(p_numpy["wcls"][0])
     )
 
 

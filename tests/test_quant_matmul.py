@@ -223,27 +223,32 @@ def test_pack_nibbles_roundtrip():
     from dllama_tpu.ops.quant_matmul import unpack_nibbles
 
     pw, qw, _ = make_packed(64, 128)
-    assert pw.qp.shape == (64, 64) == (qw.q.shape[0] // 2, qw.q.shape[1])
-    assert pw.d.dtype == jnp.float16
+    assert pw.qp.shape == (16, 64) == (qw.q.shape[0] // 8, qw.q.shape[1])
+    assert pw.qp.dtype == jnp.int32 and pw.d.dtype == jnp.float32
     np.testing.assert_array_equal(
         np.asarray(unpack_nibbles(pw.qp)), np.asarray(qw.q, dtype=np.int32)
     )
 
 
-def test_host_pack_matches_device_pack():
-    """formats.pack_q40_device (numpy, loader path) produces the exact
-    bytes of ops.pack_nibbles (jnp, requantize path)."""
+@pytest.mark.parametrize("n,k", [(128, 256), (40, 64), (24, 288), (16, 1024)])
+def test_host_pack_matches_device_pack(n, k):
+    """formats.pack_q40_device (numpy, the load path: straight from the
+    wire's bytes) produces the exact words of ops.pack_nibbles (jnp, from
+    the int8 plane), whole groups of 256 rows or one group of eight
+    segments."""
     from dllama_tpu.formats.quants import pack_q40_device
 
-    pw, qw, _ = make_packed(128, 256, seed=5)
-    qp_np, d_np = pack_q40_device(np.asarray(qw.q), np.asarray(qw.d))
+    pw, _, w = make_packed(n, k, seed=5)
+    qp_np, d_np = pack_q40_device(quantize_q40(w), n, k)
+    assert qp_np.dtype == np.int32 and d_np.dtype == np.float32
     np.testing.assert_array_equal(qp_np, np.asarray(pw.qp))
     np.testing.assert_array_equal(d_np, np.asarray(pw.d))
 
 
 def test_dequant_packed_matches_dequant():
-    """f16 scales are wire-exact (Q40 stores fp16 scales), so the packed
-    dequant is bit-identical to the int8 dequant."""
+    """The packed form holds the same nibbles and the same f32 scales (the
+    wire's f16 values, exactly), so the packed dequant is bit-identical to
+    the int8 dequant."""
     from dllama_tpu.ops.quant_matmul import dequant_packed
 
     pw, qw, _ = make_packed(64, 128, seed=2)
@@ -275,27 +280,68 @@ def test_packed_kernel_matches_reference(m, n, k):
     np.testing.assert_allclose(got, int8, rtol=0, atol=4 * ulp)
 
 
-def test_f16_bits_widen_exactly():
-    """The packed kernel widens the f16 scale plane from its raw bits (the
-    chip cannot load f16 vectors): every finite f16 pattern — subnormals
-    and both zeros included — must come out as numpy's own f16 -> f32."""
-    from dllama_tpu.ops.quant_matmul import _f16_bits_to_f32
+def _edge_weights(k, n, seed):
+    """(QuantWeight, PackedQuantWeight) [k, n] of every nibble, -8 and 7
+    in every block, under scales of every kind the wire can hold: f16
+    subnormals, negative ones, both zeros, the largest and the smallest."""
+    from dllama_tpu.ops.quant_matmul import pack_nibbles
 
-    bits = np.arange(1 << 16, dtype=np.uint16)
-    want = bits.view(np.float16).astype(np.float32)
-    finite = np.isfinite(want)
-    got = np.asarray(_f16_bits_to_f32(jnp.asarray(bits.view(np.int16))))
-    np.testing.assert_array_equal(got[finite], want[finite])
-    assert np.array_equal(np.signbit(got[finite]), np.signbit(want[finite]))
+    rng = np.random.default_rng(seed)
+    q = rng.integers(-8, 8, (k, n)).astype(np.int8)
+    q[0::32], q[1::32] = -8, 7
+    bits = rng.integers(0, 1 << 16, (k // 32, n)).astype(np.uint16)
+    bits[0, :8] = [0x0001, 0x8001, 0x03FF, 0x83FF, 0x0000, 0x8000, 0x7BFF, 0xFBFF]
+    d = bits.view(np.float16)
+    d = np.where(np.isfinite(d), d, np.float16(-0.00123)).astype(np.float32)
+    qw = QuantWeight(jnp.asarray(q), jnp.asarray(d))
+    return qw, pack_nibbles(qw)
+
+
+@pytest.mark.parametrize(
+    "k,n,block_n,block_k",
+    [(256, 256, 128, 4096), (1024, 384, 128, 512), (768, 128, None, 256)],
+)
+def test_packed_tile_is_the_int8_tile_bit_for_bit(k, n, block_n, block_k):
+    """The identity's rows through both kernels read their dequantised
+    bf16 tiles out exactly (a one-hot row sums one product): the packed
+    kernel's is `_qmm_kernel`'s, which is `(nib - 8) * d` in f32 narrowed
+    to bf16, for every nibble and every kind of scale. k = 1024 and 768 are
+    more rows than one row block (BLOCK_M = 512), in several k steps."""
+    from dllama_tpu.ops.quant_matmul import BLOCK_M, qmatmul_i4_2d
+
+    qw, pw = _edge_weights(k, n, seed=k + n)
+    eye = jnp.eye(k, dtype=jnp.bfloat16)
+    assert k <= BLOCK_M or -(-k // BLOCK_M) > 1
+    packed = qmatmul_i4_2d(
+        eye, pw.qp, pw.d, block_n=block_n, block_k=block_k, interpret=True)
+    int8 = qmatmul_2d(
+        eye, qw.q, qw.d, block_n=block_n or 256, block_k=block_k, interpret=True)
+    want = np.asarray(dequant(qw, jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(int8), want)
+    np.testing.assert_array_equal(np.asarray(packed), want)
+
+
+@pytest.mark.parametrize("m", [5, 16])
+def test_packed_decode_rows_equal_the_int8_kernel(m):
+    """At decode rows, with the packed kernel's own tile width (512 there):
+    the same bf16 tile through the same dot, column for column."""
+    from dllama_tpu.ops.quant_matmul import qmatmul_i4_2d
+
+    qw, pw = _edge_weights(512, 1024, seed=m)
+    x = jnp.asarray(
+        np.random.default_rng(m).standard_normal((m, 512)).astype(np.float32))
+    got = qmatmul_i4_2d(x, pw.qp, pw.d, interpret=True)
+    want = qmatmul_2d(x, qw.q, qw.d, block_n=512, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_packed_bytes_per_weight():
-    """The device residency win the format exists for: ≤ 0.60 B/weight
-    including scales (0.5 packed nibbles + 2/32 f16 scale = 0.5625)."""
+    """The device residency win the format exists for: 0.625 B/weight
+    including scales (0.5 packed nibbles + 4/32 f32 scale)."""
     pw, qw, _ = make_packed(256, 512)
     n_weights = 256 * 512
     packed_bytes = pw.qp.nbytes + pw.d.nbytes
-    assert packed_bytes / n_weights <= 0.60
+    assert packed_bytes / n_weights == 0.625
     assert pw.qp.nbytes * 2 == qw.q.nbytes  # exactly half the value bytes
 
 
@@ -321,7 +367,7 @@ def test_packedquantweight_is_pytree():
     pw, _, _ = make_packed(64, 64)
     stacked = jax.tree.map(lambda a: jnp.stack([a, a]), pw)
     assert isinstance(stacked, PackedQuantWeight)
-    assert stacked.qp.shape == (2, 32, 64)
+    assert stacked.qp.shape == (2, 8, 64)
     assert len(jax.tree.leaves(pw)) == 2
 
 
