@@ -154,9 +154,11 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                    choices=["auto", "q40", "q40i4", "dense"],
                    help="q40 keeps weights block-quantized on device as "
                         "int8 values (Pallas kernel, 1.125 B/weight); q40i4 "
-                        "keeps the dense matmuls' nibbles packed (0.625 "
-                        "B/weight, unpacked in the kernel; routed experts "
-                        "stay int8); auto = q40i4 on a TPU with a Q40 file "
+                        "keeps the nibbles packed (0.625 B/weight, unpacked "
+                        "in the kernel: the dense matmuls, and the routed "
+                        "experts where one device holds a sparse layer whole; "
+                        "on a mesh they stay int8); auto = q40i4 on a TPU "
+                        "with a Q40 file "
                         "(q40 where a matmul's in dim is no multiple of "
                         "256 a shard), dense elsewhere")
     p.add_argument("--profile", default=None, metavar="DIR",
@@ -385,6 +387,7 @@ def load_engine(args):
     print_roofline_report(
         h, engine.weight_format, tp=tp, pp=pp,
         spec_k=spec_k_val if spec_mode != "off" else 0,
+        experts_packed=engine.experts_packed,
     )
     # live per-chip memory vs the analytic figure: a >10% gap logs a
     # warning (leak / unplanned replication / stale analytic model)

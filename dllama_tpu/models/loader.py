@@ -260,6 +260,7 @@ def load_params(
     put: PutFn = _default_put,
     weight_format: str = "dense",
     fuse: int = 0,
+    pack_experts: bool = False,
 ) -> Params:
     """Materialize the params pytree from a `.m` file.
 
@@ -278,8 +279,11 @@ def load_params(
     `weight_format="q40i4"` instead lays the matmul weights out in the
     nibble device format (`PackedQuantWeight`: eight int4 values per int32
     word + f32 scales, 0.625 B/weight) straight from the wire's bytes; the
-    Pallas kernel unpacks in VMEM after the HBM copy. MoE expert weights
-    stay int8 `QuantWeight` (the ragged MoE kernels consume that layout).
+    Pallas kernel unpacks in VMEM after the HBM copy. With `pack_experts`
+    the routed expert stacks [L, E, in, out] are laid out the same way
+    (the engine asks for it where one device holds a sparse layer whole:
+    `moe_held_experts_q40` unpacks them as `qmatmul` does); without it they
+    stay int8 `QuantWeight`, which the mesh's expert kernels consume.
 
     `fuse` (quantized path only): the tp shard count; > 0 emits fused
     "wqkv" (q|k|v) and, for dense-FFN archs, "w13" (w1|w3) weights in
@@ -509,26 +513,31 @@ def load_params(
             # src/nn/nn-network.cpp:856-888); the ragged MoE kernel
             # dequantizes selected blocks in VMEM. Layout per expert is the
             # same [in, out] device layout as the dense matmuls, stacked
-            # [L, E, ...]. Under weight_format="q40i4" the experts KEEP
-            # this int8 layout (the ragged MoE kernels consume it).
-            def qexperts(tag: str, which: str) -> QuantWeight:
+            # [L, E, ...]: int8 values, or with `pack_experts` the packed
+            # words, written straight from the wire an expert at a time.
+            if pack_experts and not packed:
+                raise ValueError('pack_experts needs weight_format="q40i4"')
+
+            def qexperts(tag: str, which: str):
                 if streaming:
                     w_, _ = _stream_quant_stack(
                         reader, put, tag,
                         [lambda i, e, wh=which:
                             f"layers.{expert_layers[i]}.experts.{e}.{wh}"],
                         (len(expert_layers), h.n_experts),
+                        packed=pack_experts,
                     )
                     return w_
                 lqs, lds = [], []
                 for l in expert_layers:
                     unpacked = [
-                        unpack_q40(f"layers.{l}.experts.{e}.{which}")
+                        unpack_q40(f"layers.{l}.experts.{e}.{which}", pack_experts)
                         for e in range(h.n_experts)
                     ]
                     lqs.append(np.stack([u[0] for u in unpacked]))
                     lds.append(np.stack([u[1] for u in unpacked]))
-                return QuantWeight(put(tag, np.stack(lqs)), put(tag, np.stack(lds)))
+                cls = PackedQuantWeight if pack_experts else QuantWeight
+                return cls(put(tag, np.stack(lqs)), put(tag, np.stack(lds)))
 
             layers["w1"] = qexperts("w1", "w1")
             layers["w2"] = qexperts("w2", "w2")

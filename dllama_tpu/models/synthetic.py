@@ -301,11 +301,19 @@ def random_params(
 
     quant = weight_format in ("q40", "q40i4")
     packed = weight_format == "q40i4"
+    # the engine's policy (`_packs_experts`): routed experts are packed with
+    # the dense matmuls where one device holds the layer and both of their
+    # in axes are whole groups of 256; on a mesh they stay int8 QuantWeight
+    # (the ragged kernels consume that layout)
+    from ..ops.quant_matmul import PACKED_GROUP
+
+    pack_experts = (
+        packed and (mesh is None or mesh.devices.size == 1)
+        and D % PACKED_GROUP == 0 and FF % PACKED_GROUP == 0
+    )
     if quant:
         def mm(name, *shape, expert=False):
-            # MoE experts stay int8 QuantWeight under q40i4 (the ragged
-            # kernels consume that layout; loader policy)
-            return mk_quant(name, *shape, packed=packed and not expert)
+            return mk_quant(name, *shape, packed=pack_experts if expert else packed)
     else:
         def mm(name, *shape, expert=False):
             return mk(name, *shape)

@@ -39,14 +39,15 @@ from ..ops.quant_matmul import (
     PackedQuantWeight,
     QuantWeight,
     dequant,
+    dequant_packed,
     layer_of,
     qmatmul_tp,
 )
 
 # both Q40 device formats ride the same qmatmul dispatch (the packed
-# variant unpacks nibbles in VMEM); MoE expert leaves stay plain
-# QuantWeight under every quantized format, so the expert kernels below
-# test for that class alone
+# variant unpacks nibbles in VMEM); MoE expert leaves are packed only for
+# `moe_held_experts_q40` (one device holds the layer), so the mesh's expert
+# kernels below test for plain QuantWeight alone
 _QUANT_CLASSES = (QuantWeight, PackedQuantWeight)
 from ..ops.flash_attention import (
     flash_attention,
@@ -740,11 +741,14 @@ def _moe_ffn(
     masked by routing weight). That is compile-friendly and exact; the
     gather/ragged fast path for decode is `_moe_ffn_pallas`.
 
-    Quantized expert weights (QuantWeight) are dequantized on the fly —
-    one layer's experts at a time under the scan, so the transient is one
-    [E, D, F] bf16 tensor, never the whole stack.
+    Quantized expert weights (QuantWeight, or PackedQuantWeight through
+    `dequant_packed`) are dequantized on the fly — one layer's experts at
+    a time under the scan, so the transient is one [E, D, F] bf16 tensor,
+    never the whole stack.
     """
-    if isinstance(w1, QuantWeight):
+    if isinstance(w1, PackedQuantWeight):
+        w1, w2, w3 = (dequant_packed(w, x.dtype) for w in (w1, w2, w3))
+    elif isinstance(w1, QuantWeight):
         w1, w2, w3 = (dequant(w, x.dtype) for w in (w1, w2, w3))
     e = w1.shape[0]
     top_i, weights = routed or _moe_route(x, gate_w, route)  # [B, T, k]
@@ -771,9 +775,10 @@ def _moe_ffn(
 MOE_PALLAS_MAX_TOKENS = 16
 
 
-def _expert_stacks(*ws: QuantWeight) -> tuple[jnp.ndarray, ...]:
-    """Quantized experts' values and scales as [L, E, ...] stacks, in
-    argument order; one layer's [E, ...] experts are a stack of one."""
+def _expert_stacks(*ws) -> tuple[jnp.ndarray, ...]:
+    """Quantized experts' values (int8, or packed words) and scales as
+    [L, E, ...] stacks, in argument order; one layer's [E, ...] experts are
+    a stack of one."""
     return tuple(a.reshape(-1, *a.shape[-3:]) for w in ws for a in w)
 
 
@@ -1605,9 +1610,13 @@ def run_layers(
         b, t = y.shape[0], y.shape[1]
 
         _w1 = lp["w1"]
-        _quantized = isinstance(_w1, QuantWeight)
+        # packed words are the held kernel's alone (the engine packs the
+        # experts only where one device holds the layer); anything else
+        # that meets them takes the dense path below
+        _packed = isinstance(_w1, PackedQuantWeight)
+        _quantized = isinstance(_w1, _QUANT_CLASSES)
         _itemsize = 1 if _quantized else _w1.dtype.itemsize
-        _f = _w1.q.shape[-1] if _quantized else _w1.shape[-1]
+        _f = _w1.out_dim if _quantized else _w1.shape[-1]
         bias = lp.get("expert_bias")
         # the kernels run PER-SHARD under shard_map, so the VMEM/
         # tiling gate must see the per-shard F (= F / tp), not the
@@ -1650,7 +1659,7 @@ def run_layers(
                 y.reshape(b * t, -1), *_expert_stacks(lp["w1"], lp["w2"], lp["w3"]),
                 held_i, wts, jnp.asarray(lf, jnp.int32),
             ).reshape(b, t, -1).astype(y.dtype), counts
-        if pallas_ok:
+        if pallas_ok and not _packed:
             # more than one device, or unquantized experts: the older two
             # kernels until the held kernel is partitioned
             # (docs/moe_decode_dedup.md): one expert read a (token,

@@ -115,15 +115,20 @@ PACKED_BYTES_PER_WEIGHT = 0.5 + 4.0 / 32.0
 INT8_BYTES_PER_WEIGHT = 1.0 + 4.0 / 32.0
 
 
-def weight_bytes_per_token(h: "LlmHeader", weight_format: str) -> int:
+def weight_bytes_per_token(
+    h: "LlmHeader", weight_format: str, experts_packed: bool = False
+) -> int:
     """HBM bytes of weights a single decode step must read: every matmul
     weight once (MoE: attention weights + the active experts' share), each
     charged by the form the loader holds it in: under `q40i4` the dense
-    matmuls are packed nibbles (0.625 B/weight) while the routed experts
-    stay int8 as under `q40` (1.125); dense bf16 = 2 B/weight."""
+    matmuls are packed nibbles (0.625 B/weight), and the routed experts
+    with them where the engine holds them so (`experts_packed`: one device
+    holds the layer), else int8 as under `q40` (1.125); dense bf16 = 2
+    B/weight."""
     quantized = weight_format in ("q40", "q40i4")
-    expert_bpw = INT8_BYTES_PER_WEIGHT if quantized else 2.0
-    dense_bpw = PACKED_BYTES_PER_WEIGHT if weight_format == "q40i4" else expert_bpw
+    int8_bpw = INT8_BYTES_PER_WEIGHT if quantized else 2.0
+    dense_bpw = PACKED_BYTES_PER_WEIGHT if weight_format == "q40i4" else int8_bpw
+    expert_bpw = dense_bpw if experts_packed else int8_bpw
     att = h.dim * h.q_dim + 2 * h.dim * h.kv_dim + h.q_dim * h.dim
     ffn = 3 * h.dim * h.ff_dim
     ffn_bpw = dense_bpw
@@ -148,7 +153,7 @@ def weight_bytes_by_form(params, h: "LlmHeader") -> dict[str, float]:
     reads (every dense stack whole, a routed expert stack [L, E, ...] at
     the active share of the experts held), the part that is packed. 1.0
     says every byte of a step is half what int8 holds; a sparse model
-    whose experts are most of a step reads well under a half."""
+    whose experts stay int8 (a mesh) reads well under a half."""
     import jax
 
     from ..ops.quant_matmul import FusedQuantWeight, PackedQuantWeight, QuantWeight
@@ -233,7 +238,7 @@ def program_cost_ceilings(
 
 def roofline_report(
     h: "LlmHeader", weight_format: str, tp: int = 1, pp: int = 1,
-    spec_k: int = 0
+    spec_k: int = 0, experts_packed: bool = False
 ) -> dict:
     """Analytic decode roofline for this model/format/layout: weight-read
     bytes per token per chip (weights shard over tp x pp; dp/sp replicate
@@ -245,7 +250,7 @@ def roofline_report(
     ``dllama_spec_tokens_per_weight_pass`` gauge (floor 1.0 = nothing
     accepted, ceiling ``spec_k + 1`` = every draft accepted)."""
     shards = max(tp, 1) * max(pp, 1)
-    per_chip = weight_bytes_per_token(h, weight_format) // shards
+    per_chip = weight_bytes_per_token(h, weight_format, experts_packed) // shards
     peak = hbm_peak_bytes_per_s()
     rep: dict = {
         "weight_bytes_per_token_per_chip": per_chip,
@@ -266,11 +271,13 @@ def roofline_report(
 
 def print_roofline_report(
     h: "LlmHeader", weight_format: str, tp: int = 1, pp: int = 1,
-    spec_k: int = 0
+    spec_k: int = 0, experts_packed: bool = False
 ) -> dict:
     """Startup roofline printout (rides next to the memory/ICI reports in
     cli.load_engine); returns the report dict it printed."""
-    rep = roofline_report(h, weight_format, tp=tp, pp=pp, spec_k=spec_k)
+    rep = roofline_report(
+        h, weight_format, tp=tp, pp=pp, spec_k=spec_k, experts_packed=experts_packed
+    )
     gb = rep["weight_bytes_per_token_per_chip"] / 1e9
     if rep["hbm_peak_bytes_per_s"]:
         print(

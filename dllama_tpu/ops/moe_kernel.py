@@ -31,6 +31,11 @@ Two variants:
   in-VMEM after the DMA, exactly like ops/quant_matmul._qmm_kernel — the
   reference stores experts Q40 too (src/llm.cpp:425-499) and ships Q40
   slices per expert (src/nn/nn-network.cpp:856-888).
+
+One device that holds a sparse layer whole runs `moe_held_experts_q40`
+(below), in decode blocks and chunks alike, over int8 values or over the
+packed words `--weight-format q40i4` holds there (`PackedQuantWeight`: 0.625
+B a weight, unpacked in VMEM by `quant_matmul.unpack_tile`).
 """
 
 from __future__ import annotations
@@ -41,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .quant_matmul import NIBBLES, PACKED_GROUP, unpack_tile
 
 Q_BLOCK = 32
 
@@ -538,6 +545,7 @@ def _grouped_kernel_q40(
     n_f: int,
     n_steps,  # the grid's steps: static, or a traced scalar (`_held_kernel_q40`)
     rows: int,
+    dequant=_dequant_block,  # `unpack_tile` where the values are packed words
 ):
     g, fi = pl.program_id(0), pl.program_id(1)
     tile = tile_ref[g]
@@ -550,9 +558,9 @@ def _grouped_kernel_q40(
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    w1 = _dequant_block(w1q_ref[0], w1d_ref[0])
-    w3 = _dequant_block(w3q_ref[0], w3d_ref[0])
-    w2 = _dequant_block(w2q_ref[0], w2d_ref[0])
+    w1 = dequant(w1q_ref[0], w1d_ref[0])
+    w3 = dequant(w3q_ref[0], w3d_ref[0])
+    w2 = dequant(w2q_ref[0], w2d_ref[0])
     x = x_ref[:]
     h1 = jax.lax.dot_general(
         x, w1, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -654,6 +662,43 @@ def moe_grouped_experts_q40(
 _HELD_ROWS = 128  # 2048 held pairs of a 512-row chunk over 32 experts: 16 tiles
 
 
+# Packed words: a grid step of three `[2048, 256]` tiles under a 128-row tile
+# took 3.0 us where the int8 step took 2.8 (PR 44's probe,
+# `scripts/moe_packed_probe.py`): once the copy is 0.625 B a weight the step
+# is bound by what it does with the tile's ROWS, all of them against every
+# weight block for the few an expert owns, and by a step's fixed cost. So
+# the packed form takes the rows in tiles a quarter of the call's pairs
+# high, 16 to 64 rows (a decode block's 128 pairs: 32-row tiles, 6.0 us a
+# whole-F step of 4.7 M weights, the MXUs' weight intake; a chunk's 4096:
+# 64), and F in blocks of up to 2 M weights a tile (F = 768 and 1536 at
+# D = 2048 whole or halved, 512 at D = 3072, 256 at D = 7168 and 7680,
+# where 512 measured no better). The int8 form keeps 128 rows and
+# `_pick_f_block`: it is bound by its copy under every tile.
+_PACKED_TILE_WEIGHTS = 1 << 21
+
+
+def _held_f_block(f: int, d: int, packed: bool) -> int:
+    """The held kernel's F block: `_pick_f_block`'s for int8 values, the
+    widest whole-groups divisor of F within `_PACKED_TILE_WEIGHTS` for
+    packed words (F is whole groups of 256: the caller's assertion)."""
+    if not packed:
+        return _pick_f_block(f, d, quantized=True)
+    fits = [
+        b for b in range(PACKED_GROUP, f + 1, PACKED_GROUP)
+        if f % b == 0 and d * b <= _PACKED_TILE_WEIGHTS
+    ]
+    return max(fits, default=PACKED_GROUP)
+
+
+def _held_rows(pairs: int, packed: bool) -> int:
+    """The held kernel's row tile (see above): 128 rows over int8 values; a
+    quarter of the call's pairs over packed words, as a power of two from
+    16 (a bf16 tile's sublanes) to 64."""
+    if not packed:
+        return _HELD_ROWS
+    return min(64, max(16, pl.next_power_of_2(max(pairs, 1)) // 4))
+
+
 def _held_compiler_params(d: int, bf: int):
     """A larger scoped-VMEM limit where the smallest legal F block still
     passes the default 16 MiB: at D = 7680 the three 7680 x 256 tiles, their
@@ -676,11 +721,11 @@ def _with_count(index_map):
     return lambda g, fi, lo, hi, tile, expert, n: index_map(g, fi, lo, hi, tile, expert)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "block_f", "row_tile"))
 def moe_held_experts_q40(
     x: jnp.ndarray,  # [N, D]
-    w1q: jnp.ndarray,  # [E, D, F] int8, E the experts held here
-    w1d: jnp.ndarray,
+    w1q: jnp.ndarray,  # [E, D, F] int8, E the experts held here; or the
+    w1d: jnp.ndarray,  # packed words int32 [E, D // 8, F] of all three
     w2q: jnp.ndarray,
     w2d: jnp.ndarray,
     w3q: jnp.ndarray,
@@ -689,17 +734,28 @@ def moe_held_experts_q40(
     weights: jnp.ndarray,  # [N, k] f32
     layer=0,
     interpret: bool = False,
+    block_f: int | None = None,  # the probe's; served: `_held_f_block`
+    row_tile: int | None = None,  # the probe's; served: `_held_rows`
 ) -> jnp.ndarray:
     """The held experts' part of a layer's routed sum, [N, D] f32: zero for
-    a token none of whose experts is held here."""
+    a token none of whose experts is held here. By the values' type, as
+    `qmatmul` chooses its kernel: int8 values are dequantised by
+    `_dequant_block`; int32 words of eight nibbles (`PackedQuantWeight`: the
+    copy moves 0.625 B a weight where int8 moves 1.125) by `unpack_tile`,
+    into the same bf16 tile bit for bit. Schedule, masks, accumulator and
+    emit are one body's."""
     n, d = x.shape
-    e, _, f = w1q.shape[-3:]
+    e, _, f = w1d.shape[-3:]
+    packed = w1q.dtype == jnp.int32
+    pack = NIBBLES if packed else 1
+    assert not packed or (d % PACKED_GROUP == 0 and f % PACKED_GROUP == 0), (d, f)
+    assert w1q.shape[-2:] == (d // pack, f) and w2q.shape[-2:] == (f // pack, d)
     first, (w1q, w1d, w2q, w2d, w3q, w3d) = _layer_experts(
         layer, w1q, w1d, w2q, w2d, w3q, w3d
     )
-    bf = _pick_f_block(f, d, quantized=True)
+    bf = block_f or _held_f_block(f, d, packed)
     n_f = f // bf
-    r = _HELD_ROWS
+    r = row_tile or _held_rows(held_i.size, packed)
     t_s, w_col, lo, hi, tile, expert = _grouped_schedule(
         held_i, weights, n, e, rows=r
     )
@@ -708,20 +764,24 @@ def moe_held_experts_q40(
     n_pairs = jnp.sum(held_i < e).astype(jnp.int32)
     n_steps = jnp.sum(lo < n_pairs).astype(jnp.int32)
     x_sorted = jnp.take(x, t_s, axis=0).astype(jnp.bfloat16)
+    w13_map, w2_map = _with_count(_grouped_w13_map), _with_count(_grouped_w2_map)
     o_sorted = pl.pallas_call(
-        functools.partial(_held_kernel_q40, n_f=n_f, rows=r),
+        functools.partial(
+            _held_kernel_q40, n_f=n_f, rows=r,
+            dequant=unpack_tile if packed else _dequant_block,
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n_steps, n_f),
             in_specs=[
                 pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
                 pl.BlockSpec((r, 1), _with_count(_grouped_row_map)),
-                pl.BlockSpec((1, d, bf), _with_count(_grouped_w13_map)),
-                pl.BlockSpec((1, d // Q_BLOCK, bf), _with_count(_grouped_w13_map)),
-                pl.BlockSpec((1, d, bf), _with_count(_grouped_w13_map)),
-                pl.BlockSpec((1, d // Q_BLOCK, bf), _with_count(_grouped_w13_map)),
-                pl.BlockSpec((1, bf, d), _with_count(_grouped_w2_map)),
-                pl.BlockSpec((1, bf // Q_BLOCK, d), _with_count(_grouped_w2_map)),
+                pl.BlockSpec((1, d // pack, bf), w13_map),
+                pl.BlockSpec((1, d // Q_BLOCK, bf), w13_map),
+                pl.BlockSpec((1, d // pack, bf), w13_map),
+                pl.BlockSpec((1, d // Q_BLOCK, bf), w13_map),
+                pl.BlockSpec((1, bf // pack, d), w2_map),
+                pl.BlockSpec((1, bf // Q_BLOCK, d), w2_map),
             ],
             out_specs=pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
             scratch_shapes=[pltpu.VMEM((r, d), jnp.float32)],

@@ -262,26 +262,32 @@ def _qmm_kernel(l_ref, x_ref, q_ref, d_ref, o_ref, acc_ref, *, n_k: int):
     _mxu_accumulate(x_ref, w, o_ref, acc_ref, n_k)
 
 
-def _qmm_i4_kernel(l_ref, x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
-    """One (m, block_n) output tile from packed words: the HBM->VMEM copy
-    moves 0.625 B a weight, then each nibble position of a group's 32 word
-    rows is shifted out as one quant block of 32 weight rows, multiplied
-    by its scale row broadcast along sublanes and narrowed, all in whole
-    (8, 128) tiles; the eight blocks side by side are the group's 256
-    rows of the bf16 tile `_qmm_kernel` builds, bit for bit."""
-    qp = qp_ref[:]  # [bk // 8, bn] int32, eight nibbles a word
+def unpack_tile(qp: jnp.ndarray, d: jnp.ndarray) -> jnp.ndarray:
+    """A VMEM tile of packed words int32 [bk // 8, bn] under its scale rows
+    f32 [bk // 32, bn] -> bf16 [bk, bn]: each nibble position of a group's
+    32 word rows is shifted out as one quant block of 32 weight rows,
+    multiplied by its scale row broadcast along sublanes and narrowed, all
+    in whole (8, 128) tiles; the eight blocks side by side are the group's
+    256 rows of the tile the int8 kernels build (`_qmm_kernel`,
+    `moe_kernel._dequant_block`), bit for bit."""
     rows, bn = qp.shape
     groups = rows // Q_BLOCK
     words = qp.reshape(groups, Q_BLOCK, bn)
-    d = d_ref[:].reshape(groups, NIBBLES, bn)  # a group's eight scale rows
+    d = d.reshape(groups, NIBBLES, bn)  # a group's eight scale rows
     pieces = [
         (_nibble(words, j).astype(jnp.float32) * d[:, j : j + 1, :]).astype(
             jnp.bfloat16
         )
         for j in range(NIBBLES)
     ]
-    w = jnp.concatenate(pieces, axis=1).reshape(rows * NIBBLES, bn)
-    _mxu_accumulate(x_ref, w, o_ref, acc_ref, n_k)
+    return jnp.concatenate(pieces, axis=1).reshape(rows * NIBBLES, bn)
+
+
+def _qmm_i4_kernel(l_ref, x_ref, qp_ref, d_ref, o_ref, acc_ref, *, n_k: int):
+    """One (m, block_n) output tile from packed words: the HBM->VMEM copy
+    moves 0.625 B a weight, then `unpack_tile` builds the bf16 tile
+    `_qmm_kernel` builds, bit for bit."""
+    _mxu_accumulate(x_ref, unpack_tile(qp_ref[:], d_ref[:]), o_ref, acc_ref, n_k)
 
 
 def _pick_block(n: int, preferred: int, ragged: bool = False, step: int = 128) -> int:

@@ -392,7 +392,9 @@ class InferenceEngine:
 
         # "auto": keep Q40 weights quantized on device when the Pallas path
         # is available (TPU), packed two nibbles a byte wherever the kernel
-        # takes every dense matmul's in axis (`_packs`; else int8 values);
+        # takes every dense matmul's in axis (`_packs`; else int8 values),
+        # the routed experts with them where `moe_held_experts_q40` reads
+        # them (`_packs_experts`; else int8 values as under "q40");
         # dense bf16/f32 elsewhere (the CPU fallback dequantizes per call,
         # fine for tests, slow for serving).
         if weight_format == "auto":
@@ -413,6 +415,7 @@ class InferenceEngine:
                 f"in dim divisible by {PACKED_GROUP * tp}"
             )
         self.weight_format = weight_format
+        self.experts_packed = weight_format == "q40i4" and self._packs_experts()
         quantized = weight_format in ("q40", "q40i4")
         # Q80-compressed partial-sum all-reduces (the reference's
         # --buffer-float-type q80, src/llm.cpp:195): worthwhile on
@@ -444,6 +447,7 @@ class InferenceEngine:
             put=shard_params_put(self.mesh, self.header),
             # q40i4 packs host-side inside the loader itself
             weight_format=weight_format,
+            pack_experts=self.experts_packed,
             # quantized path: fuse q|k|v (and w1|w3 for dense-FFN archs)
             # into single shard-major-interleaved kernel launches — 7 -> 4
             # Pallas calls per decode layer (~41 us fixed cost each on
@@ -451,8 +455,9 @@ class InferenceEngine:
             fuse=tp if quantized else 0,
         )
         # what was loaded, by the form each leaf is held in, and how much of
-        # a decode step's quantized bytes is packed (1.0 dense; a sparse
-        # model's routed experts stay int8): obs/cost.weight_bytes_by_form
+        # a decode step's quantized bytes is packed (1.0 dense, and sparse
+        # where the experts are packed too; a mesh's routed experts stay
+        # int8): obs/cost.weight_bytes_by_form
         from ..obs.cost import weight_bytes_by_form
 
         self.weight_bytes = weight_bytes_by_form(self.params, self.header)
@@ -776,12 +781,30 @@ class InferenceEngine:
     def _packs(self, tp: int) -> bool:
         """Whether the packed kernel takes every dense matmul of the file:
         each one's in axis whole groups of 256 rows a tp shard (a routed
-        expert stays int8 and `wkv_b` is dequantised, whatever theirs)."""
+        expert is `_packs_experts`' and `wkv_b` is dequantised, whatever
+        theirs)."""
         return all(
             spec.shape[1] % (PACKED_GROUP * tp) == 0
             for spec in self.reader.specs
             if spec.float_type == FloatType.Q40 and len(spec.shape) == 2
             and ".experts." not in spec.name and not spec.name.endswith(".wkv_b")
+        )
+
+    def _packs_experts(self) -> bool:
+        """Whether the routed experts are held packed with the dense
+        matmuls: one device holds every sparse layer whole, so
+        `moe_held_experts_q40` is the kernel that reads them and unpacks a
+        tile in VMEM, and every expert's in axis (D of w1 and w3, F of w2) is
+        whole groups of 256 rows. On a mesh the older expert kernels' shards
+        slice F and read int8 values: there the experts stay int8."""
+        experts = [
+            spec for spec in self.reader.specs
+            if spec.float_type == FloatType.Q40 and ".experts." in spec.name
+        ]
+        return (
+            self.mesh.devices.size == 1
+            and bool(experts)
+            and all(spec.shape[1] % PACKED_GROUP == 0 for spec in experts)
         )
 
     def _fresh_cache(self):

@@ -215,16 +215,25 @@ def test_a_sliced_head_gives_the_references_rows(tmp_path):
     assert np.abs(got - whole[:, :64]).max() < 1e-4
 
 
-def held_kernel_case(n, k, p_held, seed, e=4, live=None):
+def held_kernel_case(n, k, p_held, seed, e=4, live=None, extremes=False):
     """Pairs of `n` rows over `e` held experts, each held with probability
     `p_held` and the sentinel `e` otherwise; rows outside `live` (a slice)
-    carry the sentinel alone, as a parked lane's do."""
+    carry the sentinel alone, as a parked lane's do. D and F are one packed
+    group (256 rows), the least the packed form takes. `extremes`: both
+    ends of a nibble in every block, and negative, zero and f16-subnormal
+    scales among the block scales."""
     rng = np.random.default_rng(seed)
-    n_layers, d, f = 2, 64, 256
+    n_layers, d, f = 2, 256, 256
 
     def stack(i, o):
         q = rng.integers(-8, 8, (n_layers, e, i, o)).astype(np.int8)
         s = ((rng.random((n_layers, e, i // 32, o)) + 0.5) * 0.02).astype(np.float32)
+        if extremes:
+            q[..., 0::32, :], q[..., 1::32, :] = -8, 7
+            s[..., 0, :] *= -1
+            s[..., 1, :] = 0.0
+            s[..., 2, :] = np.float32(np.float16(3e-6))  # an f16 subnormal
+            assert 0 < s[0, 0, 2, 0] < 6.1e-5
         return q, s, (q.astype(np.float32).reshape(n_layers, e, i // 32, 32, o)
                       * s[..., None, :]).reshape(q.shape)
 
@@ -240,6 +249,7 @@ def held_kernel_case(n, k, p_held, seed, e=4, live=None):
     return x, ids, wts, w1, w2, w3
 
 
+@pytest.mark.parametrize("form", ["int8", "packed"])
 @pytest.mark.parametrize("n,k,p_held,e,live", [
     (5, 2, 0.3, 4, None), (300, 4, 0.125, 4, None), (3, 2, 0.0, 4, None),
     (40, 4, 1.0, 4, None),
@@ -249,14 +259,20 @@ def held_kernel_case(n, k, p_held, seed, e=4, live=None):
     (16, 8, 1.0, 128, None), (256, 4, 1.0, 16, slice(64, 128)),
 ], ids=["decode", "prefill", "none-held", "all-held", "all-held-decode-lanes",
         "chunk-one-lane-live"])
-def test_held_experts_kernel_computes_the_pairs_that_landed_here(n, k, p_held, e, live):
+def test_held_experts_kernel_computes_the_pairs_that_landed_here(
+        n, k, p_held, e, live, form):
     """`moe_held_experts_q40` in interpret mode (its grid is as long as the
     steps that hold a real pair) against the sum written out, and against
-    the dense `_moe_ffn` over the same pairs."""
+    the dense `_moe_ffn` over the same pairs. Over packed words, its own
+    row tiles and all, it gives the int8 kernel's output bit for bit: the
+    unpacked tile is `_dequant_block`'s, and a pair's row meets the same
+    dots in the same order whatever the tile's height."""
     from dllama_tpu.ops.jnp_ops import silu
-    from dllama_tpu.ops.moe_kernel import moe_held_experts_q40
+    from dllama_tpu.ops.moe_kernel import _held_rows, moe_held_experts_q40
+    from dllama_tpu.ops.quant_matmul import QuantWeight, pack_nibbles
 
-    x, ids, wts, w1, w2, w3 = held_kernel_case(n, k, p_held, seed=n, e=e, live=live)
+    x, ids, wts, w1, w2, w3 = held_kernel_case(
+        n, k, p_held, seed=n, e=e, live=live, extremes=n == 40)
     if e > 4:
         assert len(np.unique(ids[ids < e])) < (ids < e).sum()  # experts shared by rows
     if live is not None:
@@ -264,9 +280,17 @@ def test_held_experts_kernel_computes_the_pairs_that_landed_here(n, k, p_held, e
         parked[live] = False
         assert (ids[parked] == e).all() and (ids[~parked] < e).all()
     layer = 1
-    got = np.asarray(moe_held_experts_q40(
-        jnp.asarray(x), *(jnp.asarray(a) for w in (w1, w2, w3) for a in w[:2]),
-        jnp.asarray(ids), jnp.asarray(wts), jnp.int32(layer), interpret=True))
+    stacks = [jnp.asarray(a) for w in (w1, w2, w3) for a in w[:2]]
+    call = lambda *ws: np.asarray(moe_held_experts_q40(
+        jnp.asarray(x), *ws, jnp.asarray(ids), jnp.asarray(wts), jnp.int32(layer),
+        interpret=True))
+    got = call(*stacks)
+    if form == "packed":
+        assert _held_rows(n * k, True) < _held_rows(n * k, False)
+        packed = [a for q, d in zip(stacks[::2], stacks[1::2])
+                  for a in pack_nibbles(QuantWeight(q, d))]
+        assert packed[0].dtype == jnp.int32 and packed[0].shape[-2] == 256 // 8
+        np.testing.assert_array_equal(call(*packed), got)
     xb = np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
     want = np.zeros_like(x)
     for t, j in zip(*np.nonzero(ids < e)):

@@ -1204,3 +1204,54 @@ def test_qmatmul_packed_at_the_cells_shapes(one_chip, m, k, n):
     from dllama_tpu.ops.quant_matmul import qmatmul_i4_2d
 
     compiled_text(qmatmul_i4_2d, *packed_args(m, k, n, one_chip))
+
+
+# (D, F, experts held, experts a token, decode lanes) of the five sparse
+# cells: Qwen3-30B-A3B, Trinity-Large, openPangu-Ultra, DeepSeek-V3.2, LFM2
+HELD_SHAPES = [
+    (2048, 768, 128, 8, 16), (3072, 3072, 32, 4, 8), (7680, 2048, 32, 8, 4),
+    (7168, 2048, 32, 8, 4), (2048, 1536, 16, 4, 16),
+]
+
+
+@pytest.mark.parametrize("rows", ["decode", "pairs128", "chunk"])
+@pytest.mark.parametrize("d,f,e,k,lanes", HELD_SHAPES,
+                         ids=[f"{d}x{f}" for d, f, *_ in HELD_SHAPES])
+def test_held_experts_packed_at_the_cells_shapes(one_chip, d, f, e, k, lanes, rows):
+    """`moe_held_experts_q40` over packed expert stacks (int32 words of eight
+    nibbles, `[L, E, in // 8, out]`) at the five served expert shapes: a
+    decode step's pairs, 128 pairs and a 512-row chunk's, each under its own
+    row tile and F block (`_held_rows`, `_held_f_block`), compile inside the
+    kernel's VMEM limit; called by layer number out of the stacks in a scan,
+    no layer's words are sliced or copied out, and the program still names
+    the jitted function the trace's `moe_held_experts_q40.N` is read by."""
+    from jax import lax
+
+    from dllama_tpu.ops import moe_kernel as mk
+
+    n_layers = 4
+    n = {"decode": lanes, "pairs128": 128 // k, "chunk": 512}[rows]
+    assert rows != "pairs128" or n * k == 128
+    bf, r = mk._held_f_block(f, d, True), mk._held_rows(n * k, True)
+    assert f % bf == 0 and bf % 256 == 0 and r in (16, 32, 64)
+    w13 = (sds((n_layers, e, d // 8, f), jnp.int32, one_chip),
+           sds((n_layers, e, d // 32, f), jnp.float32, one_chip))
+    w2 = (sds((n_layers, e, f // 8, d), jnp.int32, one_chip),
+          sds((n_layers, e, f // 32, d), jnp.float32, one_chip))
+
+    def run(x, w1q, w1d, w2q, w2d, w3q, w3d, ii, ww):
+        def step(x, l):
+            y = mk.moe_held_experts_q40(x, w1q, w1d, w2q, w2d, w3q, w3d, ii, ww, l)
+            return x + y.astype(x.dtype), None
+
+        return lax.scan(step, x, jnp.arange(n_layers, dtype=jnp.int32))[0]
+
+    text = compiled_text(
+        jax.jit(run),
+        sds((n, d), jnp.bfloat16, one_chip),
+        *w13, *w2, *w13,
+        sds((n, k), jnp.int32, one_chip),
+        sds((n, k), jnp.float32, one_chip),
+    )
+    assert "moe_held_experts_q40" in text
+    assert not weight_copies(text), weight_copies(text)

@@ -307,9 +307,10 @@ def test_packed_weight_format_tp(tmp_path):
 
 
 def test_packed_weight_format_moe_keeps_int8_experts(tmp_path):
-    """q40i4 on Qwen3-MoE packs the attention/dense weights but leaves the
-    expert stacks in the int8 QuantWeight layout the ragged MoE kernels
-    consume — and still reproduces the q40 greedy tokens."""
+    """q40i4 on a Qwen3-MoE whose expert in axes are no whole groups of 256
+    rows (64 and 96 here) packs the attention/dense weights but leaves the
+    expert stacks in the int8 QuantWeight layout, and still reproduces the
+    q40 greedy tokens."""
     from dllama_tpu.ops.quant_matmul import PackedQuantWeight, QuantWeight
 
     mp = str(tmp_path / "moe4.m")
@@ -319,6 +320,7 @@ def test_packed_weight_format_moe_keeps_int8_experts(tmp_path):
     out_q40, _, _ = e_q40.generate([1, 2, 3, 4], max_steps=12)
     e_i4 = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
                            weight_format="q40i4")
+    assert not e_i4.experts_packed
     w1 = e_i4.params["layers"]["w1"]
     assert isinstance(w1, QuantWeight) and not isinstance(w1, PackedQuantWeight)
     assert w1.q.dtype == jnp.int8 and w1.q.ndim == 4  # [L, E, D, F]
@@ -326,6 +328,71 @@ def test_packed_weight_format_moe_keeps_int8_experts(tmp_path):
     assert isinstance(wo, PackedQuantWeight)
     out_i4, _, _ = e_i4.generate([1, 2, 3, 4], max_steps=12)
     assert out_q40 == out_i4
+
+
+# a sparse model every in axis of which is whole groups of 256 rows, a tp
+# shard of two included
+_PACKABLE_MOE = dict(dim=512, hidden_dim=512, moe_hidden_dim=512, n_layers=2,
+                     n_heads=32, n_kv_heads=4, head_dim=16, vocab_size=256,
+                     seq_len=64, n_experts=4, n_active_experts=2)
+
+
+def test_packed_experts_on_one_device_int8_on_a_mesh(tmp_path):
+    """Where one device holds a sparse layer whole, q40i4 holds the routed
+    experts packed too: the `weights` event's `decode_packed_share` reads
+    0.95 or more (0.0 under q40). On a mesh (tp = 2) the experts stay int8,
+    as under q40, because the mesh's expert kernels read that layout. All
+    three serve the same tokens."""
+    from dllama_tpu.ops.quant_matmul import PackedQuantWeight, QuantWeight
+
+    mp = str(tmp_path / "moe_packable.m")
+    make_tiny_model(mp, arch=LlmArch.QWEN3_MOE, weight_type=FloatType.Q40,
+                    cfg=_PACKABLE_MOE)
+    share, out = {}, {}
+    for name, fmt, tp in (("q40", "q40", 1), ("packed", "q40i4", 1), ("mesh", "q40i4", 2)):
+        e = InferenceEngine(mp, tp=tp, dtype=jnp.float32, temperature=0.0,
+                            weight_format=fmt)
+        (event,) = e.recorder.events("weights")[-1:]
+        assert event["decode_packed_share"] == e.weight_bytes["decode_packed_share"]
+        share[name] = event["decode_packed_share"]
+        w1 = e.params["layers"]["w1"]
+        assert e.experts_packed == (name == "packed")
+        if name == "packed":
+            assert type(w1) is PackedQuantWeight and w1.qp.dtype == jnp.int32
+            assert w1.qp.shape == (2, 4, 512 // 8, 512)  # [L, E, D // 8, F]
+            assert e.weight_bytes["int8"] == 0
+        else:
+            assert type(w1) is QuantWeight and w1.q.dtype == jnp.int8
+        out[name], _, _ = e.generate([1, 2, 3, 4], max_steps=12)
+    assert share["q40"] == 0.0 and share["packed"] >= 0.95
+    assert 0.0 < share["mesh"] < 0.95
+    assert out["q40"] == out["packed"] == out["mesh"]
+
+
+@pytest.mark.parametrize("path", ["streamed", "host-stack"])
+def test_experts_packed_from_the_wire_equal_pack_nibbles_of_int8(
+        tmp_path, monkeypatch, path):
+    """The expert stacks the loader packs straight from the wire's bytes
+    (an expert at a time: `q40_pack_transposed` or its numpy twin) are
+    `pack_nibbles` of the int8 stacks, words and scales, on the streamed
+    shard path and on the host-stack path."""
+    from dllama_tpu.ops.quant_matmul import PackedQuantWeight, pack_nibbles
+
+    mp = str(tmp_path / "moe_wire.m")
+    make_tiny_model(mp, arch=LlmArch.QWEN3_MOE, weight_type=FloatType.Q40,
+                    cfg=_PACKABLE_MOE)
+    if path == "host-stack":
+        monkeypatch.setenv("DLLAMA_STREAM_LOAD", "0")
+    held = {
+        fmt: InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
+                             weight_format=fmt).params["layers"]
+        for fmt in ("q40", "q40i4")
+    }
+    for n in ("w1", "w2", "w3"):
+        got, want = held["q40i4"][n], pack_nibbles(held["q40"][n])
+        assert type(got) is PackedQuantWeight
+        np.testing.assert_array_equal(np.asarray(got.qp), np.asarray(want.qp))
+        np.testing.assert_array_equal(np.asarray(got.d), np.asarray(want.d))
 
 
 def test_packed_streamed_load_matches_host_stack(tmp_path, monkeypatch):
@@ -1938,8 +2005,9 @@ def test_weight_bytes_by_form_add_up(tmp_path, arch, weight_format):
     """The resident-bytes gauges add up to the leaves' bytes, form by form,
     and the packed share of a decode step's quantized bytes is 1.0 for a
     dense model served packed, 0.0 served as int8 values, and between them
-    where routed experts (int8 under both formats, read at the active share
-    of those held) stand beside packed dense matmuls."""
+    where routed experts that stay int8 (their in axes, 64 and 96, are no
+    whole groups of 256; read at the active share of those held) stand
+    beside packed dense matmuls."""
     import jax
 
     from dllama_tpu.obs.metrics import get_registry

@@ -375,17 +375,20 @@ def test_weight_bytes_per_token_formats():
 
 
 @pytest.mark.parametrize(
-    "weight_format,dense_bpw,expert_bpw",
-    [("q40", 1.125, 1.125), ("q40i4", 0.625, 1.125), ("dense", 2.0, 2.0)],
+    "weight_format,experts_packed,dense_bpw,expert_bpw",
+    [("q40", False, 1.125, 1.125), ("q40i4", False, 0.625, 1.125),
+     ("q40i4", True, 0.625, 0.625), ("dense", False, 2.0, 2.0)],
 )
 def test_weight_bytes_per_token_charges_each_leaf_by_its_form(
-        weight_format, dense_bpw, expert_bpw):
-    """Routed experts stay int8 under `q40i4` (models/loader.py): a sparse
-    model's step is charged 0.625 B a weight for attention and the head and
-    1.125 for the active experts, not 0.5625 throughout (ROADMAP D8)."""
+        weight_format, experts_packed, dense_bpw, expert_bpw):
+    """Under `q40i4` the routed experts are charged by the form the engine
+    holds them in: packed where one device holds the layer
+    (`experts_packed`), int8 on a mesh: there a sparse model's step is
+    charged 0.625 B a weight for attention and the head and 1.125 for the
+    active experts, not 0.5625 throughout (ROADMAP D8)."""
     from types import SimpleNamespace
 
-    from dllama_tpu.obs.cost import weight_bytes_per_token
+    from dllama_tpu.obs.cost import roofline_report, weight_bytes_per_token
 
     h = SimpleNamespace(dim=64, q_dim=64, kv_dim=32, ff_dim=32, n_layers=2,
                         vocab_size=96, n_experts=8, n_active_experts=2)
@@ -393,7 +396,9 @@ def test_weight_bytes_per_token_charges_each_leaf_by_its_form(
     experts = 3 * 64 * 32 * 2
     want = (2 * (att * dense_bpw + experts * expert_bpw)
             + 64 * 96 * dense_bpw + 2 * 64 * 8 * 4)
-    assert weight_bytes_per_token(h, weight_format) == int(want)
+    assert weight_bytes_per_token(h, weight_format, experts_packed) == int(want)
+    rep = roofline_report(h, weight_format, experts_packed=experts_packed)
+    assert rep["weight_bytes_per_token_per_chip"] == int(want)
 
 
 def test_roofline_report_degrades_without_tpu():
