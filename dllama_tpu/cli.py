@@ -47,35 +47,32 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                    help="decode lanes: >1 lets the API server stream "
                         "multiple requests concurrently (per-lane "
                         "positions over the dp batch axis)")
-    p.add_argument("--lane-block-size", type=int, default=None,
+    p.add_argument("--lane-block-size", type=int, default=8,
                    dest="lane_block_size", metavar="N",
-                   help="decode tokens per lane-scheduler block (default: "
-                        "env DLLAMA_LANE_BLOCK, else 8) — with "
-                        "--admission-chunk this bounds the worst-case "
-                        "inter-token gap at one chunk + one block")
-    p.add_argument("--kv-page-size", type=int, default=None,
+                   help="decode tokens per lane-scheduler block (default "
+                        "8) — with --admission-chunk this bounds the "
+                        "worst-case inter-token gap at one chunk + one block")
+    p.add_argument("--kv-page-size", type=int, default=0,
                    dest="kv_page_size", metavar="TOKENS",
                    help="paged-KV pool page size for cross-lane prefix "
-                        "sharing on the lane-scheduler path (default: env "
-                        "DLLAMA_KV_PAGE_SIZE, else 16); negative disables "
+                        "sharing on the lane-scheduler path (default 0 = "
+                        "the manager's, 16); negative disables "
                         "the shared pool entirely (no prefix reuse)")
-    p.add_argument("--kv-pool-pages", type=int, default=None,
+    p.add_argument("--kv-pool-pages", type=int, default=0,
                    dest="kv_pool_pages", metavar="N",
-                   help="pages in the shared KV pool (default: env "
-                        "DLLAMA_KV_POOL_PAGES, else auto: two sequences' "
-                        "worth, 2*seqLen/pageSize + 1)")
-    p.add_argument("--kv-native", type=int, default=None,
+                   help="pages in the shared KV pool (default 0 = auto: "
+                        "two sequences' worth, 2*seqLen/pageSize + 1)")
+    p.add_argument("--kv-native", type=int, default=0,
                    dest="kv_native", metavar="0|1",
                    help="pool-native paged decode on the lane path: "
                         "lanes read/write KV through a per-lane page "
                         "table straight into the shared pool, so prefix "
                         "adoption is a refcount bump (zero device-copy "
                         "bytes on page-aligned matches) and publish an "
-                        "ownership transfer (default: env "
-                        "DLLAMA_KV_NATIVE, else 0 = per-lane slab KV "
+                        "ownership transfer (default 0 = per-lane slab KV "
                         "with adopt/publish page copies); requires "
                         "pp=1 and sp=1")
-    p.add_argument("--max-streams", type=int, default=None,
+    p.add_argument("--max-streams", type=int, default=0,
                    dest="max_streams", metavar="N",
                    help="concurrent streams the scheduler may admit, "
                         "oversubscribing the decode lanes: when N > "
@@ -83,18 +80,18 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                         "most-progressed lane parks (KV published to "
                         "the shared pool, page list dropped) and the "
                         "parked stream later resumes via radix "
-                        "re-match (default: env DLLAMA_MAX_STREAMS, "
-                        "else 0 = streams cap at the lane count)")
-    p.add_argument("--admission-chunk", type=int, default=None,
+                        "re-match (default 0 = streams cap at the lane "
+                        "count)")
+    p.add_argument("--admission-chunk", type=int, default=0,
                    dest="admission_chunk", metavar="TOKENS",
                    help="max prompt tokens prefilled per scheduler tick "
-                        "while admitting a request (default: env "
-                        "DLLAMA_ADMISSION_CHUNK, else the largest prefill "
-                        "bucket); smaller = tighter inter-token gaps for "
-                        "active streams, larger = faster TTFT for the "
-                        "incoming prompt")
-    p.add_argument("--speculation", default=None,
-                   choices=("off", "ngram", "shared", "draft"),
+                        "while admitting a request (default 0 = the "
+                        "largest prefill bucket); smaller = tighter "
+                        "inter-token gaps for active streams, larger = "
+                        "faster TTFT for the incoming prompt")
+    from .runtime.spec import DEFAULT_SPEC_K, SPEC_MODES
+
+    p.add_argument("--speculation", default="off", choices=SPEC_MODES,
                    help="speculative decoding on the lane path: 'ngram' "
                         "drafts each greedy lane's continuation from its "
                         "own context (prompt lookup) and verifies k tokens "
@@ -106,14 +103,14 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                         "additionally runs a resident draft model "
                         "(--draft-model) when both n-gram sources run "
                         "dry; temperature>0 lanes fall back to the "
-                        "normal decode block per lane (default: env "
-                        "DLLAMA_SPECULATION, else off = pure bypass)")
-    p.add_argument("--spec-k", type=int, default=None,
+                        "normal decode block per lane (default off = "
+                        "pure bypass)")
+    p.add_argument("--spec-k", type=int, default=DEFAULT_SPEC_K,
                    dest="spec_k", metavar="K",
                    help="max draft tokens per speculative verify dispatch "
                         "(compiled shapes are power-of-2 bucketed; each "
                         "lane's drafter adapts below this on low "
-                        "acceptance; default: env DLLAMA_SPEC_K, else 4)")
+                        f"acceptance; default {DEFAULT_SPEC_K})")
     p.add_argument("--draft-model", default=None, dest="draft_model",
                    metavar="PATH",
                    help="tiny same-tokenizer checkpoint loaded as the "
@@ -121,8 +118,7 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                         "runs k cheap greedy steps through its own "
                         "AOT-compiled draft_step program and its own KV "
                         "cache; every draft is verified by the target, so "
-                        "output stays token-exact (default: env "
-                        "DLLAMA_DRAFT_MODEL)")
+                        "output stays token-exact")
     p.add_argument("--tp", type=int, default=0, help="tensor-parallel chips (default: all)")
     p.add_argument("--sp", type=int, default=1,
                    help="sequence-parallel chips: shard the KV cache's "
@@ -151,16 +147,15 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
     p.add_argument("--gpu-index", type=int, default=None)
     p.add_argument("--gpu-segments", default=None)
     p.add_argument("--weight-format", default="auto",
-                   choices=["auto", "q40", "q40i4", "dense"],
-                   help="q40 keeps weights block-quantized on device as "
-                        "int8 values (Pallas kernel, 1.125 B/weight); q40i4 "
-                        "keeps the nibbles packed (0.625 B/weight, unpacked "
-                        "in the kernel: the dense matmuls, and the routed "
-                        "experts where one device holds a sparse layer whole; "
-                        "on a mesh they stay int8); auto = q40i4 on a TPU "
-                        "with a Q40 file "
-                        "(q40 where a matmul's in dim is no multiple of "
-                        "256 a shard), dense elsewhere")
+                   choices=["auto", "q40", "dense"],
+                   help="auto = on a TPU with a Q40 file the weights stay "
+                        "quantized on device, the nibbles packed (0.625 "
+                        "B/weight, unpacked in the kernel) wherever every "
+                        "dense matmul's in dim is a multiple of 256 a shard "
+                        "(the routed experts with them where one device "
+                        "holds a sparse layer whole), int8 values elsewhere; "
+                        "dense off a TPU. q40 keeps int8 values (1.125 "
+                        "B/weight); dense dequantizes at load")
     p.add_argument("--profile", default=None, metavar="DIR",
                    help="write a jax.profiler trace of the run to DIR")
     p.add_argument("--trace-out", default=None, metavar="PATH",
@@ -179,17 +174,17 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                         "CLI writes it once at exit)")
     p.add_argument("--slo-ttft-ms", type=float, default=None,
                    help="TTFT SLO target in ms for the windowed "
-                        "attainment/goodput gauges (obs/slo.py; env "
-                        "DLLAMA_SLO_TTFT_MS; unset = no target)")
+                        "attainment/goodput gauges (obs/slo.py; unset = "
+                        "no target)")
     p.add_argument("--slo-tpot-ms", type=float, default=None,
                    help="mean-TPOT SLO target in ms for the windowed "
-                        "attainment/goodput gauges (obs/slo.py; env "
-                        "DLLAMA_SLO_TPOT_MS; unset = no target)")
-    p.add_argument("--series-retention", type=float, default=None,
+                        "attainment/goodput gauges (obs/slo.py; unset = "
+                        "no target)")
+    p.add_argument("--series-retention", type=float, default=3600.0,
                    metavar="SECONDS",
                    help="in-process metrics time-series retention in "
-                        "seconds (obs/timeseries.py; default 3600; env "
-                        "DLLAMA_SERIES_RETENTION_S, sampling interval via "
+                        "seconds (obs/timeseries.py; default 3600; the "
+                        "sampling interval is env "
                         "DLLAMA_SERIES_INTERVAL_S; serves /v1/debug/series "
                         "and the /dashboard sparklines)")
     p.add_argument("--moe-decode-dedup", default="auto", nargs="?",
@@ -214,41 +209,35 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                         "schedule, e.g. 'dispatch:p=0.05:seed=7,"
                         "kv_alloc:nth=12' (runtime/faults.py; env "
                         "DLLAMA_FAULTS; docs/resilience.md)")
-    p.add_argument("--retry-max", type=int, default=None,
+    p.add_argument("--retry-max", type=int, default=3,
                    help="transient-dispatch retries before failing the "
                         "request (scheduler backoff loop; default 3; "
-                        "0 disables; env DLLAMA_RETRY_MAX)")
-    p.add_argument("--retry-backoff-ms", type=int, default=None,
+                        "0 disables)")
+    p.add_argument("--retry-backoff-ms", type=int, default=5,
                    help="base backoff in ms between dispatch retries, "
-                        "doubling per attempt (default 5; env "
-                        "DLLAMA_RETRY_BACKOFF_MS)")
-    p.add_argument("--max-queue-depth", type=int, default=None,
+                        "doubling per attempt (default 5)")
+    p.add_argument("--max-queue-depth", type=int, default=0,
                    help="shed (429 + Retry-After) once this many requests "
                         "wait for a lane; priority 'low' sheds at half "
-                        "this, 'high' at double (default 0 = unbounded; "
-                        "env DLLAMA_MAX_QUEUE_DEPTH)")
-    p.add_argument("--admission-predict", action="store_true", default=None,
+                        "this, 'high' at double (default 0 = unbounded)")
+    p.add_argument("--admission-predict", action="store_true",
                    help="predictive admission control: estimate TTFT/TPOT "
                         "per request from the cost model + occupancy, "
                         "reject-or-queue infeasible deadline-hinted work "
                         "before admitting it, and order admission EDF-style "
-                        "(runtime/admission.py; env "
-                        "DLLAMA_ADMISSION_PREDICT; default off)")
-    p.add_argument("--admission-max-wait-ms", type=int, default=None,
+                        "(runtime/admission.py; default off)")
+    p.add_argument("--admission-max-wait-ms", type=int, default=30_000,
                    help="cap on the predicted queue-drain time advertised "
-                        "via Retry-After on shed responses (default 30000; "
-                        "env DLLAMA_ADMISSION_MAX_WAIT_MS)")
-    p.add_argument("--deadline-default-ms", type=int, default=None,
+                        "via Retry-After on shed responses (default 30000)")
+    p.add_argument("--deadline-default-ms", type=int, default=600_000,
                    help="effective deadline assigned to requests with no "
                         "deadline_ms/ttft_budget_ms hint, anchoring the "
-                        "EDF admission order (default 600000; env "
-                        "DLLAMA_DEADLINE_DEFAULT_MS)")
-    p.add_argument("--deadline-priority-step-ms", type=int, default=None,
+                        "EDF admission order (default 600000)")
+    p.add_argument("--deadline-priority-step-ms", type=int, default=60_000,
                    help="deadline offset per priority rung for unhinted "
                         "requests: high = -1 step, low = +1 step, so the "
                         "PR 12 priority ladder survives as EDF offsets "
-                        "(default 60000; env "
-                        "DLLAMA_DEADLINE_PRIORITY_STEP_MS)")
+                        "(default 60000)")
     p.add_argument("--sync-measure", default="auto", choices=["auto", "off"],
                    help="measure per-step collective time via a short "
                    "profiled re-run (multi-device greedy runs only; 'off' "
@@ -379,15 +368,13 @@ def load_engine(args):
     from .obs.device import compare_with_analytic, sample_device_memory
     from .obs.recorder import get_recorder
 
-    from .runtime.spec import resolve_spec_knobs
+    from .models.loader import weight_forms
 
-    spec_mode, spec_k_val = resolve_spec_knobs(
-        getattr(args, "speculation", None), getattr(args, "spec_k", None)
-    )
     print_roofline_report(
-        h, engine.weight_format, tp=tp, pp=pp,
-        spec_k=spec_k_val if spec_mode != "off" else 0,
-        experts_packed=engine.experts_packed,
+        h,
+        weight_forms(engine.reader.specs, engine.weight_format, engine.mesh.devices.size),
+        tp=tp, pp=pp,
+        spec_k=max(1, args.spec_k) if args.speculation != "off" else 0,
     )
     # live per-chip memory vs the analytic figure: a >10% gap logs a
     # warning (leak / unplanned replication / stale analytic model)

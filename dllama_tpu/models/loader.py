@@ -32,6 +32,7 @@ from ..ops.quant_matmul import (
     FusedQuantWeight,
     PackedQuantWeight,
     QuantWeight,
+    packed_kernels_take,
     planar_to_device_layout,
 )
 from ..utils import native
@@ -220,7 +221,7 @@ def _stream_quant_stack(
         # planes in place: a pairs list + np.stack would hold TWO copies of
         # the shard at once — several GB of transient for a 70B w13 tp shard
         sub_inner = (b1 - b0) * 32
-        if packed and sub_inner != inner and sub_inner % PACKED_GROUP:
+        if packed and sub_inner != inner and not packed_kernels_take(sub_inner):
             raise ValueError(
                 f"{tag}: a packed shard's in slice [{i0},{i1}) is not whole "
                 f"groups of {PACKED_GROUP} rows"
@@ -254,13 +255,51 @@ def _stream_quant_stack(
     return cls(q_arr, d_arr), tuple(douts)
 
 
+def _q40_in_dims(specs, routed: bool) -> list[int]:
+    """The in axes of a file's Q40 matrices: the routed experts', or every
+    other's but `wkv_b`'s, which is dequantised whatever its width."""
+    return [
+        spec.shape[1] for spec in specs
+        if spec.float_type == FloatType.Q40 and len(spec.shape) == 2
+        and (".experts." in spec.name) == routed
+        and not spec.name.endswith(".wkv_b")
+    ]
+
+
+def packs_dense(specs, tp: int = 1) -> bool:
+    """Whether the dense matmuls of a file are held packed on `tp` shards:
+    all or none, so every one's in axis has to be whole groups of 256 rows
+    a shard (what "auto" asks on a TPU; else int8 values)."""
+    return all(packed_kernels_take(k, tp) for k in _q40_in_dims(specs, False))
+
+
+def packs_experts(specs, devices: int = 1) -> bool:
+    """Whether the routed experts are held packed with the dense matmuls:
+    one device holds every sparse layer whole, so `moe_held_experts_q40` is
+    the kernel that reads them and unpacks a tile in VMEM, and every
+    expert's in axis (D of w1 and w3, F of w2) is whole groups of 256 rows.
+    On a mesh the older expert kernels' shards slice F and read int8
+    values: there the experts stay int8."""
+    experts = _q40_in_dims(specs, True)
+    return devices == 1 and bool(experts) and all(map(packed_kernels_take, experts))
+
+
+def weight_forms(specs, weight_format: str, devices: int = 1) -> tuple[str, str]:
+    """The forms `load_params` holds a file's (dense matmuls, routed
+    experts) in under a resolved `weight_format`, in the names of
+    obs/cost.weight_bytes_by_form: `float`, `int8` or `packed`."""
+    if weight_format == "q40i4":
+        return "packed", "packed" if packs_experts(specs, devices) else "int8"
+    form = "int8" if weight_format == "q40" else "float"
+    return form, form
+
+
 def load_params(
     reader: ModelReader,
     dtype=jnp.float32,
     put: PutFn = _default_put,
     weight_format: str = "dense",
     fuse: int = 0,
-    pack_experts: bool = False,
 ) -> Params:
     """Materialize the params pytree from a `.m` file.
 
@@ -279,11 +318,11 @@ def load_params(
     `weight_format="q40i4"` instead lays the matmul weights out in the
     nibble device format (`PackedQuantWeight`: eight int4 values per int32
     word + f32 scales, 0.625 B/weight) straight from the wire's bytes; the
-    Pallas kernel unpacks in VMEM after the HBM copy. With `pack_experts`
-    the routed expert stacks [L, E, in, out] are laid out the same way
-    (the engine asks for it where one device holds a sparse layer whole:
-    `moe_held_experts_q40` unpacks them as `qmatmul` does); without it they
-    stay int8 `QuantWeight`, which the mesh's expert kernels consume.
+    Pallas kernel unpacks in VMEM after the HBM copy. The routed expert
+    stacks [L, E, in, out] are laid out the same way where `packs_experts`
+    says so, over the devices of `put`'s mesh (one, for a put that names no
+    sharding); else they stay int8 `QuantWeight`, which the mesh's expert
+    kernels consume.
 
     `fuse` (quantized path only): the tp shard count; > 0 emits fused
     "wqkv" (q|k|v) and, for dense-FFN archs, "w13" (w1|w3) weights in
@@ -295,7 +334,12 @@ def load_params(
     """
     h = reader.header
     quantize = weight_format in ("q40", "q40i4")
-    packed = weight_format == "q40i4"
+    sharding = getattr(put, "sharding", None)
+    dense_form, expert_form = weight_forms(
+        reader.specs, weight_format,
+        devices=sharding("w1").mesh.devices.size if sharding else 1,
+    )
+    packed, pack_experts = dense_form == "packed", expert_form == "packed"
     if quantize and h.weight_type != FloatType.Q40:
         raise ValueError(
             f"weight_format={weight_format!r} needs a Q40 model file, got "
@@ -307,7 +351,7 @@ def load_params(
     # streamed path is tested against).
     streaming = (
         quantize
-        and getattr(put, "sharding", None) is not None
+        and sharding is not None
         and os.environ.get("DLLAMA_STREAM_LOAD", "1") != "0"
     )
 
@@ -513,10 +557,8 @@ def load_params(
             # src/nn/nn-network.cpp:856-888); the ragged MoE kernel
             # dequantizes selected blocks in VMEM. Layout per expert is the
             # same [in, out] device layout as the dense matmuls, stacked
-            # [L, E, ...]: int8 values, or with `pack_experts` the packed
+            # [L, E, ...]: int8 values, or where `packs_experts` the packed
             # words, written straight from the wire an expert at a time.
-            if pack_experts and not packed:
-                raise ValueError('pack_experts needs weight_format="q40i4"')
 
             def qexperts(tag: str, which: str):
                 if streaming:

@@ -7,15 +7,17 @@ are identical to models/loader.load_params output.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..formats.model_file import HiddenAct, LlmArch, LlmHeader, RopeType
+from ..formats.model_file import HiddenAct, LlmArch, LlmHeader, RopeType, tensor_plan
 from ..formats.quants import FloatType
 from ..ops.jnp_ops import rope_cache
+from .loader import weight_forms
 from .transformer import Params
 
 # Real-model shape presets (from the reference's supported model zoo,
@@ -107,7 +109,6 @@ def write_synth_model(
     an 8B file takes under a minute. Real checkpoints stay the parity
     oracle for the converter.
     Returns the LlmHeader describing the file."""
-    from ..formats.model_file import tensor_plan
     from ..formats.quants import Q40_BLOCK_BYTES, Q40_BLOCK_SIZE
     from ..formats.writer import write_header
 
@@ -300,17 +301,12 @@ def random_params(
     E = h.n_experts
 
     quant = weight_format in ("q40", "q40i4")
-    packed = weight_format == "q40i4"
-    # the engine's policy (`_packs_experts`): routed experts are packed with
-    # the dense matmuls where one device holds the layer and both of their
-    # in axes are whole groups of 256; on a mesh they stay int8 QuantWeight
-    # (the ragged kernels consume that layout)
-    from ..ops.quant_matmul import PACKED_GROUP
-
-    pack_experts = (
-        packed and (mesh is None or mesh.devices.size == 1)
-        and D % PACKED_GROUP == 0 and FF % PACKED_GROUP == 0
+    # the forms the loader would hold a Q40 file of this header in
+    dense_form, expert_form = weight_forms(
+        tensor_plan(dataclasses.replace(h, weight_type=FloatType.Q40)),
+        weight_format, devices=1 if mesh is None else mesh.devices.size,
     )
+    packed, pack_experts = dense_form == "packed", expert_form == "packed"
     if quant:
         def mm(name, *shape, expert=False):
             return mk_quant(name, *shape, packed=pack_experts if expert else packed)

@@ -45,9 +45,9 @@ from ..ops.quant_matmul import (
 )
 
 # both Q40 device formats ride the same qmatmul dispatch (the packed
-# variant unpacks nibbles in VMEM); MoE expert leaves are packed only for
-# `moe_held_experts_q40` (one device holds the layer), so the mesh's expert
-# kernels below test for plain QuantWeight alone
+# variant unpacks nibbles in VMEM); of the expert kernels only
+# `moe_held_experts_q40` reads packed leaves, so the mesh's expert kernels
+# below test for plain QuantWeight alone
 _QUANT_CLASSES = (QuantWeight, PackedQuantWeight)
 from ..ops.flash_attention import (
     flash_attention,
@@ -1610,9 +1610,8 @@ def run_layers(
         b, t = y.shape[0], y.shape[1]
 
         _w1 = lp["w1"]
-        # packed words are the held kernel's alone (the engine packs the
-        # experts only where one device holds the layer); anything else
-        # that meets them takes the dense path below
+        # packed words are the held kernel's alone; anything else that
+        # meets them takes the dense path below
         _packed = isinstance(_w1, PackedQuantWeight)
         _quantized = isinstance(_w1, _QUANT_CLASSES)
         _itemsize = 1 if _quantized else _w1.dtype.itemsize

@@ -61,7 +61,7 @@ from .metrics import (
     get_registry,
 )
 from .recorder import FlightRecorder, get_recorder
-from .slo import SloTracker, resolve_slo_knobs
+from .slo import SloTracker
 from .spans import SpanTracker, get_span_tracker
 from .timeseries import MetricsSampler, SeriesStore, resolve_series_knobs
 from .trace import NULL_SPAN, RequestSpan, Tracer
@@ -89,7 +89,6 @@ __all__ = [
     "SpanTracker",
     "get_span_tracker",
     "SloTracker",
-    "resolve_slo_knobs",
     "EngineWatchdog",
     "resolve_watchdog_knobs",
     "SeriesStore",

@@ -110,25 +110,18 @@ def analytic_step_seconds(
 
 # bytes a weight, scales included, by the form a leaf is held in
 # (ops/quant_matmul: eight nibbles an int32 word, or int8 values; an f32
-# scale per 32 block either way)
-PACKED_BYTES_PER_WEIGHT = 0.5 + 4.0 / 32.0
-INT8_BYTES_PER_WEIGHT = 1.0 + 4.0 / 32.0
+# scale per 32 block either way; a float weight as bf16)
+BYTES_PER_WEIGHT = {"packed": 0.5 + 4.0 / 32.0, "int8": 1.0 + 4.0 / 32.0, "float": 2.0}
 
 
-def weight_bytes_per_token(
-    h: "LlmHeader", weight_format: str, experts_packed: bool = False
-) -> int:
+def weight_bytes_per_token(h: "LlmHeader", forms: tuple[str, str]) -> int:
     """HBM bytes of weights a single decode step must read: every matmul
     weight once (MoE: attention weights + the active experts' share), each
-    charged by the form the loader holds it in: under `q40i4` the dense
-    matmuls are packed nibbles (0.625 B/weight), and the routed experts
-    with them where the engine holds them so (`experts_packed`: one device
-    holds the layer), else int8 as under `q40` (1.125); dense bf16 = 2
-    B/weight."""
-    quantized = weight_format in ("q40", "q40i4")
-    int8_bpw = INT8_BYTES_PER_WEIGHT if quantized else 2.0
-    dense_bpw = PACKED_BYTES_PER_WEIGHT if weight_format == "q40i4" else int8_bpw
-    expert_bpw = dense_bpw if experts_packed else int8_bpw
+    charged by the form it is held in. `forms` is what
+    models/loader.weight_forms returns: the form of the dense matmuls and
+    that of the routed experts, each `packed` (nibbles, 0.625 B/weight),
+    `int8` (1.125) or `float` (bf16, 2)."""
+    dense_bpw, expert_bpw = (BYTES_PER_WEIGHT[form] for form in forms)
     att = h.dim * h.q_dim + 2 * h.dim * h.kv_dim + h.q_dim * h.dim
     ffn = 3 * h.dim * h.ff_dim
     ffn_bpw = dense_bpw
@@ -237,10 +230,10 @@ def program_cost_ceilings(
 
 
 def roofline_report(
-    h: "LlmHeader", weight_format: str, tp: int = 1, pp: int = 1,
-    spec_k: int = 0, experts_packed: bool = False
+    h: "LlmHeader", forms: tuple[str, str], tp: int = 1, pp: int = 1,
+    spec_k: int = 0
 ) -> dict:
-    """Analytic decode roofline for this model/format/layout: weight-read
+    """Analytic decode roofline for this model/forms/layout: weight-read
     bytes per token per chip (weights shard over tp x pp; dp/sp replicate
     them, each replica reading its own copy) and, when the backend's HBM
     peak is known, the ms/token floor + tok/s ceiling. With speculation
@@ -250,7 +243,7 @@ def roofline_report(
     ``dllama_spec_tokens_per_weight_pass`` gauge (floor 1.0 = nothing
     accepted, ceiling ``spec_k + 1`` = every draft accepted)."""
     shards = max(tp, 1) * max(pp, 1)
-    per_chip = weight_bytes_per_token(h, weight_format, experts_packed) // shards
+    per_chip = weight_bytes_per_token(h, forms) // shards
     peak = hbm_peak_bytes_per_s()
     rep: dict = {
         "weight_bytes_per_token_per_chip": per_chip,
@@ -270,14 +263,12 @@ def roofline_report(
 
 
 def print_roofline_report(
-    h: "LlmHeader", weight_format: str, tp: int = 1, pp: int = 1,
-    spec_k: int = 0, experts_packed: bool = False
+    h: "LlmHeader", forms: tuple[str, str], tp: int = 1, pp: int = 1,
+    spec_k: int = 0
 ) -> dict:
     """Startup roofline printout (rides next to the memory/ICI reports in
     cli.load_engine); returns the report dict it printed."""
-    rep = roofline_report(
-        h, weight_format, tp=tp, pp=pp, spec_k=spec_k, experts_packed=experts_packed
-    )
+    rep = roofline_report(h, forms, tp=tp, pp=pp, spec_k=spec_k)
     gb = rep["weight_bytes_per_token_per_chip"] / 1e9
     if rep["hbm_peak_bytes_per_s"]:
         print(

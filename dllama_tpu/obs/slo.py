@@ -29,7 +29,6 @@ capacity under extreme rates, which errs toward recency.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 
@@ -46,24 +45,6 @@ WINDOWS: tuple[tuple[float, str], ...] = (
 # (fleet/obs.py scrapes it per replica and sums the 1m window) don't
 # hardcode a string that must match the registration below
 GOODPUT_METRIC = "dllama_slo_goodput_tokens_per_s"
-
-
-def _env_float(name: str) -> float | None:
-    v = os.environ.get(name, "")
-    return float(v) if v else None
-
-
-def resolve_slo_knobs(
-    ttft_ms: float | None = None, tpot_ms: float | None = None
-) -> tuple[float | None, float | None]:
-    """SLO target resolution, same precedence as the lane knobs: explicit
-    (CLI flag) beats env (DLLAMA_SLO_TTFT_MS / DLLAMA_SLO_TPOT_MS) beats
-    the default (no target; attainment is then vacuously 1.0)."""
-    if ttft_ms is None:
-        ttft_ms = _env_float("DLLAMA_SLO_TTFT_MS")
-    if tpot_ms is None:
-        tpot_ms = _env_float("DLLAMA_SLO_TPOT_MS")
-    return ttft_ms, tpot_ms
 
 
 class SloTracker:

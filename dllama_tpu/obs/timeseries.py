@@ -26,8 +26,8 @@ process, with zero dependencies:
 
 Surfaced by ``GET /v1/debug/series?name=&window=`` and the live
 ``GET /dashboard`` sparklines (obs/dashboard.py). Knobs:
-``--series-retention`` / ``DLLAMA_SERIES_RETENTION_S``,
-``DLLAMA_SERIES_INTERVAL_S``, ``DLLAMA_SERIES_MAX``.
+``--series-retention``, ``DLLAMA_SERIES_INTERVAL_S``,
+``DLLAMA_SERIES_MAX``.
 """
 
 from __future__ import annotations
@@ -52,23 +52,10 @@ def _env_float(name: str, default: float) -> float:
     return float(v) if v else default
 
 
-def _env_int(name: str, default: int) -> int:
-    v = os.environ.get(name, "")
-    return int(v) if v else default
-
-
-def resolve_series_knobs(
-    retention_s: float | None = None, interval_s: float | None = None
-) -> tuple[float, float]:
-    """Time-series knob resolution, same precedence as the lane knobs:
-    explicit (CLI ``--series-retention``) beats env
-    (DLLAMA_SERIES_RETENTION_S / DLLAMA_SERIES_INTERVAL_S) beats the
-    defaults (1 h retention, 1 s sampling)."""
-    if retention_s is None:
-        retention_s = _env_float("DLLAMA_SERIES_RETENTION_S", 3600.0)
-    if interval_s is None:
-        interval_s = _env_float("DLLAMA_SERIES_INTERVAL_S", 1.0)
-    return float(retention_s), float(interval_s)
+def resolve_series_knobs() -> float:
+    """The sampling interval in seconds: env DLLAMA_SERIES_INTERVAL_S, else
+    1 s. It has no flag; the retention is ``--series-retention``'s."""
+    return _env_float("DLLAMA_SERIES_INTERVAL_S", 1.0)
 
 
 class _Series:

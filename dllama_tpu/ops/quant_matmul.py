@@ -66,12 +66,19 @@ NIBBLES = 8
 PACKED_GROUP = NIBBLES * Q_BLOCK
 
 
+def packed_kernels_take(in_dim: int, shards: int = 1) -> bool:
+    """Whether the packed kernels take an in axis split over `shards`: each
+    shard's slice is whole groups of 256 rows. The one statement of it;
+    models/loader decides from it which tensors of a file are held packed."""
+    return in_dim % (PACKED_GROUP * shards) == 0
+
+
 def packed_segment(in_dim: int) -> int:
     """Weight rows a nibble position covers inside one group of a packed
     tensor: a quant block (32) where the in axis is whole groups of 8 x 32
     = 256 rows, which every served width is and the kernel asks for; else
     the axis is one group of eight equal segments (tiny test models)."""
-    return Q_BLOCK if in_dim % PACKED_GROUP == 0 else in_dim // NIBBLES
+    return Q_BLOCK if packed_kernels_take(in_dim) else in_dim // NIBBLES
 
 
 class PackedQuantWeight(NamedTuple):
@@ -448,7 +455,7 @@ def qmatmul_i4_2d(
     wide and the int8 kernel's 205; PR 43's chip runs); under a chunk's
     512-row blocks a tile that wide passes the 16 MB of scoped VMEM."""
     assert qp.dtype == jnp.int32 and d.dtype == jnp.float32, (qp.dtype, d.dtype)
-    assert x.shape[-1] % PACKED_GROUP == 0, x.shape
+    assert packed_kernels_take(x.shape[-1]), x.shape
     if block_n is None:
         block_n = 512 if x.shape[0] <= DECODE_ROWS else 256
     return _qmm_call(
@@ -472,7 +479,7 @@ def qmatmul(x: jnp.ndarray, w, layer=None) -> jnp.ndarray:
     *lead, k = x.shape
     packed = isinstance(w, PackedQuantWeight)
     # a packed axis that is not whole groups of 256 is no served width
-    if not _use_pallas() or (packed and k % PACKED_GROUP):
+    if not _use_pallas() or (packed and not packed_kernels_take(k)):
         return qmatmul_ref(x, w, layer)
     m = 1
     for s in lead:

@@ -52,7 +52,6 @@ is byte-identical to predictive-off runs by construction.
 from __future__ import annotations
 
 import math
-import os
 import threading
 import time
 
@@ -68,64 +67,6 @@ PRIORITY_OFFSET_MULT = {"high": -1.0, "normal": 0.0, "low": 1.0}
 # EWMA correction clamp: a single wild observation (compile stall, GC
 # pause) must not swing the predictor by more than this factor per side
 _CORR_MIN, _CORR_MAX = 0.1, 10.0
-
-
-def _env_int(name: str, default: int) -> int:
-    v = os.environ.get(name, "")
-    return int(v) if v else default
-
-
-def _env_bool(name: str, default: bool = False) -> bool:
-    v = os.environ.get(name, "").strip().lower()
-    if not v:
-        return default
-    return v not in ("0", "off", "false", "no")
-
-
-def resolve_admission_knobs(
-    predict: bool | None = None,
-    max_wait_ms: int | None = None,
-) -> tuple[bool, int]:
-    """Predictive-admission knob resolution, same precedence as the lane
-    knobs: explicit (CLI flag) beats env beats default.
-
-    * ``DLLAMA_ADMISSION_PREDICT`` — enable the predictive controller
-      (infeasible-reject, EDF ordering, deadline preemption); default
-      off = pure PR 12 reactive ladder.
-    * ``DLLAMA_ADMISSION_MAX_WAIT_MS`` — cap on the predicted queue
-      wait a hint-less request may be quoted in ``Retry-After``
-      (default 30000; also the ceiling for the drain estimate so one
-      absurd forecast cannot quote an hour).
-    """
-    if predict is None:
-        predict = _env_bool("DLLAMA_ADMISSION_PREDICT")
-    if max_wait_ms is None:
-        max_wait_ms = _env_int("DLLAMA_ADMISSION_MAX_WAIT_MS", 30_000)
-    return bool(predict), int(max_wait_ms)
-
-
-def resolve_deadline_knobs(
-    default_ms: int | None = None,
-    priority_step_ms: int | None = None,
-) -> tuple[int, int]:
-    """Deadline-synthesis knobs for requests with no hints.
-
-    * ``DLLAMA_DEADLINE_DEFAULT_MS`` — synthetic deadline horizon for
-      unhinted requests (default 600000 = 10 min: effectively "no
-      deadline" for feasibility, but it anchors EDF ordering).
-    * ``DLLAMA_DEADLINE_PRIORITY_STEP_MS`` — the offset between
-      priority rungs (default 60000): ``high`` runs one step earlier
-      than ``normal``, ``low`` one step later, so strict priority
-      ordering is preserved for any queue that drains inside a step
-      while a long-starved ``low`` request still ages into service.
-    """
-    if default_ms is None:
-        default_ms = _env_int("DLLAMA_DEADLINE_DEFAULT_MS", 600_000)
-    if priority_step_ms is None:
-        priority_step_ms = _env_int(
-            "DLLAMA_DEADLINE_PRIORITY_STEP_MS", 60_000
-        )
-    return int(default_ms), int(priority_step_ms)
 
 
 def effective_deadline_ms(

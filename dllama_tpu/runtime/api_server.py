@@ -63,7 +63,7 @@ from ..obs.dashboard import DASHBOARD_CONTENT_TYPE, render_dashboard
 from ..obs.device import compare_with_analytic, sample_device_memory
 from ..obs.metrics import DEFAULT_TOKEN_BUCKETS_S, get_registry
 from ..obs.recorder import get_recorder
-from ..obs.slo import SloTracker, resolve_slo_knobs
+from ..obs.slo import SloTracker
 from ..obs.spans import (
     get_span_tracker,
     profiler_collecting,
@@ -89,19 +89,16 @@ from .admission import (
     LoadPredictor,
     OccupancySnapshot,
     effective_deadline_ms,
-    resolve_admission_knobs,
-    resolve_deadline_knobs,
 )
 from .engine import InferenceEngine
 from .faults import get_fault_plane, set_fault_plane
 from .spec import (
     DEFAULT_SPEC_K,
     SOURCE_DRAFT,
+    SPEC_MODES,
     NgramDrafter,
     SharedNgramStore,
     bucket_for,
-    resolve_draft_model,
-    resolve_spec_knobs,
     spec_buckets,
 )
 
@@ -320,86 +317,6 @@ class _BlockInFlight:
     states: list
 
 
-def _env_int(name: str, default: int) -> int:
-    import os
-
-    v = os.environ.get(name, "")
-    return int(v) if v else default
-
-
-def resolve_lane_knobs(
-    lane_block_size: int | None = None, admission_chunk: int | None = None
-) -> tuple[int, int]:
-    """Scheduler knob resolution: explicit value (CLI flag) beats the env
-    override (DLLAMA_LANE_BLOCK / DLLAMA_ADMISSION_CHUNK) beats the
-    default (block 8; admission chunk 0 = auto, the engine's largest
-    prefill bucket)."""
-    if lane_block_size is None:
-        lane_block_size = _env_int("DLLAMA_LANE_BLOCK", 8)
-    if admission_chunk is None:
-        admission_chunk = _env_int("DLLAMA_ADMISSION_CHUNK", 0)
-    return int(lane_block_size), int(admission_chunk)
-
-
-def resolve_kv_knobs(
-    kv_page_size: int | None = None,
-    kv_pool_pages: int | None = None,
-    kv_native: bool | None = None,
-) -> tuple[int, int, bool]:
-    """Paged-KV knob resolution, same precedence as the lane knobs:
-    explicit (CLI flag) beats env (DLLAMA_KV_PAGE_SIZE /
-    DLLAMA_KV_POOL_PAGES / DLLAMA_KV_NATIVE) beats default. page_size
-    0 = the manager's default (16); page_size < 0 DISABLES the paged
-    pool (the lane path then has no prefix reuse at all — the
-    sharing-off baseline the serving bench compares against).
-    pool_pages 0 = auto-size from the engine (2 * seq_len/page_size + 1
-    slab mode; one pool per lane + headroom in native mode). kv_native
-    1 = pool-native paged decode: lanes read/write KV through a page
-    table straight into the shared pool, adopt is a refcount bump and
-    publish an ownership transfer (zero device copies on page-aligned
-    prefixes)."""
-    if kv_page_size is None:
-        kv_page_size = _env_int("DLLAMA_KV_PAGE_SIZE", 0)
-    if kv_pool_pages is None:
-        kv_pool_pages = _env_int("DLLAMA_KV_POOL_PAGES", 0)
-    if kv_native is None:
-        kv_native = bool(_env_int("DLLAMA_KV_NATIVE", 0))
-    return int(kv_page_size), int(kv_pool_pages), bool(kv_native)
-
-
-def resolve_stream_knobs(max_streams: int | None = None) -> int:
-    """Oversubscription knob, same precedence chain: explicit
-    (--max-streams) beats env (DLLAMA_MAX_STREAMS) beats default 0 =
-    off (streams cap at the lane count, the pre-PR16 behavior). A value
-    above the lane count lets the scheduler admit that many concurrent
-    streams, PARKING active lanes (publish whole pages + drop the page
-    list, radix entry kept) to make room, and resuming parked streams
-    through the recovery-admission path with near-zero re-prefill."""
-    if max_streams is None:
-        max_streams = _env_int("DLLAMA_MAX_STREAMS", 0)
-    return int(max_streams)
-
-
-def resolve_resilience_knobs(
-    retry_max: int | None = None,
-    retry_backoff_ms: int | None = None,
-    max_queue_depth: int | None = None,
-) -> tuple[int, int, int]:
-    """Retry/shed knob resolution, same precedence as the lane knobs:
-    explicit (CLI flag) beats env (DLLAMA_RETRY_MAX /
-    DLLAMA_RETRY_BACKOFF_MS / DLLAMA_MAX_QUEUE_DEPTH) beats default.
-    retry_max is attempts AFTER the first failure (0 disables retries);
-    max_queue_depth 0 disables queue-depth shedding (unbounded queue,
-    the pre-PR12 behavior)."""
-    if retry_max is None:
-        retry_max = _env_int("DLLAMA_RETRY_MAX", 3)
-    if retry_backoff_ms is None:
-        retry_backoff_ms = _env_int("DLLAMA_RETRY_BACKOFF_MS", 5)
-    if max_queue_depth is None:
-        max_queue_depth = _env_int("DLLAMA_MAX_QUEUE_DEPTH", 0)
-    return int(retry_max), int(retry_backoff_ms), int(max_queue_depth)
-
-
 class LaneScheduler:
     """Continuous-batching loop over the engine's batch lanes.
 
@@ -427,7 +344,7 @@ class LaneScheduler:
         self,
         state: "ApiState",
         block_size: int = 8,
-        admission_chunk: int | None = None,
+        admission_chunk: int = 0,
         speculation: str = "off",
         spec_k: int = DEFAULT_SPEC_K,
         max_streams: int = 0,
@@ -475,7 +392,7 @@ class LaneScheduler:
         # accepted rows instead of re-feeding them
         self._draft_fed: dict[int, tuple[int, int]] = {}
         # admission chunk budget: at most this many prompt tokens prefill
-        # per scheduler tick (0/None = the largest prefill bucket), so the
+        # per scheduler tick (0 = the largest prefill bucket), so the
         # worst-case inter-token gap an active stream sees is one chunk +
         # one decode block, never one full prefill
         self.admission_chunk = (
@@ -504,7 +421,7 @@ class LaneScheduler:
         self._flight: _BlockInFlight | None = None
         self._drained_why: str | None = None
         self._n_pending = 0  # requests left waiting after the tick's admissions
-        # transient-dispatch retry policy (resolve_resilience_knobs):
+        # transient-dispatch retry policy (the state's knobs):
         # attempts after the first failure, exponential backoff base.
         # _sleep is injectable so chaos tests don't pay real backoff.
         self.retry_max = int(getattr(state, "retry_max", 3))
@@ -2195,14 +2112,14 @@ class ApiState:
         chat_template_type: ChatTemplateType = ChatTemplateType.UNKNOWN,
         tracer: Tracer | None = None,
         lane_block_size: int = 8,
-        admission_chunk: int | None = None,
+        admission_chunk: int = 0,
         kv_page_size: int = 0,
         kv_pool_pages: int = 0,
         kv_native: bool = False,
         max_streams: int = 0,
         slo_ttft_ms: float | None = None,
         slo_tpot_ms: float | None = None,
-        series_retention: float | None = None,
+        series_retention: float = 3600.0,
         speculation: str = "off",
         spec_k: int = DEFAULT_SPEC_K,
         retry_max: int = 3,
@@ -2214,6 +2131,11 @@ class ApiState:
         deadline_default_ms: int = 600_000,
         deadline_priority_step_ms: int = 60_000,
     ):
+        if speculation not in SPEC_MODES:
+            raise ValueError(
+                f"speculation must be one of {'/'.join(SPEC_MODES)}, got "
+                f"{speculation!r}"
+            )
         self.engine = engine
         self.tokenizer = tokenizer
         self.model_name = model_name
@@ -2221,17 +2143,17 @@ class ApiState:
         # /v1/health and scopes chaos injection (sse_flush op filter)
         self.replica_id = replica_id
         self.start_unix = time.time()
-        # resilience knobs (resolve_resilience_knobs): the scheduler reads
-        # the retry policy off this state; admission_decision() reads the
-        # shed threshold (0 = unbounded queue, shedding off)
+        # resilience knobs: the scheduler reads the retry policy off this
+        # state; admission_decision() reads the shed threshold (0 =
+        # unbounded queue, shedding off)
         self.retry_max = int(retry_max)
         self.retry_backoff_ms = int(retry_backoff_ms)
         self.max_queue_depth = int(max_queue_depth)
-        # predictive admission (ISSUE 20, resolve_admission_knobs /
-        # resolve_deadline_knobs): predict gates the whole controller
-        # (infeasible-reject, EDF ordering, deadline preemption); the
-        # deadline knobs shape the synthetic effective deadlines that
-        # keep PR 12 priority semantics when no hints are given. The
+        # predictive admission (ISSUE 20): predict gates the whole
+        # controller (infeasible-reject, EDF ordering, deadline
+        # preemption); the deadline knobs shape the synthetic effective
+        # deadlines that keep PR 12 priority semantics when no hints are
+        # given. The
         # LoadPredictor itself also backs the derived Retry-After on
         # every shed path, predictive mode on or off.
         self.admission_predict = bool(admission_predict)
@@ -2259,9 +2181,8 @@ class ApiState:
         # span timeline (GET /v1/debug/timeline, --timeline-out) and
         # windowed SLO attainment/goodput (GET /v1/debug/slo)
         self.spans = get_span_tracker()
-        ttft_ms, tpot_ms = resolve_slo_knobs(slo_ttft_ms, slo_tpot_ms)
         self.slo = SloTracker(
-            ttft_target_ms=ttft_ms, tpot_target_ms=tpot_ms
+            ttft_target_ms=slo_ttft_ms, tpot_target_ms=slo_tpot_ms
         )
         # one refresh path for every on-demand gauge: the /metrics scrape
         # and the series sampler both call run_refresh_hooks(), so the SLO
@@ -2276,9 +2197,8 @@ class ApiState:
         # (obs/timeseries.py, obs/anomaly.py): /v1/debug/series and the
         # /dashboard sparklines read the store; the anomaly monitor rides
         # the sampler tick and feeds /v1/health's degraded status
-        retention_s, interval_s = resolve_series_knobs(series_retention)
         self.series = SeriesStore(
-            interval_s=interval_s, retention_s=retention_s
+            interval_s=resolve_series_knobs(), retention_s=series_retention
         )
         self.sampler = MetricsSampler(self.series)
         self.anomaly = AnomalyMonitor(build_default_rules(self.series))
@@ -3769,54 +3689,41 @@ def serve(
     chat_template_type: ChatTemplateType = ChatTemplateType.UNKNOWN,
     trace_out: str | None = None,
     postmortem_dir: str | None = None,
-    lane_block_size: int | None = None,
-    admission_chunk: int | None = None,
-    kv_page_size: int | None = None,
-    kv_pool_pages: int | None = None,
-    kv_native: bool | None = None,
-    max_streams: int | None = None,
+    lane_block_size: int = 8,
+    admission_chunk: int = 0,
+    kv_page_size: int = 0,
+    kv_pool_pages: int = 0,
+    kv_native: bool = False,
+    max_streams: int = 0,
     timeline_out: str | None = None,
     slo_ttft_ms: float | None = None,
     slo_tpot_ms: float | None = None,
-    series_retention: float | None = None,
-    speculation: str | None = None,
-    spec_k: int | None = None,
+    series_retention: float = 3600.0,
+    speculation: str = "off",
+    spec_k: int = DEFAULT_SPEC_K,
     draft_model: str | None = None,
-    retry_max: int | None = None,
-    retry_backoff_ms: int | None = None,
-    max_queue_depth: int | None = None,
+    retry_max: int = 3,
+    retry_backoff_ms: int = 5,
+    max_queue_depth: int = 0,
     faults: str | None = None,
     replica_id: str | None = None,
-    admission_predict: bool | None = None,
-    admission_max_wait_ms: int | None = None,
-    deadline_default_ms: int | None = None,
-    deadline_priority_step_ms: int | None = None,
+    admission_predict: bool = False,
+    admission_max_wait_ms: int = 30_000,
+    deadline_default_ms: int = 600_000,
+    deadline_priority_step_ms: int = 60_000,
 ):
-    block, chunk = resolve_lane_knobs(lane_block_size, admission_chunk)
-    page_size, pool_pages, native = resolve_kv_knobs(
-        kv_page_size, kv_pool_pages, kv_native
-    )
-    streams = resolve_stream_knobs(max_streams)
-    spec_mode, spec_k_val = resolve_spec_knobs(speculation, spec_k)
-    if spec_mode == "draft":
-        draft_path = resolve_draft_model(draft_model)
-        if draft_path is None:
+    """The HTTP server over `engine`. The knobs are the flags of
+    `cli.add_engine_args` under the same names and defaults, each with one
+    spelling: `ApiState` and its `LaneScheduler` take them as given."""
+    if speculation == "draft":
+        if draft_model is None:
             raise ValueError(
                 "--speculation draft needs a draft checkpoint: pass "
-                "--draft-model or set DLLAMA_DRAFT_MODEL"
+                "--draft-model"
             )
         # load BEFORE ApiState: the scheduler's admission rehearsal
         # prefetches draft_prefill/draft_step only if the model is there
-        engine.init_draft_model(draft_path)
-    r_max, r_backoff, q_depth = resolve_resilience_knobs(
-        retry_max, retry_backoff_ms, max_queue_depth
-    )
-    predict_on, max_wait_ms = resolve_admission_knobs(
-        admission_predict, admission_max_wait_ms
-    )
-    ddl_default, ddl_step = resolve_deadline_knobs(
-        deadline_default_ms, deadline_priority_step_ms
-    )
+        engine.init_draft_model(draft_model)
     if faults is not None:
         # arm the process-wide chaos plane for this server's lifetime
         # (--faults; the env spec DLLAMA_FAULTS armed it at import)
@@ -3827,25 +3734,25 @@ def serve(
         model_name,
         chat_template_type,
         tracer=Tracer(sink_path=trace_out) if trace_out else None,
-        lane_block_size=block,
-        admission_chunk=chunk,
-        kv_page_size=page_size,
-        kv_pool_pages=pool_pages,
-        kv_native=native,
-        max_streams=streams,
+        lane_block_size=lane_block_size,
+        admission_chunk=admission_chunk,
+        kv_page_size=kv_page_size,
+        kv_pool_pages=kv_pool_pages,
+        kv_native=kv_native,
+        max_streams=max_streams,
         slo_ttft_ms=slo_ttft_ms,
         slo_tpot_ms=slo_tpot_ms,
         series_retention=series_retention,
-        speculation=spec_mode,
-        spec_k=spec_k_val,
-        retry_max=r_max,
-        retry_backoff_ms=r_backoff,
-        max_queue_depth=q_depth,
+        speculation=speculation,
+        spec_k=spec_k,
+        retry_max=retry_max,
+        retry_backoff_ms=retry_backoff_ms,
+        max_queue_depth=max_queue_depth,
         replica_id=replica_id,
-        admission_predict=predict_on,
-        admission_max_wait_ms=max_wait_ms,
-        deadline_default_ms=ddl_default,
-        deadline_priority_step_ms=ddl_step,
+        admission_predict=admission_predict,
+        admission_max_wait_ms=admission_max_wait_ms,
+        deadline_default_ms=deadline_default_ms,
+        deadline_priority_step_ms=deadline_priority_step_ms,
     )
     if postmortem_dir:
         # a crashed scheduler loop / engine step dumps the event ring here

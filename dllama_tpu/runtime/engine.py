@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..formats.model_file import LlmHeader, ModelReader
 from ..formats.quants import FloatType
 from ..models import forward, init_kv_cache, load_params
+from ..models.loader import packs_dense
 from ..models.transformer import lanes_on_one_device
 from ..ops.quant_matmul import PACKED_GROUP
 from ..parallel import cache_specs, make_mesh, shard_params_put, validate_tp
@@ -185,6 +186,34 @@ class LaneBlock:
     seconds: float | None = None  # `step_complete`'s `ms`, once collected
 
 
+# What a model keeps besides one stack of keys and values a position, and why
+# each feature that is off by default does not run over it: `_refuse` raises
+# from this table alone, so a new family writes one row. The four mesh flags
+# (--tp, --sp, --pp, --dp) share the first reason.
+_KEPT = (
+    ("_latent", "a cache of latent rows", {
+        "mesh": "latent attention layers over a cache of latent rows run on one device",
+        "--kv-dtype": "a cache of latent rows is not quantized",
+        "--speculation": "the verify programs are untested over a cache of latent rows",
+        "--kv-native": "the pool-native programs read keys and values, not latent rows",
+    }),
+    ("_stateful", "a convolution layer's state a lane", {
+        "mesh": "convolution layers that keep a state a lane run on one device",
+        "--kv-dtype": "a convolution layer's state is not quantized",
+        "--speculation": "a rejected draft would have moved a lane's convolution "
+                         "states, which cannot step back",
+        "--kv-native": "the pool-native programs keep no lane state",
+    }),
+    ("_two_cache_kinds", "a ring cache under its window layers", {
+        "mesh": "window attention layers over a ring cache run on one device",
+        "--kv-dtype": "the window layers' ring cache is not quantized",
+        "--speculation": "the verify programs are untested over the window "
+                         "layers' ring cache",
+        "--kv-native": "the pool-native programs read one kind of cache",
+    }),
+)
+
+
 class InferenceEngine:
     """See module docstring. `batch_size` > 1 turns the batch axis into
     independent decoding lanes (`generate_batch`) — the data-parallel
@@ -302,28 +331,11 @@ class InferenceEngine:
         # short convolutions): the state stack rides in `self.cache` beside
         # the attention layers' keys and values, on one device
         self._stateful = self.header.stateful
-        if self._two_cache_kinds or self._latent or self._stateful:
-            what, cache = (
-                ("latent attention layers over a cache of latent rows",
-                 "a cache of latent rows")
-                if self._latent else
-                ("convolution layers that keep a state a lane",
-                 "a convolution layer's state")
-                if self._stateful else
-                ("window attention layers over a ring cache",
-                 "the window layers' ring cache")
-            )
-            for flag, n in (("--tp", tp), ("--sp", sp), ("--pp", pp), ("--dp", dp)):
-                if n > 1:
-                    raise ValueError(
-                        f"{flag} {n}: {what} run on one device "
-                        f"({self.header.arch.name})"
-                    )
-            if kv_dtype in ("int8", jnp.int8):
-                raise ValueError(
-                    f"--kv-dtype int8: {cache} is not "
-                    f"quantized ({self.header.arch.name})"
-                )
+        for flag, n in (("--tp", tp), ("--sp", sp), ("--pp", pp), ("--dp", dp)):
+            if n > 1:
+                self._refuse(f"{flag} {n}")
+        if kv_dtype in ("int8", jnp.int8):
+            self._refuse("--kv-dtype int8")
         if self._stateful and batch_size < 2:
             raise ValueError(
                 f"--batch-size {batch_size}: a model with lane state is served by "
@@ -392,30 +404,27 @@ class InferenceEngine:
 
         # "auto": keep Q40 weights quantized on device when the Pallas path
         # is available (TPU), packed two nibbles a byte wherever the kernel
-        # takes every dense matmul's in axis (`_packs`; else int8 values),
-        # the routed experts with them where `moe_held_experts_q40` reads
-        # them (`_packs_experts`; else int8 values as under "q40");
-        # dense bf16/f32 elsewhere (the CPU fallback dequantizes per call,
-        # fine for tests, slow for serving).
+        # takes every dense matmul's in axis (models/loader.packs_dense;
+        # else int8 values); dense bf16/f32 elsewhere (the CPU fallback
+        # dequantizes per call, fine for tests, slow for serving).
         if weight_format == "auto":
             weight_format = "dense"
             if (
                 self.header.weight_type == FloatType.Q40
                 and jax.default_backend() == "tpu"
             ):
-                weight_format = "q40i4" if self._packs(tp) else "q40"
+                weight_format = "q40i4" if packs_dense(self.reader.specs, tp) else "q40"
         if weight_format not in ("dense", "q40", "q40i4"):
             raise ValueError(
                 f"weight_format must be 'auto', 'dense', 'q40' or 'q40i4', "
                 f"got {weight_format!r}"
             )
-        if weight_format == "q40i4" and tp > 1 and not self._packs(tp):
+        if weight_format == "q40i4" and tp > 1 and not packs_dense(self.reader.specs, tp):
             raise ValueError(
                 f"q40i4 weight format with tp={tp} needs every dense matmul's "
                 f"in dim divisible by {PACKED_GROUP * tp}"
             )
         self.weight_format = weight_format
-        self.experts_packed = weight_format == "q40i4" and self._packs_experts()
         quantized = weight_format in ("q40", "q40i4")
         # Q80-compressed partial-sum all-reduces (the reference's
         # --buffer-float-type q80, src/llm.cpp:195): worthwhile on
@@ -447,7 +456,6 @@ class InferenceEngine:
             put=shard_params_put(self.mesh, self.header),
             # q40i4 packs host-side inside the loader itself
             weight_format=weight_format,
-            pack_experts=self.experts_packed,
             # quantized path: fuse q|k|v (and w1|w3 for dense-FFN archs)
             # into single shard-major-interleaved kernel launches — 7 -> 4
             # Pallas calls per decode layer (~41 us fixed cost each on
@@ -778,35 +786,6 @@ class InferenceEngine:
 
     # -- cache ---------------------------------------------------------------
 
-    def _packs(self, tp: int) -> bool:
-        """Whether the packed kernel takes every dense matmul of the file:
-        each one's in axis whole groups of 256 rows a tp shard (a routed
-        expert is `_packs_experts`' and `wkv_b` is dequantised, whatever
-        theirs)."""
-        return all(
-            spec.shape[1] % (PACKED_GROUP * tp) == 0
-            for spec in self.reader.specs
-            if spec.float_type == FloatType.Q40 and len(spec.shape) == 2
-            and ".experts." not in spec.name and not spec.name.endswith(".wkv_b")
-        )
-
-    def _packs_experts(self) -> bool:
-        """Whether the routed experts are held packed with the dense
-        matmuls: one device holds every sparse layer whole, so
-        `moe_held_experts_q40` is the kernel that reads them and unpacks a
-        tile in VMEM, and every expert's in axis (D of w1 and w3, F of w2) is
-        whole groups of 256 rows. On a mesh the older expert kernels' shards
-        slice F and read int8 values: there the experts stay int8."""
-        experts = [
-            spec for spec in self.reader.specs
-            if spec.float_type == FloatType.Q40 and ".experts." in spec.name
-        ]
-        return (
-            self.mesh.devices.size == 1
-            and bool(experts)
-            and all(spec.shape[1] % PACKED_GROUP == 0 for spec in experts)
-        )
-
     def _fresh_cache(self):
         # epoch lets callers detect that cached KV state was dropped
         # (api_server clears its prompt cache iff this moved — a
@@ -1046,6 +1025,16 @@ class InferenceEngine:
         if replay:
             self._m_replay_tokens.inc(replay)
         return {"state_lanes": 1, "replay_tokens": replay}
+
+    def _refuse(self, flag: str) -> None:
+        """Raise where the model keeps what `flag`'s feature does not run
+        over (`_KEPT`), naming the flag, the reason and the architecture."""
+        for attr, kept, why in _KEPT:
+            if getattr(self, attr):
+                raise ValueError(
+                    f"{flag}: {why.get(flag.split()[0], why['mesh'])} "
+                    f"({self.header.arch.name} keeps {kept})"
+                )
 
     def _require_stateless(self, what: str) -> None:
         if self._stateful:
@@ -1532,23 +1521,6 @@ class InferenceEngine:
 
     # -- per-lane serving (continuous-batching surface) ----------------------
 
-    def _refuse_speculation(self) -> None:
-        if self._stateful:
-            raise ValueError(
-                "--speculation: a rejected draft would have moved a lane's "
-                f"convolution states, which cannot step back ({self.header.arch.name})"
-            )
-        if self._latent:
-            raise ValueError(
-                "--speculation: the verify programs are untested over a "
-                f"cache of latent rows ({self.header.arch.name})"
-            )
-        if self._two_cache_kinds:
-            raise ValueError(
-                "--speculation: the verify programs are untested over the "
-                f"window layers' ring cache ({self.header.arch.name})"
-            )
-
     def _require_lanes(self) -> None:
         if self._lane_pad == 0:
             raise ValueError(
@@ -1686,7 +1658,7 @@ class InferenceEngine:
                     ),
                 )
         if spec_k > 0:
-            self._refuse_speculation()
+            self._refuse("--speculation")
             # one verify program per draft bucket (width 1 + bucket for
             # the pending token) at the base window; deeper windows ride
             # the same 75% prefetch as the decode block
@@ -1964,21 +1936,8 @@ class InferenceEngine:
             raise ValueError(
                 f"page_size {page_size} exceeds lane padding {self._lane_pad}"
             )
-        if native and self._stateful:
-            raise ValueError(
-                "--kv-native 1: the pool-native programs keep no lane state; "
-                f"{self.header.arch.name} has convolution layers"
-            )
-        if native and self._latent:
-            raise ValueError(
-                "--kv-native 1: the pool-native programs read keys and "
-                f"values; {self.header.arch.name} caches latent rows"
-            )
-        if native and self._two_cache_kinds:
-            raise ValueError(
-                "--kv-native 1: the pool-native programs read one kind of "
-                f"cache; {self.header.arch.name} has window layers over a ring"
-            )
+        if native:
+            self._refuse("--kv-native 1")
         if native and (self.pp > 1 or self.sp > 1):
             # the pp fwd closure parks at the slab's seq_len and sp shards
             # the sequence axis; both assume slab geometry — the native
@@ -3092,7 +3051,7 @@ class InferenceEngine:
         are proposed as target token ids and verified by the target, so
         a vocab mismatch is a config error, not a quality problem."""
         self._require_lanes()
-        self._refuse_speculation()
+        self._refuse("--speculation")
         if self.pp > 1 or self.sp > 1:
             raise ValueError(
                 "draft model requires pp == 1 and sp == 1 (the draft "

@@ -41,7 +41,6 @@ this with the same seeded parity harness used for chunked admission).
 
 from __future__ import annotations
 
-import os
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -58,8 +57,6 @@ __all__ = [
     "SOURCE_SHARED",
     "SharedNgramStore",
     "bucket_for",
-    "resolve_draft_model",
-    "resolve_spec_knobs",
     "spec_buckets",
 ]
 
@@ -110,37 +107,6 @@ def bucket_for(k: int, buckets: Sequence[int]) -> int:
         if k <= b:
             return b
     return buckets[-1]
-
-
-def resolve_spec_knobs(
-    speculation: Optional[str] = None, spec_k: Optional[int] = None
-) -> Tuple[str, int]:
-    """Resolve the speculation knobs: explicit argument beats the
-    environment (``DLLAMA_SPECULATION``, ``DLLAMA_SPEC_K``) beats the
-    default (``"off"``, ``DEFAULT_SPEC_K``)."""
-    if speculation is None:
-        speculation = os.environ.get("DLLAMA_SPECULATION", "").strip() or "off"
-    if spec_k is None:
-        raw = os.environ.get("DLLAMA_SPEC_K", "").strip()
-        spec_k = int(raw) if raw else DEFAULT_SPEC_K
-    mode = str(speculation)
-    if mode not in SPEC_MODES:
-        raise ValueError(
-            f"speculation must be one of {'/'.join(SPEC_MODES)}, got {mode!r}"
-        )
-    return mode, max(1, int(spec_k))
-
-
-def resolve_draft_model(draft_model: Optional[str] = None) -> Optional[str]:
-    """Resolve the resident-draft-model checkpoint path: explicit
-    argument beats the environment (``DLLAMA_DRAFT_MODEL``) beats None.
-    Mode ``draft`` requires a path; the server errors out at startup
-    otherwise."""
-    if draft_model is None:
-        draft_model = (
-            os.environ.get("DLLAMA_DRAFT_MODEL", "").strip() or None
-        )
-    return draft_model
 
 
 class NgramIndex:

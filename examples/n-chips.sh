@@ -10,7 +10,7 @@
 # run as e.g.:
 #   ./n-chips.sh 8 m.m t.t --tp 2 --pp 2 --sp 2        # pp x sp x tp
 #   ./n-chips.sh 8 m.m t.t --tp 2 --dp 2 --batch-size 2 # lanes over dp
-#   ./n-chips.sh 4 m.m t.t --kv-dtype int8 --weight-format q40i4
+#   ./n-chips.sh 4 m.m t.t --kv-dtype int8 --weight-format q40
 
 set -e
 N=${1:?usage: n-chips.sh <n-chips> <model.m> <tokenizer.t> [args...]}

@@ -382,3 +382,112 @@ def tiny_lfm2_config(layer_types=None, **over) -> dict:
 def make_tiny_lfm2(path: str, seed: int = 3, **over) -> dict:
     """The same for `lfm2_moe`, as `make_tiny_afmoe`."""
     return _write_tiny(path, tiny_lfm2_config(**over), seed)
+
+
+# -- one spelling a serving knob (PR 45) -----------------------------------------
+#
+# Until PR 45 nineteen flags of `cli.add_engine_args` defaulted to None so that
+# a DLLAMA_* variable of the same meaning could fill them in. The variables
+# are no longer read. Each entry: the former variable -> (a value that is not
+# the flag's default, the flag's dest, what the flag's default leaves on the
+# state, how to read it off an ApiState). A default may be a function of the
+# state where 0 or None means "work it out".
+FORMER_TWINS = {
+    "DLLAMA_LANE_BLOCK": ("5", "lane_block_size", 8, lambda st: st.scheduler.block_size),
+    "DLLAMA_ADMISSION_CHUNK": (
+        "24", "admission_chunk", lambda st: max(st.engine.prefill_buckets),
+        lambda st: st.scheduler.admission_chunk),
+    "DLLAMA_KV_PAGE_SIZE": ("4", "kv_page_size", 16, lambda st: st.kv_manager.page_size),
+    "DLLAMA_KV_POOL_PAGES": (
+        "97", "kv_pool_pages", lambda st: 2 * (st.engine.header.seq_len // 16) + 1,
+        lambda st: st.engine._kv_pool_pages),
+    "DLLAMA_KV_NATIVE": ("1", "kv_native", 0, lambda st: st.engine.kv_native),
+    "DLLAMA_MAX_STREAMS": ("6", "max_streams", 0, lambda st: st.scheduler.max_streams),
+    "DLLAMA_SPECULATION": ("ngram", "speculation", "off", lambda st: st.scheduler.spec_mode),
+    "DLLAMA_SPEC_K": ("8", "spec_k", 4, lambda st: st.scheduler.spec_k),
+    "DLLAMA_DRAFT_MODEL": (
+        "/env/d.m", "draft_model", None, lambda st: st.engine._draft_params),
+    "DLLAMA_RETRY_MAX": ("1", "retry_max", 3, lambda st: st.scheduler.retry_max),
+    "DLLAMA_RETRY_BACKOFF_MS": (
+        "70", "retry_backoff_ms", 5, lambda st: st.scheduler.retry_backoff_s * 1000.0),
+    "DLLAMA_MAX_QUEUE_DEPTH": ("9", "max_queue_depth", 0, lambda st: st.max_queue_depth),
+    "DLLAMA_ADMISSION_PREDICT": (
+        "1", "admission_predict", False, lambda st: st.admission_predict),
+    "DLLAMA_ADMISSION_MAX_WAIT_MS": (
+        "9000", "admission_max_wait_ms", 30_000, lambda st: st.admission_max_wait_ms),
+    "DLLAMA_DEADLINE_DEFAULT_MS": (
+        "120000", "deadline_default_ms", 600_000, lambda st: st.deadline_default_ms),
+    "DLLAMA_DEADLINE_PRIORITY_STEP_MS": (
+        "5000", "deadline_priority_step_ms", 60_000,
+        lambda st: st.deadline_priority_step_ms),
+    "DLLAMA_SLO_TTFT_MS": ("250", "slo_ttft_ms", None, lambda st: st.slo.ttft_target_ms),
+    "DLLAMA_SLO_TPOT_MS": ("40", "slo_tpot_ms", None, lambda st: st.slo.tpot_target_ms),
+    "DLLAMA_SERIES_RETENTION_S": (
+        "120", "series_retention", 3600.0, lambda st: st.series.retention_s),
+}
+
+
+def flags_state(tmp_path_factory, *argv, former_twins: bool = False, paths=None):
+    """The `ApiState` that `python -m dllama_tpu.runtime.api_server` builds
+    from `argv` over a tiny model with two lanes (`serve_from_args`, never
+    started; `paths`: a model and a tokenizer that exist already), with
+    every one of `FORMER_TWINS` set in the environment while it is built if
+    `former_twins`. Yields (the parsed args, the state)."""
+    import pytest
+
+    from dllama_tpu.runtime.api_server import build_arg_parser, serve_from_args
+
+    if paths is None:
+        d = tmp_path_factory.mktemp("flags")
+        paths = str(d / "m.m"), str(d / "t.t")
+        make_tiny_model(paths[0], cfg=dict(TINY, vocab_size=288, seq_len=384))
+        make_tiny_tokenizer(paths[1], chat_template="<|start_header_id|>")
+    mp, tp_ = paths
+    args = build_arg_parser().parse_args([
+        "--model", mp, "--tokenizer", tp_, "--port", "0", "--batch-size", "2",
+        "--dtype", "f32", "--temperature", "0.0", "--seed", "3", *argv,
+    ])
+    with pytest.MonkeyPatch.context() as env:
+        for name, (value, *_) in FORMER_TWINS.items():
+            if former_twins:
+                env.setenv(name, value)
+            else:
+                env.delenv(name, raising=False)
+        server = serve_from_args(args)
+    try:
+        yield args, server.state
+    finally:
+        # the rehearsal compiles on threads of its own: an interpreter that
+        # exits in the middle of one aborts
+        server.state.engine.rehearse_admission(wait=True)
+        server.server_close()
+
+
+def twin_flags(*names: str) -> list[str]:
+    """argv that passes each of `names`' flags with its variable's value."""
+    argv = []
+    for name in names:
+        value, dest, default, _ = FORMER_TWINS[name]
+        argv.append("--" + dest.replace("_", "-"))
+        if default is not False:  # a store_true flag takes no value
+            argv.append(value)
+    return argv
+
+
+def assert_one_spelling(name: str, unflagged, flagged) -> None:
+    """`unflagged` is `flags_state(..., former_twins=True)` with none of the
+    nineteen flags passed: the parser and the state hold the default of
+    `name`'s flag, whatever the variable says. `flagged` was built from
+    `twin_flags(name, ...)`: the flag's value is what the state holds."""
+    from dllama_tpu.runtime.api_server import build_arg_parser
+
+    _, dest, default, read = FORMER_TWINS[name]
+    flag_default = getattr(build_arg_parser().parse_args([]), dest)
+    args, state = unflagged
+    assert getattr(args, dest) == flag_default
+    want = default(state) if callable(default) else default
+    got = read(state)
+    assert got == want if want is not None else got is None, (name, got, want)
+    args, state = flagged
+    assert getattr(args, dest) != flag_default
+    assert read(state) == getattr(args, dest), name

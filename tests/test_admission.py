@@ -26,12 +26,11 @@ from dllama_tpu.runtime.api_server import (
     ChatMessage,
     InferenceParams,
     LaneJob,
-    resolve_lane_knobs,
 )
 from dllama_tpu.runtime.engine import InferenceEngine
 from dllama_tpu.tokenizer import Tokenizer
 
-from helpers import make_tiny_model, make_tiny_tokenizer
+from helpers import assert_one_spelling, make_tiny_model, make_tiny_tokenizer
 
 CFG = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
            head_dim=16, vocab_size=288, seq_len=384)
@@ -366,31 +365,24 @@ def test_admission_rehearsal_precompiles_chunk_programs(sched_state):
         )
 
 
-# -- knobs: CLI flags + env overrides -----------------------------------------
+# -- knobs: one spelling each, the flag's --------------------------------------
+
+# the scheduler's, the pool's and the retry policy's knobs, whose DLLAMA_*
+# twins `resolve_lane_knobs`, `resolve_kv_knobs`, `resolve_stream_knobs` and
+# `resolve_resilience_knobs` read until PR 45
+KNOB_TWINS = (
+    "DLLAMA_LANE_BLOCK", "DLLAMA_ADMISSION_CHUNK", "DLLAMA_KV_PAGE_SIZE",
+    "DLLAMA_KV_POOL_PAGES", "DLLAMA_KV_NATIVE", "DLLAMA_MAX_STREAMS",
+    "DLLAMA_RETRY_MAX", "DLLAMA_RETRY_BACKOFF_MS", "DLLAMA_MAX_QUEUE_DEPTH",
+)
 
 
-@pytest.mark.fast
-def test_lane_knob_resolution(monkeypatch):
-    import argparse
-
-    from dllama_tpu.cli import add_engine_args
-
-    parser = argparse.ArgumentParser()
-    add_engine_args(parser)
-    args = parser.parse_args(
-        ["--lane-block-size", "4", "--admission-chunk", "16"]
-    )
-    assert args.lane_block_size == 4
-    assert args.admission_chunk == 16
-
-    monkeypatch.delenv("DLLAMA_LANE_BLOCK", raising=False)
-    monkeypatch.delenv("DLLAMA_ADMISSION_CHUNK", raising=False)
-    assert resolve_lane_knobs(None, None) == (8, 0)  # 0 = auto
-    monkeypatch.setenv("DLLAMA_LANE_BLOCK", "5")
-    monkeypatch.setenv("DLLAMA_ADMISSION_CHUNK", "24")
-    assert resolve_lane_knobs(None, None) == (5, 24)
-    # an explicit flag beats the env override
-    assert resolve_lane_knobs(4, 16) == (4, 16)
+@pytest.mark.parametrize("name", KNOB_TWINS)
+def test_lane_knob_resolution(unflagged, flagged, name):
+    """The parser holds the flag's default and the state what that default
+    means, with the former variable set; an explicit flag reaches the
+    scheduler, the pool or the state."""
+    assert_one_spelling(name, unflagged, flagged)
 
 
 def test_scheduler_knob_threading(sched_state):

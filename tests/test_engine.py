@@ -121,7 +121,8 @@ def test_cli_help_renders():
     carried an unescaped `% dp`)."""
     r = _run_cli(["--help"])
     assert r.returncode == 0, r.stderr[-2000:]
-    assert "--weight-format" in r.stdout and "q40i4" in r.stdout
+    assert "--weight-format {auto,q40,dense}" in r.stdout
+    assert "q40i4" not in r.stdout
 
 
 def test_cli_perplexity(tiny_model):
@@ -320,7 +321,6 @@ def test_packed_weight_format_moe_keeps_int8_experts(tmp_path):
     out_q40, _, _ = e_q40.generate([1, 2, 3, 4], max_steps=12)
     e_i4 = InferenceEngine(mp, tp=1, dtype=jnp.float32, temperature=0.0,
                            weight_format="q40i4")
-    assert not e_i4.experts_packed
     w1 = e_i4.params["layers"]["w1"]
     assert isinstance(w1, QuantWeight) and not isinstance(w1, PackedQuantWeight)
     assert w1.q.dtype == jnp.int8 and w1.q.ndim == 4  # [L, E, D, F]
@@ -356,7 +356,6 @@ def test_packed_experts_on_one_device_int8_on_a_mesh(tmp_path):
         assert event["decode_packed_share"] == e.weight_bytes["decode_packed_share"]
         share[name] = event["decode_packed_share"]
         w1 = e.params["layers"]["w1"]
-        assert e.experts_packed == (name == "packed")
         if name == "packed":
             assert type(w1) is PackedQuantWeight and w1.qp.dtype == jnp.int32
             assert w1.qp.shape == (2, 4, 512 // 8, 512)  # [L, E, D // 8, F]
@@ -1556,16 +1555,25 @@ def test_weight_format_q40i8_is_refused_by_the_engine(tiny_model):
         InferenceEngine(mp, tp=1, dtype=jnp.float32, weight_format="q40i8")
 
 
-def test_weight_format_q40i8_is_refused_by_the_parser(capsys):
+@pytest.mark.parametrize("value", ["auto", "q40", "dense", "q40i4", "q40i8"])
+def test_cli_weight_format_choices(tiny_model, capsys, value):
+    """`--weight-format` offers `auto | q40 | dense`. `q40i4` is what `auto`
+    resolves to where it runs, so the parser refuses it as it refuses
+    `q40i8`; the engine's keyword still takes it (the CPU tests compare the
+    packed form with `q40` through it)."""
     from dllama_tpu.cli import _build_parser
 
+    argv = ["inference", "--model", "m.m", "--weight-format", value]
+    if value in ("auto", "q40", "dense"):
+        assert _build_parser().parse_args(argv).weight_format == value
+        return
     with pytest.raises(SystemExit):
-        _build_parser().parse_args(
-            ["inference", "--model", "m.m", "--weight-format", "q40i8"])
-    assert "invalid choice: 'q40i8'" in capsys.readouterr().err
-    args = _build_parser().parse_args(
-        ["inference", "--model", "m.m", "--weight-format", "q40i4"])
-    assert args.weight_format == "q40i4"
+        _build_parser().parse_args(argv)
+    assert f"invalid choice: '{value}'" in capsys.readouterr().err
+    if value == "q40i4":
+        mp, _ = tiny_model
+        e = InferenceEngine(mp, tp=1, dtype=jnp.float32, weight_format="q40i4")
+        assert e.weight_format == "q40i4"
 
 
 # -- the drained interval (`_read_back` marks it, `_dispatch` closes it) ------

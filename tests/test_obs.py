@@ -369,35 +369,42 @@ def test_weight_bytes_per_token_formats():
     att = 4 * 4 + 2 * 4 * 2 + 4 * 4     # 48
     ffn = 3 * 4 * 8                      # 96
     base = att + ffn + 4 * 10            # + embed/cls read
-    assert weight_bytes_per_token(h, "q40") == int(base * 1.125)
-    assert weight_bytes_per_token(h, "bf16") == base * 2
-    assert weight_bytes_per_token(h, "q40i4") == int(base * 0.625)
+    assert weight_bytes_per_token(h, ("int8", "int8")) == int(base * 1.125)
+    assert weight_bytes_per_token(h, ("float", "float")) == base * 2
+    assert weight_bytes_per_token(h, ("packed", "packed")) == int(base * 0.625)
 
 
 @pytest.mark.parametrize(
-    "weight_format,experts_packed,dense_bpw,expert_bpw",
-    [("q40", False, 1.125, 1.125), ("q40i4", False, 0.625, 1.125),
-     ("q40i4", True, 0.625, 0.625), ("dense", False, 2.0, 2.0)],
+    "weight_format,devices,dense_bpw,expert_bpw",
+    [("q40", 1, 1.125, 1.125), ("q40i4", 4, 0.625, 1.125),
+     ("q40i4", 1, 0.625, 0.625), ("dense", 1, 2.0, 2.0)],
 )
 def test_weight_bytes_per_token_charges_each_leaf_by_its_form(
-        weight_format, experts_packed, dense_bpw, expert_bpw):
-    """Under `q40i4` the routed experts are charged by the form the engine
-    holds them in: packed where one device holds the layer
-    (`experts_packed`), int8 on a mesh: there a sparse model's step is
+        weight_format, devices, dense_bpw, expert_bpw):
+    """Under `q40i4` the routed experts are charged by the form the loader
+    holds them in (`models/loader.weight_forms`): packed where one device
+    holds the layer, int8 on a mesh: there a sparse model's step is
     charged 0.625 B a weight for attention and the head and 1.125 for the
     active experts, not 0.5625 throughout (ROADMAP D8)."""
     from types import SimpleNamespace
 
+    from dllama_tpu.formats.quants import FloatType
+    from dllama_tpu.models.loader import weight_forms
     from dllama_tpu.obs.cost import roofline_report, weight_bytes_per_token
 
     h = SimpleNamespace(dim=64, q_dim=64, kv_dim=32, ff_dim=32, n_layers=2,
                         vocab_size=96, n_experts=8, n_active_experts=2)
+    # the bytes are the header's; the forms are those of a file whose
+    # experts' in axes the packed kernel takes
+    specs = [SimpleNamespace(name=f"layers.0.experts.0.{n}", shape=(256, 256),
+                             float_type=FloatType.Q40) for n in ("w1", "w2")]
+    forms = weight_forms(specs, weight_format, devices)
     att = 64 * 64 * 2 + 2 * 64 * 32
     experts = 3 * 64 * 32 * 2
     want = (2 * (att * dense_bpw + experts * expert_bpw)
             + 64 * 96 * dense_bpw + 2 * 64 * 8 * 4)
-    assert weight_bytes_per_token(h, weight_format, experts_packed) == int(want)
-    rep = roofline_report(h, weight_format, experts_packed=experts_packed)
+    assert weight_bytes_per_token(h, forms) == int(want)
+    rep = roofline_report(h, forms)
     assert rep["weight_bytes_per_token_per_chip"] == int(want)
 
 
@@ -415,17 +422,18 @@ def test_roofline_report_degrades_without_tpu():
     assert hbm_peak_bytes_per_s() is None
     h = SimpleNamespace(dim=64, q_dim=64, kv_dim=32, ff_dim=160, n_layers=2,
                         vocab_size=288, n_experts=0, n_active_experts=0)
-    rep = roofline_report(h, "q40", tp=2)
+    q40 = ("int8", "int8")
+    rep = roofline_report(h, q40, tp=2)
     assert rep["weight_bytes_per_token_per_chip"] > 0
     assert rep["hbm_peak_bytes_per_s"] is None
     assert rep["min_ms_per_token"] is None
     assert rep["max_tok_s_per_chip"] is None
     # tp*pp shards the weight reads
     assert rep["weight_bytes_per_token_per_chip"] == pytest.approx(
-        roofline_report(h, "q40")["weight_bytes_per_token_per_chip"] // 2,
+        roofline_report(h, q40)["weight_bytes_per_token_per_chip"] // 2,
         abs=1,
     )
-    assert print_roofline_report(h, "q40", tp=2) == rep  # prints, returns same
+    assert print_roofline_report(h, q40, tp=2) == rep  # prints, returns same
 
 
 # -- device memory telemetry -------------------------------------------------

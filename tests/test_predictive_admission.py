@@ -24,8 +24,6 @@ from dllama_tpu.runtime.admission import (
     OccupancySnapshot,
     Prediction,
     effective_deadline_ms,
-    resolve_admission_knobs,
-    resolve_deadline_knobs,
 )
 from dllama_tpu.runtime.api_server import (
     ApiState,
@@ -36,7 +34,7 @@ from dllama_tpu.runtime.api_server import (
 from dllama_tpu.runtime.engine import InferenceEngine
 from dllama_tpu.tokenizer import Tokenizer
 
-from helpers import make_tiny_model, make_tiny_tokenizer
+from helpers import assert_one_spelling, make_tiny_model, make_tiny_tokenizer
 
 CFG = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
            head_dim=16, vocab_size=288, seq_len=384)
@@ -219,27 +217,18 @@ def test_effective_deadline_edf_key():
 # -- knobs: env + CLI ---------------------------------------------------------
 
 
-@pytest.mark.fast
-def test_admission_knob_resolution(monkeypatch):
-    for name in (
-        "DLLAMA_ADMISSION_PREDICT", "DLLAMA_ADMISSION_MAX_WAIT_MS",
-        "DLLAMA_DEADLINE_DEFAULT_MS", "DLLAMA_DEADLINE_PRIORITY_STEP_MS",
-    ):
-        monkeypatch.delenv(name, raising=False)
-    assert resolve_admission_knobs(None, None) == (False, 30_000)
-    assert resolve_deadline_knobs(None, None) == (600_000, 60_000)
+KNOB_TWINS = (
+    "DLLAMA_ADMISSION_PREDICT", "DLLAMA_ADMISSION_MAX_WAIT_MS",
+    "DLLAMA_DEADLINE_DEFAULT_MS", "DLLAMA_DEADLINE_PRIORITY_STEP_MS",
+)
 
-    monkeypatch.setenv("DLLAMA_ADMISSION_PREDICT", "1")
-    monkeypatch.setenv("DLLAMA_ADMISSION_MAX_WAIT_MS", "9000")
-    monkeypatch.setenv("DLLAMA_DEADLINE_DEFAULT_MS", "120000")
-    monkeypatch.setenv("DLLAMA_DEADLINE_PRIORITY_STEP_MS", "5000")
-    assert resolve_admission_knobs(None, None) == (True, 9000)
-    assert resolve_deadline_knobs(None, None) == (120_000, 5000)
-    # explicit flags beat the env
-    assert resolve_admission_knobs(False, 1000) == (False, 1000)
-    assert resolve_deadline_knobs(60_000, 100) == (60_000, 100)
-    monkeypatch.setenv("DLLAMA_ADMISSION_PREDICT", "off")
-    assert resolve_admission_knobs(None, None)[0] is False
+
+@pytest.mark.parametrize("name", KNOB_TWINS)
+def test_admission_knob_resolution(unflagged, flagged, name):
+    """The flag's default with the former variable set (`resolve_admission_
+    knobs` / `resolve_deadline_knobs` read it until PR 45), and an explicit
+    flag on the state the admission controller reads."""
+    assert_one_spelling(name, unflagged, flagged)
 
 
 @pytest.mark.fast
@@ -260,10 +249,10 @@ def test_admission_cli_flags():
     assert args.admission_max_wait_ms == 5000
     assert args.deadline_default_ms == 100_000
     assert args.deadline_priority_step_ms == 1000
-    # absent flags stay None so env/default resolution applies
+    # absent flags hold their defaults: nothing else resolves them
     blank = parser.parse_args([])
-    assert blank.admission_predict is None
-    assert blank.admission_max_wait_ms is None
+    assert blank.admission_predict is False
+    assert blank.admission_max_wait_ms == 30_000
 
 
 # -- router: Retry-After propagation + shed backoff ---------------------------

@@ -11,6 +11,7 @@ from dllama_tpu.ops.quant_matmul import (
     QuantWeight,
     dequant,
     from_planar,
+    packed_kernels_take,
     qmatmul,
     qmatmul_2d,
     qmatmul_ref,
@@ -488,3 +489,112 @@ def test_stack_and_layer_equals_the_layers_slice(kind, layer):
         assert got.shape == (m, D)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert np.abs(np.asarray(got)).max() > 0
+
+
+# -- the packing rule (`packed_kernels_take`; models/loader owns what follows) --
+#
+# Which Q40 tensors of a file are held as packed words: the dense matmuls
+# all or none, by their in axes a tp shard; the routed experts where one
+# device holds them and the kernel takes their in axes. The six served
+# configurations at tp 1 and 4, on one and four devices, and tiny headers
+# that have to stay int8: (a configuration's name or a tiny file, tp, devices,
+# dense, experts).
+
+_PACKABLE_MOE = dict(dim=512, hidden_dim=512, moe_hidden_dim=512, n_layers=1,
+                     n_heads=32, n_kv_heads=4, head_dim=16, vocab_size=256,
+                     seq_len=64, n_experts=4, n_active_experts=2)
+PACKING_CASES = {
+    "mistral-7b-v0.3/tp1": ("mistral-7b-v0.3", 1, 1, True, False),
+    "mistral-7b-v0.3/tp4": ("mistral-7b-v0.3", 4, 4, True, False),
+    "qwen3-30b-a3b-l12/tp1": ("qwen3-30b-a3b-l12", 1, 1, True, True),
+    # routed experts on a mesh stay int8, whatever their widths
+    "qwen3-30b-a3b-l12/tp4": ("qwen3-30b-a3b-l12", 4, 4, True, False),
+    "trinity-large-l9-e32/tp1": ("trinity-large-l9-e32", 1, 1, True, True),
+    "trinity-large-l9-e32/tp4": ("trinity-large-l9-e32", 4, 4, True, False),
+    # q_lora_rank 1536 and kv_lora_rank + rope 576 are no 1024s
+    "openpangu-ultra-l5-e32/tp1": ("openpangu-ultra-l5-e32", 1, 1, True, True),
+    "openpangu-ultra-l5-e32/tp4": ("openpangu-ultra-l5-e32", 4, 4, False, False),
+    "deepseek-v3.2-l5-e32/tp1": ("deepseek-v3.2-l5-e32", 1, 1, True, True),
+    "deepseek-v3.2-l5-e32/tp4": ("deepseek-v3.2-l5-e32", 4, 4, False, False),
+    "lfm2-24b-a2b-e16/tp1": ("lfm2-24b-a2b-e16", 1, 1, True, True),
+    "lfm2-24b-a2b-e16/tp4": ("lfm2-24b-a2b-e16", 4, 4, False, False),
+    # four replicas' lanes on a mesh, no in axis sliced: still a mesh
+    "qwen3-30b-a3b-l12/dp4": ("qwen3-30b-a3b-l12", 1, 4, True, False),
+    # tiny files, held whole below: (architecture, make_tiny_model's cfg)
+    "tiny/in-axis-64": (("LLAMA", None), 1, 1, False, False),
+    "tiny-moe/in-axes-64-96": (("QWEN3_MOE", None), 1, 1, False, False),
+    "tiny-moe/in-axes-512": (("QWEN3_MOE", _PACKABLE_MOE), 1, 1, True, True),
+    "tiny-moe/in-axes-512-on-4-devices": (("QWEN3_MOE", _PACKABLE_MOE), 1, 4, True, False),
+}
+
+
+def _quant_classes(params) -> dict:
+    leaves = {**params["layers"], "wcls": params["wcls"]}
+    return {n: type(w).__name__ for n, w in leaves.items() if isinstance(w, tuple)}
+
+
+@pytest.mark.parametrize("case", list(PACKING_CASES))
+def test_packing_rule(tmp_path, case):
+    """`packs_dense` / `packs_experts` over a header's tensor plan give the
+    forms the engine's two methods gave at 98f7288, and for a header small
+    enough to hold, `load_params` and `random_params` hold the same classes
+    of leaves under `q40i4`: the rule has one home."""
+    import json
+    import os
+    import sys
+
+    from helpers import REPO_ROOT, make_tiny_model
+
+    from dllama_tpu.formats import FloatType, ModelReader
+    from dllama_tpu.formats.model_file import LlmArch, read_llm_header, tensor_plan
+    from dllama_tpu.formats.writer import write_header
+    from dllama_tpu.models.loader import (
+        load_params,
+        packs_dense,
+        packs_experts,
+        weight_forms,
+    )
+
+    source, tp, devices, dense, experts = PACKING_CASES[case]
+    path = str(tmp_path / "m.m")
+    if isinstance(source, str):  # a served configuration: its header alone
+        if REPO_ROOT not in sys.path:
+            sys.path.insert(0, REPO_ROOT)
+        from benchmark.harness import weights
+
+        with open(os.path.join(REPO_ROOT, "benchmark", "configs", source + ".json")) as f:
+            wire = weights.header_for(json.load(f))
+        with open(path, "wb") as f:
+            write_header(f, wire)
+        specs = tensor_plan(read_llm_header(path))
+    else:
+        arch, cfg = source
+        make_tiny_model(path, arch=LlmArch[arch], weight_type=FloatType.Q40, cfg=cfg)
+        specs = ModelReader(path).specs
+    assert packed_kernels_take(256) and packed_kernels_take(1024, 4)
+    assert not packed_kernels_take(64) and not packed_kernels_take(768, 4)
+    assert packs_dense(specs, tp) is dense
+    assert packs_experts(specs, devices) is experts
+    assert weight_forms(specs, "q40", devices) == ("int8", "int8")
+    assert weight_forms(specs, "dense", devices) == ("float", "float")
+    # under an explicit `q40i4` the dense matmuls are packed as asked (the
+    # engine refuses it at tp > 1 where `packs_dense` is false)
+    assert weight_forms(specs, "q40i4", devices) == (
+        "packed", "packed" if experts else "int8")
+    if isinstance(source, str):
+        return
+    from dllama_tpu.models.synthetic import random_params
+    from dllama_tpu.parallel import make_mesh, shard_params_put
+
+    reader = ModelReader(path)
+    mesh = make_mesh(dp=devices) if devices > 1 else None
+    loaded = load_params(
+        reader, weight_format="q40i4",
+        **({"put": shard_params_put(mesh, reader.header)} if mesh else {}))
+    made = random_params(reader.header, dtype=jnp.float32, mesh=mesh,
+                         weight_format="q40i4")
+    classes = _quant_classes(loaded)
+    assert classes == _quant_classes(made)
+    assert classes["wo"] == "PackedQuantWeight"
+    if reader.header.n_experts:
+        assert classes["w1"] == ("PackedQuantWeight" if experts else "QuantWeight")

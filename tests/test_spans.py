@@ -13,9 +13,11 @@ import pytest
 
 from dllama_tpu.obs.metrics import MetricsRegistry
 from dllama_tpu.obs.recorder import FlightRecorder
-from dllama_tpu.obs.slo import SloTracker, resolve_slo_knobs
+from dllama_tpu.obs.slo import SloTracker
 from dllama_tpu.obs.spans import SpanTracker
 from dllama_tpu.obs.watchdog import EngineWatchdog
+
+from helpers import assert_one_spelling
 
 pytestmark = pytest.mark.fast
 
@@ -471,15 +473,15 @@ def test_slo_observe_span():
     assert slo.snapshot()["windows"]["10s"]["n_requests"] == 1
 
 
-def test_slo_knob_resolution(monkeypatch):
-    monkeypatch.delenv("DLLAMA_SLO_TTFT_MS", raising=False)
-    monkeypatch.delenv("DLLAMA_SLO_TPOT_MS", raising=False)
-    assert resolve_slo_knobs() == (None, None)
-    monkeypatch.setenv("DLLAMA_SLO_TTFT_MS", "250")
-    monkeypatch.setenv("DLLAMA_SLO_TPOT_MS", "40")
-    assert resolve_slo_knobs() == (250.0, 40.0)
-    # explicit beats env, same precedence as the lane knobs
-    assert resolve_slo_knobs(ttft_ms=500.0) == (500.0, 40.0)
+KNOB_TWINS = ("DLLAMA_SLO_TTFT_MS", "DLLAMA_SLO_TPOT_MS")
+
+
+@pytest.mark.parametrize("name", KNOB_TWINS)
+def test_slo_knob_resolution(unflagged, flagged, name):
+    """No target unless `--slo-ttft-ms` / `--slo-tpot-ms` names one, with
+    the former variable set (`resolve_slo_knobs` read it until PR 45), and
+    an explicit flag is the tracker's target."""
+    assert_one_spelling(name, unflagged, flagged)
 
 
 # -- EngineWatchdog ----------------------------------------------------------

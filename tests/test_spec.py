@@ -30,7 +30,6 @@ from dllama_tpu.runtime.api_server import (
     ApiState,
     ChatMessage,
     InferenceParams,
-    resolve_spec_knobs,
 )
 from dllama_tpu.runtime.engine import InferenceEngine
 from dllama_tpu.runtime.spec import (
@@ -41,7 +40,7 @@ from dllama_tpu.runtime.spec import (
 )
 from dllama_tpu.tokenizer import Tokenizer
 
-from helpers import make_tiny_model, make_tiny_tokenizer
+from helpers import assert_one_spelling, make_tiny_model, make_tiny_tokenizer
 
 CFG = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
            head_dim=16, vocab_size=288, seq_len=384)
@@ -178,18 +177,23 @@ def test_spec_buckets_and_bucket_for():
     assert bucket_for(8, (1, 2, 4, 8)) == 8
 
 
-@pytest.mark.fast
-def test_spec_knob_resolution(monkeypatch):
-    monkeypatch.delenv("DLLAMA_SPECULATION", raising=False)
-    monkeypatch.delenv("DLLAMA_SPEC_K", raising=False)
-    assert resolve_spec_knobs() == ("off", 4)
-    monkeypatch.setenv("DLLAMA_SPECULATION", "ngram")
-    monkeypatch.setenv("DLLAMA_SPEC_K", "8")
-    assert resolve_spec_knobs() == ("ngram", 8)
-    # explicit beats env
-    assert resolve_spec_knobs("off", 2) == ("off", 2)
-    with pytest.raises(ValueError):
-        resolve_spec_knobs("eagle")
+KNOB_TWINS = ("DLLAMA_SPECULATION", "DLLAMA_SPEC_K")
+
+
+@pytest.mark.parametrize("name", KNOB_TWINS)
+def test_spec_knob_resolution(unflagged, flagged, name):
+    """`--speculation off` and `--spec-k 4` with the former variable set
+    (`resolve_spec_knobs` read it until PR 45); an explicit flag is the
+    scheduler's mode and k. A mode outside `SPEC_MODES` is the parser's to
+    refuse, and `ApiState`'s where it is built directly."""
+    from dllama_tpu.runtime.api_server import ApiState, build_arg_parser
+
+    assert_one_spelling(name, unflagged, flagged)
+    with pytest.raises(SystemExit):
+        build_arg_parser().parse_args(["--speculation", "eagle"])
+    _, state = unflagged
+    with pytest.raises(ValueError, match="speculation must be one of"):
+        ApiState(state.engine, state.tokenizer, speculation="eagle")
 
 
 @pytest.mark.fast
@@ -205,7 +209,7 @@ def test_spec_cli_flags(tmp_path):
     )
     assert args.speculation == "ngram" and args.spec_k == 8
     args = parser.parse_args(["--model", "m"])
-    assert args.speculation is None and args.spec_k is None
+    assert args.speculation == "off" and args.spec_k == 4
 
 
 # -- engine verify parity -----------------------------------------------------

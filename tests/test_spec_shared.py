@@ -41,11 +41,10 @@ from dllama_tpu.runtime.spec import (
     NgramDrafter,
     NgramIndex,
     SharedNgramStore,
-    resolve_draft_model,
 )
 from dllama_tpu.tokenizer import Tokenizer
 
-from helpers import make_tiny_model, make_tiny_tokenizer
+from helpers import FORMER_TWINS, flags_state, make_tiny_model, make_tiny_tokenizer
 
 CFG = dict(dim=64, hidden_dim=160, n_layers=2, n_heads=8, n_kv_heads=4,
            head_dim=16, vocab_size=288, seq_len=384)
@@ -293,13 +292,34 @@ def test_drafter_model_budget_gating():
     assert dr3.model_budget() == 0
 
 
-@pytest.mark.fast
-def test_resolve_draft_model(monkeypatch):
-    monkeypatch.delenv("DLLAMA_DRAFT_MODEL", raising=False)
-    assert resolve_draft_model() is None
-    monkeypatch.setenv("DLLAMA_DRAFT_MODEL", "/env/d.m")
-    assert resolve_draft_model() == "/env/d.m"
-    assert resolve_draft_model("/cli/d.m") == "/cli/d.m"  # explicit wins
+@pytest.fixture(scope="module")
+def flagged(tmp_path_factory, tiny_paths):
+    """`--speculation draft --draft-model <the target itself>`."""
+    mp, _ = tiny_paths
+    yield from flags_state(
+        tmp_path_factory, "--speculation", "draft", "--draft-model", mp,
+        paths=tiny_paths)
+
+
+@pytest.mark.parametrize("name", ["DLLAMA_DRAFT_MODEL"])
+def test_resolve_draft_model(monkeypatch, unflagged, flagged, tiny_paths, name):
+    """No draft model unless `--draft-model` names one, with the former
+    variable set (`resolve_draft_model` read it until PR 45): mode `draft`
+    without the flag fails at start-up though the variable names a path,
+    and the flag's checkpoint is the one the engine loads."""
+    from dllama_tpu.runtime.api_server import serve
+
+    _, dest, default, read = FORMER_TWINS[name]
+    args, state = unflagged
+    assert getattr(args, dest) is default and read(state) is None
+    args, state = flagged
+    assert args.draft_model == tiny_paths[0]
+    assert state.engine.has_draft_model and state.scheduler.spec_mode == "draft"
+    monkeypatch.setenv(name, tiny_paths[0])
+    _, bare = unflagged
+    with pytest.raises(ValueError, match="--draft-model"):
+        serve(bare.engine, bare.tokenizer, speculation="draft")
+    assert not bare.engine.has_draft_model
 
 
 @pytest.mark.fast

@@ -29,6 +29,8 @@ from dllama_tpu.obs.timeseries import (
     resolve_series_knobs,
 )
 
+from helpers import assert_one_spelling
+
 pytestmark = pytest.mark.fast
 
 
@@ -43,18 +45,27 @@ def _store(**kw):
 # -- knob resolution --------------------------------------------------------
 
 
+KNOB_TWINS = ("DLLAMA_SERIES_RETENTION_S",)
+
+
 def test_series_knob_defaults(monkeypatch):
-    monkeypatch.delenv("DLLAMA_SERIES_RETENTION_S", raising=False)
+    """An hour's retention is `--series-retention`'s default; the sampling
+    interval has no flag and stays `DLLAMA_SERIES_INTERVAL_S`'s, 1 s unset."""
+    from dllama_tpu.runtime.api_server import build_arg_parser
+
+    assert build_arg_parser().parse_args([]).series_retention == 3600.0
     monkeypatch.delenv("DLLAMA_SERIES_INTERVAL_S", raising=False)
-    assert resolve_series_knobs() == (3600.0, 1.0)
-
-
-def test_series_knob_env_and_explicit(monkeypatch):
-    monkeypatch.setenv("DLLAMA_SERIES_RETENTION_S", "120")
+    assert resolve_series_knobs() == 1.0
     monkeypatch.setenv("DLLAMA_SERIES_INTERVAL_S", "0.5")
-    assert resolve_series_knobs() == (120.0, 0.5)
-    # explicit (the CLI flag) beats env
-    assert resolve_series_knobs(retention_s=60.0) == (60.0, 0.5)
+    assert resolve_series_knobs() == 0.5
+
+
+@pytest.mark.parametrize("name", KNOB_TWINS)
+def test_series_knob_env_and_explicit(unflagged, flagged, name):
+    """The store keeps an hour with the former variable set
+    (`resolve_series_knobs` read it until PR 45), and `--series-retention
+    120` is the store's retention."""
+    assert_one_spelling(name, unflagged, flagged)
 
 
 # -- registry: flat_values + refresh hooks ----------------------------------
