@@ -69,6 +69,15 @@ class LlmArch(enum.IntEnum):
     # dense layers, then a sigmoid router with a selection bias
     # (`model_type: lfm2_moe`)
     LFM2_MOE = 0xABCD13
+    # Mamba-2 layers where most layers' attention would be: such a layer
+    # keeps a recurrent state a lane (`ssm_n_heads` x `ssm_head_dim` x
+    # `ssm_state_dim`, float32, reaching back to position 0) and the last
+    # `ssm_conv_taps - 1` rows of its convolution's input, and no cache row;
+    # the layers named by the attention mask are grouped-query attention
+    # without rope under a stated score scale; every layer's FFN is a softmax
+    # router's experts and a shared expert; multipliers on the embedding, the
+    # residual adds and the logits (`model_type: granitemoehybrid`)
+    GRANITE_MOE_HYBRID = 0xABCD14
 
 
 class RopeType(enum.IntEnum):
@@ -147,6 +156,17 @@ class HeaderKey(enum.IntEnum):
     CONV_L_CACHE = 47  # taps of the depthwise convolution; > 0: a layer is one unless named below
     ATTN_LAYERS_LO = 48  # bit l: layer l is attention (`layer_types`), l < 30
     ATTN_LAYERS_HI = 49  # bit l - 30: layer l is attention, 30 <= l < 60
+    # Mamba-2 layers (0: none); > 0: a layer is one unless the mask above names it
+    SSM_N_HEADS = 50  # heads of the recurrence
+    SSM_HEAD_DIM = 51  # a head's width: heads x this is the mixer's inner width
+    SSM_STATE_DIM = 52  # columns of a head's state, and of B and C
+    SSM_N_GROUPS = 53  # groups that share B and C
+    SSM_CONV_TAPS = 54  # taps of the depthwise convolution over `[x | B | C]`
+    # multipliers (0 or absent: 1, or what EMBED_SCALE says)
+    EMBED_MULTIPLIER_MILLI = 55  # embeddings times this, in thousandths
+    RESIDUAL_MULTIPLIER_NANO = 56  # each block's output times this before its add, in 1e-9
+    ATTENTION_MULTIPLIER_NANO = 57  # attention scores times this, not head_dim^-1/2, in 1e-9
+    LOGITS_SCALING_MILLI = 58  # logits divided by this, in thousandths
 
 
 @dataclasses.dataclass
@@ -202,7 +222,16 @@ class LlmHeader:
     rope_mscale: float = 0.0
     rope_mscale_all_dim: float = 0.0
     conv_l_cache: int = 0
-    attn_layers: int = 0  # bit l: layer l is attention, where conv_l_cache > 0
+    attn_layers: int = 0  # bit l: layer l is attention, where some layers keep a state
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_dim: int = 0
+    ssm_n_groups: int = 1
+    ssm_conv_taps: int = 0
+    embed_multiplier: float = 0.0  # 0: none (or sqrt(dim), where embed_scale)
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0  # 0: head_dim^-1/2
+    logits_scaling: float = 1.0
     header_bytes: int = 0
     file_size: int = 0
     sync_type: FloatType = FloatType.Q80
@@ -233,13 +262,30 @@ class LlmHeader:
     @property
     def stateful(self) -> bool:
         """Some layers keep a state a lane (the last gated rows of a short
-        convolution) and no cache row a position."""
-        return self.conv_l_cache > 0
+        convolution; a Mamba-2 layer's recurrent state and convolution rows)
+        and no cache row a position."""
+        return self.conv_l_cache > 0 or self.ssm_n_heads > 0
+
+    @property
+    def state_unbounded(self) -> bool:
+        """A lane's state reaches back to position 0 (a recurrence), so no
+        replay of a few positions rebuilds it behind an adopted prefix."""
+        return self.ssm_n_heads > 0
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of a Mamba-2 mixer's `x` and `z`: heads x head width."""
+        return self.ssm_n_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels its convolution runs over: `[x | B | C]`."""
+        return self.ssm_inner + 2 * self.ssm_n_groups * self.ssm_state_dim
 
     @property
     def conv_state_rows(self) -> int:
-        """Rows of a convolution layer's state: the taps before the newest."""
-        return self.conv_l_cache - 1
+        """Rows of a state layer's convolution state: the taps before the newest."""
+        return (self.ssm_conv_taps or self.conv_l_cache) - 1
 
     @property
     def kv_pack(self) -> int:
@@ -259,10 +305,11 @@ class LlmHeader:
 
     @property
     def softmax_scale(self) -> float:
-        """What attention scores are multiplied by: 1 / sqrt(head_dim), and
+        """What attention scores are multiplied by: 1 / sqrt(head_dim) (or the
+        header's `attention_multiplier`), and
         under a rotary table scaled by band the square of the magnitude
         factor that goes with it."""
-        scale = float(self.head_dim) ** -0.5
+        scale = self.attention_multiplier or float(self.head_dim) ** -0.5
         if self.rope_type == RopeType.YARN and self.rope_mscale_all_dim:
             scale *= yarn_mscale(self.rope_scaling_factor, self.rope_mscale_all_dim) ** 2
         return scale
@@ -427,6 +474,24 @@ def read_llm_header(
                 h.attn_layers |= value
             elif key == HeaderKey.ATTN_LAYERS_HI:
                 h.attn_layers |= value << _ATTN_MASK_BITS
+            elif key == HeaderKey.SSM_N_HEADS:
+                h.ssm_n_heads = value
+            elif key == HeaderKey.SSM_HEAD_DIM:
+                h.ssm_head_dim = value
+            elif key == HeaderKey.SSM_STATE_DIM:
+                h.ssm_state_dim = value
+            elif key == HeaderKey.SSM_N_GROUPS:
+                h.ssm_n_groups = value or 1
+            elif key == HeaderKey.SSM_CONV_TAPS:
+                h.ssm_conv_taps = value
+            elif key == HeaderKey.EMBED_MULTIPLIER_MILLI:
+                h.embed_multiplier = value / 1e3
+            elif key == HeaderKey.RESIDUAL_MULTIPLIER_NANO:
+                h.residual_multiplier = value / 1e9 if value else 1.0
+            elif key == HeaderKey.ATTENTION_MULTIPLIER_NANO:
+                h.attention_multiplier = value / 1e9
+            elif key == HeaderKey.LOGITS_SCALING_MILLI:
+                h.logits_scaling = value / 1e3 if value else 1.0
 
         if weight_type is None:
             raise ValueError("model does not specify weight type")
@@ -454,16 +519,26 @@ def read_llm_header(
                   LlmArch.LFM2_MOE):
         h.rope_type = RopeType.FALCON
     if h.stateful:
-        if h.conv_l_cache < 2 or h.latent or h.sliding_window:
+        taps = h.ssm_conv_taps or h.conv_l_cache
+        if taps < 2 or h.latent or h.sliding_window or (h.conv_l_cache and h.ssm_n_heads):
             raise ValueError(
-                f"a short convolution of {h.conv_l_cache} taps beside latent or "
-                "window attention: conv_l_cache >= 2, and full attention alone"
+                f"state layers with a convolution of {taps} taps beside latent or "
+                "window attention, or of two kinds: conv_l_cache >= 2 or "
+                "ssm_conv_taps >= 2, one kind of state layer, and full attention alone"
+            )
+        if h.ssm_n_heads and not (
+            h.ssm_head_dim and h.ssm_state_dim and h.ssm_n_groups == 1
+        ):
+            raise ValueError(
+                f"Mamba-2 layers of {h.ssm_n_heads} heads need ssm_head_dim and "
+                f"ssm_state_dim, and one group that shares B and C "
+                f"(ssm_n_groups {h.ssm_n_groups})"
             )
         if h.attn_layers in (0, (1 << h.n_layers) - 1):
             raise ValueError(
                 f"attention layers {h.attn_layers:#x} of {h.n_layers} layers: a model "
-                "with convolution layers has layers of both kinds (conv_l_cache 0: "
-                "every layer attends)"
+                "with state layers has layers of both kinds (conv_l_cache and "
+                "ssm_n_heads 0: every layer attends)"
             )
         if h.n_layers > 2 * _ATTN_MASK_BITS or h.attn_layers >> h.n_layers:
             raise ValueError(
@@ -505,12 +580,18 @@ class LayerKind:
     ffn_row: int
     latent: bool = False  # the cache row is `[c | k_rope]`, one head for all
     conv: bool = False  # a gated short convolution stands where attention would
+    ssm: bool = False  # a Mamba-2 mixer does
+
+    @property
+    def keeps_state(self) -> bool:
+        """The layer keeps a state a lane and writes no cache row."""
+        return self.conv or self.ssm
 
     @property
     def cache(self) -> str:
         """The kind of cache the layer's rows live in; `state`: none, the
         layer keeps a state a lane."""
-        if self.conv:
+        if self.keeps_state:
             return "state"
         return "latent" if self.latent else "window" if self.window else "full"
 
@@ -526,13 +607,14 @@ def layer_table(h: LlmHeader) -> tuple[LayerKind, ...]:
             h.full_attn_period and (l + 1) % h.full_attn_period == 0
         )
         experts = h.n_experts > 0 and l >= h.n_dense_layers
-        conv = h.stateful and not h.attn_layers >> l & 1
-        cache = "state" if conv else "latent" if h.latent else "window" if window else "full"
+        state = h.stateful and not h.attn_layers >> l & 1
+        cache = "state" if state else "latent" if h.latent else "window" if window else "full"
         table.append(LayerKind(
             # a latent layer turns the rope columns of its heads itself
             window,
-            not conv and not h.latent and (window or not h.full_attn_no_rope),
-            experts, rows[cache], ffn_rows[experts], h.latent, conv,
+            not state and not h.latent and (window or not h.full_attn_no_rope),
+            experts, rows[cache], ffn_rows[experts], h.latent,
+            state and not h.ssm_n_heads, state and h.ssm_n_heads > 0,
         ))
         rows[cache] += 1
         ffn_rows[experts] += 1
@@ -615,6 +697,22 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
             add(f"layers.{l}.conv_in", wt, (3 * h.dim, h.dim))
             add(f"layers.{l}.conv_w", FloatType.F32, (h.dim, h.conv_l_cache))
             add(f"layers.{l}.conv_out", wt, (h.dim, h.dim))
+        elif kind.ssm:
+            # `in_proj`'s rows `[z | x B C | dt]` as three tensors one behind
+            # the other (the bytes of one [inner + conv_dim + heads, dim]
+            # tensor), the depthwise taps (the last meets the newest row) and
+            # their bias, a head's step bias, log decay rate and skip, the
+            # gains of the norm behind the gate, and the projection back
+            add(f"layers.{l}.ssm_in_z", wt, (h.ssm_inner, h.dim))
+            add(f"layers.{l}.ssm_in_xbc", wt, (h.ssm_conv_dim, h.dim))
+            add(f"layers.{l}.ssm_in_dt", wt, (h.ssm_n_heads, h.dim))
+            add(f"layers.{l}.ssm_conv_w", FloatType.F32, (h.ssm_conv_dim, h.ssm_conv_taps))
+            add(f"layers.{l}.ssm_conv_b", FloatType.F32, (h.ssm_conv_dim,))
+            add(f"layers.{l}.ssm_dt_bias", FloatType.F32, (h.ssm_n_heads,))
+            add(f"layers.{l}.ssm_a_log", FloatType.F32, (h.ssm_n_heads,))
+            add(f"layers.{l}.ssm_d", FloatType.F32, (h.ssm_n_heads,))
+            add(f"layers.{l}.ssm_norm", FloatType.F32, (h.ssm_inner,))
+            add(f"layers.{l}.ssm_out", wt, (h.dim, h.ssm_inner))
         else:
             add(f"layers.{l}.q", wt, (h.q_dim, h.dim))
             add(f"layers.{l}.k", wt, (h.kv_dim, h.dim))
@@ -632,7 +730,7 @@ def tensor_plan(h: LlmHeader) -> list[TensorSpec]:
                 swiglu(f"layers.{l}.experts.{e}", h.ff_dim)
         else:  # leading dense layers are HIDDEN_DIM wide beside experts of ff_dim
             swiglu(f"layers.{l}", h.hidden_dim if h.arch in _WIDE_DENSE else h.ff_dim)
-        if h.arch in _QK_NORM and not kind.conv:
+        if h.arch in _QK_NORM and not kind.keeps_state:
             add(f"layers.{l}.q_norm", FloatType.F32, (h.head_dim,))
             add(f"layers.{l}.k_norm", FloatType.F32, (h.head_dim,))
         add(f"layers.{l}.att_norm", FloatType.F32, (h.dim,))

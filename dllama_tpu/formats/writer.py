@@ -67,6 +67,15 @@ HEADER_KEYS = {
     "conv_l_cache": 47,
     "attn_layers_lo": 48,
     "attn_layers_hi": 49,
+    "ssm_n_heads": 50,
+    "ssm_head_dim": 51,
+    "ssm_state_dim": 52,
+    "ssm_n_groups": 53,
+    "ssm_conv_taps": 54,
+    "embed_multiplier_milli": 55,
+    "residual_multiplier_nano": 56,
+    "attention_multiplier_nano": 57,
+    "logits_scaling_milli": 58,
 }
 
 

@@ -385,8 +385,9 @@ def load_params(
     expert_layers = [l for l in every if table[l].experts]
     # and where some layers are gated short convolutions, each operator's
     # leaves over the layers of its own kind
-    attn_layers = [l for l in every if not table[l].conv]
+    attn_layers = [l for l in every if not table[l].keeps_state]
     conv_layers = [l for l in every if table[l].conv]
+    ssm_layers = [l for l in every if table[l].ssm]
 
     def stack(fn: Callable[[int], np.ndarray], layers=every) -> np.ndarray:
         return np.stack([fn(l) for l in layers])
@@ -429,7 +430,7 @@ def load_params(
         "ffn_norm", stack(lambda l: w(f"layers.{l}.ffn_norm", False))
     )
     def qw_fused(
-        tag: str, names: list[Callable[[int], str]], layers=every
+        tag: str, names: list[Callable[[int], str]], layers=every, fuse: int = fuse
     ) -> FusedQuantWeight:
         """Stacked FusedQuantWeight fusing several row-split matmul tensors
         along the out axis, shard-major for `fuse` tp shards; the fuse
@@ -468,6 +469,27 @@ def load_params(
         # the taps as [K, D] rows, f32 as the norms are
         layers["conv_w"] = put(
             "conv_w", stack(lambda l: w(f"layers.{l}.conv_w"), conv_layers))
+    if ssm_layers:
+        # a Mamba-2 mixer: `in_proj`'s three tensors as one matmul `[z | xBC |
+        # dt]` (one device holds it: no interleave), the projection back, and
+        # the small leaves f32 as the norms are, the taps as [K, C] rows
+        parts = ("ssm_in_z", "ssm_in_xbc", "ssm_in_dt")
+        if quantize:
+            layers["ssm_in"] = qw_fused(
+                "ssm_in", [lambda l, n=n: f"layers.{l}.{n}" for n in parts], ssm_layers,
+                fuse=1,
+            ).weight
+            layers["ssm_out"] = qw("ssm_out", lambda l: f"layers.{l}.ssm_out", ssm_layers)
+        else:
+            layers["ssm_in"] = put("ssm_in", stack(
+                lambda l: np.concatenate([w(f"layers.{l}.{n}") for n in parts], axis=1),
+                ssm_layers).astype(dtype))
+            layers["ssm_out"] = put("ssm_out", stack(
+                lambda l: w(f"layers.{l}.ssm_out"), ssm_layers).astype(dtype))
+        layers["ssm_conv_w"] = put(
+            "ssm_conv_w", stack(lambda l: w(f"layers.{l}.ssm_conv_w"), ssm_layers))
+        for n in ("ssm_conv_b", "ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_norm"):
+            layers[n] = put(n, stack(lambda l, n=n: w(f"layers.{l}.{n}", False), ssm_layers))
     has_gate = "layers.0.att_gate" in reader.by_name
     qkv = ["q", "k", "v"] + (["att_gate"] if has_gate else [])
     if h.latent:

@@ -64,6 +64,8 @@ from ..ops.kv_cache import (
     write_rows,
 )
 from ..ops.short_conv import short_conv_chunk, short_conv_step
+from ..ops.ssm_scan import (
+    SsmShape, lane_state, put_lane_state, ssm_chunk, ssm_step, ssm_step_in_place)
 from ..ops.sparse_index import index_scores, select_rows
 from ..ops.moe_kernel import (
     moe_active_experts,
@@ -121,10 +123,14 @@ def _mm_manual(
     return reduce(jnp.einsum("bti,io->bto", x, w))
 
 
-# what a convolution layer's operator reads, and with it what attention's
-# does: stacked over the layers of their own kind where a model has both
-_CONV_LEAVES = ("conv_in", "conv_w", "conv_out")
-_OPERATOR_LEAVES = _CONV_LEAVES + (
+# what a state layer's operator reads (a gated short convolution's, a Mamba-2
+# mixer's), and with it what attention's does: stacked over the layers of
+# their own kind where a model has both
+_STATE_LEAVES = (
+    "conv_in", "conv_w", "conv_out",
+    "ssm_in", "ssm_out", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
+    "ssm_d", "ssm_norm")
+_OPERATOR_LEAVES = _STATE_LEAVES + (
     "wq", "wk", "wv", "wqkv", "wo", "wg", "q_norm", "k_norm")
 
 
@@ -184,9 +190,16 @@ def init_kv_cache(
     `c` of `[L, B, 1, S, kv_lora_rank + qk_rope_head_dim]` and, where a
     learned index picks the rows a query attends to (`index_topk > 0`), a
     second, `i`, of the positions' index keys, `index_head_dim` wide. A model
-    whose convolution layers keep a state a lane (`h.stateful`) gets `k`/`v`
-    over its attention layers alone and a state stack `s` of
-    `[convolution layers, B, conv_l_cache - 1, dim]` beside them."""
+    some of whose layers keep a state a lane (`h.stateful`) gets `k`/`v` over
+    its attention layers alone and a state stack `s` of `[state layers, B,
+    taps - 1, channels]` beside them: a gated short convolution's gated rows,
+    `dim` wide, or a Mamba-2 mixer's convolution input, `ssm_conv_dim` wide,
+    and then a second stack `r` of the recurrent states, float32 whatever
+    `dtype` is, `[state layers, B, ssm_state_dim, ssm_n_heads * ssm_head_dim]`
+    (`ops/ssm_scan.py` says why the columns lead; and every head's rows are
+    one axis: with the heads an axis of their own the chip's compiler re-laid
+    the whole stack out for a chunk program's products, head width major,
+    2.4 GB copied in and out of every chunk)."""
     s = seq_len or h.seq_len
     if h.latent:
         # one stack of `[c | k_rope]` rows, one head for every query head:
@@ -198,12 +211,12 @@ def init_kv_cache(
             cache["i"] = jnp.zeros((h.n_layers, batch_size, 1, s, h.index_head_dim), dtype)
         return cache
     n_window = sum(kind.window for kind in layer_table(h))
-    n_conv = sum(kind.conv for kind in layer_table(h))
+    n_conv = sum(kind.keeps_state for kind in layer_table(h))
     shape = (h.n_layers - n_window - n_conv, batch_size, h.n_kv_heads // h.kv_pack, s,
              h.head_dim * h.kv_pack)
     if dtype == jnp.int8:
         if n_conv:
-            raise NotImplementedError("a convolution layer's state is not quantized: int8 KV")
+            raise NotImplementedError("a state layer's state is not quantized: int8 KV")
         if n_window:
             raise NotImplementedError("window layers' ring cache is not quantized: int8 KV")
 
@@ -224,7 +237,11 @@ def init_kv_cache(
         cache["kw"] = jnp.zeros(shape, dtype=dtype)
         cache["vw"] = jnp.zeros(shape, dtype=dtype)
     if n_conv:
-        cache["s"] = jnp.zeros((n_conv, batch_size, h.conv_state_rows, h.dim), dtype)
+        cache["s"] = jnp.zeros(
+            (n_conv, batch_size, h.conv_state_rows, h.ssm_conv_dim or h.dim), dtype)
+        if h.ssm_n_heads:
+            cache["r"] = jnp.zeros(
+                (n_conv, batch_size, h.ssm_state_dim, h.ssm_inner), jnp.float32)
     return cache
 
 
@@ -1039,12 +1056,12 @@ def forward(
     # `pos` may be a [B] vector: each batch lane decodes at its own
     # position (independent request lanes — the continuous-batching
     # surface the reference's single-stream loop lacks)
-    names = [n for n in ("k", "v", "kw", "vw", "c", "i", "s") if n in cache]
+    names = [n for n in ("k", "v", "kw", "vw", "c", "i", "s", "r") if n in cache]
     attn_pos = attn_positions(pos, attn_park_threshold, cache[names[0]].shape[3])
 
     x = params["embed"][tokens]  # [B, T, D] (reference: OP_EMBEDDING)
-    if h.embed_scale:
-        x = (x.astype(jnp.float32) * float(h.dim) ** 0.5).astype(x.dtype)
+    if h.embed_scale or h.embed_multiplier:
+        x = (x.astype(jnp.float32) * (h.embed_multiplier or float(h.dim) ** 0.5)).astype(x.dtype)
 
     cos, sin = rope_slices(params, pos, t)
     x, *caches = run_layers(
@@ -1054,8 +1071,8 @@ def forward(
         kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
         route_stats=route_stats, c_cache=cache.get("c"), i_cache=cache.get("i"),
         one_live_lane=one_live_lane,
-        **({"s_cache": cache["s"], "state_rows": state_rows, "state_fresh": state_fresh,
-            "write_floor": write_floor} if "s" in cache else {}),
+        **({"s_cache": cache["s"], "r_cache": cache.get("r"), "state_rows": state_rows,
+            "state_fresh": state_fresh, "write_floor": write_floor} if "s" in cache else {}),
     )
     logits = logits_head(x, params, h, mesh, logits_mode)
     return logits, dict(zip(names, caches))
@@ -1116,10 +1133,12 @@ def logits_head(
             )
         return lax.all_gather(local, tp_axis, axis=-1, tiled=True)
     if isinstance(wcls, _QUANT_CLASSES):
-        return qmatmul_tp(y, wcls, "row", mesh)
-    return jnp.einsum(
-        "btd,dv->btv", y.astype(jnp.float32), wcls.astype(jnp.float32)
-    )
+        logits = qmatmul_tp(y, wcls, "row", mesh)
+    else:
+        logits = jnp.einsum(
+            "btd,dv->btv", y.astype(jnp.float32), wcls.astype(jnp.float32)
+        )
+    return logits if h.logits_scaling == 1.0 else logits / h.logits_scaling
 
 
 def run_layers(
@@ -1147,7 +1166,8 @@ def run_layers(
     c_cache: jnp.ndarray | None = None,  # [L, B, 1, S, W]: latent layers, alone
     one_live_lane: bool = False,
     i_cache: jnp.ndarray | None = None,  # [L, B, 1, S, dI]: their index keys
-    s_cache: jnp.ndarray | None = None,  # [Lc, B, K - 1, D]: convolution layers' states
+    s_cache: jnp.ndarray | None = None,  # [Ls, B, K - 1, C]: state layers' convolution rows
+    r_cache: jnp.ndarray | None = None,  # [Ls, B, N, H * P] f32: Mamba-2 layers' recurrent states
     state_rows: jnp.ndarray | None = None,  # [B] int32
     state_fresh: jnp.ndarray | None = None,  # [B] bool
     write_floor: jnp.ndarray | None = None,  # int32 scalar
@@ -1208,9 +1228,12 @@ def run_layers(
     lanes are split over devices (`dp`) every lane's rows are computed as
     before: the slice would gather across them.
 
-    `s_cache`: a model some of whose layers are gated short convolutions
-    (`LayerKind.conv`) carries their states behind `k` and `v`, which then
-    hold the attention layers alone; returns (x, k_new, v_new, s_new). A
+    `s_cache`: a model some of whose layers keep a state a lane
+    (`LayerKind.keeps_state`: gated short convolutions, Mamba-2 mixers)
+    carries their states behind `k` and `v`, which then hold the attention
+    layers alone; returns (x, k_new, v_new, s_new), and r_new, the Mamba-2
+    layers' recurrent states (`r_cache`), behind them where the model has
+    such. A
     state belongs to a lane and not to a position, so parking and padding
     must not move it: lane b's state advances by its first `state_rows[b]`
     rows of the T (left out: all T of a lane whose `attn_pos` is a position,
@@ -1244,7 +1267,7 @@ def run_layers(
     if latent != layer_table(h)[0].latent or (i_cache is not None) != h.indexed:
         raise ValueError("the layer table and the cache stacks disagree on latent rows")
     stateful = s_cache is not None
-    if stateful != h.stateful:
+    if stateful != h.stateful or (r_cache is not None) != (h.ssm_n_heads > 0):
         raise ValueError("the layer table and the cache stacks disagree on lane state")
     if stateful and not (
         (mesh is None or mesh.devices.size == 1) and tp_axis is None and sp_axis is None
@@ -1463,6 +1486,12 @@ def run_layers(
         )
 
     phase = "decode" if t == 1 else "prefill"
+
+    def scaled(o):
+        """A block's output as its residual add takes it."""
+        if h.residual_multiplier == 1.0:
+            return o
+        return (o.astype(jnp.float32) * h.residual_multiplier).astype(o.dtype)
 
     def write_kv(caches, k, v, row, is_window):
         """The chunk's keys and values into the layer's row of its stack.
@@ -1712,21 +1741,29 @@ def run_layers(
         z = z.reshape(b, t, hq, h.kv_pack, h.head_dim)
         return jnp.sum(z * head_slot()[:, :, None], axis=3).reshape(b, t, hq * h.head_dim)
 
+    def carried_rows(y, s_cache, srow):
+        """Layer `srow`'s rows of the state stack `s` for the lanes `y` holds
+        (every lane, or the admitted one of a chunk program), zero where a
+        lane starts from nothing: (whether that is the admitted lane alone,
+        the rows [B or 1, K - 1, C], each lane's real rows of the T, the lanes
+        that start from zero, where the rows lie in the stack)."""
+        rows, zero = state_rows, state_zero
+        alone = y.shape[0] == 1 and b > 1
+        if alone:
+            state = lax.dynamic_slice(
+                s_cache, (srow, lane, 0, 0), (1, 1, *s_cache.shape[2:]))[0]
+            rows = lax.dynamic_slice_in_dim(rows, lane, 1)
+            zero = lax.dynamic_slice_in_dim(zero, lane, 1)
+        else:
+            state = lax.dynamic_index_in_dim(s_cache, srow, 0, keepdims=False)
+        state = jnp.where(zero[:, None, None], jnp.zeros((), state.dtype), state)
+        return alone, state, rows, zero, (srow, lane if alone else 0, 0, 0)
+
     def conv_operator(y, lp, s_cache, srow, mm):
         """A convolution layer's operator over `y`'s rows (every lane's, or
         the admitted lane's [1, T, D]): (its output, the state stack with
         the layer's row `srow` moved on)."""
-        ks, d = s_cache.shape[2], s_cache.shape[3]
-        rows, zero = state_rows, state_zero
-        if y.shape[0] == 1 and b > 1:  # the admitted lane alone
-            state = lax.dynamic_slice(s_cache, (srow, lane, 0, 0), (1, 1, ks, d))[0]
-            rows = lax.dynamic_slice_in_dim(rows, lane, 1)
-            zero = lax.dynamic_slice_in_dim(zero, lane, 1)
-            at = (srow, lane, 0, 0)
-        else:
-            state = lax.dynamic_index_in_dim(s_cache, srow, 0, keepdims=False)
-            at = (srow, 0, 0, 0)
-        state = jnp.where(zero[:, None, None], jnp.zeros((), state.dtype), state)
+        _, state, rows, _, at = carried_rows(y, s_cache, srow)
         bcx = mm(y, lp["conv_in"], "row")
         with jax.named_scope("mix"):
             if t == 1:
@@ -1736,6 +1773,50 @@ def run_layers(
         o = mm(o, lp["conv_out"], "col", sync=True)
         return o, lax.dynamic_update_slice(s_cache, state[None], at)
 
+    ssm_shape = SsmShape(
+        h.ssm_n_heads, h.ssm_head_dim, h.ssm_state_dim, eps=h.norm_epsilon)
+
+    # a decode step's live lanes, first in the order `ssm_step_in_place` visits
+    live_order = None
+    if h.ssm_n_heads and t == 1:
+        live_order = (
+            jnp.argsort(state_rows <= 0, stable=True).astype(jnp.int32),
+            jnp.sum(state_rows > 0).astype(jnp.int32))
+
+    def ssm_operator(y, lp, s_cache, r_cache, srow, mm):
+        """A Mamba-2 layer's mixer over `y`'s rows (every lane's, or the
+        admitted lane's [1, T, D]): (its output, both state stacks with the
+        layer's row `srow` moved on). A chunk program reads and writes the
+        admitted lane's slice of the stacks alone; a decode step on the chip
+        the live lanes' (`ssm_step_in_place`), elsewhere every lane's, a
+        parked lane's as it was."""
+        alone, conv, rows, zero, at = carried_rows(y, s_cache, srow)
+        zxd = mm(y, lp["ssm_in"], "row")
+        # on the chip the recurrent stack is read and written by kernels alone,
+        # where and as it lies (`ops/ssm_scan.lane_state` says why)
+        on_chip = jax.default_backend() == "tpu"
+        if t == 1 and not alone and on_chip:
+            with jax.named_scope("mix"):
+                o, r_cache, conv = ssm_step_in_place(
+                    zxd, lp, r_cache, srow, conv, rows > 0, zero, ssm_shape, live_order)
+        else:
+            by_kernel = alone and on_chip
+            rec = lane_state(r_cache, srow, lane) if by_kernel else lax.dynamic_slice(
+                r_cache, at, (1, conv.shape[0], *r_cache.shape[2:]))[0]
+            # a head's [N, P] block out of the stack's columns, and back
+            heads = (*rec.shape[:2], h.ssm_n_heads, h.ssm_head_dim)
+            rec = jnp.where(zero[:, None, None], 0.0, rec).reshape(heads)
+            with jax.named_scope("mix"):
+                if t == 1:
+                    o, rec, conv = ssm_step(zxd, lp, rec, conv, rows > 0, ssm_shape)
+                else:
+                    o, rec, conv = ssm_chunk(zxd, lp, rec, conv, rows, ssm_shape)
+            rec = rec.reshape(-1, *r_cache.shape[2:])
+            r_cache = put_lane_state(r_cache, srow, lane, rec) if by_kernel else (
+                lax.dynamic_update_slice(r_cache, rec[None], at))
+        o = mm(o, lp["ssm_out"], "col", sync=True)
+        return o, lax.dynamic_update_slice(s_cache, conv[None], at), r_cache
+
     def make_step(a: int, kinds, stacks, ffn_row0=None, whole=None):
         """The scan body of layers [a, a + len(kinds)), all of one FFN
         kind. `stacks`: the quantized weight stacks it closes over; `whole`:
@@ -1743,8 +1824,8 @@ def run_layers(
         with lane state), from which the step takes its layer's."""
         experts = kinds[0].experts
         ffn_row0 = kinds[0].ffn_row if ffn_row0 is None else ffn_row0
-        # a convolution layer of a chunk program: the admitted lane's rows
-        lane_alone = lone and kinds[0].conv
+        # a state layer of a chunk program: the admitted lane's rows
+        lane_alone = lone and kinds[0].keeps_state
 
         def layer_step(carry, layer):
             x, caches = carry
@@ -1809,6 +1890,13 @@ def run_layers(
                     o, s_new = conv_operator(y, lp, caches[2], op_row, mm)
                     x = x + o.astype(x.dtype)
                 caches = (*caches[:2], s_new)
+            elif kinds[0].ssm:
+                # a Mamba-2 mixer, in attention's scope as the convolution is
+                with (jax.named_scope("attn"), jax.named_scope("ssm"),
+                      jax.named_scope(phase)):
+                    o, s_new, r_new = ssm_operator(y, lp, caches[2], caches[3], op_row, mm)
+                    x = x + scaled(o.astype(x.dtype))
+                caches = (*caches[:2], s_new, r_new)
             else:
                 with jax.named_scope("attn"):
                     gate = None
@@ -1860,6 +1948,11 @@ def run_layers(
                     elif kinds[0].rope:
                         q = apply_rope(q, cos, sin, interleaved)
                         k = apply_rope(k, cos, sin, interleaved)
+                    if h.attention_multiplier:
+                        # the kernels scale scores by head_dim^-1/2: the
+                        # queries carry what the stated scale differs by
+                        q = (q.astype(jnp.float32) * (
+                            h.attention_multiplier * float(h.head_dim) ** 0.5)).astype(q.dtype)
 
                 if h.kv_pack > 1:
                     with jax.named_scope("attn"):
@@ -1892,7 +1985,7 @@ def run_layers(
                     o = mm(z, lp["wo"], "col", sync=True).astype(x.dtype)
                     if "post_att_norm" in lp:
                         o = rms_norm(o, lp["post_att_norm"], h.norm_epsilon)
-                    x = x + o
+                    x = x + scaled(o)
 
             # -- FFN block (reference: src/llm.cpp:405-557) --
             # experts of a chunk program: over the admitted lane's rows alone
@@ -1920,7 +2013,7 @@ def run_layers(
                 f = f.astype(x.dtype)
                 if "post_ffn_norm" in lp:
                     f = rms_norm(f, lp["post_ffn_norm"], h.norm_epsilon)
-                x = x + f
+                x = x + scaled(f)
                 if (experts and lone) or lane_alone:
                     x = lax.dynamic_update_slice_in_dim(x_all, x, lane, axis=0)
             return (x, caches), counts
@@ -1935,7 +2028,9 @@ def run_layers(
     # lane cache was copied out of the stack and back to write a row a lane
     caches = (
         (c_cache,) if i_cache is None else (c_cache, i_cache)
-    ) if latent else (k_cache, v_cache, s_cache) if stateful else (
+    ) if latent else (
+        (k_cache, v_cache, s_cache) if r_cache is None
+        else (k_cache, v_cache, s_cache, r_cache)) if stateful else (
         k_cache, v_cache) if kw_cache is None else (
         k_cache, v_cache, kw_cache, vw_cache)
     counted = []
@@ -1970,7 +2065,7 @@ def run_layers(
             op_rows = jnp.asarray([kind.row for kind in kinds], jnp.int32)
             xs = (sliced, jnp.arange(a, e, dtype=jnp.int32),
                   {"op_row": op_rows, "row": (op_rows, op_rows)})
-            period = _pattern_period([kind.conv for kind in kinds])
+            period = _pattern_period([kind.keeps_state for kind in kinds])
             done = 0
             for n_groups, width in (((e - a) // period, period), (1, (e - a) % period)):
                 if not n_groups * width:
@@ -1979,10 +2074,11 @@ def run_layers(
                     make_step(
                         a, [kinds[done + j]],
                         {k: v for k, v in stacks.items()
-                         if k not in _OPERATOR_LEAVES or (k in _CONV_LEAVES) == kinds[done + j].conv},
+                         if k not in _OPERATOR_LEAVES
+                         or (k in _STATE_LEAVES) == kinds[done + j].keeps_state},
                         ffn_row0=r0,
                         whole={k: v for k, v in op_whole.items()
-                               if (k in _CONV_LEAVES) == kinds[done + j].conv},
+                               if (k in _STATE_LEAVES) == kinds[done + j].keeps_state},
                     )
                     for j in range(width)
                 ]

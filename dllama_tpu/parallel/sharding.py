@@ -42,7 +42,7 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
     group- and block-aligned."""
     moe = h.arch in (
         LlmArch.QWEN3_MOE, LlmArch.AFMOE, LlmArch.PANGU_MOE, LlmArch.DEEPSEEK_V32,
-        LlmArch.LFM2_MOE)
+        LlmArch.LFM2_MOE, LlmArch.GRANITE_MOE_HYBRID)
     # stacked layer weights carry a leading layer axis; MoE adds an expert axis
     row = P(None, None, None, "tp") if moe else P(None, None, "tp")  # out split
     col = P(None, None, "tp", None) if moe else P(None, "tp", None)  # in split
@@ -73,7 +73,8 @@ def param_spec_tree(h: LlmHeader) -> dict[str, Any]:
             layers[prefix + "w2"] = P(None, "tp", None)
     layers["wg"] = P(None, None, "tp")  # the attention gate: heads, as wq
     # a gated short convolution's projections and taps: one device holds them
-    for n in ("conv_in", "conv_out", "conv_w"):
+    for n in ("conv_in", "conv_out", "conv_w", "ssm_in", "ssm_out", "ssm_conv_w",
+              "ssm_conv_b", "ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_norm"):
         layers[n] = P()
     for n in ("q_norm", "k_norm", "post_att_norm", "post_ffn_norm"):
         layers[n] = P()

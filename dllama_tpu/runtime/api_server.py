@@ -1065,10 +1065,15 @@ class LaneScheduler:
     def _match_prefix(self, lane: int, tokens: list[int]) -> tuple[int, list]:
         """The pool's longest stored prefix of `tokens` and its pages,
         retained for `lane`. A model whose lanes keep state declines a
-        prefix no longer than what it would run again to rebuild them."""
+        prefix no longer than what it would run again to rebuild them, and
+        every prefix where the state reaches back to position 0
+        (`engine.state_unbounded`): the lane then runs its prompt whole."""
         start_pos, pages = self.kv.match(lane, tokens)
-        if start_pos and self._prefill_start(start_pos) <= 0:
+        why = "unbounded" if self.engine.state_unbounded else (
+            "short" if self._prefill_start(start_pos) <= 0 else None)
+        if start_pos and why:
             self.kv.release_lane(lane)
+            self.engine.decline_adoption(why)
             return 0, []
         return start_pos, pages
 

@@ -197,11 +197,11 @@ _KEPT = (
         "--speculation": "the verify programs are untested over a cache of latent rows",
         "--kv-native": "the pool-native programs read keys and values, not latent rows",
     }),
-    ("_stateful", "a convolution layer's state a lane", {
-        "mesh": "convolution layers that keep a state a lane run on one device",
-        "--kv-dtype": "a convolution layer's state is not quantized",
-        "--speculation": "a rejected draft would have moved a lane's convolution "
-                         "states, which cannot step back",
+    ("_stateful", "a layer's state a lane", {
+        "mesh": "layers that keep a state a lane run on one device",
+        "--kv-dtype": "a layer's state a lane is not quantized",
+        "--speculation": "a rejected draft would have moved a lane's states (a "
+                         "convolution's rows, a recurrence), which cannot step back",
         "--kv-native": "the pool-native programs keep no lane state",
     }),
     ("_two_cache_kinds", "a ring cache under its window layers", {
@@ -328,8 +328,9 @@ class InferenceEngine:
         # for all, which heads-over-chips cannot divide
         self._latent = self.header.latent
         # layers that keep a state a lane and no cache row a position (gated
-        # short convolutions): the state stack rides in `self.cache` beside
-        # the attention layers' keys and values, on one device
+        # short convolutions, Mamba-2 mixers): the state stacks ride in
+        # `self.cache` beside the attention layers' keys and values, on one
+        # device
         self._stateful = self.header.stateful
         for flag, n in (("--tp", tp), ("--sp", sp), ("--pp", pp), ("--dp", dp)):
             if n > 1:
@@ -531,25 +532,38 @@ class InferenceEngine:
         # state reaches back `conv_state_rows` positions of its own input, so
         # that of the k-th from the bottom k times as many of the tokens, and
         # an attention layer between them reads older positions from the cache
-        # alone. Rounded up to a multiple of 8 rows.
+        # alone. Rounded up to a multiple of 8 rows. A recurrent state reaches
+        # back to position 0 and no replay rebuilds it: `state_unbounded`, under
+        # which no prefix is adopted and none stored.
         n_conv = sum(k.conv for k in layer_table(self.header))
         self.state_replay_rows = -(-n_conv * self.header.conv_state_rows // 8) * 8
+        self.state_unbounded = self.header.state_unbounded
         if self._stateful:
             self._cache_sharding["s"] = NamedSharding(self.mesh, P())
+            if self.state_unbounded:
+                self._cache_sharding["r"] = NamedSharding(self.mesh, P())
             # where each lane's states stand: the position behind the last row
             # that moved them, None where nothing was installed
             self._state_pos: list[int | None] = [None] * batch_size
         self._m_state_installs = self.obs.counter(
-            "dllama_conv_state_installs_total",
+            "dllama_lane_state_installs_total",
             "Lane states installed at an admission's first chunk: zero = from "
             "position 0, replay = rebuilt behind an adopted prefix by running "
             "the positions before its end again.",
             labelnames=("how",),
         )
         self._m_replay_tokens = self.obs.counter(
-            "dllama_conv_replay_tokens_total",
+            "dllama_lane_state_replay_tokens_total",
             "Token rows of adopted prefixes that chunk programs ran again to "
-            "rebuild a lane's convolution states (cache writes masked).",
+            "rebuild a lane's states (cache writes masked).",
+        )
+        self._m_adoptions_declined = self.obs.counter(
+            "dllama_prefix_adoptions_declined_total",
+            "Stored prefixes a lane matched and did not adopt because its "
+            "states could not be rebuilt behind them: unbounded = a recurrent "
+            "state reaches back to position 0, short = the prefix is no longer "
+            "than the positions a replay runs again.",
+            labelnames=("why",),
         )
         self._m_ring_wraps = self.obs.counter(
             "dllama_kv_ring_wraps_total",
@@ -611,7 +625,8 @@ class InferenceEngine:
             "rows for the whole context, window = a ring of the window "
             "and one chunk, latent = one stack of [c | k_rope] rows for the "
             "whole context, index = the index keys beside them, conv = the "
-            "convolution layers' states (rows a lane, not a position).",
+            "state layers' convolution rows (rows a lane, not a position), "
+            "recurrent = the Mamba-2 layers' float32 states a lane.",
             labelnames=("kind",),
         )
         self.kv_cache_bytes = {
@@ -621,9 +636,9 @@ class InferenceEngine:
             )
             for kind, names in (
                 ("full", ("k", "v")), ("window", ("kw", "vw")), ("latent", ("c",)),
-                ("index", ("i",)), ("conv", ("s",)))
+                ("index", ("i",)), ("conv", ("s",)), ("recurrent", ("r",)))
             # a kind of its own where there is one
-            if kind not in ("index", "conv") or names[0] in self.cache
+            if kind not in ("index", "conv", "recurrent") or names[0] in self.cache
         }
         for kind, n in self.kv_cache_bytes.items():
             g_bytes.labels(kind=kind).set(n)
@@ -1035,6 +1050,12 @@ class InferenceEngine:
                     f"{flag}: {why.get(flag.split()[0], why['mesh'])} "
                     f"({self.header.arch.name} keeps {kept})"
                 )
+
+    def decline_adoption(self, why: str) -> None:
+        """Count a stored prefix that a lane matched and did not adopt
+        (`dllama_prefix_adoptions_declined_total`); on the recorder too."""
+        self._m_adoptions_declined.labels(why=why).inc()
+        self.recorder.record("prefix_adoption_declined", why=why)
 
     def _require_stateless(self, what: str) -> None:
         if self._stateful:
@@ -1792,9 +1813,13 @@ class InferenceEngine:
         rows, posv = self._one_lane_chunk(
             lane, tokens[:width], bucket, pos0, window if native else self._park
         )
+        try:
+            state_arg = self._lane_state_arg(lane, pos0, width, write_floor)
+        except ValueError:
+            self._spans.end(prep)  # a refusal is no dispatch: nothing stays open
+            raise
         arr, *rest = self._host_args(
-            *self._page_table_arg(), posv,
-            *self._lane_state_arg(lane, pos0, width, write_floor), tokens=rows
+            *self._page_table_arg(), posv, *state_arg, tokens=rows
         )
         with self._dispatch(
             "prefill_lane_chunk", prep, host_args=1 + len(rest),
@@ -1896,7 +1921,7 @@ class InferenceEngine:
                         self.kv_dtype,
                     ), sharding
                 )
-                for name, leaf in self._cache_specs.items() if name != "s"
+                for name, leaf in self._cache_specs.items() if name not in ("s", "r")
             }
         if self.kv_dtype == jnp.int8:
             def leaf():
@@ -1966,8 +1991,13 @@ class InferenceEngine:
         first positions are gone from those layers, no page from position 0
         can be stored, and a later request with that prefix misses. (A
         prefix that was stored is whole: every row a window layer's next
-        query needs came with it.)"""
+        query needs came with it.) None of a model whose lane state reaches
+        back to position 0."""
         if self._two_cache_kinds and n_tokens > self.kv_ring:
+            return 0
+        if self.state_unbounded:
+            # no lane could adopt the rows: its recurrent states would not
+            # come with them, and no replay rebuilds those
             return 0
         return n_tokens
 
@@ -2746,6 +2776,7 @@ class InferenceEngine:
             # where the lane decodes (position 0 is zero by itself)
             astray = [i for i in live if pos[i] and self._state_pos[i] != pos[i]]
             if astray:
+                self._spans.end(prep)
                 raise ValueError(
                     f"lanes {astray} decode at {[pos[i] for i in astray]} and their "
                     f"states stand at {[self._state_pos[i] for i in astray]}"

@@ -86,6 +86,9 @@ _REPLICATED_KEYS = {
     "post_att_norm", "post_ffn_norm", "expert_bias",
     # a gated short convolution's projections and taps: one device holds them
     "conv_in", "conv_out", "conv_w",
+    # and a Mamba-2 mixer's
+    "ssm_in", "ssm_out", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
+    "ssm_d", "ssm_norm",
 }
 
 

@@ -341,8 +341,9 @@ LFM2_TYPES = ["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 2 +
 
 def attn_layer_words(layer_types) -> dict:
     """The header's two words that name the attention layers of a pattern of
-    `conv` and `full_attention` layers (keys 48 and 49: 30 layers a word)."""
-    mask = sum(1 << l for l, t in enumerate(layer_types) if t == "full_attention")
+    state layers and `full_attention` or `attention` layers (keys 48 and 49:
+    30 layers a word)."""
+    mask = sum(1 << l for l, t in enumerate(layer_types) if t in ("full_attention", "attention"))
     return {"attn_layers_lo": mask & ((1 << 30) - 1), "attn_layers_hi": mask >> 30}
 
 
@@ -491,3 +492,68 @@ def assert_one_spelling(name: str, unflagged, flagged) -> None:
     args, state = flagged
     assert getattr(args, dest) != flag_default
     assert read(state) == getattr(args, dest), name
+
+
+GRANITE_TYPES = ["mamba", "mamba", "attention", "mamba"] * 2
+
+
+def tiny_granite_config(layer_types=None, **over) -> dict:
+    """A benchmark configuration file's worth of the `granitemoehybrid`
+    architecture (Mamba-2 layers that keep a recurrent state a lane, an
+    attention layer without rope now and then, a share of the experts behind
+    a softmax router beside a shared expert, multipliers on the embedding, the
+    residual adds, the scores and the logits) at test widths. The default
+    pattern is the published one's shape: two whole periods."""
+    types = list(layer_types or GRANITE_TYPES)
+    cfg = {
+        "name": "granite-tiny", "family": "granitemoehybrid",
+        "hidden_size": 64, "intermediate_size": 32, "shared_intermediate_size": 64,
+        "num_hidden_layers": len(types), "num_attention_heads": 8,
+        "num_key_value_heads": 4, "head_dim": 8, "vocab_size": 512,
+        "max_position_embeddings": 4096, "rope_theta": 10000, "rms_norm_eps": 1e-5,
+        "layer_types": types, "mamba_n_heads": 4, "mamba_d_head": 8, "mamba_d_state": 16,
+        "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_chunk_size": 256,
+        "embedding_multiplier": 12, "residual_multiplier": 0.22,
+        "attention_multiplier": 0.25, "logits_scaling": 16,
+        "num_local_experts": 4, "num_experts": 4, "num_routed_experts": 8,
+        "first_expert": 0, "num_experts_per_tok": 3,
+    }
+    cfg.update(over)
+    cfg["file"] = {
+        "arch": "GRANITE_MOE_HYBRID", "rope_pairing": "half", "qk_norm": False,
+        "norm_epsilon_enum": 5,
+        "header": {
+            "full_attn_no_rope": 1,
+            "n_shared_experts": cfg["shared_intermediate_size"] // cfg["intermediate_size"],
+            "score_func": 0, "route_norm": 1,
+            "n_routed_experts": cfg["num_routed_experts"],
+            "first_expert": cfg["first_expert"], **attn_layer_words(types),
+            "ssm_n_heads": cfg["mamba_n_heads"], "ssm_head_dim": cfg["mamba_d_head"],
+            "ssm_state_dim": cfg["mamba_d_state"], "ssm_n_groups": cfg["mamba_n_groups"],
+            "ssm_conv_taps": cfg["mamba_d_conv"],
+            "embed_multiplier_milli": round(cfg["embedding_multiplier"] * 1e3),
+            "residual_multiplier_nano": round(cfg["residual_multiplier"] * 1e9),
+            "attention_multiplier_nano": round(cfg["attention_multiplier"] * 1e9),
+            "logits_scaling_milli": round(cfg["logits_scaling"] * 1e3),
+        },
+        "tensors": {
+            "ssm_a_log": {"dist": "uniform", "lo": 1.0, "hi": 16.0, "map": "log"},
+            "ssm_dt_bias": {"dist": "uniform", "lo": 0.02, "hi": 0.2, "map": "inv_softplus"},
+            "ssm_d": {"dist": "uniform", "lo": 0.9, "hi": 1.1},
+            "ssm_conv_b": {"dist": "normal", "std": 0.1},
+            "ssm_in_dt": {"gain": 0.25},
+            # the multipliers are the published ones; the seeded weights are
+            # sized so that the embedding enters at std 1 and every mixer
+            # adds as much, or the next token would follow from the last alone
+            "embed": {"dist": "normal", "std": 1 / cfg["embedding_multiplier"]},
+            "ssm_out": {"gain": 1 / cfg["residual_multiplier"]},
+            "wo": {"gain": 1 / cfg["residual_multiplier"]},
+            "w2": {"gain": 0.25 / cfg["residual_multiplier"]},
+        },
+    }
+    return cfg
+
+
+def make_tiny_granite(path: str, seed: int = 3, **over) -> dict:
+    """The same for `granitemoehybrid`, as `make_tiny_afmoe`."""
+    return _write_tiny(path, tiny_granite_config(**over), seed)

@@ -836,6 +836,148 @@ def test_layer_scan_carries_lane_state_beside_a_cache_of_ten_layers(
         assert f"[{lanes * rows},{3 * h.dim}]" not in text
 
 
+def outside_fusions(text: str) -> str:
+    """The HLO text without the computations that fusions call: what is left
+    are the instructions whose results are buffers (a line inside a fused
+    computation names a value that is never stored)."""
+    import re
+
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    out, skip = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            skip = line.split(" ")[0] in fused
+        if not skip:
+            out.append(line)
+    return "\n".join(out)
+
+
+# granite-4.0-h-small's cut: 2 attention layers at 4096 + 512 rows, 18 Mamba-2
+# layers' float32 states of 128 x 64 x 128 and 3 rows of 8448, 32 lanes
+GRANITE_TYPES = (["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 2
+
+
+def granite_header(published: bool):
+    """granite-4.0-h-small as one of four chips, or the tests' tiny widths
+    with the same pattern."""
+    from dllama_tpu.formats.model_file import LlmArch, LlmHeader, RopeType
+
+    sizes = dict(
+        dim=4096, hidden_dim=768, n_heads=32, n_kv_heads=8, head_dim=128,
+        n_experts=18, n_routed_experts=72, n_active_experts=10, vocab_size=25088,
+        ssm_n_heads=128, ssm_head_dim=64, ssm_state_dim=128,
+    ) if published else dict(
+        dim=256, hidden_dim=256, n_heads=4, n_kv_heads=2, head_dim=128,
+        n_experts=4, n_routed_experts=8, n_active_experts=3, vocab_size=512,
+        ssm_n_heads=8, ssm_head_dim=64, ssm_state_dim=128,
+    )
+    return LlmHeader(
+        arch=LlmArch.GRANITE_MOE_HYBRID, n_layers=len(GRANITE_TYPES), seq_len=4096,
+        rope_type=RopeType.FALCON, full_attn_no_rope=True, n_shared_experts=2,
+        ssm_conv_taps=4, embed_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=1 / 128, logits_scaling=16.0,
+        attn_layers=sum(1 << l for l, t in enumerate(GRANITE_TYPES) if t == "attention"),
+        **sizes,
+    )
+
+
+def granite_layers(h, s):
+    """The leaves as the loader stacks them on one chip: each operator's over
+    the layers of its kind, packed words, the held experts' stacks packed."""
+    from dllama_tpu.formats.model_file import layer_table
+    from dllama_tpu.ops.quant_matmul import FusedQuantWeight, PackedQuantWeight
+
+    n, nm = h.n_layers, sum(k.ssm for k in layer_table(h))
+    na, d, f, e = n - nm, h.dim, h.ff_dim, h.n_experts
+    inner, conv = h.ssm_inner, h.ssm_conv_dim
+
+    def f32(*shape):
+        return sds(shape, jnp.float32, s)
+
+    def packed(layers, k, width):
+        return q40_stack(layers, k, width, s, packed=True)
+
+    def experts(k, width):
+        return PackedQuantWeight(sds((n, e, k // 8, width), jnp.int32, s),
+                                 sds((n, e, k // 32, width), jnp.float32, s))
+
+    return dict(
+        att_norm=f32(n, d), ffn_norm=f32(n, d),
+        wqkv=FusedQuantWeight(packed(na, d, h.q_dim + 2 * h.kv_dim), 1,
+                              (h.q_dim, h.kv_dim, h.kv_dim)),
+        wo=packed(na, h.q_dim, d),
+        ssm_in=packed(nm, d, inner + conv + h.ssm_n_heads), ssm_out=packed(nm, inner, d),
+        ssm_conv_w=f32(nm, 4, conv), ssm_conv_b=f32(nm, conv), ssm_norm=f32(nm, inner),
+        ssm_dt_bias=f32(nm, h.ssm_n_heads), ssm_a_log=f32(nm, h.ssm_n_heads),
+        ssm_d=f32(nm, h.ssm_n_heads),
+        moe_gate=f32(n, d, h.n_routed_experts),
+        shared_w13=FusedQuantWeight(packed(n, d, 4 * f), 1, (2 * f, 2 * f)),
+        shared_w2=packed(n, 2 * f, d),
+        w1=experts(d, f), w3=experts(d, f), w2=experts(f, d),
+    )
+
+
+@pytest.mark.parametrize("rows,window", [(1, 1024), (512, 2048), (256, 1024), (64, 512)],
+                         ids=["decode", "prefill512", "prefill256", "prefill64"])
+@pytest.mark.parametrize("published", [True, False], ids=["published", "tiny"])
+def test_layer_scan_carries_a_recurrent_state_beside_a_cache_of_two_layers(
+        one_chip, monkeypatch, published, rows, window):
+    """granite-4.0-h-small's decode step and its chunk programs (two blocks of
+    the recurrence's 256 rows, one, a quarter of one) at its cell's size, and
+    at the tests' tiny widths: the scan carries the cache stack of the 2
+    attention layers, the 18 Mamba-2 layers' convolution rows and their 2.4 GB
+    of float32 recurrent states, each written in place by
+    `dynamic-update-slice`; no copy of the recurrent stack, or of one layer's
+    lanes of it, is made; no branch is taken on the device over the layer
+    pattern; and a chunk program's mixers compute the admitted lane's rows,
+    not those of all 32."""
+    from dllama_tpu.formats.model_file import layer_table
+    from dllama_tpu.models import transformer as tf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = one_chip
+    h = granite_header(published)
+    nm = sum(k.ssm for k in layer_table(h))
+    lanes = 32
+    cache = (h.n_layers - nm, lanes, h.n_kv_heads, 4608, 128)
+    conv = (nm, lanes, 3, h.ssm_conv_dim)
+    state = (nm, lanes, 128, h.ssm_inner)
+
+    def step(x, layers, k, v, st, rec, pos, cos, sin, aux):
+        counts = []
+        live = pos < 4096
+        out = tf.run_layers(
+            x, layers, k, v, h, pos, jnp.where(live, pos, -4608), cos, sin,
+            attn_window=window, route_stats=counts, one_live_lane=rows > 1,
+            s_cache=st, r_cache=rec, state_rows=jnp.where(live, aux[0], 0),
+            write_floor=aux[1] if rows > 1 else None,
+            state_fresh=jnp.logical_and(live, aux[2] > 0),
+        )
+        return out, counts
+
+    text = compiled_text(
+        jax.jit(step, donate_argnums=(2, 3, 4, 5)),
+        sds((lanes, rows, h.dim), jnp.bfloat16, s), granite_layers(h, s),
+        sds(cache, jnp.bfloat16, s), sds(cache, jnp.bfloat16, s),
+        sds(conv, jnp.bfloat16, s), sds(state, jnp.float32, s),
+        sds((lanes,), jnp.int32, s),
+        sds((lanes, rows, 64), jnp.float32, s), sds((lanes, rows, 64), jnp.float32, s),
+        sds((3,), jnp.int32, s),
+    )
+    assert "moe_held_experts_q40" in text and " conditional(" not in text
+    if not published:
+        return  # the tiny widths lower: a shape the chip rejects fails here
+    assert text.count("dynamic-update-slice(") >= 4  # K rows, V rows, both states
+    assert not cache_copies(text, cache), cache_copies(text, cache)
+    # the recurrent stack (2.4 GB) is the scan's carry, updated where it lies:
+    # neither the stack nor one layer's 32 lanes of it (134 MB) is copied
+    moved = cache_copies(outside_fusions(text), state, "f32")
+    assert not moved, moved
+    if rows > 1:
+        width = h.ssm_inner + h.ssm_conv_dim + h.ssm_n_heads
+        assert f"[{rows},{width}]" in text and f"[{lanes * rows},{width}]" not in text
+
+
 def test_layer_scan_writes_cache_rows_in_place_tp4(tp4, monkeypatch):
     """The same prefill chunk at tp=4: KH is the stack's sharded axis
     (`P(None, "dp", "tp", None, None)` into the flash kernel's `shard_map`,
@@ -1184,6 +1326,11 @@ CELL_MATMULS = {
     "lfm2-24b-a2b-e16": ([16, 512, 8192], [
         (2048, 3072), (2048, 2048), (2048, 6144), (2048, 23552), (11776, 2048),
         (2048, 16384)]),
+    # `in_proj`'s 16768 = 131 x 128 columns: tiles of 128, the one multiple
+    # of 128 that divides them
+    "granite-4.0-h-small-l20-e18": ([32, 512, 16384], [
+        (4096, 16768), (8192, 4096), (4096, 6144), (4096, 4096), (4096, 3072),
+        (1536, 4096), (4096, 25088)]),
 }
 
 
@@ -1206,17 +1353,20 @@ def test_qmatmul_packed_at_the_cells_shapes(one_chip, m, k, n):
     compiled_text(qmatmul_i4_2d, *packed_args(m, k, n, one_chip))
 
 
-# (D, F, experts held, experts a token, decode lanes) of the five sparse
-# cells: Qwen3-30B-A3B, Trinity-Large, openPangu-Ultra, DeepSeek-V3.2, LFM2
+# (D, F, experts held, experts a token, decode lanes) of the six sparse
+# cells: Qwen3-30B-A3B, Trinity-Large, openPangu-Ultra, DeepSeek-V3.2, LFM2,
+# granite-4.0-h-small
 HELD_SHAPES = [
     (2048, 768, 128, 8, 16), (3072, 3072, 32, 4, 8), (7680, 2048, 32, 8, 4),
-    (7168, 2048, 32, 8, 4), (2048, 1536, 16, 4, 16),
+    (7168, 2048, 32, 8, 4), (2048, 1536, 16, 4, 16), (4096, 768, 18, 10, 32),
 ]
 
 
-@pytest.mark.parametrize("rows", ["decode", "pairs128", "chunk"])
-@pytest.mark.parametrize("d,f,e,k,lanes", HELD_SHAPES,
-                         ids=[f"{d}x{f}" for d, f, *_ in HELD_SHAPES])
+@pytest.mark.parametrize("rows,d,f,e,k,lanes", [
+    pytest.param(rows, *shape, id=f"{shape[0]}x{shape[1]}-{rows}")
+    for rows in ("decode", "pairs128", "chunk") for shape in HELD_SHAPES
+    if rows != "pairs128" or 128 % shape[3] == 0  # ten a token: no whole rows of 128 pairs
+])
 def test_held_experts_packed_at_the_cells_shapes(one_chip, d, f, e, k, lanes, rows):
     """`moe_held_experts_q40` over packed expert stacks (int32 words of eight
     nibbles, `[L, E, in // 8, out]`) at the five served expert shapes: a
