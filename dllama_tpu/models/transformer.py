@@ -68,6 +68,7 @@ from ..ops.ssm_scan import (
     SsmShape, lane_state, put_lane_state, ssm_chunk, ssm_step, ssm_step_in_place)
 from ..ops.sparse_index import index_scores, select_rows
 from ..ops.moe_kernel import (
+    held_forms,
     moe_active_experts,
     moe_active_experts_q40,
     moe_grouped_experts,
@@ -1010,6 +1011,7 @@ def forward(
     moe_decode_dedup: bool = False,
     kv_ring: int = 0,
     route_stats: list | None = None,
+    expert_forms: list | None = None,
     one_live_lane: bool = False,
     state_rows: jnp.ndarray | None = None,
     state_fresh: jnp.ndarray | None = None,
@@ -1051,6 +1053,11 @@ def forward(
     `state_rows`, `state_fresh`, `write_floor`: a model with lane state
     alone (`run_layers` says what each means); left out, every live lane's
     state moves by the T rows, from zero at position 0.
+
+    `route_stats` or `expert_forms` (a list, one of the two): what the
+    expert layers count of this pass is appended, summed over them: the
+    routed pairs (`moe_block`), or the forms their held kernel took
+    (`ops/moe_kernel.held_forms`).
     """
     b, t = tokens.shape
     # `pos` may be a [B] vector: each batch lane decodes at its own
@@ -1069,7 +1076,8 @@ def forward(
         cos, sin, mesh=mesh, attn_window=attn_window,
         sync_quant=sync_quant, moe_decode_dedup=moe_decode_dedup,
         kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
-        route_stats=route_stats, c_cache=cache.get("c"), i_cache=cache.get("i"),
+        route_stats=route_stats, expert_forms=expert_forms,
+        c_cache=cache.get("c"), i_cache=cache.get("i"),
         one_live_lane=one_live_lane,
         **({"s_cache": cache["s"], "r_cache": cache.get("r"), "state_rows": state_rows,
             "state_fresh": state_fresh, "write_floor": write_floor} if "s" in cache else {}),
@@ -1163,6 +1171,7 @@ def run_layers(
     vw_cache: jnp.ndarray | None = None,
     kv_ring: int = 0,
     route_stats: list | None = None,
+    expert_forms: list | None = None,
     c_cache: jnp.ndarray | None = None,  # [L, B, 1, S, W]: latent layers, alone
     one_live_lane: bool = False,
     i_cache: jnp.ndarray | None = None,  # [L, B, 1, S, dI]: their index keys
@@ -1633,7 +1642,8 @@ def run_layers(
     def moe_block(y, lp, lf):
         """The experts' FFN of a layer whose experts' row is `lf` over the
         rows of `y` (every lane's, or the one admitted lane's), and what the
-        routing counters count of it where they are asked for."""
+        routing counters count of it, or the form counter, where one of them
+        is asked for."""
         from ..ops.moe_kernel import moe_pallas_supported
 
         b, t = y.shape[0], y.shape[1]
@@ -1679,13 +1689,15 @@ def run_layers(
                 jnp.sum(live_rows) * route.n_active, jnp.sum(on),
                 jnp.sum(touched), jnp.sum(jnp.any(on, axis=-1)),
             ]).astype(jnp.int32)
+        elif expert_forms is not None:
+            counts = held_forms(held_i, route.n_held, route.n_routed, _packed)
         # one device holds the layer: each distinct quantized expert the
         # live rows touched is read once, in a chunk and a decode block
         # alike (the kernel's grid stops behind the last of them)
         if pallas_ok and _quantized and one_device:
             return moe_held_experts_q40(
                 y.reshape(b * t, -1), *_expert_stacks(lp["w1"], lp["w2"], lp["w3"]),
-                held_i, wts, jnp.asarray(lf, jnp.int32),
+                held_i, wts, jnp.asarray(lf, jnp.int32), n_routed=route.n_routed,
             ).reshape(b, t, -1).astype(y.dtype), counts
         if pallas_ok and not _packed:
             # more than one device, or unquantized experts: the older two
@@ -2120,5 +2132,5 @@ def run_layers(
         if counts is not None:
             counted.append(counts.sum(axis=0))
     if counted:
-        route_stats.append(sum(counted))
+        (expert_forms if route_stats is None else route_stats).append(sum(counted))
     return (x, *caches)

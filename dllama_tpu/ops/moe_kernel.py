@@ -340,7 +340,8 @@ _GROUP_ROWS = 32  # row tile; worst-case wasted compute = E extra tiles
 
 def _grouped_schedule(top_i, weights, n_tokens, n_experts,
                       max_segments: int | None = None,
-                      rows: int = _GROUP_ROWS):
+                      rows: int = _GROUP_ROWS,
+                      keep: int | None = None):
     """jnp (traced) schedule for the grouped kernel.
 
     Returns (t_sorted [A_pad], w_col [A_pad, 1], step_lo/hi/tile/expert
@@ -358,11 +359,19 @@ def _grouped_schedule(top_i, weights, n_tokens, n_experts,
     small-grid variant and only dispatches it (lax.cond) when the
     runtime unique-expert count fits; with more segments than the cap
     the trailing scatter indices fall out of range and XLA drops them
-    (never executed: the caller's predicate guarantees the fit)."""
+    (never executed: the caller's predicate guarantees the fit).
+
+    `keep` (a multiple of the row tile, below A): the schedule of the sorted
+    assignments' first `keep` alone, every array sized by them. It is the
+    whole schedule's leading part, step for step, as far as the kept rows
+    reach (`moe_held_experts_q40`: the held pairs lead)."""
     n, k = top_i.shape
     a = n * k
     r = rows
     a_pad = -(-a // r) * r
+    if keep is not None:
+        assert keep % r == 0 and 0 < keep < a, (keep, r, a)
+        a = a_pad = keep
     n_tiles = a_pad // r
     seg_budget = (
         min(n_experts, a)
@@ -375,6 +384,8 @@ def _grouped_schedule(top_i, weights, n_tokens, n_experts,
     flat_t = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
     flat_w = weights.reshape(-1).astype(jnp.float32)
     order = jnp.argsort(flat_e, stable=True)
+    if keep is not None:
+        order = order[:keep]
     e_s = jnp.concatenate(
         [flat_e[order], jnp.full((a_pad - a,), n_experts, flat_e.dtype)]
     )
@@ -721,7 +732,68 @@ def _with_count(index_map):
     return lambda g, fi, lo, hi, tile, expert, n: index_map(g, fi, lo, hi, tile, expert)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_f", "row_tile"))
+# What surrounds the kernel is sized by the call's pairs in the whole form:
+# the schedule's scatters, the gather of x, the mask and the scatter-add. Where
+# this chip holds a share of the experts the router scores, the held pairs are
+# the sorted pairs' leading part, about `n_held / n_routed` of them, so the
+# landed form builds all of that over the first `cap` sorted pairs, one and a
+# half times the pairs a uniform router would land here, and the whole form is
+# kept for the call whose pairs pass `cap`.
+_LANDED_ROOM = 1.5
+# Fewer rows saved than this and the call keeps one form. Microseconds a call,
+# whole form and landed (PR 48's probe on a v5e, `scripts/moe_packed_probe.py`
+# SHARE_CASES, rows saved in brackets): 7680 x 2048, 1024 pairs [832] 2445 and
+# 1972, 4096 pairs [3328] 5680 and 3325; 4096 x 768, 320 pairs [192] 326 and
+# 298, 1280 [768] 522 and 406, 5120 [3200] 1445 and 922; 2048 x 1536, 64 pairs
+# [32] 191 and 197, 512 [320] 301 and 294, 2048 [1280] 490 and 425. A decode
+# step's pairs (32 to 320 in the cells) keep the whole form's straight line.
+_LANDED_MIN_SAVED = 512
+
+
+def _landed_cap(pairs: int, rows: int, n_held: int, n_routed: int | None) -> int:
+    """The sorted pairs the landed form is built over, a multiple of the row
+    tile: the call's pairs padded to the tile (one form: the whole one)
+    where every expert is held or too few rows would be saved."""
+    a_pad = -(-pairs // rows) * rows
+    if not n_routed or n_held >= n_routed:
+        return a_pad
+    landed = -(-int(_LANDED_ROOM * pairs * n_held) // n_routed)
+    cap = max(-(-landed // rows), 1) * rows
+    return cap if a_pad - cap >= _LANDED_MIN_SAVED else a_pad
+
+
+def _sum_landed(o_sorted: jnp.ndarray, held_i: jnp.ndarray, n_pairs) -> jnp.ndarray:
+    """The whole form's scatter-add out of the landed form's rows, [N, D]
+    f32, by gathers: the scatter-add's time does not go with its rows (768
+    rows of 7680 columns into 512 took 1.41 ms where 4096 took 1.96, PR 48's
+    traces), a gather's does. A token's rows are added from zero in ascending
+    sorted position, the order in which the scatter-add meets them (it sorts
+    its updates by row, ties in place; the CPU applies them in turn): the
+    same bits, which the probe checks on the chip at every shape."""
+    n, k = held_i.shape
+    order = jnp.argsort(held_i.reshape(-1), stable=True)
+    # [k, N]: where the sorted order put each of a token's pairs
+    at = jnp.sort(jnp.argsort(order).astype(jnp.int32).reshape(n, k), axis=1).T
+    rows = jnp.take(o_sorted, at.reshape(-1), axis=0, mode="clip")
+    rows = jnp.where((at < n_pairs).reshape(-1, 1), rows, 0.0).reshape(k, n, -1)
+    out = jnp.zeros(rows.shape[1:], jnp.float32)
+    for j in range(k):
+        out = out + rows[j]
+    return out
+
+
+def held_forms(held_i: jnp.ndarray, n_held: int, n_routed: int, packed: bool):
+    """int32 [3] of one `moe_held_experts_q40` call over `held_i`, for a
+    program's counter: (1, 0, pairs landed) where it takes the landed form,
+    (0, 1, pairs landed) where the whole one, by the kernel's own test."""
+    cap = _landed_cap(held_i.size, _held_rows(held_i.size, packed), n_held, n_routed)
+    n_pairs = jnp.sum(held_i < n_held).astype(jnp.int32)
+    short = jnp.logical_and(cap < held_i.size, n_pairs <= cap).astype(jnp.int32)
+    return jnp.stack([short, 1 - short, n_pairs])
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_routed", "interpret", "block_f", "row_tile", "cap"))
 def moe_held_experts_q40(
     x: jnp.ndarray,  # [N, D]
     w1q: jnp.ndarray,  # [E, D, F] int8, E the experts held here; or the
@@ -733,9 +805,11 @@ def moe_held_experts_q40(
     held_i: jnp.ndarray,  # [N, k] int32: the held expert's row, or E
     weights: jnp.ndarray,  # [N, k] f32
     layer=0,
+    n_routed: int | None = None,  # the experts the router scores (None: E)
     interpret: bool = False,
     block_f: int | None = None,  # the probe's; served: `_held_f_block`
     row_tile: int | None = None,  # the probe's; served: `_held_rows`
+    cap: int | None = None,  # the probe's and the tests'; served: `_landed_cap`
 ) -> jnp.ndarray:
     """The held experts' part of a layer's routed sum, [N, D] f32: zero for
     a token none of whose experts is held here. By the values' type, as
@@ -743,7 +817,13 @@ def moe_held_experts_q40(
     `_dequant_block`; int32 words of eight nibbles (`PackedQuantWeight`: the
     copy moves 0.625 B a weight where int8 moves 1.125) by `unpack_tile`,
     into the same bf16 tile bit for bit. Schedule, masks, accumulator and
-    emit are one body's."""
+    emit are one body's.
+
+    A held share of `n_routed` experts: `lax.cond` on the pairs that landed
+    takes the landed form (`_landed_cap`, `_sum_landed`) or the whole one.
+    Row tile and F block are the call's in both, a pair's row meets the same
+    dots in the same order and a token's pairs are added in the same order:
+    one output, bit for bit."""
     n, d = x.shape
     e, _, f = w1d.shape[-3:]
     packed = w1q.dtype == jnp.int32
@@ -756,43 +836,63 @@ def moe_held_experts_q40(
     bf = block_f or _held_f_block(f, d, packed)
     n_f = f // bf
     r = row_tile or _held_rows(held_i.size, packed)
-    t_s, w_col, lo, hi, tile, expert = _grouped_schedule(
-        held_i, weights, n, e, rows=r
-    )
-    a_pad = t_s.shape[0]
-    # sorted, the held pairs lead: the steps up to the last of them
-    n_pairs = jnp.sum(held_i < e).astype(jnp.int32)
-    n_steps = jnp.sum(lo < n_pairs).astype(jnp.int32)
-    x_sorted = jnp.take(x, t_s, axis=0).astype(jnp.bfloat16)
     w13_map, w2_map = _with_count(_grouped_w13_map), _with_count(_grouped_w2_map)
-    o_sorted = pl.pallas_call(
-        functools.partial(
-            _held_kernel_q40, n_f=n_f, rows=r,
-            dequant=unpack_tile if packed else _dequant_block,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(n_steps, n_f),
-            in_specs=[
-                pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
-                pl.BlockSpec((r, 1), _with_count(_grouped_row_map)),
-                pl.BlockSpec((1, d // pack, bf), w13_map),
-                pl.BlockSpec((1, d // Q_BLOCK, bf), w13_map),
-                pl.BlockSpec((1, d // pack, bf), w13_map),
-                pl.BlockSpec((1, d // Q_BLOCK, bf), w13_map),
-                pl.BlockSpec((1, bf // pack, d), w2_map),
-                pl.BlockSpec((1, bf // Q_BLOCK, d), w2_map),
-            ],
-            out_specs=pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
-            scratch_shapes=[pltpu.VMEM((r, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((a_pad, d), jnp.float32),
-        interpret=interpret,
-        compiler_params=_held_compiler_params(d, bf),
-    )(lo, hi, tile, expert + first, n_steps.reshape(1), x_sorted, w_col,
-      w1q, w1d, w3q, w3d, w2q, w2d)
-    # tiles the grid never reached hold whatever the buffer held
-    reached = jnp.arange(a_pad, dtype=jnp.int32)[:, None] < n_pairs
-    return jnp.zeros((n, d), jnp.float32).at[t_s].add(
-        jnp.where(reached, o_sorted, 0.0)
-    )
+
+    def over(keep: int | None):
+        """The routed sum from the sorted pairs' first `keep` (None: all)."""
+        t_s, w_col, lo, hi, tile, expert = _grouped_schedule(
+            held_i, weights, n, e, rows=r, keep=keep
+        )
+        a_pad = t_s.shape[0]
+        # sorted, the held pairs lead: the steps up to the last of them
+        n_pairs = jnp.sum(held_i < e).astype(jnp.int32)
+        n_steps = jnp.sum(lo < n_pairs).astype(jnp.int32)
+        x_sorted = jnp.take(x, t_s, axis=0).astype(jnp.bfloat16)
+        o_sorted = pl.pallas_call(
+            functools.partial(
+                _held_kernel_q40, n_f=n_f, rows=r,
+                dequant=unpack_tile if packed else _dequant_block,
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(n_steps, n_f),
+                in_specs=[
+                    pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
+                    pl.BlockSpec((r, 1), _with_count(_grouped_row_map)),
+                    pl.BlockSpec((1, d // pack, bf), w13_map),
+                    pl.BlockSpec((1, d // Q_BLOCK, bf), w13_map),
+                    pl.BlockSpec((1, d // pack, bf), w13_map),
+                    pl.BlockSpec((1, d // Q_BLOCK, bf), w13_map),
+                    pl.BlockSpec((1, bf // pack, d), w2_map),
+                    pl.BlockSpec((1, bf // Q_BLOCK, d), w2_map),
+                ],
+                out_specs=pl.BlockSpec((r, d), _with_count(_grouped_x_map)),
+                scratch_shapes=[pltpu.VMEM((r, d), jnp.float32)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((a_pad, d), jnp.float32),
+            interpret=interpret,
+            compiler_params=_held_compiler_params(d, bf),
+        )(lo, hi, tile, expert + first, n_steps.reshape(1), x_sorted, w_col,
+          w1q, w1d, w3q, w3d, w2q, w2d)
+        if keep is not None:
+            return _sum_landed(o_sorted, held_i, n_pairs)
+        # tiles the grid never reached hold whatever the buffer held
+        reached = jnp.arange(a_pad, dtype=jnp.int32)[:, None] < n_pairs
+        return jnp.zeros((n, d), jnp.float32).at[t_s].add(
+            jnp.where(reached, o_sorted, 0.0)
+        )
+
+    cap = cap or _landed_cap(held_i.size, r, e, n_routed)
+    if cap >= held_i.size:
+        return over(None)
+    # jitted under names of their own: a profile names a kernel by the
+    # function that holds it, and a `cond`'s branch has none
+    def moe_held_experts_q40_landed():
+        return over(cap)
+
+    def moe_held_experts_q40_whole():
+        return over(None)
+
+    return jax.lax.cond(
+        jnp.sum(held_i < e) <= cap,
+        jax.jit(moe_held_experts_q40_landed), jax.jit(moe_held_experts_q40_whole))

@@ -591,6 +591,16 @@ class InferenceEngine:
             "over the expert layers of every decode step: what the expert "
             "kernel had to read.",
         )
+        self._m_moe_forms = self.obs.counter(
+            "dllama_moe_block_forms_total",
+            "Expert layers of prefill chunk programs, where the experts "
+            "held here are a share of those the router scores, by the form "
+            "their kernel's surroundings took: landed = sized by the pairs "
+            "that landed here, whole = by every pair the router chose (more "
+            "pairs landed than the landed form holds). Counted when the "
+            "next decode block is collected.",
+            labelnames=("program", "form"),
+        )
         self._m_moe_chunk_rows = self.obs.counter(
             "dllama_moe_chunk_rows_total",
             "Token rows of prefill chunk programs by what the expert block "
@@ -765,7 +775,8 @@ class InferenceEngine:
 
             def fwd(params, tokens, pos, cache, *, attn_window=0,
                     logits_mode="all", attn_park_threshold=0, n_micro=1,
-                    route_stats=None, one_live_lane=False, **lane_state):
+                    route_stats=None, expert_forms=None, one_live_lane=False,
+                    **lane_state):
                 del n_micro  # sequence-wave microbatching is pp-only
                 return forward(
                     params, h, tokens, pos, cache, mesh=mesh,
@@ -774,6 +785,7 @@ class InferenceEngine:
                     sync_quant=sync_quant,
                     moe_decode_dedup=moe_decode_dedup,
                     kv_ring=kv_ring, route_stats=route_stats,
+                    expert_forms=expert_forms,
                     one_live_lane=one_live_lane, **lane_state,
                 )
 
@@ -786,6 +798,11 @@ class InferenceEngine:
             h.n_experts < h.n_routed_experts
             or mesh is None or mesh.devices.size == 1
         )
+        # a chunk program of a held share of the experts returns, un-read,
+        # the forms its expert layers took (`ops/moe_kernel.held_forms`):
+        # (blocks enqueued before it, the array), counted at a later collect
+        self._counts_forms = self._counts_routing and h.n_experts < h.n_routed_experts
+        self._chunk_forms: list[tuple[int, jax.Array]] = []
 
     def _pp_micro(self, t: int) -> int:
         """Sequence-wave microbatch count for a T-wide pp prefill chunk:
@@ -1583,13 +1600,18 @@ class InferenceEngine:
         bucket and every lane writes one, so the admitted lane's states move
         by its real rows alone and every other lane's stay; cache rows at
         positions below the floor keep what they hold; and `fresh` starts
-        the lane's states from zero (`prefill_lane_chunk`)."""
+        the lane's states from zero (`prefill_lane_chunk`).
+
+        A held share of the experts (`_counts_forms`): the program returns
+        (cache, int32 [3]): of its expert layers, those that took the landed
+        form, those that took the whole one, and the pairs that landed."""
 
         def make():
             precision = self._precision
             fwd = self._fwd
             park = self._park
             stateful = self._stateful
+            forms = [] if self._counts_forms else None
 
             @partial(jax.jit, donate_argnums=(2,))
             def step(params, tokens, cache, pos_vec, *aux):
@@ -1613,8 +1635,9 @@ class InferenceEngine:
                         attn_window=window, attn_park_threshold=park,
                         logits_mode="last", n_micro=self._pp_micro(t),
                         one_live_lane=True, **lane_state,
+                        **({} if forms is None else {"expert_forms": forms}),
                     )
-                return cache
+                return cache if forms is None else (cache, forms.pop())
 
             return step
 
@@ -1838,7 +1861,15 @@ class InferenceEngine:
                 with self._cache_guard():
                     if fault is not None:
                         raise fault
-                    self.cache = step(self.params, arr, self.cache, *rest)
+                    out = step(self.params, arr, self.cache, *rest)
+                    if self._counts_forms:
+                        self.cache, forms = out
+                        # never waited for; with no collect to count them
+                        # (prompts prefilled and nothing decoded) the oldest go
+                        self._chunk_forms = self._chunk_forms[-63:] + [
+                            (self._enqueued, forms)]
+                    else:
+                        self.cache = out
         return width
 
     def prefill_lane(
@@ -2857,12 +2888,31 @@ class InferenceEngine:
                 pairs_routed=routed, pairs_held=held, held_touched=touched,
                 tokens_landed=landed,
             )
+        self._count_chunk_forms(block.enqueued)
         # each active stream advances one token per block row
         self._m_tpot.observe(block.seconds / block.n_steps)
         self._m_sampler.labels(
             sampler="full" if block.fields["n_sampling"] else "greedy"
         ).inc()
         return [[int(t) for t in row] for row in out_np]
+
+    def _count_chunk_forms(self, block: int) -> None:
+        """Count the forms of the chunk programs enqueued before decode block
+        number `block`, which has just been read back: that block took the
+        cache they returned, so the device has passed them and their few
+        integers are read without a wait."""
+        passed = [f for at, f in self._chunk_forms if at < block]
+        if not passed:
+            return
+        self._chunk_forms = [(at, f) for at, f in self._chunk_forms if at >= block]
+        landed, whole, pairs = (
+            int(n) for n in np.sum([np.asarray(f) for f in passed], axis=0))
+        self._m_moe_forms.labels(program="chunk", form="landed").inc(landed)
+        self._m_moe_forms.labels(program="chunk", form="whole").inc(whole)
+        self.recorder.record(
+            "moe_block_forms", program="chunk", chunks=len(passed),
+            landed=landed, whole=whole, pairs_landed=pairs,
+        )
 
     def decode_lanes(
         self,

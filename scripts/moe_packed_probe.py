@@ -18,6 +18,15 @@ packed output has to equal the int8 kernel's bit for bit. Prints
 microseconds a call and a grid step and GB/s of the bytes the form holds;
 writes chiprun_out/moe_packed_probe.json. `--compile-only` compiles every
 case for a described v5e and runs nothing (no chip needed).
+
+`SHARE_CASES`: a held share of the experts the router scores (`pangu` 32 of
+256, `granite` 18 of 72, `lfm2` 16 of 64), packed as served, every row's k
+experts drawn among all of them, at a decode step's rows and a chunk's
+buckets. Per case the whole form alone (the surroundings over every pair:
+`cap` = the call's pairs) against the two forms under `lax.cond` at
+`_landed_cap`'s count whatever rows it saves (the draw lands fewer pairs, so
+the landed form runs): microseconds a call of both, and whether the outputs
+are equal bit for bit. `_LANDED_MIN_SAVED` is read off these lines.
 """
 
 from __future__ import annotations
@@ -50,6 +59,16 @@ CASES = {
     "trinity-decode": (3072, 3072, 32, 2, 8, 4, 4, 3, (256, 512)),
 }
 FORMS = {"int8": 1.125, "packed": 0.625}  # bytes a weight, scales included
+# name: (D, F, experts held, experts routed, layers, rows, k)
+SHARE_CASES = {
+    f"{model}-share-{rows}": (d, f, held, routed, layers, rows, k)
+    for model, (d, f, held, routed, layers, k), buckets in (
+        ("pangu", (7680, 2048, 32, 256, 2, 8), (4, 128, 512)),
+        ("granite", (4096, 768, 18, 72, 4, 10), (32, 64, 128, 256, 512)),
+        ("lfm2", (2048, 1536, 16, 64, 4, 4), (16, 128, 512)),
+    )
+    for rows in buckets
+}
 
 
 def make_stack(key, layers, e, i, o):
@@ -84,6 +103,25 @@ def held_pairs(rows, k, e, pairs, touched, seed=0):
     return jnp.asarray(ids), jnp.asarray(w)
 
 
+def routed_pairs(rows, k, held, routed, seed=0):
+    """[rows, k] ids as `Routing.held` gives them: every row's k distinct
+    experts among `routed`, those below `held` kept, the rest the sentinel."""
+    rng = np.random.default_rng(seed)
+    ids = np.stack([rng.choice(routed, k, replace=False) for _ in range(rows)])
+    ids = np.where(ids < held, ids, held).astype(np.int32)
+    w = np.where(ids < held, rng.random((rows, k)) + 0.1, 0).astype(np.float32)
+    return jnp.asarray(ids), jnp.asarray(w)
+
+
+def landed_cap(rows, k, held, routed):
+    """`_landed_cap`'s count for the call, whatever rows it saves."""
+    was, mk._LANDED_MIN_SAVED = mk._LANDED_MIN_SAVED, 0
+    try:
+        return mk._landed_cap(rows * k, mk._held_rows(rows * k, True), held, routed)
+    finally:
+        mk._LANDED_MIN_SAVED = was
+
+
 def grid_steps(ids, e, row_tile):
     """Distinct (row tile, expert) among the sorted held pairs."""
     held = np.sort(np.asarray(ids)[np.asarray(ids) < e])
@@ -95,7 +133,9 @@ def loop_over_layers(layers, calls, **tiles):
     def run(x, stacks, ids, w):
         def body(i, acc):
             y = mk.moe_held_experts_q40(x, *stacks, ids, w, i % layers, **tiles)
-            return acc + y[0, 0]
+            # a share case reads the whole output: from one element of it the
+            # compiler could drop the rest of the landed form's sum of gathers
+            return acc + (jnp.sum(y) if tiles.get("cap") else y[0, 0])
         return jax.lax.fori_loop(0, calls, body, jnp.float32(0))
     return run
 
@@ -127,6 +167,18 @@ def describe_compile(cases):
     s = SingleDeviceSharding(topo.devices[0])
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=s)
     for name in cases:
+        if name in SHARE_CASES:
+            d, f, held, routed, layers, rows, k = SHARE_CASES[name]
+            for cap in (rows * k, landed_cap(rows, k, held, routed)):
+                t0 = time.perf_counter()
+                loop_over_layers(layers, layers, cap=cap).lower(
+                    sds((rows, d), jnp.bfloat16),
+                    [sds(*a) for a in stack_shapes("packed", layers, held, d, f)],
+                    sds((rows, k), jnp.int32), sds((rows, k), jnp.float32),
+                ).compile()
+                print(f"compiled {name} cap={cap} {time.perf_counter() - t0:.1f}s",
+                      flush=True)
+            continue
         d, f, e, layers, rows, k, _, _, bfs = CASES[name]
         for form in FORMS:
             for bf in bfs:
@@ -150,7 +202,7 @@ def describe_compile(cases):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--compile-only", action="store_true")
-    ap.add_argument("--cases", default=",".join(CASES))
+    ap.add_argument("--cases", default=",".join([*CASES, *SHARE_CASES]))
     ap.add_argument("--row-tiles", default="128,32",
                     help="row tiles of the sweep at equal tiles (F blocks: CASES)")
     a = ap.parse_args()
@@ -165,7 +217,11 @@ def main() -> int:
     out = []
     stacks, held_shape = {}, None
     for ci, name in enumerate(cases):
-        d, f, e, layers, rows, k, pairs, touched, bfs = CASES[name]
+        if name in SHARE_CASES:
+            d, f, e, routed, layers, rows, k = SHARE_CASES[name]
+            pairs = touched = None
+        else:
+            d, f, e, layers, rows, k, pairs, touched, bfs = CASES[name]
         if held_shape != (d, f, e, layers):  # the decode sweep shares stacks
             stacks.clear()
             held_shape = (d, f, e, layers)
@@ -175,9 +231,28 @@ def main() -> int:
                 stacks.setdefault("int8", []).extend((q, s))
                 stacks.setdefault("packed", []).extend((jax.jit(pack_stack)(q, s), s))
             jax.block_until_ready(stacks)
-        ids, w = held_pairs(rows, k, e, pairs, touched, seed=ci)
         x = jax.random.normal(jax.random.PRNGKey(100 + ci), (rows, d), jnp.bfloat16)
         calls = 4 * layers
+        if name in SHARE_CASES:
+            ids, w = routed_pairs(rows, k, e, routed, seed=ci)
+            cap = landed_cap(rows, k, e, routed)
+            landed = int((np.asarray(ids) < e).sum())
+            rec = dict(case=name, d=d, f=f, rows=rows, pairs=rows * k, landed=landed,
+                       cap=cap, served_cap=mk._landed_cap(
+                           rows * k, mk._held_rows(rows * k, True), e, routed))
+            got = {}
+            for form, c in (("whole", rows * k), ("landed", cap)):
+                rec[f"us_per_call_{form}"] = time_call(
+                    loop_over_layers(layers, calls, cap=c),
+                    (x, stacks["packed"], ids, w), calls)
+                got[form] = mk.moe_held_experts_q40(
+                    x, *stacks["packed"], ids, w, layers - 1, cap=c)
+            rec["landed_form_ran"] = landed <= cap < rows * k
+            rec["bit_equal"] = bool(jnp.array_equal(got["whole"], got["landed"]))
+            out.append(rec)
+            print(json.dumps(rec), flush=True)
+            continue
+        ids, w = held_pairs(rows, k, e, pairs, touched, seed=ci)
         # as served (each form's own tiles), then the sweep at equal tiles
         tilings = [None] + [(r, bf) for r in row_tiles for bf in bfs]
         served = {}

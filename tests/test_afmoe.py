@@ -7,6 +7,7 @@ vocabulary, and the prefix pool over two kinds of cache."""
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 
@@ -302,6 +303,109 @@ def test_held_experts_kernel_computes_the_pairs_that_landed_here(
         jnp.asarray(xb)[None], None, *(jnp.asarray(w[2][layer]) for w in (w1, w2, w3)),
         tf.Routing(k), silu, routed=(jnp.asarray(ids)[None], jnp.asarray(wts)[None])))[0]
     assert np.abs(got - dense).max() <= 0.02 * max(np.abs(dense).max(), 1e-6)
+
+
+LANDED_N, LANDED_K, LANDED_CAP, LANDED_TILE = 64, 4, 64, 16
+
+
+def landed_pairs(case: str, rng, e: int):
+    """[64, 4] held ids (the sentinel `e` elsewhere) and weights of one
+    case of `LANDED_CASES`, and the rows of x the case wants alike."""
+    n, k, cap = LANDED_N, LANDED_K, LANDED_CAP
+    held = np.zeros(n * k, bool)
+    ids = rng.integers(0, e, n * k)
+    alike = None
+    if case == "parked":  # a quarter of the rows live, the rest the sentinel alone
+        held.reshape(n, k)[16:32] = rng.random((16, k)) < 0.6
+    elif case == "one-expert-tiles":
+        # padding rows: one token many times over, all its pairs on one held
+        # expert, so whole row tiles are one expert's, beside a few others'
+        alike = slice(20, 60)
+        held.reshape(n, k)[alike, 1] = True
+        ids.reshape(n, k)[alike, 1] = 2
+        held.reshape(n, k)[:6, 0] = True
+    else:
+        count = {"none": 0, "one": 1, "cap-1": cap - 1, "cap": cap, "cap+1": cap + 1,
+                 "all": n * k}[case]
+        held[rng.permutation(n * k)[:count]] = True
+    ids = np.where(held, ids, e).astype(np.int32).reshape(n, k)
+    wts = np.where(ids < e, rng.random((n, k)) + 0.1, 0).astype(np.float32)
+    return ids, wts, alike
+
+
+LANDED_CASES = ["none", "one", "cap-1", "cap", "cap+1", "all", "parked", "one-expert-tiles"]
+
+
+@pytest.mark.parametrize("form", ["int8", "packed"])
+@pytest.mark.parametrize("case", LANDED_CASES)
+def test_the_landed_form_equals_the_whole_form_bit_for_bit(case, form):
+    """`moe_held_experts_q40` with its surroundings sized by the sorted
+    pairs' first 64 of 256 (`cap`: the schedule, the gather, the kernel's
+    output, and a sum by gathers where the whole form masks and
+    scatter-adds), under `lax.cond` beside the whole form, against the whole
+    form alone: one output bit for bit, whichever side of the `cond` the
+    pairs that landed take (at most 64: the landed form; 65 and more: the
+    whole one)."""
+    from dllama_tpu.ops.moe_kernel import moe_held_experts_q40
+    from dllama_tpu.ops.quant_matmul import QuantWeight, pack_nibbles
+
+    e = 4
+    x, _, _, w1, w2, w3 = held_kernel_case(LANDED_N, LANDED_K, 0.0, seed=11, e=e)
+    ids, wts, alike = landed_pairs(case, np.random.default_rng(12), e)
+    if alike is not None:
+        x[alike] = x[alike.start]
+    landed = int((ids < e).sum())
+    assert {"none": 0, "one": 1, "cap-1": 63, "cap": 64, "cap+1": 65, "all": 256}.get(
+        case, landed) == landed
+    assert case != "one-expert-tiles" or (
+        np.sort(ids[ids < e])[16:32] == 2).all()  # a whole row tile of one expert
+    stacks = [jnp.asarray(a) for w in (w1, w2, w3) for a in w[:2]]
+    if form == "packed":
+        stacks = [a for q, d in zip(stacks[::2], stacks[1::2])
+                  for a in pack_nibbles(QuantWeight(q, d))]
+    call = lambda cap: np.asarray(moe_held_experts_q40(
+        jnp.asarray(x), *stacks, jnp.asarray(ids), jnp.asarray(wts), jnp.int32(1),
+        interpret=True, row_tile=LANDED_TILE, cap=cap))
+    whole = call(LANDED_N * LANDED_K)
+    np.testing.assert_array_equal(call(LANDED_CAP), whole)
+    assert np.isfinite(whole).all() and (landed == 0) == (not whole.any())
+    if case == "parked":
+        assert not whole[:16].any() and not whole[32:].any() and whole[16:32].any()
+
+
+def held_text(n, k, e, n_routed, **kw):
+    from dllama_tpu.ops.moe_kernel import moe_held_experts_q40
+
+    _, ids, wts, w1, w2, w3 = held_kernel_case(n, k, 0.5, seed=1, e=e)
+    stacks = [jnp.asarray(a) for w in (w1, w2, w3) for a in w[:2]]
+    return str(jax.make_jaxpr(
+        functools.partial(moe_held_experts_q40, n_routed=n_routed, interpret=True, **kw)
+    )(jnp.zeros((n, 256), jnp.float32), *stacks, jnp.asarray(ids), jnp.asarray(wts),
+      jnp.int32(1)))
+
+
+@pytest.mark.parametrize("n,k,e,n_routed,forms", [
+    (512, 8, 4, 4, 1),  # every expert held: a chunk of the sparse cell
+    (4, 8, 4, 32, 1),  # an eighth held, a decode step's pairs: too few rows to save
+    (512, 8, 4, 32, 2),  # an eighth held, a chunk's pairs: cap 768 of 4096
+], ids=["all-held-chunk", "share-decode", "share-chunk"])
+def test_the_form_follows_from_the_shape_and_the_held_share(n, k, e, n_routed, forms):
+    """Where every expert is held, or too few rows would be saved, the
+    function traces to one form: the program it traces to when it is told
+    nothing of a share (the parent's call), one kernel call in it. A chunk
+    of a held share traces to a `cond` over both."""
+    from dllama_tpu.ops import moe_kernel as mk
+
+    pairs = n * k
+    cap = mk._landed_cap(pairs, mk._held_rows(pairs, False), e, n_routed)
+    text = held_text(n, k, e, n_routed)
+    plain = held_text(n, k, e, None)
+    assert text.count("pallas_call[") == forms and plain.count("pallas_call[") == 1
+    if forms == 1:
+        assert cap == -(-pairs // 128) * 128 and text == plain
+    else:
+        assert cap == 768 and text != plain
+        assert text == held_text(n, k, e, None, cap=768)
 
 
 def test_ring_flash_kernel_equals_the_dense_path():

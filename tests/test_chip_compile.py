@@ -722,6 +722,25 @@ LFM2_TYPES = ["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 9 +
     "full_attention", "conv"]
 
 
+def expert_block_forms(h, rows: int, packed: bool) -> int:
+    """The forms a program's expert block compiles of `moe_held_experts_q40`
+    over one lane's `rows`: 2 (under one conditional) where the landed form
+    saves rows enough at that count of pairs and held share, else 1."""
+    from dllama_tpu.ops import moe_kernel as mk
+
+    pairs = rows * h.n_active_experts
+    cap = mk._landed_cap(pairs, mk._held_rows(pairs, packed), h.n_experts, h.n_routed_experts)
+    return 2 if cap < pairs else 1
+
+
+def only_the_expert_block_branches(text: str, forms: int) -> bool:
+    """Every conditional of a compiled program holds one landed form of the
+    held kernel (none where the expert block compiles one form)."""
+    landed = [line for line in text.splitlines() if "tpu_custom_call" in line
+              and "moe_held_experts_q40_landed" in line.split(" = ")[0]]
+    return text.count(" conditional(") == len(landed) and bool(landed) == (forms == 2)
+
+
 def lfm2_header(published: bool):
     """LFM2-24B-A2B as one of four chips, or the tests' tiny widths with the
     same pattern over 12 layers."""
@@ -785,8 +804,10 @@ def test_layer_scan_carries_lane_state_beside_a_cache_of_ten_layers(
     attention layers and the state stack of the 30 convolution layers, each
     written in place by `dynamic-update-slice` (a chunk's rows; a lane's two
     rows), no layer of either is copied out, no branch is taken on the
-    device over the layer pattern, and the chunk program's convolution
-    layers compute the admitted lane's 512 rows, not the 8192 of all."""
+    device over the layer pattern (a chunk's sparse layers branch over their
+    expert block's two forms, and nothing else does), and the chunk
+    program's convolution layers compute the admitted lane's 512 rows, not
+    the 8192 of all."""
     from dllama_tpu.formats.model_file import layer_table
     from dllama_tpu.models import transformer as tf
 
@@ -820,7 +841,8 @@ def test_layer_scan_carries_lane_state_beside_a_cache_of_ten_layers(
         sds((lanes, rows, 32), jnp.float32, s), sds((lanes, rows, 32), jnp.float32, s),
         sds((3,), jnp.int32, s),
     )
-    assert "moe_held_experts_q40" in text and " conditional(" not in text
+    assert "moe_held_experts_q40" in text
+    assert only_the_expert_block_branches(text, expert_block_forms(h, rows, packed=False))
     if not published:
         return  # the tiny widths lower: a shape the chip rejects fails here
     assert text.count("dynamic-update-slice(") >= 3  # K rows, V rows, a state
@@ -929,8 +951,9 @@ def test_layer_scan_carries_a_recurrent_state_beside_a_cache_of_two_layers(
     of float32 recurrent states, each written in place by
     `dynamic-update-slice`; no copy of the recurrent stack, or of one layer's
     lanes of it, is made; no branch is taken on the device over the layer
-    pattern; and a chunk program's mixers compute the admitted lane's rows,
-    not those of all 32."""
+    pattern (a chunk's sparse layers branch over their expert block's two
+    forms, and nothing else does); and a chunk program's mixers compute the
+    admitted lane's rows, not those of all 32."""
     from dllama_tpu.formats.model_file import layer_table
     from dllama_tpu.models import transformer as tf
 
@@ -964,7 +987,8 @@ def test_layer_scan_carries_a_recurrent_state_beside_a_cache_of_two_layers(
         sds((lanes, rows, 64), jnp.float32, s), sds((lanes, rows, 64), jnp.float32, s),
         sds((3,), jnp.int32, s),
     )
-    assert "moe_held_experts_q40" in text and " conditional(" not in text
+    assert "moe_held_experts_q40" in text
+    assert only_the_expert_block_branches(text, expert_block_forms(h, rows, packed=True))
     if not published:
         return  # the tiny widths lower: a shape the chip rejects fails here
     assert text.count("dynamic-update-slice(") >= 4  # K rows, V rows, both states
@@ -1404,4 +1428,54 @@ def test_held_experts_packed_at_the_cells_shapes(one_chip, d, f, e, k, lanes, ro
         sds((n, k), jnp.float32, one_chip),
     )
     assert "moe_held_experts_q40" in text
+    assert not weight_copies(text), weight_copies(text)
+
+
+@pytest.mark.parametrize("d,f,e,n_routed,k,cap", [
+    (7680, 2048, 32, 256, 8, 768), (4096, 768, 18, 72, 10, 1920),
+    (2048, 1536, 16, 64, 4, 768),
+], ids=["pangu", "granite", "lfm2"])
+def test_held_share_chunk_compiles_both_forms(one_chip, d, f, e, n_routed, k, cap):
+    """A 512-row chunk's expert block where a share of the routed experts is
+    held (packed stacks, by layer number in a scan): what surrounds the
+    kernel is compiled twice under one conditional, over the sorted pairs'
+    first `cap` (the landed form) and over all of them, each with its own
+    kernel call under the call's row tile and F block; the experts' words
+    reach both branches where they lie, no layer's copied."""
+    from jax import lax
+
+    from dllama_tpu.ops import moe_kernel as mk
+
+    n_layers, n = 4, 512
+    assert mk._landed_cap(n * k, mk._held_rows(n * k, True), e, n_routed) == cap
+    w13 = (sds((n_layers, e, d // 8, f), jnp.int32, one_chip),
+           sds((n_layers, e, d // 32, f), jnp.float32, one_chip))
+    w2 = (sds((n_layers, e, f // 8, d), jnp.int32, one_chip),
+          sds((n_layers, e, f // 32, d), jnp.float32, one_chip))
+
+    def run(x, w1q, w1d, w2q, w2d, w3q, w3d, ii, ww):
+        def step(x, l):
+            y = mk.moe_held_experts_q40(
+                x, w1q, w1d, w2q, w2d, w3q, w3d, ii, ww, l, n_routed=n_routed)
+            return x + y.astype(x.dtype), None
+
+        return lax.scan(step, x, jnp.arange(n_layers, dtype=jnp.int32))[0]
+
+    text = compiled_text(
+        jax.jit(run),
+        sds((n, d), jnp.bfloat16, one_chip),
+        *w13, *w2, *w13,
+        sds((n, k), jnp.int32, one_chip),
+        sds((n, k), jnp.float32, one_chip),
+    )
+    assert text.count(" conditional(") == 1
+    # a profile's names for the two: the jitted functions that hold them
+    kernels = {form: [line for line in text.splitlines() if "tpu_custom_call" in line
+                      and f"moe_held_experts_q40_{form}" in line.split(" = ")[0]]
+               for form in ("landed", "whole")}
+    assert [len(v) for v in kernels.values()] == [1, 1], kernels
+    for form, rows in (("landed", cap), ("whole", n * k)):
+        (line,) = kernels[form]
+        assert f"bf16[{rows},{d}]" in line, line[:300]  # x_sorted
+        assert line.split(" = ")[1].startswith(f"f32[{rows},{d}]"), line[:300]
     assert not weight_copies(text), weight_copies(text)

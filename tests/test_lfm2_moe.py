@@ -455,8 +455,10 @@ def test_an_adopted_prefix_gives_the_state_and_logits_of_the_request_cold(lanes)
     assert np.abs(k[:, 1, :, :n] - k[:, 2, :, :n]).max() < 1e-5
     assert np.array_equal(k[:, 1, :, :m], k[:, 0, :, :m])  # the adopted rows, untouched
     assert e._m_replay_tokens.value - replay0 == e.state_replay_rows
+    # the recorder is the process's: another engine's chunks carry no such field
     replayed = [d for d in e.recorder.events("step_dispatch")
-                if d["step"] == "prefill_lane_chunk" and d["lane"] == 1 and d["replay_tokens"]]
+                if d["step"] == "prefill_lane_chunk" and d["lane"] == 1
+                and d.get("replay_tokens")]
     assert replayed[0]["pos"] == start
     assert sum(d["replay_tokens"] for d in replayed) == 24
     out = e.decode_lanes([second[-1]] * 16, [n] * 16, 6, [l in (1, 2) for l in range(16)])

@@ -332,3 +332,99 @@ def test_lane_programs_carry_the_layer_scopes(lane_programs, program):
     if program == "decode":  # a prefill chunk's logits are dead code
         assert any("/logits_head" in n for n in op_names)
         assert any("/sample" in n for n in op_names)
+
+
+@pytest.fixture(scope="module")
+def held_share(tmp_path_factory):
+    """Four lanes of a tiny `afmoe` that holds 4 of the 16 experts its
+    router scores, chunks of 64 rows: 128 pairs a chunk and layer, of which
+    the landed form holds 48 where the row tile is 16 and any rows saved
+    count (both set here: the served constants leave a call this small one
+    form)."""
+    from helpers import make_tiny_afmoe
+
+    from dllama_tpu.ops import moe_kernel as mk
+
+    d = tmp_path_factory.mktemp("forms")
+    was = mk._HELD_ROWS, mk._LANDED_MIN_SAVED
+    mk._HELD_ROWS, mk._LANDED_MIN_SAVED = 16, 0
+    try:
+        assert mk._landed_cap(128, mk._held_rows(128, False), 4, 16) == 48
+        make_tiny_afmoe(str(d / "m.m"), num_routed_experts=16)
+        make_tiny_tokenizer(str(d / "t.t"), chat_template="<|start_header_id|>", pad_to=512)
+        tok = Tokenizer(str(d / "t.t"))
+        engine = InferenceEngine(
+            str(d / "m.m"), tokenizer=tok, tp=1, dtype=jnp.float32, temperature=0.0,
+            seed=3, batch_size=4, prefill_buckets=(1, 64), max_seq_len=256)
+        yield engine, tok
+    finally:
+        mk._HELD_ROWS, mk._LANDED_MIN_SAVED = was
+
+
+def _forms(engine):
+    return {form: engine._m_moe_forms.labels(program="chunk", form=form).value
+            for form in ("landed", "whole")}
+
+
+def test_a_chunks_forms_are_counted_where_the_next_block_is_collected(held_share, monkeypatch):
+    """A chunk program's form counts stay on the device, un-read, until a
+    decode block enqueued after it has been read back: that collect adds
+    them to `dllama_moe_block_forms_total` and the recorder, and reads
+    nothing back of its own (`_read_back`: the one wait of a collect). A
+    chunk enqueued behind the block waits for the next."""
+    engine, _ = held_share
+    assert engine._counts_forms
+    n_expert_layers = 4
+    waits = []
+    read_back = engine._read_back
+    monkeypatch.setattr(
+        engine, "_read_back", lambda step, *a, **kw: waits.append(step) or read_back(
+            step, *a, **kw))
+    before, n0 = _forms(engine), len(engine.recorder.events("moe_block_forms"))
+    engine.prefill_lane(0, list(range(5, 105)))  # 99 rows: a chunk of 64, one of 35 padded
+    assert len(engine._chunk_forms) == 2 and not waits
+    assert _forms(engine) == before
+    assert len(engine.recorder.events("moe_block_forms")) == n0
+    block = engine.dispatch_lanes([7, 0, 0, 0], [99, 0, 0, 0], 2,
+                                  active=[True, False, False, False])
+    engine.prefill_lane_chunk(1, list(range(9, 40)), 0)  # behind the block
+    engine.collect_lanes(block)
+    assert waits == ["decode_lanes"]
+    (event,) = engine.recorder.events("moe_block_forms")[n0:]
+    assert event["program"] == "chunk" and event["chunks"] == 2
+    assert event["landed"] + event["whole"] == 2 * n_expert_layers
+    assert event["landed"] > 0  # a chunk of 128 pairs, a quarter held: about 32 land
+    assert 0 < event["pairs_landed"] <= 2 * n_expert_layers * 128
+    after = _forms(engine)
+    assert after["landed"] - before["landed"] == event["landed"]
+    assert after["whole"] - before["whole"] == event["whole"]
+    assert len(engine._chunk_forms) == 1
+    out = engine.decode_lanes([7, 8, 0, 0], [101, 31, 0, 0], 1,
+                              active=[True, True, False, False])
+    assert len(out) == 1 and not engine._chunk_forms
+    assert engine.recorder.events("moe_block_forms")[-1]["chunks"] == 1
+    assert 'dllama_moe_block_forms_total{program="chunk",form="landed"}' in engine.obs.render()
+
+
+def test_a_server_stopped_with_a_chunks_forms_pending_drops_them(held_share):
+    """Nothing waits for a chunk's form counts: a server whose last chunk
+    no decode block followed stops as any other, and the counts go with
+    the engine."""
+    engine, tok = held_share
+    srv = serve(engine, tok, host="127.0.0.1", port=0)
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    _stream(f"http://127.0.0.1:{srv.server_address[1]}", 1, 4)
+    sched = srv.state.scheduler
+    deadline = time.time() + 60
+    while time.time() < deadline and (
+            sched._flight is not None or any(sched.lanes) or sched.admitting):
+        time.sleep(0.01)
+    assert engine.recorder.events("moe_block_forms")  # the request's own chunks
+    counted = _forms(engine)
+    engine.prefill_lane_chunk(2, list(range(9, 40)), 0)
+    assert len(engine._chunk_forms) == 1
+    t0 = time.time()
+    srv.shutdown()
+    srv.server_close()
+    assert time.time() - t0 < 30
+    assert len(engine._chunk_forms) == 1 and _forms(engine) == counted
