@@ -42,7 +42,13 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
                         "single-host ICI always syncs exact f32")
     p.add_argument("--nthreads", type=int, default=1, help="accepted for CLI parity; XLA owns threading")
     p.add_argument("--net-turbo", type=int, default=1, help="accepted for CLI parity")
-    p.add_argument("--nbatches", "--n-batches", type=int, default=32, dest="nbatches", help="prefill chunk size")
+    p.add_argument("--nbatches", "--n-batches", type=int, default=256, dest="nbatches",
+                   help="the smallest prefill chunk bucket above 1 (default "
+                        "256; the reference's CPU batch was 32). A chunk runs "
+                        "the smallest bucket that covers it: 1, this one, 512, "
+                        "and the program's own rungs at 128 and 256 where they "
+                        "lie between (engine.prefill_ladder): (1, 256, 512) by "
+                        "default, (1, 128, 256, 512) under 128, (1, 512) under 512")
     p.add_argument("--batch-size", type=int, default=1, dest="batch_size",
                    help="decode lanes: >1 lets the API server stream "
                         "multiple requests concurrently (per-lane "
@@ -279,7 +285,7 @@ def _resolve_tp(args) -> int:
 def load_engine(args):
     import jax.numpy as jnp
 
-    from .runtime.engine import InferenceEngine
+    from .runtime.engine import InferenceEngine, prefill_ladder
     from .tokenizer import Tokenizer
 
     if not args.model or not args.tokenizer:
@@ -315,7 +321,7 @@ def load_engine(args):
         temperature=args.temperature,
         topp=args.topp,
         seed=args.seed,
-        prefill_buckets=tuple(sorted({1, args.nbatches, 512})),
+        prefill_buckets=prefill_ladder(args.nbatches),
         weight_format=args.weight_format,
         batch_size=getattr(args, "batch_size", 1),
         buffer_float_type=buffer_ft,

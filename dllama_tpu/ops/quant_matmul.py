@@ -342,6 +342,20 @@ ACC_X_TILE_BYTES = BLOCK_M * 3584 * 2
 DECODE_ROWS = 64
 
 
+def _pick_row_block(m: int) -> int:
+    """Rows a block: the call's rows whole up to BLOCK_M; beyond it the
+    fewest blocks that hold them, evened out. A block costs its rows on the
+    MXUs whatever is real in it, so 640 rows (five lanes' 128-row chunk) are
+    2 x 320 and not 512 and a tail of 128 padded to 512, and 1280 are 3 x
+    432 (the last one ragged by 16: rows are independent, the pad's are
+    dropped) where 3 x 512 compute a fifth more. A multiple of 16, the
+    sublanes of a bf16 tile; any multiple of BLOCK_M keeps its blocks."""
+    if m <= BLOCK_M:
+        return m
+    n_blocks = pl.cdiv(m, BLOCK_M)
+    return pl.cdiv(pl.cdiv(m, n_blocks), 16) * 16
+
+
 def _pick_k_block(k: int, preferred: int, rows: int) -> int:
     """`_pick_block` for the contraction axis under `rows` activation rows:
     the deepest legal block whose activation tile, where k takes several
@@ -372,7 +386,7 @@ def _qmm_call(
     assert values.shape[1:] == (k // pack, n), (values.shape, x.shape)
     assert scales.shape == (values.shape[0], k // Q_BLOCK, n), scales.shape
     bn = _pick_block(n, block_n, ragged=True)
-    bm = min(m, BLOCK_M)
+    bm = _pick_row_block(m)
     bk = _pick_k_block(k, block_k, bm)
     assert bk % Q_BLOCK == 0
     n_k = k // bk

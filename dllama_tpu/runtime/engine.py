@@ -34,10 +34,26 @@ from ..tokenizer import Tokenizer
 from .faults import get_fault_plane
 from .sampler import Sampler
 
-# Prefill chunk buckets: one compiled program per bucket (the reference's
-# --nBatches plays the same role: its graphs are compiled-in for nBatches
-# rows and prefill walks the prompt in nBatches-sized chunks).
-DEFAULT_PREFILL_BUCKETS = (1, 8, 32, 128, 512)
+# the rungs a ladder takes between the smallest rung asked for and the
+# largest: below about 128 rows a lane a chunk stops getting cheaper with
+# its rows (the weights' intake sets its floor), and every rung is one more
+# program to build at every attention window
+MIDDLE_RUNGS = (128, 256)
+
+
+def prefill_ladder(smallest: int, largest: int = 512) -> tuple[int, ...]:
+    """The prefill chunk buckets, one compiled program a bucket and window
+    (the reference's --nBatches plays the same role: its graphs are
+    compiled-in for nBatches rows and prefill walks the prompt in
+    nBatches-sized chunks): 1, the `smallest` rung a caller asks for
+    (`--nbatches`), `largest`, and the middle rungs that lie strictly
+    between the two. A chunk runs the smallest rung that covers it
+    (`_bucket_for`), so a rung saves the padding rows above it."""
+    middle = {b for b in MIDDLE_RUNGS if smallest < b < largest}
+    return tuple(sorted({1, smallest, largest} | middle))
+
+
+DEFAULT_PREFILL_BUCKETS = prefill_ladder(8)
 # the smallest attention window of a cache of latent rows (`_attn_window`)
 LATENT_MIN_WINDOW = 4096
 
@@ -607,6 +623,20 @@ class InferenceEngine:
             "did with them: computed = rows it routed and ran (the admitted "
             "lane's bucket), parked_skipped = parked lanes' rows it left out.",
             labelnames=("rows",),
+        )
+        self._m_prefill_chunks = self.obs.counter(
+            "dllama_prefill_chunks_total",
+            "Prefill chunk programs dispatched (prefill_lane_chunk), by the "
+            "bucket they ran: the smallest rung of the ladder that covers "
+            "the chunk's tokens.",
+            labelnames=("bucket",),
+        )
+        self._m_prefill_rows = self.obs.counter(
+            "dllama_prefill_rows_total",
+            "Rows a lane of those chunk programs: real = the tokens a chunk "
+            "was asked for, bucket = the rows its program computed; "
+            "1 - real / bucket is the padded share.",
+            labelnames=("kind",),
         )
         self._m_drained = self.obs.counter(
             "dllama_engine_device_drained_seconds_total",
@@ -1841,6 +1871,9 @@ class InferenceEngine:
         except ValueError:
             self._spans.end(prep)  # a refusal is no dispatch: nothing stays open
             raise
+        self._m_prefill_chunks.labels(bucket=str(bucket)).inc()
+        self._m_prefill_rows.labels(kind="real").inc(width)
+        self._m_prefill_rows.labels(kind="bucket").inc(bucket)
         arr, *rest = self._host_args(
             *self._page_table_arg(), posv, *state_arg, tokens=rows
         )

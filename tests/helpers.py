@@ -557,3 +557,77 @@ def tiny_granite_config(layer_types=None, **over) -> dict:
 def make_tiny_granite(path: str, seed: int = 3, **over) -> dict:
     """The same for `granitemoehybrid`, as `make_tiny_afmoe`."""
     return _write_tiny(path, tiny_granite_config(**over), seed)
+
+
+# -- the prefill ladder's middle rungs (PR 49) ------------------------------------
+
+# prompts whose fills take the 128-row and the 256-row rung of the served ladder
+MIDDLE_RUNG_PROMPTS = {100: 128, 200: 256}
+
+
+_RUNG_ENGINES: dict = {}
+
+
+def _rung_engine(path: str, buckets: tuple):
+    """One two-lane engine a model file and ladder, kept over a file's cases
+    (each would build its chunk programs anew)."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.runtime.engine import InferenceEngine
+
+    key = path, buckets
+    if key not in _RUNG_ENGINES:
+        _RUNG_ENGINES[key] = InferenceEngine(
+            path, tp=1, dtype=jnp.float32, temperature=0.0, batch_size=2,
+            prefill_buckets=buckets, max_seq_len=1024)
+    _RUNG_ENGINES[key].reset()
+    return _RUNG_ENGINES[key]
+
+
+def assert_a_middle_rung_equals_the_largest(path: str, n_prompt: int, tol: float = 1e-5) -> None:
+    """A prompt prefilled through a middle rung of the ladder the CLI serves
+    (`engine.prefill_ladder(128)`: 128 and 256 rows under the 512 every
+    family's chunk program already ran at) leaves what the same prompt
+    leaves through the largest rung alone, the ladder (1, 512): the lane's
+    cache rows, its lane states, the next token's logits, within the
+    tolerance the families' own chunk tests use. Only rows of padding,
+    which no query reads and no state moves by, differ between the two."""
+    import jax
+
+    from dllama_tpu.runtime.engine import prefill_ladder
+
+    lane, n_fills = 1, n_prompt - 1
+    ids = [int(t) for t in np.random.default_rng(n_prompt).integers(1, 250, n_prompt)]
+    left = {}
+    for name, buckets in (("ladder", prefill_ladder(128)), ("largest", (1, 512))):
+        e = _rung_engine(path, buckets)
+        assert e.prefill_buckets == buckets
+        # the recorder outlives an engine and its ring drops the oldest: by `seq`
+        base = max((d["seq"] for d in e.recorder.events("step_dispatch")), default=-1)
+        e.prefill_lane(lane, ids)
+        ran = [(d["bucket"], d["n_tokens"]) for d in e.recorder.events("step_dispatch")
+               if d["seq"] > base and d["step"] == "prefill_lane_chunk"]
+        want = MIDDLE_RUNG_PROMPTS[n_prompt] if name == "ladder" else 512
+        assert ran == [(want, n_fills)], (name, ran)
+        tok = np.zeros((2, 1), np.int32)
+        tok[lane] = ids[-1]
+        pos = np.full((2,), e._park, np.int32)
+        pos[lane] = n_fills
+        logits, _ = jax.jit(lambda params, t, p, cache, e=e: e._fwd(
+            params, t, p, cache, attn_window=e._attn_window(n_prompt),
+            attn_park_threshold=e._park, logits_mode="last"))(e.params, tok, pos, e.cache)
+        def filled(k, v):
+            if k in ("s", "r"):  # a state is rows a lane, not a position
+                return v[:, lane]
+            # a ring stack has spare rows before the ring (`_ring_append`)
+            row0 = (v.shape[3] - e.kv_ring) // 2 if k in ("kw", "vw") else 0
+            return v[:, lane, :, row0:row0 + n_fills]
+
+        rows = {k: np.asarray(filled(k, v)) for k, v in e.cache.items()}
+        left[name] = rows, np.asarray(logits[lane, -1])
+    (rows, logits), (want_rows, want_logits) = left["ladder"], left["largest"]
+    assert np.abs(logits - want_logits).max() < tol * want_logits.std()
+    assert rows.keys() == want_rows.keys()
+    for k, want in want_rows.items():
+        assert want.any(), k
+        assert np.abs(rows[k] - want).max() < tol * np.abs(want).max(), k

@@ -348,6 +348,9 @@ def test_admission_rehearsal_precompiles_chunk_programs(sched_state):
     the compile cache via the background prefetch, so the first admission
     under load pays no synchronous compile stall."""
     eng = sched_state.engine
+    # the engine's own ladder, `prefill_ladder(8)`, as far as 384 positions
+    # hold a rung: the middle rungs at 128 and 256 are rehearsed like any
+    assert eng.prefill_buckets == (1, 8, 128, 256)
     keys = [
         ("lane_prefill", b, eng._attn_window(b)) for b in eng.prefill_buckets
     ]

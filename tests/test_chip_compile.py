@@ -795,7 +795,9 @@ def lfm2_layers(h, s):
     )
 
 
-@pytest.mark.parametrize("rows,window", [(1, 1024), (512, 2048)], ids=["decode", "prefill"])
+@pytest.mark.parametrize(
+    "rows,window", [(1, 1024), (512, 2048), (256, 1024), (128, 4096)],
+    ids=["decode", "prefill", "prefill256", "prefill128"])  # the ladder's middle rungs: PR 49
 @pytest.mark.parametrize("published", [True, False], ids=["published", "tiny"])
 def test_layer_scan_carries_lane_state_beside_a_cache_of_ten_layers(
         one_chip, monkeypatch, published, rows, window):
@@ -939,8 +941,9 @@ def granite_layers(h, s):
     )
 
 
-@pytest.mark.parametrize("rows,window", [(1, 1024), (512, 2048), (256, 1024), (64, 512)],
-                         ids=["decode", "prefill512", "prefill256", "prefill64"])
+@pytest.mark.parametrize(
+    "rows,window", [(1, 1024), (512, 2048), (256, 1024), (64, 512), (128, 4096)],
+    ids=["decode", "prefill512", "prefill256", "prefill64", "prefill128"])
 @pytest.mark.parametrize("published", [True, False], ids=["published", "tiny"])
 def test_layer_scan_carries_a_recurrent_state_beside_a_cache_of_two_layers(
         one_chip, monkeypatch, published, rows, window):
@@ -1332,9 +1335,13 @@ def test_qmatmul_q40i4(one_chip, m, k, n):
 # every (k, n) the seven cells' programs hand `qmatmul` (fused q|k|v(|gate)
 # and w1|w3 as one tp shard fuses them), with the rows of the cell's decode
 # block and of its chunk program (lanes x 512; a convolution layer and its
-# FFN: the admitted lane's 512)
+# FFN: the admitted lane's 512), and since PR 49 of the chunk programs at
+# the ladder's middle rungs where the row blocks are new: five lanes' 640
+# and 1280 rows (`_pick_row_block`: 2 x 320, 3 x 432 with a ragged tail),
+# one lane's 128 and 256; sixteen and thirty-two lanes' are whole blocks of
+# 512 like their 8192
 CELL_MATMULS = {
-    "mistral-7b-v0.3": ([5, 2560], [
+    "mistral-7b-v0.3": ([5, 640, 1280, 2560], [
         (4096, 6144), (4096, 4096), (4096, 28672), (14336, 4096), (4096, 32768)]),
     "qwen3-30b-a3b-l12": ([16, 8192], [(2048, 5120), (4096, 2048), (2048, 151936)]),
     "trinity-large-l9-e32": ([8, 4096], [
@@ -1347,12 +1354,12 @@ CELL_MATMULS = {
         (7168, 1536), (1536, 24576), (7168, 576), (16384, 7168), (7168, 36864),
         (18432, 7168), (7168, 4096), (2048, 7168), (1536, 8192), (7168, 128),
         (7168, 16160)]),
-    "lfm2-24b-a2b-e16": ([16, 512, 8192], [
+    "lfm2-24b-a2b-e16": ([16, 128, 256, 512, 8192], [
         (2048, 3072), (2048, 2048), (2048, 6144), (2048, 23552), (11776, 2048),
         (2048, 16384)]),
     # `in_proj`'s 16768 = 131 x 128 columns: tiles of 128, the one multiple
     # of 128 that divides them
-    "granite-4.0-h-small-l20-e18": ([32, 512, 16384], [
+    "granite-4.0-h-small-l20-e18": ([32, 128, 256, 512, 16384], [
         (4096, 16768), (8192, 4096), (4096, 6144), (4096, 4096), (4096, 3072),
         (1536, 4096), (4096, 25088)]),
 }

@@ -13,11 +13,13 @@ import pytest
 
 from dllama_tpu.formats import FloatType
 from dllama_tpu.formats.model_file import LlmArch
-from dllama_tpu.runtime.engine import InferenceEngine
+from dllama_tpu.runtime.engine import InferenceEngine, prefill_ladder
 from dllama_tpu.runtime.faults import InjectedFault
 from dllama_tpu.tokenizer import Tokenizer
 
-from helpers import REPO_ROOT, make_tiny_model, make_tiny_tokenizer
+from helpers import (
+    MIDDLE_RUNG_PROMPTS, REPO_ROOT, TINY, assert_a_middle_rung_equals_the_largest, make_tiny_model,
+    make_tiny_tokenizer)
 
 
 @pytest.fixture()
@@ -1547,6 +1549,126 @@ HARNESS_CALLS = {
 @pytest.mark.parametrize("name", list(HARNESS_CALLS))
 def test_what_the_benchmark_harness_calls(slab_engine, name):
     HARNESS_CALLS[name](slab_engine)
+
+
+# -- the prefill ladder (PR 49): one rule, `engine.prefill_ladder` --------------
+
+LADDERS = {
+    1: (1, 128, 256, 512),
+    8: (1, 8, 128, 256, 512),
+    32: (1, 32, 128, 256, 512),
+    128: (1, 128, 256, 512),
+    256: (1, 256, 512),  # what the CLI serves by default: no program more than (1, 32, 512)
+    512: (1, 512),  # the long-context configurations': no program more than they built
+    1024: (1, 512, 1024),  # above the largest: what `sorted({1, n, 512})` always gave
+}
+
+
+@pytest.mark.parametrize("smallest", list(LADDERS))
+def test_prefill_ladder_rule(smallest):
+    """1, the smallest rung asked for, the largest, and the rungs at 128
+    and 256 where they lie strictly between the two."""
+    ladder = prefill_ladder(smallest)
+    assert ladder == LADDERS[smallest]
+    assert list(ladder) == sorted(set(ladder))
+    assert {1, smallest, 512} <= set(ladder)
+    assert set(ladder) - {1, smallest, 512} == {
+        b for b in (128, 256) if smallest < b < 512}
+
+
+def test_prefill_ladder_under_another_largest_rung():
+    assert prefill_ladder(32, largest=256) == (1, 32, 128, 256)
+    assert prefill_ladder(64, largest=128) == (1, 64, 128)
+
+
+@pytest.fixture(scope="module")
+def long_tiny_model(tmp_path_factory):
+    """The tiny preset with a context every rung of the ladder fits."""
+    d = tmp_path_factory.mktemp("ladder")
+    mp, tp_ = str(d / "m.m"), str(d / "t.t")
+    make_tiny_model(mp, cfg=dict(TINY, seq_len=1024))
+    make_tiny_tokenizer(tp_)
+    return mp, tp_
+
+
+@pytest.mark.parametrize("nbatches", [None, 32, 128, 512])
+def test_cli_load_engine_serves_the_ladder_of_its_nbatches(long_tiny_model, nbatches):
+    """`--nbatches` is the smallest rung above 1 and defaults to 256; the
+    engine `cli.load_engine` builds holds `prefill_ladder` of it."""
+    from dllama_tpu.cli import _build_parser, load_engine
+
+    mp, tp_ = long_tiny_model
+    argv = ["inference", "--model", mp, "--tokenizer", tp_, "--dtype", "f32", "--tp", "1"]
+    args = _build_parser().parse_args(
+        argv + (["--nbatches", str(nbatches)] if nbatches else []))
+    assert args.nbatches == (nbatches or 256)
+    engine, _ = load_engine(args)
+    assert engine.prefill_buckets == prefill_ladder(args.nbatches)
+    if nbatches is None:
+        assert engine.prefill_buckets == (1, 256, 512)
+
+
+@pytest.fixture(scope="module")
+def ladder_engine(long_tiny_model):
+    return InferenceEngine(long_tiny_model[0], tp=1, dtype=jnp.float32, temperature=0.0,
+                           batch_size=2, prefill_buckets=prefill_ladder(32))
+
+
+@pytest.mark.parametrize("n,pos,bucket", [
+    (1, 0, 1), (2, 0, 32), (32, 0, 32), (33, 0, 128), (128, 0, 128), (129, 0, 256),
+    (256, 0, 256), (257, 0, 512), (512, 0, 512), (900, 0, 512),
+    # near the end of the context a rung's PADDED extent has to fit: the
+    # largest rung below the space, which then cuts the chunk
+    (200, 1024 - 300, 256), (290, 1024 - 300, 256), (200, 1024 - 256, 256),
+    (200, 1024 - 255, 128), (90, 1024 - 100, 32), (20, 1024 - 20, 1),
+])
+def test_bucket_for_takes_the_smallest_rung_that_covers_the_chunk(ladder_engine, n, pos, bucket):
+    assert ladder_engine.prefill_buckets == (1, 32, 128, 256, 512)
+    assert ladder_engine._bucket_for(n, pos) == bucket
+
+
+def test_rehearse_admission_builds_every_rung(ladder_engine):
+    e = ladder_engine
+    e.rehearse_admission(BLOCK, wait=True)
+    built = {k for k, o in e._compile_origin.items() if o == "prefetch"}
+    assert {("lane_prefill", b, e._attn_window(b)) for b in (1, 32, 128, 256, 512)} <= built
+    assert not [k for k, o in e._compile_origin.items() if o == "prefetch-failed"]
+
+
+def test_prefill_counters_say_chunks_by_rung_and_rows_real_and_computed(ladder_engine):
+    """`dllama_prefill_chunks_total{bucket}` and
+    `dllama_prefill_rows_total{kind}`: what `prefill_lane_chunk` puts into
+    its `step_dispatch` event as `bucket` and `n_tokens`, summed."""
+    e = ladder_engine
+
+    def counted():  # the registry outlives an engine: differences
+        return (
+            {b: e._m_prefill_chunks.labels(bucket=str(b)).value for b in e.prefill_buckets},
+            [e._m_prefill_rows.labels(kind=k).value for k in ("real", "bucket")],
+        )
+
+    chunks0, rows0 = counted()
+    widths = [5, 32, 33, 1, 200, 300, 128]  # rungs 32, 32, 128, 1, 256, 512, 128
+    pos = 0
+    for w in widths:
+        assert e.prefill_lane_chunk(1, list(range(1, w + 1)), pos) == w
+        pos += w
+    # a budget cuts the chunk before its rung is taken (--admission-chunk)
+    assert e.prefill_lane_chunk(0, list(range(1, 300)), 0, budget=100) == 100
+    chunks, rows = counted()
+    assert {b: chunks[b] - chunks0[b] for b in chunks} == {1: 1, 32: 2, 128: 3, 256: 1, 512: 1}
+    assert [a - b for a, b in zip(rows, rows0)] == [
+        sum(widths) + 100, 1 + 2 * 32 + 3 * 128 + 256 + 512]
+    text = e.obs.render()
+    assert 'dllama_prefill_chunks_total{bucket="256"}' in text
+    assert 'dllama_prefill_rows_total{kind="real"}' in text
+
+
+@pytest.mark.parametrize("n_prompt", list(MIDDLE_RUNG_PROMPTS))
+def test_a_prompt_through_a_middle_rung_leaves_what_the_largest_rung_leaves(
+        long_tiny_model, n_prompt):
+    """The dense family's case of the test every family's file has."""
+    assert_a_middle_rung_equals_the_largest(long_tiny_model[0], n_prompt)
 
 
 def test_weight_format_q40i8_is_refused_by_the_engine(tiny_model):

@@ -567,6 +567,28 @@ def test_a_chunk_that_continues_nothing_and_a_lane_astray_are_refused(lanes):
         e.decode_lanes([5] * LANES, [30] * LANES, 2, [l == 4 for l in range(LANES)])
 
 
+def test_a_refused_chunk_counts_no_row_and_a_run_one_its_rung(lanes):
+    """`dllama_prefill_rows_total` and `dllama_prefill_chunks_total` (PR 49)
+    count a chunk where it is dispatched: one refused for its lane's states
+    counts nothing, and a prompt's chunks count their real rows and the
+    rows of the rungs they ran."""
+    e, _, _ = lanes
+    e.reset()
+
+    def counted():
+        return [e._m_prefill_rows.labels(kind=k).value for k in ("real", "bucket")] + [
+            e._m_prefill_chunks.labels(bucket=str(b)).value for b in (1, 8, CHUNK)]
+
+    before = counted()
+    with pytest.raises(ValueError, match="neither continues"):
+        e.prefill_lane_chunk(3, token_ids(20), 40)
+    assert counted() == before
+    e.prefill_lane(3, token_ids(46))  # 45 fills: two whole chunks, 13 rows in a third
+    assert [a - b for a, b in zip(counted(), before)] == [45, 3 * CHUNK, 0, 0, 3]
+    e.prefill_lane(5, token_ids(6))  # 5 fills: the 8-row rung
+    assert [a - b for a, b in zip(counted(), before)] == [50, 3 * CHUNK + 8, 0, 1, 3]
+
+
 @pytest.mark.parametrize("kwargs,named", [
     ({"tp": 2}, "--tp 2"), ({"sp": 2}, "--sp 2"), ({"pp": 2}, "--pp 2"),
     ({"dp": 2}, "--dp 2"), ({"kv_dtype": "int8"}, "--kv-dtype int8"),
@@ -692,3 +714,13 @@ def test_adoption_is_declined_and_counted_and_nothing_is_published(server):
     assert declined.value == declined0 + 1
     assert e.recorder.events("prefix_adoption_declined")[-1]["why"] == "unbounded"
     sched.kv.check()
+
+
+@pytest.mark.parametrize("n_prompt", [100, 200])
+def test_a_prompt_through_a_middle_rung_leaves_what_the_largest_rung_leaves(lanes, n_prompt):
+    """The served ladder's rungs at 128 and 256 rows (PR 49) against the 512
+    this family's chunk program always ran at: cache rows, lane states and
+    the next token's logits of one prompt through either."""
+    from helpers import assert_a_middle_rung_equals_the_largest
+
+    assert_a_middle_rung_equals_the_largest(lanes[2], n_prompt)

@@ -601,3 +601,13 @@ def test_a_prefix_no_longer_than_the_replay_is_declined(server):
     assert 0 < shared <= e.state_replay_rows  # the start token: something is stored
     assert sched._match_prefix(0, prompt) == (0, [])
     sched.kv.check()
+
+
+@pytest.mark.parametrize("n_prompt", [100, 200])
+def test_a_prompt_through_a_middle_rung_leaves_what_the_largest_rung_leaves(lanes, n_prompt):
+    """The served ladder's rungs at 128 and 256 rows (PR 49) against the 512
+    this family's chunk program always ran at: cache rows, lane states and
+    the next token's logits of one prompt through either."""
+    from helpers import assert_a_middle_rung_equals_the_largest
+
+    assert_a_middle_rung_equals_the_largest(lanes[2], n_prompt)

@@ -374,3 +374,13 @@ def test_pool_native_pages_and_speculation_are_refused_by_name(lanes):
         e.init_kv_pool(4, 40, native=True)
     with pytest.raises(ValueError, match="--speculation.*latent"):
         e.rehearse_admission(4, spec_k=4)
+
+
+@pytest.mark.parametrize("n_prompt", [100, 200])
+def test_a_prompt_through_a_middle_rung_leaves_what_the_largest_rung_leaves(lanes, n_prompt):
+    """The served ladder's rungs at 128 and 256 rows (PR 49) against the 512
+    this family's chunk program always ran at: cache rows, lane states and
+    the next token's logits of one prompt through either."""
+    from helpers import assert_a_middle_rung_equals_the_largest
+
+    assert_a_middle_rung_equals_the_largest(lanes[2], n_prompt)

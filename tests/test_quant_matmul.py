@@ -336,6 +336,37 @@ def test_packed_decode_rows_equal_the_int8_kernel(m):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("m,block", [
+    (1, 1), (5, 5), (512, 512), (520, 272), (640, 320), (1280, 432), (1536, 512),
+    (2048, 512), (2560, 512), (4096, 512), (8192, 512)])
+def test_row_blocks_are_the_fewest_that_hold_the_rows_evened_out(m, block):
+    """Five lanes' 128-row chunk is 640 rows: two blocks of 320, not 512 and
+    a tail of 128 padded to 512; a multiple of 512 keeps its blocks."""
+    from dllama_tpu.ops.quant_matmul import BLOCK_M, _pick_row_block
+
+    assert _pick_row_block(m) == block
+    assert block <= BLOCK_M and (m <= BLOCK_M or block % 16 == 0)
+    assert -(-m // block) == -(-m // BLOCK_M)  # no block more than before
+
+
+@pytest.mark.parametrize("m", [640, 1280])
+def test_packed_rows_of_a_middle_rung_equal_the_int8_kernel_and_the_reference(m):
+    """Five lanes' chunk at the ladder's rungs of 128 and 256 rows, through
+    evened row blocks (the last of 1280 ragged by 16 rows): every row is the
+    int8 kernel's bit for bit and the reference's, none lost to a pad."""
+    from dllama_tpu.ops.quant_matmul import qmatmul_i4_2d
+
+    qw, pw = _edge_weights(256, 256, seed=m)
+    x = jnp.asarray(
+        np.random.default_rng(m).standard_normal((m, 256)).astype(np.float32))
+    got = np.asarray(qmatmul_i4_2d(x, pw.qp, pw.d, interpret=True))
+    want = np.asarray(qmatmul_2d(x, qw.q, qw.d, interpret=True))
+    np.testing.assert_array_equal(got, want)
+    ref = np.asarray(qmatmul_ref(x, qw))
+    np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-2 * np.abs(ref).max())
+    assert np.abs(got[-1]).max() > 0
+
+
 def test_packed_bytes_per_weight():
     """The device residency win the format exists for: 0.625 B/weight
     including scales (0.5 packed nibbles + 4/32 f32 scale)."""
