@@ -410,7 +410,7 @@ class LaneScheduler:
         self._admission_count = 0
         # lanes mid-admission (resumable chunked prefill state machine)
         self.admitting: dict[int, _AdmittingLane] = {}
-        self._rr = -1  # round-robin cursor over concurrently admitting lanes
+        self._rr = -1  # round-robin cursor over concurrently admitting lanes: a tick's lead
         # injectable clock for the stall/prefill accounting (fake-clock
         # scheduler tests replace it; production uses the monotonic timer)
         self._clock = time.perf_counter
@@ -1246,38 +1246,116 @@ class LaneScheduler:
             state.m_streams_parked.set(self._n_parked)
             self.kv.release_lane(lane)
 
+    def _adopt_due(self, adm: _AdmittingLane) -> bool:
+        """Whether `adm`'s next tick is its adopt. Pool-native mode runs the
+        adopt tick even on a zero-token match: kv.adopt() is where the
+        lane's private pages allocate and its page table installs — without
+        it there is no KV home for the prefill to write into."""
+        return self.kv is not None and not adm.adopted and (
+            bool(adm.adopt_pages) or getattr(self.kv, "native", False)
+        )
+
+    def _chunk_riders(self, lead: int) -> list[int]:
+        """The admitting lanes that ride in `lead`'s chunk program, in
+        round-robin order behind it: those whose next action is a chunk too
+        (not cancelled, adopted or needing no adopt, fill tokens left), as
+        many as the engine's chunk program fills besides the lead: none
+        where it takes one lane's rows (`engine.chunk_lanes`)."""
+        def rides(adm):
+            return (not adm.job.cancelled and not self._adopt_due(adm)
+                    and adm.cursor < len(adm.tokens) - 1)
+
+        order = sorted(self.admitting)
+        behind = [i for i in order if i > lead] + [i for i in order if i < lead]
+        riders = [lane for lane in behind if rides(self.admitting[lane])]
+        return riders[: self.engine.chunk_lanes - 1]
+
+    def _chunk_dispatch(self, carried: list[int]) -> None:
+        """ONE chunk program for the next chunk of every lane of `carried`
+        (the tick's lead first), each at its own position. Cursors move
+        once the dispatch has returned, each by what its lane consumed: a
+        lane the engine left out (its rows would pass the context's end in
+        the common bucket) consumed nothing and waits for a tick that it
+        leads."""
+        adms = [self.admitting[lane] for lane in carried]
+        wd = self.state.watchdog
+        spans = [
+            self.state.spans.begin(
+                "admission_chunk", component="scheduler",
+                request_id=adm.job.span.request_id, lane=lane,
+                pos=adm.pos0 + adm.cursor,
+            )
+            for lane, adm in zip(carried, adms)
+        ]
+        chunks = [
+            (lane, adm.tokens[adm.cursor:-1], adm.pos0 + adm.cursor)
+            for lane, adm in zip(carried, adms)
+        ]
+        if len(chunks) == 1:
+            (lane, tokens, pos), adm = chunks[0], adms[0]
+
+            def dispatch():
+                return [self.engine.prefill_lane_chunk(
+                    lane, tokens, pos, budget=self.admission_chunk,
+                    # lane state: the adopted rows stay as they are
+                    **({"write_floor": adm.pos0 + adm.start_pos}
+                       if adm.cursor < adm.start_pos else {}),
+                )]
+        else:
+            def dispatch():
+                return self.engine.prefill_lanes_chunk(
+                    chunks, budget=self.admission_chunk)
+        if wd is not None:
+            wd.dispatch_begin("prefill_lane_chunk")
+        t0 = self._clock()
+        try:
+            widths = self._retry_dispatch("prefill_lane_chunk", dispatch)
+        finally:
+            if wd is not None:
+                wd.dispatch_end()
+            for sp in reversed(spans):
+                self.state.spans.end(sp)
+        took = self._clock() - t0
+        for lane, adm, width in zip(carried, adms, widths):
+            if not width:
+                continue
+            adm.prefill_s += took
+            adm.cursor += width
+            adm.n_chunks += 1
+            self.state.m_admission_chunks.inc()
+            self.state.recorder.record(
+                "admission_chunk", lane=lane, chunk=adm.n_chunks,
+                pos=adm.pos0 + adm.cursor - width, n_tokens=width,
+                done=adm.cursor >= len(adm.tokens) - 1,
+            )
+
     def _admission_tick(self) -> None:
-        """Run at most ONE bounded prefill chunk for ONE admitting lane
-        per scheduler tick, round-robin across concurrent admissions, and
-        flip the lane into decode once its last fill token lands."""
+        """Run at most ONE bounded engine dispatch of admission per
+        scheduler tick and flip a lane into decode once its last fill token
+        lands. The tick's lead is picked round-robin across concurrent
+        admissions; its adopt is its own tick, and its chunk program carries
+        the next chunk of every other admitting lane that can ride in it
+        (`_chunk_riders`)."""
         if not self.admitting:
             return
         order = sorted(self.admitting)
         lane = min((i for i in order if i > self._rr), default=order[0])
         self._rr = lane
         adm = self.admitting[lane]
-        job = adm.job
-        if job.cancelled:
+        if adm.job.cancelled:
             self._abort_admission(lane, "cancelled")
             return
-        fills = adm.tokens[:-1]
         wd = self.state.watchdog
-        rid = job.span.request_id
         epoch0 = self.engine.cache_epoch
-        # pool-native mode runs the adopt tick even on a zero-token
-        # match: kv.adopt() is where the lane's private pages allocate
-        # and its page table installs — without it there is no KV home
-        # for the prefill to write into
-        adopt_needed = self.kv is not None and (
-            bool(adm.adopt_pages) or getattr(self.kv, "native", False)
-        )
+        carried = [lane]
         try:
-            if adopt_needed and not adm.adopted:
+            if self._adopt_due(adm):
                 # the adopt copy is this lane's first tick action and is
                 # its own tick (one bounded engine dispatch per tick, same
                 # budget discipline as a prefill chunk)
                 sp = self.state.spans.begin(
-                    "adopt", component="scheduler", request_id=rid,
+                    "adopt", component="scheduler",
+                    request_id=adm.job.span.request_id,
                     lane=lane, n_pages=len(adm.adopt_pages),
                 )
                 if wd is not None:
@@ -1294,67 +1372,48 @@ class LaneScheduler:
                     self.state.spans.end(sp)
                 adm.prefill_s += self._clock() - t0
                 adm.adopted = True
-            elif adm.cursor < len(fills):
-                sp = self.state.spans.begin(
-                    "admission_chunk", component="scheduler",
-                    request_id=rid, lane=lane, pos=adm.pos0 + adm.cursor,
-                )
-                if wd is not None:
-                    wd.dispatch_begin("prefill_lane_chunk")
-                t0 = self._clock()
-                try:
-                    width = self._retry_dispatch(
-                        "prefill_lane_chunk",
-                        lambda: self.engine.prefill_lane_chunk(
-                            lane,
-                            fills[adm.cursor:],
-                            adm.pos0 + adm.cursor,
-                            budget=self.admission_chunk,
-                            # lane state: the adopted rows stay as they are
-                            **({"write_floor": adm.pos0 + adm.start_pos}
-                               if adm.cursor < adm.start_pos else {}),
-                        ),
-                    )
-                finally:
-                    if wd is not None:
-                        wd.dispatch_end()
-                    self.state.spans.end(sp)
-                adm.prefill_s += self._clock() - t0
-                adm.cursor += width
-                adm.n_chunks += 1
-                self.state.m_admission_chunks.inc()
-                self.state.recorder.record(
-                    "admission_chunk", lane=lane, chunk=adm.n_chunks,
-                    pos=adm.pos0 + adm.cursor - width, n_tokens=width,
-                    done=adm.cursor >= len(fills),
-                )
-            if adm.cursor >= len(fills) and (
-                adm.adopted or not adopt_needed
-            ):
+            elif adm.cursor < len(adm.tokens) - 1:
+                carried += self._chunk_riders(lane)
+                self._chunk_dispatch(carried)
+        except Exception as e:
+            self._admission_fault(e, epoch0, carried)
+            return
+        for lane in carried:
+            # a lane that a finish's fault failed or rewound is not done
+            adm = self.admitting.get(lane)
+            if adm is None or adm.cursor < len(adm.tokens) - 1 or self._adopt_due(adm):
+                continue
+            try:
                 with self.state.spans.span(
                     "finish_admission", component="scheduler",
-                    request_id=rid, lane=lane,
+                    request_id=adm.job.span.request_id, lane=lane,
                 ):
                     self._finish_admission(lane, adm)
-        except Exception as e:
-            self.state.recorder.record(
-                "admission_error", lane=lane, error=str(e),
-                error_type=type(e).__name__,
-                poisoned=self.engine.cache_epoch != epoch0,
-            )
-            if self.engine.cache_epoch != epoch0:
-                # the failed adopt/chunk ran inside the engine's donated-
-                # buffer guard: the WHOLE cache was rebuilt, so every
-                # other lane's slab KV died with this admission — recover
-                # them all, failing only this lane's request (before
-                # PR 12 this path silently left active lanes decoding
-                # against a zeroed cache)
-                self._recover(e, culprit=lane)
-            else:
-                # cache intact (retries exhausted on a transient fault):
-                # only this admission is affected — error the job and
-                # drop its page retains (the lane's partial KV is
-                # overwritten by the next admission anyway)
+            except Exception as e:
+                self._admission_fault(e, epoch0, [lane])
+
+    def _admission_fault(self, e: Exception, epoch0: int, lanes: list[int]) -> None:
+        """An admission dispatch for `lanes` (a tick's lead first), or one
+        lane's finish, raised."""
+        self.state.recorder.record(
+            "admission_error", lane=lanes[0], error=str(e),
+            error_type=type(e).__name__,
+            poisoned=self.engine.cache_epoch != epoch0,
+        )
+        if self.engine.cache_epoch != epoch0:
+            # the failed adopt/chunk ran inside the engine's donated-
+            # buffer guard: the WHOLE cache was rebuilt, so every
+            # other lane's slab KV died with this admission — recover
+            # them all, failing only the lead's request (before
+            # PR 12 this path silently left active lanes decoding
+            # against a zeroed cache)
+            self._recover(e, culprit=lanes[0])
+        else:
+            # cache intact (retries exhausted on a transient fault):
+            # only this dispatch's admissions are affected — error the
+            # jobs and drop their page retains (a lane's partial KV is
+            # overwritten by the next admission anyway)
+            for lane in lanes:
                 self._fail_admitting(
                     lane, {"message": str(e), "retryable": True}
                 )
@@ -2317,8 +2376,8 @@ class ApiState:
         )
         self.m_admission_chunks = self.obs.counter(
             "dllama_admission_chunks_total",
-            "Bounded prefill chunks dispatched by the chunked admission "
-            "state machine (one per scheduler tick per admitting lane).",
+            "Bounded prefill chunks of admitting lanes (one a lane that a "
+            "scheduler tick's chunk program carried).",
         )
         self.m_decode_blocks = self.obs.counter(
             "dllama_sched_decode_blocks_total",

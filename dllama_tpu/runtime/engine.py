@@ -230,6 +230,16 @@ _KEPT = (
 )
 
 
+def chunk_takes_one_lane(h: LlmHeader) -> bool:
+    """Whether some block of the model's chunk program takes the admitted
+    lane's rows alone (`run_layers` under `one_live_lane`): the expert block,
+    a layer that keeps a state a lane, a latent index's mask. Such a program
+    is wrong for two live lanes; every other computes each live lane's rows
+    at that lane's position, as the verify programs do, and one call can
+    fill several admitting lanes' rows (`InferenceEngine.chunk_lanes`)."""
+    return bool(h.n_experts or h.stateful or h.indexed)
+
+
 class InferenceEngine:
     """See module docstring. `batch_size` > 1 turns the batch axis into
     independent decoding lanes (`generate_batch`) — the data-parallel
@@ -628,14 +638,22 @@ class InferenceEngine:
             "dllama_prefill_chunks_total",
             "Prefill chunk programs dispatched (prefill_lane_chunk), by the "
             "bucket they ran: the smallest rung of the ladder that covers "
-            "the chunk's tokens.",
+            "the widest chunk they carried.",
             labelnames=("bucket",),
+        )
+        self._m_prefill_lanes = self.obs.counter(
+            "dllama_prefill_lanes_total",
+            "Lanes those chunk programs filled, summed over the programs: "
+            "over dllama_prefill_chunks_total, the lanes a program carries "
+            "(1 where the model's chunk program takes one lane's rows).",
         )
         self._m_prefill_rows = self.obs.counter(
             "dllama_prefill_rows_total",
-            "Rows a lane of those chunk programs: real = the tokens a chunk "
-            "was asked for, bucket = the rows its program computed; "
-            "1 - real / bucket is the padded share.",
+            "Rows of those chunk programs: real = the tokens the carried "
+            "lanes' chunks were asked for, bucket = the rows a carried lane "
+            "(1 - real / bucket is the padded share), computed = the rows of "
+            "every lane, parked ones too (real / computed is the share of a "
+            "program's rows that are tokens).",
             labelnames=("kind",),
         )
         self._m_drained = self.obs.counter(
@@ -1010,6 +1028,15 @@ class InferenceEngine:
         seen = [p + i + 1 for p in starts for i in range(n)]
         return {"rows_full": sum(seen), "rows_window": sum(min(s, w) for s in seen)}
 
+    def _chunk_rows_in_context(self, filled) -> dict:
+        """`_rows_in_context` of a chunk program: each `(lane, tokens, pos0)`
+        of `filled` its tokens' rows from its `pos0`, summed."""
+        rows: dict = {}
+        for _, tokens, pos0 in filled:
+            for k, v in self._rows_in_context([pos0], len(tokens)).items():
+                rows[k] = rows.get(k, 0) + v
+        return rows
+
     def _chunk_expert_rows(self, bucket: int) -> dict:
         """`step_dispatch` field of a sparse model's chunk: the token rows
         its expert block computes, the admitted lane's `bucket` where the
@@ -1111,13 +1138,15 @@ class InferenceEngine:
                 f"alone (prefill_lane_chunk, decode_lanes) ({self.header.arch.name})"
             )
 
-    def _one_lane_chunk(self, lane: int, tokens, bucket: int, pos0: int, park: int):
-        """A chunk program's token rows and positions: ``tokens`` in
-        ``lane``'s row at ``pos0``, every other lane zeros at ``park``."""
+    def _lane_chunks(self, chunks, bucket: int, park: int):
+        """A chunk program's token rows and positions: each ``(lane, tokens,
+        pos0)`` of ``chunks`` has its tokens in its lane's row at its
+        ``pos0``, every other lane zeros at ``park``."""
         rows = np.zeros((self.batch_size, bucket), np.int32)
-        rows[lane, : len(tokens)] = tokens
         posv = np.full(self.batch_size, park, np.int32)
-        posv[lane] = pos0
+        for lane, tokens, pos0 in chunks:
+            rows[lane, : len(tokens)] = tokens
+            posv[lane] = pos0
         return rows, posv
 
     def _read_back(self, step: str, out, newest: bool = True) -> np.ndarray:
@@ -1809,6 +1838,15 @@ class InferenceEngine:
                 for ev in pending:
                     ev.wait()
 
+    @property
+    def chunk_lanes(self) -> int:
+        """The lanes one chunk program can fill (`prefill_lanes_chunk`):
+        every lane, or 1 where the program takes one lane's rows somewhere
+        (`chunk_takes_one_lane`) or reads the pool's pages (`--kv-native
+        1`, whose paged program has not been run with two live lanes)."""
+        one = chunk_takes_one_lane(self.header) or self.kv_native
+        return 1 if one else self.batch_size
+
     def prefill_lane_chunk(
         self,
         lane: int,
@@ -1826,7 +1864,8 @@ class InferenceEngine:
         whole prefill. `budget` caps the chunk width (--admission-chunk).
         Chunks reuse the same _lane_prefill_fn bucket programs as the
         monolithic path — no new compiled shapes — and write the same KV
-        rows, so chunked admission is token-exact vs monolithic.
+        rows, so chunked admission is token-exact vs monolithic. The
+        one-element case of `prefill_lanes_chunk`.
 
         A model with lane state (`header.stateful`): the lane's states move
         by the chunk's `width` real rows. A chunk at `pos0` that does not
@@ -1836,25 +1875,71 @@ class InferenceEngine:
         `write_floor` and starts `state_replay_rows` positions before it;
         the cache rows below the floor stay the adopted ones, and at the
         floor every layer's state is what a cold run would hold there."""
+        return self.prefill_lanes_chunk(
+            [(lane, tokens, pos0)], budget=budget, write_floor=write_floor)[0]
+
+    def prefill_lanes_chunk(
+        self,
+        chunks: list[tuple[int, list[int], int]],
+        budget: int | None = None,
+        write_floor: int = 0,
+    ) -> list[int]:
+        """ONE chunk program for the next chunk of every `(lane, tokens,
+        pos0)` of `chunks`, at most `chunk_lanes` of them, the first the
+        lead: the program computes every lane's rows whatever they hold, so
+        each admitting lane's row holds its own tokens at its own position
+        and a parked lane's alone is zeros. Returns the width each consumed,
+        in `chunks`' order.
+
+        The common bucket is the largest that a carried lane's chunk asks
+        of `_bucket_for`; the common window, the largest that one of them
+        reaches in it. A lane whose rows would pass the context's end in
+        that bucket, or whose own bucket an earlier lane's rows would, is
+        left out and consumed 0: its own tick carries it. The program and
+        its key are `_lane_prefill_fn`'s, one lane or several. A lane's
+        cache rows are what a chunk of its own in that bucket and window
+        writes. `write_floor`: of a model with lane state, one lane."""
         self._require_lanes()
-        if not 0 <= lane < self.batch_size:
-            raise ValueError(f"lane {lane} out of range")
-        n = len(tokens)
-        if n < 1:
-            raise ValueError("empty chunk")
-        if pos0 + n > self.header.seq_len:
+        if not 1 <= len(chunks) <= self.chunk_lanes:
             raise ValueError(
-                f"{n} fill tokens at pos {pos0} exceed "
-                f"seqLen {self.header.seq_len}"
+                f"a chunk program of {self.header.arch.name} fills 1 to "
+                f"{self.chunk_lanes} lanes, not {len(chunks)}"
             )
+        lanes = [lane for lane, _, _ in chunks]
+        if len(set(lanes)) < len(lanes):
+            raise ValueError(f"a lane twice in one chunk program: {lanes}")
+        for lane, tokens, pos0 in chunks:
+            if not 0 <= lane < self.batch_size:
+                raise ValueError(f"lane {lane} out of range")
+            n = len(tokens)
+            if n < 1:
+                raise ValueError("empty chunk")
+            if pos0 + n > self.header.seq_len:
+                raise ValueError(
+                    f"{n} fill tokens at pos {pos0} exceed "
+                    f"seqLen {self.header.seq_len}"
+                )
         prep = self._dispatch_prep("prefill_lane_chunk")
         fault = self._fault("prefill_lane_chunk")
         if fault is not None and not fault.poison:
             raise fault
-        want = min(n, budget) if budget and budget > 0 else n
-        bucket = self._bucket_for(want, pos0)
-        width = min(bucket, want)
-        window = self._attn_window(pos0 + bucket)
+        wants = [min(len(tokens), budget) if budget and budget > 0 else len(tokens)
+                 for _, tokens, _ in chunks]
+        bucket, carried = 0, []
+        for i, ((_, _, pos0), want) in enumerate(zip(chunks, wants)):
+            wider = max(bucket, self._bucket_for(want, pos0))
+            # the lead rides whatever `_bucket_for` gave it
+            if not i or all(
+                self._chunk_space(chunks[j][2]) >= wider for j in (*carried, i)
+            ):
+                bucket = wider
+                carried.append(i)
+        widths, filled = [0] * len(chunks), []
+        for i in carried:
+            lane, tokens, pos0 = chunks[i]
+            widths[i] = min(bucket, wants[i])
+            filled.append((lane, tokens[: widths[i]], pos0))
+        window = max(self._attn_window(pos0 + bucket) for _, _, pos0 in filled)
         native = self.kv_native
         step = (
             self._lane_prefill_paged_fn(bucket, window=window)
@@ -1863,27 +1948,28 @@ class InferenceEngine:
         )
         # the paged view parks at `window` (its tail rows); the slab
         # parks at seq_len (its padding rows)
-        rows, posv = self._one_lane_chunk(
-            lane, tokens[:width], bucket, pos0, window if native else self._park
-        )
+        rows, posv = self._lane_chunks(filled, bucket, window if native else self._park)
+        lane, _, pos0 = chunks[0]
         try:
-            state_arg = self._lane_state_arg(lane, pos0, width, write_floor)
+            state_arg = self._lane_state_arg(lane, pos0, widths[0], write_floor)
         except ValueError:
             self._spans.end(prep)  # a refusal is no dispatch: nothing stays open
             raise
         self._m_prefill_chunks.labels(bucket=str(bucket)).inc()
-        self._m_prefill_rows.labels(kind="real").inc(width)
-        self._m_prefill_rows.labels(kind="bucket").inc(bucket)
+        self._m_prefill_lanes.inc(len(filled))
+        self._m_prefill_rows.labels(kind="real").inc(sum(widths))
+        self._m_prefill_rows.labels(kind="bucket").inc(bucket * len(filled))
+        self._m_prefill_rows.labels(kind="computed").inc(bucket * self.batch_size)
         arr, *rest = self._host_args(
             *self._page_table_arg(), posv, *state_arg, tokens=rows
         )
         with self._dispatch(
             "prefill_lane_chunk", prep, host_args=1 + len(rest),
-            lane=lane, pos=pos0,
-            n_tokens=width, bucket=bucket, window=window,
-            **self._rows_in_context([pos0], width),
+            lane=lane, pos=pos0, lanes=[c[0] for c in filled],
+            n_tokens=sum(widths), bucket=bucket, window=window,
+            **self._chunk_rows_in_context(filled),
             **self._chunk_expert_rows(bucket),
-            **self._chunk_state_fields(pos0, width, write_floor),
+            **self._chunk_state_fields(pos0, widths[0], write_floor),
         ):
             if native:
                 with self._kv_pool_guard():
@@ -1903,7 +1989,7 @@ class InferenceEngine:
                             (self._enqueued, forms)]
                     else:
                         self.cache = out
-        return width
+        return widths
 
     def prefill_lane(
         self, lane: int, tokens: list[int], pos0: int = 0, write_floor: int = 0
@@ -3405,7 +3491,7 @@ class InferenceEngine:
             bucket = self._draft_bucket_for(len(fills), p)
             width = min(bucket, len(fills))
             step = self._draft_prefill_fn(bucket)
-            rows, posv = self._one_lane_chunk(lane, fills[:width], bucket, p, park)
+            rows, posv = self._lane_chunks([(lane, fills[:width], p)], bucket, park)
             arr, posv = self._host_args(posv, tokens=rows)
             with self._draft_cache_guard():
                 self.draft_cache = step(
@@ -3471,10 +3557,10 @@ class InferenceEngine:
             for lane in range(self.batch_size)
         ]
 
-    def _bucket_for(self, n: int, pos: int) -> int:
-        """Smallest bucket covering n tokens whose PADDED extent still fits
-        in the cache (dynamic_update_slice clamps silently if pos+bucket >
-        seqLen, which would corrupt earlier cache rows)."""
+    def _chunk_space(self, pos: int) -> int:
+        """The rows a chunk at `pos` may be padded to (dynamic_update_slice
+        clamps silently if pos+bucket > seqLen, which would corrupt earlier
+        cache rows)."""
         space = self.header.seq_len - pos
         if self.pp > 1 and self.sp > 1:
             # stage-local sp writes are windowed per shard (run_layers
@@ -3482,6 +3568,12 @@ class InferenceEngine:
             # bucket filter enforces this for configured buckets; cap the
             # fallback widths below the same way.
             space = min(space, self.header.seq_len // self.sp)
+        return space
+
+    def _bucket_for(self, n: int, pos: int) -> int:
+        """Smallest bucket covering n tokens whose PADDED extent still fits
+        in the cache (`_chunk_space`)."""
+        space = self._chunk_space(pos)
         fitting = [b for b in self.prefill_buckets if b <= space]
         if not fitting:
             # guarded by the prefill bounds check: space >= 1 and bucket 1

@@ -19,6 +19,7 @@ import threading
 import time
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from dllama_tpu.runtime.api_server import (
@@ -177,6 +178,73 @@ def test_chunked_prefill_token_parity(tiny_paths):
     assert b2 == a2  # prefix-reuse resume (pending token) parity
 
 
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["f32", "int8"])
+def test_lanes_filled_by_one_program_equal_lanes_filled_one_a_tick(tiny_paths, kv_dtype):
+    """Three lanes admitted together, each tick's chunk program carrying the
+    next chunk of every one of them (`prefill_lanes_chunk`), at different
+    positions, lengths and last-chunk sizes: the cache rows of each lane's
+    prompt and its first 16 greedy tokens are those of the same three
+    admitted one lane a tick, while a fourth lane stands parked."""
+    mp, _ = tiny_paths
+    e = InferenceEngine(
+        mp, tp=1, dtype=jnp.float32, kv_dtype=kv_dtype, temperature=0.0,
+        batch_size=4, prefill_buckets=(1, 8, 32),
+    )
+    budget = 32
+    # lane -> (a context it already holds, the fill tokens it admits behind it)
+    held = {0: 0, 1: 21, 3: 130}
+    fills = {0: 71, 1: 32, 3: 9}  # last chunks of 7, 32 and 9 rows
+    toks = {lane: [2 + (lane * 31 + i * 7) % 250 for i in range(held[lane] + fills[lane] + 1)]
+            for lane in held}
+
+    def admit(together: bool):
+        e.reset()
+        for lane, n in held.items():
+            if n:
+                e.prefill_lane(lane, toks[lane][: n + 1])
+        cur = dict(held)
+        end = {lane: held[lane] + fills[lane] for lane in held}
+        programs, lanes0 = 0, e._m_prefill_lanes.value
+        while any(cur[lane] < end[lane] for lane in cur):
+            left = [lane for lane in cur if cur[lane] < end[lane]]
+            for group in ([left] if together else [[lane] for lane in left]):
+                chunks = [(lane, toks[lane][cur[lane]:end[lane]], cur[lane]) for lane in group]
+                if together:
+                    widths = e.prefill_lanes_chunk(chunks, budget=budget)
+                else:
+                    widths = [e.prefill_lane_chunk(*chunks[0], budget=budget)]
+                programs += 1
+                for lane, width in zip(group, widths):
+                    assert 0 < width <= budget
+                    cur[lane] += width
+        rows = {}
+        for lane in held:
+            for name, leaf in e.cache.items():
+                leaf = leaf.q.astype(jnp.float32) * leaf.s if kv_dtype else leaf
+                rows[lane, name] = np.asarray(leaf[:, lane, :, : end[lane]])
+        t = [toks[lane][-1] if lane in held else 0 for lane in range(4)]
+        pos = [end[lane] if lane in held else 0 for lane in range(4)]
+        live = [lane in held for lane in range(4)]
+        out = []
+        for _ in range(4):
+            block = e.decode_lanes(t, pos, 4, live, [0.0] * 4, [0.9] * 4)
+            out += block
+            t, pos = list(block[-1]), [p + 4 for p in pos]
+        return (rows, [[row[lane] for row in out] for lane in held], programs,
+                e._m_prefill_lanes.value - lanes0)
+
+    rows_one, tokens_one, programs_one, lanes_one = admit(together=False)
+    rows_all, tokens_all, programs_all, lanes_all = admit(together=True)
+    assert programs_one == 3 + 1 + 1 and programs_all == 3
+    assert tokens_all == tokens_one and all(len(t) == 16 for t in tokens_all)
+    for key, want in rows_one.items():
+        # (an int8 row: within a step of its scale, where a sum rounded apart)
+        tol = 2e-2 * np.abs(want).max() if kv_dtype else 1e-5
+        assert np.abs(rows_all[key] - want).max() <= tol, key
+    # lanes a program: 5 over 5, then 5 over 3
+    assert (lanes_one, lanes_all) == (5, 5)
+
+
 # -- bugfix regression: decode between chunks, round-robin fairness -----------
 
 
@@ -185,7 +253,9 @@ def test_decode_runs_between_admission_chunks(sched_state):
     prefills before any decode ran. Under the chunked state machine, a
     decode block must run between any two admission chunks while an
     active lane exists — and two concurrent admissions must round-robin
-    (strictly alternating chunks while both have fills left)."""
+    (strictly alternating leads while both have fills left). A tick
+    dispatches ONE chunk program however many lanes admit: a dense model's
+    carries both admitting lanes' chunks, each counted as its lane's."""
     state = sched_state
     sched, rec = state.scheduler, state.recorder
 
@@ -217,6 +287,7 @@ def test_decode_runs_between_admission_chunks(sched_state):
     # legitimately hit its length limit before the admissions finish, at
     # which point back-to-back chunks are fine — nobody is stalled).
     ops, active = [], {0}  # lane A was admitted before `base`
+    carried, lane_chunks = [], []  # a program's lanes; every lane's chunks
     for ev in rec.events():
         if ev["seq"] <= base:
             continue
@@ -227,8 +298,11 @@ def test_decode_runs_between_admission_chunks(sched_state):
         elif ev["kind"] == "step_dispatch":
             if ev.get("step") == "prefill_lane_chunk":
                 ops.append(("chunk", ev["lane"], len(active)))
+                carried.append(ev["lanes"])
             elif ev.get("step") == "decode_lanes":
                 ops.append(("decode", None, len(active)))
+        elif ev["kind"] == "admission_chunk":
+            lane_chunks.append(ev["lane"])
     chunk_idx = [i for i, op in enumerate(ops) if op[0] == "chunk"]
     live_pairs = 0
     # the regression assert: never two admission chunks back-to-back
@@ -246,7 +320,213 @@ def test_decode_runs_between_admission_chunks(sched_state):
     for i in range(len(lanes_seq) - 1):
         if len(set(lanes_seq[i + 1:])) > 1:
             assert lanes_seq[i + 1] != lanes_seq[i], lanes_seq
-    assert state.m_admission_chunks.value - b_chunks == len(lanes_seq)
+    # one program a tick carried both admissions' chunks, the lead first
+    assert all(lanes[0] == lead for lanes, lead in zip(carried, lanes_seq))
+    assert [len(lanes) for lanes in carried].count(2) >= 4, carried
+    assert max(len(lanes) for lanes in carried) == 2
+    assert sorted(lane_chunks) == sorted(lane for lanes in carried for lane in lanes)
+    assert state.m_admission_chunks.value - b_chunks == len(lane_chunks) > len(lanes_seq)
+
+
+# -- a tick's chunk program carries every admitting lane that can ride in it --
+#
+# Driven by hand: the scheduler's thread is stopped and the test calls
+# `_begin_admission` and `_admission_tick` itself, with raw token ids for
+# prompts, so that every tick's dispatch is known.
+
+
+def _driven(mp, tp_, **engine_kw):
+    tok = Tokenizer(tp_)
+    engine = InferenceEngine(
+        mp, tokenizer=tok, tp=1, dtype=jnp.float32, temperature=0.0, seed=3,
+        batch_size=4, **engine_kw,
+    )
+    state = ApiState(engine, tok, lane_block_size=4, admission_chunk=100)
+    state.scheduler.stop()
+    state.scheduler._sleep = lambda s: None
+    return state
+
+
+@pytest.fixture(scope="module")
+def driven_state(tiny_paths):
+    return _driven(*tiny_paths)
+
+
+@pytest.fixture
+def driven(driven_state):
+    from dllama_tpu.runtime.faults import set_fault_plane
+
+    sched = driven_state.scheduler
+    assert not sched.admitting and not any(sched.lanes)
+    kv = sched.kv
+    yield driven_state
+    set_fault_plane("")
+    sched.kv = kv
+    sched._drop_all(RuntimeError("the test is over"))
+    sched._rr = -1
+
+
+def _begin(state, lane, n_tokens):
+    """`lane` begins the admission of `n_tokens` raw ids (no template)."""
+    ids = [2 + (lane * 41 + i * 7) % 250 for i in range(n_tokens)]
+    job = LaneJob(InferenceParams(resume_tokens=ids, max_tokens=4, temperature=0.0))
+    job.span = state.tracer.span(path="lanes")
+    state.scheduler._begin_admission(lane, job)
+    return state.scheduler.admitting[lane]
+
+
+def _chunk_programs(state, base):
+    return [e for e in state.recorder.events() if e["seq"] > base
+            and e["kind"] == "step_dispatch" and e["step"] == "prefill_lane_chunk"]
+
+
+def _ended(adm):
+    kinds = []
+    while not adm.job.events.empty():
+        kinds.append(adm.job.events.get()[0])
+    return kinds
+
+
+def test_a_lane_that_would_pass_the_contexts_end_takes_a_tick_of_its_own(driven):
+    """Lane 0 stands at position 300 of 384 and asks for a rung of 8; lane
+    1 starts and asks for one of 128, which lane 0's rows would not fit:
+    whoever leads, the other is left out, its cursor where it was, and is
+    carried by the tick that it leads."""
+    state, sched, eng = driven, driven.scheduler, driven.engine
+    assert eng.chunk_lanes == 4 and eng.header.seq_len == 384
+    a = _begin(state, 0, 330)
+    for _ in range(3):
+        sched._admission_tick()
+    assert a.cursor == 300 and eng._bucket_for(29, 300) == 8
+    b = _begin(state, 1, 151)
+    base = state.recorder.total_recorded
+    sched._admission_tick()  # lane 1 leads
+    assert (a.cursor, b.cursor) == (300, 100)
+    sched._admission_tick()  # lane 0 leads
+    assert (a.cursor, b.cursor) == (308, 100)
+    while sched.admitting:
+        sched._admission_tick()
+    got = [(e["lanes"], e["pos"], e["n_tokens"], e["bucket"]) for e in _chunk_programs(state, base)]
+    assert got == [([1], 0, 100, 128), ([0], 300, 8, 8), ([1], 100, 50, 128), ([0], 308, 8, 8),
+                   ([0], 316, 8, 8), ([0], 324, 5, 8)]
+    assert (a.n_chunks, b.n_chunks) == (7, 2)
+    assert sched.lanes[0] is not None and sched.lanes[1] is not None
+
+
+@pytest.mark.parametrize("case", ["cancelled", "adopt", "fault", "poison"])
+def test_who_rides_in_a_shared_chunk_program_and_what_a_fault_of_it_costs(driven, case):
+    """Three lanes admit. `cancelled`: a lane whose client went away rides
+    in no program, and the tick it leads aborts it. `adopt`: a lane whose
+    next action is its adopt rides in none either; its adopt is a tick of
+    its own, and then it rides. `fault`: a shared dispatch that fails on an
+    intact cache, retries spent, fails every lane it carried, cursors
+    unmoved, and no other. `poison`: one that took the cache with it fails
+    its lead alone; the riders start over."""
+    from dllama_tpu.runtime.faults import set_fault_plane
+
+    state, sched = driven, driven.scheduler
+    adms = [_begin(state, lane, n) for lane, n in ((0, 250), (1, 180), (2, 120))]
+    sched._admission_tick()  # lane 0 leads, all three ride
+    assert [adm.cursor for adm in adms] == [100, 100, 100]
+    base = state.recorder.total_recorded
+    retries0 = state.m_dispatch_retries.value
+    if case == "cancelled":
+        adms[2].job.cancelled = True
+        sched._admission_tick()  # lane 1 leads
+        assert [adm.cursor for adm in adms] == [200, 179, 100] and 1 not in sched.admitting
+        sched._admission_tick()  # lane 2 leads: aborted, and no program
+        assert 2 not in sched.admitting and _ended(adms[2]) == ["done"]
+        sched._admission_tick()
+        assert [e["lanes"] for e in _chunk_programs(state, base)] == [[1, 0], [0]]
+    elif case == "adopt":
+        adopted = []
+        sched.kv = type("Pool", (), {
+            "native": False, "adopt": lambda self, lane, pages: adopted.append((lane, pages)),
+            "release_lane": lambda self, lane: None, "release_all_lanes": lambda self: None})()
+        adms[2].adopt_pages = [5, 6]
+        sched._admission_tick()  # lane 1 leads, lane 0 rides
+        assert [adm.cursor for adm in adms] == [200, 179, 100] and not adopted
+        sched._admission_tick()  # lane 2 leads: its adopt, and no program
+        assert adopted == [(2, [5, 6])] and adms[2].adopted and adms[2].cursor == 100
+        sched._admission_tick()  # lane 0 leads, lane 2 rides
+        assert [e["lanes"] for e in _chunk_programs(state, base)] == [[1, 0], [0, 2]]
+        assert 0 not in sched.admitting and adms[2].cursor == 119
+    elif case == "fault":
+        adms[2].job.cancelled = True
+        set_fault_plane("dispatch:op=prefill_lane_chunk:every=1")
+        sched._admission_tick()  # lane 1 leads, lane 0 rides: both fail
+        assert state.m_dispatch_retries.value - retries0 == sched.retry_max
+        assert [adm.cursor for adm in adms] == [100, 100, 100]
+        assert list(sched.admitting) == [2] and not _chunk_programs(state, base)
+        assert _ended(adms[0]) == _ended(adms[1]) == ["error"] and not _ended(adms[2])
+    else:
+        epoch = state.engine.cache_epoch
+        set_fault_plane("dispatch:op=prefill_lane_chunk:nth=1:kind=poison")
+        sched._admission_tick()  # lane 1 leads; lanes 0 and 2 ride and start over
+        assert state.engine.cache_epoch == epoch + 1
+        assert _ended(adms[1]) == ["error"] and sorted(sched.admitting) == [0, 2]
+        assert [adm.cursor for adm in adms] == [0, 100, 0]
+        while sched.admitting:
+            sched._admission_tick()
+        assert [e["lanes"] for e in _chunk_programs(state, base)[1:]] == [[2, 0], [0, 2], [0]]
+        assert sched.lanes[0] is not None and sched.lanes[2] is not None
+
+
+def _one_lane_a_tick(eng, rr, script, budget):
+    """The chunk programs of a scheduler that gives ONE admitting lane a
+    chunk a tick, round-robin: `script[tick]` = the (lane, fill tokens) that
+    begin before that tick. (lane, pos, n_tokens, bucket, window) each."""
+    left, cur, out, tick = {}, {}, [], 0
+    while left or tick < len(script):
+        for lane, n in script[tick] if tick < len(script) else ():
+            left[lane], cur[lane] = n, 0
+        tick += 1
+        order = sorted(left)
+        rr = lane = min((i for i in order if i > rr), default=order[0])
+        width = min(left[lane], budget)
+        bucket = eng._bucket_for(width, cur[lane])
+        width = min(width, bucket)
+        out.append((lane, cur[lane], width, bucket, eng._attn_window(cur[lane] + bucket)))
+        cur[lane] += width
+        left[lane] -= width
+        if not left[lane]:
+            del left[lane]
+    return out
+
+
+@pytest.mark.parametrize("family", ["afmoe", "lfm2_moe"])
+def test_a_chunk_program_that_takes_one_lanes_rows_is_dispatched_as_before(
+        tmp_path_factory, family):
+    """A model with experts, and one with lane state besides: `chunk_lanes`
+    is 1, and three overlapping admissions dispatch what one lane a tick,
+    round-robin, dispatches: the same programs with the same arguments."""
+    import helpers
+    from dllama_tpu.models.synthetic import write_synth_tokenizer
+
+    d = tmp_path_factory.mktemp(family)
+    mp, tp_ = str(d / "m.m"), str(d / "t.t")
+    {"afmoe": helpers.make_tiny_afmoe, "lfm2_moe": helpers.make_tiny_lfm2}[family](mp)
+    write_synth_tokenizer(tp_, 512)
+    state = _driven(mp, tp_, prefill_buckets=(1, 8, 16), max_seq_len=256)
+    sched, eng = state.scheduler, state.engine
+    sched.admission_chunk = 16
+    assert eng.chunk_lanes == 1 and eng.header.n_experts
+    assert eng.header.stateful == (family == "lfm2_moe")
+    script = [[(0, 70), (1, 41)], [], [(2, 30)]]
+    base, lanes0 = state.recorder.total_recorded, eng._m_prefill_lanes.value
+    want = _one_lane_a_tick(eng, sched._rr, script, 16)
+    tick = 0
+    while sched.admitting or tick < len(script):
+        for lane, n in script[tick] if tick < len(script) else ():
+            _begin(state, lane, n + 1)
+        tick += 1
+        sched._admission_tick()
+    got = _chunk_programs(state, base)
+    assert [(e["lane"], e["pos"], e["n_tokens"], e["bucket"], e["window"]) for e in got] == want
+    assert all(e["lanes"] == [e["lane"]] for e in got) and len(got) == 5 + 3 + 2
+    assert eng._m_prefill_lanes.value - lanes0 == len(got)
+    assert all(ls is not None for ls in sched.lanes[:3])
+    sched._drop_all(RuntimeError("the test is over"))
 
 
 # -- stall model: chunk events + bounded decode gaps (fake clock) -------------
@@ -585,9 +865,16 @@ def test_a_stream_that_ends_on_its_tokens_runs_one_block_for_nobody(ahead_state,
         # streamed is a prefix of the stream, and nothing of it is published
         assert reason == "cancelled" and ids == full[:len(ids)] and len(ids) < 24
         assert pub_got == pub_want and len(pub_got) == 4
-    # the block in flight when the stream ended ran its lane live
+    # the block in flight when the stream ended ran its lane live, beside
+    # the streams admitted by then (which of the three an adopt tick led
+    # first is the round-robin cursor's, left by the runs before)
     last = [e for e in blocks if e["seq"] < finish["seq"]][-1]
-    assert last["ahead"] == 1 and last["n_live"] == 3
+    live = set()
+    for e in ev_got:
+        if e["seq"] < last["seq"] and e["kind"] in ("admit", "finish"):
+            (live.add if e["kind"] == "admit" else live.discard)(e["lane"])
+    assert finish["lane"] in live and len(live) >= 2
+    assert last["ahead"] == 1 and last["n_live"] == len(live)
     assert sched._flight is None and not any(sched.lanes)
     sched.kv.check()
 

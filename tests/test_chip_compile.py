@@ -1113,6 +1113,30 @@ def test_lane_block_keeps_the_sampler_conditional(one_chip):
     assert not sorts & outside, sorts & outside
 
 
+def test_rehearsal_schedules_the_dense_cells_programs_and_no_other():
+    """`mistral-7b-v0.3` as its cells serve it (five lanes of 4096 positions,
+    the ladder of `--nbatches 32`, blocks of 8 steps): `rehearse_admission`
+    schedules one chunk program a rung at the rung's base window and the
+    decode block, under the keys it always had. A chunk program that fills
+    several admitting lanes' rows is one of these, or the same rung at a
+    deeper window, built as one lane's is when a lane gets there."""
+    import types
+
+    from dllama_tpu.runtime.engine import InferenceEngine, prefill_ladder
+
+    scheduled = []
+    stand_in = types.SimpleNamespace(
+        _require_lanes=lambda: None, _aot_blocks=True, kv_native=False, kv_pool=None,
+        _draft_params=None, prefill_buckets=prefill_ladder(32), sp=1, _latent=False,
+        header=types.SimpleNamespace(seq_len=4096, sliding_window=0),
+        _prefetch=lambda key, build: scheduled.append(key),
+    )
+    stand_in._attn_window = lambda limit: InferenceEngine._attn_window(stand_in, limit)
+    InferenceEngine.rehearse_admission(stand_in, 8)
+    assert scheduled == [("lane_prefill", rung, 512) for rung in (1, 32, 128, 256, 512)] + [
+        ("lane_block", 8, 512)]
+
+
 def test_cache_copies_flags_the_caches_as_scan_xs(one_chip):
     """The design before PR 29: the layer scan takes the caches as `xs` and
     gives them back as `ys`. Each step then slices the layer's whole cache

@@ -1636,15 +1636,17 @@ def test_rehearse_admission_builds_every_rung(ladder_engine):
 
 
 def test_prefill_counters_say_chunks_by_rung_and_rows_real_and_computed(ladder_engine):
-    """`dllama_prefill_chunks_total{bucket}` and
-    `dllama_prefill_rows_total{kind}`: what `prefill_lane_chunk` puts into
-    its `step_dispatch` event as `bucket` and `n_tokens`, summed."""
+    """`dllama_prefill_chunks_total{bucket}`, `dllama_prefill_lanes_total`
+    and `dllama_prefill_rows_total{kind}`: what a chunk program puts into
+    its `step_dispatch` event as `bucket`, `lanes` and `n_tokens`, summed;
+    `computed` is every lane's rows, the parked one's too."""
     e = ladder_engine
 
     def counted():  # the registry outlives an engine: differences
         return (
             {b: e._m_prefill_chunks.labels(bucket=str(b)).value for b in e.prefill_buckets},
-            [e._m_prefill_rows.labels(kind=k).value for k in ("real", "bucket")],
+            [e._m_prefill_rows.labels(kind=k).value for k in ("real", "bucket", "computed")]
+            + [e._m_prefill_lanes.value],
         )
 
     chunks0, rows0 = counted()
@@ -1657,11 +1659,57 @@ def test_prefill_counters_say_chunks_by_rung_and_rows_real_and_computed(ladder_e
     assert e.prefill_lane_chunk(0, list(range(1, 300)), 0, budget=100) == 100
     chunks, rows = counted()
     assert {b: chunks[b] - chunks0[b] for b in chunks} == {1: 1, 32: 2, 128: 3, 256: 1, 512: 1}
-    assert [a - b for a, b in zip(rows, rows0)] == [
-        sum(widths) + 100, 1 + 2 * 32 + 3 * 128 + 256 + 512]
+    rungs = 1 + 2 * 32 + 3 * 128 + 256 + 512
+    assert [a - b for a, b in zip(rows, rows0)] == [sum(widths) + 100, rungs, 2 * rungs, 8]
+    # one program that fills both lanes: a rung a lane carried, and no row more computed
+    assert e.prefill_lanes_chunk([(0, list(range(1, 41)), 100), (1, [7] * 90, 0)]) == [40, 90]
+    assert [a - b for a, b in zip(counted()[1], rows)] == [130, 256, 256, 2]
+    assert counted()[0][128] - chunks[128] == 1
     text = e.obs.render()
     assert 'dllama_prefill_chunks_total{bucket="256"}' in text
     assert 'dllama_prefill_rows_total{kind="real"}' in text
+    assert 'dllama_prefill_rows_total{kind="computed"}' in text
+    assert "dllama_prefill_lanes_total" in text
+
+
+@pytest.mark.parametrize("chunks,budget,widths,bucket,window", [
+    # the common rung is the widest that a carried lane asks, the window the deepest
+    ([(0, 300, 0), (1, 40, 500)], None, [300, 40], 512, 1024),
+    ([(0, 300, 0), (1, 40, 500)], 100, [100, 40], 128, 1024),
+    ([(1, 40, 0), (0, 300, 100)], 100, [40, 100], 128, 512),
+    # the lead rides in a rider's wider rung
+    ([(0, 20, 0), (1, 200, 32)], None, [20, 200], 256, 512),
+    # a rider whose rows would pass the context's end in the common rung is left out
+    ([(0, 300, 0), (1, 40, 600)], None, [300, 0], 512, 512),
+    # and so is a rider whose own rung the lead's rows would not fit
+    ([(1, 40, 600), (0, 300, 0)], None, [40, 0], 128, 1024),
+])
+def test_one_chunk_program_for_the_lanes_that_fit_its_common_rung(
+        ladder_engine, chunks, budget, widths, bucket, window):
+    e = ladder_engine
+    assert e.chunk_lanes == 2 and e.header.seq_len == 1024
+    built = set(e._compiled)
+    base = e.recorder.total_recorded
+    got = e.prefill_lanes_chunk(
+        [(lane, list(range(1, n + 1)), pos) for lane, n, pos in chunks], budget=budget)
+    assert got == widths
+    ev, = [ev for ev in e.recorder.events() if ev["seq"] > base and ev["kind"] == "step_dispatch"]
+    carried = [c for c, w in zip(chunks, widths) if w]
+    assert (ev["step"], ev["lane"], ev["pos"]) == ("prefill_lane_chunk", chunks[0][0], chunks[0][2])
+    assert (ev["lanes"], ev["n_tokens"]) == ([c[0] for c in carried], sum(widths))
+    assert (ev["bucket"], ev["window"]) == (bucket, window)
+    # no program that one lane a tick would not build
+    assert set(e._compiled) - built <= {("lane_prefill", bucket, window)}
+
+
+def test_a_chunk_program_fills_no_more_lanes_than_it_computes_and_each_once(ladder_engine):
+    e = ladder_engine
+    with pytest.raises(ValueError, match="fills 1 to 2 lanes, not 3"):
+        e.prefill_lanes_chunk([(0, [1], 0), (1, [1], 0), (0, [1], 8)])
+    with pytest.raises(ValueError, match="a lane twice"):
+        e.prefill_lanes_chunk([(1, [1], 0), (1, [1], 8)])
+    with pytest.raises(ValueError, match="fills 1 to 2 lanes, not 0"):
+        e.prefill_lanes_chunk([])
 
 
 @pytest.mark.parametrize("n_prompt", list(MIDDLE_RUNG_PROMPTS))

@@ -233,8 +233,10 @@ SOAK_SCHEDULES = [
     # one decode poison: a batched step has no culprit, every lane
     # recovers and every stream stays byte-identical
     ("dispatch:op=decode_lanes:nth=2:kind=poison", 0),
-    # admission poison: exactly the culprit lane fails, survivors resume
-    ("dispatch:op=prefill_lane_chunk:nth=2:kind=poison", 1),
+    # admission poison: exactly the culprit lane fails (the lead of the
+    # chunk program, whose riders start over), survivors resume. The first
+    # program: a round's admissions may all ride in one
+    ("dispatch:op=prefill_lane_chunk:nth=1:kind=poison", 1),
     # unfiltered poison sprinkle: outcome depends on which dispatch it
     # lands on — hold only the either-or invariant
     ("dispatch:p=0.08:seed=3:kind=poison:n=2", None),
