@@ -3832,6 +3832,8 @@ def serve(
         inner_close()
         if state.scheduler is not None:
             state.scheduler.stop()
+        # the last completion stamps reach the timeline before its sink closes
+        state.engine.close()
         if state.watchdog is not None:
             state.watchdog.stop()
         # join the sampler so a closed server (and test churn) never

@@ -12,9 +12,11 @@ temperature/top-p path uses the reference-parity host sampler.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import time
+import weakref
 from functools import partial
 
 import jax
@@ -31,6 +33,7 @@ from ..models.transformer import lanes_on_one_device
 from ..ops.quant_matmul import PACKED_GROUP
 from ..parallel import cache_specs, make_mesh, shard_params_put, validate_tp
 from ..tokenizer import Tokenizer
+from .done import DoneWatcher, Program
 from .faults import get_fault_plane
 from .sampler import Sampler
 
@@ -56,6 +59,14 @@ def prefill_ladder(smallest: int, largest: int = 512) -> tuple[int, ...]:
 DEFAULT_PREFILL_BUCKETS = prefill_ladder(8)
 # the smallest attention window of a cache of latent rows (`_attn_window`)
 LATENT_MIN_WINDOW = 4096
+
+
+def _chunk_mark(cache) -> jax.Array:
+    """One int32 of a chunk program's output cache, whatever it reads: the
+    small output beside the donated cache that says when the program has
+    left the device (`InferenceEngine._launched`)."""
+    leaf = jax.tree.leaves(cache)[0]
+    return leaf[(0,) * leaf.ndim].astype(jnp.int32)  # a slice: no reshape of a sharded stack
 
 
 def _sds(x):
@@ -195,7 +206,7 @@ class LaneBlock:
     n_steps: int  # after the clamp at the context's end
     live: frozenset  # the lanes the program ran live
     native: bool
-    enqueued: int  # the engine's count of dispatches at its own
+    program: Program  # its completion stamp in the making (`_launched`)
     t0: float  # its dispatch's begin, on the spans' clock
     t1: float  # its call's end: the program is enqueued
     fields: dict  # what `step_complete` repeats of `step_dispatch`
@@ -659,18 +670,39 @@ class InferenceEngine:
         self._m_drained = self.obs.counter(
             "dllama_engine_device_drained_seconds_total",
             "Seconds the device stood drained and waited for the host: from "
-            "the end of a read-back to the begin of the next dispatch that "
-            "is no pool copy, by the step dispatched.",
+            "the moment a program left the device to the begin of the next "
+            "dispatch that is no pool copy, where that came later, by the "
+            "step dispatched.",
             labelnames=("before",),
         )
-        # the clock reading since which the device has had nothing to do;
-        # None while something enqueued has not been read back
-        self._drained_at = None
-        # programs enqueued so far that are no pool copy (counted where the
-        # call returned: one that raised enqueued nothing): a block's handle
-        # keeps the count at its own, so its collect can tell whether
-        # anything was enqueued behind it
+        self._m_busy = self.obs.counter(
+            "dllama_engine_device_busy_seconds_total",
+            "Seconds the lane path's programs ran on the device, from their "
+            "completion stamps, by step: its rate is the device's duty cycle.",
+            labelnames=("step",),
+        )
+        self._m_dispatches = self.obs.counter(
+            "dllama_engine_dispatches_total",
+            "Dispatches that are no pool copy, by step and by whether the "
+            "program enqueued before had left the device at the dispatch's "
+            "begin (dry) or not (busy).",
+            labelnames=("step", "device"),
+        )
+        # programs enqueued so far that a completion stamp is kept for (the
+        # lane path's, counted where the call returned: one that raised
+        # enqueued nothing); a chunk's forms and a block's handle keep the
+        # count at their own
         self._enqueued = 0
+        # the completion stamps (`runtime/done.py`): the programs enqueued
+        # and not yet accounted for, in dispatch order (`_settle`), the
+        # newest of all, and when the one before the first of them left
+        # the device. The watcher reads the clock the engine reads (this
+        # module's `time`, looked up at each reading)
+        self._watcher = DoneWatcher(lambda: time.monotonic())
+        weakref.finalize(self, self._watcher.close, 0)
+        self._unsettled: collections.deque[Program] = collections.deque(maxlen=256)
+        self._newest: Program | None = None
+        self._last_done: float | None = None
         # the newest lane block while it is dispatched and not collected,
         # and the end of the newest collect (`collect_lanes`' `ms` starts
         # no earlier)
@@ -922,42 +954,114 @@ class InferenceEngine:
 
     def _begin_dispatch(
         self, step: str, prep=None, head=None, host_args=0, **fields
-    ) -> float:
+    ) -> dict:
         """The begin of one dispatch of a compiled program: ends ``prep``,
         the caller's open ``dispatch_prep`` span, reads the clock once
-        (returned: the dispatch's ``t0``) and records ``step_dispatch``.
+        (the dispatch's ``t0``) and records ``step_dispatch``. Returns what
+        ``_launched`` wants of it: ``step``, ``t0`` and ``dry``.
 
         ``step_dispatch`` says what the host did before the call:
         ``prep_ms`` from ``prep``'s begin (a method with no such span:
         ``head``, its first clock reading) to ``t0``, and ``host_args``,
-        the host arrays ``_host_args`` handed over for the call.
-
-        The drained interval ends here too. Where ``_read_back`` left its
-        mark and nothing was enqueued since, the device has stood idle
-        from the mark to ``t0`` and the host is why: that is the span
-        ``device_drained`` (``before`` = this step), the counter
-        ``dllama_engine_device_drained_seconds_total{before}`` and
-        ``drained_ms`` on ``step_dispatch``. The first enqueue clears the
-        mark, so a dispatch behind an un-read one (a block behind a
-        chunk or behind a block) records nothing; a pool copy neither
-        ends the interval nor clears the mark, nor counts as an enqueue
-        to the read-back that asks whether its program is the newest."""
+        the host arrays ``_host_args`` handed over for the call; and, of a
+        step that is no pool copy, ``dry``: whether the newest program
+        enqueued had left the device at ``t0`` (asked of its handle
+        without a wait, so exact where it says 0), counted as
+        ``dllama_engine_dispatches_total{step, device}``. The programs
+        stamped since the last dispatch are accounted for here
+        (``_settle``)."""
         t0 = time.monotonic()
         self._spans.end(prep, at=t0)
+        begun = {"step": step, "t0": t0}
         host = {"host_args": host_args}
-        begun = prep.t0 if prep is not None else head
-        if begun is not None:
-            host["prep_ms"] = round((t0 - begun) * 1000, 3)
-        if self._drained_at is not None and step not in self._POOL_COPIES:
-            since, self._drained_at = self._drained_at, None
-            self._spans.end(self._spans.begin(
-                "device_drained", component="engine", annotate=False,
-                at=since, before=step,
-            ), at=t0)
-            self._m_drained.labels(before=step).inc(t0 - since)
-            host["drained_ms"] = round((t0 - since) * 1000, 3)
+        since = prep.t0 if prep is not None else head
+        if since is not None:
+            host["prep_ms"] = round((t0 - since) * 1000, 3)
+        if step not in self._POOL_COPIES:
+            dry = self._newest is None or self._newest.left_the_device()
+            begun["dry"] = dry
+            host["dry"] = int(dry)
+            self._m_dispatches.labels(
+                step=step, device="dry" if dry else "busy").inc()
+        self._settle()
         self.recorder.record("step_dispatch", step=step, **fields, **host)
-        return t0
+        return begun
+
+    def _launched(self, begun: dict, handle) -> Program:
+        """A program of the lane path has been enqueued: `begun` is its
+        ``_begin_dispatch``'s, `handle` a small output of it that no later
+        program is given to consume. The watcher stamps when it leaves the
+        device, and a later dispatch accounts for it (`_settle`)."""
+        self._enqueued += 1
+        program = Program(
+            self._enqueued, begun["step"], begun["t0"], time.monotonic(),
+            begun["dry"], handle,
+        )
+        self._newest = program
+        if len(self._unsettled) == self._unsettled.maxlen:
+            # nobody stamps any more (a watcher that hangs on a dead device):
+            # what is known of the programs before this one is nothing
+            self._unsettled.clear()
+            self._last_done = None
+        self._unsettled.append(program)
+        self._watcher.watch(program)
+        return program
+
+    def _settle(self) -> None:
+        """Account for every program, in the order they were enqueued, that
+        has left the device since the last call, from its stamp `done[k]`,
+        the one before it and its dispatch's `t0[k]` and `t1[k]`:
+
+        - recorder ``device_done``: ``program`` = its number among the
+          programs enqueued, ``device_ms`` = ``done[k]`` less the
+          later of ``done[k-1]`` and ``t1[k]``, ``queued_ms`` = what of
+          ``done[k-1]`` lies past ``t1[k]``, ``dry_ms`` (below), ``at`` =
+          ``done[k]``, ``error`` where its handle raised, ``late_ms`` where
+          a read-back's reading came before the watcher's, by how much;
+          ``dllama_engine_device_busy_seconds_total{step}``;
+        - where ``done[k-1]`` lies before ``t0[k]``, the device had nothing
+          to do between them and the host is why: the span
+          ``device_drained`` (``before`` = the step; on the thread that
+          dispatched it), ``dllama_engine_device_drained_seconds_total``
+          and ``dry_ms``, all three from that one pair of readings.
+
+        Late by a program or two where the loop runs ahead, which a ring,
+        a streamed timeline and a counter do not mind."""
+        while self._unsettled and self._unsettled[0].done is not None:
+            k = self._unsettled.popleft()
+            done, last = k.done, self._last_done
+            self._last_done = done
+            event = {"program": k.seq, "step": k.step, "at": done, "dry_ms": 0.0}
+            if k.error is not None:
+                event["error"] = k.error
+            if k.read is not None and k.watched is not None and k.watched > k.read:
+                event["late_ms"] = round((k.watched - k.read) * 1000, 3)
+            began = k.t1 if last is None else max(last, k.t1)
+            device_s = max(0.0, done - began)
+            event["device_ms"] = round(device_s * 1000, 3)
+            event["queued_ms"] = round(max(0.0, began - k.t1) * 1000, 3)
+            if k.dry and last is not None and last < k.t0:
+                sp = self._spans.begin(
+                    "device_drained", component="engine", annotate=False,
+                    at=last, before=k.step,
+                )
+                if sp is not None:
+                    sp.thread = k.thread  # whoever comes by to settle it
+                self._spans.end(sp, at=k.t0)
+                self._m_drained.labels(before=k.step).inc(k.t0 - last)
+                event["dry_ms"] = round((k.t0 - last) * 1000, 3)
+            self._m_busy.labels(step=k.step).inc(device_s)
+            self.recorder.record("device_done", **event)
+
+    def close(self) -> None:
+        """A server that stops: account for what has been stamped, end the
+        watcher's thread and drop the programs it has not stamped. The
+        engine serves on if asked: nothing is then known of the device, and
+        the next program starts a watcher of its own."""
+        self._settle()
+        self._unsettled.clear()
+        self._newest = self._last_done = None
+        self._watcher.close()
 
     def _complete_dispatch(self, step: str, t0: float, t1: float, **fields) -> float:
         """The end of one dispatch: the step histogram and the recorder's
@@ -978,11 +1082,12 @@ class InferenceEngine:
         pair (``ms`` on the latter), the ``engine`` span named ``step``
         and the step histogram all get the same two clock readings
         (``_begin_dispatch``, ``_complete_dispatch``), and the yielded
-        dict gets ``seconds``. The read-back wait inside is
-        ``_read_back``'s. A dispatch that raises ends its span and
-        completes nothing."""
-        t0 = self._begin_dispatch(step, prep, head, host_args, **fields)
-        timed = {}
+        dict, ``_begin_dispatch``'s, gets ``seconds``. The lane path hands
+        it to ``_launched`` behind its program's call. The read-back wait
+        inside is ``_read_back``'s. A dispatch that raises ends its span
+        and completes nothing."""
+        timed = self._begin_dispatch(step, prep, head, host_args, **fields)
+        t0 = timed["t0"]
         sp = self._spans.begin(step, component="engine", at=t0, **fields)
         try:
             yield timed
@@ -991,8 +1096,6 @@ class InferenceEngine:
             raise
         t1 = time.monotonic()
         self._spans.end(sp, at=t1)
-        if step not in self._POOL_COPIES:
-            self._enqueued += 1
         timed["seconds"] = self._complete_dispatch(step, t0, t1, **fields)
 
     def _rows_in_context(self, starts: list[int], n: int) -> dict:
@@ -1149,17 +1252,14 @@ class InferenceEngine:
             posv[lane] = pos0
         return rows, posv
 
-    def _read_back(self, step: str, out, newest: bool = True) -> np.ndarray:
+    def _read_back(self, step: str, out, program: Program) -> np.ndarray:
         """The device-complete wait: the program call returned as soon as
         it was enqueued, and the read-back waits for the device. Its own
         ``<step>.device`` span, so a timeline splits dispatch overhead
         from device time. The device runs programs in the order they
-        were enqueued, so where ``out`` is the ``newest`` program's
-        output it is drained at the wait's end: the same clock reading
-        ends the span and marks that for the next ``_begin_dispatch``.
-        With a program enqueued behind the awaited one (a block
-        dispatched ahead of this collect) the device goes on, and no
-        mark is set."""
+        were enqueued, so the clock reading that ends the span says that
+        ``program``, whose output ``out`` is, and every program before it
+        have left the device by then (``Program.read``)."""
         sp = self._spans.begin(f"{step}.device", component="engine")
         try:
             host = np.asarray(out)
@@ -1168,8 +1268,9 @@ class InferenceEngine:
             raise
         t1 = time.monotonic()
         self._spans.end(sp, at=t1)
-        if newest:
-            self._drained_at = t1
+        for k in self._unsettled:
+            if k.seq <= program.seq and k.read is None:
+                k.read = t1
         return host
 
     def _fault(self, op: str):
@@ -1515,7 +1616,8 @@ class InferenceEngine:
             out, self.cache = block(
                 self.params, arr, self.cache, pos_arg, rng, temperature, topp
             )
-            out = self._read_back("decode_block", out)  # [n_steps, lanes]
+            out = self._read_back(  # [n_steps, lanes]
+                "decode_block", out, self._launched(timed, out))
         self._m_tpot.observe(timed["seconds"] / n_steps)
         if per_lane:
             return [[int(t) for t in row] for row in out]
@@ -1661,9 +1763,11 @@ class InferenceEngine:
         positions below the floor keep what they hold; and `fresh` starts
         the lane's states from zero (`prefill_lane_chunk`).
 
-        A held share of the experts (`_counts_forms`): the program returns
-        (cache, int32 [3]): of its expert layers, those that took the landed
-        form, those that took the whole one, and the pairs that landed."""
+        The program returns (cache, a small output for its completion
+        stamp): one integer (`_chunk_mark`), or of a held share of the
+        experts (`_counts_forms`) int32 [3]: of its expert layers, those
+        that took the landed form, those that took the whole one, and the
+        pairs that landed."""
 
         def make():
             precision = self._precision
@@ -1696,7 +1800,7 @@ class InferenceEngine:
                         one_live_lane=True, **lane_state,
                         **({} if forms is None else {"expert_forms": forms}),
                     )
-                return cache if forms is None else (cache, forms.pop())
+                return cache, (_chunk_mark(cache) if forms is None else forms.pop())
 
             return step
 
@@ -1970,25 +2074,24 @@ class InferenceEngine:
             **self._chunk_rows_in_context(filled),
             **self._chunk_expert_rows(bucket),
             **self._chunk_state_fields(pos0, widths[0], write_floor),
-        ):
+        ) as timed:
+            # `mark`: the forms its expert layers took, or one integer, for
+            # the completion stamp's sake alone
             if native:
                 with self._kv_pool_guard():
                     if fault is not None:
                         raise fault
-                    self.kv_pool = step(self.params, arr, self.kv_pool, *rest)
+                    self.kv_pool, mark = step(self.params, arr, self.kv_pool, *rest)
             else:
                 with self._cache_guard():
                     if fault is not None:
                         raise fault
-                    out = step(self.params, arr, self.cache, *rest)
-                    if self._counts_forms:
-                        self.cache, forms = out
-                        # never waited for; with no collect to count them
-                        # (prompts prefilled and nothing decoded) the oldest go
-                        self._chunk_forms = self._chunk_forms[-63:] + [
-                            (self._enqueued, forms)]
-                    else:
-                        self.cache = out
+                    self.cache, mark = step(self.params, arr, self.cache, *rest)
+            program = self._launched(timed, mark)
+            if self._counts_forms and not native:
+                # never waited for; with no collect to count them
+                # (prompts prefilled and nothing decoded) the oldest go
+                self._chunk_forms = self._chunk_forms[-63:] + [(program.seq, mark)]
         return widths
 
     def prefill_lane(
@@ -2689,7 +2792,7 @@ class InferenceEngine:
                 rows = pos_vec[:, None] + jnp.arange(t)[None, :]
                 safe = rows < window
                 pool = self._paged_scatter(pool, view, pt, rows, safe)
-                return pool
+                return pool, _chunk_mark(pool)
 
             return step
 
@@ -2936,12 +3039,13 @@ class InferenceEngine:
             fields["state_lanes"] = len(live)
         # ahead: a block is enqueued and not collected, so the device has
         # this one queued when that one ends
-        t0 = self._begin_dispatch(
+        begun = self._begin_dispatch(
             "decode_lanes", prep, host_args=1 + len(rest), **fields,
             ahead=int(self._uncollected is not None),
             **self._rows_in_context([pos[i] for i in live], n_steps),
         )
         # the call: the enqueue and the transfer of its host arrays
+        t0 = begun["t0"]
         sp = self._spans.begin("decode_lanes", component="engine", at=t0, **fields)
         guard = self._kv_pool_guard if native else self._cache_guard
         try:
@@ -2957,13 +3061,12 @@ class InferenceEngine:
         except BaseException:
             self._spans.end(sp, error=True)
             raise
-        self._enqueued += 1
-        t1 = time.monotonic()
+        program = self._launched(begun, out)
         self._uncollected = LaneBlock(
             out=out, n_steps=n_steps, live=frozenset(live), native=native,
-            enqueued=self._enqueued, t0=t0, t1=t1, fields=fields,
+            program=program, t0=t0, t1=program.t1, fields=fields,
         )
-        self._spans.end(sp, at=t1)
+        self._spans.end(sp, at=program.t1)
         return self._uncollected
 
     def discard_lanes(self, block: LaneBlock) -> None:
@@ -2984,10 +3087,7 @@ class InferenceEngine:
             self._uncollected = None
         guard = self._kv_pool_guard if block.native else self._cache_guard
         with guard():
-            out_np = self._read_back(
-                "decode_lanes", block.out,
-                newest=block.enqueued == self._enqueued,
-            )
+            out_np = self._read_back("decode_lanes", block.out, block.program)
         t1 = time.monotonic()
         block.seconds = self._complete_dispatch(
             "decode_lanes", max(block.t0, self._collected_at), t1,
@@ -3007,7 +3107,7 @@ class InferenceEngine:
                 pairs_routed=routed, pairs_held=held, held_touched=touched,
                 tokens_landed=landed,
             )
-        self._count_chunk_forms(block.enqueued)
+        self._count_chunk_forms(block.program.seq)
         # each active stream advances one token per block row
         self._m_tpot.observe(block.seconds / block.n_steps)
         self._m_sampler.labels(
@@ -3215,14 +3315,15 @@ class InferenceEngine:
         with self._dispatch(
             "verify_lanes", prep, host_args=1 + len(rest),
             pos=deepest, t=t, window=window, n_live=len(live),
-        ), guard():
+        ) as timed, guard():
             if fault is not None:
                 raise fault
             if native:
                 out, self.kv_pool = vstep(self.params, arr, self.kv_pool, *rest)
             else:
                 out, self.cache = vstep(self.params, arr, self.cache, *rest)
-            out_np = self._read_back("verify_lanes", out)
+            out_np = self._read_back(
+                "verify_lanes", out, self._launched(timed, out))
         return [[int(x) for x in row] for row in out_np]
 
     # -- resident draft model (second-generation speculation) ----------------
@@ -3546,7 +3647,8 @@ class InferenceEngine:
             out, self.draft_cache = block(
                 self._draft_params, arr, self.draft_cache, *rest
             )
-            out_np = self._read_back("draft_step", out)
+            out_np = self._read_back(
+                "draft_step", out, self._launched(timed, out))
         if self._m_spec_draft_ms is not None:
             self._m_spec_draft_ms.labels(kind="propose").observe(
                 timed["seconds"] * 1000

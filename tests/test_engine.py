@@ -5,6 +5,7 @@ import contextlib
 import os
 import subprocess
 import sys
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -1746,39 +1747,52 @@ def test_cli_weight_format_choices(tiny_model, capsys, value):
         assert e.weight_format == "q40i4"
 
 
-# -- the drained interval (`_read_back` marks it, `_dispatch` closes it) ------
+# -- completion stamps: when each program left the device ----------------------
 #
 # Each case is a run of dispatches on the tiny preset under a clock that
-# advances one second a reading: the steps before which the device stands
-# drained.
+# advances one second a reading. A program's handle is held until the case
+# says `done` (the device has finished everything enqueued, and the watcher
+# has stamped it); a read-back stamps by itself. What is listed: the steps
+# before which the device stood drained, and each dispatch's `dry`.
 
 DRAINED_CASES = {
-    # a block behind an un-read chunk records nothing; between two blocks
-    # there is exactly one interval
-    "chunk_block_block": (["chunk", "block", "block"], ["decode_lanes"]),
+    # a block behind a chunk that still runs finds the device busy; between
+    # two blocks there is exactly one interval
+    "chunk_block_block": (["chunk", "block", "block"], ["decode_lanes"], [1, 0, 1]),
     # a pool copy onto a drained device is host work inside the interval
     "block_publish_chunk_block": (
-        ["block", "publish", "chunk", "block"], ["prefill_lane_chunk"]),
-    "block_adopt_block": (["block", "adopt", "block"], ["decode_lanes"]),
-    # and behind an un-read chunk it leaves no mark
+        ["block", "publish", "chunk", "block"], ["prefill_lane_chunk"], [1, 1, 0]),
+    "block_adopt_block": (["block", "adopt", "block"], ["decode_lanes"], [1, 1]),
+    # and behind a chunk that still runs it changes nothing
     "block_chunk_publish_block": (
-        ["block", "chunk", "publish", "block"], ["prefill_lane_chunk"]),
-    "block_block_block": (["block"] * 3, ["decode_lanes"] * 2),
+        ["block", "chunk", "publish", "block"], ["prefill_lane_chunk"], [1, 1, 0]),
+    "block_block_block": (["block"] * 3, ["decode_lanes"] * 2, [1, 1, 1]),
     # a block dispatched ahead is queued behind the awaited one: that wait
-    # leaves no mark; the wait for the newest program does
+    # says nothing of it; the wait for the newest program does
     "block_dispatch_ahead_collect_collect_block": (
         ["block", "dispatch", "ahead", "collect", "collect", "block"],
-        ["decode_lanes", "decode_lanes"]),
+        ["decode_lanes", "decode_lanes"], [1, 1, 0, 1]),
     # nor does a wait behind which a chunk was enqueued
     "dispatch_ahead_collect_chunk_collect_block": (
-        ["dispatch", "ahead", "collect", "chunk", "collect", "block"], []),
+        ["dispatch", "ahead", "collect", "chunk", "collect", "block"], [], [1, 0, 0, 0]),
     # a dispatch ahead that raises enqueued nothing: the block in flight is
-    # still the newest program, and its wait leaves the mark
+    # still the newest program, and its wait says the device is dry
     "dispatch_poison_collect_block": (
-        ["dispatch", "poison", "collect", "block"], ["decode_lanes"]),
-    # a block abandoned un-read still runs (no mark, no interval), but the
-    # next block is not ahead of a collect that never comes
-    "dispatch_discard_block": (["dispatch", "discard", "block"], []),
+        ["dispatch", "poison", "collect", "block"], ["decode_lanes"], [1, 0, 1]),
+    # a block abandoned un-read still runs, and the next block is not ahead
+    # of a collect that never comes
+    "dispatch_discard_block": (["dispatch", "discard", "block"], [], [1, 0]),
+    # a block dispatched ahead of a collect that leaves the device before
+    # the next dispatch leaves an interval, though nobody has read it back
+    "ahead_done_before_the_next_dispatch": (
+        ["dispatch", "ahead", "collect", "done", "ahead", "collect", "collect"],
+        ["decode_lanes"], [1, 0, 1]),
+    # one that leaves it after the next dispatch leaves none
+    "ahead_done_after_the_next_dispatch": (
+        ["dispatch", "ahead", "collect", "ahead", "collect", "collect"], [], [1, 0, 0]),
+    # chunks are never read back: the watcher alone says when one has left
+    "chunk_done_chunk": (["chunk", "done", "chunk"], ["prefill_lane_chunk"], [1, 1]),
+    "chunk_chunk": (["chunk", "chunk"], [], [1, 0]),
 }
 
 
@@ -1798,67 +1812,255 @@ class _TickingTime:
         return getattr(time, name)
 
 
-@pytest.mark.parametrize("case", list(DRAINED_CASES))
-def test_device_drained_between_a_read_back_and_the_next_dispatch(
-        slab_engine, monkeypatch, case):
-    import dllama_tpu.runtime.engine as engine_mod
+class _Held:
+    """A program's handle whose completion the test releases: to the
+    watcher the program runs until then."""
 
-    e = slab_engine
-    calls, want = DRAINED_CASES[case]
-    flying = []
-    do = {
-        "chunk": lambda: e.prefill_lane_chunk(1, list(range(1, 9)), 0),
-        "block": lambda: e.decode_lanes([5, 0], [0, 0], 4, active=[True, False]),
-        "publish": lambda: e.kv_publish(0, [1], start_page=0),
-        "adopt": lambda: e.kv_adopt(1, [1]),
-        "dispatch": lambda: flying.append(
-            e.dispatch_lanes([5, 0], [0, 0], 4, active=[True, False])),
-        # lane 0 goes on from the token the block in flight samples last
-        "ahead": lambda: flying.append(
-            e.dispatch_lanes([None, 0], [4, 0], 4, active=[True, False])),
-        "collect": lambda: e.collect_lanes(flying.pop(0)),
-        "poison": lambda: poisoned(),
-        "discard": lambda: e.discard_lanes(flying.pop(0)),
-    }
+    def __init__(self, real, raises=None):
+        self.real, self.raises = real, raises
+        self.waited_for, self.released = threading.Event(), threading.Event()
+
+    def block_until_ready(self):
+        self.waited_for.set()
+        assert self.released.wait(60)
+        if self.raises is not None:
+            raise self.raises
+        self.real.block_until_ready()
+
+    def is_ready(self):
+        return self.released.is_set()
+
+
+@contextlib.contextmanager
+def _held_programs(e, monkeypatch, raises=()):
+    """Every program `e` enqueues inside hands the watcher a `_Held`; yields
+    the programs, and `done()`: release them all and wait for their stamps.
+    `raises`: {n: the exception the n-th program's handle raises}."""
+    import time
+
+    programs = []
+    real = e._launched
+
+    def launched(begun, handle):
+        programs.append(real(begun, _Held(handle, dict(raises).get(len(programs)))))
+        return programs[-1]
+
+    def done():
+        for p in programs:
+            held = p.handle
+            if held is not None:
+                held.released.set()
+        deadline = time.monotonic() + 60
+        while not all(p.watched is not None for p in programs):
+            assert time.monotonic() < deadline, "the watcher stamped nothing"
+            time.sleep(0.001)
+
+    e.close()  # what earlier tests enqueued is no program before these
+    with monkeypatch.context() as m:
+        m.setattr(e, "_launched", launched)
+        try:
+            yield programs, done
+        finally:
+            done()
+            e.close()
+
+
+def _stamp_calls(e, monkeypatch):
+    """The calls of a case, by name, on lanes 0 (blocks) and 1 (chunks)."""
+    flying, at = [], [0]
+
+    def dispatch(ahead):
+        tokens = [None if ahead else 5, 0]
+        flying.append(e.dispatch_lanes(tokens, [at[0], 0], 4, active=[True, False]))
+        at[0] += 4
 
     def poisoned():
         fault = InjectedFault("dispatch", "decode_lanes", "poison", 1)
         with monkeypatch.context() as m:
             m.setattr(e, "_fault", lambda op: fault)
             with pytest.raises(InjectedFault):
-                do["ahead"]()
+                dispatch(ahead=True)
+        at[0] -= 4
 
-    for call in dict.fromkeys(calls):
+    def block():
+        dispatch(ahead=False)
+        return e.collect_lanes(flying.pop())
+
+    return {
+        "chunk": lambda: e.prefill_lane_chunk(1, list(range(1, 9)), 0),
+        "block": block,
+        "publish": lambda: e.kv_publish(0, [1], start_page=0),
+        "adopt": lambda: e.kv_adopt(1, [1]),
+        "dispatch": lambda: dispatch(ahead=False),
+        # lane 0 goes on from the token the block in flight samples last
+        "ahead": lambda: dispatch(ahead=True),
+        "collect": lambda: e.collect_lanes(flying.pop(0)),
+        "poison": poisoned,
+        "discard": lambda: e.discard_lanes(flying.pop(0)),
+    }, flying, at
+
+
+def _new_spans(e, n_before, name="device_drained"):
+    return [s for s in e._spans.completed()[-(e._spans.total_recorded - n_before):]
+            if s["name"] == name] if e._spans.total_recorded > n_before else []
+
+
+@pytest.mark.parametrize("case", list(DRAINED_CASES))
+def test_device_drained_from_a_programs_end_to_the_next_dispatch(
+        slab_engine, monkeypatch, case):
+    import dllama_tpu.runtime.engine as engine_mod
+
+    e = slab_engine
+    calls, want, want_dry = DRAINED_CASES[case]
+    do, flying, at = _stamp_calls(e, monkeypatch)
+    for call in dict.fromkeys(c for c in calls if c != "done"):
         do[call]()  # built outside the clock's reach
     while flying:
         do["collect"]()
     e.reset()
-    e._drained_at = None
-    monkeypatch.setattr(engine_mod, "time", _TickingTime())
-    n_spans, seq = e._spans.total_recorded, e.recorder.total_recorded
-    steps = set(want)
-    counted0 = {b: e._m_drained.labels(before=b).value for b in steps}
-    for call in calls:
-        do[call]()
-    spans = [s for s in e._spans.completed()[-(e._spans.total_recorded - n_spans):]
-             if s["name"] == "device_drained"]
+    at[0] = 0
+    programs_run = [c for c in calls if c in ("chunk", "block", "dispatch", "ahead")]
+    steps = {"chunk": "prefill_lane_chunk"}
+    with _held_programs(e, monkeypatch) as (programs, done):
+        monkeypatch.setattr(engine_mod, "time", _TickingTime())
+        n_spans, seq = e._spans.total_recorded, e.recorder.total_recorded
+        counted0 = {b: e._m_drained.labels(before=b).value for b in set(want)}
+        busy0 = {s: e._m_busy.labels(step=s).value
+                 for s in ("decode_lanes", "prefill_lane_chunk")}
+        do["done"] = done
+        for call in calls:
+            do[call]()
+    spans = _new_spans(e, n_spans)
     assert [s["attrs"] for s in spans] == [{"before": b} for b in want]
     assert all(s["component"] == "engine" and "parent" not in s for s in spans)
-    # the counter, the spans and `drained_ms` are one pair of clock readings
-    for b in steps:
+    assert all(s["thread"] == threading.get_ident() for s in spans)
+    assert all(s["dur_s"] >= 1.0 and s["dur_s"] == int(s["dur_s"]) for s in spans)
+    # one `device_done` a program, in dispatch order, none in error
+    done_events = [ev for ev in e.recorder.events("device_done") if ev["seq"] > seq]
+    assert [ev["step"] for ev in done_events] == [
+        steps.get(c, "decode_lanes") for c in programs_run]
+    assert [ev["program"] for ev in done_events] == [p.seq for p in programs]
+    assert not [ev for ev in done_events if "error" in ev]
+    assert [ev["at"] for ev in done_events] == sorted(ev["at"] for ev in done_events)
+    assert all(ev["device_ms"] >= 0 and ev["queued_ms"] >= 0 for ev in done_events)
+    # the counter, the spans and `dry_ms` are one pair of clock readings
+    for b in set(want):
         assert e._m_drained.labels(before=b).value - counted0[b] == sum(
             s["dur_s"] for s in spans if s["attrs"]["before"] == b)
+    assert [(ev["step"], ev["dry_ms"]) for ev in done_events if ev["dry_ms"]] == [
+        (s["attrs"]["before"], s["dur_s"] * 1000) for s in spans]
+    for step, was in busy0.items():
+        assert e._m_busy.labels(step=step).value - was == pytest.approx(sum(
+            ev["device_ms"] for ev in done_events if ev["step"] == step) / 1000)
     events = [ev for ev in e.recorder.events("step_dispatch") if ev["seq"] > seq]
-    assert len(events) == sum(call not in ("collect", "discard") for call in calls)
+    assert len(events) == sum(c not in ("collect", "discard", "done") for c in calls)
+    # a dispatch that is no pool copy says whether it found the device dry
+    assert [ev["dry"] for ev in events if "dry" in ev] == want_dry
+    assert all(("dry" in ev) == (ev["step"] not in e._POOL_COPIES) for ev in events)
     # a block says whether one was in flight at its dispatch
     assert [ev["ahead"] for ev in events if ev["step"] == "decode_lanes"] == [
-        int(call in ("ahead", "poison")) for call in calls
-        if call in ("block", "dispatch", "ahead", "poison")]
-    assert [(ev["step"], ev["drained_ms"]) for ev in events if "drained_ms" in ev] == [
-        (s["attrs"]["before"], s["dur_s"] * 1000) for s in spans]
-    assert all(s["dur_s"] >= 1.0 and s["dur_s"] == int(s["dur_s"]) for s in spans)
-    completes = [ev for ev in e.recorder.events("step_complete") if ev["seq"] > seq]
-    assert all("drained_ms" not in ev for ev in completes)
+        int(c in ("ahead", "poison")) for c in calls
+        if c in ("block", "dispatch", "ahead", "poison")]
+    assert not any("drained" in key for ev in events for key in ev)
+
+
+def test_a_late_watcher_does_not_move_a_stamp_a_read_back_took(slab_engine, monkeypatch):
+    """The watcher's reading can be late (here: by the test's hold; on a
+    server: by the interpreter's lock); the read-back's is the stamp, the
+    event says how late the watcher was, and the interval before the next
+    dispatch begins at the read-back's end."""
+    import dllama_tpu.runtime.engine as engine_mod
+
+    e = slab_engine
+    do, _, _ = _stamp_calls(e, monkeypatch)
+    do["block"]()
+    e.reset()
+    with _held_programs(e, monkeypatch) as (programs, done):
+        monkeypatch.setattr(engine_mod, "time", _TickingTime())
+        n_spans, seq = e._spans.total_recorded, e.recorder.total_recorded
+        do["block"]()
+        done()
+        do["block"]()
+    first, second = [ev for ev in e.recorder.events("device_done") if ev["seq"] > seq]
+    assert programs[0].watched > programs[0].read == first["at"]
+    assert first["late_ms"] == (programs[0].watched - programs[0].read) * 1000 >= 1000
+    (span,) = _new_spans(e, n_spans)
+    assert span["t0"] + e._spans.epoch_monotonic == pytest.approx(programs[0].read)
+    assert second["dry_ms"] == span["dur_s"] * 1000 == (programs[1].t0 - programs[0].read) * 1000
+
+
+def test_a_handle_that_raises_is_stamped_error_and_the_next_is_still_stamped(
+        slab_engine, monkeypatch):
+    e = slab_engine
+    do, _, _ = _stamp_calls(e, monkeypatch)
+    do["chunk"]()
+    e.reset()
+    with _held_programs(e, monkeypatch, raises={0: RuntimeError("poisoned")}) as (
+            programs, done):
+        seq = e.recorder.total_recorded
+        do["chunk"]()
+        do["chunk"]()
+        done()
+    assert [p.error for p in programs] == ["RuntimeError", None]
+    assert all(p.handle is None for p in programs)  # the watcher keeps nothing
+    events = [ev for ev in e.recorder.events("device_done") if ev["seq"] > seq]
+    assert [ev.get("error") for ev in events] == ["RuntimeError", None]
+    assert [ev["program"] for ev in events] == [p.seq for p in programs]
+
+
+def test_device_drained_carries_the_thread_that_dispatched(slab_engine, monkeypatch):
+    """The span is committed by whoever next comes by; its `thread` is the
+    dispatching thread's (`benchmark/harness/drained.py` takes the spans
+    of the thread that holds the ticks)."""
+    e = slab_engine
+    do, _, _ = _stamp_calls(e, monkeypatch)
+    do["chunk"]()
+    e.reset()
+    with _held_programs(e, monkeypatch) as (programs, done):
+        n_spans = e._spans.total_recorded
+
+        def dispatches():
+            do["chunk"]()
+            done()
+            do["chunk"]()
+
+        other = threading.Thread(target=dispatches, name="test-dispatcher")
+        other.start()
+        other.join(timeout=120)
+        assert not other.is_alive()
+    (span,) = _new_spans(e, n_spans)  # settled here, on the test's thread
+    assert span["thread"] == other.ident != threading.get_ident()
+    assert span["attrs"] == {"before": "prefill_lane_chunk"}
+
+
+def test_a_stopped_engines_watcher_ends_and_drops_what_is_left(slab_engine, monkeypatch):
+    e = slab_engine
+    do, _, _ = _stamp_calls(e, monkeypatch)
+    do["chunk"]()
+    e.reset()
+    with _held_programs(e, monkeypatch) as (programs, done):
+        seq = e.recorder.total_recorded
+        do["chunk"]()
+        do["chunk"]()
+        thread = e._watcher._thread
+        assert thread.is_alive() and thread.daemon and thread.name == "dllama-device-done"
+        assert programs[0].handle.waited_for.wait(60)
+        stopper = threading.Thread(target=e.close)
+        stopper.start()  # waits for the thread, which waits for the first handle
+        while not e._watcher._stopped.is_set():
+            pass
+        programs[0].handle.released.set()
+        stopper.join(timeout=60)
+        assert not stopper.is_alive() and not thread.is_alive() and not e._watcher.alive
+        # the first was stamped, the second dropped un-stamped with its handle
+        assert programs[0].watched is not None and programs[1].watched is None
+        assert programs[1].handle is None and not e._unsettled
+        programs.clear()
+    assert [ev for ev in e.recorder.events("device_done") if ev["seq"] > seq] == []
+    # and the engine serves on: the next program starts a watcher of its own
+    do["chunk"]()
+    e.close()
+    assert len([ev for ev in e.recorder.events("device_done") if ev["seq"] > seq]) <= 1
 
 
 # -- dispatch and collect, one block ahead ------------------------------------
@@ -1925,7 +2127,6 @@ def test_dispatch_and_collect_one_block_ahead_give_decode_lanes_rows(slab_engine
                   if ev["seq"] > seq and ev["step"] == "decode_lanes"]
     assert [ev["ahead"] for ev in dispatches] == [0, 1, 1, 1, 1]
     assert [ev["n_live"] for ev in dispatches] == [2, 2, 1, 2, 2]
-    assert all("drained_ms" not in ev for ev in dispatches[1:])
     completes = [ev for ev in e.recorder.events("step_complete")
                  if ev["seq"] > seq and ev["step"] == "decode_lanes"]
     assert len(completes) == 5 and all(ev["ms"] >= 0 for ev in completes)
@@ -2114,19 +2315,22 @@ def test_paged_step_dispatch_says_its_prep_and_its_host_arrays(build_engine, ste
 def test_prep_ms_is_the_prep_spans_begin_to_the_dispatchs(slab_engine, monkeypatch):
     """`prep_ms` is made of readings the spans take: `dispatch_prep`'s
     begin to the step span's begin; a pool copy's, its head to its
-    span's begin, and it neither carries `drained_ms` nor clears the
-    mark a read-back left."""
+    span's begin. A pool copy is no program of the completion stamps: its
+    `step_dispatch` says no `dry`, it leaves no `device_done`, and the
+    block before it stays the newest program."""
     import dllama_tpu.runtime.engine as engine_mod
 
     e = slab_engine
     HANDED_OVER["decode_lanes"][0](e)
     e.kv_publish(0, [1], start_page=0)
     e.reset()
+    e.close()
     monkeypatch.setattr(engine_mod, "time", _TickingTime())
     n_spans, seq = e._spans.total_recorded, e.recorder.total_recorded
     HANDED_OVER["decode_lanes"][0](e)
+    newest = e._newest
     e.kv_publish(0, [1], start_page=0)
-    assert e._drained_at is not None
+    assert e._newest is newest and newest.step == "decode_lanes"
     spans = {s["name"]: s for s in
              e._spans.completed()[-(e._spans.total_recorded - n_spans):]}
     block, copy = [ev for ev in e.recorder.events("step_dispatch") if ev["seq"] > seq]
@@ -2134,9 +2338,11 @@ def test_prep_ms_is_the_prep_spans_begin_to_the_dispatchs(slab_engine, monkeypat
     assert block["prep_ms"] == round(
         (spans["decode_lanes"]["t0"] - spans["dispatch_prep"]["t0"]) * 1000, 3)
     assert copy["step"] == "kv_publish" and copy["prep_ms"] == 1000.0
-    assert "drained_ms" not in copy
+    assert block["dry"] == 1 and "dry" not in copy
+    e.close()
+    done = [ev for ev in e.recorder.events("device_done") if ev["seq"] > seq]
+    assert [ev["step"] for ev in done] == ["decode_lanes"]
     e.reset()
-    e._drained_at = None
 
 
 # ------------------------------------------- what "auto" serves, and the gauges

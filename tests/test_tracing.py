@@ -83,6 +83,7 @@ def traced_run(tmp_path_factory):
     session."""
     d = tmp_path_factory.mktemp("tracing")
     engine, tok = _engine(d)
+    recorded = engine.recorder.total_recorded  # the recorder is the process's
     timeline = str(d / "timeline.json")
     srv = serve(engine, tok, host="127.0.0.1", port=0, timeline_out=timeline)
     threading.Thread(target=srv.serve_forever, daemon=True).start()
@@ -114,7 +115,7 @@ def traced_run(tmp_path_factory):
     srv.shutdown()
     srv.server_close()
     return {"events": _scheduler_events(str(d / "profile")), "ring": ring,
-            "timeline": timeline, "tracker": spans, "engine": engine}
+            "timeline": timeline, "tracker": spans, "engine": engine, "recorded": recorded}
 
 
 def _inside(events, outer):
@@ -255,56 +256,60 @@ def test_streamed_timeline_nests_by_parent_off_the_profiler(traced_run):
     assert sum(profiled) >= 20 and thread != threading.get_ident()
 
 
-def test_drained_spans_lie_between_a_read_back_and_the_next_dispatch(traced_run):
-    """Served streams: every `device_drained` begins where a `.device` wait
-    ended and ends where the dispatch named by `before` begins, on the
-    scheduler's thread, and no dispatch but a pool copy lies inside it; the
-    counter holds their sum. The loop runs one block ahead, so the wait for
-    a block behind which the next was dispatched ends in no interval, and a
-    block dispatched ahead says so and carries no `drained_ms`."""
-    _, spans = read_timeline(traced_run["timeline"])
+def test_drained_spans_lie_between_a_programs_end_and_the_next_dispatch(traced_run):
+    """Served streams: every `device_drained` begins where the completion
+    stamps say the program before left the device (`device_done`'s `at`)
+    and ends where the dispatch named by `before` begins, on the scheduler's
+    thread; no dispatch but a pool copy lies inside it, and no program runs
+    in it; the counter holds their sum. The loop runs one block ahead, and
+    the recorder holds one `device_done` for every dispatch of the lane
+    path, in dispatch order, a block dispatched ahead among them."""
+    meta, spans = read_timeline(traced_run["timeline"])
     (thread,) = {s["args"]["thread"] for s in spans if s["name"] == "sched_tick"}
     drained = [s for s in spans if s["name"] == "device_drained"]
     copies = {"kv_adopt", "kv_publish", "kv_page_copy"}
-    steps = {s["name"][: -len(".device")] for s in spans if s["name"].endswith(".device")}
-    assert "prefill_lane_chunk" not in steps | copies
-    dispatches = [s for s in spans if s["pid"] == 2
-                  and s["name"] in steps | copies | {"prefill_lane_chunk"}]
+    steps = {"decode_lanes", "prefill_lane_chunk"}
+    dispatches = [s for s in spans if s["pid"] == 2 and s["name"] in steps | copies]
     assert {"kv_publish", "kv_adopt"} <= {s["name"] for s in dispatches}
-    waits = [w for w in spans if w["name"].endswith(".device")]
+    recorder, since = traced_run["engine"].recorder, traced_run["recorded"]
+    done = [e for e in recorder.events("device_done") if e["seq"] > since]
+    began = [e for e in recorder.events("step_dispatch")
+             if e["seq"] > since and e["step"] in steps]
+    assert [e["step"] for e in done] == [e["step"] for e in began][: len(done)]
+    assert len(began) - len(done) <= 1 and not [e for e in done if "error" in e]
+    assert [e["program"] for e in done] == list(
+        range(done[0]["program"], done[0]["program"] + len(done)))
+    assert sum(e["ahead"] for e in began if e["step"] == "decode_lanes") >= 12
+    assert {e["dry"] for e in began} == {0, 1}
+    assert not any("drained" in key for e in began for key in e)
+    # on the timeline's clock, in microseconds
+    ends = [(e["at"] - meta["epoch_monotonic"]) * 1e6 for e in done]
+    enqueues = sorted(s["ts"] for s in dispatches if s["name"] not in copies)
+    assert len(drained) >= 1
     for d in drained:
         assert d["args"]["thread"] == thread and d["pid"] == 2
         lo, hi = d["ts"], d["ts"] + d["dur"]
-        assert any(abs(w["ts"] + w["dur"] - lo) < 0.01 for w in waits), d
+        assert any(abs(at - lo) < 0.01 for at in ends), d
         assert any(abs(s["ts"] - hi) < 0.01 and s["name"] == d["args"]["before"]
                    for s in dispatches), d
         within = {s["name"] for s in dispatches if lo - 0.01 <= s["ts"] < hi - 0.01}
         assert within <= copies, (d, within)
-    # a wait behind which a block or a chunk was enqueued leaves no mark:
-    # no interval begins at its end
-    # (blocks are collected in the order of their calls: the i-th wait is
-    # for the i-th call)
-    enqueues = sorted(s["ts"] for s in dispatches if s["name"] not in copies)
-    blocks = sorted((s for s in spans if s["name"] == "decode_lanes"), key=lambda s: s["ts"])
-    block_waits = sorted((w for w in waits if w["name"] == "decode_lanes.device"),
-                         key=lambda w: w["ts"])
-    n_queued_behind = 0
-    for block, w in zip(blocks, block_waits):
-        assert block["ts"] < w["ts"]
-        if any(block["ts"] < t < w["ts"] for t in enqueues):
-            n_queued_behind += 1
-            assert not any(abs(w["ts"] + w["dur"] - d["ts"]) < 0.01 for d in drained), w
-    assert n_queued_behind >= 12
-    assert 1 <= len(drained) <= len(blocks) - n_queued_behind + 2
-    events = [e for e in traced_run["engine"].recorder.events("step_dispatch")
-              if e["step"] == "decode_lanes"]
-    assert sum(e["ahead"] for e in events) >= 12
-    assert not [e for e in events if e["ahead"] and "drained_ms" in e]
-    # the counter is the process's own: other engines may have added to it
+        # the program dispatched at its end is the next to leave the device
+        assert not any(lo + 0.01 < at < hi for at in ends), d
+    # what the stamps say of a program and of the wait before it are one account
+    assert [e["dry_ms"] for e in done if e["dry_ms"]] == pytest.approx(
+        [d["dur"] / 1e3 for d in sorted(drained, key=lambda d: d["ts"])], abs=2e-3)
+    assert enqueues == sorted(enqueues) and len(enqueues) == len(began)
+    # the counters are the process's own: other engines may have added to them
+    engine = traced_run["engine"]
     for before in {d["args"]["before"] for d in drained}:
         seconds = sum(d["dur"] for d in drained if d["args"]["before"] == before) / 1e6
-        counted = traced_run["engine"]._m_drained.labels(before=before).value
-        assert counted >= seconds - 1e-6 > 0
+        assert engine._m_drained.labels(before=before).value >= seconds - 1e-6 > 0
+    for step in steps:
+        seconds = sum(e["device_ms"] for e in done if e["step"] == step) / 1e3
+        assert engine._m_busy.labels(step=step).value >= seconds - 1e-3 > 0
+    assert sum(engine._m_dispatches.labels(step="decode_lanes", device=d).value
+               for d in ("dry", "busy")) >= sum(e["step"] == "decode_lanes" for e in began)
 
 
 @pytest.fixture(scope="module", params=["dense", "moe"])
