@@ -1012,7 +1012,7 @@ def forward(
     kv_ring: int = 0,
     route_stats: list | None = None,
     expert_forms: list | None = None,
-    one_live_lane: bool = False,
+    live_lanes_alone: bool = False,
     state_rows: jnp.ndarray | None = None,
     state_fresh: jnp.ndarray | None = None,
     write_floor: jnp.ndarray | None = None,
@@ -1046,9 +1046,9 @@ def forward(
     the vocab matmul is a large fraction of chunk FLOPs (~25% on a
     1B/128k-vocab shape), which lands directly on TTFT.
 
-    `one_live_lane` (static): the caller's program admits ONE lane and parks
-    every other (the engine's chunk programs): `run_layers`' expert block
-    then computes that lane's rows alone.
+    `live_lanes_alone` (static): the caller's program is a chunk program,
+    whose lanes are admitting ones at a position and parked ones:
+    `run_layers`' expert block then computes the live lanes' rows alone.
 
     `state_rows`, `state_fresh`, `write_floor`: a model with lane state
     alone (`run_layers` says what each means); left out, every live lane's
@@ -1078,7 +1078,7 @@ def forward(
         kw_cache=cache.get("kw"), vw_cache=cache.get("vw"), kv_ring=kv_ring,
         route_stats=route_stats, expert_forms=expert_forms,
         c_cache=cache.get("c"), i_cache=cache.get("i"),
-        one_live_lane=one_live_lane,
+        live_lanes_alone=live_lanes_alone,
         **({"s_cache": cache["s"], "r_cache": cache.get("r"), "state_rows": state_rows,
             "state_fresh": state_fresh, "write_floor": write_floor} if "s" in cache else {}),
     )
@@ -1173,7 +1173,7 @@ def run_layers(
     route_stats: list | None = None,
     expert_forms: list | None = None,
     c_cache: jnp.ndarray | None = None,  # [L, B, 1, S, W]: latent layers, alone
-    one_live_lane: bool = False,
+    live_lanes_alone: bool = False,
     i_cache: jnp.ndarray | None = None,  # [L, B, 1, S, dI]: their index keys
     s_cache: jnp.ndarray | None = None,  # [Ls, B, K - 1, C]: state layers' convolution rows
     r_cache: jnp.ndarray | None = None,  # [Ls, B, N, H * P] f32: Mamba-2 layers' recurrent states
@@ -1227,15 +1227,21 @@ def run_layers(
     fixed-width window update + validity gather (a chunk's rows spread
     over every shard). Requires mesh=None.
 
-    `one_live_lane`: what the program is, not what its rows look like: a
-    chunk program admits one lane and parks the others, so at most one
-    entry of `attn_pos` is a position. A layer's expert block (the norm
-    before it, the router, the routed and the shared experts, the norm
-    behind) then runs over that lane's `[T, D]` rows, one contiguous slice
-    of `x` at a traced lane number, and writes them back; a parked lane's
-    rows, which no query reads, pass the block as they came. Where the
-    lanes are split over devices (`dp`) every lane's rows are computed as
-    before: the slice would gather across them.
+    `live_lanes_alone`: what the program is, not what its rows look like: a
+    chunk program holds admitting lanes, whose entry of `attn_pos` is a
+    position, and parked ones. A layer's expert block (the norm before it,
+    the router, the routed and the shared experts, the norm behind) then
+    visits the live lanes one after another, a loop of as many trips as
+    lanes are live: each lane's `[T, D]` rows are one contiguous slice of
+    `x` at a traced lane number, computed as a program that admitted that
+    lane alone computes them and written back; a parked lane's rows, which
+    no query reads, pass the block as they came. The routing counters and
+    the form counter sum over the lanes visited. A layer that keeps a state
+    a lane, a latent index's mask and `write_floor` take ONE lane, the
+    first live one: such a model's program admits one (`engine.
+    chunk_takes_one_lane`). Where the lanes are split over devices (`dp`)
+    every lane's rows are computed as before: the slice would gather
+    across them.
 
     `s_cache`: a model some of whose layers keep a state a lane
     (`LayerKind.keeps_state`: gated short convolutions, Mamba-2 mixers)
@@ -1254,7 +1260,7 @@ def run_layers(
     The layer pattern is static: each run of one FFN kind is scanned by
     whole periods of its pattern, a period's layers unrolled in the scan's
     body, so no branch is taken on the device and an attention layer alone
-    writes cache rows. In a chunk program (`one_live_lane`) a convolution
+    writes cache rows. In a chunk program (`live_lanes_alone`) a convolution
     layer, its FFN with it, runs over the admitted lane's rows alone.
     """
     b, t = x.shape[0], x.shape[1]
@@ -1356,13 +1362,17 @@ def run_layers(
                 f"first query sees"
             )
     # token rows of live lanes: what the expert block computes pairs for and
-    # the routing counters count; `lone`: the admitted lane's rows alone
-    lone = one_live_lane and jnp.ndim(attn_pos) == 1 and lanes_on_one_device(mesh)
+    # the routing counters count; `lone`: one live lane's rows at a time
+    lone = live_lanes_alone and jnp.ndim(attn_pos) == 1 and lanes_on_one_device(mesh)
     if write_floor is not None and not (lone or b == 1):
-        raise ValueError("write_floor: of a program that admits one lane (one_live_lane)")
+        raise ValueError("write_floor: of a program that admits one lane (live_lanes_alone)")
     if lone:
-        lane = jnp.argmax(attn_pos >= 0).astype(jnp.int32)
-        live_rows = jnp.broadcast_to(attn_pos[lane] >= 0, (t,))
+        # the live lanes' numbers first, in the order the expert block visits
+        # them; `lane`: the first, which is the one where one is admitted
+        lanes_live = jnp.argsort(attn_pos < 0, stable=True).astype(jnp.int32)
+        n_live = jnp.sum(attn_pos >= 0).astype(jnp.int32)
+        lane = lanes_live[0]
+        live_rows = jnp.broadcast_to(n_live > 0, (t,))
     elif jnp.ndim(attn_pos) == 1:
         live_rows = jnp.broadcast_to((attn_pos >= 0)[:, None], (b, t)).reshape(-1)
     else:
@@ -2000,34 +2010,52 @@ def run_layers(
                     x = x + scaled(o)
 
             # -- FFN block (reference: src/llm.cpp:405-557) --
-            # experts of a chunk program: over the admitted lane's rows alone
-            if not lane_alone:
-                x_all = x
-            if experts and lone and not lane_alone:
-                x = lax.dynamic_slice_in_dim(x_all, lane, 1, axis=0)  # [1, T, D]
-            with jax.named_scope("norm"):
-                y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
-            with jax.named_scope("moe" if experts else "ffn"):
-                if experts:
-                    with jax.named_scope(phase):
-                        with jax.named_scope("routed"):
-                            f, counts = moe_block(y, lp, lf)
-                        if tp_axis is not None:
-                            # manual tp: experts arrived F-sliced (same layout the
-                            # mesh path shards); the local partial outputs all-reduce
-                            # here instead of inside the helpers' shard_map
-                            f = lax.psum(f, tp_axis)
-                        if "shared_w2" in lp:
-                            with jax.named_scope("shared"):
-                                f = f + swiglu(y, "shared_").astype(f.dtype)
-                else:
-                    f = swiglu(y)
-                f = f.astype(x.dtype)
-                if "post_ffn_norm" in lp:
-                    f = rms_norm(f, lp["post_ffn_norm"], h.norm_epsilon)
-                x = x + scaled(f)
-                if (experts and lone) or lane_alone:
-                    x = lax.dynamic_update_slice_in_dim(x_all, x, lane, axis=0)
+            def ffn_block(x):
+                """The layer's FFN over `x`'s rows and the residual add: (x,
+                what `moe_block` counted)."""
+                counts = None
+                with jax.named_scope("norm"):
+                    y = rms_norm(x, lp["ffn_norm"], h.norm_epsilon)
+                with jax.named_scope("moe" if experts else "ffn"):
+                    if experts:
+                        with jax.named_scope(phase):
+                            with jax.named_scope("routed"):
+                                f, counts = moe_block(y, lp, lf)
+                            if tp_axis is not None:
+                                # manual tp: experts arrived F-sliced (same layout the
+                                # mesh path shards); the local partial outputs all-reduce
+                                # here instead of inside the helpers' shard_map
+                                f = lax.psum(f, tp_axis)
+                            if "shared_w2" in lp:
+                                with jax.named_scope("shared"):
+                                    f = f + swiglu(y, "shared_").astype(f.dtype)
+                    else:
+                        f = swiglu(y)
+                    f = f.astype(x.dtype)
+                    if "post_ffn_norm" in lp:
+                        f = rms_norm(f, lp["post_ffn_norm"], h.norm_epsilon)
+                    return x + scaled(f), counts
+
+            if lane_alone:
+                x, counts = ffn_block(x)
+                x = lax.dynamic_update_slice_in_dim(x_all, x, lane, axis=0)
+            elif experts and lone:
+                # experts of a chunk program: over the live lanes' rows alone,
+                # a lane a trip, each as a program of its own computes them
+                def visit(i, carry):
+                    x_all, counts = carry
+                    at = lanes_live[i]
+                    x, c = ffn_block(lax.dynamic_slice_in_dim(x_all, at, 1, axis=0))
+                    with jax.named_scope("moe"):  # the write-back fuses the residual add
+                        x_all = lax.dynamic_update_slice_in_dim(x_all, x, at, axis=0)
+                    return x_all, None if c is None else counts + c
+
+                none_yet = jax.tree.map(
+                    lambda c: jnp.zeros(c.shape, c.dtype),
+                    jax.eval_shape(lambda rows: ffn_block(rows)[1], x[:1]))
+                x, counts = lax.fori_loop(0, n_live, visit, (x, none_yet))
+            else:
+                x, counts = ffn_block(x)
             return (x, caches), counts
 
         return layer_step

@@ -85,7 +85,7 @@ def forward_pp(
     sync_quant: bool = False,
     park_pos: int = 0,
     moe_decode_dedup: bool = False,
-    one_live_lane: bool = False,
+    live_lanes_alone: bool = False,
 ):
     """Pipeline-parallel forward: same contract as models.forward.
 
@@ -267,7 +267,7 @@ def forward_pp(
                 tp_axis="tp" if tp > 1 else None, tp_n=tp,
                 sp_axis=sp_ax, sp_n=sp,
                 # a micro-batch holds the admitted lane's rows as the chunk does
-                one_live_lane=one_live_lane and lanes_on_one_device(mesh),
+                live_lanes_alone=live_lanes_alone and lanes_on_one_device(mesh),
             )
             # commit this stage's cache range only for a valid chunk;
             # invalid ticks computed on pass-through/fill data (park mode:

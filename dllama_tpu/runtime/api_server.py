@@ -411,6 +411,9 @@ class LaneScheduler:
         # lanes mid-admission (resumable chunked prefill state machine)
         self.admitting: dict[int, _AdmittingLane] = {}
         self._rr = -1  # round-robin cursor over concurrently admitting lanes: a tick's lead
+        # decode blocks owed before the next chunk program: one a rider of
+        # the last, where a rider added rows to it (`_admission_tick`)
+        self._chunk_debt = 0
         # injectable clock for the stall/prefill accounting (fake-clock
         # scheduler tests replace it; production uses the monotonic timer)
         self._clock = time.perf_counter
@@ -830,11 +833,13 @@ class LaneScheduler:
                     "park_preempt", component="scheduler",
                     annotate=False, at=t_evict,
                 ))
-            # stall-free admission: at most ONE bounded prefill chunk per
+            # stall-free admission: at most ONE bounded chunk program per
             # tick, then a decode block for every active lane — the worst
-            # case inter-token gap is one chunk + one block, and two
-            # pending jobs can never prefill back-to-back while another
-            # lane is mid-stream
+            # case inter-token gap is one chunk program + one block. A
+            # program carries every admitting lane's next chunk; where a
+            # rider adds rows to it (experts), its riders are paid for in
+            # blocks before the next: a tick each with a block and no
+            # program, so a decoding lane gets a block a lane's chunk
             self._admission_tick()
             if any(self.lanes) or self._flight is not None:
                 self._guarded_step(self._step_block)
@@ -1261,22 +1266,23 @@ class LaneScheduler:
         (not cancelled, adopted or needing no adopt, fill tokens left), as
         many as the engine's chunk program fills besides the lead: none
         where it takes one lane's rows (`engine.chunk_lanes`)."""
-        def rides(adm):
-            return (not adm.job.cancelled and not self._adopt_due(adm)
-                    and adm.cursor < len(adm.tokens) - 1)
-
         order = sorted(self.admitting)
         behind = [i for i in order if i > lead] + [i for i in order if i < lead]
-        riders = [lane for lane in behind if rides(self.admitting[lane])]
+        riders = [lane for lane in behind if self._chunk_due(self.admitting[lane])]
         return riders[: self.engine.chunk_lanes - 1]
 
-    def _chunk_dispatch(self, carried: list[int]) -> None:
+    def _chunk_due(self, adm: _AdmittingLane) -> bool:
+        """Whether `adm`'s next action is a chunk."""
+        return (not adm.job.cancelled and not self._adopt_due(adm)
+                and adm.cursor < len(adm.tokens) - 1)
+
+    def _chunk_dispatch(self, carried: list[int]) -> int:
         """ONE chunk program for the next chunk of every lane of `carried`
         (the tick's lead first), each at its own position. Cursors move
         once the dispatch has returned, each by what its lane consumed: a
         lane the engine left out (its rows would pass the context's end in
         the common bucket) consumed nothing and waits for a tick that it
-        leads."""
+        leads. Returns the lanes the program filled."""
         adms = [self.admitting[lane] for lane in carried]
         wd = self.state.watchdog
         spans = [
@@ -1328,6 +1334,7 @@ class LaneScheduler:
                 pos=adm.pos0 + adm.cursor - width, n_tokens=width,
                 done=adm.cursor >= len(adm.tokens) - 1,
             )
+        return sum(1 for width in widths if width)
 
     def _admission_tick(self) -> None:
         """Run at most ONE bounded engine dispatch of admission per
@@ -1335,13 +1342,28 @@ class LaneScheduler:
         lands. The tick's lead is picked round-robin across concurrent
         admissions; its adopt is its own tick, and its chunk program carries
         the next chunk of every other admitting lane that can ride in it
-        (`_chunk_riders`)."""
+        (`_chunk_riders`).
+
+        Where a rider adds rows to the program (`engine.
+        chunk_rider_adds_rows`) the program owes the decoding lanes a block
+        a rider: while it owes and a lane decodes, a tick whose lead's turn
+        is a chunk dispatches none and takes one off the debt (the tick's
+        block follows as ever), so a lane's chunk is still followed by a
+        block of its own. With no lane decoding the debt is dropped."""
         if not self.admitting:
+            self._chunk_debt = 0
             return
         order = sorted(self.admitting)
         lane = min((i for i in order if i > self._rr), default=order[0])
-        self._rr = lane
         adm = self.admitting[lane]
+        if self._chunk_debt and self._chunk_due(adm):
+            if any(self.lanes) or self._flight is not None:
+                self._chunk_debt -= 1
+                self.state.m_admission_yielded.inc()
+                self.state.recorder.record("admission_yield", lane=lane, owed=self._chunk_debt)
+                return
+            self._chunk_debt = 0
+        self._rr = lane
         if adm.job.cancelled:
             self._abort_admission(lane, "cancelled")
             return
@@ -1374,7 +1396,9 @@ class LaneScheduler:
                 adm.adopted = True
             elif adm.cursor < len(adm.tokens) - 1:
                 carried += self._chunk_riders(lane)
-                self._chunk_dispatch(carried)
+                filled = self._chunk_dispatch(carried)
+                if self.engine.chunk_rider_adds_rows:
+                    self._chunk_debt = filled - 1
         except Exception as e:
             self._admission_fault(e, epoch0, carried)
             return
@@ -2378,6 +2402,13 @@ class ApiState:
             "dllama_admission_chunks_total",
             "Bounded prefill chunks of admitting lanes (one a lane that a "
             "scheduler tick's chunk program carried).",
+        )
+        self.m_admission_yielded = self.obs.counter(
+            "dllama_admission_ticks_yielded_total",
+            "Scheduler ticks on which an admitting lane's chunk was due and "
+            "no chunk program was dispatched: the last one carried riders "
+            "that added rows to it (experts), and owed the decoding lanes a "
+            "block a rider.",
         )
         self.m_decode_blocks = self.obs.counter(
             "dllama_sched_decode_blocks_total",

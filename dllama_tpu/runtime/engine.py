@@ -242,13 +242,14 @@ _KEPT = (
 
 
 def chunk_takes_one_lane(h: LlmHeader) -> bool:
-    """Whether some block of the model's chunk program takes the admitted
-    lane's rows alone (`run_layers` under `one_live_lane`): the expert block,
-    a layer that keeps a state a lane, a latent index's mask. Such a program
-    is wrong for two live lanes; every other computes each live lane's rows
-    at that lane's position, as the verify programs do, and one call can
-    fill several admitting lanes' rows (`InferenceEngine.chunk_lanes`)."""
-    return bool(h.n_experts or h.stateful or h.indexed)
+    """Whether some block of the model's chunk program takes ONE lane
+    (`run_layers` under `live_lanes_alone`): a layer that keeps a state a
+    lane takes one lane's state, a latent index builds one lane's mask. Such
+    a program is wrong for two live lanes; every other computes each live
+    lane's rows at that lane's position (the expert block a live lane after
+    another), as the verify programs do, and one call can fill several
+    admitting lanes' rows (`InferenceEngine.chunk_lanes`)."""
+    return bool(h.stateful or h.indexed)
 
 
 class InferenceEngine:
@@ -641,8 +642,8 @@ class InferenceEngine:
         self._m_moe_chunk_rows = self.obs.counter(
             "dllama_moe_chunk_rows_total",
             "Token rows of prefill chunk programs by what the expert block "
-            "did with them: computed = rows it routed and ran (the admitted "
-            "lane's bucket), parked_skipped = parked lanes' rows it left out.",
+            "did with them: computed = rows it routed and ran (the bucket, a "
+            "carried lane), parked_skipped = parked lanes' rows it left out.",
             labelnames=("rows",),
         )
         self._m_prefill_chunks = self.obs.counter(
@@ -656,7 +657,8 @@ class InferenceEngine:
             "dllama_prefill_lanes_total",
             "Lanes those chunk programs filled, summed over the programs: "
             "over dllama_prefill_chunks_total, the lanes a program carries "
-            "(1 where the model's chunk program takes one lane's rows).",
+            "(1 where the model's chunk program takes one lane: its state, "
+            "its index's mask).",
         )
         self._m_prefill_rows = self.obs.counter(
             "dllama_prefill_rows_total",
@@ -839,14 +841,14 @@ class InferenceEngine:
 
             def fwd(params, tokens, pos, cache, *, attn_window=0,
                     logits_mode="all", attn_park_threshold=0, n_micro=1,
-                    one_live_lane=False):
+                    live_lanes_alone=False):
                 return forward_pp(
                     params, h, tokens, pos, cache, mesh,
                     attn_window=attn_window, logits_mode=logits_mode,
                     attn_park_threshold=attn_park_threshold,
                     n_micro=n_micro, sync_quant=sync_quant,
                     park_pos=park, moe_decode_dedup=moe_decode_dedup,
-                    one_live_lane=one_live_lane,
+                    live_lanes_alone=live_lanes_alone,
                 )
 
         else:
@@ -855,7 +857,7 @@ class InferenceEngine:
 
             def fwd(params, tokens, pos, cache, *, attn_window=0,
                     logits_mode="all", attn_park_threshold=0, n_micro=1,
-                    route_stats=None, expert_forms=None, one_live_lane=False,
+                    route_stats=None, expert_forms=None, live_lanes_alone=False,
                     **lane_state):
                 del n_micro  # sequence-wave microbatching is pp-only
                 return forward(
@@ -866,7 +868,7 @@ class InferenceEngine:
                     moe_decode_dedup=moe_decode_dedup,
                     kv_ring=kv_ring, route_stats=route_stats,
                     expert_forms=expert_forms,
-                    one_live_lane=one_live_lane, **lane_state,
+                    live_lanes_alone=live_lanes_alone, **lane_state,
                 )
 
         self._fwd = fwd
@@ -1140,17 +1142,17 @@ class InferenceEngine:
                 rows[k] = rows.get(k, 0) + v
         return rows
 
-    def _chunk_expert_rows(self, bucket: int) -> dict:
-        """`step_dispatch` field of a sparse model's chunk: the token rows
-        its expert block computes, the admitted lane's `bucket` where the
-        program takes that lane's rows alone (`run_layers`' `one_live_lane`)
-        and every lane's where the lanes are split over devices. Counted
-        too, with the parked lanes' rows left out. Nothing for a dense
-        model."""
+    def _chunk_expert_rows(self, bucket: int, n_lanes: int) -> dict:
+        """`step_dispatch` field of a sparse model's chunk that fills
+        `n_lanes` lanes: the token rows its expert block computes, `bucket` a
+        carried lane where the program takes the live lanes' rows alone
+        (`run_layers`' `live_lanes_alone`) and every lane's where the lanes
+        are split over devices. Counted too, with the parked lanes' rows
+        left out. Nothing for a dense model."""
         if not self.header.n_experts:
             return {}
         every = self.batch_size * bucket
-        rows = bucket if lanes_on_one_device(self.mesh) else every
+        rows = n_lanes * bucket if self.chunk_rider_adds_rows else every
         self._m_moe_chunk_rows.labels(rows="computed").inc(rows)
         self._m_moe_chunk_rows.labels(rows="parked_skipped").inc(every - rows)
         return {"expert_rows": rows}
@@ -1797,7 +1799,7 @@ class InferenceEngine:
                         params, tokens, pos_vec, cache,
                         attn_window=window, attn_park_threshold=park,
                         logits_mode="last", n_micro=self._pp_micro(t),
-                        one_live_lane=True, **lane_state,
+                        live_lanes_alone=True, **lane_state,
                         **({} if forms is None else {"expert_forms": forms}),
                     )
                 return cache, (_chunk_mark(cache) if forms is None else forms.pop())
@@ -1945,11 +1947,20 @@ class InferenceEngine:
     @property
     def chunk_lanes(self) -> int:
         """The lanes one chunk program can fill (`prefill_lanes_chunk`):
-        every lane, or 1 where the program takes one lane's rows somewhere
+        every lane, or 1 where the program takes one lane somewhere
         (`chunk_takes_one_lane`) or reads the pool's pages (`--kv-native
         1`, whose paged program has not been run with two live lanes)."""
         one = chunk_takes_one_lane(self.header) or self.kv_native
         return 1 if one else self.batch_size
+
+    @property
+    def chunk_rider_adds_rows(self) -> bool:
+        """Whether a lane more in a chunk program is rows more to compute:
+        true where the expert block runs over the live lanes' rows alone, a
+        lane after another; false for a dense program, and where the lanes
+        are split over devices, which compute every lane's rows whatever
+        they hold. What the scheduler pays a program's riders by."""
+        return bool(self.header.n_experts) and lanes_on_one_device(self.mesh)
 
     def prefill_lane_chunk(
         self,
@@ -1990,9 +2001,10 @@ class InferenceEngine:
     ) -> list[int]:
         """ONE chunk program for the next chunk of every `(lane, tokens,
         pos0)` of `chunks`, at most `chunk_lanes` of them, the first the
-        lead: the program computes every lane's rows whatever they hold, so
-        each admitting lane's row holds its own tokens at its own position
-        and a parked lane's alone is zeros. Returns the width each consumed,
+        lead: the program computes every lane's rows whatever they hold (its
+        expert block the live lanes' alone, a lane after another), so each
+        admitting lane's row holds its own tokens at its own position and a
+        parked lane's alone is zeros. Returns the width each consumed,
         in `chunks`' order.
 
         The common bucket is the largest that a carried lane's chunk asks
@@ -2072,7 +2084,7 @@ class InferenceEngine:
             lane=lane, pos=pos0, lanes=[c[0] for c in filled],
             n_tokens=sum(widths), bucket=bucket, window=window,
             **self._chunk_rows_in_context(filled),
-            **self._chunk_expert_rows(bucket),
+            **self._chunk_expert_rows(bucket, len(filled)),
             **self._chunk_state_fields(pos0, widths[0], write_floor),
         ) as timed:
             # `mark`: the forms its expert layers took, or one integer, for
@@ -2787,7 +2799,7 @@ class InferenceEngine:
                         params, tokens, pos_vec, view,
                         attn_window=window, attn_park_threshold=window,
                         logits_mode="last", n_micro=self._pp_micro(t),
-                        one_live_lane=True,
+                        live_lanes_alone=True,
                     )
                 rows = pos_vec[:, None] + jnp.arange(t)[None, :]
                 safe = rows < window
@@ -3384,12 +3396,12 @@ class InferenceEngine:
         mesh = self.mesh
 
         def dfwd(params, tokens, pos, cache, *, attn_park_threshold=0,
-                 logits_mode="all", one_live_lane=False):
+                 logits_mode="all", live_lanes_alone=False):
             return forward(
                 params, dh, tokens, pos, cache, mesh=mesh,
                 attn_window=0, logits_mode=logits_mode,
                 attn_park_threshold=attn_park_threshold,
-                one_live_lane=one_live_lane,
+                live_lanes_alone=live_lanes_alone,
             )
 
         self._draft_fwd = dfwd
@@ -3497,7 +3509,7 @@ class InferenceEngine:
                 _, cache = dfwd(
                     params, tokens, pos_vec, cache,
                     attn_park_threshold=park, logits_mode="last",
-                    one_live_lane=True,
+                    live_lanes_alone=True,
                 )
                 return cache
 

@@ -559,6 +559,14 @@ def make_tiny_granite(path: str, seed: int = 3, **over) -> dict:
     return _write_tiny(path, tiny_granite_config(**over), seed)
 
 
+# the benchmark's families at test widths, by the name of their `LlmArch`
+TINY_FAMILY_WRITERS = {
+    "afmoe": make_tiny_afmoe, "pangu_ultra_moe": make_tiny_pangu,
+    "deepseek_v32": make_tiny_dsv32, "lfm2_moe": make_tiny_lfm2,
+    "granitemoehybrid": make_tiny_granite,
+}
+
+
 # -- the prefill ladder's middle rungs (PR 49) ------------------------------------
 
 # prompts whose fills take the 128-row and the 256-row rung of the served ladder

@@ -178,6 +178,20 @@ def test_chunked_prefill_token_parity(tiny_paths):
     assert b2 == a2  # prefix-reuse resume (pending token) parity
 
 
+def _greedy_16(e, toks, end):
+    """Four blocks of four greedy tokens for each lane of `end` (the position
+    its prompt `toks[lane]` ends at) of a four-lane engine, the others parked."""
+    t = [toks[lane][-1] if lane in end else 0 for lane in range(4)]
+    pos = [end.get(lane, 0) for lane in range(4)]
+    live = [lane in end for lane in range(4)]
+    out = []
+    for _ in range(4):
+        block = e.decode_lanes(t, pos, 4, live, [0.0] * 4, [0.9] * 4)
+        out += block
+        t, pos = list(block[-1]), [p + 4 for p in pos]
+    return [[row[lane] for row in out] for lane in end]
+
+
 @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["f32", "int8"])
 def test_lanes_filled_by_one_program_equal_lanes_filled_one_a_tick(tiny_paths, kv_dtype):
     """Three lanes admitted together, each tick's chunk program carrying the
@@ -222,16 +236,7 @@ def test_lanes_filled_by_one_program_equal_lanes_filled_one_a_tick(tiny_paths, k
             for name, leaf in e.cache.items():
                 leaf = leaf.q.astype(jnp.float32) * leaf.s if kv_dtype else leaf
                 rows[lane, name] = np.asarray(leaf[:, lane, :, : end[lane]])
-        t = [toks[lane][-1] if lane in held else 0 for lane in range(4)]
-        pos = [end[lane] if lane in held else 0 for lane in range(4)]
-        live = [lane in held for lane in range(4)]
-        out = []
-        for _ in range(4):
-            block = e.decode_lanes(t, pos, 4, live, [0.0] * 4, [0.9] * 4)
-            out += block
-            t, pos = list(block[-1]), [p + 4 for p in pos]
-        return (rows, [[row[lane] for row in out] for lane in held], programs,
-                e._m_prefill_lanes.value - lanes0)
+        return rows, _greedy_16(e, toks, end), programs, e._m_prefill_lanes.value - lanes0
 
     rows_one, tokens_one, programs_one, lanes_one = admit(together=False)
     rows_all, tokens_all, programs_all, lanes_all = admit(together=True)
@@ -366,10 +371,10 @@ def driven(driven_state):
     sched._rr = -1
 
 
-def _begin(state, lane, n_tokens):
+def _begin(state, lane, n_tokens, max_tokens=4):
     """`lane` begins the admission of `n_tokens` raw ids (no template)."""
     ids = [2 + (lane * 41 + i * 7) % 250 for i in range(n_tokens)]
-    job = LaneJob(InferenceParams(resume_tokens=ids, max_tokens=4, temperature=0.0))
+    job = LaneJob(InferenceParams(resume_tokens=ids, max_tokens=max_tokens, temperature=0.0))
     job.span = state.tracer.span(path="lanes")
     state.scheduler._begin_admission(lane, job)
     return state.scheduler.admitting[lane]
@@ -494,24 +499,38 @@ def _one_lane_a_tick(eng, rr, script, budget):
     return out
 
 
-@pytest.mark.parametrize("family", ["afmoe", "lfm2_moe"])
-def test_a_chunk_program_that_takes_one_lanes_rows_is_dispatched_as_before(
-        tmp_path_factory, family):
-    """A model with experts, and one with lane state besides: `chunk_lanes`
-    is 1, and three overlapping admissions dispatch what one lane a tick,
-    round-robin, dispatches: the same programs with the same arguments."""
+def _tiny_family(tmp_path_factory, family: str, seq_len: int = 256):
+    """(model, tokenizer) files of a tiny model of `family`."""
     import helpers
+    from dllama_tpu.formats.model_file import LlmArch
     from dllama_tpu.models.synthetic import write_synth_tokenizer
 
     d = tmp_path_factory.mktemp(family)
     mp, tp_ = str(d / "m.m"), str(d / "t.t")
-    {"afmoe": helpers.make_tiny_afmoe, "lfm2_moe": helpers.make_tiny_lfm2}[family](mp)
+    if family == "qwen3_moe":
+        make_tiny_model(mp, arch=LlmArch.QWEN3_MOE, cfg=dict(
+            CFG, moe_hidden_dim=96, n_experts=4, n_active_experts=2, seq_len=seq_len))
+    else:
+        helpers.TINY_FAMILY_WRITERS[family](mp)
     write_synth_tokenizer(tp_, 512)
+    return mp, tp_
+
+
+@pytest.mark.parametrize("family", ["deepseek_v32", "lfm2_moe"])
+def test_a_chunk_program_that_takes_one_lanes_rows_is_dispatched_as_before(
+        tmp_path_factory, family):
+    """A model with a latent index, and one with lane state: `chunk_lanes`
+    is 1 (an index builds one lane's mask, a state layer takes one lane's
+    state), and three overlapping admissions dispatch what one lane a tick,
+    round-robin, dispatches: the same programs with the same arguments, and
+    no tick yields."""
+    mp, tp_ = _tiny_family(tmp_path_factory, family)
     state = _driven(mp, tp_, prefill_buckets=(1, 8, 16), max_seq_len=256)
     sched, eng = state.scheduler, state.engine
     sched.admission_chunk = 16
     assert eng.chunk_lanes == 1 and eng.header.n_experts
     assert eng.header.stateful == (family == "lfm2_moe")
+    assert eng.header.indexed == (family == "deepseek_v32")
     script = [[(0, 70), (1, 41)], [], [(2, 30)]]
     base, lanes0 = state.recorder.total_recorded, eng._m_prefill_lanes.value
     want = _one_lane_a_tick(eng, sched._rr, script, 16)
@@ -525,8 +544,248 @@ def test_a_chunk_program_that_takes_one_lanes_rows_is_dispatched_as_before(
     assert [(e["lane"], e["pos"], e["n_tokens"], e["bucket"], e["window"]) for e in got] == want
     assert all(e["lanes"] == [e["lane"]] for e in got) and len(got) == 5 + 3 + 2
     assert eng._m_prefill_lanes.value - lanes0 == len(got)
+    assert all(e["expert_rows"] == e["bucket"] for e in got)
     assert all(ls is not None for ls in sched.lanes[:3])
+    assert not state.m_admission_yielded.value and not sched._chunk_debt
     sched._drop_all(RuntimeError("the test is over"))
+
+
+@pytest.mark.parametrize("family", ["qwen3_moe", "afmoe", "pangu_ultra_moe"])
+def test_expert_lanes_filled_by_one_program_equal_lanes_filled_one_a_tick(
+        tmp_path_factory, family):
+    """A model with experts and neither lane state nor an index: one chunk
+    program fills every admitting lane (`chunk_lanes` = the lanes), its
+    expert block a live lane after another. Three lanes at different
+    positions, lengths and last-chunk sizes, filled by one program a tick:
+    each lane's cache rows (every stack; a ring's rows by position, the
+    window's) and its first 16 greedy tokens are those of the same three
+    filled one lane a tick, while a fourth lane stands parked. `afmoe`'s ring
+    of 48 rows is shorter than the prompts: lane 1's second chunk, at 37,
+    runs over the ring's end in the program that writes lane 0's at 16,
+    which does not."""
+    mp, _ = _tiny_family(tmp_path_factory, family)
+    e = InferenceEngine(
+        mp, tp=1, dtype=jnp.float32, temperature=0.0, batch_size=4,
+        prefill_buckets=(1, 8, 16), max_seq_len=256,
+    )
+    assert e.chunk_lanes == 4 and e.chunk_rider_adds_rows
+    budget, ring, pad = 16, e.kv_ring, e._lane_pad
+    assert ring == (48 if family == "afmoe" else 0)
+    held = {0: 0, 1: 21, 3: 60}
+    fills = {0: 71, 1: 32, 3: 9}  # last chunks of 7, 16 and 9 rows
+    toks = {lane: [2 + (lane * 31 + i * 7) % 250 for i in range(held[lane] + fills[lane] + 1)]
+            for lane in held}
+
+    def admit(together: bool):
+        e.reset()
+        for lane, n in held.items():
+            if n:
+                e.prefill_lane(lane, toks[lane][: n + 1])
+        cur = dict(held)
+        end = {lane: held[lane] + fills[lane] for lane in held}
+        programs = []
+        rows0 = e._m_moe_chunk_rows.labels(rows="computed").value
+        base = e.recorder.total_recorded
+        while any(cur[lane] < end[lane] for lane in cur):
+            left = [lane for lane in cur if cur[lane] < end[lane]]
+            for group in ([left] if together else [[lane] for lane in left]):
+                chunks = [(lane, toks[lane][cur[lane]:end[lane]], cur[lane]) for lane in group]
+                widths = e.prefill_lanes_chunk(chunks, budget=budget)
+                programs.append([(lane, cur[lane]) for lane in group])
+                for lane, width in zip(group, widths):
+                    assert 0 < width <= budget
+                    cur[lane] += width
+        events = [ev for ev in e.recorder.events() if ev["seq"] > base
+                  and ev["kind"] == "step_dispatch" and ev["step"] == "prefill_lane_chunk"]
+        assert [ev["expert_rows"] for ev in events] == [
+            ev["bucket"] * len(ev["lanes"]) for ev in events]
+        assert e._m_moe_chunk_rows.labels(rows="computed").value - rows0 == sum(
+            ev["expert_rows"] for ev in events)
+        rows = {}
+        for lane in held:
+            for name, leaf in e.cache.items():
+                if name in ("kw", "vw"):
+                    at = [pad + p % ring for p in range(
+                        max(0, end[lane] - e.header.sliding_window), end[lane])]
+                    rows[lane, name] = np.asarray(leaf[:, lane])[:, :, at]
+                else:
+                    rows[lane, name] = np.asarray(leaf[:, lane, :, : end[lane]])
+        return rows, _greedy_16(e, toks, end), programs
+
+    rows_one, tokens_one, programs_one = admit(together=False)
+    rows_all, tokens_all, programs_all = admit(together=True)
+    assert len(programs_one) == 5 + 2 + 1
+    assert programs_all == [[(0, 0), (1, 21), (3, 60)], [(0, 16), (1, 37)], [(0, 32)],
+                            [(0, 48)], [(0, 64)]]
+    assert tokens_all == tokens_one and all(len(t) == 16 for t in tokens_all)
+    assert len({tuple(t) for t in tokens_all}) == 3
+    for key, want in rows_one.items():
+        assert want.size and np.abs(rows_all[key] - want).max() <= 1e-5, key
+
+
+@pytest.fixture(scope="module")
+def driven_experts_state(tmp_path_factory):
+    return _driven(*_tiny_family(tmp_path_factory, "qwen3_moe", seq_len=384))
+
+
+@pytest.fixture
+def driven_experts(driven_experts_state):
+    from dllama_tpu.runtime.faults import set_fault_plane
+
+    sched = driven_experts_state.scheduler
+    assert not sched.admitting and not any(sched.lanes) and not sched._chunk_debt
+    sched.kv = None  # no stored prefix: every test's prompts start at their first token
+    yield driven_experts_state
+    set_fault_plane("")
+    sched._drop_all(RuntimeError("the test is over"))
+    sched._rr = -1
+    sched._admission_tick()  # nothing admits: what was owed is dropped
+    assert not sched._chunk_debt
+
+
+def _tick(sched):
+    """What the scheduler's loop does of a tick for admission and decode,
+    inside the tick's span as there (its end takes with it what a failed
+    dispatch left open above it)."""
+    tick_sp = sched.state.spans.begin("sched_tick", component="scheduler")
+    try:
+        sched._admission_tick()
+        if any(sched.lanes) or sched._flight is not None:
+            sched._guarded_step(sched._step_block)
+    finally:
+        sched.state.spans.end(tick_sp)
+
+
+def _programs(state, base):
+    """The chunk programs' lanes and the decode blocks (None) since `base`,
+    in the order of their dispatch."""
+    return [e["lanes"] if e["step"] == "prefill_lane_chunk" else None
+            for e in state.recorder.events() if e["seq"] > base
+            and e["kind"] == "step_dispatch" and e["step"] in ("prefill_lane_chunk", "decode_lanes")]
+
+
+def _decoding_then_three_admit(state):
+    """Lane 3 decodes (an answer of 400 tokens); lanes 0 to 2 begin prompts of
+    250, 180 and 120 tokens and one tick's program has carried all three."""
+    sched = state.scheduler
+    _begin(state, 3, 5, max_tokens=400)
+    _tick(sched)
+    assert sched.lanes[3] is not None and not sched._chunk_debt
+    adms = [_begin(state, lane, n) for lane, n in ((0, 250), (1, 180), (2, 120))]
+    base = state.recorder.total_recorded
+    _tick(sched)
+    assert [adm.cursor for adm in adms] == [100, 100, 100]
+    assert _programs(state, base) == [[0, 1, 2], None]
+    return adms, base
+
+
+@pytest.mark.parametrize("decoding", [True, False], ids=["a_lane_decodes", "none_decodes"])
+def test_a_program_with_riders_that_add_rows_owes_a_block_a_rider(driven_experts, decoding):
+    """A model with experts: a rider adds rows to the chunk program, so a
+    program that carried three lanes while a lane decodes is followed by two
+    ticks with a block and no chunk program, then a chunk program (whose two
+    lanes owe one tick more); the counter counts the yielded ticks. With no
+    lane decoding nothing is owed to anyone: chunk programs follow each
+    other."""
+    state, sched = driven_experts, driven_experts.scheduler
+    assert state.engine.chunk_lanes == 4 and state.engine.chunk_rider_adds_rows
+    yielded0 = state.m_admission_yielded.value
+    if not decoding:
+        adms = [_begin(state, lane, n) for lane, n in ((0, 350), (1, 330), (2, 310))]
+        base = state.recorder.total_recorded
+        for _ in range(3):
+            _tick(sched)  # no prompt ends in these: none decodes
+        assert _programs(state, base) == [[0, 1, 2], [1, 2, 0], [2, 0, 1]]
+        assert [adm.cursor for adm in adms] == [300, 300, 300]
+        assert state.m_admission_yielded.value == yielded0
+        return
+    adms, base = _decoding_then_three_admit(state)
+    assert sched._chunk_debt == 2 and sched._rr == 0
+    for owed in (1, 0):
+        _tick(sched)
+        assert sched._chunk_debt == owed and sched._rr == 0
+        assert [adm.cursor for adm in adms] == [100, 100, 100]
+    assert state.m_admission_yielded.value - yielded0 == 2
+    _tick(sched)  # lane 1 leads; lanes 1 and 2 end their prompts
+    assert [adm.cursor for adm in adms] == [200, 179, 119] and sched._chunk_debt == 2
+    assert _programs(state, base) == [[0, 1, 2], None, None, None, [1, 2, 0], None]
+    for _ in range(3):
+        _tick(sched)
+    assert _programs(state, base)[6:] == [None, None, [0], None]
+    assert not sched.admitting and not sched._chunk_debt
+    assert state.m_admission_yielded.value - yielded0 == 4
+
+
+def test_a_dense_program_owes_nothing(tiny_paths, tmp_path):
+    """A dense program computes every lane's rows whatever they hold: its
+    riders are free, and a chunk program follows the last tick's however
+    many lanes that carried, a lane decoding or not."""
+    from dllama_tpu.models.synthetic import write_synth_tokenizer
+
+    write_synth_tokenizer(str(tmp_path / "t.t"), 512)  # one that holds every token the model gives
+    state = _driven(tiny_paths[0], str(tmp_path / "t.t"))
+    sched = state.scheduler
+    sched.kv = None
+    assert state.engine.chunk_lanes == 4 and not state.engine.chunk_rider_adds_rows
+    yielded0 = state.m_admission_yielded.value
+    adms, base = _decoding_then_three_admit(state)
+    assert not sched._chunk_debt
+    _tick(sched)
+    _tick(sched)
+    assert _programs(state, base) == [[0, 1, 2], None, [1, 2, 0], None, [0], None]
+    assert not sched.admitting and state.m_admission_yielded.value == yielded0
+    sched._drop_all(RuntimeError("the test is over"))
+
+
+@pytest.mark.parametrize("case", ["cancelled", "fault", "poison"])
+def test_what_a_program_owed_goes_with_the_lanes_it_was_owed_to(driven_experts, case):
+    """Two blocks are owed when the admitting lanes' clients go away, or the
+    tick's block fails on an intact cache (every request dropped), or takes
+    the cache with it (every lane starts over, none decodes, and the next
+    tick's program runs): once nothing admits nothing stays owed, and the
+    next admission's first chunk is the next tick's."""
+    from dllama_tpu.runtime.faults import set_fault_plane
+
+    state, sched = driven_experts, driven_experts.scheduler
+    adms, base = _decoding_then_three_admit(state)
+    assert sched._chunk_debt == 2
+    yielded0 = state.m_admission_yielded.value
+    if case == "cancelled":
+        for adm in adms:
+            adm.job.cancelled = True
+        for _ in range(3):
+            _tick(sched)  # a tick aborts its lead
+        assert not sched.admitting and sched.lanes[3] is not None
+        _tick(sched)
+    elif case == "fault":
+        set_fault_plane("dispatch:op=decode_lanes:every=1")
+        _tick(sched)  # the tick yields, and its block fails: all four dropped
+        set_fault_plane("")
+        assert not sched.admitting and not any(sched.lanes)
+        assert state.m_admission_yielded.value - yielded0 == 1
+        yielded0 += 1
+        _tick(sched)
+    else:
+        epoch = state.engine.cache_epoch
+        set_fault_plane("dispatch:op=decode_lanes:nth=1:kind=poison")
+        _tick(sched)  # the tick yields, and its block takes the cache
+        assert state.engine.cache_epoch == epoch + 1 and sorted(sched.admitting) == [0, 1, 2, 3]
+        assert state.m_admission_yielded.value - yielded0 == 1
+        yielded0 += 1
+        at = state.recorder.total_recorded
+        _tick(sched)
+        # no lane decodes, so the next tick's program runs, every lane aboard;
+        # it ends lane 3's few rows, which decodes again and is owed for three
+        assert _programs(state, at) == [[1, 2, 3, 0], None] and sched._chunk_debt == 3
+        assert state.m_admission_yielded.value == yielded0
+        sched._drop_all(RuntimeError("enough"))
+        _tick(sched)
+    assert not sched._chunk_debt
+    b = _begin(state, 1, 50)
+    at = state.recorder.total_recorded
+    _tick(sched)
+    assert b.cursor == 49 and [p for p in _programs(state, at) if p] == [[1]]
+    assert state.m_admission_yielded.value == yielded0
 
 
 # -- stall model: chunk events + bounded decode gaps (fake clock) -------------

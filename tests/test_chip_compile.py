@@ -295,7 +295,7 @@ def layer_scan_text(header, layers, cache, t, window, s, cache_s=None, mesh=None
     def step(x, layers, k, v, pos, cos, sin):
         return tf.run_layers(
             x, layers, k, v, header, pos, pos, cos, sin,
-            mesh=mesh, attn_window=window, one_live_lane=t > 1,
+            mesh=mesh, attn_window=window, live_lanes_alone=t > 1,
         )
 
     return compiled_text(
@@ -504,7 +504,7 @@ def test_layer_scan_writes_both_cache_stacks_in_place(one_chip, monkeypatch, row
         out = tf.run_layers(
             x, layers, k, v, h, pos, jnp.where(pos >= 16384, -16896, pos), cos, sin,
             attn_window=window, kw_cache=kw, vw_cache=vw, kv_ring=4608,
-            route_stats=counts, one_live_lane=rows > 1,
+            route_stats=counts, live_lanes_alone=rows > 1,
         )
         return out, counts
 
@@ -593,7 +593,7 @@ def test_layer_scan_writes_latent_rows_in_place(one_chip, monkeypatch, rows, win
         out = tf.run_layers(
             x, layers, None, None, h, pos, jnp.where(pos >= 16384, -16896, pos),
             cos, sin, attn_window=window, c_cache=c, route_stats=counts,
-            one_live_lane=rows > 1,
+            live_lanes_alone=rows > 1,
         )
         return out, counts
 
@@ -687,7 +687,7 @@ def test_layer_scan_writes_latent_rows_and_index_keys_in_place(
         out = tf.run_layers(
             x, layers, None, None, h, pos, jnp.where(pos >= 8192, -8704, pos),
             cos, sin, attn_window=window, c_cache=c, i_cache=i, route_stats=counts,
-            one_live_lane=rows > 1,
+            live_lanes_alone=rows > 1,
         )
         return out, counts
 
@@ -828,7 +828,7 @@ def test_layer_scan_carries_lane_state_beside_a_cache_of_ten_layers(
         live = pos < 4096
         out = tf.run_layers(
             x, layers, k, v, h, pos, jnp.where(live, pos, -4608), cos, sin,
-            attn_window=window, route_stats=counts, one_live_lane=rows > 1,
+            attn_window=window, route_stats=counts, live_lanes_alone=rows > 1,
             s_cache=st, state_rows=jnp.where(live, aux[0], 0),
             write_floor=aux[1] if rows > 1 else None,  # a chunk program's alone
             state_fresh=jnp.logical_and(live, aux[2] > 0),
@@ -974,7 +974,7 @@ def test_layer_scan_carries_a_recurrent_state_beside_a_cache_of_two_layers(
         live = pos < 4096
         out = tf.run_layers(
             x, layers, k, v, h, pos, jnp.where(live, pos, -4608), cos, sin,
-            attn_window=window, route_stats=counts, one_live_lane=rows > 1,
+            attn_window=window, route_stats=counts, live_lanes_alone=rows > 1,
             s_cache=st, r_cache=rec, state_rows=jnp.where(live, aux[0], 0),
             write_floor=aux[1] if rows > 1 else None,
             state_fresh=jnp.logical_and(live, aux[2] > 0),
@@ -1113,28 +1113,35 @@ def test_lane_block_keeps_the_sampler_conditional(one_chip):
     assert not sorts & outside, sorts & outside
 
 
-def test_rehearsal_schedules_the_dense_cells_programs_and_no_other():
+@pytest.mark.parametrize("preset", ["mistral-7b-v0.3", "openpangu-ultra-l5-e32"])
+def test_rehearsal_schedules_the_cells_programs_and_no_other(preset):
     """`mistral-7b-v0.3` as its cells serve it (five lanes of 4096 positions,
-    the ladder of `--nbatches 32`, blocks of 8 steps): `rehearse_admission`
-    schedules one chunk program a rung at the rung's base window and the
-    decode block, under the keys it always had. A chunk program that fills
-    several admitting lanes' rows is one of these, or the same rung at a
-    deeper window, built as one lane's is when a lane gets there."""
+    the ladder of `--nbatches 32`, blocks of 8 steps) and a preset with
+    experts, `openpangu-ultra-l5-e32` as `pangu-docqa` serves it (four lanes
+    of 8192 latent rows, the default ladder): `rehearse_admission` schedules
+    one chunk program a rung at the rung's base window and the decode block,
+    under the keys it always had. A chunk program that fills several
+    admitting lanes' rows (its expert block a live lane after another, a
+    traced count of them) is one of these, or the same rung at a deeper
+    window, built as one lane's is when a lane gets there."""
     import types
 
     from dllama_tpu.runtime.engine import InferenceEngine, prefill_ladder
 
+    dense = preset == "mistral-7b-v0.3"
     scheduled = []
     stand_in = types.SimpleNamespace(
         _require_lanes=lambda: None, _aot_blocks=True, kv_native=False, kv_pool=None,
-        _draft_params=None, prefill_buckets=prefill_ladder(32), sp=1, _latent=False,
-        header=types.SimpleNamespace(seq_len=4096, sliding_window=0),
+        _draft_params=None, prefill_buckets=prefill_ladder(32 if dense else 256), sp=1,
+        _latent=not dense,
+        header=types.SimpleNamespace(seq_len=4096 if dense else 8192, sliding_window=0),
         _prefetch=lambda key, build: scheduled.append(key),
     )
     stand_in._attn_window = lambda limit: InferenceEngine._attn_window(stand_in, limit)
     InferenceEngine.rehearse_admission(stand_in, 8)
-    assert scheduled == [("lane_prefill", rung, 512) for rung in (1, 32, 128, 256, 512)] + [
-        ("lane_block", 8, 512)]
+    rungs, window = ((1, 32, 128, 256, 512), 512) if dense else ((1, 256, 512), 4096)
+    assert scheduled == [("lane_prefill", rung, window) for rung in rungs] + [
+        ("lane_block", 8, window)]
 
 
 def test_cache_copies_flags_the_caches_as_scan_xs(one_chip):

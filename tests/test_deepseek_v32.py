@@ -314,7 +314,7 @@ def test_four_lanes_at_unequal_positions_one_parked(tmp_path):
     seqs = [token_ids(n + 1, seed=10 + i) for i, n in enumerate(lengths)]
     cache = init_kv_cache(h, lanes, jnp.float32, seq_len=SEQ + CHUNK)
     step = jax.jit(lambda toks, pos, cache, lone: forward(
-        params, h, toks, pos, cache, attn_park_threshold=park, one_live_lane=lone),
+        params, h, toks, pos, cache, attn_park_threshold=park, live_lanes_alone=lone),
         static_argnums=3)
     for lane, ids in enumerate(seqs):  # lane by lane, the others parked
         p = 0

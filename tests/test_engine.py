@@ -1673,6 +1673,63 @@ def test_prefill_counters_say_chunks_by_rung_and_rows_real_and_computed(ladder_e
     assert "dllama_prefill_lanes_total" in text
 
 
+@pytest.mark.parametrize("family,rides", [
+    ("llama", True), ("qwen3_moe", True), ("afmoe", True), ("pangu_ultra_moe", True),
+    ("lfm2_moe", False), ("granitemoehybrid", False), ("deepseek_v32", False),
+    ("qwen3_moe --kv-native 1", False),
+])
+def test_chunk_lanes_by_what_the_header_says(tmp_path, tiny_model, family, rides):
+    """A chunk program fills every lane (`chunk_lanes` = `batch_size`) of a
+    dense model and of one with experts, whose block visits the live lanes
+    one after another, so that a rider adds rows there and none to a dense
+    program; it fills ONE lane where a layer keeps a state a lane, where an
+    index builds a lane's mask, and where it reads the pool's pages. Read
+    from the header: no family is named in the engine."""
+    import helpers
+
+    path = str(tmp_path / "f.m")
+    name, _, native = family.partition(" ")
+    if name == "llama":
+        path = tiny_model[0]
+    elif name == "qwen3_moe":
+        make_tiny_model(path, arch=LlmArch.QWEN3_MOE, weight_type=FloatType.Q40)
+    else:
+        helpers.TINY_FAMILY_WRITERS[name](path)
+    e = InferenceEngine(path, tp=1, dtype=jnp.float32, temperature=0.0, batch_size=3,
+                        prefill_buckets=(1, 8))
+    if native:
+        e.init_kv_pool(4, native=True)
+    h = e.header
+    assert e.chunk_lanes == (3 if rides else 1)
+    assert rides == (not (h.stateful or h.indexed or e.kv_native))
+    assert e.chunk_rider_adds_rows == bool(h.n_experts) == (name != "llama")
+    if e.chunk_lanes == 1:
+        with pytest.raises(ValueError, match="fills 1 to 1 lanes"):
+            e.prefill_lanes_chunk([(0, [1, 2], 0), (1, [3], 0)])
+
+
+def test_expert_rows_count_the_bucket_a_carried_lane(tmp_path):
+    """A chunk program of a model with experts that fills two lanes of four,
+    and then one: `expert_rows` and `dllama_moe_chunk_rows_total{rows=
+    "computed"}` count the bucket a carried lane, `parked_skipped` the rows
+    of the lanes left parked."""
+    path = str(tmp_path / "moe.m")
+    make_tiny_model(path, arch=LlmArch.QWEN3_MOE, weight_type=FloatType.Q40)
+    e = InferenceEngine(path, tp=1, dtype=jnp.float32, temperature=0.0, batch_size=4,
+                        prefill_buckets=(1, 8))
+
+    def rows_counted():
+        return [e._m_moe_chunk_rows.labels(rows=r).value for r in ("computed", "parked_skipped")]
+
+    rows0, base = rows_counted(), e.recorder.total_recorded
+    assert e.prefill_lanes_chunk([(3, [5, 6, 7], 0), (1, list(range(1, 12)), 4)]) == [3, 8]
+    assert e.prefill_lanes_chunk([(1, [9, 10, 11], 12)]) == [3]
+    events = [ev for ev in e.recorder.events() if ev["seq"] > base and ev["kind"] == "step_dispatch"]
+    assert [(ev["lanes"], ev["bucket"], ev["expert_rows"]) for ev in events] == [
+        ([3, 1], 8, 16), ([1], 8, 8)]
+    assert [a - b for a, b in zip(rows_counted(), rows0)] == [24, 2 * 4 * 8 - 24]
+
+
 @pytest.mark.parametrize("chunks,budget,widths,bucket,window", [
     # the common rung is the widest that a carried lane asks, the window the deepest
     ([(0, 300, 0), (1, 40, 500)], None, [300, 40], 512, 1024),

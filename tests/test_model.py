@@ -114,7 +114,7 @@ def _sparse_family(tmp_path, family: str):
 @pytest.mark.parametrize("family", ["qwen3_moe", "afmoe", "pangu_ultra_moe"])
 def test_chunk_of_one_live_lane_computes_its_experts_alone(tmp_path, family, live):
     """A chunk program's rows hold one admitted lane of four, the others
-    parked (`one_live_lane`): the expert block runs over that lane's rows
+    parked (`live_lanes_alone`): the expert block runs over that lane's rows
     alone. Two chunks at the lane, first, in the middle or last: every row's
     logits and the cache rows written are what the program over every lane's
     rows gives (the uncompacted formula), the other lanes' rows stay as they
@@ -126,7 +126,7 @@ def test_chunk_of_one_live_lane_computes_its_experts_alone(tmp_path, family, liv
     ids = [int(t) for t in np.random.default_rng(11 + live).integers(
         0, min(500, h.vocab_size), 2 * chunk)]
 
-    def run(one_live_lane: bool):
+    def run(live_lanes_alone: bool):
         cache = init_kv_cache(h, lanes, jnp.float32, **cache_kw)
         rng = np.random.default_rng(5)
         cache = {k: jnp.asarray(rng.standard_normal(v.shape), v.dtype)
@@ -134,7 +134,7 @@ def test_chunk_of_one_live_lane_computes_its_experts_alone(tmp_path, family, liv
         before = {k: np.asarray(v) for k, v in cache.items()}
         step = jax.jit(lambda toks, pos, cache: forward(
             params, h, toks, pos, cache, attn_park_threshold=park,
-            one_live_lane=one_live_lane, **fwd_kw))
+            live_lanes_alone=live_lanes_alone, **fwd_kw))
         out = []
         for p in (0, chunk):
             toks = np.zeros((lanes, chunk), np.int32)
@@ -162,6 +162,66 @@ def test_chunk_of_one_live_lane_computes_its_experts_alone(tmp_path, family, liv
                 name, lane)
 
 
+@pytest.mark.parametrize("live", [(0, 2), (1, 2, 3)], ids=["two", "three"])
+@pytest.mark.parametrize("family", ["qwen3_moe", "afmoe", "pangu_ultra_moe"])
+def test_chunk_of_several_live_lanes_visits_each_as_its_own_program_does(
+        tmp_path, family, live):
+    """A chunk program's rows hold two or three admitting lanes of four, each
+    at a position of its own (for `afmoe` one of them across its ring's end):
+    the expert block visits them one after another. Every live lane's logits
+    and cache rows are, bit for bit, those of the same rows run one live lane
+    a program; the parked lanes' rows stay as they were; and the routing
+    counters and the form counter are the sums over those programs."""
+    import jax
+
+    h, params, fwd_kw, cache_kw, _ = _sparse_family(tmp_path, family)
+    lanes, park, chunk = 4, h.seq_len, 16
+    ring = fwd_kw.get("kv_ring", 0)
+    at = dict(zip(live, (ring - 6 if ring else 40, 0, 3 * chunk)))
+    ids = np.random.default_rng(23).integers(
+        0, min(500, h.vocab_size), (lanes, chunk)).astype(np.int32)
+    cache0 = init_kv_cache(h, lanes, jnp.float32, **cache_kw)
+    rng = np.random.default_rng(5)
+    cache0 = {k: np.asarray(rng.standard_normal(v.shape), v.dtype) for k, v in cache0.items()}
+
+    def program(counter):
+        def fn(toks, pos, cache):
+            counted = []
+            logits, cache = forward(
+                params, h, toks, pos, cache, attn_park_threshold=park,
+                live_lanes_alone=True, **{counter: counted}, **fwd_kw)
+            return logits, cache, counted[0]
+        return jax.jit(fn)
+
+    def run(step, groups):
+        cache = {k: jnp.asarray(v) for k, v in cache0.items()}
+        logits, counts = {}, 0
+        for group in groups:
+            toks = np.zeros((lanes, chunk), np.int32)
+            pos = np.full(lanes, park, np.int32)
+            for lane in group:
+                toks[lane], pos[lane] = ids[lane], at[lane]
+            out, cache, c = step(jnp.asarray(toks), jnp.asarray(pos), cache)
+            counts = counts + np.asarray(c)
+            logits.update({lane: np.asarray(out[lane]) for lane in group})
+        return logits, {k: np.asarray(v) for k, v in cache.items()}, counts
+
+    for counter in ("route_stats", "expert_forms"):
+        step = program(counter)
+        got, cache, counts = run(step, [live])
+        want, apart, summed = run(step, [(lane,) for lane in live])
+        assert np.array_equal(counts, summed) and counts.any(), (counter, counts, summed)
+        for lane in live:
+            assert np.array_equal(got[lane], want[lane]), (counter, lane)
+        for name, rows in cache.items():
+            kept = slice(chunk, rows.shape[3] - chunk) if name in ("kw", "vw") else slice(0, park)
+            for lane in range(lanes):
+                assert np.array_equal(rows[:, lane, :, kept], apart[name][:, lane, :, kept]), (
+                    name, lane)
+                assert (lane in live) != np.array_equal(
+                    rows[:, lane, :, kept], cache0[name][:, lane, :, kept]), (name, lane)
+
+
 @pytest.mark.parametrize("program", ["chunk", "decode", "verify", "scalar"])
 def test_only_a_chunk_program_routes_one_lane(tmp_path, program):
     """What the router's `top_k` is shaped by: a chunk program (one admitted
@@ -178,7 +238,7 @@ def test_only_a_chunk_program_routes_one_lane(tmp_path, program):
     told = program in ("chunk", "scalar")
     jaxpr = jax.make_jaxpr(lambda toks, pos, cache: forward(
         params, h, toks, pos, cache, attn_park_threshold=h.seq_len,
-        one_live_lane=told))(jnp.zeros((lanes, t), jnp.int32), pos, cache)
+        live_lanes_alone=told))(jnp.zeros((lanes, t), jnp.int32), pos, cache)
     routed = {e.invars[0].aval.shape[0] for e, _ in _equations(jaxpr.jaxpr)
               if e.primitive.name == "top_k"}
     assert routed == {t if program == "chunk" else lanes * t}

@@ -332,7 +332,8 @@ def test_lane_programs_carry_the_layer_scopes(lane_programs, program):
     op_names = set(re.findall(r'op_name="([^"]*)"', texts[program]))
     ffn = "moe" if kind == "moe" else "ffn"
     for scope in ("attn", ffn, "kv_write", "norm"):
-        assert any(re.search(rf"/layers/(while/body/(closed_call/)?)?{scope}(/|$|;)", n)
+        # (a chunk's expert block is a loop over the live lanes inside the scan's body)
+        assert any(re.search(rf"/layers/((while|body|closed_call)/)*{scope}(/|$|;)", n)
                    for n in op_names), scope
     if program == "decode":  # a prefill chunk's logits are dead code
         assert any("/logits_head" in n for n in op_names)
